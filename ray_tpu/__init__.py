@@ -19,6 +19,10 @@ Public API mirrors the reference framework (see SURVEY.md):
 """
 
 from ._version import __version__  # noqa: F401
+from .core.compile_cache import place_compile_cache as _place_compile_cache
+
+_place_compile_cache()
+
 from .api import (  # noqa: F401
     ActorClass,
     ActorHandle,

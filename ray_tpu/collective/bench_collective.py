@@ -68,7 +68,7 @@ def main(quick: bool = False) -> None:
     import ray_tpu.collective as col
     from ray_tpu.collective import algorithms as alg
     from ray_tpu.collective.tuner import get_tuner, reset_tuner
-    from ray_tpu.collective.types import Topology, compat_shard_map
+    from ray_tpu.collective.types import Topology
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     n = len(jax.devices())
@@ -94,12 +94,12 @@ def main(quick: bool = False) -> None:
     def build(algo: str):
         """(callable, input) running one device-side allreduce."""
         if algo in (alg.TWO_LEVEL, alg.TWO_LEVEL_Q8):
-            fn = jax.jit(compat_shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda t: alg.two_level_allreduce(
                     t[0], "ici", "dcn", topo.ici_size,
                     quantized=(algo == alg.TWO_LEVEL_Q8),
-                )[None],
-                mesh2, (P(("dcn", "ici")),), P(("dcn", "ici")),
+                )[None], mesh=mesh2, in_specs=(P(("dcn", "ici")),),
+                out_specs=P(("dcn", "ici")), check_vma=False,
             ))
             arr = g2
         else:
@@ -112,8 +112,10 @@ def main(quick: bool = False) -> None:
                 alg.FLAT_Q8: lambda t: alg.quantized_allreduce(
                     t[0], "world")[None],
             }[algo]
-            fn = jax.jit(compat_shard_map(
-                body, mesh, (P("world"),), P("world")))
+            fn = jax.jit(jax.shard_map(
+                body, mesh=mesh, in_specs=(P("world"),),
+                out_specs=P("world"), check_vma=False,
+            ))
             arr = g1
         return (lambda: jax.block_until_ready(fn(arr)))
 
@@ -170,15 +172,19 @@ def main(quick: bool = False) -> None:
     ref = stack.sum(axis=0)
     qfn_out = None
     if qalgo == alg.TWO_LEVEL_Q8:
-        qfn = jax.jit(compat_shard_map(
+        qfn = jax.jit(jax.shard_map(
             lambda t: alg.two_level_allreduce(
                 t[0], "ici", "dcn", topo.ici_size, quantized=True)[None],
-            mesh2, (P(("dcn", "ici")),), P(("dcn", "ici"))))
+            mesh=mesh2, in_specs=(P(("dcn", "ici")),),
+            out_specs=P(("dcn", "ici")), check_vma=False,
+        ))
         qfn_out = np.asarray(qfn(g2))
     else:
-        qfn = jax.jit(compat_shard_map(
+        qfn = jax.jit(jax.shard_map(
             lambda t: alg.quantized_allreduce(t[0], "world")[None],
-            mesh, (P("world"),), P("world")))
+            mesh=mesh, in_specs=(P("world"),),
+            out_specs=P("world"), check_vma=False,
+        ))
         qfn_out = np.asarray(qfn(g1))
     err = float(np.abs(qfn_out[0] - ref).max())
     rel = err / max(float(np.abs(ref).max()), 1e-9)

@@ -84,12 +84,14 @@ class LocalXlaGroup:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from .types import compat_shard_map
 
         in_spec = P("world")
         out_spec = P("world") if out_spec_rank_axis else P()
         return jax.jit(
-            compat_shard_map(fn, self.mesh, (in_spec,), out_spec)
+            jax.shard_map(
+                fn, mesh=self.mesh, in_specs=(in_spec,),
+                out_specs=out_spec, check_vma=False,
+            )
         )
 
     def _cached(self, key, builder):
@@ -106,8 +108,6 @@ class LocalXlaGroup:
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from .types import compat_shard_map
-
         if self._mesh2 is None:
             topo = self.topology
             self._mesh2 = Mesh(
@@ -115,7 +115,10 @@ class LocalXlaGroup:
                 ("dcn", "ici"),
             )
         spec = P(("dcn", "ici"))
-        return jax.jit(compat_shard_map(fn, self._mesh2, (spec,), spec))
+        return jax.jit(jax.shard_map(
+            fn, mesh=self._mesh2, in_specs=(spec,),
+            out_specs=spec, check_vma=False,
+        ))
 
     def _select(self, op: str, per_rank_nbytes: int, quantized: bool) -> str:
         """Tuner decision for one op call (single-controller group:

@@ -37,39 +37,6 @@ class ReduceOp(str, Enum):
     MEAN = "mean"
 
 
-def compat_shard_map(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the top-level ``jax.shard_map`` (with
-    ``check_vma``) moved namespaces over releases; older versions ship
-    ``jax.experimental.shard_map`` (with ``check_rep``).  Replication
-    checking is disabled either way — the collective bodies intentionally
-    return per-rank values."""
-    import inspect
-
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    params = inspect.signature(_sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def compat_axis_size(axis_name):
-    """Static mapped-axis size inside a shard_map body across jax versions:
-    ``jax.lax.axis_size`` where it exists; otherwise ``psum(1, axis)``,
-    which constant-folds to a Python int under shard_map."""
-    import jax
-
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 @dataclass(frozen=True)
 class Topology:
     """Physical shape of a collective group for algorithm selection:

@@ -146,13 +146,14 @@ class XlaGroup:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from .types import compat_shard_map
-
         fn = self._fn_cache.get(key)
         if fn is None:
             out_spec = P() if out_replicated else P(("host",))
             fn = jax.jit(
-                compat_shard_map(body, self.mesh, (P(("host",)),), out_spec)
+                jax.shard_map(
+                    body, mesh=self.mesh, in_specs=(P(("host",)),),
+                    out_specs=out_spec, check_vma=False,
+                )
             )
             self._fn_cache[key] = fn
         return fn
@@ -165,8 +166,6 @@ class XlaGroup:
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from .types import compat_shard_map
-
         fn = self._fn_cache.get(key)
         if fn is None:
             if self._mesh3 is None:
@@ -178,7 +177,10 @@ class XlaGroup:
                     ("dcn", "ici", "device"),
                 )
             spec = P(("dcn", "ici"))
-            fn = jax.jit(compat_shard_map(body, self._mesh3, (spec,), spec))
+            fn = jax.jit(jax.shard_map(
+                body, mesh=self._mesh3, in_specs=(spec,),
+                out_specs=spec, check_vma=False,
+            ))
             self._fn_cache[key] = fn
         return fn
 

@@ -179,7 +179,13 @@ _KNOBS: Dict[str, tuple] = {
         float, 8.0,
         "Reconnect window before a disconnected owner's leases are reaped",
     ),
-    "worker_startup_timeout_s": (float, 60.0, "Worker process start deadline"),
+    "worker_startup_timeout_s": (
+        float, 300.0,
+        "Deadline for a worker process to start AND for an actor's "
+        "constructor to return (the creation chain derives its deadlines "
+        "from this one).  A constructor that builds a model on a cold chip "
+        "— backend start, weights, first compiles — takes minutes",
+    ),
     "max_tasks_in_flight_per_worker": (int, 10, "Pipelined pushes per leased worker"),
     # -- object store --
     "max_inline_object_bytes": (int, 100 * 1024, "Inline small objects in RPCs"),
@@ -304,8 +310,10 @@ _KNOBS: Dict[str, tuple] = {
         float, 10.0, "Per-sweep deadline for replica health replies"
     ),
     "serve_health_failure_threshold": (
-        int, 3, "Consecutive health timeouts before a replica is replaced "
-        "(a first-request jax compile can hold the GIL for tens of seconds)"
+        int, 30, "Consecutive health timeouts before a replica is replaced "
+        "(building a real model and its first-request jax compiles keep a "
+        "replica silent for minutes on a cold chip; a DEAD replica fails "
+        "its check at once and never waits for this)"
     ),
     # -- usage stats --
     "usage_stats_enabled": (bool, True, "Cluster-local usage recording"),

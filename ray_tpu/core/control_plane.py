@@ -73,6 +73,9 @@ class NodeEntry:
         self.agent_address = agent_address
         self.snapshot = snapshot
         self.last_heartbeat = time.monotonic()
+        # Health sweeps in a row that found the heartbeat late but the
+        # agent answering a ping (see _health_check_loop).
+        self.late_sweeps = 0
         self.alive = True
         # Drain state machine (autoscaler scale-down): a draining node is
         # unschedulable but still heartbeats; the autoscaler terminates it
@@ -540,6 +543,7 @@ class ControlPlane:
         if entry is None:
             return {"ok": False, "reregister": True}
         entry.last_heartbeat = time.monotonic()
+        entry.late_sweeps = 0
         entry.snapshot = payload["snapshot"]
         self.scheduler.update_node(node_id, payload["snapshot"])
         self._kick_pending()
@@ -578,15 +582,48 @@ class ControlPlane:
         except Exception as e:  # noqa: BLE001 — telemetry is best-effort
             logger.debug("control-plane metrics publish failed: %s", e)
 
+    async def _agent_answers(self, entry, timeout: float) -> bool:
+        try:
+            await self.agent_clients.get(entry.agent_address).call(
+                "ping", timeout=timeout, retries=0
+            )
+            return True
+        except Exception:  # noqa: BLE001 — refused, reset or timed out
+            return False
+
     async def _health_check_loop(self):
         period = GlobalConfig.health_check_period_s
         timeout = GlobalConfig.health_check_timeout_s
         while True:
             await asyncio.sleep(period)
-            self._publish_own_metrics()
             now = time.monotonic()
-            for node_id, entry in list(self.nodes.items()):
-                if entry.alive and now - entry.last_heartbeat > timeout:
+            self._publish_own_metrics()
+            late = [
+                (node_id, entry, now - entry.last_heartbeat)
+                for node_id, entry in self.nodes.items()
+                if entry.alive and now - entry.last_heartbeat > timeout
+            ]
+            # Late is not dead: ask before burying.  A killed agent refuses
+            # the connection at once; one whose machine stood still — or
+            # whose heartbeats queue behind this loop's own stall — answers
+            # as soon as it runs again (a TPU runtime starting or stopping
+            # held agent heartbeat rounds up for 10 s, and this loop for
+            # 7 s, on the v5e host).  An agent that answers pings (a lane
+            # thread) while its main loop never heartbeats again is buried
+            # on the third late sweep.
+            answers = await asyncio.gather(
+                *(self._agent_answers(e, timeout) for _, e, _ in late)
+            )
+            for (node_id, entry, gap), answered in zip(late, answers):
+                logger.warning(
+                    "node %s: no heartbeat for %.1fs (limit %.1fs); ping %s",
+                    node_id.hex()[:8], gap, timeout,
+                    "answered" if answered else "failed",
+                )
+                entry.late_sweeps += 1
+                if answered and entry.late_sweeps < 3:
+                    entry.last_heartbeat = time.monotonic()
+                else:
                     await self._on_node_dead(node_id)
             if (
                 self._recovery_deadline is not None

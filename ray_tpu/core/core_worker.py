@@ -33,6 +33,7 @@ from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from . import tpu_detect
 from .config import GlobalConfig
 from .exceptions import (
     ActorDiedError,
@@ -1209,6 +1210,11 @@ class CoreWorker:
         self._task_of_return: Dict[ObjectID, TaskSpec] = {}
         self._pending_exec_tasks: Set[TaskID] = set()
         self._cancelled_tasks: Set[TaskID] = set()
+        # A lease that holds chips must bring jax up on the TPU: checked
+        # after user code until it has (tpu_detect.leased_platform_verified).
+        self._chip_lease_unverified = (
+            mode == self.WORKER and tpu_detect.lease_holds_chips()
+        )
         self._cancelled_order: deque = deque()
         self._tasks_cancelled = 0  # owner-side accepted cancels
 
@@ -3764,6 +3770,14 @@ class CoreWorker:
             value,
         )
 
+    def _hold_to_leased_chips(self) -> None:
+        """After user code ran on a worker whose lease holds chips: raise
+        if its jax came up on anything but the TPU (asked until it is up)."""
+        if self._chip_lease_unverified and (
+            tpu_detect.leased_platform_verified()
+        ):
+            self._chip_lease_unverified = False
+
     async def _execute(self, spec: TaskSpec, fn, ticket=None) -> dict:
         from ray_tpu.util.tracing import task_execution_span
 
@@ -3774,7 +3788,16 @@ class CoreWorker:
         self.task_events.record(spec.task_id.hex(), spec.name, "RUNNING", **ev_kw)
         try:
             with task_execution_span(spec):
-                return await self._execute_inner(spec, fn, ev_kw, ticket)
+                reply = await self._execute_inner(spec, fn, ev_kw, ticket)
+            if self._chip_lease_unverified and reply.get("error") is None:
+                try:
+                    self._hold_to_leased_chips()
+                except RuntimeError as e:
+                    err = TaskError(e, "", spec.name)
+                    reply = dict(
+                        reply, returns=None, error=serialize_to_bytes(err)
+                    )
+            return reply
         finally:
             # A wedged pipeline cursor would stall every later call: any
             # path that didn't consume the ticket (coroutine fn, streaming,
@@ -3973,6 +3996,7 @@ class CoreWorker:
             instance = await loop.run_in_executor(
                 self._task_executor, lambda: cls(*args, **kwargs)
             )
+            self._hold_to_leased_chips()
             self.actor_instance = instance
             self.actor_spec = spec
             self.actor_incarnation = payload.get("incarnation", 0)

@@ -26,6 +26,8 @@ from .config import GlobalConfig
 from .rpc import RpcClient, find_free_port
 
 _HEAD_INFO_FILE = "/tmp/ray_tpu/head_info.json"
+# Node.stop(): the agent reaps its workers first (node_agent._WORKER_REAP_S).
+_STOP_WAIT_S = 100.0
 
 
 def _wait_for_server(address: str, timeout: float = 30.0) -> None:
@@ -89,20 +91,27 @@ class ProcessGroup:
         self.procs.append(proc)
         return proc
 
-    def kill_all(self):
+    def kill_all(self, timeout_s: float = 3.0):
+        """SIGTERM, then SIGKILL what outlives ``timeout_s``.  A node agent
+        stops its workers and waits for them to be gone before it exits,
+        so a caller that must leave no process behind gives it the time
+        (``Node.stop``); an agent cut short leaves its workers to their
+        own watchdog."""
         for proc in self.procs:
             try:
                 os.killpg(os.getpgid(proc.pid), signal.SIGTERM)
             except (ProcessLookupError, PermissionError):
                 pass
-        deadline = time.monotonic() + 3
+        deadline = time.monotonic() + timeout_s
         for proc in self.procs:
             try:
                 proc.wait(timeout=max(0.1, deadline - time.monotonic()))
             except subprocess.TimeoutExpired:
                 try:
                     os.killpg(os.getpgid(proc.pid), signal.SIGKILL)
-                except (ProcessLookupError, PermissionError):
+                    proc.wait(timeout=10)  # no zombie left either
+                except (ProcessLookupError, PermissionError,
+                        subprocess.TimeoutExpired):
                     pass
         self.procs.clear()
 
@@ -368,7 +377,7 @@ class Node:
         # resolvers on this (now dead) session's endpoint record.
         if self.ha_dir and os.environ.get("RAY_TPU_CP_HA_DIR") == self.ha_dir:
             del os.environ["RAY_TPU_CP_HA_DIR"]
-        self.pg.kill_all()
+        self.pg.kill_all(timeout_s=_STOP_WAIT_S)
         from .object_store import drop_arena
 
         drop_arena(self.session_id)

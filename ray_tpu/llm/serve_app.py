@@ -73,6 +73,19 @@ class LLMServer:
             body, self.model_name,
         )
 
+    def device_info(self) -> Dict[str, Any]:
+        """The devices this replica's engine runs on, as jax reports them."""
+        import jax
+
+        devices = jax.devices()
+        stats = devices[0].memory_stats() or {}
+        return {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+            "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        }
+
     async def completions(self, body: Dict[str, Any]) -> Dict[str, Any]:
         prompt = body.get("prompt", "")
         out = await self._generate_batch((prompt, _sampling_from_request(body)))
@@ -106,7 +119,8 @@ class LLMDisaggServer:
     def __init__(self, engine_cfg: Optional[EngineConfig] = None,
                  model_name: str = "ray-tpu-gpt2",
                  num_prefill: int = 1, num_decode: int = 1,
-                 cb_cfg=None, num_cpus_per_replica: float = 0.0):
+                 cb_cfg=None, num_cpus_per_replica: float = 0.0,
+                 num_tpus_per_replica: float = 0):
         import ray_tpu
         from .continuous_batching import BatchedDecodeReplica
         from .disagg import DisaggRouter, PrefillReplica
@@ -118,13 +132,18 @@ class LLMDisaggServer:
         # Same default tokenizer the replica engines use — usage token
         # accounting must match the monolithic server's.
         self._tokenizer = ByteTokenizer()
-        Pre = ray_tpu.remote(num_cpus=num_cpus_per_replica)(PrefillReplica)
+        # Each prefill / decode actor is its own process: with
+        # num_tpus_per_replica they each lease that many chips, the way
+        # build_openai_app's num_tpus gives LLMServer its chip (so this
+        # path needs two chips at least; one chip serves through LLMServer).
+        opts: Dict[str, Any] = {"num_cpus": num_cpus_per_replica}
+        if num_tpus_per_replica:
+            opts["num_tpus"] = num_tpus_per_replica
+        Pre = ray_tpu.remote(**opts)(PrefillReplica)
         # max_concurrency is load-bearing: run()/run_stream() calls park
         # on per-request events while the resident loop decodes; a slot-
         # starved decode actor would serialize its clients.
-        Dec = ray_tpu.remote(
-            num_cpus=num_cpus_per_replica, max_concurrency=64
-        )(BatchedDecodeReplica)
+        Dec = ray_tpu.remote(max_concurrency=64, **opts)(BatchedDecodeReplica)
         self._prefill = [Pre.remote(engine_cfg) for _ in range(num_prefill)]
         self._decode = [
             Dec.remote(engine_cfg, cb_cfg) for _ in range(num_decode)
@@ -274,13 +293,18 @@ def build_disagg_openai_app(
     num_prefill: int = 1,
     num_decode: int = 1,
     cb_cfg=None,
+    num_tpus: float = 0,
 ):
     """OpenAI app over the prefill/decode + continuous-batching path;
     expose via ``serve.run`` + ``serve.start_http_proxy`` like
     ``build_openai_app`` (same ``/v1`` endpoints, ``stream: true``
-    SSE included)."""
+    SSE included).  ``num_tpus`` chips go to EACH prefill and decode
+    actor (the router replica itself stays off the chip)."""
     d = LLMDisaggServer.options(route_prefix="/v1")
-    return d.bind(engine_cfg, model_name, num_prefill, num_decode, cb_cfg)
+    return d.bind(
+        engine_cfg, model_name, num_prefill, num_decode, cb_cfg,
+        num_tpus_per_replica=num_tpus,
+    )
 
 
 def build_openai_app(

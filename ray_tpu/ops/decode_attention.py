@@ -25,6 +25,15 @@ Kernel design (v5e-measured; see ``models/gpt2_decode.py`` docstring):
   - grouped-query attention is native: each kv head carries its
     ``G = H // Hkv`` query rows as one [G, block_t] score tile.
 
+What the v5e compiler accepts (``tests/test_tpu_compile.py``, PR 22): the
+kernel compiles at the GPT-2 shape (L,B,H,Hkv,T,D) = (12,32,12,12,1024,64).
+It is REFUSED for VMEM (``RESOURCE_EXHAUSTED``) at TinyLlama's
+(22,32,32,8,2048,64) and at the 7B shape (32 kv heads, T=4096, D=128: 129 MB
+of 128 MB) — one whole ``[Hkv, T, D]`` window per cache operand is the
+design above, so wider caches need the T-blocked copy.  ``kernel=True`` on a
+TPU is the kernel or an error, never the reference in silence; the model
+decode steps default to ``kernel=False``.
+
 Layouts (head-major, nothing transposes on the hot path):
   q        [B, H, D];  k/v cache [L, B, Hkv, T, D];  k/v self [B, Hkv, D]
   pos      [B]  — index of the current token (attends [0, pos-1] + self)
@@ -164,16 +173,18 @@ def decode_attention(q, k_cache, v_cache, pos, layer: int = 0, *,
     b, h, d = q.shape
     _l, _b, hkv, t, _d = k_cache.shape
     g = h // hkv
-    use_kernel = (
-        kernel
-        and t % block_t == 0
-        and k_self is not None
-        and (_on_tpu() or interpret)
-    )
-    if not use_kernel:
+    on_tpu = _on_tpu()
+    if not kernel or not (on_tpu or interpret):
         return reference_decode_attention(
             q, k_cache, v_cache, pos, layer, k_self, v_self
         )
+    if t % block_t or k_self is None:
+        raise ValueError(
+            f"decode attention kernel: cache length {t} must be a multiple "
+            f"of block_t={block_t} and k_self/v_self must be given; ask for "
+            "the reference with kernel=False"
+        )
+    interpret = interpret and not on_tpu  # never the interpreter on a chip
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

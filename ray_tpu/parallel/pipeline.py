@@ -30,9 +30,8 @@ def pipeline_local(stage_fn: Callable, stage_params, microbatches, *,
     microbatches: [M, mb, ...] — full input, replicated across stages.
     Returns [M, mb, ...] outputs of the final stage (replicated).
     """
-    from ..collective.types import compat_axis_size
 
-    n = compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_stage = jax.lax.axis_index(axis_name)
     params = jax.tree.map(lambda p: p[0], stage_params)
     m = microbatches.shape[0]
@@ -86,15 +85,14 @@ def pipelined(stage_fn: Callable, mesh, *, axis_name: str = "stage",
     ``batch_axes``."""
     from jax.sharding import PartitionSpec as P
 
-    from ..collective.types import compat_shard_map
-
     inner = functools.partial(pipeline_local, stage_fn, axis_name=axis_name)
 
     def apply(stacked_params, microbatches):
         params_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
         x_spec = P(None, batch_axes)
-        return compat_shard_map(
-            inner, mesh, (params_specs, x_spec), x_spec
+        return jax.shard_map(
+            inner, mesh=mesh, in_specs=(params_specs, x_spec),
+            out_specs=x_spec, check_vma=False,
         )(stacked_params, microbatches)
 
     return apply

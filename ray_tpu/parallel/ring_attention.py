@@ -48,9 +48,8 @@ def ring_attention_local(q, k, v, *, axis_name: str = "seq",
 
     q/k/v: [B, S_local, H, D] (this device's sequence shard).
     """
-    from ..collective.types import compat_axis_size
 
-    n = compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
@@ -97,12 +96,11 @@ def ring_attention(q, k, v, mesh, *, causal: bool = True,
     q/k/v: [B, S, H, D] global arrays (S sharded over ``seq_axis``)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..collective.types import compat_shard_map
-
     spec = P(batch_axes, seq_axis, head_axis, None)
     inner = functools.partial(
         ring_attention_local, axis_name=seq_axis, causal=causal
     )
-    return compat_shard_map(
-        inner, mesh, (spec, spec, spec), spec
+    return jax.shard_map(
+        inner, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False,
     )(q, k, v)

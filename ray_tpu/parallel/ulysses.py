@@ -26,9 +26,8 @@ def ulysses_attention_local(
 ):
     """shard_map-inner Ulysses attention.  q/k/v: [B, S_local, H, D] with H
     divisible by the axis size."""
-    from ..collective.types import compat_axis_size
 
-    n = compat_axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     assert h % n == 0, f"heads ({h}) must divide by seq-axis size ({n})"
     attn = attn_fn or functools.partial(reference_attention, causal=causal)
@@ -56,13 +55,12 @@ def ulysses_attention(q, k, v, mesh, *, causal: bool = True,
     over ``seq_axis``; heads unsharded on that axis)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..collective.types import compat_shard_map
-
     spec = P(batch_axes, seq_axis, None, None)
     inner = functools.partial(
         ulysses_attention_local, axis_name=seq_axis, causal=causal,
         attn_fn=attn_fn,
     )
-    return compat_shard_map(
-        inner, mesh, (spec, spec, spec), spec
+    return jax.shard_map(
+        inner, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False,
     )(q, k, v)

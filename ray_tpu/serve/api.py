@@ -180,10 +180,13 @@ def shutdown():
 
 
 # ------------------------------------------------------------------- HTTP
-def start_http_proxy(host: str = "127.0.0.1", port: int = 8000) -> str:
+def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
+                     request_timeout_s: float = 60.0) -> str:
     """Serve deployments over HTTP: POST <route_prefix> with a JSON body
     ``{"args": [...], "kwargs": {...}}`` (or any JSON object passed as the
-    single argument)."""
+    single argument).  ``request_timeout_s`` bounds one unary request; the
+    first request of a model replica includes its jax compiles, which take
+    minutes on a cold chip."""
     import asyncio
 
     from aiohttp import web
@@ -364,7 +367,7 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000) -> str:
             response = handle.remote(*args, **kwargs)
             try:
                 result = await loop.run_in_executor(
-                    None, lambda: response.result(timeout=60)
+                    None, lambda: response.result(timeout=request_timeout_s)
                 )
             except Exception as e:  # noqa: BLE001
                 span.set_attribute("error", str(e))

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from typing import List
 
+from .worker_group import JAX_INIT_TIMEOUT_S
+
 
 class Backend:
     def on_start(self, worker_group) -> None:  # noqa: D401
@@ -41,12 +43,24 @@ class JaxBackend(Backend):
             ),
             timeout=60,
         )
+        # One runtime address per worker: one-chip workers sharing a host
+        # need each other's to form libtpu's process grid (see
+        # tpu_detect.join_host_process_grid); others ignore them.
+        peers = ray_tpu.get(
+            [
+                w.get_coordinator_address.remote(0)
+                for w in worker_group.workers
+            ],
+            timeout=60,
+        )
         ray_tpu.get(
             [
-                w.init_jax_distributed.remote(addr, n, rank, self.platform)
+                w.init_jax_distributed.remote(
+                    addr, n, rank, self.platform, peers
+                )
                 for rank, w in enumerate(worker_group.workers)
             ],
-            timeout=300,
+            timeout=JAX_INIT_TIMEOUT_S + 30,
         )
 
 

@@ -23,6 +23,11 @@ from .checkpoint import Checkpoint
 from .session import TrainContext, _clear_session, _set_session
 
 
+# Bound on the jax.distributed rendezvous: a member that cannot come up
+# fails the gang in this time instead of parking the others.
+JAX_INIT_TIMEOUT_S = 120
+
+
 @ray_tpu.remote
 class TrainWorker:
     """One member of the gang.  max_concurrency=2 so poll()/control methods
@@ -52,14 +57,26 @@ class TrainWorker:
         return f"{host}:{port or find_free_port(host)}"
 
     def init_jax_distributed(self, coordinator: str, n: int, rank: int,
-                             platform: str = ""):
+                             platform: str = "", peers=None):
         import jax
+
+        from ray_tpu.core import tpu_detect
 
         if platform:
             jax.config.update("jax_platforms", platform)
+        elif peers and tpu_detect.lease_holds_chips():
+            tpu_detect.join_host_process_grid(rank, peers)
         jax.distributed.initialize(
-            coordinator_address=coordinator, num_processes=n, process_id=rank
+            coordinator_address=coordinator, num_processes=n, process_id=rank,
+            initialization_timeout=JAX_INIT_TIMEOUT_S,
         )
+        if jax.process_count() != n:
+            # The coordination service joined, the device runtime did not:
+            # every member would train alone and call it data-parallel.
+            raise RuntimeError(
+                f"jax.distributed joined {n} processes but this backend "
+                f"sees {jax.process_count()} ({jax.device_count()} devices)"
+            )
         return True
 
     def init_torch_distributed(self, host: str, port: int, n: int, rank: int):

@@ -33,11 +33,12 @@ def _run1(body, stack):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_tpu.collective.types import compat_shard_map
-
     mesh = _mesh1()
     g = jax.device_put(stack, NamedSharding(mesh, P("world")))
-    f = jax.jit(compat_shard_map(body, mesh, (P("world"),), P("world")))
+    f = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("world"),),
+        out_specs=P("world"), check_vma=False,
+    ))
     return np.asarray(f(g))
 
 
@@ -45,12 +46,13 @@ def _run2(body, stack):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ray_tpu.collective.types import compat_shard_map
-
     mesh = _mesh2()
     spec = P(("dcn", "ici"))
     g = jax.device_put(stack, NamedSharding(mesh, spec))
-    f = jax.jit(compat_shard_map(body, mesh, (spec,), spec))
+    f = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(spec,),
+        out_specs=spec, check_vma=False,
+    ))
     return np.asarray(f(g))
 
 

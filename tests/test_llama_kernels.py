@@ -200,10 +200,16 @@ class TestDecodeAttention:
             atol=2e-2, rtol=2e-2,
         )
 
-    def test_non_divisible_t_falls_back(self):
+    def test_non_divisible_t_raises(self):
+        """A caller who asks for the kernel and cannot have it gets an
+        error, not the reference in silence; kernel=False is the explicit
+        way to the reference."""
         q, k, v, ks, vs, pos = self._data(t=60)
+        with pytest.raises(ValueError, match="multiple of block_t"):
+            decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs,
+                             block_t=16, kernel=True, interpret=True)
         ref = reference_decode_attention(q, k, v, pos, 0, ks, vs)
         out = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs,
-                               block_t=16, kernel=True, interpret=True)
+                               block_t=16, kernel=False)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
