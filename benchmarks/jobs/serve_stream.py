@@ -1,0 +1,317 @@
+"""Job kind ``serve_stream``: ``serve`` -> HTTP proxy -> ``LLMServer`` ->
+``JaxLLMEngine``, streamed completions from one client process.
+
+The clients are coroutines of one asyncio loop in this process's main thread
+(the proxy's own loop runs in its thread, as it does for a user who calls
+``serve.start_http_proxy``).  A closed loop keeps ``clients`` requests in
+flight and records how late each next request left.  Every timing is this
+process's ``perf_counter``.  The model's pieces come from
+``families/<family>.py``, named by the configuration's ``family`` key.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import functools
+import importlib
+import itertools
+import json
+import time
+import urllib.request
+
+from ..lib import traffic
+from ..lib.cluster import check, log
+from ..lib.device import measured_peak
+
+REQUEST_WAIT_S = 900  # first request: engine build + cold compiles
+TRACE_AFTER_S, TRACE_SECONDS = 3.0, 4.0
+WARM_TOKENS = 48
+CHECK_TOKENS = 24  # asked of each request of the checks after the window
+ALONE = 4          # of the requests sent at once, those sent again alone
+
+
+def percentile(values, pct):
+    ordered = sorted(values)
+    return ordered[min(len(ordered) - 1, int(len(ordered) * pct / 100))]
+
+
+def post(url: str, body: dict) -> dict:
+    req = urllib.request.Request(
+        url, json.dumps(body).encode(), {"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=REQUEST_WAIT_S) as resp:
+        out = json.loads(resp.read())
+    check("result" in out, f"server error: {out}")
+    return out["result"]
+
+
+async def stream_one(session, url: str, req: dict, temperature: float) -> dict:
+    """One streamed completion.  Each SSE chunk's text is one visible
+    character per token: tokens of one chunk arrived together."""
+    body = {"prompt": req["prompt"], "max_tokens": req["max_tokens"],
+            "temperature": temperature, "stream": True}
+    out = {"asked": req["max_tokens"], "t_send": time.perf_counter(),
+           "token_times": [], "text": "", "error": None}
+    try:
+        done = False
+        async with session.post(url, json=body) as resp:
+            async for raw in resp.content:
+                line = raw.strip()
+                if not line.startswith(b"data: "):
+                    continue
+                if line == b"data: [DONE]":
+                    done = True
+                    break
+                chunk = json.loads(line[6:])
+                if "error" in chunk:
+                    out["error"] = str(chunk["error"])
+                    continue
+                text = chunk["choices"][0]["text"]
+                if text:
+                    out["token_times"].extend(
+                        [time.perf_counter()] * len(text))
+                    out["text"] += text
+        if not done and out["error"] is None:
+            out["error"] = "stream ended without [DONE]"
+    except Exception as e:  # noqa: BLE001 - a failed request is a result
+        out["error"] = f"{type(e).__name__}: {e}"
+    out["t_end"] = time.perf_counter()
+    return out
+
+
+async def offer_load(url: str, mix: dict, reqs: list, seconds: float,
+                     on_start=None) -> dict:
+    """Run the mix for ``seconds``; requests in flight at the end are left
+    to finish (their tokens after the end are not counted)."""
+    import aiohttp
+
+    arrivals = mix["arrivals"]
+    if arrivals["kind"] != "closed":
+        raise ValueError(f"arrivals {arrivals['kind']!r}: this generator "
+                         "offers a closed loop only")
+    supply = itertools.cycle(reqs)
+    done, lateness = [], []
+    timeout = aiohttp.ClientTimeout(total=REQUEST_WAIT_S)
+    conn = aiohttp.TCPConnector(limit=0)
+    async with aiohttp.ClientSession(timeout=timeout, connector=conn) as sess:
+        t0 = time.perf_counter()
+        t_end = t0 + seconds
+        side = asyncio.ensure_future(on_start(t0)) if on_start else None
+
+        async def closed_client():
+            last_end = None
+            while time.perf_counter() < t_end:
+                if last_end is not None:
+                    lateness.append(time.perf_counter() - last_end)
+                res = await stream_one(sess, url, next(supply),
+                                       mix["temperature"])
+                last_end = res["t_end"]
+                done.append(res)
+
+        await asyncio.gather(
+            *[closed_client() for _ in range(arrivals["clients"])])
+        if side is not None:
+            await side
+    return {"t0": t0, "t_end": t_end, "requests": done, "lateness": lateness}
+
+
+def reduce_window(load: dict, seconds: float) -> dict:
+    t0, t_end = load["t0"], load["t_end"]
+    sent = [r for r in load["requests"] if t0 <= r["t_send"] < t_end]
+    # A reply with no token (the stop token came first) is short, not failed.
+    failed = [r for r in sent if r["error"]]
+    ttft = [(r["token_times"][0] - r["t_send"]) * 1e3
+            for r in sent if r["token_times"]]
+    gaps, tokens = [], 0
+    for r in load["requests"]:
+        times = r["token_times"]
+        tokens += sum(1 for t in times if t0 <= t < t_end)
+        gaps.extend((b - a) * 1e3 for a, b in zip(times, times[1:])
+                    if t0 <= b < t_end)
+    return {"attempted": len(sent), "failed": len(failed),
+            "errors": sorted({r["error"] for r in failed if r["error"]})[:5],
+            "ttft_ms": ttft, "itl_ms": gaps, "tokens": tokens,
+            "tokens_per_s": tokens / seconds}
+
+
+def check_answers(url: str, reqs: list, slots: int, problems: list) -> None:
+    """After the window.  One request at a time: usage adds up, the tokens
+    asked for came, and a streamed text equals the unary text (each
+    character is a token id, so this compares ids).  Then as many distinct
+    requests at once as the engine has slots, and some of them again alone:
+    greedy ids must not depend on what the other slots hold."""
+    from ..lib.bench_server import ids_of
+
+    sample = sorted(reqs, key=lambda r: r["prompt_tokens"])
+    sample = [sample[0], sample[len(sample) // 2], sample[-1]]
+    for req in sample:
+        body = {"prompt": req["prompt"],
+                "max_tokens": min(req["max_tokens"], CHECK_TOKENS)}
+        out = post(url, body)
+        usage, text = out["usage"], out["choices"][0]["text"]
+        n = usage["completion_tokens"]
+        eos_cut = n == len(text) + 1  # the stop token is counted, not shown
+        if usage["prompt_tokens"] != req["prompt_tokens"] or (
+                usage["total_tokens"] != usage["prompt_tokens"] + n):
+            problems.append(f"usage does not add up: {usage} for a prompt "
+                            f"of {req['prompt_tokens']} tokens")
+        if not (n == body["max_tokens"] == len(text) or eos_cut):
+            problems.append(f"asked {body['max_tokens']} tokens, got {n} "
+                            f"({len(text)} shown)")
+
+    async def streamed(batch):
+        import aiohttp
+
+        async with aiohttp.ClientSession() as sess:
+            return await asyncio.gather(*[
+                stream_one(sess, url, r, 0.0) for r in batch])
+
+    req = sample[1]
+    body = {"prompt": req["prompt"],
+            "max_tokens": min(req["max_tokens"], CHECK_TOKENS)}
+    unary = post(url, body)["choices"][0]["text"]
+    got = asyncio.run(streamed([dict(req, **body)]))[0]
+    if got["error"] or got["text"] != unary:
+        problems.append(f"streamed ids {ids_of(got['text'])} != unary ids "
+                        f"{ids_of(unary)} ({got['error']})")
+
+    batch = [dict(r, max_tokens=CHECK_TOKENS) for r in reqs[:slots]]
+    together = asyncio.run(streamed(batch))
+    picks = range(0, len(batch), max(1, len(batch) // ALONE))
+    for i in picks:
+        alone = asyncio.run(streamed([batch[i]]))[0]
+        if alone["error"] or together[i]["error"] or (
+                alone["text"] != together[i]["text"]):
+            problems.append(
+                f"request {i} of {len(batch)} sent at once gave ids "
+                f"{ids_of(together[i]['text'])}, alone "
+                f"{ids_of(alone['text'])} "
+                f"({together[i]['error']}, {alone['error']})")
+
+
+def run(job) -> dict:
+    from ray_tpu import serve
+    from ray_tpu.core.rpc import find_free_port
+    from ray_tpu.llm import EngineConfig
+
+    from ..lib import bench_server
+
+    mix, cfg = job.mix, job.config
+    fam = importlib.import_module("benchmarks.families." + cfg["family"])
+    if job.rehearse:
+        mix = dict(mix, **mix["tiny"])
+    model = cfg["tiny"] if job.rehearse else cfg["model"]
+    eng = cfg["tiny_engine"] if job.rehearse else cfg["engine"]
+    reqs = traffic.requests(mix, job.seed)
+    check(max(r["prompt_tokens"] + r["max_tokens"] for r in reqs)
+          < eng["max_seq_len"] - 1, "a request does not fit the cache")
+
+    engine_cfg = EngineConfig(
+        model=fam.config(model), max_batch_size=eng["max_batch_size"],
+        max_seq_len=eng["max_seq_len"], seed=job.seed % (2 ** 31),
+        param_loader=functools.partial(fam.load_params, model, job.seed))
+    # Built as build_openai_app builds LLMServer: /v1, no CPU, one chip.
+    app = serve.deployment(
+        name="LLMServer", max_ongoing_requests=eng["max_batch_size"],
+    )(bench_server.BenchLLMServer).options(
+        route_prefix="/v1",
+        ray_actor_options={"num_cpus": 0, "num_tpus": 1},
+    ).bind(engine_cfg, cfg["name"], cfg["family"])
+
+    t_run = time.perf_counter()
+    handle = serve.run(app)
+    base = serve.start_http_proxy(
+        port=find_free_port(), request_timeout_s=REQUEST_WAIT_S)
+    url = base + mix["route"]
+
+    def ask(method, *args, wait=120):
+        return getattr(handle, method).remote(*args).result(timeout=wait)
+
+    # Warm every shape the window uses: one request compiles prefill_one,
+    # the decode step and the greedy sampler; then as many at once as there
+    # are slots, because the engine slices the logits at a static slot
+    # index: one tiny program per slot.
+    warm = {"prompt": reqs[0]["prompt"], "max_tokens": WARM_TOKENS}
+
+    async def warm_up():
+        import aiohttp
+
+        t = aiohttp.ClientTimeout(total=REQUEST_WAIT_S)
+        async with aiohttp.ClientSession(timeout=t) as sess:
+            first = await stream_one(sess, url, warm, mix["temperature"])
+            ready = time.perf_counter() - t_run
+            rest = await asyncio.gather(*[
+                stream_one(sess, url, warm, mix["temperature"])
+                for _ in range(eng["max_batch_size"])])
+        return ready, [first] + rest
+
+    replica_ready_s, warmed = asyncio.run(warm_up())
+    bad = [w["error"] for w in warmed if w["error"]]
+    check(not bad, f"warm-up failed: {bad[:3]}")
+    info = ask("device_info")
+    log(f"serve: replica on {info}; first answer {replica_ready_s:.1f}s after "
+        f"serve.run(); warm-up done {time.perf_counter() - t_run:.1f}s")
+    check(info["platform"] == ("cpu" if job.rehearse else "tpu"),
+          f"replica's jax came up on {info['platform']}")
+    before = ask("counters", True)
+
+    async def trace_side(t0):
+        await asyncio.sleep(max(0.0, t0 + TRACE_AFTER_S - time.perf_counter()))
+        loop = asyncio.get_running_loop()
+        await loop.run_in_executor(None, ask, "start_trace", job.trace_dir)
+        await asyncio.sleep(min(TRACE_SECONDS, max(job.seconds - 4.0, 0.5)))
+        await loop.run_in_executor(None, ask, "stop_trace")
+
+    setup_s = time.time() - job.t_start_wall
+    load = asyncio.run(offer_load(
+        url, mix, reqs, job.seconds,
+        on_start=trace_side if job.trace_dir else None))
+    win = reduce_window(load, job.seconds)
+    after = ask("counters")
+
+    problems = []
+    if win["failed"]:
+        problems.append(f"{win['failed']} of {win['attempted']} requests "
+                        f"failed: {win['errors']}")
+    compiles = after["compiles"] - before["compiles"]
+    if compiles:
+        problems.append(f"{compiles} compilation(s) inside the window")
+    check_answers(url, reqs, eng["max_batch_size"], problems)
+    ref = ask("check_reference", job.seed % (2 ** 31), wait=600)
+    if not ref["ok"]:
+        problems.append(f"prefill + decode off the float32 reference: {ref}")
+
+    hist = {int(k): v for k, v in after["active_hist"].items()}
+    steps = sum(hist.values())
+    active_mean = (sum(k * v for k, v in hist.items()) / steps) if steps else None
+    late = load["lateness"]
+    notes = {
+        "requests_in_window": win["attempted"], "tokens": win["tokens"],
+        "ttft_p50_ms": percentile(win["ttft_ms"], 50) if win["ttft_ms"] else None,
+        "itl_p50_ms": percentile(win["itl_ms"], 50) if win["itl_ms"] else None,
+        "itl_samples": len(win["itl_ms"]),
+        "generator_lateness_p95_ms":
+            percentile(late, 95) * 1e3 if late else None,
+        "engine_steps": steps, "active_slots_hist": hist,
+        "active_slots_mean": active_mean,
+        "reference_rel_errs": ref["rel_errs"],
+        "compiles_in_window": compiles,
+        "memory_at_start": after["memory_at_start"],
+        "memory_stats": after["memory_stats"],
+    }
+    return {
+        "problems": problems,
+        "attempted": win["attempted"], "failed": win["failed"],
+        "end_to_end": {
+            "serve_tokens_per_s": win["tokens_per_s"],
+            "ttft_p90_ms": percentile(win["ttft_ms"], 90) if win["ttft_ms"] else None,
+            "itl_p95_ms": percentile(win["itl_ms"], 95) if win["itl_ms"] else None,
+            "setup_s": setup_s,
+        },
+        "device": {
+            "platform": info["platform"], "kind": info["kind"],
+            "count": info["count"],
+            "memory_peak_bytes": measured_peak(after["memory_stats"])},
+        "stats": {"replica_ready_s": replica_ready_s,
+                  "active_slots_mean": active_mean, "model": model},
+        "notes": notes,
+    }
