@@ -338,7 +338,9 @@ class ControlPlane:
             "jobs",
             job_id.hex(),
             pickle.dumps(
-                {k: v for k, v in job.items() if k != "last_heartbeat"}
+                {k: v for k, v in job.items()
+                 if k not in ("last_heartbeat", "late_sweeps",
+                              "grace_until")}
             ),
         )
 
@@ -582,9 +584,13 @@ class ControlPlane:
         except Exception as e:  # noqa: BLE001 — telemetry is best-effort
             logger.debug("control-plane metrics publish failed: %s", e)
 
-    async def _agent_answers(self, entry, timeout: float) -> bool:
+    async def _answers(self, address, timeout: float) -> bool:
+        """Does the process at ``address`` (an agent, a driver) answer a
+        ping?  No address, refused, reset or timed out: no."""
+        if not address:
+            return False
         try:
-            await self.agent_clients.get(entry.agent_address).call(
+            await self.agent_clients.get(address).call(
                 "ping", timeout=timeout, retries=0
             )
             return True
@@ -612,7 +618,8 @@ class ControlPlane:
             # thread) while its main loop never heartbeats again is buried
             # on the third late sweep.
             answers = await asyncio.gather(
-                *(self._agent_answers(e, timeout) for _, e, _ in late)
+                *(self._answers(e.agent_address, timeout)
+                  for _, e, _ in late)
             )
             for (node_id, entry, gap), answered in zip(late, answers):
                 logger.warning(
@@ -640,11 +647,35 @@ class ControlPlane:
                         await self._on_actor_worker_died(
                             actor_id, "node lost across control-plane restart"
                         )
+            # Drivers get the agents' treatment: the same stall that holds
+            # an agent's heartbeats up holds a driver's (on the v5e host one
+            # serving run in 26 lost its job this way, 20 s after it
+            # started, while the replica's TPU runtime came up).  A killed
+            # driver refuses the ping at once and is cleaned up as before.
+            # Only this loop writes ``late_sweeps`` / ``grace_until``; the
+            # heartbeat handler (a lane thread) keeps to its one timestamp.
+            late_jobs = []
             for job_id, job in list(self.jobs.items()):
-                if (
-                    job["state"] == "RUNNING"
-                    and now - job.get("last_heartbeat", now) > timeout
-                ):
+                if job["state"] != "RUNNING":
+                    continue
+                if now - job.get("last_heartbeat", now) <= timeout:
+                    job["late_sweeps"] = 0
+                elif now > job.get("grace_until", 0.0):
+                    late_jobs.append((job_id, job))
+            answers = await asyncio.gather(
+                *(self._answers(j.get("driver_address"), timeout)
+                  for _, j in late_jobs)
+            )
+            for (job_id, job), answered in zip(late_jobs, answers):
+                job["late_sweeps"] = job.get("late_sweeps", 0) + 1
+                logger.warning(
+                    "job %s: no driver heartbeat for %.1fs; ping %s",
+                    job_id.hex(), now - job["last_heartbeat"],
+                    "answered" if answered else "failed",
+                )
+                if answered and job["late_sweeps"] < 3:
+                    job["grace_until"] = time.monotonic() + timeout
+                elif job["state"] == "RUNNING":
                     job["state"] = "FINISHED"
                     self.events.record(JOB_LIFECYCLE, job_id.hex(), "FINISHED")
                     self._persist_job(job_id)

@@ -177,7 +177,7 @@ class DecodeReplica:
         anti-pattern disaggregation exists to avoid."""
         deadline = time.monotonic() + timeout_s
         while True:
-            with self.engine._step_lock:
+            with self.engine.locked(request_id):
                 done = self.engine._finished.pop(request_id, None)
                 if done is None:
                     self.engine.step()

@@ -13,12 +13,23 @@ init/init_cache/prefill/decode_step.
 
 Shapes are static (max_batch_size × max_seq_len) so XLA compiles exactly
 two programs: prefill and decode.
+
+Observability (names are a contract: tests pin them, PERF.md lists which
+metric reads which).  Host work runs inside ``util.tracing.host_span``s —
+``engine.lock_wait``, ``engine.step`` > ``engine.admit`` >
+(``engine.prefill.dispatch``, ``engine.sample``), ``engine.decode.dispatch``,
+``engine.sample``, ``engine.retire``, and a zero-length ``engine.counts`` at
+the end of every step — which a profiler session writes on the device
+trace's clock.  ``stats()`` gives the same counts with no session, and every
+step feeds ``flight_recorder.record_llm_step`` (``/metrics``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -26,6 +37,8 @@ import numpy as np
 
 from ..models import GPT2Config, model_family
 from ..models.gpt2_decode import sample_logits
+from ..util import flight_recorder, tracing
+from ..util.tracing import host_span
 from .tokenizer import ByteTokenizer
 
 
@@ -74,6 +87,28 @@ class _Slot:
         return self.prompt_len + len(self.generated) - 1
 
 
+def _arrival() -> tuple:
+    """What a queue entry ends with: when it arrived (``perf_counter``) and
+    the cluster trace it belongs to, if any."""
+    ctx = tracing.current_context()
+    return time.perf_counter(), (ctx[0] if ctx else None)
+
+
+def _drop_queued(queue: List[tuple], request_id: int) -> int:
+    """Remove a request's entries IN PLACE.  ``add_request`` appends
+    without the engine lock (a lock turn there would add a whole step to
+    every TTFT), so the list must never be rebound: an append racing a
+    rebind lands in the discarded list and its stream spins to its
+    timeout.  Appends only ever grow the tail; this walks from the tail
+    it saw down, under the lock that every ``pop`` also holds."""
+    dropped = 0
+    for i in range(len(queue) - 1, -1, -1):
+        if queue[i][0] == request_id:
+            del queue[i]
+            dropped += 1
+    return dropped
+
+
 class JaxLLMEngine:
     def __init__(self, cfg: EngineConfig, tokenizer=None):
         import jax
@@ -92,15 +127,22 @@ class JaxLLMEngine:
         # Per-slot state; None = free.
         self.slots: List[Optional[_Slot]] = [None] * cfg.max_batch_size
         self._next_id = itertools.count()
-        self._waiting: List[tuple] = []  # (request_id, token_ids, params)
+        # (request_id, token_ids, params, perf_counter at arrival, trace_id)
+        self._waiting: List[tuple] = []
         self._finished: Dict[int, dict] = {}
         # ALL engine-state mutation serializes on this lock: step() may be
         # driven concurrently by batched calls (replica event loop) and by
         # generate_stream callers (replica executor threads).  Reentrant:
-        # generate/generate_stream hold it across pop+step.
-        import threading
-
+        # generate/generate_stream hold it across pop+step.  Take it
+        # through ``locked()``, which accounts for the wait.
         self._step_lock = threading.RLock()
+        self._lock_owner: Optional[int] = None  # thread ident, outermost hold
+        # Counters behind stats(); all but the two gauges only grow.
+        self._counts: Dict[str, Any] = dict.fromkeys(
+            ("steps", "decode_steps", "admitted", "retired", "cancelled",
+             "prompt_tokens", "padded_prompt_tokens", "generated_tokens",
+             "occupied_slot_steps"), 0)
+        self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
         def prefill_one(params, cache, tokens, length, slot_idx):
             """Prefill a single request into batch row ``slot_idx``."""
@@ -157,7 +199,7 @@ class JaxLLMEngine:
         params = params or SamplingParams()
         token_ids = encode_prompt(self.tokenizer, prompt, self.cfg.max_seq_len)
         request_id = next(self._next_id)
-        self._waiting.append((request_id, token_ids, params))
+        self._waiting.append((request_id, token_ids, params, *_arrival()))
         return request_id
 
     def add_request_from_kv(self, meta: dict, k, v) -> int:
@@ -167,10 +209,11 @@ class JaxLLMEngine:
         the [L, 1, H, S, D] KV pages for the prompt."""
         import jax.numpy as jnp
 
-        with self._step_lock:
+        arrival = _arrival()  # before the wait for the lock
+        with self.locked():
             request_id = next(self._next_id)
             self._waiting_kv.append(
-                (request_id, meta, jnp.asarray(k), jnp.asarray(v))
+                (request_id, meta, jnp.asarray(k), jnp.asarray(v), *arrival)
             )
             return request_id
 
@@ -186,16 +229,37 @@ class JaxLLMEngine:
             idx = self._free_slot()
             if idx is None:
                 return
-            request_id, meta, k, v = self._waiting_kv.pop(0)
-            self.cache = self._insert_kv(self.cache, k, v, idx)
-            slot = _Slot(
-                request_id=request_id,
-                prompt_len=meta["prompt_len"],
-                generated=[meta["first_token"]],
-                params=meta["sampling"],
-            )
-            self.slots[idx] = slot
-            self._check_done(slot, meta["first_token"])
+            request_id, meta, k, v, t_arrive, trace_id = (
+                self._waiting_kv.pop(0))
+            with self._admit_span(request_id, idx, meta["prompt_len"],
+                                  int(k.shape[3]), t_arrive, trace_id):
+                self.cache = self._insert_kv(self.cache, k, v, idx)
+                slot = _Slot(
+                    request_id=request_id,
+                    prompt_len=meta["prompt_len"],
+                    generated=[meta["first_token"]],
+                    params=meta["sampling"],
+                )
+                self.slots[idx] = slot
+                self._check_done(slot, meta["first_token"])
+
+    def _admit_span(self, request_id: int, slot: int, prompt_len: int,
+                    padded_len: int, t_arrive: float,
+                    trace_id: Optional[str]):
+        """Count one admission and open its ``engine.admit`` span (the
+        attributes are all known at entry; ``trace_id`` joins the span to
+        the cluster trace of ``tracing.start_span``)."""
+        wait_s = time.perf_counter() - t_arrive
+        c = self._counts
+        c["admitted"] += 1
+        c["prompt_tokens"] += prompt_len
+        c["padded_prompt_tokens"] += padded_len
+        c["queue_wait_s_total"] += wait_s
+        attrs = {"trace_id": trace_id} if trace_id else {}
+        return host_span(
+            "engine.admit", request_id=request_id, slot=slot,
+            prompt_len=prompt_len, padded_len=padded_len,
+            queue_wait_ms=wait_s * 1e3, **attrs)
 
     def _admit(self):
         import jax.numpy as jnp
@@ -205,25 +269,33 @@ class JaxLLMEngine:
             idx = self._free_slot()
             if idx is None:
                 return
-            request_id, token_ids, params = self._waiting.pop(0)
-            tokens = np.zeros(self.cfg.max_seq_len, np.int32)
-            tokens[: len(token_ids)] = token_ids
-            logits, self.cache = self._prefill_one(
-                self.params,
-                self.cache,
-                jnp.asarray(tokens),
-                len(token_ids),
-                idx,
-            )
-            first = self._sample_one(logits[None], params)[0]
-            slot = _Slot(
-                request_id=request_id,
-                prompt_len=len(token_ids),
-                generated=[int(first)],
-                params=params,
-            )
-            self.slots[idx] = slot
-            self._check_done(slot, int(first))
+            request_id, token_ids, params, t_arrive, trace_id = (
+                self._waiting.pop(0))
+            with self._admit_span(request_id, idx, len(token_ids),
+                                  self.cfg.max_seq_len, t_arrive, trace_id):
+                # Returns before the device finishes: the wait for the
+                # prefill program shows in the sample span that follows.
+                with host_span("engine.prefill.dispatch"):
+                    tokens = np.zeros(self.cfg.max_seq_len, np.int32)
+                    tokens[: len(token_ids)] = token_ids
+                    logits, self.cache = self._prefill_one(
+                        self.params,
+                        self.cache,
+                        jnp.asarray(tokens),
+                        len(token_ids),
+                        idx,
+                    )
+                with host_span("engine.sample", slots=1):
+                    first = self._sample_one(logits[None], params)[0]
+                self._counts["generated_tokens"] += 1
+                slot = _Slot(
+                    request_id=request_id,
+                    prompt_len=len(token_ids),
+                    generated=[int(first)],
+                    params=params,
+                )
+                self.slots[idx] = slot
+                self._check_done(slot, int(first))
 
     def _sample_one(self, logits, params: SamplingParams):
         import jax
@@ -260,57 +332,120 @@ class JaxLLMEngine:
         Thread-safe (serialized on the engine lock)."""
         import jax.numpy as jnp
 
-        with self._step_lock:
+        with self.locked():
             return self._step_locked(jnp)
 
+    @contextlib.contextmanager
+    def locked(self, request_id: Optional[int] = None):
+        """Hold the engine lock.  A thread's outermost acquisition is an
+        ``engine.lock_wait`` span and counts into ``lock_wait_s_total``;
+        re-entry (``stream_request`` -> ``step``) waits for nothing and
+        records nothing."""
+        me = threading.get_ident()
+        if self._lock_owner == me:
+            yield
+            return
+        attrs = {} if request_id is None else {"request_id": request_id}
+        t0 = time.perf_counter()
+        with host_span("engine.lock_wait", **attrs):
+            self._step_lock.acquire()
+        self._lock_owner = me
+        self._counts["lock_wait_s_total"] += time.perf_counter() - t0
+        try:
+            yield
+        finally:
+            self._lock_owner = None
+            self._step_lock.release()
+
     def _step_locked(self, jnp) -> List[dict]:
-        self._admit()
-        finished = self._retire()  # requests that finished at admission
-        active = [
-            (i, s) for i, s in enumerate(self.slots)
-            if s is not None and not s.done
-        ]
-        if active:
-            tokens = np.zeros(self.cfg.max_batch_size, np.int32)
-            pos = np.zeros(self.cfg.max_batch_size, np.int32)
-            for i, s in active:
-                tokens[i] = s.generated[-1]
-                pos[i] = s.last_pos
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos)
-            )
-            logits_np = logits  # stays on device for sampling
-            for i, s in active:
-                token = int(
-                    self._sample_one(logits_np[i : i + 1], s.params)[0]
-                )
-                s.generated.append(token)
-                self._check_done(s, token)
-        finished.extend(self._retire())
+        c = self._counts
+        admitted0, retired0 = c["admitted"], c["retired"]
+        with host_span("engine.step", seq=c["steps"]):
+            self._admit()
+            finished = self._retire()  # requests that finished at admission
+            active = [
+                (i, s) for i, s in enumerate(self.slots)
+                if s is not None and not s.done
+            ]
+            if active:
+                with host_span("engine.decode.dispatch", active=len(active)):
+                    tokens = np.zeros(self.cfg.max_batch_size, np.int32)
+                    pos = np.zeros(self.cfg.max_batch_size, np.int32)
+                    for i, s in active:
+                        tokens[i] = s.generated[-1]
+                        pos[i] = s.last_pos
+                    logits, self.cache = self._decode(
+                        self.params, self.cache,
+                        jnp.asarray(tokens), jnp.asarray(pos),
+                    )
+                with host_span("engine.sample", slots=len(active)):
+                    for i, s in active:  # logits stay on the device
+                        token = int(
+                            self._sample_one(logits[i : i + 1], s.params)[0]
+                        )
+                        s.generated.append(token)
+                        self._check_done(s, token)
+                c["decode_steps"] += 1
+                c["generated_tokens"] += len(active)
+            finished.extend(self._retire())
+            occupied, waiting = self.occupied(), self._n_waiting()
+            admitted = c["admitted"] - admitted0
+            retired = c["retired"] - retired0
+            c["steps"] += 1
+            c["occupied_slot_steps"] += occupied
+            # What is only known at the end of the step: zero-length, last.
+            with host_span("engine.counts", occupied=occupied,
+                           waiting=waiting, admitted=admitted,
+                           retired=retired):
+                pass
+        flight_recorder.record_llm_step(
+            occupied, waiting, admitted, retired, self.cfg.max_batch_size)
         return finished
 
     def _retire(self) -> List[dict]:
         out = []
-        for i, s in enumerate(self.slots):
-            if s is not None and s.done:
-                gen = s.generated
-                stop = (
-                    s.params.stop_token
-                    if s.params.stop_token is not None
-                    else getattr(self.tokenizer, "EOS", None)
-                )
-                if stop is not None and gen and gen[-1] == stop:
-                    gen = gen[:-1]
-                result = {
-                    "request_id": s.request_id,
-                    "token_ids": gen,
-                    "text": self.tokenizer.decode(gen),
-                    "num_generated": len(s.generated),
-                }
-                self._finished[s.request_id] = result
-                out.append(result)
-                self.slots[i] = None
+        with host_span("engine.retire"):
+            for i, s in enumerate(self.slots):
+                if s is not None and s.done:
+                    gen = s.generated
+                    stop = (
+                        s.params.stop_token
+                        if s.params.stop_token is not None
+                        else getattr(self.tokenizer, "EOS", None)
+                    )
+                    if stop is not None and gen and gen[-1] == stop:
+                        gen = gen[:-1]
+                    result = {
+                        "request_id": s.request_id,
+                        "token_ids": gen,
+                        "text": self.tokenizer.decode(gen),
+                        "num_generated": len(s.generated),
+                    }
+                    self._finished[s.request_id] = result
+                    out.append(result)
+                    self.slots[i] = None
+        self._counts["retired"] += len(out)
         return out
+
+    # ---------------------------------------------------------------- counts
+    def occupied(self) -> int:
+        """Slots that hold a request right now."""
+        return sum(1 for s in self.slots if s is not None)
+
+    def _n_waiting(self) -> int:
+        return len(self._waiting) + len(self._waiting_kv)
+
+    def stats(self) -> Dict[str, Any]:
+        """Counters since the engine was built (plain numbers that only
+        grow) and two gauges, ``occupied`` and ``waiting``; taken under the
+        engine lock, so one step's counts are never seen half-added.
+        ``generated_tokens`` counts tokens THIS engine sampled (an adopted
+        KV request's first token came from its prefill replica);
+        ``occupied_slot_steps`` sums, over steps, the slots occupied when
+        the step returns: over ``steps`` it is the mean batch occupancy."""
+        with self.locked():
+            return dict(self._counts, occupied=self.occupied(),
+                        waiting=self._n_waiting())
 
     def has_unfinished(self) -> bool:
         return bool(self._waiting) or bool(self._waiting_kv) or any(
@@ -321,16 +456,14 @@ class JaxLLMEngine:
     def cancel_request(self, request_id: int) -> None:
         """Drop a request wherever it is (queue, slot, finished results) —
         abandoned streams must not keep decoding or park results forever."""
-        with self._step_lock:
-            self._waiting = [
-                w for w in self._waiting if w[0] != request_id
-            ]
-            self._waiting_kv = [
-                w for w in self._waiting_kv if w[0] != request_id
-            ]
+        with self.locked(request_id):
+            dropped = _drop_queued(self._waiting, request_id)
+            dropped += _drop_queued(self._waiting_kv, request_id)
             for i, slot in enumerate(self.slots):
                 if slot is not None and slot.request_id == request_id:
                     self.slots[i] = None
+                    dropped += 1
+            self._counts["cancelled"] += dropped
             self._finished.pop(request_id, None)
 
     def generate_stream(self, prompt: str,
@@ -356,7 +489,7 @@ class JaxLLMEngine:
                     raise TimeoutError("generation exceeded timeout")
                 done = None
                 delta_tokens: list = []
-                with self._step_lock:
+                with self.locked(request_id):
                     done = self._finished.pop(request_id, None)
                     if done is None:
                         self.step()
@@ -398,7 +531,7 @@ class JaxLLMEngine:
         ids = [self.add_request(p, params) for p in prompts]
         deadline = time.monotonic() + timeout_s
         while True:
-            with self._step_lock:
+            with self.locked():
                 if all(i in self._finished for i in ids):
                     return [self._finished.pop(i) for i in ids]
                 self.step()

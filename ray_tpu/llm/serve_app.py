@@ -17,6 +17,7 @@ import uuid
 from typing import Any, Dict, List, Optional
 
 from .. import serve
+from ..util import tracing
 from .engine import EngineConfig, JaxLLMEngine, SamplingParams
 
 
@@ -48,7 +49,7 @@ class LLMServer:
             for prompt, params in requests
         ]
         while True:
-            with self.engine._step_lock:
+            with self.engine.locked():
                 if all(i in self.engine._finished for i in ids):
                     return [self.engine._finished.pop(i) for i in ids]
                 self.engine.step()
@@ -72,6 +73,21 @@ class LLMServer:
             ),
             body, self.model_name,
         )
+
+    def engine_stats(self) -> Dict[str, Any]:
+        """The engine's counters (``JaxLLMEngine.stats``): steps, admitted,
+        retired, tokens, slot occupancy, queue and lock wait."""
+        return self.engine.stats()
+
+    def start_profile(self, path: str) -> str:
+        """Trace THIS replica (only the process that holds the chip can):
+        device operations and the engine's spans into ``path``, on one
+        clock, until ``stop_profile``.  See docs/llm_serving.md."""
+        tracing.start_profile(path)
+        return path
+
+    def stop_profile(self) -> None:
+        tracing.stop_profile()
 
     def device_info(self) -> Dict[str, Any]:
         """The devices this replica's engine runs on, as jax reports them."""

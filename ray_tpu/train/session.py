@@ -12,6 +12,7 @@ import threading
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
+from ..util.tracing import host_span
 from .checkpoint import Checkpoint
 
 _session = threading.local()
@@ -51,9 +52,12 @@ def get_context() -> TrainContext:
 
 
 def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
-    ctx = get_context()
-    if ctx._report_fn is not None:
-        ctx._report_fn(metrics, checkpoint)
+    # The one program-side boundary a user's step loop crosses: a span on
+    # the device trace's clock while a profiler session runs.
+    with host_span("train.report"):
+        ctx = get_context()
+        if ctx._report_fn is not None:
+            ctx._report_fn(metrics, checkpoint)
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

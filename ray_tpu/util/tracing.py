@@ -14,6 +14,23 @@ Usage:
     with tracing.start_span("preprocess") as span:
         ...                       # user code; nested submits inherit
     spans = tracing.get_trace(span.trace_id)   # driver-side query
+
+Two sinks, two clocks — which to use:
+
+* ``start_span`` / ``record_span``: a request across processes.  Wall
+  clock, control-plane store, read by ``cli timeline``, the dashboard and
+  ``get_trace``.  May be held across ``await`` (it rides a contextvar).
+* ``host_span``: work on ONE thread of the process that holds the chip.
+  It is ``jax.profiler.TraceAnnotation``: while a profiler session runs
+  (``start_profile`` here, ``jax.profiler.start_trace`` anywhere) the span
+  lands in the same ``.xplane.pb`` as the device's operations, on the
+  device trace's time base; with no session it costs about a microsecond
+  and records nothing.  It must begin and end on one thread and must not
+  be held across ``await`` or a generator ``yield`` (annotations are a
+  per-thread stack).  Attributes are fixed at entry; what is known only
+  at the end goes on a zero-length span written there.  The join between
+  the two traces is an attribute (``engine.admit`` carries the cluster
+  ``trace_id``), not a shared span.
 """
 
 from __future__ import annotations
@@ -22,6 +39,7 @@ import contextlib
 import contextvars
 import dataclasses
 import os
+import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -33,6 +51,45 @@ _current: contextvars.ContextVar[Optional[Tuple[str, str]]] = (
 
 def _rand_id(nbytes: int = 8) -> str:
     return os.urandom(nbytes).hex()
+
+
+# ------------------------------------------------- the device-clock sink
+_NO_SPAN = contextlib.nullcontext()
+_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def host_span(name: str, **attrs):
+    """A span on the device trace's clock (see the module docstring).
+
+    Never imports jax: in a process that has not loaded it (the proxy, the
+    controller, a driver) this is a shared no-op.  A jax that is still
+    half-way through its import on another thread counts as not loaded."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+        if _annotation is None:
+            return _NO_SPAN
+    return _annotation(name, **attrs)
+
+
+def start_profile(path: str) -> None:
+    """Start a profiler session in THIS process (the one that holds the
+    chip): device operations and ``host_span``s into ``path``, one file,
+    one time base.  The profiler's Python tracer is off: it doubled a
+    host-bound serving step (PERF.md, PR 24)."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(path, profiler_options=options)
+
+
+def stop_profile() -> None:
+    import jax
+
+    jax.profiler.stop_trace()
 
 
 @dataclasses.dataclass

@@ -317,6 +317,41 @@ def test_late_heartbeat_of_a_live_agent_is_not_node_death():
         ray_tpu.shutdown()
 
 
+def test_late_heartbeat_of_a_live_driver_is_not_job_death():
+    """Drivers get the agents' treatment: a driver whose heartbeats stood
+    still for longer than the limit (the same stall, on the v5e host, cost
+    one serving run in 26 its job while the replica's TPU runtime came up)
+    answers the control plane's ping, and its job and actors stay."""
+    import time
+
+    import ray_tpu
+    from ray_tpu.core.core_worker import global_worker
+
+    ray_tpu.init(
+        num_cpus=2, _system_config={"health_check_timeout_s": 3.0}
+    )
+    try:
+        @ray_tpu.remote
+        class Keeper:
+            def ping(self):
+                return 7
+
+        keeper = Keeper.remote()
+        assert ray_tpu.get(keeper.ping.remote(), timeout=60) == 7
+        w = global_worker()
+        w.loop.call_soon_threadsafe(w._heartbeat_task.cancel)
+        time.sleep(5.5)  # no heartbeat for 3 s + a sweep or two
+        w.loop.call_soon_threadsafe(
+            lambda: setattr(w, "_heartbeat_task",
+                            w.loop.create_task(w._job_heartbeat_loop())))
+        time.sleep(2.0)  # a lost job's actors would be gone by now
+        assert ray_tpu.get(keeper.ping.remote(), timeout=60) == 7
+        jobs = w._run_sync(w.cp.call("list_jobs", {}))
+        assert jobs[w.job_id]["state"] == "RUNNING"
+    finally:
+        ray_tpu.shutdown()
+
+
 @pytest.mark.parametrize("ignores_sigterm", [False, True])
 def test_shutdown_returns_with_its_workers_gone(ignores_sigterm):
     """``shutdown()`` leaves no process behind: the agent kills and reaps

@@ -1,0 +1,361 @@
+"""The serving engine's and the train session's spans and counters.
+
+``util.tracing.host_span`` is ``jax.profiler.TraceAnnotation``: under a
+profiler session the spans land in the profiler's own trace (on the CPU
+backend too), which ``benchmarks/lib/host_spans.py`` reads back.  Span names
+and attributes are a contract (PERF.md lists the metric that reads each).
+Nothing here times anything.
+"""
+
+import subprocess
+import sys
+import threading
+
+import pytest
+
+from benchmarks.lib import host_spans as hs
+from benchmarks.lib import trace_reduce as tr
+from ray_tpu.llm.engine import EngineConfig, JaxLLMEngine, SamplingParams
+from ray_tpu.models import GPT2Config
+from ray_tpu.util import tracing
+
+SLOTS, SEQ = 4, 64
+PROMPTS = ["a", "bc", "def", "ghij", "klmno", "pqrstu"]
+MAX_TOKENS = [3, 5, 2, 7, 4, 6]
+
+
+def make_engine():
+    return JaxLLMEngine(EngineConfig(
+        model=GPT2Config.tiny(vocab_size=384),
+        max_batch_size=SLOTS, max_seq_len=SEQ))
+
+
+def run_scenario(engine):
+    """Six requests of known lengths through four slots, stepped by hand the
+    way ``BenchLLMServer`` counts: slots occupied when ``step()`` returns.
+    A stop token that never comes, so every request runs to max_tokens."""
+    for prompt, n in zip(PROMPTS, MAX_TOKENS):
+        engine.add_request(
+            prompt, SamplingParams(max_tokens=n, stop_token=-1))
+    outside = []
+    while engine.has_unfinished():
+        engine.step()
+        outside.append(sum(1 for s in engine.slots if s is not None))
+    return outside
+
+
+def traced(tmp_path, body):
+    """Run ``body`` under a profiler session; the program's spans per thread
+    (nested), as the benchmark's library reads them."""
+    tracing.start_profile(str(tmp_path))
+    try:
+        body()
+    finally:
+        tracing.stop_profile()
+    [path] = tr.find_traces(str(tmp_path))
+    return hs.from_planes(hs.load_host(path), {})
+
+
+@pytest.fixture(scope="module")
+def scenario(tmp_path_factory):
+    """One traced run of the scenario: (engine, outside counts, spans)."""
+    engine = make_engine()
+    engine.generate(["warm"], SamplingParams(max_tokens=2, stop_token=-1))
+    before = engine.stats()
+    out = {}
+    trace = traced(tmp_path_factory.mktemp("trace"),
+                   lambda: out.update(outside=run_scenario(engine)))
+    return engine, before, out["outside"], trace
+
+
+def test_every_span_of_the_table_is_there(scenario):
+    _engine, _before, _outside, trace = scenario
+    names = {s.name for s in trace.spans()}
+    assert names == {
+        "engine.lock_wait", "engine.step", "engine.admit",
+        "engine.prefill.dispatch", "engine.decode.dispatch", "engine.sample",
+        "engine.retire", "engine.counts"}
+
+
+def test_spans_nest_per_thread(scenario):
+    _engine, _before, outside, trace = scenario
+    [roots] = trace.threads  # one thread drove the engine
+    assert {r.name for r in roots} == {"engine.lock_wait", "engine.step"}
+    steps = trace.spans("engine.step")
+    assert len(steps) == len(outside)
+    for step in steps:
+        kinds = [c.name for c in step.children]
+        assert set(kinds) <= {"engine.admit", "engine.retire", "engine.counts",
+                              "engine.decode.dispatch", "engine.sample"}
+        assert kinds[-1] == "engine.counts" and kinds.count("engine.counts") == 1
+        assert kinds.count("engine.retire") == 2
+        if "engine.decode.dispatch" in kinds:
+            i = kinds.index("engine.decode.dispatch")
+            assert kinds[i + 1] == "engine.sample"
+    for admit in trace.spans("engine.admit"):
+        assert [c.name for c in admit.children] == [
+            "engine.prefill.dispatch", "engine.sample"]
+        assert admit.children[1].stats == {"slots": 1}
+    # A lock wait is over before the step it waited for begins.
+    assert all(not r.children for r in roots if r.name == "engine.lock_wait")
+
+
+def test_attributes_at_entry(scenario):
+    engine, before, outside, trace = scenario
+    steps = trace.spans("engine.step")
+    assert [s.stats["seq"] for s in steps] == list(
+        range(before["steps"], before["steps"] + len(steps)))
+    admits = trace.spans("engine.admit")
+    assert len(admits) == len(PROMPTS)
+    for admit, prompt in zip(admits, PROMPTS):
+        st = admit.stats
+        assert set(st) == {"request_id", "slot", "prompt_len", "padded_len",
+                           "queue_wait_ms"}  # no cluster trace: no trace_id
+        assert st["prompt_len"] == len(engine.tokenizer.encode(prompt))
+        assert st["padded_len"] == SEQ and 0 <= st["slot"] < SLOTS
+        assert st["queue_wait_ms"] >= 0
+    for step in steps:
+        for child in step.children:
+            if child.name == "engine.decode.dispatch":
+                assert child.stats["active"] >= 1
+            if child.name == "engine.sample":
+                assert child.stats["slots"] >= 1
+
+
+def test_counts_once_per_step_and_equal_to_the_outside_count(scenario):
+    _engine, _before, outside, trace = scenario
+    counts = trace.spans("engine.counts")
+    assert [c.stats["occupied"] for c in counts] == outside
+    assert sum(c.stats["admitted"] for c in counts) == len(PROMPTS)
+    assert sum(c.stats["retired"] for c in counts) == len(PROMPTS)
+    assert counts[0].stats["waiting"] == len(PROMPTS) - SLOTS
+    assert counts[-1].stats == {"occupied": 0, "waiting": 0, "admitted": 0,
+                                "retired": counts[-1].stats["retired"]}
+    assert all(set(c.stats) == {"occupied", "waiting", "admitted", "retired"}
+               for c in counts)
+
+
+def fresh_stats(engine, base):
+    now = engine.stats()
+    return {k: now[k] - base[k] for k in now
+            if k not in ("occupied", "waiting",
+                         "queue_wait_s_total", "lock_wait_s_total")}
+
+
+def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
+    engine, before, outside, _trace = scenario
+    with_session = fresh_stats(engine, before)
+    n = len(PROMPTS)
+    assert with_session == {
+        "steps": len(outside),
+        "decode_steps": len(outside),  # every step of the scenario decodes
+        "admitted": n, "retired": n, "cancelled": 0,
+        "prompt_tokens": sum(len(engine.tokenizer.encode(p)) for p in PROMPTS),
+        "padded_prompt_tokens": n * SEQ,
+        "generated_tokens": sum(MAX_TOKENS),
+        # The inside count equals the outside count, step for step.
+        "occupied_slot_steps": sum(outside),
+    }
+    assert engine.stats()["occupied"] == engine.occupied() == 0
+    assert engine.stats()["waiting"] == 0
+    base = engine.stats()
+    outside_again = run_scenario(engine)  # no profiler session now
+    assert outside_again == outside
+    assert fresh_stats(engine, base) == with_session
+    assert sum(outside) / len(outside) == (
+        with_session["occupied_slot_steps"] / with_session["steps"])
+
+
+def test_stats_only_grow_and_waits_are_counted():
+    engine = make_engine()
+    zero = engine.stats()
+    # stats() takes the lock itself: its own wait is already counted.
+    assert all(v == 0 for k, v in zero.items() if k != "lock_wait_s_total")
+    assert set(zero) == {
+        "steps", "decode_steps", "admitted", "retired", "cancelled",
+        "prompt_tokens", "padded_prompt_tokens", "generated_tokens",
+        "occupied_slot_steps", "queue_wait_s_total", "lock_wait_s_total",
+        "occupied", "waiting"}
+    engine.add_request("queued", SamplingParams(max_tokens=9, stop_token=-1))
+    assert engine.stats()["waiting"] == 1 and engine.occupied() == 0
+    engine.step()
+    one = engine.stats()
+    assert one["occupied"] == engine.occupied() == 1 and one["waiting"] == 0
+    assert one["queue_wait_s_total"] > 0 and one["lock_wait_s_total"] > 0
+    engine.generate(["x"], SamplingParams(max_tokens=3))
+    two = engine.stats()
+    assert all(two[k] >= one[k] for k in one if k not in ("occupied", "waiting"))
+
+
+def test_cancel_counts_what_it_dropped():
+    engine = make_engine()
+    rid = engine.add_request("in the queue", SamplingParams(max_tokens=4))
+    engine.cancel_request(rid)
+    held = engine.add_request("in a slot", SamplingParams(max_tokens=9))
+    engine.step()
+    engine.cancel_request(held)
+    engine.cancel_request(held)  # nothing left to drop
+    stats = engine.stats()
+    assert stats["cancelled"] == 2 and stats["occupied"] == 0
+    assert not engine.has_unfinished()
+
+
+def test_two_streams_wait_for_the_lock_and_never_step_together(tmp_path):
+    engine = make_engine()
+    params = SamplingParams(max_tokens=12, stop_token=-1)
+    engine.generate(["warm"], params)
+    gate = threading.Barrier(2)
+
+    def stream(prompt):
+        gate.wait()
+        for _delta in engine.generate_stream(prompt, params):
+            pass
+
+    def both():
+        threads = [threading.Thread(target=stream, args=(p,))
+                   for p in ("first", "second")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+
+    trace = traced(tmp_path, both)
+    waiting = [roots for roots in trace.threads
+               if any(r.name == "engine.lock_wait" for r in roots)]
+    assert len(waiting) == 2  # both threads asked for the lock
+    for roots in waiting:
+        assert all("request_id" in r.stats
+                   for r in roots if r.name == "engine.lock_wait")
+    steps = sorted(trace.spans("engine.step"), key=lambda s: s.start)
+    assert all(a.end <= b.start for a, b in zip(steps, steps[1:]))
+    assert [s.stats["seq"] for s in steps] == list(
+        range(steps[0].stats["seq"], steps[0].stats["seq"] + len(steps)))
+    assert len(trace.spans("engine.admit")) == 2
+
+
+def test_admit_carries_the_cluster_trace_id(tmp_path):
+    engine = make_engine()
+
+    def body():
+        with tracing.start_span("request") as span:
+            body.trace_id = span.trace_id
+            engine.add_request("traced", SamplingParams(max_tokens=2))
+        engine.add_request("untraced", SamplingParams(max_tokens=2))
+        while engine.has_unfinished():
+            engine.step()
+
+    first, second = traced(tmp_path, body).spans("engine.admit")
+    assert first.stats["trace_id"] == body.trace_id
+    assert "trace_id" not in second.stats
+
+
+def test_start_span_opens_no_annotation(tmp_path):
+    def body():
+        with tracing.start_span("cluster.side", {"k": 1}):
+            with tracing.host_span("engine.marker", k=1):
+                pass
+
+    trace = traced(tmp_path, body)
+    assert [s.name for s in trace.spans()] == ["engine.marker"]
+    [path] = tr.find_traces(str(tmp_path))
+    from jax.profiler import ProfileData
+
+    every = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "cluster.side" not in every
+
+
+def test_host_span_is_a_no_op_that_does_not_import_jax():
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "assert 'jax' not in sys.modules, 'importing tracing loaded jax'\n"
+        "a = tracing.host_span('engine.x', n=1)\n"
+        "b = tracing.host_span('engine.y')\n"
+        "assert a is b  # the shared no-op\n"
+        "with a:\n"
+        "    with b:\n"
+        "        pass\n"
+        "assert 'jax' not in sys.modules, 'host_span loaded jax'\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_add_is_never_lost_to_a_concurrent_cancel():
+    """``add_request`` appends without the engine lock; ``cancel_request``
+    used to rebind the queue to a filtered copy, so an append that fell
+    between the copy and the rebind landed in the discarded list."""
+    engine = make_engine()
+    params = SamplingParams(max_tokens=1)
+    victims = [engine.add_request("victim", params) for _ in range(300)]
+    added = []
+    stop = threading.Event()
+
+    def adder():
+        while not stop.is_set() and len(added) < 3000:
+            added.append(engine.add_request("keep", params))
+
+    t = threading.Thread(target=adder)
+    t.start()
+    for rid in victims:
+        engine.cancel_request(rid)
+    stop.set()
+    t.join()
+    queue = engine._waiting
+    assert [w[0] for w in queue] == added  # none lost, order kept
+    assert engine.stats()["cancelled"] == len(victims)
+    # The queue is the same list it was: a rebind would lose appends.
+    engine.cancel_request(added[0])
+    assert engine._waiting is queue and queue[0][0] == added[1]
+
+
+def test_train_report_runs_inside_a_span(tmp_path):
+    from ray_tpu.train import session
+
+    got = []
+    session._set_session(session.TrainContext(
+        world_rank=0, world_size=1, local_rank=0, node_rank=0,
+        _report_fn=lambda metrics, ckpt: got.append(metrics)))
+    try:
+        trace = traced(tmp_path, lambda: [
+            session.report({"step": i}) for i in range(3)])
+    finally:
+        session._clear_session()
+    assert got == [{"step": 0}, {"step": 1}, {"step": 2}]
+    assert [s.name for s in trace.spans()] == ["train.report"] * 3
+
+
+def test_serve_app_exposes_stats_and_profile_hooks(tmp_path):
+    from ray_tpu.llm.serve_app import LLMServer
+
+    server = LLMServer.func_or_class(EngineConfig(
+        model=GPT2Config.tiny(vocab_size=384), max_batch_size=2,
+        max_seq_len=32))
+    assert server.start_profile(str(tmp_path)) == str(tmp_path)
+    try:
+        list(server.stream_chunks({"prompt": "hi", "max_tokens": 3}))
+    finally:
+        server.stop_profile()
+    stats = server.engine_stats()
+    assert stats["admitted"] == stats["retired"] == 1
+    [path] = tr.find_traces(str(tmp_path))
+    names = {s.name for s in hs.from_planes(hs.load_host(path), {}).spans()}
+    assert {"engine.step", "engine.admit", "engine.counts"} <= names
+
+
+def test_each_step_feeds_the_metrics_registry(monkeypatch):
+    from ray_tpu.util import flight_recorder
+
+    rows = []
+    monkeypatch.setattr(
+        flight_recorder, "record_llm_step",
+        lambda *a: rows.append(a))
+    engine = make_engine()
+    outside = run_scenario(engine)
+    assert [r[0] for r in rows] == outside          # occupancy
+    assert sum(r[2] for r in rows) == len(PROMPTS)  # admitted
+    assert sum(r[3] for r in rows) == len(PROMPTS)  # retired
+    assert {r[4] for r in rows} == {SLOTS}          # bucket
+    assert rows[0][1] == len(PROMPTS) - SLOTS       # queue depth
