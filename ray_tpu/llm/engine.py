@@ -11,8 +11,19 @@ Model-agnostic: any config type with a registered ``ModelFamily``
 the reference's vLLM model registry) plugs in; the engine only speaks
 init/init_cache/prefill/decode_step.
 
-Shapes are static (max_batch_size × max_seq_len) so XLA compiles exactly
-two programs: prefill and decode.
+Shapes are static (max_batch_size × max_seq_len), so XLA compiles prefill,
+decode and two samplers over the decode step's ``[max_batch_size, V]``
+logits: ``sample_logits_greedy`` when every active slot has temperature 0,
+``sample_logits_rows`` otherwise (both also at ``[1, V]``, for a prefill's
+first token).  Sampling parameters reach the sampler as per-row ARRAYS, so a
+new ``SamplingParams`` value compiles nothing; a step reads its tokens from
+the device ONCE, whatever the number of slots.  At ``temperature > 0`` the
+draws for a given ``seed`` differ from versions that split one key per slot
+on the host: the key is now split once a step, inside the program.
+
+Program names are a contract too: the benchmark's readers find the decode
+program as the only ``jit__lambda`` and the samplers by ``jit_sample_logits``,
+so whatever is jitted here beside the decode step is a NAMED function.
 
 Observability (names are a contract: tests pin them, PERF.md lists which
 metric reads which).  Host work runs inside ``util.tracing.host_span``s —
@@ -36,7 +47,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from ..models import GPT2Config, model_family
-from ..models.gpt2_decode import sample_logits
+from ..models.gpt2_decode import sample_logits_greedy, sample_logits_rows
 from ..util import flight_recorder, tracing
 from ..util.tracing import host_span
 from .tokenizer import ByteTokenizer
@@ -141,7 +152,7 @@ class JaxLLMEngine:
         self._counts: Dict[str, Any] = dict.fromkeys(
             ("steps", "decode_steps", "admitted", "retired", "cancelled",
              "prompt_tokens", "padded_prompt_tokens", "generated_tokens",
-             "occupied_slot_steps"), 0)
+             "occupied_slot_steps", "host_syncs"), 0)
         self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
         def prefill_one(params, cache, tokens, length, slot_idx):
@@ -160,7 +171,7 @@ class JaxLLMEngine:
                     cache["v"], one_cache["v"], (0, slot_idx, 0, 0, 0)
                 ),
             }
-            return logits[0], cache
+            return logits, cache  # [1, V]: a batch of one for the sampler
 
         self._prefill_one = jax.jit(prefill_one, donate_argnums=(1,))
 
@@ -185,12 +196,8 @@ class JaxLLMEngine:
             ),
             donate_argnums=(1,),
         )
-        # Sampling params are static: Python branches inside sample_logits;
-        # one small compile per distinct SamplingParams config.
-        self._sample = jax.jit(
-            sample_logits,
-            static_argnames=("temperature", "top_k", "top_p"),
-        )
+        self._sample_rows = jax.jit(sample_logits_rows)
+        self._sample_greedy = jax.jit(sample_logits_greedy)
 
     # ----------------------------------------------------------------- queue
     def add_request(
@@ -286,30 +293,35 @@ class JaxLLMEngine:
                         idx,
                     )
                 with host_span("engine.sample", slots=1):
-                    first = self._sample_one(logits[None], params)[0]
+                    first = int(self._sample(logits, [(0, params)])[0])
                 self._counts["generated_tokens"] += 1
                 slot = _Slot(
                     request_id=request_id,
                     prompt_len=len(token_ids),
-                    generated=[int(first)],
+                    generated=[first],
                     params=params,
                 )
                 self.slots[idx] = slot
-                self._check_done(slot, int(first))
+                self._check_done(slot, first)
 
-    def _sample_one(self, logits, params: SamplingParams):
-        import jax
-
-        self._key, sub = jax.random.split(self._key)
-        return np.asarray(
-            self._sample(
-                logits,
-                sub,
-                temperature=params.temperature,
-                top_k=params.top_k,
-                top_p=params.top_p,
-            )
-        )
+    def _sample(self, logits, rows) -> np.ndarray:
+        """One token for every row of the device's ``logits`` in ONE program
+        and ONE device->host read; ``rows`` = (row, SamplingParams) of the
+        rows that matter, the others are computed and ignored."""
+        if all(p.temperature <= 0 for _, p in rows):
+            tokens = self._sample_greedy(logits)
+        else:
+            n = logits.shape[0]
+            temperature = np.zeros(n, np.float32)
+            top_k = np.zeros(n, np.int32)
+            top_p = np.ones(n, np.float32)
+            for i, p in rows:
+                temperature[i], top_k[i], top_p[i] = (
+                    p.temperature, p.top_k, p.top_p)
+            tokens, self._key = self._sample_rows(
+                logits, self._key, temperature, top_k, top_p)
+        self._counts["host_syncs"] += 1
+        return np.asarray(tokens)
 
     def _check_done(self, slot: _Slot, token: int):
         stop = (
@@ -360,6 +372,7 @@ class JaxLLMEngine:
     def _step_locked(self, jnp) -> List[dict]:
         c = self._counts
         admitted0, retired0 = c["admitted"], c["retired"]
+        syncs0 = c["host_syncs"]
         with host_span("engine.step", seq=c["steps"]):
             self._admit()
             finished = self._retire()  # requests that finished at admission
@@ -379,10 +392,10 @@ class JaxLLMEngine:
                         jnp.asarray(tokens), jnp.asarray(pos),
                     )
                 with host_span("engine.sample", slots=len(active)):
-                    for i, s in active:  # logits stay on the device
-                        token = int(
-                            self._sample_one(logits[i : i + 1], s.params)[0]
-                        )
+                    sampled = self._sample(
+                        logits, [(i, s.params) for i, s in active])
+                    for i, s in active:
+                        token = int(sampled[i])
                         s.generated.append(token)
                         self._check_done(s, token)
                 c["decode_steps"] += 1
@@ -396,7 +409,8 @@ class JaxLLMEngine:
             # What is only known at the end of the step: zero-length, last.
             with host_span("engine.counts", occupied=occupied,
                            waiting=waiting, admitted=admitted,
-                           retired=retired):
+                           retired=retired,
+                           host_syncs=c["host_syncs"] - syncs0):
                 pass
         flight_recorder.record_llm_step(
             occupied, waiting, admitted, retired, self.cfg.max_batch_size)
@@ -442,7 +456,9 @@ class JaxLLMEngine:
         ``generated_tokens`` counts tokens THIS engine sampled (an adopted
         KV request's first token came from its prefill replica);
         ``occupied_slot_steps`` sums, over steps, the slots occupied when
-        the step returns: over ``steps`` it is the mean batch occupancy."""
+        the step returns: over ``steps`` it is the mean batch occupancy;
+        ``host_syncs`` counts device->host token reads: one a decode step,
+        one a locally prefilled admission."""
         with self.locked():
             return dict(self._counts, occupied=self.occupied(),
                         waiting=self._n_waiting())

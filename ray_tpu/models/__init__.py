@@ -14,6 +14,8 @@ from .gpt2_decode import (  # noqa: F401
     gpt2_init_cache,
     gpt2_prefill,
     sample_logits,
+    sample_logits_greedy,
+    sample_logits_rows,
 )
 from .llama import (  # noqa: F401
     LlamaConfig,

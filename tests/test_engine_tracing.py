@@ -130,9 +130,14 @@ def test_counts_once_per_step_and_equal_to_the_outside_count(scenario):
     assert sum(c.stats["retired"] for c in counts) == len(PROMPTS)
     assert counts[0].stats["waiting"] == len(PROMPTS) - SLOTS
     assert counts[-1].stats == {"occupied": 0, "waiting": 0, "admitted": 0,
-                                "retired": counts[-1].stats["retired"]}
-    assert all(set(c.stats) == {"occupied", "waiting", "admitted", "retired"}
+                                "retired": counts[-1].stats["retired"],
+                                "host_syncs": 1}
+    assert all(set(c.stats) == {"occupied", "waiting", "admitted", "retired",
+                                "host_syncs"}
                for c in counts)
+    # One read for the step's tokens and one for each admission's first.
+    assert [c.stats["host_syncs"] for c in counts] == [
+        1 + c.stats["admitted"] for c in counts]
 
 
 def fresh_stats(engine, base):
@@ -155,6 +160,8 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
         "generated_tokens": sum(MAX_TOKENS),
         # The inside count equals the outside count, step for step.
         "occupied_slot_steps": sum(outside),
+        # One token read a decode step, one a prefilled admission.
+        "host_syncs": len(outside) + n,
     }
     assert engine.stats()["occupied"] == engine.occupied() == 0
     assert engine.stats()["waiting"] == 0
@@ -174,8 +181,8 @@ def test_stats_only_grow_and_waits_are_counted():
     assert set(zero) == {
         "steps", "decode_steps", "admitted", "retired", "cancelled",
         "prompt_tokens", "padded_prompt_tokens", "generated_tokens",
-        "occupied_slot_steps", "queue_wait_s_total", "lock_wait_s_total",
-        "occupied", "waiting"}
+        "occupied_slot_steps", "host_syncs", "queue_wait_s_total",
+        "lock_wait_s_total", "occupied", "waiting"}
     engine.add_request("queued", SamplingParams(max_tokens=9, stop_token=-1))
     assert engine.stats()["waiting"] == 1 and engine.occupied() == 0
     engine.step()
