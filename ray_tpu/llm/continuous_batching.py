@@ -266,6 +266,9 @@ class ContinuousBatchingEngine:
         self._buckets = _buckets(self.cfg.max_batch_size)
         self.bucket = self._buckets[0]
         self.cache = fam.init_cache(mcfg, self.bucket, self.cfg.max_seq_len)
+        from .disagg import require_kv_cache
+
+        require_kv_cache(fam, self.cache)  # rows move as k / v pages below
         self.slots: List[Optional[_Seq]] = [None] * self.bucket
 
         # Compiled-program caches, all keyed by bucket (bounded at

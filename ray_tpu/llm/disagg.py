@@ -41,6 +41,19 @@ from .engine import EngineConfig, JaxLLMEngine, SamplingParams
 from .tokenizer import ByteTokenizer
 
 
+def require_kv_cache(family, cache) -> None:
+    """The hand-over between replicas moves a prompt's ``k`` and ``v`` pages
+    (``[L, 1, Hkv, T, D]`` each) and nothing else.  A family whose cache is
+    anything else (a latent cache, state beside keys and values) is refused
+    here by name, before a key error somewhere inside a program."""
+    leaves = sorted(cache) if isinstance(cache, dict) else [type(cache).__name__]
+    if leaves != ["k", "v"]:
+        raise NotImplementedError(
+            f"model family {family.name!r} keeps a cache with leaves "
+            f"{leaves}: disaggregated and continuous-batching serving hand "
+            "over 'k' and 'v' pages only; serve it with JaxLLMEngine")
+
+
 class PrefillEngine:
     """Prefill-only engine: prompt -> (first token, resident KV pages).
 
@@ -62,6 +75,8 @@ class PrefillEngine:
         else:
             self.params = fam.init(jax.random.PRNGKey(cfg.seed), mcfg)
         self._key = jax.random.PRNGKey(cfg.seed + 1)
+        require_kv_cache(fam, jax.eval_shape(
+            lambda: fam.init_cache(mcfg, 1, cfg.max_seq_len)))
 
         def prefill_row(params, tokens, length):
             import jax.numpy as jnp
@@ -165,7 +180,8 @@ class DecodeReplica:
     def add_from_kv(self, meta: Dict[str, Any]) -> int:
         """Fetch the KV pages from the prefill owner and enqueue."""
         k, v = fetch_prefill_kv(meta)
-        return self.engine.add_request_from_kv(meta, k, v)
+        require_kv_cache(self.engine.family, self.engine.cache)
+        return self.engine.add_request_from_kv(meta, {"k": k, "v": v})
 
     def run(self, request_id: int, timeout_s: float = 300.0) -> dict:
         """Decode until this request finishes; returns its result.

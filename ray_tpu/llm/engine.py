@@ -7,9 +7,15 @@ requests join and leave the batch at token granularity (continuous
 batching) and the chip never waits for the longest request in a batch.
 
 Model-agnostic: any config type with a registered ``ModelFamily``
-(``ray_tpu.models.model_family`` — GPT-2 and Llama ship in-tree, mirroring
-the reference's vLLM model registry) plugs in; the engine only speaks
-init/init_cache/prefill/decode_step.
+(``ray_tpu.models.model_family`` — GPT-2, Llama and LongCat ship in-tree,
+mirroring the reference's vLLM model registry) plugs in; the engine only
+speaks init/init_cache/prefill/decode_step.  The cache is the family's: a
+pytree of which the engine knows one thing, that every leaf's slot axis is
+axis 1 (``splice_row``); dense keys and values, or one latent leaf, are the
+same to it.  A family whose steps also return counts (``*_counted``: the
+routing counts of an expert layer) has them returned by the same two
+programs, read ONE STEP LATE (so a step still waits for the device once),
+summed in ``stats()`` and written on ``engine.counts``.
 
 Shapes are static (max_batch_size × max_seq_len), so XLA compiles prefill,
 decode and two samplers over the decode step's ``[max_batch_size, V]``
@@ -120,6 +126,28 @@ def _drop_queued(queue: List[tuple], request_id: int) -> int:
     return dropped
 
 
+def splice_row(cache, row, idx):
+    """Write a one-slot cache ``row`` into slot ``idx`` of ``cache``: the
+    one thing known of a family's cache is that every leaf's slot axis is
+    axis 1."""
+    import jax
+
+    def put(whole, one):
+        start = (0, idx) + (0,) * (whole.ndim - 2)
+        return jax.lax.dynamic_update_slice(whole, one, start)
+
+    return jax.tree.map(put, cache, row)
+
+
+def _without_counts(step):
+    """A family step that returns (logits, cache), as one that counts
+    nothing: (logits, cache, {})."""
+    def counted(*args):
+        logits, cache = step(*args)
+        return logits, cache, {}
+    return counted
+
+
 class JaxLLMEngine:
     def __init__(self, cfg: EngineConfig, tokenizer=None):
         import jax
@@ -155,47 +183,46 @@ class JaxLLMEngine:
              "occupied_slot_steps", "host_syncs"), 0)
         self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
+        prefill = fam.prefill_counted or _without_counts(fam.prefill)
+        decode_step = (fam.decode_step_counted
+                       or _without_counts(fam.decode_step))
+
         def prefill_one(params, cache, tokens, length, slot_idx):
             """Prefill a single request into batch row ``slot_idx``."""
             import jax.numpy as jnp
 
             one_cache = fam.init_cache(mcfg, 1, cfg.max_seq_len)
-            logits, one_cache = fam.prefill(
+            logits, one_cache, counts = prefill(
                 params, tokens[None], jnp.asarray([length]), one_cache, mcfg
             )
-            cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], one_cache["k"], (0, slot_idx, 0, 0, 0)
-                ),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], one_cache["v"], (0, slot_idx, 0, 0, 0)
-                ),
-            }
-            return logits, cache  # [1, V]: a batch of one for the sampler
+            # [1, V]: a batch of one for the sampler
+            return logits, splice_row(cache, one_cache, slot_idx), counts
 
         self._prefill_one = jax.jit(prefill_one, donate_argnums=(1,))
+        # Disaggregated admission: the one-slot cache arrives from a prefill
+        # replica instead of the local prefill program.
+        self._insert_row = jax.jit(splice_row, donate_argnums=(0,))
+        self._waiting_kv: List[tuple] = []  # (rid, meta, one-slot cache, ..)
 
-        def insert_kv(cache, k1, v1, idx):
-            """Splice a prefilled single-row KV block into batch row idx
-            (disaggregated admission — the row arrives from a prefill
-            replica instead of the local prefill program)."""
-            return {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], k1, (0, idx, 0, 0, 0)
-                ),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], v1, (0, idx, 0, 0, 0)
-                ),
-            }
-
-        self._insert_kv = jax.jit(insert_kv, donate_argnums=(0,))
-        self._waiting_kv: List[tuple] = []  # (rid, meta, k, v)
+        # A lambda, so that the program keeps the name the readers know.
         self._decode = jax.jit(
-            lambda params, cache, tokens, pos: fam.decode_step(
+            lambda params, cache, tokens, pos: decode_step(
                 params, tokens, pos, cache, mcfg
             ),
             donate_argnums=(1,),
         )
+        # What the family's programs count (int32 scalars a run; nothing for
+        # a family that counts nothing), summed here as Python ints.
+        names = () if fam.decode_step_counted is None else jax.eval_shape(
+            lambda p, c: decode_step(
+                p, np.zeros(cfg.max_batch_size, np.int32),
+                np.zeros(cfg.max_batch_size, np.int32), c, mcfg)[2],
+            self.params, self.cache)
+        self._family_counts = {
+            kind: dict.fromkeys(names, 0) for kind in ("decode", "prefill")}
+        # (kind, a run's counts on the device, their copy to the host under
+        # way) in dispatch order, until ``_fold_counts`` reads them.
+        self._unread_counts: List[tuple] = []
         self._sample_rows = jax.jit(sample_logits_rows)
         self._sample_greedy = jax.jit(sample_logits_greedy)
 
@@ -209,18 +236,20 @@ class JaxLLMEngine:
         self._waiting.append((request_id, token_ids, params, *_arrival()))
         return request_id
 
-    def add_request_from_kv(self, meta: dict, k, v) -> int:
+    def add_request_from_kv(self, meta: dict, row) -> int:
         """Disaggregated admission: enqueue a request whose prompt was
         prefilled elsewhere.  ``meta`` carries prompt_len / first_token /
-        sampling (see llm.disagg.PrefillEngine.prefill); ``k``/``v`` are
-        the [L, 1, H, S, D] KV pages for the prompt."""
+        sampling (see llm.disagg.PrefillEngine.prefill); ``row`` is the
+        prompt's one-slot cache, a pytree like this engine's cache with one
+        slot."""
+        import jax
         import jax.numpy as jnp
 
         arrival = _arrival()  # before the wait for the lock
         with self.locked():
             request_id = next(self._next_id)
             self._waiting_kv.append(
-                (request_id, meta, jnp.asarray(k), jnp.asarray(v), *arrival)
+                (request_id, meta, jax.tree.map(jnp.asarray, row), *arrival)
             )
             return request_id
 
@@ -236,11 +265,13 @@ class JaxLLMEngine:
             idx = self._free_slot()
             if idx is None:
                 return
-            request_id, meta, k, v, t_arrive, trace_id = (
+            request_id, meta, row, t_arrive, trace_id = (
                 self._waiting_kv.pop(0))
+            # A one-slot cache's extent is the family's to know: counted as
+            # this engine's own padded length.
             with self._admit_span(request_id, idx, meta["prompt_len"],
-                                  int(k.shape[3]), t_arrive, trace_id):
-                self.cache = self._insert_kv(self.cache, k, v, idx)
+                                  self.cfg.max_seq_len, t_arrive, trace_id):
+                self.cache = self._insert_row(self.cache, row, idx)
                 slot = _Slot(
                     request_id=request_id,
                     prompt_len=meta["prompt_len"],
@@ -285,13 +316,14 @@ class JaxLLMEngine:
                 with host_span("engine.prefill.dispatch"):
                     tokens = np.zeros(self.cfg.max_seq_len, np.int32)
                     tokens[: len(token_ids)] = token_ids
-                    logits, self.cache = self._prefill_one(
+                    logits, self.cache, counts = self._prefill_one(
                         self.params,
                         self.cache,
                         jnp.asarray(tokens),
                         len(token_ids),
                         idx,
                     )
+                    self._note_counts("prefill", counts)
                 with host_span("engine.sample", slots=1):
                     first = int(self._sample(logits, [(0, params)])[0])
                 self._counts["generated_tokens"] += 1
@@ -373,6 +405,9 @@ class JaxLLMEngine:
         c = self._counts
         admitted0, retired0 = c["admitted"], c["retired"]
         syncs0 = c["host_syncs"]
+        # Counts of the runs dispatched before this step: their programs
+        # will have ended when this step has read its own tokens.
+        late = len(self._unread_counts)
         with host_span("engine.step", seq=c["steps"]):
             self._admit()
             finished = self._retire()  # requests that finished at admission
@@ -387,10 +422,11 @@ class JaxLLMEngine:
                     for i, s in active:
                         tokens[i] = s.generated[-1]
                         pos[i] = s.last_pos
-                    logits, self.cache = self._decode(
+                    logits, self.cache, counts = self._decode(
                         self.params, self.cache,
                         jnp.asarray(tokens), jnp.asarray(pos),
                     )
+                    self._note_counts("decode", counts)
                 with host_span("engine.sample", slots=len(active)):
                     sampled = self._sample(
                         logits, [(i, s.params) for i, s in active])
@@ -410,7 +446,8 @@ class JaxLLMEngine:
             with host_span("engine.counts", occupied=occupied,
                            waiting=waiting, admitted=admitted,
                            retired=retired,
-                           host_syncs=c["host_syncs"] - syncs0):
+                           host_syncs=c["host_syncs"] - syncs0,
+                           **self._fold_counts(late)):
                 pass
         flight_recorder.record_llm_step(
             occupied, waiting, admitted, retired, self.cfg.max_batch_size)
@@ -442,6 +479,30 @@ class JaxLLMEngine:
         return out
 
     # ---------------------------------------------------------------- counts
+    def _note_counts(self, kind: str, counts: dict) -> None:
+        """Keep what a run of the prefill or decode program counted, and
+        start its copy to the host; nothing for a family without counts."""
+        import jax
+
+        if counts:
+            for leaf in jax.tree.leaves(counts):
+                leaf.copy_to_host_async()
+            self._unread_counts.append((kind, counts))
+
+    def _fold_counts(self, n: int) -> Dict[str, int]:
+        """Add the ``n`` oldest unread runs' counts to the totals; returns
+        what the decode steps among them counted (``engine.counts``'
+        attributes: one step late, because a step folds only runs dispatched
+        before it, whose copies have arrived: no wait, no ``host_syncs``)."""
+        decoded: Dict[str, int] = {}
+        for kind, counts in self._unread_counts[:n]:
+            for name, value in counts.items():
+                self._family_counts[kind][name] += int(value)
+                if kind == "decode":
+                    decoded[name] = decoded.get(name, 0) + int(value)
+        del self._unread_counts[:n]
+        return decoded
+
     def occupied(self) -> int:
         """Slots that hold a request right now."""
         return sum(1 for s in self.slots if s is not None)
@@ -458,9 +519,16 @@ class JaxLLMEngine:
         ``occupied_slot_steps`` sums, over steps, the slots occupied when
         the step returns: over ``steps`` it is the mean batch occupancy;
         ``host_syncs`` counts device->host token reads: one a decode step,
-        one a locally prefilled admission."""
+        one a locally prefilled admission.  What the family's programs
+        counted (an expert layer's routing) follows under the family's
+        names for decode steps and with ``prefill_`` before them for
+        prefills (runs not yet read are read here)."""
         with self.locked():
-            return dict(self._counts, occupied=self.occupied(),
+            self._fold_counts(len(self._unread_counts))  # may wait: exact
+            counted = dict(self._family_counts["decode"])
+            counted.update(("prefill_" + k, v) for k, v
+                           in self._family_counts["prefill"].items())
+            return dict(self._counts, **counted, occupied=self.occupied(),
                         waiting=self._n_waiting())
 
     def has_unfinished(self) -> bool:

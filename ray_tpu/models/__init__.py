@@ -1,5 +1,6 @@
 import dataclasses as _dataclasses
-from typing import Any as _Any, Callable as _Callable
+import functools as _functools
+from typing import Any as _Any, Callable as _Callable, Optional as _Optional
 
 from .gpt2 import (  # noqa: F401
     GPT2Config,
@@ -29,6 +30,18 @@ from .llama_decode import (  # noqa: F401
     llama_init_cache,
     llama_prefill,
 )
+from .longcat import (  # noqa: F401
+    LongcatConfig,
+    longcat_apply,
+    longcat_init,
+    longcat_loss,
+    longcat_param_axes,
+)
+from .longcat_decode import (  # noqa: F401
+    longcat_decode_step,
+    longcat_init_cache,
+    longcat_prefill,
+)
 
 
 @_dataclasses.dataclass(frozen=True)
@@ -45,8 +58,17 @@ class ModelFamily:
     loss: _Callable  # (params, tokens, cfg, mesh=None, ...) -> scalar
     param_axes: _Callable  # () -> logical sharding tree
     init_cache: _Callable  # (cfg, batch, max_len) -> cache
+    # The cache is the family's: a pytree whose every leaf has the slot
+    # (batch) axis at axis 1; nothing else of its layout is shared.  Both
+    # return (logits, cache).
     prefill: _Callable  # (params, tokens, lengths, cache, cfg)
     decode_step: _Callable  # (params, tokens, pos, cache, cfg)
+    # Optional twins that return (logits, cache, counts): a dict of int32
+    # scalars the program counted (routing choices, ...).  The LLM engine
+    # runs these where a family has them, reads the counts one step late
+    # and sums them in stats().
+    prefill_counted: _Optional[_Callable] = None
+    decode_step_counted: _Optional[_Callable] = None
 
 
 _FAMILIES = {}
@@ -107,5 +129,21 @@ register_model_family(
         init_cache=llama_init_cache,
         prefill=llama_prefill,
         decode_step=llama_decode_step,
+    ),
+)
+register_model_family(
+    LongcatConfig,
+    ModelFamily(
+        name="longcat",
+        init=longcat_init,
+        apply=longcat_apply,
+        loss=longcat_loss,
+        param_axes=longcat_param_axes,
+        init_cache=longcat_init_cache,
+        prefill=longcat_prefill,
+        decode_step=longcat_decode_step,
+        prefill_counted=_functools.partial(longcat_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            longcat_decode_step, with_counts=True),
     ),
 )

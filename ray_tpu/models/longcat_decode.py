@@ -1,0 +1,115 @@
+"""LongCat prefill + decode through the latent (MLA) cache.
+
+The cache is ONE leaf, ``{"latent": [2L, B, T, rkv+dr]}``: for every
+attention (two a double layer) and slot, each token's ``[ckv | kr]`` after
+norm, scale and rope: 576 values a token an attention at the published
+sizes, where per-head keys and values would be 64 x (192 + 128).  The slot
+axis is axis 1, as for every family's cache (``llm/engine.py`` splices rows
+there and knows nothing else of the layout).
+
+Prefill runs the expanded form (per-head keys and values rebuilt from the
+latent, dense causal scores); decode the absorbed form: ``Wkvb``'s key half
+is folded into the query and its value half applied after the weighted sum
+of latents, so a step reads ``T x 576`` values a slot an attention and never
+builds a key or a value.  Deferred-scatter protocol as in ``gpt2_decode.py``:
+the cache holds ``[0, pos-1]``, the current token's latent is merged as a
+last score, and all ``2L`` latents are written in one batched update.
+
+Both return ``(logits, cache)`` as every family's do; with
+``with_counts=True`` (the family's ``*_counted`` twins, which the engine
+runs) ``(logits, cache, counts)``: the routing counts of ``longcat.py`` as
+int32 scalars.  A decode row at position 0 is an idle slot (a prompt has at
+least one token, so a live row's position is >= 1): it chooses no expert and
+is not counted.  Prefill counts positions ``< length``.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .llama import _rmsnorm
+from .longcat import (LongcatConfig, add_counts, double_layer,
+                      longcat_forward, matmul, mla_project)
+
+
+def longcat_init_cache(cfg: LongcatConfig, batch: int, max_len: int):
+    shape = (2 * cfg.n_layer, batch, max_len, cfg.latent_dim)
+    return {"latent": jnp.zeros(shape, jnp.dtype(cfg.dtype))}
+
+
+def longcat_prefill(
+    params, tokens, lengths, cache, cfg: LongcatConfig, *,
+    with_counts: bool = False
+) -> Tuple:
+    """tokens: [B, S] right-padded prompts; lengths: [B] true lengths.
+    Returns (last_logits [B, V], cache with positions [0, S) written,
+    routing counts of the positions < length)."""
+    s = tokens.shape[1]
+    live = jnp.arange(s)[None] < lengths[:, None]
+    x, latents, counts = longcat_forward(params, tokens, live, cfg)
+    cache = {"latent": jax.lax.dynamic_update_slice(
+        cache["latent"], latents.astype(cache["latent"].dtype), (0, 0, 0, 0))}
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    logits = matmul("be,ve->bv", last, params["lm_head"])
+    out = (logits, cache)
+    return (*out, counts) if with_counts else out
+
+
+def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg: LongcatConfig):
+    """One query token a row against its slot's latents.  q [B, H, dn+dr];
+    latent_self [B, C] (the current token's); latent_cache [B, T, C] holding
+    [0, pos-1]; pos [B] -> [B, d] float32."""
+    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    w_k, w_v = att["wkv_b"][..., :dn], att["wkv_b"][..., dn:]
+    qt = matmul("bhn,chn->bhc", q[..., :dn], w_k).astype(q.dtype)
+    qc = jnp.concatenate([qt, q[..., dn:]], -1)  # [B, H, C]
+    scale = q.shape[-1] ** -0.5
+    scores = matmul("bhc,btc->bht", qc, latent_cache) * scale
+    before = jnp.arange(latent_cache.shape[1])[None, None] < pos[:, None, None]
+    scores = jnp.where(before, scores, -1e30)
+    s_self = matmul("bhc,bc->bh", qc, latent_self) * scale
+    probs = jax.nn.softmax(
+        jnp.concatenate([scores, s_self[..., None]], -1), axis=-1)
+    oc = (matmul("bht,btc->bhc", probs[..., :-1].astype(q.dtype),
+                 latent_cache[..., :rkv])
+          + probs[..., -1:] * latent_self[:, None, :rkv])
+    o = matmul("bhc,chv->bhv", oc.astype(q.dtype), w_v)
+    return matmul("bhv,hve->be", o.astype(q.dtype), att["wo"])
+
+
+def write_latents(cache_arr, new, pos):
+    """cache_arr [A, B, T, C]; new [A, B, C]; pos [B]: one masked pass over
+    the cache."""
+    at = jnp.arange(cache_arr.shape[2])[None, :] == pos[:, None]  # [B, T]
+    return jnp.where(at[None, :, :, None], new[:, :, None, :], cache_arr)
+
+
+def longcat_decode_step(
+    params, tokens, pos, cache, cfg: LongcatConfig, *,
+    with_counts: bool = False
+) -> Tuple:
+    """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
+    pos = jnp.asarray(pos)
+    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    latent_cache = cache["latent"]
+    live = pos > 0
+    new, total = [], None
+
+    def attend(att, y):
+        q, latent = mla_project(y[:, None], att, pos[:, None], cfg)
+        new.append(latent[:, 0].astype(latent_cache.dtype))
+        return mla_absorbed(q[:, 0], new[-1], latent_cache[len(new) - 1],
+                            pos, att, cfg)
+
+    for layer in range(cfg.n_layer):
+        x, counts = double_layer(x, params, layer, live, attend, cfg)
+        total = add_counts(total, counts)
+    latent_cache = write_latents(latent_cache, jnp.stack(new), pos)
+    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
+    logits = matmul("be,ve->bv", x, params["lm_head"])
+    out = (logits, {"latent": latent_cache})
+    return (*out, total) if with_counts else out

@@ -8,11 +8,17 @@ shapes throughout:
   - routing is einsum + one_hot + cumsum (no dynamic shapes — XLA-friendly);
   - dispatched token buffers are [experts, batch, capacity, model] with the
     leading axis sharded over the ``expert`` mesh axis; the dispatch and
-    combine einsums therefore lower to ``all_to_all`` over ICI;
+    combine einsums are expected to lower to ``all_to_all`` over ICI
+    (asserted, never measured on a chip);
   - per-expert FFN weights are stacked [n_experts, d_model, d_ff] and
     sharded over (``expert``, -, ``model``), so EP composes with TP;
   - a Switch-style load-balancing aux loss accumulates through the
     ``lax.scan`` over layers.
+
+Not the expert layer the LongCat family uses, and not the supported one:
+it has a capacity and drops tokens, no cache, no ``ModelFamily``, and has
+never run on a chip.  ``models/longcat.py`` holds the dropless layer that is
+told which experts it holds.
 """
 
 from __future__ import annotations

@@ -13,15 +13,13 @@ import ray_tpu.api as api
 
 
 def _session_log(name_part):
+    """A log of THIS test's cluster: the newest session directory under
+    /tmp/ray_tpu may be another xdist worker's, started a moment ago."""
     import glob
-    import tempfile
 
-    base = os.path.join(tempfile.gettempdir(), "ray_tpu")
-    sessions = sorted(glob.glob(os.path.join(base, "session_*")),
-                      key=os.path.getmtime, reverse=True)
-    assert sessions
-    logs = glob.glob(os.path.join(sessions[0], f"*{name_part}*.log"))
-    assert logs, f"no {name_part} log in {sessions[0]}"
+    session = api._local_node.log_dir
+    logs = glob.glob(os.path.join(session, f"*{name_part}*.log"))
+    assert logs, f"no {name_part} log in {session}"
     return max(logs, key=os.path.getmtime)
 
 

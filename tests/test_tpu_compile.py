@@ -107,3 +107,31 @@ def test_decode_kernel_refused_for_vmem_at_tinyllama_shape(
     args = _decode_args(22, 32, 32, 8, 2048, 64, one_chip)
     with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
         _lower_decode(args).compile()
+
+
+def test_longcat_decode_step_fits_beside_its_weights(one_chip):
+    """The LongCat decode step at the benchmark cell's size (published
+    widths, 4 double layers, 16 experts held, 32 slots x 2048) compiles for
+    the v5e with temporaries far under the weights it reads: a layer's slice
+    of the stacked experts taken outside the expert loop, or a layer's
+    weights sliced first and indexed later, is COPIED every step (4.6 GB and
+    3.6 GB, PERF.md PR 29) and the compiler then refuses the program beside
+    11 GB of arguments."""
+    from ray_tpu.models import LongcatConfig, longcat_init, model_family
+
+    cfg = LongcatConfig(n_layer=4, experts_held=16, vocab_size=16384)
+    fam = model_family(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: longcat_init(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: fam.init_cache(cfg, 32, 2048)))
+    rows = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, t, pos: fam.decode_step_counted(
+        p, t, pos, c, cfg), donate_argnums=(1,))
+    memory = step.lower(params, cache, rows, rows).compile().memory_analysis()
+    assert memory.argument_size_in_bytes > 10.9e9
+    assert memory.temp_size_in_bytes < 1.0e9  # 0.69 GB: one pass of the cache
