@@ -17,15 +17,23 @@ routing counts of an expert layer) has them returned by the same two
 programs, read ONE STEP LATE (so a step still waits for the device once),
 summed in ``stats()`` and written on ``engine.counts``.
 
-Shapes are static (max_batch_size × max_seq_len), so XLA compiles prefill,
-decode and two samplers over the decode step's ``[max_batch_size, V]``
-logits: ``sample_logits_greedy`` when every active slot has temperature 0,
-``sample_logits_rows`` otherwise (both also at ``[1, V]``, for a prefill's
-first token).  Sampling parameters reach the sampler as per-row ARRAYS, so a
-new ``SamplingParams`` value compiles nothing; a step reads its tokens from
-the device ONCE, whatever the number of slots.  At ``temperature > 0`` the
-draws for a given ``seed`` differ from versions that split one key per slot
-on the host: the key is now split once a step, inside the program.
+Shapes are static.  The cache is max_batch_size × max_seq_len, and so is
+the decode step.  Prefill runs at the PROMPT's length, not the cache's: one
+request a call, padded to the smallest rung of ``prefill_ladder(max_seq_len)``
+(256, 512, 1024, … and ``max_seq_len`` itself) that holds it, and the row it
+writes covers positions ``[0, rung)`` of the slot.  Every rung is one
+compilation of the same ``prefill_one``, made when the engine is BUILT
+(ahead of time, in threads, while the weights load), so no prompt length
+meets a compiler later; an engine of ``max_seq_len <= 256`` has one rung.
+The decode step and two samplers over its ``[max_batch_size, V]`` logits
+compile at the first request: ``sample_logits_greedy`` when every active
+slot has temperature 0, ``sample_logits_rows`` otherwise (both also at
+``[1, V]``, for a prefill's first token).  Sampling parameters reach the
+sampler as per-row ARRAYS, so a new ``SamplingParams`` value compiles
+nothing; a step reads its tokens from the device ONCE, whatever the number
+of slots.  At ``temperature > 0`` the draws for a given ``seed`` differ from
+versions that split one key per slot on the host: the key is now split once
+a step, inside the program.
 
 Program names are a contract too: the benchmark's readers find the decode
 program as the only ``jit__lambda`` and the samplers by ``jit_sample_logits``,
@@ -43,11 +51,13 @@ step feeds ``flight_recorder.record_llm_step`` (``/metrics``).
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import dataclasses
 import itertools
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -126,10 +136,37 @@ def _drop_queued(queue: List[tuple], request_id: int) -> int:
     return dropped
 
 
+# The shortest prefill rung.  With bf16 weights a prefill of S tokens does S
+# FLOP for every byte of weights it reads, and the v5e's peaks cross at
+# 197e12 FLOP/s / 819e9 B/s = 240: below ~256 tokens a prefill costs the
+# weights' read whatever its length, so a rung there buys a compilation and
+# no time.
+MIN_PREFILL_RUNG = 256
+
+
+def prefill_ladder(max_seq_len: int) -> List[int]:
+    """The padded lengths prefill is compiled at, a pure function of
+    ``max_seq_len``: 256 x 2**k for every such value below it, then
+    ``max_seq_len`` itself.  A prompt runs at the smallest rung that holds
+    it (``prefill_rung``)."""
+    rungs = []
+    rung = MIN_PREFILL_RUNG
+    while rung < max_seq_len:
+        rungs.append(rung)
+        rung *= 2
+    return rungs + [max_seq_len]
+
+
+def prefill_rung(rungs: List[int], n_tokens: int) -> int:
+    """The smallest of the ascending ``rungs`` that is >= ``n_tokens``."""
+    return rungs[bisect.bisect_left(rungs, n_tokens)]
+
+
 def splice_row(cache, row, idx):
-    """Write a one-slot cache ``row`` into slot ``idx`` of ``cache``: the
-    one thing known of a family's cache is that every leaf's slot axis is
-    axis 1."""
+    """Write a one-slot cache ``row`` into slot ``idx`` of ``cache``, from
+    position 0 on (a row shorter than the slot leaves the slot's tail as it
+    was): the one thing known of a family's cache is that every leaf's slot
+    axis is axis 1."""
     import jax
 
     def put(whole, one):
@@ -188,17 +225,37 @@ class JaxLLMEngine:
                        or _without_counts(fam.decode_step))
 
         def prefill_one(params, cache, tokens, length, slot_idx):
-            """Prefill a single request into batch row ``slot_idx``."""
+            """Prefill a single request, padded to ``tokens``' length (a
+            rung), into positions ``[0, rung)`` of batch row ``slot_idx``.
+            What the slot's last tenant left beyond the rung stays: decode
+            reads nothing at or beyond a slot's ``pos``."""
             import jax.numpy as jnp
 
-            one_cache = fam.init_cache(mcfg, 1, cfg.max_seq_len)
+            one_cache = fam.init_cache(mcfg, 1, tokens.shape[0])
             logits, one_cache, counts = prefill(
                 params, tokens[None], jnp.asarray([length]), one_cache, mcfg
             )
             # [1, V]: a batch of one for the sampler
             return logits, splice_row(cache, one_cache, slot_idx), counts
 
-        self._prefill_one = jax.jit(prefill_one, donate_argnums=(1,))
+        # ONE named function under one jit (the program's name is what the
+        # benchmark's reader finds), compiled ahead of time once a rung: on
+        # shapes alone, so beside the weights' load (a jitted loader returns
+        # before the device has run it), and in threads, because XLA
+        # compiles outside the GIL.  ``_admit`` calls these executables.
+        self._prefill_rungs = prefill_ladder(cfg.max_seq_len)
+        jitted = jax.jit(prefill_one, donate_argnums=(1,))
+        scalar = jax.ShapeDtypeStruct((), np.int32)
+
+        def compile_rung(rung: int):
+            tokens = jax.ShapeDtypeStruct((rung,), np.int32)
+            return jitted.lower(
+                self.params, self.cache, tokens, scalar, scalar).compile()
+
+        with ThreadPoolExecutor(len(self._prefill_rungs)) as pool:
+            self._prefill_one = dict(zip(
+                self._prefill_rungs,
+                pool.map(compile_rung, self._prefill_rungs)))
         # Disaggregated admission: the one-slot cache arrives from a prefill
         # replica instead of the local prefill program.
         self._insert_row = jax.jit(splice_row, donate_argnums=(0,))
@@ -309,19 +366,20 @@ class JaxLLMEngine:
                 return
             request_id, token_ids, params, t_arrive, trace_id = (
                 self._waiting.pop(0))
+            rung = prefill_rung(self._prefill_rungs, len(token_ids))
             with self._admit_span(request_id, idx, len(token_ids),
-                                  self.cfg.max_seq_len, t_arrive, trace_id):
+                                  rung, t_arrive, trace_id):
                 # Returns before the device finishes: the wait for the
                 # prefill program shows in the sample span that follows.
                 with host_span("engine.prefill.dispatch"):
-                    tokens = np.zeros(self.cfg.max_seq_len, np.int32)
+                    tokens = np.zeros(rung, np.int32)
                     tokens[: len(token_ids)] = token_ids
-                    logits, self.cache, counts = self._prefill_one(
+                    logits, self.cache, counts = self._prefill_one[rung](
                         self.params,
                         self.cache,
                         jnp.asarray(tokens),
-                        len(token_ids),
-                        idx,
+                        np.int32(len(token_ids)),
+                        np.int32(idx),
                     )
                     self._note_counts("prefill", counts)
                 with host_span("engine.sample", slots=1):
