@@ -37,7 +37,7 @@ def train_loop(config: dict) -> None:
 
     import ray_tpu.train as train
     from benchmarks.lib.device import device_memory
-    from benchmarks.lib.trace_reduce import start_device_trace
+    from ray_tpu.util.tracing import start_profile, stop_profile
 
     fam = importlib.import_module("benchmarks.families." + config["family"])
     phases, memory_at = {}, {}
@@ -137,7 +137,7 @@ def train_loop(config: dict) -> None:
         del local
     phase("reference_check")
 
-    losses, step_ms, report_ms, input_ms = [], [], [], []
+    losses, step_ms, input_ms = [], [], []
     for _ in range(config["warmup_steps"]):
         params, opt_state, loss, _stop = compiled(
             params, opt_state, tokens, votes)
@@ -158,7 +158,7 @@ def train_loop(config: dict) -> None:
     n = 0
     while True:
         if trace_dir and n == TRACE_FROM_STEP:
-            start_device_trace(os.path.join(trace_dir, f"rank{rank}"))
+            start_profile(os.path.join(trace_dir, f"rank{rank}"))
             tracing = True
         t0 = time.perf_counter()
         stop_vote = t0 - t_window >= config["seconds"]
@@ -170,14 +170,12 @@ def train_loop(config: dict) -> None:
         t2 = time.perf_counter()
         loss_f, stop_now = float(loss), bool(int(stop))
         train.report({"step": n, "loss": loss_f})
-        t3 = time.perf_counter()
         n += 1
         losses.append(loss_f)
         input_ms.append((t1 - t0) * 1e3)
         step_ms.append((t2 - t1) * 1e3)
-        report_ms.append((t3 - t2) * 1e3)
         if tracing and (n == TRACE_FROM_STEP + trace_steps or stop_now):
-            jax.profiler.stop_trace()
+            stop_profile()
             tracing = False
         if stop_now:
             break
@@ -188,7 +186,7 @@ def train_loop(config: dict) -> None:
         "rank": rank, "world": world, "t_enter": t_enter,
         "t_window_wall": t_window_wall, "elapsed_s": elapsed, "steps": n,
         "tokens_per_step": B * S, "losses": losses, "n_warm": n_warm,
-        "step_ms": step_ms, "report_ms": report_ms, "input_ms": input_ms,
+        "step_ms": step_ms, "input_ms": input_ms,
         "compile_s": compile_s, "prog_loss": prog_loss, "ref_loss": ref_loss,
         "pallas_calls": text.count("tpu_custom_call"),
         "all_reduces": text.count("all-reduce"),
@@ -303,8 +301,8 @@ def run(job) -> dict:
         # What the per-layer readers may read (host clock, benchmark's own).
         "stats": {
             "gang_ready_s": r0["t_enter"] - t_fit,
-            "step_ms": r0["step_ms"], "report_ms": r0["report_ms"],
-            "input_ms": r0["input_ms"], "steps": r0["steps"],
+            "step_ms": r0["step_ms"], "input_ms": r0["input_ms"],
+            "steps": r0["steps"],
             "traced_steps": r0["traced_steps"],
             "rows_per_chip": size["global_batch"] // job.chips,
             "seq": size["seq"], "model": model,

@@ -1,18 +1,16 @@
 """The serving cells' deployment and weights, from the benchmark's own files.
 
 ``BenchLLMServer`` is ``ray_tpu.llm``'s ``LLMServer`` by subclassing: the
-request path is the program's, untouched.  It adds what the program does not
-offer yet (PERF.md lists each as a hook for the ``tracing`` issue):
+request path is the program's, untouched, and the job traces it and reads its
+engine's counters through ``LLMServer``'s own ``start_profile`` /
+``stop_profile`` / ``engine_stats``.  It adds what the program does not offer:
 
 * a tokenizer whose ``decode`` renders every id as one visible character, so
   that each generated token reaches the client (``ByteTokenizer.decode`` drops
   ids >= 256: with random weights over 32,768 rows nearly all of them);
-* ``start_trace`` / ``stop_trace`` (only the process that holds the chip can
-  trace it);
 * ``check_reference``: prefill then decode through a cache against the plain
   float32 forward, on the live weights;
-* counters read around the engine's public ``step()``: slots occupied,
-  compilations, the device's memory counters.
+* ``counters``: compilations in this process, the device's memory counters.
 
 The weights come from the family's ``load_params`` (``families/<family>.py``)
 through ``EngineConfig.param_loader``.
@@ -21,8 +19,6 @@ through ``EngineConfig.param_loader``.
 from __future__ import annotations
 
 import importlib
-import threading
-import time
 
 from benchmarks.lib.device import device_memory
 from ray_tpu.llm.serve_app import LLMServer as _LLMServerDeployment
@@ -108,50 +104,11 @@ class BenchLLMServer(_LLMServer):
         jax.monitoring.register_event_duration_secs_listener(on_duration)
         super().__init__(engine_cfg, model_name)
         self.engine.tokenizer = VisibleTokenizer()
-        # Counted around the engine's public step(), which every route goes
-        # through today (stream_request and generate call self.step()): the
-        # slots occupied when a step returns.  An engine without these two
-        # names is another engine; say so here, not by a wrong number later.
-        engine = self.engine
-        if not callable(getattr(engine, "step", None)) or not isinstance(
-                getattr(engine, "slots", None), list):
-            raise RuntimeError(
-                "BenchLLMServer counts occupied slots around engine.step() "
-                "and reads engine.slots; this engine has not both. "
-                "active_slots_mean.serve needs a benchmark PR.")
-        self._active_hist = {}
-        self._count_lock = threading.Lock()
-        step = engine.step
 
-        def counted_step():
-            out = step()
-            n = sum(1 for s in engine.slots if s is not None)
-            with self._count_lock:
-                self._active_hist[n] = self._active_hist.get(n, 0) + 1
-            return out
-
-        engine.step = counted_step
-
-    def counters(self, reset: bool = False) -> dict:
-        with self._count_lock:
-            hist = dict(self._active_hist)
-            if reset:
-                self._active_hist.clear()
-        return {"active_hist": hist, "compiles": self._compiles,
+    def counters(self) -> dict:
+        return {"compiles": self._compiles,
                 "memory_at_start": self._memory_at_start,
                 "memory_stats": device_memory()}
-
-    def start_trace(self, path: str) -> float:
-        from benchmarks.lib.trace_reduce import start_device_trace
-
-        start_device_trace(path)
-        return time.time()
-
-    def stop_trace(self) -> float:
-        import jax
-
-        jax.profiler.stop_trace()
-        return time.time()
 
     def check_reference(self, seed: int, prompt_len: int = 64,
                         steps: int = 3, layers: int = 2) -> dict:

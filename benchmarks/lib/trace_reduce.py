@@ -25,18 +25,6 @@ WRAPPERS = re.compile(r"^(while|conditional|call)([.\d]*)$")
 _SUFFIX = re.compile(r"(\(\d+\)|(?<=\D)[.\d]+)(?= |$)")
 
 
-def start_device_trace(path: str) -> None:
-    """Trace the device, and as little of the host as the profiler allows:
-    its Python tracer (on by default) doubled the time of a host-bound
-    serving step (PR 24), and nothing here reads the host's planes."""
-    import jax
-
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 1
-    jax.profiler.start_trace(path, profiler_options=options)
-
-
 def short_name(name: str) -> str:
     """An ``XLA Ops`` event is named by its whole HLO line,
     ``%fusion.3 = bf16[..] fusion(...), custom_call_target="tpu_custom_call"``.

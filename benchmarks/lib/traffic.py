@@ -1,10 +1,13 @@
 """The one traffic generator: a mix's data file + a seed -> the requests.
 
 A pure function of ``(mix, seed)``.  The *sizes* of a mix are a fixed
-population drawn once from ``population_seed``; ``seed`` only orders them and
-fills in the prompt bytes.  So every seed offers the same multiset of prompt
-and output lengths in another order, and two seeds differ no more than two
-runs of one seed.  Clients walk the list round and round.
+population drawn once from ``population_seed``, in a fixed order; ``seed``
+only says where in that round the clients start, and fills in the prompt
+bytes.  Clients walk the list round and round, so every seed offers the same
+lengths in the same cyclic order.  The order is part of the work: shuffled by
+the seed (until PR 32) it set a window's tails, and two seeds differed by
+five to ten times what two runs of one seed do (which long prompts fall into
+the same lock round; PERF.md PR 32).
 """
 
 from __future__ import annotations
@@ -39,6 +42,7 @@ def requests(mix: dict, seed: int) -> List[dict]:
     BOS, so a prompt of n tokens is n - 1 ASCII characters."""
     rng = random.Random(seed)
     pop = sizes(mix)
-    rng.shuffle(pop)
+    start = rng.randrange(len(pop))
+    pop = pop[start:] + pop[:start]
     return [{"prompt": _text(n_prompt - 1, rng), "prompt_tokens": n_prompt,
              "max_tokens": n_out} for n_prompt, n_out in pop]

@@ -5,7 +5,9 @@
 
 * every name in BENCHMARK.json resolves to its files, readers and job kind;
 * the traffic generator is a pure function of the seed, and every seed offers
-  the same sizes in another order;
+  the same sizes in the same cyclic order;
+* the serving window's arithmetic (``tests/test_bench_window.py``, under
+  pytest: lead-in, window edges, percentiles);
 * the trace reduction gives the pinned busy / idle / kernel numbers on the
   small recorded trace (``lib/trace_sample.json``, a slice of a chip trace),
   and agrees with a brute-force count;
@@ -73,6 +75,14 @@ def test_traffic_is_a_function_of_the_seed():
     assert all(lo <= r["prompt_tokens"] <= hi
                and len(r["prompt"].encode()) == r["prompt_tokens"] - 1
                for r in a)
+
+
+def test_window_arithmetic():
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", os.path.join(HERE, "tests"), "-q",
+         "-p", "no:cacheprovider"], capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout[-2000:]
+    print("   ", out.stdout.strip().splitlines()[-1])
 
 
 def brute_busy(events):
@@ -206,7 +216,8 @@ def test_rehearsals():
 
 if __name__ == "__main__":
     tests = [test_files_resolve, test_traffic_is_a_function_of_the_seed,
-             test_trace_reduction, test_module_reader_wants_one_program,
+             test_window_arithmetic, test_trace_reduction,
+             test_module_reader_wants_one_program,
              test_references_agree_with_the_program]
     if "--rehearse" in sys.argv:
         tests.append(test_rehearsals)
