@@ -1078,12 +1078,6 @@ def run_control_plane_suite():
             pool_depth_after=depth,
         )
 
-        # The LLM serving A/B moved to its own suite (`bench.py
-        # llm_load` -> ray_tpu.llm.bench_llm): mono vs disagg-batched
-        # is measured there interleaved in ONE window under
-        # concurrent load, next to the llm_load high-QPS stage.
-
-
         # wait over 1k in-flight task refs, popped one wait() at a time as
         # they complete — the reference's wait_multiple_refs shape
         # (ray_perf.py:159: submit 1000 small_value tasks, then loop
@@ -1730,36 +1724,6 @@ def run_collective_suite(quick=False):
         raise RuntimeError(
             f"bench_collective exited {proc.returncode}: "
             f"{proc.stderr[-2000:]}"
-        )
-
-
-# ------------------------------------------------- llm serving suite
-
-def run_llm_suite(quick=False):
-    """Continuous-batching LLM serving stages (ray_tpu.llm.bench_llm).
-
-    ``llm_disagg_vs_mono_speedup`` is the serving-pattern gate: mono vs
-    prefill/decode + continuous-batching decode, both arms driven by the
-    same concurrent repeat-traffic stream and ALTERNATING back-to-back
-    inside one window (best-of-N, per-arm spread recorded — this box
-    swings ~2x window-to-window).  ``llm_load_*`` rows come from the
-    high-QPS harness, whose p99 inter-token-stall bound and
-    occupancy > 1 are asserted INSIDE the stage (a violation fails the
-    subprocess and this suite)."""
-    rows, proc = _bench_subprocess("ray_tpu.llm.bench_llm", "llm", quick)
-    for row in rows:
-        metric = row.pop("metric")
-        value = row.pop("value")
-        unit = (
-            "x" if metric.endswith("_speedup")
-            else "req/s" if metric.endswith("_per_s")
-            else "s" if metric.endswith("_s")
-            else "count"
-        )
-        emit(metric, value, unit, **row)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"bench_llm exited {proc.returncode}: {proc.stderr[-2000:]}"
         )
 
 
@@ -2501,8 +2465,6 @@ def main():
             run("collective", lambda: run_collective_suite(quick=quick))
         if only in ("all", "rl"):
             run("rl", lambda: run_rl_suite(quick=quick))
-        if only in ("all", "llm", "llm_load"):
-            run("llm_load", lambda: run_llm_suite(quick=quick))
         if only in ("all", "scaling"):
             run("scaling", run_scaling_suite)
         if only in ("all", "model"):

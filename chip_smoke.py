@@ -514,8 +514,8 @@ def run(args) -> dict:
         one, phase_train(sz, args.seed, num_workers=4, chips_per_worker=1),
         "four workers x one chip")
     wait_chips_free(4)
-    # Two slots bound the decode role's bucket compiles; batch size does
-    # not enter a greedy result.
+    # Two slots keep the replicas' caches small; batch size does not enter
+    # a greedy result.
     mono = phase_serve(sz, args.seed, max_batch=2)
     wait_chips_free(4)
     disagg = phase_serve_disagg(sz, args.seed, max_batch=2)

@@ -2,8 +2,9 @@
 
 Reference: ray ``python/ray/llm/`` — there a vLLM engine wrapper + OpenAI
 server + batch processors; here the engine itself is TPU-native JAX
-(KV-cache continuous batching over the GPT-2 family), the server is a
-Serve app, and batch inference rides the Data layer's actor pools.
+(``JaxLLMEngine``: a fixed pool of cache slots over any registered model
+family), the server is a Serve app, and batch inference rides the Data
+layer's actor pools.
 """
 
 from .engine import EngineConfig, JaxLLMEngine, SamplingParams  # noqa: F401
@@ -15,9 +16,4 @@ from .disagg import (  # noqa: F401
     DisaggRouter,
     PrefillEngine,
     PrefillReplica,
-)
-from .continuous_batching import (  # noqa: F401
-    BatchedDecodeReplica,
-    ContinuousBatchingConfig,
-    ContinuousBatchingEngine,
 )

@@ -659,6 +659,31 @@ class JaxLLMEngine:
             # Timeout or abandoned consumer: release the slot/queue entry.
             self.cancel_request(request_id)
 
+    def wait(self, request_ids: List[int],
+             timeout_s: float = 300.0) -> List[dict]:
+        """Step the engine, one lock turn at a time, until every one of
+        THIS caller's ``request_ids`` has finished; returns their results in
+        that order.  Other callers' in-flight work (streams, other waits)
+        shares the steps and delays nothing here.  Past ``timeout_s`` the
+        requests are cancelled (slots and queue entries freed) and
+        ``TimeoutError`` is raised.  With ``stream_request``, one of the two
+        bodies that step the engine."""
+        def done() -> bool:
+            return all(i in self._finished for i in request_ids)
+
+        deadline = time.monotonic() + timeout_s
+        while True:
+            with self.locked():
+                if not done():
+                    self.step()
+                if done():
+                    return [self._finished.pop(i) for i in request_ids]
+            if time.monotonic() > deadline:
+                for i in request_ids:
+                    self.cancel_request(i)
+                raise TimeoutError(
+                    f"requests {list(request_ids)} exceeded {timeout_s} s")
+
     def generate(
         self,
         prompts: List[str],
@@ -666,16 +691,6 @@ class JaxLLMEngine:
         timeout_s: float = 300.0,
     ) -> List[dict]:
         """Blocking batch generation (requests stream through the slot pool
-        regardless of len(prompts) vs max_batch_size).  Returns as soon as
-        THIS call's requests are done — a concurrent caller's in-flight
-        work must not delay this caller's results (every caller used to
-        spin until the whole engine drained)."""
-        ids = [self.add_request(p, params) for p in prompts]
-        deadline = time.monotonic() + timeout_s
-        while True:
-            with self.locked():
-                if all(i in self._finished for i in ids):
-                    return [self._finished.pop(i) for i in ids]
-                self.step()
-            if time.monotonic() > deadline:
-                raise TimeoutError("generation exceeded timeout")
+        regardless of len(prompts) vs max_batch_size)."""
+        return self.wait(
+            [self.add_request(p, params) for p in prompts], timeout_s)

@@ -4,7 +4,7 @@ Reference: ray ``python/ray/llm/_internal/serve/core/server/`` (the
 OpenAI-compatible router over vLLM deployments) and ``serve/llm``'s
 ``build_openai_app``.  The deployment holds one ``JaxLLMEngine`` per
 replica (one chip each via ``num_tpus=1``); ``@serve.batch`` coalesces
-concurrent single-prompt calls so they enter the engine's continuous batch
+concurrent single-prompt calls so they enter the engine's slot pool
 together.  Endpoints: ``/v1/completions`` and ``/v1/chat/completions``
 via the serve HTTP proxy (the raw JSON body arrives as the call's single
 argument).
@@ -41,18 +41,14 @@ class LLMServer:
     @serve.batch(max_batch_size=8, batch_wait_timeout_s=0.02)
     async def _generate_batch(self, requests: List[tuple]):
         """requests: [(prompt, SamplingParams)] — one engine pass serves
-        them all (the engine's slot pool IS the batch).  All engine-state
-        access holds the engine lock: SSE streams may be stepping the same
-        engine from replica threads concurrently."""
-        ids = [
-            self.engine.add_request(prompt, params)
-            for prompt, params in requests
-        ]
-        while True:
-            with self.engine.locked():
-                if all(i in self.engine._finished for i in ids):
-                    return [self.engine._finished.pop(i) for i in ids]
-                self.engine.step()
+        them all (the engine's slot pool IS the batch), beside whatever SSE
+        streams are stepping the same engine from replica threads.  No
+        deadline of its own: the handle's caller has one, and a request ends
+        at ``max_tokens``."""
+        return self.engine.wait(
+            [self.engine.add_request(prompt, params)
+             for prompt, params in requests],
+            timeout_s=float("inf"))
 
     async def __call__(self, body: Dict[str, Any]):
         """OpenAI completions-ish: dispatch on request shape.  With
@@ -121,25 +117,23 @@ class LLMServer:
 
 @serve.deployment(name="LLMDisaggServer", ray_actor_options={"num_cpus": 0})
 class LLMDisaggServer:
-    """OpenAI endpoints over the disaggregated continuous-batching path.
+    """OpenAI endpoints over the disaggregated path.
 
-    One replica of this deployment owns a prefill pool + a
-    continuous-batching decode pool (``llm.continuous_batching.
-    BatchedDecodeReplica``) and routes through ``DisaggRouter`` with
-    prefix-cache-aware decode routing.  Streaming requests flow proxy →
-    this replica (``serve.request.stream`` span) → prefill actor → decode
-    actor, each hop inheriting the request's trace context, so one
-    stitched cluster trace (returned in ``x-ray-tpu-trace-id``) covers
-    the whole batched streaming request."""
+    One replica of this deployment owns a prefill pool + a decode pool
+    (``llm.disagg.PrefillReplica`` / ``DecodeReplica`` actors) and routes
+    through ``DisaggRouter``.  Streaming requests flow proxy → this replica
+    (``serve.request.stream`` span) → prefill actor → decode actor, each
+    hop inheriting the request's trace context, so one stitched cluster
+    trace (returned in ``x-ray-tpu-trace-id``) covers the whole streaming
+    request."""
 
     def __init__(self, engine_cfg: Optional[EngineConfig] = None,
                  model_name: str = "ray-tpu-gpt2",
                  num_prefill: int = 1, num_decode: int = 1,
-                 cb_cfg=None, num_cpus_per_replica: float = 0.0,
+                 num_cpus_per_replica: float = 0.0,
                  num_tpus_per_replica: float = 0):
         import ray_tpu
-        from .continuous_batching import BatchedDecodeReplica
-        from .disagg import DisaggRouter, PrefillReplica
+        from .disagg import DecodeReplica, DisaggRouter, PrefillReplica
 
         from .tokenizer import ByteTokenizer
 
@@ -156,22 +150,13 @@ class LLMDisaggServer:
         if num_tpus_per_replica:
             opts["num_tpus"] = num_tpus_per_replica
         Pre = ray_tpu.remote(**opts)(PrefillReplica)
-        # max_concurrency is load-bearing: run()/run_stream() calls park
-        # on per-request events while the resident loop decodes; a slot-
-        # starved decode actor would serialize its clients.
-        Dec = ray_tpu.remote(max_concurrency=64, **opts)(BatchedDecodeReplica)
+        # max_concurrency is load-bearing: concurrent run()/run_stream()
+        # calls take turns stepping one shared engine, so their requests
+        # share its decode batch; on an exclusive actor each would decode
+        # alone.
+        Dec = ray_tpu.remote(max_concurrency=64, **opts)(DecodeReplica)
         self._prefill = [Pre.remote(engine_cfg) for _ in range(num_prefill)]
-        self._decode = [
-            Dec.remote(engine_cfg, cb_cfg) for _ in range(num_decode)
-        ]
-        # Fire-and-forget bucket pre-compile: on a loaded box the full
-        # warm can take minutes, and blocking THIS replica's constructor
-        # or health checks on it makes the serve reconciler strike out a
-        # merely-compiling replica (kill → fresh children → more compile
-        # load — a death spiral).  Early requests may pay an on-demand
-        # bucket compile instead; the refs are kept so the work isn't
-        # cancelled.
-        self._warm_refs = [d.warm.remote() for d in self._decode]
+        self._decode = [Dec.remote(engine_cfg) for _ in range(num_decode)]
         self.router = DisaggRouter(self._prefill, self._decode)
 
     def __call__(self, body: Dict[str, Any]):
@@ -200,21 +185,15 @@ class LLMDisaggServer:
         )
 
     def stats(self) -> Dict[str, Any]:
+        """Each decode replica's ``JaxLLMEngine.stats()``."""
         import ray_tpu
 
-        return {
-            "router": {"hits": self.router.router_hits,
-                       "misses": self.router.router_misses},
-            "decode": [
-                ray_tpu.get(d.stats.remote(), timeout=30)
-                for d in self._decode
-            ],
-        }
+        return {"decode": ray_tpu.get(
+            [d.stats.remote() for d in self._decode], timeout=30)}
 
     def check_health(self):
-        # Deliberately does NOT round-trip to the child actors: a decode
-        # replica busy with a bucket compile holds its executor for tens
-        # of seconds, and a blocking probe here would convert "compiling"
+        # Deliberately does NOT round-trip to the child actors: one still
+        # building its engine (weights, compiles) would turn "starting"
         # into health strikes against THIS replica (the reconciler would
         # kill it and orphan the children).  Child failures surface as
         # request errors instead.
@@ -308,17 +287,16 @@ def build_disagg_openai_app(
     model_name: str = "ray-tpu-gpt2",
     num_prefill: int = 1,
     num_decode: int = 1,
-    cb_cfg=None,
     num_tpus: float = 0,
 ):
-    """OpenAI app over the prefill/decode + continuous-batching path;
-    expose via ``serve.run`` + ``serve.start_http_proxy`` like
-    ``build_openai_app`` (same ``/v1`` endpoints, ``stream: true``
-    SSE included).  ``num_tpus`` chips go to EACH prefill and decode
-    actor (the router replica itself stays off the chip)."""
+    """OpenAI app over the prefill/decode disaggregated path; expose via
+    ``serve.run`` + ``serve.start_http_proxy`` like ``build_openai_app``
+    (same ``/v1`` endpoints, ``stream: true`` SSE included).  ``num_tpus``
+    chips go to EACH prefill and decode actor (the router replica itself
+    stays off the chip)."""
     d = LLMDisaggServer.options(route_prefix="/v1")
     return d.bind(
-        engine_cfg, model_name, num_prefill, num_decode, cb_cfg,
+        engine_cfg, model_name, num_prefill, num_decode,
         num_tpus_per_replica=num_tpus,
     )
 

@@ -123,7 +123,6 @@ from .metric_registry import (  # noqa: F401 — re-exports
     LLM_BATCH_BUCKET,
     LLM_BATCH_OCCUPANCY,
     LLM_DECODE_STEPS_TOTAL,
-    LLM_PREEMPTIONS_TOTAL,
     LLM_PREFIX_CACHE_HITS_TOTAL,
     LLM_PREFIX_CACHE_MISSES_TOTAL,
     LLM_QUEUE_DEPTH,
@@ -691,8 +690,7 @@ def record_serve_request(deployment: str, replica: str, queue_wait_s: float,
                          streaming: bool = False) -> None:
     """One completed serving request on a replica: queue wait (arrival →
     user-concurrency slot) and time-to-first-result (the full latency for
-    unary requests, the first chunk for streams).  These are the signals
-    the continuous-batching serving gate (ROADMAP item 5) reports on."""
+    unary requests, the first chunk for streams)."""
     if not GlobalConfig.enable_flight_recorder:
         return
     tags = {"deployment": deployment, "replica": replica}
@@ -848,12 +846,12 @@ def record_elastic_resize(direction: str) -> None:
     counter(TRAIN_ELASTIC_RESIZES_TOTAL, 1.0, {"direction": direction})
 
 
-# ------------------------------------------ continuous-batching LLM serving
+# ------------------------------------------------ LLM serving (JaxLLMEngine)
 def record_llm_step(occupancy: int, queue_depth: int, admitted: int,
                     retired: int, bucket: int) -> None:
-    """One token boundary + decode step of the continuous-batching
-    scheduler: batch occupancy / bucket / queue-depth gauges plus the
-    per-step admission/retirement counters (docs/llm_serving.md)."""
+    """One ``JaxLLMEngine`` step: occupied-slot / batch-size / queue-depth
+    gauges plus the step's admission/retirement counters
+    (docs/llm_serving.md)."""
     if not GlobalConfig.enable_flight_recorder:
         return
     entries = [
@@ -871,14 +869,10 @@ def record_llm_step(occupancy: int, queue_depth: int, admitted: int,
     _metrics._record_batch(entries)
 
 
-def record_llm_preemption() -> None:
-    counter(LLM_PREEMPTIONS_TOTAL, 1.0)
-
-
 def record_llm_prefix_lookup(site: str, hit: bool, n: int = 1) -> None:
-    """Prefix-KV cache accounting, by lookup site (``engine`` = full-
-    coverage admission reuse on a decode replica, ``router`` = affinity
-    decisions on the request router)."""
+    """Prefix-affinity accounting, by lookup site (``router`` = the serve
+    ``PrefixAwareRouter``'s affinity decisions; no engine reuses a prefix's
+    KV yet, ROADMAP W12)."""
     counter(
         LLM_PREFIX_CACHE_HITS_TOTAL if hit else LLM_PREFIX_CACHE_MISSES_TOTAL,
         float(n), {"site": site},

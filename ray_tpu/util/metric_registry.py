@@ -38,14 +38,13 @@ SERVE_AUTOSCALE_EVENTS_TOTAL = "ray_tpu_serve_autoscale_events_total"
 SERVE_REPLICAS = "ray_tpu_serve_replicas"
 SERVE_MUX_CACHE_EVENTS_TOTAL = "ray_tpu_serve_mux_cache_events_total"
 
-# ------------------------------------------- continuous-batching LLM serving
+# ------------------------------------------------ LLM serving (JaxLLMEngine)
 LLM_BATCH_OCCUPANCY = "ray_tpu_llm_batch_occupancy"
 LLM_BATCH_BUCKET = "ray_tpu_llm_batch_bucket"
 LLM_QUEUE_DEPTH = "ray_tpu_llm_queue_depth"
 LLM_DECODE_STEPS_TOTAL = "ray_tpu_llm_decode_steps_total"
 LLM_ADMITTED_TOTAL = "ray_tpu_llm_admitted_total"
 LLM_RETIRED_TOTAL = "ray_tpu_llm_retired_total"
-LLM_PREEMPTIONS_TOTAL = "ray_tpu_llm_preemptions_total"
 LLM_PREFIX_CACHE_HITS_TOTAL = "ray_tpu_llm_prefix_cache_hits_total"
 LLM_PREFIX_CACHE_MISSES_TOTAL = "ray_tpu_llm_prefix_cache_misses_total"
 
@@ -216,22 +215,20 @@ METRICS: Dict[str, str] = {
     SERVE_MUX_CACHE_EVENTS_TOTAL: "multiplexed model-cache events on "
                                   "replicas, by event (hit, miss, "
                                   "eviction)",
-    LLM_BATCH_OCCUPANCY: "sequences decoded by the last continuous-"
-                         "batching step (gauge)",
-    LLM_BATCH_BUCKET: "current padded decode batch bucket (gauge)",
-    LLM_QUEUE_DEPTH: "requests waiting for a decode slot (gauge; "
-                     "admission + preemption-resume queues)",
-    LLM_DECODE_STEPS_TOTAL: "batched decode steps executed",
-    LLM_ADMITTED_TOTAL: "sequences admitted into the running batch at a "
+    LLM_BATCH_OCCUPANCY: "engine slots that hold a request when a step "
+                         "returns (gauge)",
+    LLM_BATCH_BUCKET: "the engine's decode batch size, its slot count "
+                      "(gauge)",
+    LLM_QUEUE_DEPTH: "requests waiting for an engine slot (gauge)",
+    LLM_DECODE_STEPS_TOTAL: "engine steps executed",
+    LLM_ADMITTED_TOTAL: "requests admitted into the running batch at a "
                         "token boundary",
-    LLM_RETIRED_TOTAL: "sequences retired from the running batch at a "
+    LLM_RETIRED_TOTAL: "requests retired from the running batch at a "
                        "token boundary",
-    LLM_PREEMPTIONS_TOTAL: "sequences preempted (KV to host, requeued) by "
-                           "the starvation guard",
-    LLM_PREFIX_CACHE_HITS_TOTAL: "prompt admissions served from cached "
-                                 "prefix KV, by site (engine, router)",
-    LLM_PREFIX_CACHE_MISSES_TOTAL: "prompt lookups that found no full "
-                                   "prefix-KV coverage, by site",
+    LLM_PREFIX_CACHE_HITS_TOTAL: "requests routed to a replica that served "
+                                 "their prefix before, by site (router)",
+    LLM_PREFIX_CACHE_MISSES_TOTAL: "requests whose prefix no replica had "
+                                   "served, by site",
     COLLECTIVE_OPS_TOTAL: "collective ops executed, by op/backend",
     COLLECTIVE_BYTES_TOTAL: "collective payload bytes, by op/backend",
     COLLECTIVE_DURATION_HIST: "collective op duration (histogram)",

@@ -31,8 +31,9 @@ def make_engine():
 
 
 def run_scenario(engine):
-    """Six requests of known lengths through four slots, stepped by hand the
-    way ``BenchLLMServer`` counts: slots occupied when ``step()`` returns.
+    """Six requests of known lengths through four slots, stepped by hand,
+    counted as ``engine.counts`` counts: slots occupied when ``step()``
+    returns.
     A stop token that never comes, so every request runs to max_tokens."""
     for prompt, n in zip(PROMPTS, MAX_TOKENS):
         engine.add_request(

@@ -1,5 +1,9 @@
 """LLM engine, OpenAI-compatible serving, and batch inference tests."""
 
+import asyncio
+import threading
+import time
+
 import numpy as np
 import pytest
 
@@ -12,16 +16,24 @@ from ray_tpu.llm import (
     build_llm_processor,
     build_openai_app,
 )
+from ray_tpu.llm.disagg import DecodeReplica, PrefillEngine
+from ray_tpu.llm.serve_app import LLMServer
+from ray_tpu.models import LlamaConfig
 from ray_tpu.models.gpt2 import GPT2Config
 
+TINY_MODELS = {
+    "gpt2": lambda seq: GPT2Config.tiny(
+        vocab_size=384, max_seq=seq, dtype="float32"),
+    "llama": lambda seq: LlamaConfig.tiny(
+        vocab_size=384, max_seq=seq, dtype="float32"),
+}
 
-def _tiny_cfg(**kw):
+
+def _tiny_cfg(family="gpt2", **kw):
     defaults = dict(max_batch_size=4, max_seq_len=64, seed=0)
     defaults.update(kw)
     return EngineConfig(
-        model=GPT2Config.tiny(vocab_size=384, max_seq=64, dtype="float32"),
-        **defaults,
-    )
+        model=TINY_MODELS[family](defaults["max_seq_len"]), **defaults)
 
 
 class TestEngine:
@@ -86,6 +98,30 @@ class TestEngine:
         assert together[0]["token_ids"] == solo_a[0]["token_ids"]
         assert together[1]["token_ids"] == solo_b[0]["token_ids"]
 
+    @pytest.mark.parametrize("family", sorted(TINY_MODELS))
+    def test_admission_mid_decode_matches_solo(self, family):
+        """A request added after the others have decoded a few steps joins
+        the running batch at a token boundary and gets the ids it gets
+        alone (greedy: parity is of the sampled tokens)."""
+        cfg = _tiny_cfg(family)
+        p = SamplingParams(max_tokens=10, temperature=0.0)
+        prompts = ["hello world", "jax on tpu", "disaggregate me", "z"]
+        alone = JaxLLMEngine(cfg)
+        expected = [alone.generate([q], p)[0]["token_ids"] for q in prompts]
+
+        engine = JaxLLMEngine(cfg)
+        ids = []
+        for q in prompts:  # staggered: each joins a RUNNING batch
+            ids.append(engine.add_request(q, p))
+            engine.step()
+            engine.step()
+        got = engine.wait(ids)
+        assert [r["token_ids"] for r in got] == expected
+        st = engine.stats()
+        assert st["admitted"] == st["retired"] == len(prompts)
+        # They really shared decode steps.
+        assert st["occupied_slot_steps"] > st["steps"]
+
     def test_temperature_sampling_runs(self):
         engine = JaxLLMEngine(_tiny_cfg())
         outs = engine.generate(
@@ -98,6 +134,84 @@ class TestEngine:
         ids = tok.encode("héllo wörld")
         assert ids[0] == tok.BOS
         assert tok.decode(ids[1:]) == "héllo wörld"
+
+
+# The three callers of ``JaxLLMEngine.wait``, the one method that steps the
+# engine for a set of requests: each builds its engine from ``cfg`` and
+# returns (engine, run(prompts, params) -> results, run_out(prompt, params),
+# which waits for the prompt with no time left).
+def _waiter_generate(cfg):
+    engine = JaxLLMEngine(cfg)
+    return (engine, engine.generate,
+            lambda prompt, p: engine.generate([prompt], p, timeout_s=0.0))
+
+
+def _waiter_decode_replica(cfg):
+    replica, pre = DecodeReplica(cfg), PrefillEngine(cfg)
+
+    def admit(prompt, p):
+        return replica.add_from_kv(pre.prefill(prompt, p))
+
+    return (replica.engine,
+            lambda prompts, p: [replica.run(admit(q, p)) for q in prompts],
+            lambda prompt, p: replica.run(admit(prompt, p), timeout_s=0.0))
+
+
+def _waiter_llm_server(cfg):
+    server = LLMServer.func_or_class(cfg)
+
+    # The body under @serve.batch, handed one flush's requests (the batcher
+    # itself keeps a task of the loop it ran on: not for a test process
+    # that pickles the class later).
+    body = LLMServer.func_or_class._generate_batch.__wrapped__
+
+    # The batched body takes no timeout: its wait, with none left.
+    return (server.engine,
+            lambda prompts, p: asyncio.run(
+                body(server, [(q, p) for q in prompts])),
+            lambda prompt, p: server.engine.wait(
+                [server.engine.add_request(prompt, p)], timeout_s=0.0))
+
+
+@pytest.mark.parametrize("make", [
+    _waiter_generate, _waiter_decode_replica, _waiter_llm_server],
+    ids=["generate", "decode_replica_run", "llm_server_batch"])
+def test_every_waiter_gets_its_own_results_beside_a_stream(make):
+    """While another thread streams a long request through the same engine,
+    a waiter returns the ids it returns alone, the stream its own text; a
+    wait that runs out of time cancels its request and frees the slot."""
+    cfg = _tiny_cfg(max_batch_size=4, max_seq_len=512)
+    engine, run, run_out = make(cfg)
+    p = SamplingParams(max_tokens=6, temperature=0.0)
+    long = SamplingParams(max_tokens=480, temperature=0.0, stop_token=-1)
+    prompts = ["hello world", "jax on tpu", "one more"]
+    alone = [r["token_ids"] for r in run(prompts, p)]
+    streamed_alone = "".join(engine.generate_stream("stream me", long))
+
+    deltas = []
+    streamer = threading.Thread(
+        target=lambda: deltas.extend(engine.generate_stream("stream me", long)),
+        daemon=True)
+    streamer.start()
+    deadline = time.monotonic() + 60
+    while engine.occupied() == 0:
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+    beside = [r["token_ids"] for r in run(prompts, p)]
+    still_streaming = streamer.is_alive()
+    streamer.join(timeout=120)
+    assert not streamer.is_alive()
+    assert beside == alone
+    assert "".join(deltas) == streamed_alone
+    assert still_streaming  # 480 steps of stream beside ~20 of waiting
+
+    before = engine.stats()
+    with pytest.raises(TimeoutError):
+        run_out("never finished", long)
+    after = engine.stats()
+    assert after["cancelled"] == before["cancelled"] + 1
+    assert after["occupied"] == 0 and not engine.has_unfinished()
+    assert after["retired"] == before["retired"]
 
 
 class TestSampling:

@@ -329,12 +329,13 @@ def test_bench_family_builds_the_programs_tree():
     assert abs(router.std() - bench_family.ROUTER_SCALE) < 0.005
 
 
-@pytest.mark.parametrize("kind", ["prefill", "continuous_batching"])
+@pytest.mark.parametrize("kind", ["prefill", "decode_replica"])
 def test_kv_handover_engines_refuse_a_latent_cache_by_name(kind):
-    from ray_tpu.llm.continuous_batching import ContinuousBatchingEngine
-    from ray_tpu.llm.disagg import PrefillEngine
+    """Both ends of the hand-over refuse when they are BUILT: nothing has
+    been prefilled, fetched or freed yet."""
+    from ray_tpu.llm.disagg import DecodeReplica, PrefillEngine
 
-    build = PrefillEngine if kind == "prefill" else ContinuousBatchingEngine
+    build = PrefillEngine if kind == "prefill" else DecodeReplica
     with pytest.raises(NotImplementedError) as err:
         build(EngineConfig(model=tiny(), max_batch_size=2, max_seq_len=32))
     assert "longcat" in str(err.value) and "latent" in str(err.value)
