@@ -11,11 +11,10 @@ the model runner inside the vLLM engine the reference wraps, ray
   - cache writes are **deferred**: each layer's current-token k/v is merged
     into attention analytically (``k_self``/``v_self`` in
     ``ops/decode_attention.py``) and all 2L writes collapse into one
-    batched ``write_token_to_cache`` at the end of the step — TPU scatters
-    with multiple index dims lower pathologically (~1 ms each), so this is
-    worth ~20 ms/step at L=12 (round-1 design: 36 ms/step; this: 20.5 ms
-    at B=32, T=1024 on the v5e-lite part, whose effective HBM bandwidth of
-    ~40-60 GB/s — not compute — is the decode floor);
+    ``write_token_to_cache`` a cache array at the end of the step, which
+    updates the one tile of rows that holds each slot's position, in the
+    donated cache (a write a layer, as a scatter, cost ~1 ms each on the
+    v5e: round-1 design 36 ms/step at L=12, B=32, T=1024; deferred, 20.5);
   - per-slot positions make the batch *ragged*: each sequence attends only
     to its own ``[0, pos]`` prefix;
   - the layer loop is a Python loop (static layer indices; L compile-time
@@ -111,7 +110,8 @@ def gpt2_decode_step(
     full-T block copies can't ride the ~40 GB/s effective HBM).  The kernel
     remains the right call on full-bandwidth parts / long caches.
     """
-    from ..ops.decode_attention import decode_attention
+    from ..ops.decode_attention import (decode_attention,
+                                        write_token_to_cache)
 
     b = tokens.shape[0]
     x = params["wte"][tokens] + params["wpe"][pos]
@@ -127,8 +127,8 @@ def gpt2_decode_step(
         new_ks.append(k.astype(ck.dtype))
         new_vs.append(v.astype(cv.dtype))
         # Deferred-scatter protocol: the cache holds [0, pos-1]; the current
-        # token's k/v are merged in-kernel (one batched cache write below
-        # replaces 2L per-layer scatters — TPU scatters cost ~1 ms each).
+        # token's k/v are merged in-kernel, and written once below for all
+        # layers.
         o = decode_attention(
             q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1],
             kernel=kernel,
@@ -143,10 +143,8 @@ def gpt2_decode_step(
             jnp.einsum("bf,fe->be", h, layer["wo2"]) + layer["bo2"]
         ).astype(x.dtype)
 
-    from ..ops.decode_attention import write_token_to_cache
-
-    ck = write_token_to_cache(ck, jnp.stack(new_ks), pos)
-    cv = write_token_to_cache(cv, jnp.stack(new_vs), pos)
+    ck = write_token_to_cache(ck, jnp.stack(new_ks), pos, axis=3)
+    cv = write_token_to_cache(cv, jnp.stack(new_vs), pos, axis=3)
     x = _layernorm(x, params["lnf_g"], params["lnf_b"])
     logits = jnp.einsum("be,ve->bv", x, params["wte"])
     return logits.astype(jnp.float32), {"k": ck, "v": cv}

@@ -1,7 +1,8 @@
 """KV-cache inference path for the Llama family: prefill + ragged decode.
 
 Same design as ``gpt2_decode.py`` (head-major stacked cache
-``[L, B, Hkv, T, D]``, scatter writes, Pallas decode-attention kernel) with
+``[L, B, Hkv, T, D]``, one deferred in-place write of the step's token a
+cache array, optional Pallas decode-attention kernel) with
 the Llama specifics: RMSNorm, rotary positions, SwiGLU, and **grouped-query
 attention** — the cache holds only the Hkv kv-heads and the decode kernel
 attends each group of H/Hkv query heads against its shared kv-head in one
@@ -83,7 +84,8 @@ def llama_decode_step(
 ) -> Tuple[jnp.ndarray, dict]:
     """tokens: [B]; pos: [B] position of each token.  Ragged decode with
     per-slot rotary positions."""
-    from ..ops.decode_attention import decode_attention
+    from ..ops.decode_attention import (decode_attention,
+                                        write_token_to_cache)
 
     b = tokens.shape[0]
     x = params["wte"][tokens].astype(jnp.dtype(cfg.dtype))  # [B, E]
@@ -102,7 +104,7 @@ def llama_decode_step(
         new_ks.append(k.astype(ck.dtype))
         new_vs.append(v.astype(cv.dtype))
         # Deferred-scatter protocol (see gpt2_decode.py): cache holds
-        # [0, pos-1]; current k/v merged in-kernel, one batched write below.
+        # [0, pos-1]; current k/v merged in-kernel, one write below.
         o = decode_attention(
             q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1],
             kernel=kernel,
@@ -117,10 +119,8 @@ def llama_decode_step(
             "bf,fe->be", gate * up, layer["w_down"]
         ).astype(x.dtype)
 
-    from ..ops.decode_attention import write_token_to_cache
-
-    ck = write_token_to_cache(ck, jnp.stack(new_ks), pos)
-    cv = write_token_to_cache(cv, jnp.stack(new_vs), pos)
+    ck = write_token_to_cache(ck, jnp.stack(new_ks), pos, axis=3)
+    cv = write_token_to_cache(cv, jnp.stack(new_vs), pos, axis=3)
     x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
     logits = jnp.einsum("be,ve->bv", x, params["lm_head"])
     return logits.astype(jnp.float32), {"k": ck, "v": cv}

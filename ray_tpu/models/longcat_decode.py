@@ -13,7 +13,10 @@ is folded into the query and its value half applied after the weighted sum
 of latents, so a step reads ``T x 576`` values a slot an attention and never
 builds a key or a value.  Deferred-scatter protocol as in ``gpt2_decode.py``:
 the cache holds ``[0, pos-1]``, the current token's latent is merged as a
-last score, and all ``2L`` latents are written in one batched update.
+last score, and all ``2L`` latents are written at the step's end by the
+families' one ``write_token_to_cache`` (the tile of rows that holds each
+slot's position, in place; each attention's slice of the cache is still
+copied out of it for its products: ``tests/test_tpu_compile.py``).
 
 Both return ``(logits, cache)`` as every family's do; with
 ``with_counts=True`` (the family's ``*_counted`` twins, which the engine
@@ -30,6 +33,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.decode_attention import write_token_to_cache
 from .llama import _rmsnorm
 from .longcat import (LongcatConfig, add_counts, double_layer,
                       longcat_forward, matmul, mla_project)
@@ -81,13 +85,6 @@ def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg: LongcatConfig):
     return matmul("bhv,hve->be", o.astype(q.dtype), att["wo"])
 
 
-def write_latents(cache_arr, new, pos):
-    """cache_arr [A, B, T, C]; new [A, B, C]; pos [B]: one masked pass over
-    the cache."""
-    at = jnp.arange(cache_arr.shape[2])[None, :] == pos[:, None]  # [B, T]
-    return jnp.where(at[None, :, :, None], new[:, :, None, :], cache_arr)
-
-
 def longcat_decode_step(
     params, tokens, pos, cache, cfg: LongcatConfig, *,
     with_counts: bool = False
@@ -108,7 +105,8 @@ def longcat_decode_step(
     for layer in range(cfg.n_layer):
         x, counts = double_layer(x, params, layer, live, attend, cfg)
         total = add_counts(total, counts)
-    latent_cache = write_latents(latent_cache, jnp.stack(new), pos)
+    latent_cache = write_token_to_cache(
+        latent_cache, jnp.stack(new), pos, axis=2)
     x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
     logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, {"latent": latent_cache})

@@ -20,8 +20,9 @@ Kernel design (v5e-measured; see ``models/gpt2_decode.py`` docstring):
     clamped index maps is the known follow-up;
   - the *current* token's k/v ride in as separate [B, Hkv, D] operands and
     are merged into the online softmax as a final length-1 block — this is
-    what lets the engine defer all cache scatters to one batched write per
-    step instead of two per layer (TPU scatters are ~1 ms each);
+    what lets a decode step defer its cache writes to one
+    ``write_token_to_cache`` a cache array at its end, instead of two
+    scatters per layer;
   - grouped-query attention is native: each kv head carries its
     ``G = H // Hkv`` query rows as one [G, block_t] score tile.
 
@@ -85,20 +86,67 @@ def reference_decode_attention(q, k_cache, v_cache, pos, layer: int,
     return out.reshape(b, h, d)
 
 
-def write_token_to_cache(cache_arr, new, pos):
-    """Write one token's k or v into the stacked cache.
+def tile_positions(shape, dtype, axis: int) -> int:
+    """How many positions along ``axis`` one memory tile of such an array
+    holds on the TPU.  A tile is 128 lanes of the minor axis by the
+    sublanes of the next one: 8 rows of 32 bits, so 16 of bf16.  The TPU
+    keeps an array's last axis minor unless that would pad it: where the
+    last axis is no multiple of 128 it swaps the last two
+    (``tests/test_tpu_compile.py`` reads that from the compiler for nine
+    shapes).  So positions lie on the sublanes of Mistral's ``[.., T, 128]``
+    and on the lanes of LongCat's ``[.., T, 576]`` and GPT-2's
+    ``[.., T, 64]``."""
+    last_is_minor = shape[-1] % 128 == 0
+    on_lanes = axis == len(shape) - (1 if last_is_minor else 2)
+    return 128 if on_lanes else 32 // jnp.dtype(dtype).itemsize
 
-    cache_arr [L,B,Hkv,T,D]; new [L,B,Hkv,D]; pos [B] → updated cache.
-    Lowered as vmapped ``dynamic_update_slice`` — measured ~1 ms for a full
-    12-layer write on v5e, vs ~12 ms for the equivalent gather/scatter
-    (TPU scatters with multiple index dims lower pathologically)."""
 
-    def per_lb(c, u, p):  # c [Hkv,T,D], u [Hkv,D]
-        return jax.lax.dynamic_update_slice(c, u[:, None, :], (0, p, 0))
+def write_token_to_cache(cache_arr, new, pos, axis: int):
+    """Write one token a slot into a stacked cache, in place.
 
-    over_b = jax.vmap(per_lb, in_axes=(0, 0, 0))
-    over_lb = jax.vmap(over_b, in_axes=(0, 0, None))
-    return over_lb(cache_arr, new, pos)
+    ``cache_arr`` has its slots on axis 1 and its positions on ``axis``
+    (3 in ``[L,B,Hkv,T,D]``, 2 in the latent ``[A,B,T,C]``); ``new`` is
+    ``cache_arr`` without the position axis; ``pos`` [B].  Slot ``b``'s row
+    at ``pos[b]`` becomes ``new[:, b]`` and nothing else changes (a
+    ``pos[b]`` outside ``[0, T)`` writes nothing).
+
+    A loop over the slots, each turn a read-modify-write of the aligned
+    memory tile that holds ``pos[b]`` (``tile_positions``).  The start is
+    ``pos & -rows`` under ``allow_negative_indices=False`` so that the
+    compiler can see its low bits are zero: it then fuses read, select and
+    write into one update of the donated cache where it lies (0.1-0.2 ms a
+    step at the serving cells' sizes, v5e).  Every shorter way to say this
+    costs more there (PERF.md, PR 34): a ``dynamic_update_slice`` vmapped
+    over ``pos`` is one ``scatter``, which the compiler brackets with a
+    relayout of the operand in and out (four 1 GB copies a Mistral step);
+    a ``where`` over the position axis reads and writes everything; a loop
+    of one-row updates, a tile of the wrong size or one whose alignment the
+    compiler cannot see is updated through masked partial stores (0.8-1.2
+    ms a LongCat step, where a row is one lane of 288 tiles)."""
+    t = cache_arr.shape[axis]
+    rows = min(t, tile_positions(cache_arr.shape, cache_arr.dtype, axis))
+    new = jnp.expand_dims(new, axis)
+    sizes = list(cache_arr.shape)
+    sizes[1], sizes[axis] = 1, rows
+    offsets = jnp.arange(rows).reshape(
+        [rows if i == axis else 1 for i in range(cache_arr.ndim)])
+
+    def write_slot(b, arr):
+        start = jnp.bitwise_and(pos[b], -rows) if t > rows else 0
+        if t % rows:  # the last tile would end beyond T: it starts early
+            start = jnp.minimum(start, t - rows)
+        at = [0] * arr.ndim
+        at[1], at[axis] = b, start
+        old = jax.lax.dynamic_slice(
+            arr, at, sizes, allow_negative_indices=False)
+        row = jax.lax.dynamic_slice_in_dim(new, b, 1, axis=1)
+        # XLA clamps a start outside the array; the mask follows it.
+        first = jnp.clip(start, 0, t - rows)
+        return jax.lax.dynamic_update_slice(
+            arr, jnp.where(offsets == pos[b] - first, row, old), at,
+            allow_negative_indices=False)
+
+    return jax.lax.fori_loop(0, cache_arr.shape[1], write_slot, cache_arr)
 
 
 def _decode_kernel(pos_ref, q_ref, ks_ref, vs_ref, k_ref, v_ref, o_ref, *,

@@ -390,9 +390,13 @@ class TestBatchedPgCommits:
         before = w._run_sync(w.cp.call("debug_control_plane"))
         pgs = [None] * 12
         errors = []
+        # All at once: started one after the other, threads starved of the
+        # CPU by the suite's other workers each find the last sweep done.
+        together = threading.Barrier(len(pgs))
 
         def create(i):
             try:
+                together.wait(60)
                 pgs[i] = placement_group([{"CPU": 0.01}])
             except Exception as e:  # noqa: BLE001
                 errors.append(e)

@@ -12,7 +12,9 @@ imports this file.  Dispatch asks ``jax.devices()`` and would take its CPU
 branch during such a compile, so the tests steer ``_on_tpu`` themselves.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.ops import attention
-from ray_tpu.ops.decode_attention import decode_attention
+from ray_tpu.ops.decode_attention import decode_attention, tile_positions
 
 
 @pytest.fixture(scope="module")
@@ -109,7 +111,48 @@ def test_decode_kernel_refused_for_vmem_at_tinyllama_shape(
         _lower_decode(args).compile()
 
 
-def test_longcat_decode_step_fits_beside_its_weights(one_chip):
+# The serving cells' configurations (benchmarks/configs/<name>.json).
+CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32"]
+
+
+@pytest.fixture(scope="module")
+def cell_decode_step(one_chip):
+    """``compiled(name) -> (executable, cache)``: a serving cell's decode
+    step as ``JaxLLMEngine`` jits it (the counted twin where the family has
+    one, cache donated, the cell's widths, slots and positions), compiled
+    for the v5e: once a module, 10-13 s each."""
+    import importlib
+    import json
+
+    from ray_tpu.models import model_family
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    @functools.cache
+    def compiled(name):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                               "configs", name + ".json")) as f:
+            cell = json.load(f)
+        cfg = importlib.import_module(
+            "benchmarks.families." + cell["family"]).config(cell["model"])
+        slots = cell["engine"]["max_batch_size"]
+        fam = model_family(cfg)
+        decode_step = fam.decode_step_counted or fam.decode_step
+        params = on_chip(jax.eval_shape(
+            lambda: fam.init(jax.random.PRNGKey(0), cfg)))
+        cache = on_chip(jax.eval_shape(lambda: fam.init_cache(
+            cfg, slots, cell["engine"]["max_seq_len"])))
+        rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+        step = jax.jit(lambda p, c, t, pos: decode_step(p, t, pos, c, cfg),
+                       donate_argnums=(1,))
+        return step.lower(params, cache, rows, rows).compile(), cache
+
+    return compiled
+
+
+def test_longcat_decode_step_fits_beside_its_weights(cell_decode_step):
     """The LongCat decode step at the benchmark cell's size (published
     widths, 4 double layers, 16 experts held, 32 slots x 2048) compiles for
     the v5e with temporaries far under the weights it reads: a layer's slice
@@ -117,21 +160,74 @@ def test_longcat_decode_step_fits_beside_its_weights(one_chip):
     weights sliced first and indexed later, is COPIED every step (4.6 GB and
     3.6 GB, PERF.md PR 29) and the compiler then refuses the program beside
     11 GB of arguments."""
-    from ray_tpu.models import LongcatConfig, longcat_init, model_family
-
-    cfg = LongcatConfig(n_layer=4, experts_held=16, vocab_size=16384)
-    fam = model_family(cfg)
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
-    params = on_chip(jax.eval_shape(
-        lambda: longcat_init(jax.random.PRNGKey(0), cfg)))
-    cache = on_chip(jax.eval_shape(lambda: fam.init_cache(cfg, 32, 2048)))
-    rows = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
-    step = jax.jit(lambda p, c, t, pos: fam.decode_step_counted(
-        p, t, pos, c, cfg), donate_argnums=(1,))
-    memory = step.lower(params, cache, rows, rows).compile().memory_analysis()
+    memory = cell_decode_step("longcat_flash_l4_ep32")[0].memory_analysis()
     assert memory.argument_size_in_bytes > 10.9e9
-    assert memory.temp_size_in_bytes < 1.0e9  # 0.69 GB: one pass of the cache
+    # 0.68 GB, of which 0.60 is the eight [32, 2048, 576] attention slices
+    # of the cache, each copied out of it for its products (the read side)
+    assert memory.temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("name", CELL_CONFIGS)
+def test_decode_step_writes_its_token_into_the_cache_in_place(
+    cell_decode_step, name
+):
+    """Nothing but the in-place update of one tile a slot produces an array
+    the size of a cache leaf.  Before ``write_token_to_cache`` was that, the
+    Mistral step held four ``copy`` of ``bf16[16,16,8,2048,128]`` (the
+    operand of a scatter relaid T-major and back, for k and for v: 12.8 of
+    its 28.6 ms on the chip, ``temp`` 1.085 GB), and the LongCat step a
+    ``select`` fusion over the whole latent cache."""
+    compiled, cache = cell_decode_step(name)
+    # bf16[16,16,8,2048,128] twice and bf16[8,32,2048,576]
+    leaves = "|".join({re.escape("bf16[%s]" % ",".join(map(str, leaf.shape)))
+                       for leaf in jax.tree.leaves(cache)})
+    producers = set(re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = (?:{leaves})\S* ([\w-]+)\(",
+        compiled.as_text(), re.M))
+    # The update is a ``dynamic-update-slice`` or, where the compiler can
+    # see the tile is aligned, its fusion of read, select and write into
+    # one (``select_dynamic-update-slice_fusion``): named after its root.
+    updates = {name for name, _ in producers
+               if re.search("dynamic[-_]update[-_]slice", name)}
+    assert updates
+    others = {(name, op) for name, op in producers if name not in updates
+              and op not in ("parameter", "get-tuple-element")}
+    assert not others
+    if name == "mistral7b_l16":
+        # 0.745 GB
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.80e9
+
+
+# Cache leaves as the families shape them (positions on the axis before the
+# last), and neighbours of theirs: (shape, dtype).
+CACHE_LEAVES = [
+    ((16, 16, 8, 2048, 128), "bfloat16"),  # Mistral cell
+    ((8, 32, 2048, 576), "bfloat16"),      # LongCat cell
+    ((12, 32, 12, 1024, 64), "bfloat16"),  # GPT-2
+    ((12, 32, 12, 1024, 64), "float32"),
+    ((22, 32, 4, 2048, 64), "bfloat16"),   # TinyLlama
+    ((8, 32, 2048, 640), "bfloat16"),
+    ((8, 32, 2000, 576), "bfloat16"),
+    ((8, 32, 2048, 96), "float32"),
+    ((8, 16, 8, 2048, 256), "float32"),
+]
+
+
+@pytest.mark.parametrize("shape,dtype", CACHE_LEAVES, ids=str)
+def test_tile_positions_follows_the_layout_the_tpu_gives(
+    one_chip, shape, dtype
+):
+    """``write_token_to_cache`` sizes its tile from where the TPU lays the
+    position axis, which it cannot ask: ``tile_positions`` restates the
+    compiler's rule (last axis minor unless it is no multiple of 128), and
+    this reads the rule back from the compiler.  A tile of the wrong size
+    stays correct and costs a LongCat step 1.2 ms (PERF.md, PR 34)."""
+    leaf = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    text = jax.jit(lambda a: a + 1).lower(leaf).compile().as_text()
+    minor_to_major = re.search(
+        r"entry_computation_layout=\{\(\w+\[[\d,]+\]\{([\d,]+):", text)
+    lanes, sublanes = map(int, minor_to_major.group(1).split(",")[:2])
+    axis = len(shape) - 2
+    assert axis in (lanes, sublanes)
+    want = 128 if axis == lanes else 32 // jnp.dtype(dtype).itemsize
+    assert tile_positions(shape, dtype, axis) == want
