@@ -167,10 +167,11 @@ class DecodeReplica:
         timeout cancels the request and raises).
 
         Deploy decode replicas with ``max_concurrency`` > 1: concurrent
-        run() calls take turns stepping the shared engine, and concurrent
-        add_from_kv admissions (arriving on other lanes) join the SAME
-        decode batch — on an exclusive actor each request would decode
-        solo, which is the anti-pattern disaggregation exists to avoid."""
+        run() calls wait side by side while the engine's one loop steps
+        for all of them, and concurrent add_from_kv admissions (arriving
+        on other lanes) join the SAME decode batch — on an exclusive actor
+        each request would decode solo, which is the anti-pattern
+        disaggregation exists to avoid."""
         return self.engine.wait([request_id], timeout_s)[0]
 
     def stats(self) -> Dict[str, Any]:

@@ -39,9 +39,27 @@ Program names are a contract too: the benchmark's readers find the decode
 program as the only ``jit__lambda`` and the samplers by ``jit_sample_logits``,
 so whatever is jitted here beside the decode step is a NAMED function.
 
+Who steps: ONE thread an engine, ``engine.loop``.  It runs ``step()`` while
+anything is unfinished and otherwise sleeps on a condition that
+``add_request``, ``add_request_from_kv`` and ``shutdown`` notify; it starts
+when the first caller blocks in ``wait`` or ``stream_request`` (an engine
+stepped by hand through the public ``step()`` never grows a thread) and
+``shutdown()`` joins it.  Callers never step.  Each request has a mailbox.
+The tokens its slot gained in one step are put there in the next, as soon as
+that step's decode is dispatched (the device then has a step's work, and the
+woken callers' threads have the interpreter to themselves while the loop
+waits for it; a request's first token, sampled at admission, leaves in its
+own first step that way); its result is put there when it retires.  Its
+caller, blocked on the mailbox, takes everything it finds: a stream yields
+that as one delta, so one token a delta while the consumer keeps up, and the
+deltas grow by themselves while it does not.  No chunk size, no flush
+interval, no knob.  A step that raises fails every request there is.  The
+engine lock is the loop's for a step and an outside caller's
+(``cancel_request``, ``add_request_from_kv``, ``stats``) between two steps.
+
 Observability (names are a contract: tests pin them, PERF.md lists which
 metric reads which).  Host work runs inside ``util.tracing.host_span``s —
-``engine.lock_wait``, ``engine.step`` > ``engine.admit`` >
+``engine.lock_wait`` (outside callers only), ``engine.step`` > ``engine.admit`` >
 (``engine.prefill.dispatch``, ``engine.sample``), ``engine.decode.dispatch``,
 ``engine.sample``, ``engine.retire``, and a zero-length ``engine.counts`` at
 the end of every step — which a profiler session writes on the device
@@ -55,6 +73,7 @@ import bisect
 import contextlib
 import dataclasses
 import itertools
+import queue
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -107,6 +126,7 @@ class _Slot:
     generated: List[int]
     params: SamplingParams
     done: bool = False
+    delivered: int = 0  # of ``generated``, handed to the request's mailbox
 
     @property
     def last_pos(self) -> int:
@@ -121,17 +141,17 @@ def _arrival() -> tuple:
     return time.perf_counter(), (ctx[0] if ctx else None)
 
 
-def _drop_queued(queue: List[tuple], request_id: int) -> int:
+def _drop_queued(waiting: List[tuple], request_id: int) -> int:
     """Remove a request's entries IN PLACE.  ``add_request`` appends
     without the engine lock (a lock turn there would add a whole step to
     every TTFT), so the list must never be rebound: an append racing a
-    rebind lands in the discarded list and its stream spins to its
+    rebind lands in the discarded list and its caller waits to its
     timeout.  Appends only ever grow the tail; this walks from the tail
     it saw down, under the lock that every ``pop`` also holds."""
     dropped = 0
-    for i in range(len(queue) - 1, -1, -1):
-        if queue[i][0] == request_id:
-            del queue[i]
+    for i in range(len(waiting) - 1, -1, -1):
+        if waiting[i][0] == request_id:
+            del waiting[i]
             dropped += 1
     return dropped
 
@@ -205,19 +225,32 @@ class JaxLLMEngine:
         self._next_id = itertools.count()
         # (request_id, token_ids, params, perf_counter at arrival, trace_id)
         self._waiting: List[tuple] = []
-        self._finished: Dict[int, dict] = {}
-        # ALL engine-state mutation serializes on this lock: step() may be
-        # driven concurrently by batched calls (replica event loop) and by
-        # generate_stream callers (replica executor threads).  Reentrant:
-        # generate/generate_stream hold it across pop+step.  Take it
-        # through ``locked()``, which accounts for the wait.
+        # A mailbox a request, from ``add_request`` until its caller has
+        # collected or cancelled it: whoever steps puts there the tokens
+        # the request's slot gained (lists), then its result (a dict), or
+        # the error that ended it (an exception).
+        self._mailboxes: Dict[int, queue.SimpleQueue] = {}
+        self._blocked: set = set()  # idents of threads waiting on a mailbox
+        # ALL engine-state mutation serializes on this lock: the loop holds
+        # it for a step, an outside caller (cancel, stats, an adopted
+        # request) between two steps.  Reentrant: the loop holds it around
+        # ``step()``.  Outside callers take it through ``locked()``, which
+        # accounts for the wait; everybody takes it through ``_turn``, or
+        # the loop, which asks again the moment it lets go, would never
+        # lose it.
         self._step_lock = threading.RLock()
+        self._turn = threading.Lock()
         self._lock_owner: Optional[int] = None  # thread ident, outermost hold
+        # The loop sleeps on this while there is nothing to step;
+        # ``_loop`` and ``_stopped`` change under it.
+        self._wake = threading.Condition()
+        self._loop: Optional[threading.Thread] = None
+        self._stopped = False
         # Counters behind stats(); all but the two gauges only grow.
         self._counts: Dict[str, Any] = dict.fromkeys(
-            ("steps", "decode_steps", "admitted", "retired", "cancelled",
-             "prompt_tokens", "padded_prompt_tokens", "generated_tokens",
-             "occupied_slot_steps", "host_syncs"), 0)
+            ("steps", "loop_steps", "decode_steps", "admitted", "retired",
+             "cancelled", "prompt_tokens", "padded_prompt_tokens",
+             "generated_tokens", "occupied_slot_steps", "host_syncs"), 0)
         self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
         prefill = fam.prefill_counted or _without_counts(fam.prefill)
@@ -290,7 +323,9 @@ class JaxLLMEngine:
         params = params or SamplingParams()
         token_ids = encode_prompt(self.tokenizer, prompt, self.cfg.max_seq_len)
         request_id = next(self._next_id)
+        self._mailboxes[request_id] = queue.SimpleQueue()  # before the queue
         self._waiting.append((request_id, token_ids, params, *_arrival()))
+        self._notify_loop()
         return request_id
 
     def add_request_from_kv(self, meta: dict, row) -> int:
@@ -305,10 +340,12 @@ class JaxLLMEngine:
         arrival = _arrival()  # before the wait for the lock
         with self.locked():
             request_id = next(self._next_id)
+            self._mailboxes[request_id] = queue.SimpleQueue()
             self._waiting_kv.append(
                 (request_id, meta, jax.tree.map(jnp.asarray, row), *arrival)
             )
-            return request_id
+        self._notify_loop()
+        return request_id
 
     def _free_slot(self) -> Optional[int]:
         for i, s in enumerate(self.slots):
@@ -431,33 +468,145 @@ class JaxLLMEngine:
     def step(self) -> List[dict]:
         """Admit waiting requests, run ONE decode step for all active slots,
         retire finished requests.  Returns newly finished outputs.
-        Thread-safe (serialized on the engine lock)."""
+        Thread-safe (serialized on the engine lock).  For a caller that IS
+        the loop (the engine's own thread; a test or a debugger stepping by
+        hand): everybody else adds a request and waits (``wait``,
+        ``stream_request``)."""
         import jax.numpy as jnp
 
         with self.locked():
             return self._step_locked(jnp)
 
+    def _acquire(self) -> None:
+        """Take the engine lock in turn: whoever waits for it holds
+        ``_turn``, so a thread that has just released it queues behind."""
+        with self._turn:
+            self._step_lock.acquire()
+        self._lock_owner = threading.get_ident()
+
+    def _release(self) -> None:
+        self._lock_owner = None
+        self._step_lock.release()
+
     @contextlib.contextmanager
     def locked(self, request_id: Optional[int] = None):
-        """Hold the engine lock.  A thread's outermost acquisition is an
+        """Hold the engine lock, as a caller from outside the loop does for
+        at most one step.  A thread's outermost acquisition is an
         ``engine.lock_wait`` span and counts into ``lock_wait_s_total``;
-        re-entry (``stream_request`` -> ``step``) waits for nothing and
-        records nothing."""
-        me = threading.get_ident()
-        if self._lock_owner == me:
+        re-entry (the loop -> ``step``) waits for nothing and records
+        nothing."""
+        if self._lock_owner == threading.get_ident():
             yield
             return
         attrs = {} if request_id is None else {"request_id": request_id}
         t0 = time.perf_counter()
         with host_span("engine.lock_wait", **attrs):
-            self._step_lock.acquire()
-        self._lock_owner = me
+            self._acquire()
         self._counts["lock_wait_s_total"] += time.perf_counter() - t0
         try:
             yield
         finally:
-            self._lock_owner = None
-            self._step_lock.release()
+            self._release()
+
+    # ------------------------------------------------------------------ loop
+    def _notify_loop(self) -> None:
+        with self._wake:
+            self._wake.notify()
+
+    def _ensure_loop(self) -> None:
+        """Start the loop at the first caller that is about to block: an
+        engine only ever stepped by hand never grows a thread."""
+        if self._loop is not None and not self._stopped:
+            return  # every later call: no lock
+        with self._wake:
+            if self._stopped:
+                raise RuntimeError("the engine is shut down")
+            if self._loop is None:
+                self._loop = threading.Thread(
+                    target=self._run_loop, name="engine.loop", daemon=True)
+                self._loop.start()
+
+    def _run_loop(self) -> None:
+        """The one thread that steps the engine on its callers' behalf:
+        steps while anything is unfinished, sleeps until ``add_request``,
+        ``add_request_from_kv`` or ``shutdown`` notify.  A step that raises
+        fails every request there is and the loop goes on."""
+        while True:
+            with self._wake:
+                while not (self._stopped or self.has_unfinished()):
+                    self._wake.wait()
+                if self._stopped:
+                    return
+            self._acquire()  # not ``locked()``: the loop's turn is no wait
+            try:
+                self.step()
+                self._counts["loop_steps"] += 1
+            except Exception as e:  # noqa: BLE001 - the callers' to raise
+                self._fail_all(e)
+            finally:
+                self._release()
+
+    def _deliver_tokens(self) -> None:
+        """Under the lock: the tokens each live slot has gained since the
+        last call go to its request's mailbox, which wakes its waiter.  (A
+        request's last tokens are in its result: ``_retire``.)"""
+        for s in self.slots:
+            if s is not None and len(s.generated) > s.delivered:
+                box = self._mailboxes.get(s.request_id)
+                if box is not None:
+                    box.put(s.generated[s.delivered:])
+                s.delivered = len(s.generated)
+
+    def _fail_all(self, error: BaseException) -> None:
+        """Under the lock: drop every request there is and put ``error`` in
+        its mailbox."""
+        del self._waiting[:], self._waiting_kv[:]
+        self.slots = [None] * len(self.slots)
+        for box in list(self._mailboxes.values()):
+            box.put(error)
+
+    def shutdown(self) -> None:
+        """Stop the loop and join it.  Every caller blocked in ``wait`` or
+        ``stream_request`` gets a ``RuntimeError``, and so does every later
+        one; ``step()`` by hand still works."""
+        with self._wake:
+            self._stopped = True
+            self._wake.notify()
+            loop = self._loop
+        if loop is not None:
+            loop.join()
+        with self.locked():
+            self._fail_all(RuntimeError("the engine is shut down"))
+
+    def _take(self, request_id: int, deadline: float) -> list:
+        """Block until the request's mailbox holds something or ``deadline``
+        (``time.monotonic``) passes; returns EVERYTHING it holds: lists of
+        tokens in step order, then perhaps the result.  Raises what the loop
+        put there for an error, ``TimeoutError`` at the deadline."""
+        box = self._mailboxes.get(request_id)
+        if box is None:
+            raise KeyError(f"request {request_id} is not this engine's, or "
+                           "was collected or cancelled already")
+        self._ensure_loop()
+        left = deadline - time.monotonic()
+        me = threading.get_ident()
+        self._blocked.add(me)
+        try:
+            if left <= 0:
+                raise queue.Empty
+            items = [box.get(timeout=None if left == float("inf") else left)]
+        except queue.Empty:
+            raise TimeoutError(
+                f"request {request_id} exceeded its timeout") from None
+        finally:
+            self._blocked.discard(me)
+        with contextlib.suppress(queue.Empty):
+            while True:
+                items.append(box.get_nowait())
+        for item in items:
+            if isinstance(item, BaseException):
+                raise item
+        return items
 
     def _step_locked(self, jnp) -> List[dict]:
         c = self._counts
@@ -485,6 +634,12 @@ class JaxLLMEngine:
                         jnp.asarray(tokens), jnp.asarray(pos),
                     )
                     self._note_counts("decode", counts)
+                # The device has a step's work now: what the last step
+                # sampled (and this one's admissions) leaves the engine
+                # while it runs.  Handed over before the dispatch, the
+                # woken callers' threads would hold the interpreter when
+                # the loop needs it to start the device.
+                self._deliver_tokens()
                 with host_span("engine.sample", slots=len(active)):
                     sampled = self._sample(
                         logits, [(i, s.params) for i, s in active])
@@ -503,7 +658,7 @@ class JaxLLMEngine:
             # What is only known at the end of the step: zero-length, last.
             with host_span("engine.counts", occupied=occupied,
                            waiting=waiting, admitted=admitted,
-                           retired=retired,
+                           retired=retired, waiters=len(self._blocked),
                            host_syncs=c["host_syncs"] - syncs0,
                            **self._fold_counts(late)):
                 pass
@@ -530,7 +685,9 @@ class JaxLLMEngine:
                         "text": self.tokenizer.decode(gen),
                         "num_generated": len(s.generated),
                     }
-                    self._finished[s.request_id] = result
+                    box = self._mailboxes.get(s.request_id)
+                    if box is not None:
+                        box.put(result)  # its caller wakes: no step is owed
                     out.append(result)
                     self.slots[i] = None
         self._counts["retired"] += len(out)
@@ -606,15 +763,15 @@ class JaxLLMEngine:
                     self.slots[i] = None
                     dropped += 1
             self._counts["cancelled"] += dropped
-            self._finished.pop(request_id, None)
+            self._mailboxes.pop(request_id, None)
 
     def generate_stream(self, prompt: str,
                         params: Optional[SamplingParams] = None,
                         timeout_s: float = 300.0):
-        """Incremental generation: yields the text delta after every decode
-        step for this request.  Concurrent streams (and batched generate
-        calls) share the slot pool — every state access holds the engine
-        lock; only the yields happen outside it."""
+        """Incremental generation: yields this request's text as the loop
+        samples it, a delta for every step the consumer keeps up with.
+        Concurrent streams (and batched generate calls) share the slot
+        pool and the loop's steps."""
         yield from self.stream_request(
             self.add_request(prompt, params), timeout_s
         )
@@ -622,67 +779,56 @@ class JaxLLMEngine:
     def stream_request(self, request_id: int, timeout_s: float = 300.0):
         """Stream an ALREADY-QUEUED request's deltas (the disaggregated
         streaming path: the id came from add_request_from_kv, whose prompt
-        was prefilled on another replica)."""
+        was prefilled on another replica).  Blocks on the request's mailbox
+        and never steps: each delta is everything the loop has put there
+        since the last one, so one token a delta while the consumer keeps
+        up, and more by themselves while it does not."""
         emitted = 0
         deadline = time.monotonic() + timeout_s
         try:
             while True:
-                if time.monotonic() > deadline:
-                    raise TimeoutError("generation exceeded timeout")
-                done = None
-                delta_tokens: list = []
-                with self.locked(request_id):
-                    done = self._finished.pop(request_id, None)
-                    if done is None:
-                        self.step()
-                        done = self._finished.pop(request_id, None)
-                    if done is None:
-                        slot = next(
-                            (s for s in self.slots
-                             if s is not None
-                             and s.request_id == request_id),
-                            None,
-                        )
-                        if slot is not None and len(slot.generated) > emitted:
-                            delta_tokens = list(slot.generated[emitted:])
-                            emitted += len(delta_tokens)
+                tokens, done = [], None
+                for item in self._take(request_id, deadline):
+                    if isinstance(item, dict):
+                        done = item
+                    else:
+                        tokens.extend(item)
+                if done is not None:  # its ids hold the tail, stop cut off
+                    tokens = done["token_ids"][emitted:]
+                text = self.tokenizer.decode(tokens)
+                if text:
+                    yield text
                 if done is not None:
-                    tail = self.tokenizer.decode(done["token_ids"][emitted:])
-                    if tail:
-                        yield tail
                     return
-                if delta_tokens:
-                    text = self.tokenizer.decode(delta_tokens)
-                    if text:
-                        yield text
+                emitted += len(tokens)
         finally:
             # Timeout or abandoned consumer: release the slot/queue entry.
             self.cancel_request(request_id)
 
     def wait(self, request_ids: List[int],
              timeout_s: float = 300.0) -> List[dict]:
-        """Step the engine, one lock turn at a time, until every one of
-        THIS caller's ``request_ids`` has finished; returns their results in
-        that order.  Other callers' in-flight work (streams, other waits)
-        shares the steps and delays nothing here.  Past ``timeout_s`` the
-        requests are cancelled (slots and queue entries freed) and
-        ``TimeoutError`` is raised.  With ``stream_request``, one of the two
-        bodies that step the engine."""
-        def done() -> bool:
-            return all(i in self._finished for i in request_ids)
-
+        """Block until the loop has finished every one of THIS caller's
+        ``request_ids``; returns their results in that order.  Other
+        callers' in-flight work (streams, other waits) shares the steps and
+        delays nothing here.  Past ``timeout_s`` the requests are cancelled
+        (slots and queue entries freed) and ``TimeoutError`` is raised."""
         deadline = time.monotonic() + timeout_s
-        while True:
-            with self.locked():
-                if not done():
-                    self.step()
-                if done():
-                    return [self._finished.pop(i) for i in request_ids]
-            if time.monotonic() > deadline:
-                for i in request_ids:
-                    self.cancel_request(i)
-                raise TimeoutError(
-                    f"requests {list(request_ids)} exceeded {timeout_s} s")
+        results = []
+        try:
+            for request_id in request_ids:
+                done = None
+                while done is None:
+                    done = next(
+                        (item for item in self._take(request_id, deadline)
+                         if isinstance(item, dict)), None)
+                results.append(done)
+        except BaseException:
+            for request_id in request_ids:
+                self.cancel_request(request_id)
+            raise
+        for request_id in request_ids:  # collected
+            self._mailboxes.pop(request_id, None)
+        return results
 
     def generate(
         self,

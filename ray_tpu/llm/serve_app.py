@@ -42,7 +42,7 @@ class LLMServer:
     async def _generate_batch(self, requests: List[tuple]):
         """requests: [(prompt, SamplingParams)] — one engine pass serves
         them all (the engine's slot pool IS the batch), beside whatever SSE
-        streams are stepping the same engine from replica threads.  No
+        streams wait on the same engine's loop from replica threads.  No
         deadline of its own: the handle's caller has one, and a request ends
         at ``max_tokens``."""
         return self.engine.wait(
@@ -61,8 +61,9 @@ class LLMServer:
         return await self.completions(body)
 
     def stream_chunks(self, body: Dict[str, Any]):
-        """Sync generator of OpenAI-style streaming chunks (per decode
-        step).  Runs on a replica thread via handle_request_streaming."""
+        """Sync generator of OpenAI-style streaming chunks (one per engine
+        step while the consumer keeps up).  Runs on a replica thread via
+        handle_request_streaming."""
         yield from _stream_openai_chunks(
             self.engine.generate_stream(
                 _prompt_from_body(body), _sampling_from_request(body)
@@ -151,9 +152,8 @@ class LLMDisaggServer:
             opts["num_tpus"] = num_tpus_per_replica
         Pre = ray_tpu.remote(**opts)(PrefillReplica)
         # max_concurrency is load-bearing: concurrent run()/run_stream()
-        # calls take turns stepping one shared engine, so their requests
-        # share its decode batch; on an exclusive actor each would decode
-        # alone.
+        # calls wait on one shared engine's loop, so their requests share
+        # its decode batch; on an exclusive actor each would decode alone.
         Dec = ray_tpu.remote(max_concurrency=64, **opts)(DecodeReplica)
         self._prefill = [Pre.remote(engine_cfg) for _ in range(num_prefill)]
         self._decode = [Dec.remote(engine_cfg) for _ in range(num_decode)]

@@ -146,7 +146,11 @@ def _maybe_flush(force: bool = False):
     """Push this process's metric state to the control-plane KV (best
     effort).  Safe from ANY thread: called on the worker's protocol loop
     (built-in runtime metrics record there) it schedules an async push —
-    a blocking ``kv_put`` would deadlock the loop on its own completion."""
+    a blocking ``kv_put`` would deadlock the loop on its own completion.
+    A recording thread hands the push to the loop and does not wait for it
+    either: the loop may be waiting for THAT thread (an engine's loop
+    records each step while a unary caller blocks the replica's loop until
+    its request is done).  Only ``flush()`` (``force``) waits."""
     now = time.monotonic()
     if not force and (not _dirty or now - _last_flush < _FLUSH_INTERVAL_S):
         return
@@ -171,8 +175,10 @@ def _maybe_flush(force: bool = False):
             running = None
         if running is not None and running is w.loop:
             running.create_task(_kv_put_async(w, payload))
-        else:
+        elif force:
             w.kv_put(_REGISTRY_NS, f"worker:{w.worker_id.hex()}", payload)
+        else:
+            asyncio.run_coroutine_threadsafe(_kv_put_async(w, payload), w.loop)
     except Exception:  # raylint: waive[RTL003] flush is best-effort and cannot count via itself
         pass
 

@@ -20,6 +20,28 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def engines_shut_down(monkeypatch):
+    """Every ``JaxLLMEngine`` a test builds is shut down after it, so no
+    ``engine.loop`` thread outlives its test (a replica's dies with its
+    process).  Nothing for a worker that has not imported the engine."""
+    module = sys.modules.get("ray_tpu.llm.engine")
+    if module is None:
+        yield
+        return
+    built = []
+    init = module.JaxLLMEngine.__init__
+
+    def tracked(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        built.append(self)
+
+    monkeypatch.setattr(module.JaxLLMEngine, "__init__", tracked)
+    yield
+    for engine in built:
+        engine.shutdown()
+
+
 @pytest.fixture
 def ray_start_regular():
     import ray_tpu

@@ -128,8 +128,11 @@ def make_engine():
 
 
 def drain(engine):
+    """Step by hand until nothing is unfinished; the results by request."""
+    done = {}
     while engine.has_unfinished():
-        engine.step()
+        done.update((r["request_id"], r) for r in engine.step())
+    return done
 
 
 def test_one_host_read_a_decode_step_and_one_an_admission():
@@ -184,8 +187,7 @@ def test_greedy_together_equals_alone_beside_a_sampled_neighbour():
         engine.add_request(prompt, SamplingParams(
             max_tokens=14, temperature=1.2, top_k=40, top_p=0.9,
             stop_token=-1))
-    drain(engine)
-    assert engine._finished[mine]["token_ids"] == alone
+    assert drain(engine)[mine]["token_ids"] == alone
     assert engine._sample_rows._cache_size() > 0  # the general program ran
 
 
@@ -200,8 +202,7 @@ def test_sampled_requests_keep_their_own_top_k_in_a_mixed_batch():
         max_tokens=8, temperature=5.0, top_k=1, stop_token=-1))
     engine.add_request("other", SamplingParams(
         max_tokens=8, temperature=5.0, stop_token=-1))
-    drain(engine)
-    assert engine._finished[one]["token_ids"] == greedy["token_ids"]
+    assert drain(engine)[one]["token_ids"] == greedy["token_ids"]
 
 
 def test_host_syncs_per_step_metric_reads_the_counts_span(tmp_path):
@@ -228,6 +229,7 @@ def test_host_syncs_per_step_metric_reads_the_counts_span(tmp_path):
 
     engine = make_engine()
     engine.generate(["warm"], SamplingParams(max_tokens=2, stop_token=-1))
+    engine.shutdown()  # the loop that generate() started: by hand from here
     before = engine.stats()
     tracing.start_profile(str(tmp_path))
     try:

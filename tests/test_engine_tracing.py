@@ -30,6 +30,12 @@ def make_engine():
         max_batch_size=SLOTS, max_seq_len=SEQ))
 
 
+def by_hand(engine):
+    """Step until nothing is unfinished, as a caller that IS the loop."""
+    while engine.has_unfinished():
+        engine.step()
+
+
 def run_scenario(engine):
     """Six requests of known lengths through four slots, stepped by hand,
     counted as ``engine.counts`` counts: slots occupied when ``step()``
@@ -59,9 +65,11 @@ def traced(tmp_path, body):
 
 @pytest.fixture(scope="module")
 def scenario(tmp_path_factory):
-    """One traced run of the scenario: (engine, outside counts, spans)."""
+    """One traced run of the scenario: (engine, outside counts, spans).
+    Stepped by hand from the first request on: the engine has no loop."""
     engine = make_engine()
-    engine.generate(["warm"], SamplingParams(max_tokens=2, stop_token=-1))
+    engine.add_request("warm", SamplingParams(max_tokens=2, stop_token=-1))
+    by_hand(engine)
     before = engine.stats()
     out = {}
     trace = traced(tmp_path_factory.mktemp("trace"),
@@ -132,9 +140,9 @@ def test_counts_once_per_step_and_equal_to_the_outside_count(scenario):
     assert counts[0].stats["waiting"] == len(PROMPTS) - SLOTS
     assert counts[-1].stats == {"occupied": 0, "waiting": 0, "admitted": 0,
                                 "retired": counts[-1].stats["retired"],
-                                "host_syncs": 1}
+                                "waiters": 0, "host_syncs": 1}
     assert all(set(c.stats) == {"occupied", "waiting", "admitted", "retired",
-                                "host_syncs"}
+                                "waiters", "host_syncs"}
                for c in counts)
     # One read for the step's tokens and one for each admission's first.
     assert [c.stats["host_syncs"] for c in counts] == [
@@ -154,6 +162,7 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
     n = len(PROMPTS)
     assert with_session == {
         "steps": len(outside),
+        "loop_steps": 0,  # stepped by hand: no loop, no thread
         "decode_steps": len(outside),  # every step of the scenario decodes
         "admitted": n, "retired": n, "cancelled": 0,
         "prompt_tokens": sum(len(engine.tokenizer.encode(p)) for p in PROMPTS),
@@ -172,6 +181,7 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
     assert fresh_stats(engine, base) == with_session
     assert sum(outside) / len(outside) == (
         with_session["occupied_slot_steps"] / with_session["steps"])
+    assert engine._loop is None
 
 
 def test_stats_only_grow_and_waits_are_counted():
@@ -180,8 +190,9 @@ def test_stats_only_grow_and_waits_are_counted():
     # stats() takes the lock itself: its own wait is already counted.
     assert all(v == 0 for k, v in zero.items() if k != "lock_wait_s_total")
     assert set(zero) == {
-        "steps", "decode_steps", "admitted", "retired", "cancelled",
-        "prompt_tokens", "padded_prompt_tokens", "generated_tokens",
+        "steps", "loop_steps", "decode_steps", "admitted", "retired",
+        "cancelled", "prompt_tokens", "padded_prompt_tokens",
+        "generated_tokens",
         "occupied_slot_steps", "host_syncs", "queue_wait_s_total",
         "lock_wait_s_total", "occupied", "waiting"}
     engine.add_request("queued", SamplingParams(max_tokens=9, stop_token=-1))
@@ -193,6 +204,8 @@ def test_stats_only_grow_and_waits_are_counted():
     engine.generate(["x"], SamplingParams(max_tokens=3))
     two = engine.stats()
     assert all(two[k] >= one[k] for k in one if k not in ("occupied", "waiting"))
+    # The one step by hand is not the loop's; generate()'s are.
+    assert one["loop_steps"] == 0 and two["loop_steps"] == two["steps"] - 1
 
 
 def test_cancel_counts_what_it_dropped():
@@ -208,10 +221,11 @@ def test_cancel_counts_what_it_dropped():
     assert not engine.has_unfinished()
 
 
-def test_two_streams_wait_for_the_lock_and_never_step_together(tmp_path):
+def test_two_streams_wait_on_their_mailboxes_and_only_the_loop_steps(tmp_path):
     engine = make_engine()
     params = SamplingParams(max_tokens=12, stop_token=-1)
     engine.generate(["warm"], params)
+    before = engine.stats()
     gate = threading.Barrier(2)
 
     def stream(prompt):
@@ -228,17 +242,29 @@ def test_two_streams_wait_for_the_lock_and_never_step_together(tmp_path):
             t.join()
 
     trace = traced(tmp_path, both)
-    waiting = [roots for roots in trace.threads
-               if any(r.name == "engine.lock_wait" for r in roots)]
-    assert len(waiting) == 2  # both threads asked for the lock
+    after = engine.stats()
+    # ONE thread stepped, and a step is all it did: its turn at the lock is
+    # no wait.
+    [stepping] = [roots for roots in trace.threads
+                  if any(r.name == "engine.step" for r in roots)]
+    assert {r.name for r in stepping} == {"engine.step"}
+    assert after["loop_steps"] - before["loop_steps"] == len(stepping) == (
+        after["steps"] - before["steps"])
+    # The streams took the lock from outside, once each: the cancel that
+    # ends a stream, for at most a step.
+    waiting = [roots for roots in trace.threads if roots is not stepping]
+    assert len(waiting) == 2
     for roots in waiting:
-        assert all("request_id" in r.stats
-                   for r in roots if r.name == "engine.lock_wait")
+        [wait] = roots
+        assert wait.name == "engine.lock_wait" and not wait.children
+        assert "request_id" in wait.stats
     steps = sorted(trace.spans("engine.step"), key=lambda s: s.start)
     assert all(a.end <= b.start for a, b in zip(steps, steps[1:]))
     assert [s.stats["seq"] for s in steps] == list(
         range(steps[0].stats["seq"], steps[0].stats["seq"] + len(steps)))
     assert len(trace.spans("engine.admit")) == 2
+    # Callers blocked on a mailbox at the end of a step: both, at some step.
+    assert max(c.stats["waiters"] for c in trace.spans("engine.counts")) == 2
 
 
 def test_admit_carries_the_cluster_trace_id(tmp_path):

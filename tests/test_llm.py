@@ -1,6 +1,7 @@
 """LLM engine, OpenAI-compatible serving, and batch inference tests."""
 
 import asyncio
+import sys
 import threading
 import time
 
@@ -136,8 +137,8 @@ class TestEngine:
         assert tok.decode(ids[1:]) == "héllo wörld"
 
 
-# The three callers of ``JaxLLMEngine.wait``, the one method that steps the
-# engine for a set of requests: each builds its engine from ``cfg`` and
+# The three callers of ``JaxLLMEngine.wait``, which blocks until the engine's
+# loop has finished a set of requests: each builds its engine from ``cfg`` and
 # returns (engine, run(prompts, params) -> results, run_out(prompt, params),
 # which waits for the prompt with no time left).
 def _waiter_generate(cfg):
@@ -214,6 +215,245 @@ def test_every_waiter_gets_its_own_results_beside_a_stream(make):
     assert after["retired"] == before["retired"]
 
 
+# ------------------------------------------------------------------ the loop
+# One thread steps the engine; callers wait on their request's mailbox.  Every
+# wait below has a deadline that holds beside five other xdist workers.
+LONG_WAIT_S = 120
+
+
+class IdTokenizer(ByteTokenizer):
+    """Every id one visible character: a streamed text shows its ids."""
+
+    def decode(self, ids):
+        return "".join(chr(0x100 + i) for i in ids)
+
+
+def _ids(text):
+    return [ord(c) - 0x100 for c in text]
+
+
+def _loop_engine(**kw):
+    return JaxLLMEngine(_tiny_cfg(**kw), tokenizer=IdTokenizer())
+
+
+def _gated(engine, monkeypatch):
+    """Make every step wait for a permit: the test decides how many steps
+    run before a consumer looks.  Returns the semaphore."""
+    permits = threading.Semaphore(0)
+    step_locked = engine._step_locked
+
+    def gated(jnp):
+        assert permits.acquire(timeout=LONG_WAIT_S)
+        return step_locked(jnp)
+
+    monkeypatch.setattr(engine, "_step_locked", gated)
+    return permits
+
+
+def _until(condition):
+    deadline = time.monotonic() + LONG_WAIT_S
+    while not condition():
+        assert time.monotonic() < deadline
+        time.sleep(0.001)
+
+
+def _in_threads(bodies):
+    """Run the callables at once; their results, or the exception each
+    raised, in order."""
+    out = [None] * len(bodies)
+
+    def run(i, body):
+        try:
+            out[i] = body()
+        except BaseException as e:  # noqa: BLE001 - the test looks at it
+            out[i] = e
+
+    threads = [threading.Thread(target=run, args=(i, b), daemon=True)
+               for i, b in enumerate(bodies)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=LONG_WAIT_S)
+    assert not any(t.is_alive() for t in threads)
+    return out
+
+
+def test_sixteen_streams_at_once_get_the_ids_each_gets_alone():
+    p = SamplingParams(max_tokens=12, temperature=0.0, stop_token=-1)
+    prompts = [f"prompt number {i} " * (1 + i % 3) for i in range(16)]
+    alone = _loop_engine(max_batch_size=16)
+    expected = [alone.generate([q], p)[0]["token_ids"] for q in prompts]
+
+    engine = _loop_engine(max_batch_size=16)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # threads change places at every chance
+    try:
+        texts = _in_threads([
+            lambda q=q: "".join(engine.generate_stream(q, p))
+            for q in prompts])
+    finally:
+        sys.setswitchinterval(interval)
+    assert [_ids(t) for t in texts] == expected  # no token lost or doubled
+    stats = engine.stats()
+    assert stats["loop_steps"] == stats["steps"] > 0
+    assert stats["admitted"] == stats["retired"] == 16
+    assert stats["occupied_slot_steps"] > stats["steps"]  # they shared steps
+    assert not engine._mailboxes  # every stream collected its own
+
+
+def test_a_delta_a_token_while_the_consumer_keeps_up(monkeypatch):
+    """With a consumer that takes each delta before the next step runs, a
+    stream of n tokens is n deltas of one: the prefill's token leaves once
+    its step's decode is dispatched, each later one once the next step's is,
+    the last with the result (where the consumer may find the one before it
+    too)."""
+    n = 9
+    p = SamplingParams(max_tokens=n, temperature=0.0, stop_token=-1)
+    engine = _loop_engine()
+    [want] = engine.generate(["keep up"], p)
+    before = engine.stats()
+    permits = _gated(engine, monkeypatch)
+    deltas = []
+    permits.release()
+    for delta in engine.generate_stream("keep up", p):
+        deltas.append(_ids(delta))
+        permits.release()
+    assert [len(d) for d in deltas[:n - 2]] == [1] * (n - 2)
+    assert len(deltas) in (n - 1, n)
+    assert sum(deltas, []) == want["token_ids"]
+    after = engine.stats()
+    assert after["steps"] - before["steps"] == n - 1
+    assert after["loop_steps"] == after["steps"]
+
+
+def test_a_consumer_that_sleeps_gets_one_delta_with_all_it_missed(monkeypatch):
+    p = SamplingParams(max_tokens=20, temperature=0.0, stop_token=-1)
+    engine = _loop_engine()
+    [want] = engine.generate(["sleepy consumer"], p)
+    permits = _gated(engine, monkeypatch)
+    steps = engine._counts["steps"]  # not stats(): a gated step holds the lock
+    stream = engine.generate_stream("sleepy consumer", p)
+    permits.release()
+    got = [_ids(next(stream))]
+    _until(lambda: engine._counts["steps"] == steps + 1)
+    assert len(got[0]) == 1  # the step's second token waits for the next one
+    for _ in range(5):  # five steps while nobody looks
+        permits.release()
+    _until(lambda: engine._counts["steps"] == steps + 6)
+    got.append(_ids(next(stream)))
+    assert len(got[1]) == 5
+    for _ in range(40):
+        permits.release()
+    got.extend(_ids(delta) for delta in stream)
+    assert sum(got, []) == want["token_ids"]  # nothing lost, nothing doubled
+
+
+def test_a_freed_slot_is_refilled_at_the_next_step(monkeypatch):
+    from ray_tpu.util import flight_recorder
+
+    rows = []
+    monkeypatch.setattr(flight_recorder, "record_llm_step",
+                        lambda *a: rows.append(a))
+    slots = 2
+    engine = _loop_engine(max_batch_size=slots)
+    outs = engine.generate(
+        [f"request {i}" for i in range(7)],
+        SamplingParams(max_tokens=4, temperature=0.0, stop_token=-1))
+    assert len(outs) == 7 and engine.stats()["loop_steps"] == len(rows)
+    # (occupied, waiting, admitted, retired, bucket) a step: where a step
+    # ends with a free slot and a queue, the next one admits.
+    freed = [i for i, (occupied, waiting, *_rest) in enumerate(rows)
+             if occupied < slots and waiting]
+    assert freed and all(rows[i + 1][2] >= 1 for i in freed)
+
+
+def test_an_idle_loop_makes_no_steps_and_a_hand_stepped_engine_has_no_thread():
+    p = SamplingParams(max_tokens=3, temperature=0.0)
+    engine = _loop_engine()
+    engine.generate(["wake the loop"], p)
+    assert engine._loop.is_alive() and engine._loop.name == "engine.loop"
+    steps = engine.stats()["steps"]
+    time.sleep(0.5)
+    assert engine.stats()["steps"] == steps and not engine.has_unfinished()
+    assert engine.generate(["and again"], p)[0]["num_generated"] >= 1
+
+    by_hand = _loop_engine()
+    rid = by_hand.add_request("stepped by hand", p)
+    done = []
+    while by_hand.has_unfinished():
+        done.extend(by_hand.step())
+    assert [r["request_id"] for r in done] == [rid]
+    assert by_hand._loop is None and by_hand.stats()["loop_steps"] == 0
+
+
+def test_shutdown_joins_the_loop_and_fails_blocked_and_later_callers():
+    long = SamplingParams(max_tokens=400, temperature=0.0, stop_token=-1)
+    engine = _loop_engine(max_batch_size=1, max_seq_len=512)
+    results = []
+    blocked = threading.Thread(daemon=True, target=lambda: results.extend(
+        _in_threads([lambda: engine.generate(["in the slot"], long),
+                     lambda: list(engine.generate_stream("queued", long))])))
+    blocked.start()
+    _until(lambda: engine.occupied() == 1 and engine._n_waiting() == 1)
+    loop = engine._loop
+    engine.shutdown()
+    assert not loop.is_alive()
+    blocked.join(timeout=LONG_WAIT_S)
+    assert [type(r) for r in results] == [RuntimeError, RuntimeError]
+    assert not engine.has_unfinished() and engine.occupied() == 0
+    with pytest.raises(RuntimeError, match="shut down"):
+        engine.generate(["too late"], long)
+    with pytest.raises(RuntimeError, match="shut down"):
+        list(engine.generate_stream("too late", long))
+    engine.shutdown()  # again: nothing to do
+
+
+def test_a_step_that_raises_fails_every_waiter_and_the_loop_goes_on(
+        monkeypatch):
+    p = SamplingParams(max_tokens=30, temperature=0.0, stop_token=-1)
+    engine = _loop_engine(max_batch_size=2)
+    [want] = engine.generate(["after the fault"], p)
+    decode = engine._decode
+
+    def boom(*_args):
+        raise FloatingPointError("boom")
+
+    monkeypatch.setattr(engine, "_decode", boom)
+    got = _in_threads([
+        lambda: engine.generate(["one"], p),
+        lambda: list(engine.generate_stream("two", p)),
+        lambda: engine.generate(["three, in the queue"], p)])
+    assert [type(r) for r in got] == [FloatingPointError] * 3
+    assert not engine.has_unfinished() and not engine._mailboxes
+    monkeypatch.setattr(engine, "_decode", decode)
+    assert engine._loop.is_alive()
+    [again] = engine.generate(["after the fault"], p)
+    assert again["token_ids"] == want["token_ids"]
+
+
+def test_a_timeout_and_an_abandoned_stream_free_slot_and_queue_entry():
+    long = SamplingParams(max_tokens=400, temperature=0.0, stop_token=-1)
+    engine = _loop_engine(max_batch_size=1, max_seq_len=512)
+    holder = engine.generate_stream("holds the slot", long)
+    assert next(holder)
+    # Queued behind it, out of time: the queue entry goes.
+    with pytest.raises(TimeoutError):
+        list(engine.generate_stream("queued", long, timeout_s=0.05))
+    with pytest.raises(TimeoutError):
+        engine.generate(["queued too"], long, timeout_s=0.05)
+    stats = engine.stats()
+    assert stats["cancelled"] == 2 and stats["waiting"] == 0
+    assert stats["occupied"] == 1
+    holder.close()  # the consumer walks away: the slot goes
+    stats = engine.stats()
+    assert stats["cancelled"] == 3 and stats["occupied"] == 0
+    assert not engine.has_unfinished() and not engine._mailboxes
+    time.sleep(0.1)  # a step that began before the cancel may end after it
+    steps = engine.stats()["steps"]
+    time.sleep(0.1)
+    assert engine.stats()["steps"] == steps  # nothing left to step for
+
+
 class TestSampling:
     def test_top_k_restricts(self):
         import jax
@@ -276,6 +516,20 @@ class TestServing:
         ).result(timeout=120)
         assert resp["object"] == "chat.completion"
         assert resp["choices"][0]["message"]["role"] == "assistant"
+        serve.delete("LLMServer")
+
+    def test_a_unary_request_outlasts_a_metrics_flush(self, cluster):
+        """The unary route blocks the replica's event loop until the
+        engine's loop has finished the request, and the engine's loop
+        records metrics every step: a flush that falls due meanwhile (every
+        2 s) must not wait for the event loop, or neither ever returns."""
+        import ray_tpu.serve as serve
+
+        handle = serve.run(build_openai_app(_tiny_cfg(max_seq_len=2048)))
+        asked = 1900  # several seconds of steps
+        resp = handle.remote(
+            {"prompt": "hi", "max_tokens": asked}).result(timeout=240)
+        assert resp["usage"]["completion_tokens"] == asked
         serve.delete("LLMServer")
 
     def test_http_prefix_routing(self, cluster):

@@ -89,12 +89,15 @@ def test_timeline_and_profile(ray_start_regular, tmp_path):
     def has_events():
         events = ray_tpu.timeline(str(out))
         names = {e["name"] for e in events}
-        return "work" in names and "my_span" in names
+        # A task still RUNNING in the control plane's view has no end yet
+        # (dur 0): wait for one whose FINISHED event has arrived too.
+        return "my_span" in names and any(
+            e["name"] == "work" and e.get("dur", 0) > 0 for e in events)
 
     _wait_for(has_events, msg="timeline events")
     events = json.loads(out.read_text())
-    ev = next(e for e in events if e["name"] == "work")
-    assert ev["ph"] == "X" and ev["dur"] > 0
+    ev = next(e for e in events if e["name"] == "work" and e["dur"] > 0)
+    assert ev["ph"] == "X"
 
 
 def test_cli_status_and_list(ray_start_regular, capsys):

@@ -151,6 +151,48 @@ def test_metrics_recorded_in_worker(cluster):
     assert by_name["worker_side"]["value"] == 4.0
 
 
+def test_a_recording_thread_never_waits_for_the_workers_loop(monkeypatch):
+    """A flush that falls due on a thread other than the worker's loop is
+    handed to the loop and not waited for: the loop may itself be waiting for
+    that thread (an engine's loop records every step while a unary caller
+    blocks the replica's loop until its request is done).  ``flush()`` is
+    the one call that waits."""
+    import asyncio
+    import threading
+
+    from ray_tpu.core import core_worker
+
+    pushed, blocking = [], []
+
+    class ControlPlane:
+        async def call(self, method, payload):
+            pushed.append((method, payload["key"]))
+
+    class Worker:
+        loop = asyncio.new_event_loop()  # nobody runs it yet: a busy loop
+        cp = ControlPlane()
+        worker_id = b"\x01\x02"
+
+        def kv_put(self, *args):
+            blocking.append(args)
+
+    monkeypatch.setattr(core_worker, "_global_worker", Worker())
+    monkeypatch.setattr(metrics, "_flush_hook", None)
+    monkeypatch.setattr(metrics, "_last_flush", 0.0)  # a flush is due
+    recorder = threading.Thread(
+        target=lambda: metrics.Counter("recorded_off_the_loop").inc(),
+        daemon=True)
+    recorder.start()
+    recorder.join(timeout=30)
+    assert not recorder.is_alive() and not blocking and not pushed
+    Worker.loop.run_until_complete(asyncio.sleep(0.05))  # the loop's turn
+    Worker.loop.close()
+    assert pushed == [("kv_put", "worker:0102")]
+    metrics.Counter("recorded_off_the_loop").inc()  # none due so soon
+    metrics.flush()
+    assert len(blocking) == 1
+
+
 def test_runtime_env_env_vars(cluster):
     @ray_tpu.remote(runtime_env={"env_vars": {"RT_TEST_VAR": "hello"}})
     def read_env():
