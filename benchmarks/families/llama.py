@@ -8,6 +8,7 @@ cache are the program's own (``engine.family``).
 
 from __future__ import annotations
 
+from benchmarks.lib import flops
 from benchmarks.reference.llama_ref import llama_ref_logits
 from ray_tpu.models import LlamaConfig
 
@@ -63,3 +64,6 @@ def load_params(model: dict, seed: int):
 def reference_logits(params, tokens, cfg: LlamaConfig):
     return llama_ref_logits(params, tokens, cfg.n_head, cfg.n_kv_head,
                             cfg.rope_theta, cfg.rms_eps)
+
+
+decode_flops_per_token = flops.llama_decode_flops_per_token

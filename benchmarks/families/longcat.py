@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from benchmarks.lib import flops_longcat
 from benchmarks.reference.longcat_ref import longcat_ref_logits
 from ray_tpu.models import LongcatConfig
 
@@ -105,3 +106,6 @@ def reference_logits(params, tokens, cfg: LongcatConfig):
     sizes = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
     return longcat_ref_logits(params, tokens, sizes, cfg.n_layer,
                               cfg.expert_offset)
+
+
+decode_flops_per_token = flops_longcat.decode_flops_per_token

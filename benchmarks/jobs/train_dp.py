@@ -25,6 +25,21 @@ TRACE_SECONDS = 4.0  # of the steady window, not the whole of it
 TRACE_FROM_STEP = 2
 
 
+def split_setup(t_start: float, t_fit: float, t_enter: float,
+                t_window: float) -> dict:
+    """The start of a training run, in two numbers (wall clock, one host).
+    ``gang_ready_s``: ``fit()`` called -> the first line of rank 0's loop:
+    placement, the workers' processes, ``jax.distributed`` and the chips'
+    runtimes coming up, which identical code moves by +-7 s on four chips
+    (PERF.md PR 38).  ``setup_s``: everything else from ``run.py``'s start
+    to the window's first step (cluster, imports, state, compile from the
+    cache, reference, warm steps), which repeats.  The two add up to what
+    ``setup_s`` was until PR 38."""
+    gang_ready_s = t_enter - t_fit
+    return {"gang_ready_s": gang_ready_s,
+            "setup_s": (t_window - t_start) - gang_ready_s}
+
+
 def train_loop(config: dict) -> None:
     t_enter = time.time()
     _last = [time.perf_counter()]
@@ -286,6 +301,8 @@ def run(job) -> dict:
     if job.chips > 1 and r0["all_reduces"] < 1:
         problems.append("no all-reduce in the compiled step")
     tokens_per_s = r0["steps"] * r0["tokens_per_step"] / r0["elapsed_s"]
+    start = split_setup(job.t_start_wall, t_fit, r0["t_enter"],
+                        r0["t_window_wall"])
     peak = max(measured_peak(r["memory_stats"]) for r in ranks)
     per_token = fam.train_flops_per_token(model, size["seq"])
     return {
@@ -293,14 +310,13 @@ def run(job) -> dict:
         "attempted": r0["steps"],
         "failed": len([x for x in measured if not math.isfinite(x)]),
         "end_to_end": {
-            "train_tokens_per_s": tokens_per_s,
-            "setup_s": r0["t_window_wall"] - job.t_start_wall,
+            "train_tokens_per_s": tokens_per_s, **start,
         },
         "device": {"platform": r0["platform"], "kind": r0["kind"],
                    "count": r0["devices"], "memory_peak_bytes": peak},
         # What the per-layer readers may read (host clock, benchmark's own).
         "stats": {
-            "gang_ready_s": r0["t_enter"] - t_fit,
+            "gang_ready_s": start["gang_ready_s"],
             "step_ms": r0["step_ms"], "input_ms": r0["input_ms"],
             "steps": r0["steps"],
             "traced_steps": r0["traced_steps"],
@@ -308,7 +324,12 @@ def run(job) -> dict:
             "seq": size["seq"], "model": model,
             "flops_per_token": per_token,
         },
+        "compared": {"loss_rel_err": [rel, LOSS_RTOL]},
         "notes": {
+            "gang_ready_s": start["gang_ready_s"],
+            "ranks_entered_s": [r["t_enter"] - t_fit for r in ranks],
+            "fit_called_s": t_fit - job.t_start_wall,
+            "phases_rank0": r0["phases"],
             "median_step_ms": statistics.median(r0["step_ms"]),
             "flops_per_token": per_token,
             "model_flops_per_s_per_chip": tokens_per_s * per_token / job.chips,

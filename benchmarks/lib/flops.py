@@ -67,3 +67,19 @@ def roofline_share(flops: float, nbytes: float, seconds: float,
     t_memory = nbytes / peaks["hbm_bytes_per_s"]
     return {"pct": 100.0 * max(t_compute, t_memory) / seconds,
             "bound": "compute" if t_compute >= t_memory else "memory"}
+
+
+def llama_decode_flops_per_token(m: dict, context: float) -> float:
+    """One decoded token at ``context`` cached positions: 2 per matmul
+    parameter, + scores and values over the context (2 matmuls of
+    2*context*e a layer)."""
+    return (2.0 * llama_matmul_params(m)
+            + 4.0 * m["n_layer"] * context * m["d_model"])
+
+
+def mean_decode_context(sizes: list) -> float:
+    """Mean context of an occupied slot over the decode steps of a
+    population of (prompt_tokens, output_tokens): a request decodes
+    ``out`` steps at contexts ``prompt .. prompt + out``."""
+    steps = sum(out for _p, out in sizes)
+    return sum(out * (p + out / 2.0) for p, out in sizes) / steps

@@ -51,12 +51,19 @@ def decode_step_bytes(m: dict, experts_touched: float, occupied: float,
             + occupied * context * latent_bytes_per_token(m))
 
 
-def mean_decode_context(sizes: list) -> float:
-    """Mean context of an occupied slot over the decode steps of a
-    population of (prompt_tokens, output_tokens): a request decodes
-    ``out`` steps at contexts ``prompt .. prompt + out``."""
-    steps = sum(out for _p, out in sizes)
-    return sum(out * (p + out / 2.0) for p, out in sizes) / steps
+def decode_flops_per_token(m: dict, context: float) -> float:
+    """One decoded token on this chip's share at ``context`` cached
+    positions: 2 per dense parameter and per parameter of the held experts'
+    expected share of the token's choices (as ``prefill_flops`` counts it),
+    the head, and the absorbed attention over the latents (scores over
+    ``kv_lora_rank + qk_rope_head_dim``, values over ``kv_lora_rank``, a
+    head, a position, two attentions a layer)."""
+    dense = m["n_layer"] * nonexpert_layer_params(m)
+    routed = m["n_layer"] * expert_params(m) * m["top_k"] * m["experts_held"] / (
+        m["n_routed_experts"] + m["zero_expert_num"])
+    attn = 2 * m["n_layer"] * 2.0 * context * m["n_head"] * (
+        2 * m["kv_lora_rank"] + m["qk_rope_head_dim"])
+    return 2.0 * (dense + routed + m["vocab_size"] * m["d_model"]) + attn
 
 
 def prefill_flops(m: dict, tokens: int) -> float:

@@ -5,7 +5,7 @@ touched and slots occupied a step are the engine's own counts on the spans
 ``counts``; the mean context of an occupied slot comes from the traffic's
 sizes.  ``None`` when the program or the counts are not in the trace."""
 
-from benchmarks.lib import flops_longcat, traffic
+from benchmarks.lib import flops, flops_longcat, traffic
 from benchmarks.readers.module_ms import read as module_ms
 from benchmarks.readers.span_stat import read as span_stat
 
@@ -18,5 +18,5 @@ def read(ctx, module, counts):
         return None
     nbytes = flops_longcat.decode_step_bytes(
         ctx.stats["model"], touched, occupied,
-        flops_longcat.mean_decode_context(traffic.sizes(ctx.mix)))
+        flops.mean_decode_context(traffic.sizes(ctx.mix)))
     return 100.0 * nbytes / ctx.peaks["hbm_bytes_per_s"] / (ms / 1e3)

@@ -75,6 +75,14 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
+def end_to_end_metrics(bench: dict, cell: str, values: dict) -> dict:
+    """The cell's end-to-end metrics as the line carries them: those that
+    list the cell, and those with no list (``setup_s``), which a cell in no
+    metric's ``workloads`` prints alone."""
+    return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+            for m in bench["end_to_end"] if applies(m, cell)}
+
+
 def read_layer_metrics(bench: dict, cell: str, ctx: ReadContext) -> dict:
     out = {}
     for metric in bench["per_layer"]:
@@ -168,10 +176,8 @@ def main() -> int:
     line = {"correct": not result["problems"],
             "attempted": result["attempted"], "failed": result["failed"]}
     if not args.trace:
-        wanted = [m for m in bench["end_to_end"] if applies(m, cell["name"])]
-        line["metrics"] = {
-            m["name"]: {"value": result["end_to_end"][m["name"]],
-                        "unit": m["unit"]} for m in wanted}
+        line["metrics"] = end_to_end_metrics(
+            bench, cell["name"], result["end_to_end"])
     else:
         from benchmarks.lib.trace_reduce import Trace
 
@@ -187,6 +193,9 @@ def main() -> int:
         device = dict(device, busy_s=trace.busy_s, window_s=trace.window_s)
         line["breakdown"] = trace.breakdown()
     line["device"] = device
+    # Each number compared beside its limit, last in the line and on stderr.
+    line["compared"] = result.get("compared", {})
+    log("compared (value, limit): " + json.dumps(line["compared"]))
     print(json.dumps(line))
     return 0
 

@@ -6,8 +6,9 @@
 * every name in BENCHMARK.json resolves to its files, readers and job kind;
 * the traffic generator is a pure function of the seed, and every seed offers
   the same sizes in the same cyclic order;
-* the serving window's arithmetic (``tests/test_bench_window.py``, under
-  pytest: lead-in, window edges, percentiles);
+* the serving window's arithmetic, the training start's two numbers, which
+  metrics a cell's line carries and the no-op test's arithmetic
+  (``tests/test_bench_window.py``, under pytest);
 * the trace reduction gives the pinned busy / idle / kernel numbers on the
   small recorded trace (``lib/trace_sample.json``, a slice of a chip trace),
   and agrees with a brute-force count;
@@ -47,8 +48,17 @@ def test_files_resolve():
         assert hasattr(importlib.import_module(
             "benchmarks.families." + cfg["family"]), "config")
         mix = load(os.path.join(HERE, "traffic", cell["traffic"] + ".json"))
-        assert hasattr(importlib.import_module(
-            "benchmarks.jobs." + mix["kind"]), "run")
+        kind = importlib.import_module("benchmarks.jobs." + mix["kind"])
+        assert hasattr(kind, "run")
+        # The job kind gives every end-to-end number that lists the cell.
+        if mix["kind"] == "serve_stream":
+            gives = set(kind.end_to_end(kind.reduce_window(
+                {"t0": 0.0, "t_end": 1.0, "requests": []}, 1.0)))
+        else:
+            gives = {"train_tokens_per_s", *kind.split_setup(0, 0, 0, 0)}
+        listed = {m["name"] for m in bench["end_to_end"]
+                  if cell["name"] in m.get("workloads", [])}
+        assert listed and listed <= gives | {"setup_s"}, (cell, listed, gives)
     for m in bench["per_layer"]:
         spec = load(os.path.join(HERE, "layer_metrics", m["name"] + ".json"))
         assert spec["name"] == m["name"]
@@ -58,6 +68,9 @@ def test_files_resolve():
         cells = m.get("workloads", [c["name"] for c in bench["workloads"]])
         assert all("workloads" not in moved or c in moved["workloads"]
                    for c in cells), m["name"]
+    assert sorted(n[:-5] for n in os.listdir(
+        os.path.join(HERE, "layer_metrics"))) == sorted(
+            m["name"] for m in bench["per_layer"])  # none left reading null
     peaks = load(os.path.join(HERE, "lib", "peaks.json"))
     assert peaks["TPU v5 lite"]["bf16_flops_per_s"] == 197e12
 
