@@ -1052,6 +1052,8 @@ class ObjectRefGenerator:
     def __init__(self, task_id: TaskID, worker: "CoreWorker"):
         self._task_id = task_id
         self._worker = worker
+        self._closed = False
+        self._error = None  # the task's, held by ``take`` for its next call
 
     def __iter__(self) -> "ObjectRefGenerator":
         return self
@@ -1066,6 +1068,26 @@ class ObjectRefGenerator:
         if kind == "err":
             raise value
         raise StopIteration
+
+    def take(self) -> list:
+        """Block until the next item, then return it WITH every item that
+        has arrived since, as ObjectRefs in order: a consumer that falls
+        behind its producer catches up a call, not an item, at a time.
+        ``[]`` once the stream has ended; the task's error raises after the
+        items that came before it have been returned."""
+        if self._closed:
+            error, self._error = self._error, None
+            if error is not None:
+                raise error
+            return []
+        got = self._worker._run_sync(
+            self._worker._stream_take(self._task_id)
+        )
+        kind, value = got[-1]
+        if kind != "item":
+            self._closed = True
+            self._error = value if kind == "err" else None
+        return [v for k, v in got if k == "item"] or self.take()
 
     def close(self):
         """Drop the stream (abandoned consumers must not leak the queue
@@ -2455,6 +2477,16 @@ class CoreWorker:
         if kind != "item":
             self._streams.pop(task_id, None)
         return (kind, value)
+
+    async def _stream_take(self, task_id: TaskID) -> list:
+        """``_stream_next``, then whatever else the queue holds already:
+        ``(kind, value)`` pairs in order, the last one perhaps the end."""
+        got = [await self._stream_next(task_id)]
+        state = self._streams.get(task_id)
+        while state is not None and not state["queue"].empty():
+            got.append(await self._stream_next(task_id))
+            state = self._streams.get(task_id)
+        return got
 
     def cancel_stream(self, task_id: TaskID):
         """Abandoned-generator cleanup (called from ObjectRefGenerator)."""

@@ -42,6 +42,18 @@ from .longcat_decode import (  # noqa: F401
     longcat_init_cache,
     longcat_prefill,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    nemotron_h_apply,
+    nemotron_h_init,
+    nemotron_h_loss,
+    nemotron_h_param_axes,
+)
+from .nemotron_h_decode import (  # noqa: F401
+    nemotron_h_decode_step,
+    nemotron_h_init_cache,
+    nemotron_h_prefill,
+)
 
 
 @_dataclasses.dataclass(frozen=True)
@@ -145,5 +157,22 @@ register_model_family(
         prefill_counted=_functools.partial(longcat_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             longcat_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    NemotronHConfig,
+    ModelFamily(
+        name="nemotron_h",
+        init=nemotron_h_init,
+        apply=nemotron_h_apply,
+        loss=nemotron_h_loss,
+        param_axes=nemotron_h_param_axes,
+        init_cache=nemotron_h_init_cache,
+        prefill=nemotron_h_prefill,
+        decode_step=nemotron_h_decode_step,
+        prefill_counted=_functools.partial(
+            nemotron_h_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            nemotron_h_decode_step, with_counts=True),
     ),
 )

@@ -58,15 +58,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .expert_share import EXPERT_CHUNK, held_choices, held_experts  # noqa: F401
 from .llama import _rmsnorm, rope
 
 ATTENTION = ("wq_a", "rms_q", "wq_b", "wkv_a", "rms_kv", "wkv_b", "wo")
 COUNT_NAMES = ("routed_total", "routed_zero", "routed_held", "experts_touched")
-# Rows of one expert's matrix product: a held expert sees few tokens (0.5 a
-# decode step, ~32 a 2048-token prefill), so its tokens are gathered and run
-# in chunks of at most this many rows; an expert no live token chose runs
-# nothing and reads no weight.
-EXPERT_CHUNK = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -251,51 +247,6 @@ def route(u, router, bias, cfg: LongcatConfig):
     return sel, w
 
 
-def held_experts(u, hit, w_held, experts, layer: int):
-    """``sum_e w_held[:, e] * SwiGLU_e(u)`` over the experts held here, for
-    the tokens that chose them: ``u [N, d]``, ``hit [N, Eh]`` bool,
-    ``w_held [N, Eh]`` float32 -> ``[N, d]`` float32.  Dropless: each
-    expert's tokens are gathered (hit rows first, in row order) and run in
-    chunks of ``EXPERT_CHUNK`` rows, as many chunks as its tokens need.  One
-    loop walks the chunks of all experts, so an expert nobody chose costs no
-    iteration and its weights are not read.  ``experts`` is the whole
-    layer-stacked subtree and ``[layer, e]`` is taken inside the loop: a
-    layer's slice taken outside it is copied (1.2 GB a layer at the
-    published sizes) before the loop may read it."""
-    n, d = u.shape
-    held = hit.shape[1]
-    out = jnp.zeros((n, d), jnp.float32)
-    if held == 0:  # a share with no expert: the identity part alone
-        return out
-    chunk = min(n, EXPERT_CHUNK)
-    padded = -(-n // chunk) * chunk
-    counts = hit.sum(0)  # [Eh] tokens of each expert
-    # Per expert, its rows first; the tail (and the padding to whole chunks)
-    # indexes past the last row, so the gather fills zeros and the scatter
-    # drops; every index is distinct.
-    order = jnp.argsort(~hit.T, axis=1, stable=True)
-    past = n + jnp.arange(padded)[None]
-    order = jnp.where(jnp.arange(n)[None] < counts[:, None], order,
-                      past[:, :n])
-    order = jnp.concatenate(
-        [order, jnp.broadcast_to(past[:, n:], (held, padded - n))], 1)
-    chunks = -(-counts // chunk)  # [Eh] chunks of each expert
-    ends = jnp.cumsum(chunks)
-
-    def one_chunk(i, out):
-        e = (ends <= i).sum()  # the expert whose chunk this is
-        first = (i - (ends[e] - chunks[e])) * chunk
-        rows = jax.lax.dynamic_slice(order, (e, first), (1, chunk))[0]
-        x = u.at[rows].get(mode="fill", fill_value=0)
-        w = w_held.at[rows, e].get(mode="fill", fill_value=0)
-        y = ffn(x, experts["w_gate"][layer, e], experts["w_up"][layer, e],
-                experts["w_down"][layer, e])
-        return out.at[rows].add(y * w[:, None], mode="drop",
-                                unique_indices=True)
-
-    return jax.lax.fori_loop(0, ends[-1], one_chunk, out)
-
-
 def moe(u, live, router, bias, experts, layer: int, cfg: LongcatConfig):
     """The expert layer's share on this chip.  ``u [N, d]`` normed tokens
     in float32 (the router and the identity experts read them as they are;
@@ -307,15 +258,14 @@ def moe(u, live, router, bias, experts, layer: int, cfg: LongcatConfig):
         sel, w = route(u, router, bias, cfg)
         w = jnp.where(live[:, None], w, 0.0)
         zero = sel >= cfg.n_routed_experts
-        local = sel - cfg.expert_offset
-        held = (local >= 0) & (local < cfg.experts_held) & live[:, None]
-        # [N, k] choices -> [N, Eh] combine weights of the held experts.
-        onehot = held[..., None] & (
-            local[..., None] == jnp.arange(cfg.experts_held))
-        hit = onehot.any(1)
-        w_held = (w[..., None] * onehot).sum(1)
-        y = held_experts(u.astype(jnp.dtype(cfg.dtype)), hit, w_held, experts,
-                         layer)
+        held, hit, w_held = held_choices(
+            sel, w, live, cfg.expert_offset, cfg.experts_held)
+
+        def swiglu(x, e):  # [layer, e] inside the loop: expert_share.py
+            return ffn(x, experts["w_gate"][layer, e],
+                       experts["w_up"][layer, e], experts["w_down"][layer, e])
+
+        y = held_experts(u.astype(jnp.dtype(cfg.dtype)), hit, w_held, swiglu)
         # Identity experts: one multiply-add, no weights.
         w_zero = (w * zero).sum(-1, keepdims=True)
         y = y + w_zero * u

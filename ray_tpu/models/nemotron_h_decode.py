@@ -1,0 +1,144 @@
+"""Nemotron-H prefill + decode through a cache whose leaves are of two kinds.
+
+``{"k", "v": [A, B, Hkv, T, D]}`` are the attentions' keys and values, with a
+position axis, as the Llama family's; ``{"conv": [M, B, (K-1)(HP + 2GN)],
+"ssm": [M, B, H, P, N]}`` are the Mamba-2 layers' state, float32, with NO
+position axis: the convolution's last ``K-1`` inputs (oldest first, side by
+side: three rows of 10240 as an axis of their own would be padded to the
+TPU's tile of eight) and the state ``S`` after the slot's last token.  The
+slot axis is axis 1 of every leaf, which is all ``llm/engine.py`` knows:
+``init_cache(cfg, 1, rung)`` gives a one-slot row whose state leaves do not
+depend on the rung, and ``splice_row`` writes it over the slot's, so an
+admission replaces a slot's state WHOLE while its keys and values beyond the
+rung keep what the last tenant left (decode reads nothing at or beyond
+``pos``).
+
+Prefill runs the chunked scan over the padded prompt with ``dt = 0`` at
+positions ``>= length``: the state it returns is the state at the prompt's
+TRUE length, whatever the rung, and the convolution's state is its last
+``K-1`` true inputs.  Decode runs one step of the recurrence for all slots:
+``S <- exp(dt A) S + dt x (x) B`` and ``y = S C + D x`` in float32, the layer's
+slice of the stacked ``ssm`` updated where it lies (the engine donates the
+cache; ``tests/test_tpu_compile.py`` reads the compiled step for a copy;
+the small ``conv`` leaf, of which every element moves every step, is built
+anew), and attention by the deferred-scatter protocol of ``llama_decode.py``: the
+cache holds ``[0, pos-1]``, the current key and value are merged as a last
+score, and all are written at the step's end by ``write_token_to_cache``.
+
+A decode row at position 0 is an idle slot (a prompt has at least one token):
+it chooses no expert and is not counted.  Its state is computed like any
+other's and stays finite: every step decays it by ``exp(dt A) < 1`` and adds
+a bounded term.  Both return ``(logits, cache)``; with ``with_counts=True``
+(the family's ``*_counted`` twins, which the engine runs) ``(logits, cache,
+counts)``: the routing counts of ``nemotron_h.py`` as int32 scalars.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.decode_attention import decode_attention, write_token_to_cache
+from .llama import _rmsnorm
+from .longcat import matmul
+from .nemotron_h import (NemotronHConfig, attention_project, mamba_output,
+                         mamba_project, nemotron_h_forward, run_layers,
+                         split_xbc)
+
+
+def nemotron_h_init_cache(cfg: NemotronHConfig, batch: int, max_len: int):
+    nm, na = cfg.kinds.count("M"), cfg.kinds.count("*")
+    kv = (na, batch, cfg.n_kv_head, max_len, cfg.head_dim)
+    dt = jnp.dtype(cfg.dtype)
+    return {
+        "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+        "conv": jnp.zeros((nm, batch, (cfg.conv_kernel - 1) * cfg.d_conv),
+                          jnp.float32),
+        "ssm": jnp.zeros((nm, batch, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                          cfg.ssm_state_size), jnp.float32),
+    }
+
+
+def nemotron_h_prefill(
+    params, tokens, lengths, cache, cfg: NemotronHConfig, *,
+    with_counts: bool = False
+) -> Tuple:
+    """tokens: [B, S] right-padded prompts; lengths: [B] true lengths.
+    Returns (last_logits [B, V], cache with keys and values of positions
+    [0, S) written and the state after position ``length - 1`` in place of
+    the slot's, routing counts of the positions < length)."""
+    x, kept, counts = nemotron_h_forward(params, tokens, lengths, cfg)
+    cache = dict(cache)
+    for name, new in kept.items():
+        if name in ("k", "v"):  # [A, B, S, Hkv, D] -> head-major
+            new = new.transpose(0, 1, 3, 2, 4)
+        cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    logits = matmul("be,ve->bv", last, params["lm_head"])
+    out = (logits, cache)
+    return (*out, counts) if with_counts else out
+
+
+def mamba_step(y, conv_state, state, m, i: int, cfg: NemotronHConfig):
+    """One token a row through Mamba-2 layer ``i``.  y ``[B, d]``,
+    conv_state ``[B, (K-1)(HP + 2GN)]``, state ``[B, H, P, N]`` -> (``[B, d]``
+    float32, the two states after the token, in the dtypes they came in)."""
+    r = cfg.mamba_num_heads // cfg.n_groups
+    z, xbc, dt = mamba_project(y, m, i, cfg)
+    window = jnp.concatenate(
+        [conv_state.astype(jnp.float32), xbc], axis=1)  # [B, K C]
+    conv = (window.reshape(-1, cfg.conv_kernel, cfg.d_conv)
+            * m["conv_w"][i]).sum(1) + m["conv_b"][i]
+    x, b, c = split_xbc(jax.nn.silu(conv), cfg)
+    b, c = (jnp.repeat(v, r, axis=1)[:, :, None] for v in (b, c))  # [B,H,1,N]
+    keep = jnp.exp(dt * -jnp.exp(m["a_log"][i]))  # [B, H]
+    new = (keep[..., None, None] * state.astype(jnp.float32)
+           + (dt[..., None] * x)[..., None] * b)
+    out = (new * c).sum(-1) + m["d_skip"][i][:, None] * x  # [B, H, P]
+    return (mamba_output(out, z, m, i, cfg),
+            window[:, cfg.d_conv:].astype(conv_state.dtype),
+            new.astype(state.dtype))
+
+
+def nemotron_h_decode_step(
+    params, tokens, pos, cache, cfg: NemotronHConfig, *,
+    with_counts: bool = False
+) -> Tuple:
+    """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
+    pos = jnp.asarray(pos)
+    blocks = params["blocks"]
+    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    cache = dict(cache)
+    new_conv, new_k, new_v = [], [], []
+
+    def mamba(i, y):
+        out, conv, ssm = mamba_step(
+            y, cache["conv"][i], cache["ssm"][i], blocks["mamba"], i, cfg)
+        new_conv.append(conv)
+        cache["ssm"] = cache["ssm"].at[i].set(ssm)
+        return out
+
+    def attend(i, y):
+        q, k, v = attention_project(y, blocks["attn"], i)
+        new_k.append(k.astype(cache["k"].dtype))
+        new_v.append(v.astype(cache["v"].dtype))
+        o = decode_attention(q, cache["k"], cache["v"], pos, i,
+                             k_self=new_k[-1], v_self=new_v[-1], kernel=False)
+        return matmul("bhd,hde->be", o.astype(y.dtype), blocks["attn"]["wo"][i])
+
+    x, counts = run_layers(params, x, pos > 0, mamba, attend, cfg)
+    if new_conv:
+        cache["conv"] = jnp.stack(new_conv)
+    if new_k:
+        cache["k"] = write_token_to_cache(
+            cache["k"], jnp.stack(new_k), pos, axis=3)
+        cache["v"] = write_token_to_cache(
+            cache["v"], jnp.stack(new_v), pos, axis=3)
+    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
+    logits = matmul("be,ve->bv", x, params["lm_head"])
+    out = (logits, cache)
+    return (*out, counts) if with_counts else out

@@ -283,18 +283,20 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
                     lambda: handle.options(stream=True).remote(body)
                 ),
             )
-            sentinel = object()
             try:
+                # One `data:` frame a chunk, and whatever chunks arrived
+                # while the last ones went out leave in one write: with many
+                # streams a chunk costs this process several thread hops,
+                # and a proxy that falls behind catches up a hop, not a
+                # chunk, at a time.
                 while True:
-                    chunk = await loop.run_in_executor(
-                        None, lambda: next(gen, sentinel)
-                    )
-                    if chunk is sentinel:
+                    chunks = await loop.run_in_executor(None, gen.take)
+                    if not chunks:
                         break
-                    await resp.write(
+                    await resp.write(b"".join(
                         b"data: " + json.dumps(chunk, default=str).encode()
-                        + b"\n\n"
-                    )
+                        + b"\n\n" for chunk in chunks
+                    ))
             except Exception as e:  # noqa: BLE001 — surface mid-stream errors
                 span.set_attribute("error", str(e))
                 await resp.write(

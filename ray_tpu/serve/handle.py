@@ -46,6 +46,12 @@ class DeploymentResponseGenerator:
         ref = next(self._gen)
         return ray_tpu.get(ref, timeout=self._timeout)
 
+    def take(self) -> list:
+        """Block until the next chunk, then return it with every chunk that
+        has arrived since (``ObjectRefGenerator.take``); ``[]`` at the end."""
+        refs = self._gen.take()
+        return ray_tpu.get(refs, timeout=self._timeout) if refs else []
+
 
 class _MethodCaller:
     def __init__(self, handle: "DeploymentHandle", method: str):

@@ -343,6 +343,49 @@ class TestStreamingGenerators:
             for _ in gen:
                 pass
 
+    def test_take_returns_what_has_arrived_in_order(self, cluster):
+        """``take()`` blocks for one item and hands over every item that
+        has arrived by then: a consumer that waited catches up in one call;
+        every item comes exactly once, in order; ``[]`` at the end."""
+        import time
+
+        @ray_tpu.remote
+        def burst(n):
+            for i in range(n):
+                yield i
+
+        gen = burst.remote(20)
+        time.sleep(2.0)  # all twenty have been pushed by now
+        first = gen.take()
+        assert len(first) > 1  # more than the one it blocked for
+        values = ray_tpu.get(first, timeout=60)
+        while True:
+            refs = gen.take()
+            if not refs:
+                break
+            values += ray_tpu.get(refs, timeout=60)
+        assert values == list(range(20))
+        assert gen.take() == []
+
+    def test_take_raises_after_the_items_before_the_error(self, cluster):
+        import time
+
+        @ray_tpu.remote
+        def bad():
+            yield 1
+            yield 2
+            raise RuntimeError("stream broke")
+
+        gen = bad.remote()
+        time.sleep(2.0)
+        values = []
+        with pytest.raises(Exception, match="stream broke"):
+            while True:
+                refs = gen.take()
+                assert refs  # the error comes before any empty batch
+                values += ray_tpu.get(refs, timeout=60)
+        assert values == [1, 2]
+
     def test_large_items_via_shm(self, cluster):
         import numpy as np
 

@@ -237,6 +237,52 @@ def test_http_sse_streaming(cluster):
     serve.delete("Ticker")
 
 
+def test_http_sse_sends_every_chunk_once_when_they_come_faster_than_it_writes(
+        cluster):
+    """The proxy takes whatever chunks have arrived in one hop
+    (``DeploymentResponseGenerator.take``): each is still one ``data:``
+    frame, in order, and a mid-stream error follows the chunks before it."""
+    import json as _json
+    import urllib.request
+
+    @serve.deployment(ray_actor_options={"num_cpus": 0})
+    class Burst:
+        def __call__(self, body):
+            for i in range(body["n"]):
+                yield {"i": i}
+            if body.get("fail"):
+                raise RuntimeError("burst broke")
+
+    handle = serve.run(Burst.bind(), route_prefix="/burst")
+    gen = handle.options(stream=True).remote({"n": 40})
+    got = []
+    while True:
+        chunks = gen.take()
+        if not chunks:
+            break
+        got += chunks
+    assert got == [{"i": i} for i in range(40)]
+
+    url = serve.start_http_proxy(port=8172)
+    for fail in (False, True):
+        req = urllib.request.Request(
+            f"{url}/burst",
+            data=_json.dumps({"stream": True, "n": 200, "fail": fail}).encode(),
+            headers={"Content-Type": "application/json"},
+        )
+        raw = urllib.request.urlopen(req, timeout=120).read().decode()
+        frames = [_json.loads(l[6:]) for l in raw.splitlines()
+                  if l.startswith("data: ") and l != "data: [DONE]"]
+        assert raw.rstrip().endswith("data: [DONE]")
+        assert frames[:200] == [{"i": i} for i in range(200)]
+        if fail:
+            assert len(frames) == 201 and "burst broke" in frames[200]["error"]
+        else:
+            assert len(frames) == 200
+    serve.stop_http_proxy()
+    serve.delete("Burst")
+
+
 class TestAutoscaleDrainRetire:
     def test_up_then_drain_then_down(self, cluster):
         """Queue pressure scales replicas up; idling scales down via

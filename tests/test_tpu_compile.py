@@ -112,7 +112,8 @@ def test_decode_kernel_refused_for_vmem_at_tinyllama_shape(
 
 
 # The serving cells' configurations (benchmarks/configs/<name>.json).
-CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32"]
+CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
+                "nemotron3_super_l11_ep4"]
 
 
 @pytest.fixture(scope="module")
@@ -178,24 +179,62 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
     its 28.6 ms on the chip, ``temp`` 1.085 GB), and the LongCat step a
     ``select`` fusion over the whole latent cache."""
     compiled, cache = cell_decode_step(name)
-    # bf16[16,16,8,2048,128] twice and bf16[8,32,2048,576]
-    leaves = "|".join({re.escape("bf16[%s]" % ",".join(map(str, leaf.shape)))
-                       for leaf in jax.tree.leaves(cache)})
+    # bf16[16,16,8,2048,128] twice; bf16[8,32,2048,576]; the hybrid's
+    # bf16[1,64,2,2048,128] twice and bf16[5,64,10240,3] (its float32 state
+    # has a test of its own, below)
+    leaves = {"bf16[%s]" % ",".join(map(str, leaf.shape)): leaf.size * 2
+              for leaf in jax.tree.leaves(cache)}
     producers = set(re.findall(
-        rf"^\s*(?:ROOT )?%(\S+) = (?:{leaves})\S* ([\w-]+)\(",
-        compiled.as_text(), re.M))
+        rf"^\s*(?:ROOT )?%(\S+) = ({'|'.join(map(re.escape, leaves))})\S* "
+        rf"([\w-]+)\(", compiled.as_text(), re.M))
     # The update is a ``dynamic-update-slice`` or, where the compiler can
     # see the tile is aligned, its fusion of read, select and write into
     # one (``select_dynamic-update-slice_fusion``): named after its root.
-    updates = {name for name, _ in producers
+    updates = {name for name, _, _ in producers
                if re.search("dynamic[-_]update[-_]slice", name)}
     assert updates
-    others = {(name, op) for name, op in producers if name not in updates
-              and op not in ("parameter", "get-tuple-element")}
+    # ``copy-start`` / ``copy-done`` of a leaf under 100 MB (the hybrid's
+    # 67 MB of keys, and of values) is a prefetch into the chip's fast
+    # memory and back; of Mistral's 1.07 GB it would be the copy again.
+    others = {(name, leaf, op) for name, leaf, op in producers
+              if name not in updates
+              and op not in ("parameter", "get-tuple-element")
+              and not (op == "copy-done" and leaves[leaf] < 100e6)}
     assert not others
     if name == "mistral7b_l16":
         # 0.745 GB
         assert compiled.memory_analysis().temp_size_in_bytes < 0.80e9
+
+
+def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
+    cell_decode_step
+):
+    """The Nemotron-H cell's decode step (published widths, one period of
+    11 layers, 128 experts held, 64 slots x 2048): every element of the 1.34
+    GB ``ssm`` leaf changes every step, so the least a step can do is read
+    it once and write it once, where it lies.  Each Mamba-2 layer's update
+    is ONE fusion rooted at the ``dynamic-update-slice`` of its slice into
+    the donated leaf (the leaf is aliased to the output: nothing is
+    allocated for it), nothing else produces an array of the leaf's shape,
+    and the step's temporaries stay far under one layer's slice (268 MB): a
+    stack of the layers' new states at the step's end, or a slice copied
+    out for its products, would be 1.34 GB more a step (PR 34's lesson)."""
+    compiled, cache = cell_decode_step("nemotron3_super_l11_ep4")
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 10.8e9
+    assert memory.alias_size_in_bytes > 1.5e9  # the whole cache, donated
+    assert memory.temp_size_in_bytes < 0.2e9   # 0.07 GB
+    shape = ",".join(map(str, cache["ssm"].shape))
+    assert shape == "5,64,128,64,128"
+    producers = re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(",
+        compiled.as_text(), re.M)
+    updates = [name for name, op in producers if op == "fusion"
+               and re.search("dynamic[-_]update[-_]slice", name)]
+    assert len(updates) == 5  # one a Mamba-2 layer
+    # (``dynamic-update-slice``: those fusions' own roots)
+    assert {op for name, op in producers if name not in updates} <= {
+        "parameter", "get-tuple-element", "dynamic-update-slice"}
 
 
 # Cache leaves as the families shape them (positions on the axis before the
