@@ -14,8 +14,8 @@ pytree of which the engine knows one thing, that every leaf's slot axis is
 axis 1 (``splice_row``); dense keys and values, or one latent leaf, are the
 same to it.  A family whose steps also return counts (``*_counted``: the
 routing counts of an expert layer) has them returned by the same two
-programs, read ONE STEP LATE (so a step still waits for the device once),
-summed in ``stats()`` and written on ``engine.counts``.
+programs, read ONE STEP LATE like the tokens (copies to the host started at
+dispatch), summed in ``stats()`` and written on ``engine.counts``.
 
 Shapes are static.  The cache is max_batch_size × max_seq_len, and so is
 the decode step.  Prefill runs at the PROMPT's length, not the cache's: one
@@ -25,46 +25,76 @@ writes covers positions ``[0, rung)`` of the slot.  Every rung is one
 compilation of the same ``prefill_one``, made when the engine is BUILT
 (ahead of time, in threads, while the weights load), so no prompt length
 meets a compiler later; an engine of ``max_seq_len <= 256`` has one rung.
-The decode step and two samplers over its ``[max_batch_size, V]`` logits
-compile at the first request: ``sample_logits_greedy`` when every active
-slot has temperature 0, ``sample_logits_rows`` otherwise (both also at
-``[1, V]``, for a prefill's first token).  Sampling parameters reach the
-sampler as per-row ARRAYS, so a new ``SamplingParams`` value compiles
-nothing; a step reads its tokens from the device ONCE, whatever the number
-of slots.  At ``temperature > 0`` the draws for a given ``seed`` differ from
-versions that split one key per slot on the host: the key is now split once
-a step, inside the program.
+The decode step is compiled there too, beside the rungs.  Two samplers over
+its ``[max_batch_size, V]`` logits compile at the first request:
+``sample_logits_greedy`` when every active slot has temperature 0,
+``sample_logits_rows`` otherwise (both also at ``[1, V]``, for a prefill's
+first token), and ``put_first_token``.  Sampling
+parameters reach the sampler as per-row ARRAYS, so a new ``SamplingParams``
+value compiles nothing.  At ``temperature > 0`` the draws for a given
+``seed`` differ from versions that split one key per slot on the host: the
+key is now split once a step, inside the program.
+
+The loop runs ONE STEP AHEAD of the device.  A step's sampled tokens stay on
+the device: the sampler's ``[max_batch_size]`` vector is the next decode's
+token operand as it is (an admission's first token is put into it at its
+row, on the device, as a new array), and ``pos`` is the host's, a COUNT it
+knows without the tokens' values.  One iteration: admit -> dispatch decode k
+(fed by step k - 1's vector) -> dispatch sampler k -> READ vector k - 1,
+whose copy to the host started when it was dispatched and which was complete
+before decode k could start -> read this iteration's admissions' first
+tokens (the host waits for their prefills here, with decode k queued behind
+them) -> retire, deliver.  The device always has a decode and a sampler
+queued while the host reads, retires, admits and launches; a step still
+reads ONE vector, whatever the number of slots, and one first token an
+admission.  Stops are of two kinds.  One the host knows by COUNT
+(``max_tokens``, the cache's extent) is known before the last token's value:
+the slot rides no further decode and its row is the next tenant's at once;
+the slot itself is kept with the unread vector until its last token is on
+the host.  A stop by VALUE (``stop_token``, the tokenizer's ``EOS``) is seen
+one step late: the row rides one decode more, whose token is dropped (never
+appended, delivered or counted; ``stats()["overrun_row_steps"]`` counts the
+row-steps so lost, a cancel with a step in flight among them), and whose
+write at the row's next position (and, in a recurrent family, into its
+state) is the next tenant's prefill's to replace, as ``splice_row`` does for
+every leaf.  Such a position is inside the cache: the count rule stops a
+slot at ``max_seq_len - 1``.
 
 Program names are a contract too: the benchmark's readers find the decode
 program as the only ``jit__lambda`` and the samplers by ``jit_sample_logits``,
 so whatever is jitted here beside the decode step is a NAMED function.
 
 Who steps: ONE thread an engine, ``engine.loop``.  It runs ``step()`` while
-anything is unfinished and otherwise sleeps on a condition that
-``add_request``, ``add_request_from_kv`` and ``shutdown`` notify; it starts
-when the first caller blocks in ``wait`` or ``stream_request`` (an engine
-stepped by hand through the public ``step()`` never grows a thread) and
-``shutdown()`` joins it.  Callers never step.  Each request has a mailbox.
-The tokens its slot gained in one step are put there in the next, as soon as
-that step's decode is dispatched (the device then has a step's work, and the
-woken callers' threads have the interpreter to themselves while the loop
-waits for it; a request's first token, sampled at admission, leaves in its
-own first step that way); its result is put there when it retires.  Its
-caller, blocked on the mailbox, takes everything it finds: a stream yields
-that as one delta, so one token a delta while the consumer keeps up, and the
-deltas grow by themselves while it does not.  No chunk size, no flush
-interval, no knob.  A step that raises fails every request there is.  The
-engine lock is the loop's for a step and an outside caller's
-(``cancel_request``, ``add_request_from_kv``, ``stats``) between two steps.
+anything is unfinished (a dispatched step whose tokens are unread counts) and
+otherwise sleeps on a condition that ``add_request``, ``add_request_from_kv``
+and ``shutdown`` notify; it starts when the first caller blocks in ``wait``
+or ``stream_request`` (an engine stepped by hand through the public
+``step()``, which is one iteration of the same loop, never grows a thread)
+and ``shutdown()`` joins it.  Callers never step.  Each request has a
+mailbox.  The tokens its slot gained are put there as they are read, which
+is after the iteration's decode is dispatched (the device then has a step's
+work, and the woken callers' threads have the interpreter to themselves
+while the loop waits for it; a request's first token leaves in the iteration
+that prefilled it); its result is put there when it retires, the iteration
+after the one that sampled its last token.  Its caller, blocked on the
+mailbox, takes everything it finds: a stream yields that as one delta, so
+one token a delta while the consumer keeps up, and the deltas grow by
+themselves while it does not.  No chunk size, no flush interval, no knob.  A
+step that raises fails every request there is.  The engine lock is the
+loop's for a step and an outside caller's (``cancel_request``,
+``add_request_from_kv``, ``stats``) between two steps, with a step in flight
+on the device.
 
 Observability (names are a contract: tests pin them, PERF.md lists which
 metric reads which).  Host work runs inside ``util.tracing.host_span``s —
-``engine.lock_wait`` (outside callers only), ``engine.step`` > ``engine.admit`` >
-(``engine.prefill.dispatch``, ``engine.sample``), ``engine.decode.dispatch``,
-``engine.sample``, ``engine.retire``, and a zero-length ``engine.counts`` at
-the end of every step — which a profiler session writes on the device
-trace's clock.  ``stats()`` gives the same counts with no session, and every
-step feeds ``flight_recorder.record_llm_step`` (``/metrics``).
+``engine.lock_wait`` (outside callers only), ``engine.step`` >
+``engine.admit`` > (``engine.prefill.dispatch``, ``engine.sample``: the first
+token's sampler and its way into the operand), ``engine.decode.dispatch``,
+``engine.sample`` (the sampler's dispatch and every read of the iteration) >
+``engine.retire``, and a zero-length ``engine.counts`` at the end of every
+step — which a profiler session writes on the device trace's clock.
+``stats()`` gives the same counts with no session, and every step feeds
+``flight_recorder.record_llm_step`` (``/metrics``).
 """
 
 from __future__ import annotations
@@ -123,15 +153,17 @@ def encode_prompt(tokenizer, prompt: str, max_seq_len: int) -> List[int]:
 class _Slot:
     request_id: int
     prompt_len: int
-    generated: List[int]
+    generated: List[int]  # the tokens that have reached the host
     params: SamplingParams
-    done: bool = False
+    sampled: int = 1  # tokens sampled for it on the device, read or not
+    done: bool = False  # stopped or cancelled: nothing more is appended
     delivered: int = 0  # of ``generated``, handed to the request's mailbox
 
     @property
     def last_pos(self) -> int:
-        """Cache position of the most recent token."""
-        return self.prompt_len + len(self.generated) - 1
+        """Cache position of the most recent token: a COUNT, which the host
+        knows without the token's value."""
+        return self.prompt_len + self.sampled - 1
 
 
 def _arrival() -> tuple:
@@ -196,6 +228,16 @@ def splice_row(cache, row, idx):
     return jax.tree.map(put, cache, row)
 
 
+def put_first_token(feed, token, idx):
+    """The decode step's ``[max_batch_size]`` token operand with row ``idx``
+    replaced by an admission's first token (``[1]``), as a NEW array: the
+    vector it is made from may still be unread, and holds there the last
+    token of the row's last tenant."""
+    import jax
+
+    return jax.lax.dynamic_update_slice(feed, token, (idx,))
+
+
 def _without_counts(step):
     """A family step that returns (logits, cache), as one that counts
     nothing: (logits, cache, {})."""
@@ -208,6 +250,7 @@ def _without_counts(step):
 class JaxLLMEngine:
     def __init__(self, cfg: EngineConfig, tokenizer=None):
         import jax
+        import jax.numpy as jnp
 
         self.cfg = cfg
         self.tokenizer = tokenizer or ByteTokenizer()
@@ -250,7 +293,8 @@ class JaxLLMEngine:
         self._counts: Dict[str, Any] = dict.fromkeys(
             ("steps", "loop_steps", "decode_steps", "admitted", "retired",
              "cancelled", "prompt_tokens", "padded_prompt_tokens",
-             "generated_tokens", "occupied_slot_steps", "host_syncs"), 0)
+             "generated_tokens", "occupied_slot_steps", "host_syncs",
+             "overrun_row_steps"), 0)
         self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
         prefill = fam.prefill_counted or _without_counts(fam.prefill)
@@ -285,22 +329,29 @@ class JaxLLMEngine:
             return jitted.lower(
                 self.params, self.cache, tokens, scalar, scalar).compile()
 
-        with ThreadPoolExecutor(len(self._prefill_rungs)) as pool:
-            self._prefill_one = dict(zip(
-                self._prefill_rungs,
-                pool.map(compile_rung, self._prefill_rungs)))
-        # Disaggregated admission: the one-slot cache arrives from a prefill
-        # replica instead of the local prefill program.
-        self._insert_row = jax.jit(splice_row, donate_argnums=(0,))
-        self._waiting_kv: List[tuple] = []  # (rid, meta, one-slot cache, ..)
-
+        # The decode step beside them: its tracing, lowering and the
+        # executable's load are seconds of a replica's first request
+        # otherwise (one executable, so it cannot compile a second time).
         # A lambda, so that the program keeps the name the readers know.
-        self._decode = jax.jit(
+        decode = jax.jit(
             lambda params, cache, tokens, pos: decode_step(
                 params, tokens, pos, cache, mcfg
             ),
             donate_argnums=(1,),
         )
+        a_slot = jax.ShapeDtypeStruct((cfg.max_batch_size,), np.int32)
+        with ThreadPoolExecutor(len(self._prefill_rungs) + 1) as pool:
+            decoding = pool.submit(lambda: decode.lower(
+                self.params, self.cache, a_slot, a_slot).compile())
+            self._prefill_one = dict(zip(
+                self._prefill_rungs,
+                pool.map(compile_rung, self._prefill_rungs)))
+            self._decode = decoding.result()
+        # Disaggregated admission: the one-slot cache arrives from a prefill
+        # replica instead of the local prefill program.
+        self._insert_row = jax.jit(splice_row, donate_argnums=(0,))
+        self._waiting_kv: List[tuple] = []  # (rid, meta, one-slot cache, ..)
+
         # What the family's programs count (int32 scalars a run; nothing for
         # a family that counts nothing), summed here as Python ints.
         names = () if fam.decode_step_counted is None else jax.eval_shape(
@@ -315,6 +366,16 @@ class JaxLLMEngine:
         self._unread_counts: List[tuple] = []
         self._sample_rows = jax.jit(sample_logits_rows)
         self._sample_greedy = jax.jit(sample_logits_greedy)
+        # The decode step's token operand lives on the device: the last
+        # step's sampled vector, with this step's admissions' first tokens
+        # put in.
+        self._put_first_token = jax.jit(put_first_token)
+        self._feed = jnp.zeros(cfg.max_batch_size, jnp.int32)
+        # The last decode step's sampled vector while the host has not read
+        # it, with who rode the step: (tokens on the device, [(row, slot)]).
+        # A slot that left its row by count is kept HERE until its last
+        # token is on the host.
+        self._unread: Optional[tuple] = None
 
     # ----------------------------------------------------------------- queue
     def add_request(
@@ -353,12 +414,25 @@ class JaxLLMEngine:
                 return i
         return None
 
-    def _admit_kv(self):
-        """Drain adopted-KV requests into free slots (no local prefill)."""
+    def _seat(self, idx: int, slot: _Slot, token) -> None:
+        """Row ``idx`` is ``slot``'s from the next decode on, and its first
+        token (``[1]``, on the device) is that decode's operand there.  A
+        request whose first token is its last by count has no decode to
+        ride and takes no row."""
+        if self._spent(slot, slot.sampled):
+            return
+        self._feed = self._put_first_token(self._feed, token, np.int32(idx))
+        self.slots[idx] = slot
+
+    def _admit_kv(self, jnp) -> List[_Slot]:
+        """Drain adopted-KV requests into free slots (no local prefill).
+        Returns those whose first token, which came with them, was their
+        last."""
+        stopped = []
         while self._waiting_kv:
             idx = self._free_slot()
             if idx is None:
-                return
+                break
             request_id, meta, row, t_arrive, trace_id = (
                 self._waiting_kv.pop(0))
             # A one-slot cache's extent is the family's to know: counted as
@@ -372,8 +446,13 @@ class JaxLLMEngine:
                     generated=[meta["first_token"]],
                     params=meta["sampling"],
                 )
-                self.slots[idx] = slot
                 self._check_done(slot, meta["first_token"])
+                if slot.done:
+                    stopped.append(slot)
+                else:  # the host's token: the decode's operand is the device's
+                    self._seat(idx, slot, jnp.asarray(
+                        [meta["first_token"]], jnp.int32))
+        return stopped
 
     def _admit_span(self, request_id: int, slot: int, prompt_len: int,
                     padded_len: int, t_arrive: float,
@@ -393,21 +472,24 @@ class JaxLLMEngine:
             prompt_len=prompt_len, padded_len=padded_len,
             queue_wait_ms=wait_s * 1e3, **attrs)
 
-    def _admit(self):
-        import jax.numpy as jnp
-
-        self._admit_kv()
+    def _admit(self, jnp) -> tuple:
+        """Fill free slots from the queues.  Nothing here waits for the
+        device: a prefill, its sampler and the first token's way into the
+        decode's operand are dispatched one behind the other.  Returns
+        (the first tokens sampled here, each ``(tokens [1] on the device,
+        [(0, slot)])`` as ``_absorb`` takes them; the adopted requests that
+        stopped at their first token)."""
+        firsts = []
+        stopped = self._admit_kv(jnp)
         while self._waiting:
             idx = self._free_slot()
             if idx is None:
-                return
+                break
             request_id, token_ids, params, t_arrive, trace_id = (
                 self._waiting.pop(0))
             rung = prefill_rung(self._prefill_rungs, len(token_ids))
             with self._admit_span(request_id, idx, len(token_ids),
                                   rung, t_arrive, trace_id):
-                # Returns before the device finishes: the wait for the
-                # prefill program shows in the sample span that follows.
                 with host_span("engine.prefill.dispatch"):
                     tokens = np.zeros(rung, np.int32)
                     tokens[: len(token_ids)] = token_ids
@@ -419,22 +501,24 @@ class JaxLLMEngine:
                         np.int32(idx),
                     )
                     self._note_counts("prefill", counts)
-                with host_span("engine.sample", slots=1):
-                    first = int(self._sample(logits, [(0, params)])[0])
-                self._counts["generated_tokens"] += 1
                 slot = _Slot(
                     request_id=request_id,
                     prompt_len=len(token_ids),
-                    generated=[first],
+                    generated=[],
                     params=params,
                 )
-                self.slots[idx] = slot
-                self._check_done(slot, first)
+                with host_span("engine.sample", slots=1):
+                    first = self._sample(logits, [(0, params)])
+                    self._seat(idx, slot, first)
+                firsts.append((first, [(0, slot)]))
+        return firsts, stopped
 
-    def _sample(self, logits, rows) -> np.ndarray:
-        """One token for every row of the device's ``logits`` in ONE program
-        and ONE device->host read; ``rows`` = (row, SamplingParams) of the
-        rows that matter, the others are computed and ignored."""
+    def _sample(self, logits, rows):
+        """One token for every row of the device's ``logits`` in ONE
+        program; ``rows`` = (row, SamplingParams) of the rows that matter,
+        the others are computed and ignored.  The tokens stay on the device
+        (``int32 [rows of logits]``) with their copy to the host under way:
+        ``_absorb`` reads them."""
         if all(p.temperature <= 0 for _, p in rows):
             tokens = self._sample_greedy(logits)
         else:
@@ -447,27 +531,56 @@ class JaxLLMEngine:
                     p.temperature, p.top_k, p.top_p)
             tokens, self._key = self._sample_rows(
                 logits, self._key, temperature, top_k, top_p)
-        self._counts["host_syncs"] += 1
-        return np.asarray(tokens)
+        tokens.copy_to_host_async()
+        return tokens
+
+    def _spent(self, slot: _Slot, n: int) -> bool:
+        """Whether ``n`` tokens are all the request may have: a stop known
+        by COUNT, so before the last token's value is."""
+        return (n >= slot.params.max_tokens
+                or slot.prompt_len + n >= self.cfg.max_seq_len - 1)
+
+    def _stop_token(self, slot: _Slot) -> Optional[int]:
+        if slot.params.stop_token is not None:
+            return slot.params.stop_token
+        return getattr(self.tokenizer, "EOS", None)
 
     def _check_done(self, slot: _Slot, token: int):
-        stop = (
-            slot.params.stop_token
-            if slot.params.stop_token is not None
-            else getattr(self.tokenizer, "EOS", None)
-        )
-        total_len = slot.prompt_len + len(slot.generated)
-        if (
-            (stop is not None and token == stop)
-            or len(slot.generated) >= slot.params.max_tokens
-            or total_len >= self.cfg.max_seq_len - 1
-        ):
+        """After ``token`` has joined ``slot.generated``."""
+        if (token == self._stop_token(slot)
+                or self._spent(slot, len(slot.generated))):
             slot.done = True
+
+    def _absorb(self, tokens, rows) -> List[_Slot]:
+        """ONE device->host read: a dispatched sampler's ``tokens`` reach
+        the slots that were in its ``rows``.  Returns the slots this ended.
+        A slot that is done already (it stopped by VALUE, which the host
+        sees a step late, or was cancelled with the step in flight) rode
+        that step for nothing: its token is dropped and counted."""
+        values = np.asarray(tokens)
+        c = self._counts
+        c["host_syncs"] += 1
+        ended = []
+        for i, s in rows:
+            if s.done:
+                c["overrun_row_steps"] += 1
+                continue
+            token = int(values[i])
+            s.generated.append(token)
+            c["generated_tokens"] += 1
+            self._check_done(s, token)
+            if s.done:
+                ended.append(s)
+        return ended
 
     # ------------------------------------------------------------------ step
     def step(self) -> List[dict]:
-        """Admit waiting requests, run ONE decode step for all active slots,
-        retire finished requests.  Returns newly finished outputs.
+        """One iteration of the loop: admit waiting requests, dispatch ONE
+        decode step and its sampler for every slot that holds a row, then
+        read what the LAST step sampled (and this step's admissions' first
+        tokens) and retire the requests that ended there.  Returns newly
+        finished outputs: a request's last token is read the step after the
+        one that sampled it, and ``has_unfinished()`` stays true until then.
         Thread-safe (serialized on the engine lock).  For a caller that IS
         the loop (the engine's own thread; a test or a debugger stepping by
         hand): everybody else adds a request and waits (``wait``,
@@ -546,12 +659,20 @@ class JaxLLMEngine:
             finally:
                 self._release()
 
+    def _held(self) -> List[_Slot]:
+        """Under the lock: the slots of the requests that are not over, in
+        a row or out of it by count with the last token unread (one that
+        is both comes twice)."""
+        leaving = self._unread[1] if self._unread is not None else ()
+        return [s for s in (*self.slots, *(s for _, s in leaving))
+                if s is not None and not s.done]
+
     def _deliver_tokens(self) -> None:
         """Under the lock: the tokens each live slot has gained since the
         last call go to its request's mailbox, which wakes its waiter.  (A
         request's last tokens are in its result: ``_retire``.)"""
-        for s in self.slots:
-            if s is not None and len(s.generated) > s.delivered:
+        for s in self._held():
+            if len(s.generated) > s.delivered:
                 box = self._mailboxes.get(s.request_id)
                 if box is not None:
                     box.put(s.generated[s.delivered:])
@@ -562,6 +683,7 @@ class JaxLLMEngine:
         its mailbox."""
         del self._waiting[:], self._waiting_kv[:]
         self.slots = [None] * len(self.slots)
+        self._unread = None
         for box in list(self._mailboxes.values()):
             box.put(error)
 
@@ -611,45 +733,48 @@ class JaxLLMEngine:
     def _step_locked(self, jnp) -> List[dict]:
         c = self._counts
         admitted0, retired0 = c["admitted"], c["retired"]
-        syncs0 = c["host_syncs"]
+        syncs0, overrun0 = c["host_syncs"], c["overrun_row_steps"]
         # Counts of the runs dispatched before this step: their programs
-        # will have ended when this step has read its own tokens.
+        # will have ended when this step has read the last step's tokens.
         late = len(self._unread_counts)
         with host_span("engine.step", seq=c["steps"]):
-            self._admit()
-            finished = self._retire()  # requests that finished at admission
-            active = [
-                (i, s) for i, s in enumerate(self.slots)
-                if s is not None and not s.done
-            ]
-            if active:
-                with host_span("engine.decode.dispatch", active=len(active)):
-                    tokens = np.zeros(self.cfg.max_batch_size, np.int32)
+            firsts, stopped = self._admit(jnp)
+            unread, self._unread = self._unread, None
+            # Whoever holds a row has a decode to ride (``_seat``; the rows
+            # given up by count, below).
+            riding = [(i, s) for i, s in enumerate(self.slots)
+                      if s is not None]
+            if riding:
+                with host_span("engine.decode.dispatch", active=len(riding)):
                     pos = np.zeros(self.cfg.max_batch_size, np.int32)
-                    for i, s in active:
-                        tokens[i] = s.generated[-1]
+                    for i, s in riding:
                         pos[i] = s.last_pos
                     logits, self.cache, counts = self._decode(
-                        self.params, self.cache,
-                        jnp.asarray(tokens), jnp.asarray(pos),
-                    )
+                        self.params, self.cache, self._feed, jnp.asarray(pos))
                     self._note_counts("decode", counts)
-                # The device has a step's work now: what the last step
-                # sampled (and this one's admissions) leaves the engine
-                # while it runs.  Handed over before the dispatch, the
-                # woken callers' threads would hold the interpreter when
-                # the loop needs it to start the device.
-                self._deliver_tokens()
-                with host_span("engine.sample", slots=len(active)):
-                    sampled = self._sample(
-                        logits, [(i, s.params) for i, s in active])
-                    for i, s in active:
-                        token = int(sampled[i])
-                        s.generated.append(token)
-                        self._check_done(s, token)
                 c["decode_steps"] += 1
-                c["generated_tokens"] += len(active)
-            finished.extend(self._retire())
+            with host_span("engine.sample", slots=len(riding)):
+                if riding:
+                    self._feed = self._sample(
+                        logits, [(i, s.params) for i, s in riding])
+                    self._unread = (self._feed, riding)
+                    for i, s in riding:
+                        s.sampled += 1
+                        if self._spent(s, s.sampled):
+                            # Known by count: the row is the next tenant's
+                            # now, the slot is ``_unread``'s until its last
+                            # token is on the host.
+                            self.slots[i] = None
+                # The device has this step queued; the host catches up
+                # with the last one, whose vector was complete before this
+                # step's decode could start, and then waits for this step's
+                # prefills.  Callers wake only here, when the interpreter
+                # is not needed to start the device.
+                if unread is not None:
+                    stopped += self._absorb(*unread)
+                finished = self._settle(stopped)
+                for tokens, rows in firsts:
+                    finished += self._settle(self._absorb(tokens, rows))
             occupied, waiting = self.occupied(), self._n_waiting()
             admitted = c["admitted"] - admitted0
             retired = c["retired"] - retired0
@@ -660,35 +785,42 @@ class JaxLLMEngine:
                            waiting=waiting, admitted=admitted,
                            retired=retired, waiters=len(self._blocked),
                            host_syncs=c["host_syncs"] - syncs0,
+                           overrun=c["overrun_row_steps"] - overrun0,
                            **self._fold_counts(late)):
                 pass
         flight_recorder.record_llm_step(
             occupied, waiting, admitted, retired, self.cfg.max_batch_size)
         return finished
 
-    def _retire(self) -> List[dict]:
+    def _settle(self, ended: List[_Slot]) -> List[dict]:
+        """The slots that ``ended`` retire, then every live slot's new
+        tokens go to its mailbox."""
+        out = self._retire(ended) if ended else []
+        self._deliver_tokens()
+        return out
+
+    def _retire(self, ended: List[_Slot]) -> List[dict]:
+        """Each slot's result to its mailbox, and its row freed if it still
+        holds one (a stop by value; a stop by count gave its row up when it
+        was known)."""
         out = []
         with host_span("engine.retire"):
+            for s in ended:
+                gen = s.generated
+                if gen and gen[-1] == self._stop_token(s):
+                    gen = gen[:-1]
+                result = {
+                    "request_id": s.request_id,
+                    "token_ids": gen,
+                    "text": self.tokenizer.decode(gen),
+                    "num_generated": len(s.generated),
+                }
+                box = self._mailboxes.get(s.request_id)
+                if box is not None:
+                    box.put(result)  # its caller wakes: no step is owed
+                out.append(result)
             for i, s in enumerate(self.slots):
                 if s is not None and s.done:
-                    gen = s.generated
-                    stop = (
-                        s.params.stop_token
-                        if s.params.stop_token is not None
-                        else getattr(self.tokenizer, "EOS", None)
-                    )
-                    if stop is not None and gen and gen[-1] == stop:
-                        gen = gen[:-1]
-                    result = {
-                        "request_id": s.request_id,
-                        "token_ids": gen,
-                        "text": self.tokenizer.decode(gen),
-                        "num_generated": len(s.generated),
-                    }
-                    box = self._mailboxes.get(s.request_id)
-                    if box is not None:
-                        box.put(result)  # its caller wakes: no step is owed
-                    out.append(result)
                     self.slots[i] = None
         self._counts["retired"] += len(out)
         return out
@@ -747,9 +879,10 @@ class JaxLLMEngine:
                         waiting=self._n_waiting())
 
     def has_unfinished(self) -> bool:
+        """Anything queued, in a slot, or sampled and not yet read."""
         return bool(self._waiting) or bool(self._waiting_kv) or any(
             s is not None for s in self.slots
-        )
+        ) or self._unread is not None
 
     # ------------------------------------------------------------- generate
     def cancel_request(self, request_id: int) -> None:
@@ -758,10 +891,13 @@ class JaxLLMEngine:
         with self.locked(request_id):
             dropped = _drop_queued(self._waiting, request_id)
             dropped += _drop_queued(self._waiting_kv, request_id)
-            for i, slot in enumerate(self.slots):
-                if slot is not None and slot.request_id == request_id:
-                    self.slots[i] = None
+            # What a step in flight samples for it is dropped when read.
+            for slot in self._held():
+                if slot.request_id == request_id and not slot.done:
+                    slot.done = True
                     dropped += 1
+            self.slots = [None if s is not None and s.done else s
+                          for s in self.slots]
             self._counts["cancelled"] += dropped
             self._mailboxes.pop(request_id, None)
 

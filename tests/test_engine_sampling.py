@@ -148,6 +148,15 @@ def test_one_host_read_a_decode_step_and_one_an_admission():
     assert stats["host_syncs"] == stats["decode_steps"] + stats["admitted"]
     engine.step()  # nothing to admit, nothing to decode: nothing to read
     assert engine.stats()["host_syncs"] == stats["host_syncs"]
+    # Mid-flight the last decode step's vector is unread: a step ahead.
+    engine.add_request("again", SamplingParams(max_tokens=5, stop_token=-1))
+    engine.step()
+    engine.step()
+    mid = engine.stats()
+    assert mid["host_syncs"] == mid["decode_steps"] - 1 + mid["admitted"]
+    drain(engine)
+    end = engine.stats()
+    assert end["host_syncs"] == end["decode_steps"] + end["admitted"]
 
 
 def test_a_new_sampling_params_value_compiles_nothing():
@@ -247,7 +256,215 @@ def test_host_syncs_per_step_metric_reads_the_counts_span(tmp_path):
     got = span_stat.read(ctx, **spec["args"])
     steps = now["steps"] - before["steps"]
     assert got == (now["host_syncs"] - before["host_syncs"]) / steps
-    assert got == (steps + 3) / steps  # every step decodes; three admissions
+    # Every step but the first reads what the one before it sampled (the
+    # last only reads); three admissions.
+    assert got == (steps - 1 + 3) / steps
     for span in trace.spans("engine.counts"):
         del span.stats["host_syncs"]
     assert span_stat.read(ctx, **spec["args"]) is None
+
+
+# ------------------------------------------------- one step ahead of the host
+# A step's sampled tokens feed the next decode on the device and are read a
+# step later; the stops the host knows by count free the row at once, a stop
+# by value is seen a step late and the row rides one step for nothing.
+GREEDY = dict(temperature=0.0)
+
+
+def alone(prompt, params):
+    """The request's result from an engine that serves nothing else."""
+    return make_engine().generate([prompt], params)[0]
+
+
+def value_stop(prompt, at_least=2):
+    """(stop token, ids before it): a token of the request's own greedy
+    stream that first shows at index >= ``at_least``, to stop it mid-way."""
+    ids = alone(prompt, SamplingParams(max_tokens=24, stop_token=-1,
+                                       **GREEDY))["token_ids"]
+    k = next(k for k in range(at_least, len(ids)) if ids[k] not in ids[:k])
+    return ids[k], ids[:k]
+
+
+def test_a_mixed_batch_gives_each_request_what_it_gets_alone():
+    """Admissions mid-flight, stops by ``max_tokens``, one by ``stop_token``
+    in the middle of its stream, one cut at ``max_seq_len - 1``: ids, text
+    and ``num_generated`` as alone; the over-run token is nowhere."""
+    stop, before_stop = value_stop("stops by value")
+    plan = [
+        ("a", SamplingParams(max_tokens=7, stop_token=-1, **GREEDY)),
+        ("stops by value", SamplingParams(max_tokens=24, stop_token=stop,
+                                          **GREEDY)),
+        ("x" * 50, SamplingParams(max_tokens=100, stop_token=-1, **GREEDY)),
+        ("bc", SamplingParams(max_tokens=1, stop_token=-1, **GREEDY)),
+        ("joins later", SamplingParams(max_tokens=9, stop_token=-1, **GREEDY)),
+        ("and later still", SamplingParams(max_tokens=5, stop_token=-1,
+                                           **GREEDY)),
+        ("the last one in", SamplingParams(max_tokens=3, stop_token=-1,
+                                           **GREEDY)),
+    ]
+    want = [alone(prompt, params) for prompt, params in plan]
+    assert want[1]["token_ids"] == before_stop  # the stop token cut off
+    assert want[1]["num_generated"] == len(before_stop) + 1
+    prompt_len = len(make_engine().tokenizer.encode("x" * 50))
+    assert want[2]["num_generated"] == SEQ - 1 - prompt_len  # the extent
+
+    engine = make_engine()
+    ids, done = [], {}
+    for i, (prompt, params) in enumerate(plan):  # staggered over the steps
+        ids.append(engine.add_request(prompt, params))
+        if i >= 2:
+            for _ in range(2):
+                done.update((r["request_id"], r) for r in engine.step())
+    done.update(drain(engine))
+    for rid, expected in zip(ids, want):
+        got = done[rid]
+        assert got["token_ids"] == expected["token_ids"]
+        assert got["text"] == expected["text"]
+        assert got["num_generated"] == expected["num_generated"]
+    stats = engine.stats()
+    assert stats["generated_tokens"] == sum(w["num_generated"] for w in want)
+    # Only the value stop rode a step too many: once.
+    assert stats["overrun_row_steps"] == 1
+    assert stats["host_syncs"] == stats["decode_steps"] + stats["admitted"]
+    assert stats["retired"] == len(plan) and not engine.has_unfinished()
+
+
+def test_streams_that_end_by_count_over_run_nothing():
+    engine = make_engine()
+    out = engine.generate(
+        ["a", "bc", "def", "ghij", "klmno"],
+        SamplingParams(max_tokens=5, stop_token=-1, **GREEDY))
+    assert [o["num_generated"] for o in out] == [5] * 5
+    assert engine.stats()["overrun_row_steps"] == 0
+    engine.shutdown()
+
+
+def test_a_step_dispatches_its_decode_before_it_reads_the_last_steps_tokens():
+    engine = make_engine()
+    calls = []
+    decode, absorb = engine._decode, engine._absorb
+
+    def spy_decode(*args):
+        calls.append("decode")
+        return decode(*args)
+
+    def spy_absorb(tokens, rows):
+        calls.append(("read", tokens.shape[0], [s.request_id for _, s in rows]))
+        return absorb(tokens, rows)
+
+    engine._decode, engine._absorb = spy_decode, spy_absorb
+    rid = engine.add_request("abc", SamplingParams(max_tokens=3,
+                                                   stop_token=-1, **GREEDY))
+    want = alone("abc", SamplingParams(max_tokens=3, stop_token=-1, **GREEDY))
+    # The step that prefills: the decode is dispatched behind the prefill,
+    # THEN the first token is read, and it is in the mailbox when the step
+    # returns (not a step later).
+    assert engine.step() == [] and calls == ["decode", ("read", 1, [rid])]
+    assert engine._mailboxes[rid].get_nowait() == want["token_ids"][:1]
+    # A later step: its own decode first, then the vector of the step before.
+    del calls[:]
+    assert engine.step() == [] and calls == ["decode", ("read", SLOTS, [rid])]
+    assert engine._mailboxes[rid].get_nowait() == want["token_ids"][1:2]
+    # Out of tokens by count: no decode to ride, the row is free, and the
+    # request is unfinished until its last token is on the host.
+    assert engine.occupied() == 0 and engine.has_unfinished()
+    del calls[:]
+    [result] = engine.step()
+    assert calls == [("read", SLOTS, [rid])]
+    assert result["token_ids"] == want["token_ids"]
+    assert not engine.has_unfinished()
+
+
+def test_the_decode_step_compiles_once():
+    """The decode program is ONE executable, compiled when the engine is
+    built: admissions, decodes, an adopted-KV request and a sampled
+    neighbour all run it (an operand of another kind would raise, not
+    compile again).  The program that puts a first token into its operand
+    compiles for the kinds of operand a first round brings and for nothing
+    after."""
+    from ray_tpu.collective.device_objects import device_object_store
+    from ray_tpu.llm.disagg import PrefillEngine
+
+    engine = make_engine()
+    greedy = SamplingParams(max_tokens=6, stop_token=-1, **GREEDY)
+    prefiller = PrefillEngine(engine.cfg)
+    want = alone("adopted", greedy)["token_ids"]
+
+    def a_round():
+        engine.add_request("first", greedy)
+        engine.step()
+        engine.add_request("joins a running batch", SamplingParams(
+            max_tokens=5, temperature=0.8, top_k=5, stop_token=-1))
+        drain(engine)
+        meta = prefiller.prefill("adopted", greedy)
+        store = device_object_store()
+        row = {"k": store.fetch(meta["k_ref"]),
+               "v": store.fetch(meta["v_ref"])}
+        rid = engine.add_request_from_kv(meta, row)
+        assert drain(engine)[rid]["token_ids"] == want
+
+    a_round()
+    assert isinstance(engine._decode, jax.stages.Compiled)
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_kw: compiles.append(name)
+        if name.endswith("backend_compile_duration") else None)
+    # jit keys its cache by the function, so engines share it: compare sizes.
+    size = engine._put_first_token._cache_size()
+    a_round()
+    assert not compiles
+    assert engine._put_first_token._cache_size() == size
+
+
+def test_cancel_with_a_step_in_flight_drops_its_token_and_frees_the_row():
+    greedy = SamplingParams(max_tokens=8, stop_token=-1, **GREEDY)
+    engine = make_engine()
+    gone = engine.add_request("cancelled mid-flight", greedy)
+    stays = engine.add_request("keeps going", greedy)
+    engine.step()
+    engine.step()
+    engine.cancel_request(gone)  # a decode that holds its row is in flight
+    assert engine.occupied() == 1 and gone not in engine._mailboxes
+    late = engine.add_request("takes the freed row", greedy)
+    done = drain(engine)
+    assert gone not in done
+    assert done[stays]["token_ids"] == alone("keeps going",
+                                             greedy)["token_ids"]
+    assert done[late]["token_ids"] == alone("takes the freed row",
+                                            greedy)["token_ids"]
+    stats = engine.stats()
+    assert stats["cancelled"] == 1 and stats["overrun_row_steps"] == 1
+    # A request out of its row by count, its last token unread: cancelled
+    # there, it never retires.
+    short = engine.add_request("short", SamplingParams(
+        max_tokens=2, stop_token=-1, **GREEDY))
+    engine.step()
+    assert engine.occupied() == 0 and engine.has_unfinished()
+    engine.cancel_request(short)
+    assert drain(engine) == {} and engine.stats()["cancelled"] == 2
+
+
+def test_shutdown_with_a_step_in_flight_fails_the_callers_and_leaves_a_sound_engine():
+    import threading
+
+    long = SamplingParams(max_tokens=40, stop_token=-1, **GREEDY)
+    engine = make_engine()
+    failed = []
+
+    def caller():
+        try:
+            for _delta in engine.generate_stream("shut down under me", long):
+                engine.shutdown()  # from a consumer: a step is in flight
+        except RuntimeError as e:
+            failed.append(e)
+
+    t = threading.Thread(target=caller)
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive() and len(failed) == 1
+    assert not engine.has_unfinished() and engine._unread is None
+    # By hand it still serves, whatever the dropped step left in the feed.
+    short = SamplingParams(max_tokens=6, stop_token=-1, **GREEDY)
+    rid = engine.add_request("after the shutdown", short)
+    assert drain(engine)[rid]["token_ids"] == alone(
+        "after the shutdown", short)["token_ids"]

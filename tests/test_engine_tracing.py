@@ -94,13 +94,19 @@ def test_spans_nest_per_thread(scenario):
     assert len(steps) == len(outside)
     for step in steps:
         kinds = [c.name for c in step.children]
-        assert set(kinds) <= {"engine.admit", "engine.retire", "engine.counts",
-                              "engine.decode.dispatch", "engine.sample"}
-        assert kinds[-1] == "engine.counts" and kinds.count("engine.counts") == 1
-        assert kinds.count("engine.retire") == 2
-        if "engine.decode.dispatch" in kinds:
-            i = kinds.index("engine.decode.dispatch")
-            assert kinds[i + 1] == "engine.sample"
+        # Admissions, then the decode's dispatch, then ONE sample span (the
+        # sampler's dispatch and every read of the step), then the counts.
+        admits = kinds.count("engine.admit")
+        decoded = "engine.decode.dispatch" in kinds
+        assert kinds == (["engine.admit"] * admits
+                         + ["engine.decode.dispatch"] * decoded
+                         + ["engine.sample", "engine.counts"])
+        sample = step.children[-2]
+        assert all(a.end <= b.start for a, b in zip(step.children,
+                                                    step.children[1:]))
+        # Requests retire where their last token is read: inside it.
+        assert {c.name for c in sample.children} <= {"engine.retire"}
+    assert len(trace.spans("engine.retire")) >= 1
     for admit in trace.spans("engine.admit"):
         assert [c.name for c in admit.children] == [
             "engine.prefill.dispatch", "engine.sample"]
@@ -127,8 +133,10 @@ def test_attributes_at_entry(scenario):
         for child in step.children:
             if child.name == "engine.decode.dispatch":
                 assert child.stats["active"] >= 1
-            if child.name == "engine.sample":
-                assert child.stats["slots"] >= 1
+            if child.name == "engine.sample":  # the rows that rode the step
+                assert child.stats["slots"] == sum(
+                    c.stats["active"] for c in step.children
+                    if c.name == "engine.decode.dispatch")
 
 
 def test_counts_once_per_step_and_equal_to_the_outside_count(scenario):
@@ -140,13 +148,18 @@ def test_counts_once_per_step_and_equal_to_the_outside_count(scenario):
     assert counts[0].stats["waiting"] == len(PROMPTS) - SLOTS
     assert counts[-1].stats == {"occupied": 0, "waiting": 0, "admitted": 0,
                                 "retired": counts[-1].stats["retired"],
-                                "waiters": 0, "host_syncs": 1}
+                                "waiters": 0, "host_syncs": 1, "overrun": 0}
     assert all(set(c.stats) == {"occupied", "waiting", "admitted", "retired",
-                                "waiters", "host_syncs"}
+                                "waiters", "host_syncs", "overrun"}
                for c in counts)
-    # One read for the step's tokens and one for each admission's first.
+    # One read for the tokens of the step BEFORE, if it decoded (the first
+    # step here follows a drained engine), one for each admission's first.
+    decoded = [any(c.name == "engine.decode.dispatch" for c in step.children)
+               for step in trace.spans("engine.step")]
+    assert decoded == [True] * (len(counts) - 1) + [False]  # the last reads
     assert [c.stats["host_syncs"] for c in counts] == [
-        1 + c.stats["admitted"] for c in counts]
+        before + c.stats["admitted"]
+        for before, c in zip([0] + decoded, counts)]
 
 
 def fresh_stats(engine, base):
@@ -163,7 +176,9 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
     assert with_session == {
         "steps": len(outside),
         "loop_steps": 0,  # stepped by hand: no loop, no thread
-        "decode_steps": len(outside),  # every step of the scenario decodes
+        # Every step decodes but the last, which reads what the one before
+        # it sampled.
+        "decode_steps": len(outside) - 1,
         "admitted": n, "retired": n, "cancelled": 0,
         "prompt_tokens": sum(len(engine.tokenizer.encode(p)) for p in PROMPTS),
         "padded_prompt_tokens": n * SEQ,
@@ -171,7 +186,8 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
         # The inside count equals the outside count, step for step.
         "occupied_slot_steps": sum(outside),
         # One token read a decode step, one a prefilled admission.
-        "host_syncs": len(outside) + n,
+        "host_syncs": len(outside) - 1 + n,
+        "overrun_row_steps": 0,  # every request ran to its max_tokens
     }
     assert engine.stats()["occupied"] == engine.occupied() == 0
     assert engine.stats()["waiting"] == 0
@@ -193,8 +209,8 @@ def test_stats_only_grow_and_waits_are_counted():
         "steps", "loop_steps", "decode_steps", "admitted", "retired",
         "cancelled", "prompt_tokens", "padded_prompt_tokens",
         "generated_tokens",
-        "occupied_slot_steps", "host_syncs", "queue_wait_s_total",
-        "lock_wait_s_total", "occupied", "waiting"}
+        "occupied_slot_steps", "host_syncs", "overrun_row_steps",
+        "queue_wait_s_total", "lock_wait_s_total", "occupied", "waiting"}
     engine.add_request("queued", SamplingParams(max_tokens=9, stop_token=-1))
     assert engine.stats()["waiting"] == 1 and engine.occupied() == 0
     engine.step()
@@ -218,7 +234,10 @@ def test_cancel_counts_what_it_dropped():
     engine.cancel_request(held)  # nothing left to drop
     stats = engine.stats()
     assert stats["cancelled"] == 2 and stats["occupied"] == 0
-    assert not engine.has_unfinished()
+    # The step in flight sampled a token for the row: it is read and dropped.
+    assert engine.has_unfinished() and stats["overrun_row_steps"] == 0
+    assert engine.step() == [] and not engine.has_unfinished()
+    assert engine.stats()["overrun_row_steps"] == 1
 
 
 def test_two_streams_wait_on_their_mailboxes_and_only_the_loop_steps(tmp_path):

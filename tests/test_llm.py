@@ -303,10 +303,10 @@ def test_sixteen_streams_at_once_get_the_ids_each_gets_alone():
 
 def test_a_delta_a_token_while_the_consumer_keeps_up(monkeypatch):
     """With a consumer that takes each delta before the next step runs, a
-    stream of n tokens is n deltas of one: the prefill's token leaves once
-    its step's decode is dispatched, each later one once the next step's is,
-    the last with the result (where the consumer may find the one before it
-    too)."""
+    stream of n tokens is n deltas of one: the prefill's token leaves in the
+    step that admitted it, once that step's decode is dispatched behind the
+    prefill; each later one in the step after the one that sampled it, once
+    that step's decode is dispatched; the last with the result."""
     n = 9
     p = SamplingParams(max_tokens=n, temperature=0.0, stop_token=-1)
     engine = _loop_engine()
@@ -318,11 +318,12 @@ def test_a_delta_a_token_while_the_consumer_keeps_up(monkeypatch):
     for delta in engine.generate_stream("keep up", p):
         deltas.append(_ids(delta))
         permits.release()
-    assert [len(d) for d in deltas[:n - 2]] == [1] * (n - 2)
-    assert len(deltas) in (n - 1, n)
+    assert [len(d) for d in deltas] == [1] * n
     assert sum(deltas, []) == want["token_ids"]
     after = engine.stats()
-    assert after["steps"] - before["steps"] == n - 1
+    # n - 1 decode steps, and one step more that reads the last one's token.
+    assert after["decode_steps"] - before["decode_steps"] == n - 1
+    assert after["steps"] - before["steps"] == n
     assert after["loop_steps"] == after["steps"]
 
 
@@ -336,7 +337,7 @@ def test_a_consumer_that_sleeps_gets_one_delta_with_all_it_missed(monkeypatch):
     permits.release()
     got = [_ids(next(stream))]
     _until(lambda: engine._counts["steps"] == steps + 1)
-    assert len(got[0]) == 1  # the step's second token waits for the next one
+    assert len(got[0]) == 1  # the step's decode's token is still the device's
     for _ in range(5):  # five steps while nobody looks
         permits.release()
     _until(lambda: engine._counts["steps"] == steps + 6)
@@ -447,7 +448,8 @@ def test_a_timeout_and_an_abandoned_stream_free_slot_and_queue_entry():
     holder.close()  # the consumer walks away: the slot goes
     stats = engine.stats()
     assert stats["cancelled"] == 3 and stats["occupied"] == 0
-    assert not engine.has_unfinished() and not engine._mailboxes
+    assert not engine._mailboxes
+    _until(lambda: not engine.has_unfinished())  # the step in flight is read
     time.sleep(0.1)  # a step that began before the cancel may end after it
     steps = engine.stats()["steps"]
     time.sleep(0.1)

@@ -256,7 +256,9 @@ def test_engine_serves_the_family_and_counts_its_routing():
     # Alone, one after the other: the counts can be recounted exactly.
     alone = [engine.generate([p], params)[0]["token_ids"] for p in PROMPTS]
     stats = engine.stats()
+    # Drained: every decode step's vector has been read, a step after it.
     assert stats["host_syncs"] == stats["decode_steps"] + stats["admitted"]
+    assert stats["overrun_row_steps"] == 0
     want = {k: 0 for k in zero if "routed" in k or "touched" in k}
     for prompt, generated in zip(PROMPTS, alone):
         ids = engine.tokenizer.encode(prompt)
@@ -279,6 +281,36 @@ def test_engine_serves_the_family_and_counts_its_routing():
     after = engine.stats()
     assert after["host_syncs"] == after["decode_steps"] + after["admitted"]
     assert after["routed_total"] > stats["routed_total"]
+
+
+def stepped(engine, prompt, params):
+    """One request through an engine stepped by hand; its result."""
+    rid = engine.add_request(prompt, params)
+    while True:
+        for result in engine.step():
+            if result["request_id"] == rid:
+                return result
+
+
+def test_a_row_that_over_ran_gives_its_next_tenant_what_a_fresh_engine_gives():
+    """A stop by value reaches the host a step late, so the row rides one
+    decode more, which writes a latent beyond the stream's end.  The next
+    tenant's prefill replaces the row: its ids are a fresh engine's."""
+    free = SamplingParams(max_tokens=16, stop_token=-1)
+    ids = stepped(make_engine(slots=1), PROMPTS[2], free)["token_ids"]
+    k = next(k for k in range(2, len(ids)) if ids[k] not in ids[:k])
+    engine = make_engine(slots=1)
+    first = stepped(engine, PROMPTS[2],
+                    SamplingParams(max_tokens=16, stop_token=ids[k]))
+    assert first["token_ids"] == ids[:k] and first["num_generated"] == k + 1
+    assert engine.has_unfinished()  # the step it rode for nothing, unread
+    second = stepped(engine, PROMPTS[0], free)
+    assert second["token_ids"] == stepped(
+        make_engine(slots=1), PROMPTS[0], free)["token_ids"]
+    stats = engine.stats()
+    assert stats["overrun_row_steps"] == 1
+    assert stats["generated_tokens"] == k + 1 + 16
+    assert stats["host_syncs"] == stats["decode_steps"] + stats["admitted"]
 
 
 def test_engine_counts_span_carries_the_routing_one_step_late(tmp_path):
@@ -310,7 +342,9 @@ def test_engine_counts_span_carries_the_routing_one_step_late(tmp_path):
         # One step late: the last decode step's counts are not written yet.
         assert 0 < written <= grown
         assert grown - written <= 2 * cfg_choices(engine)
+    # The vector of the step before, and a first token an admission.
     assert all(a["host_syncs"] <= 1 + a["admitted"] for a in seen)
+    assert all(a["overrun"] == 0 for a in seen)  # streams end by count
 
 
 def cfg_choices(engine):

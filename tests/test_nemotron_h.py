@@ -368,6 +368,27 @@ def by_hand(engine, prompts, params):
     return [done[i] for i in ids]
 
 
+def test_a_row_that_over_ran_gives_its_next_tenant_what_a_fresh_engine_gives():
+    """A stop by value reaches the host a step late, so the row rides one
+    decode more: its recurrent state takes a step beyond the stream's end
+    and its keys and values gain a position.  The next tenant's prefill
+    replaces every leaf of the row: its ids are a fresh engine's."""
+    free = SamplingParams(max_tokens=16, stop_token=-1)
+    [ids] = by_hand(make_engine(slots=1), [PROMPTS[2]], free)
+    k = next(k for k in range(2, len(ids)) if ids[k] not in ids[:k])
+    engine = make_engine(slots=1)
+    [first] = by_hand(engine, [PROMPTS[2]],
+                      SamplingParams(max_tokens=16, stop_token=ids[k]))
+    assert first == ids[:k]
+    assert engine.has_unfinished()  # the step it rode for nothing, unread
+    [second] = by_hand(engine, [PROMPTS[0]], free)
+    assert [second] == by_hand(make_engine(slots=1), [PROMPTS[0]], free)
+    stats = engine.stats()
+    assert stats["overrun_row_steps"] == 1
+    assert stats["generated_tokens"] == k + 1 + 16
+    assert stats["host_syncs"] == stats["decode_steps"] + stats["admitted"]
+
+
 def test_engine_slots_hold_state_beside_keys_and_values():
     """What ``llm/engine.py`` needed for recurrent state in its slots:
     nothing.  A slot's second tenant gives the ids it gives alone (the state
@@ -393,7 +414,9 @@ def test_engine_slots_hold_state_beside_keys_and_values():
     streamed = "".join(full.stream_request(rid))
     assert streamed == full.tokenizer.decode(alone[2])
     stats = full.stats()
+    # Drained: every decode step's vector has been read, a step after it.
     assert stats["host_syncs"] == stats["decode_steps"] + stats["admitted"]
+    assert stats["overrun_row_steps"] == 0  # every stream ended by count
     assert stats["routed_held"] > 0 and stats["prefill_routed_held"] > 0
     assert stats["experts_touched"] <= stats["routed_held"] < (
         stats["routed_total"])
