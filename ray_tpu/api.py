@@ -8,6 +8,8 @@ Equivalent of ray ``python/ray/_private/worker.py`` public functions
 from __future__ import annotations
 
 import atexit
+import logging
+import os
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 from .core import node as node_mod
@@ -30,6 +32,8 @@ from .core.placement import (  # noqa: F401
     remove_placement_group,
 )
 from .core.task_spec import ObjectRef  # noqa: F401
+
+logger = logging.getLogger(__name__)
 
 _local_node: Optional[node_mod.Node] = None
 _config_overrides_before: Optional[Dict[str, Any]] = None
@@ -150,6 +154,18 @@ def shutdown():
     global _local_node, _config_overrides_before
     worker = try_global_worker()
     if worker is not None:
+        if _local_node is not None and worker.task_events is not None:
+            # The driver that started the head leaves the session's trace
+            # beside its logs: the store dies with the control plane.
+            try:
+                from .util import tracing
+
+                tracing.write_spans(
+                    os.path.join(_local_node.log_dir, "spans.jsonl"),
+                    _local_node.session_id,
+                )
+            except Exception as e:  # noqa: BLE001 - shutdown goes on
+                logger.warning("spans.jsonl was not written: %s", e)
         worker.shutdown()
         set_global_worker(None)
         # Undo init()'s gc.freeze: without this, every init/shutdown

@@ -2030,6 +2030,27 @@ class ControlPlane:
             "num_span_drops": self.task_event_store.span_drop_total(),
         }
 
+    async def handle_collect_task_events(self, payload, conn):
+        """Every alive agent pulls its workers' task events and spans once
+        more, now (``obs_pull_now``): when this returns, the store holds
+        what the cluster had recorded when it was called."""
+
+        async def one(address):
+            try:
+                return await self.agent_clients.get(address).call(
+                    "obs_pull_now", {}, timeout=10, retries=1
+                )
+            except Exception:  # noqa: BLE001 — agent racing shutdown
+                return False
+
+        return sum(await asyncio.gather(
+            *(
+                one(entry.agent_address)
+                for entry in list(self.nodes.values())
+                if entry.alive
+            )
+        ))
+
     async def handle_list_objects(self, payload, conn):
         """Cluster-wide sealed-object listing: concurrent fan-out to every
         alive agent's directory (``ray list objects`` analog) — one wedged

@@ -3120,6 +3120,7 @@ class CoreWorker:
             owner_address=self.address,
             tensor_transport=tensor_transport,
             priority=priority,
+            trace_ctx=_tracing_context(),
         )
 
         async def register():
@@ -4025,8 +4026,20 @@ class CoreWorker:
             cls = await self._get_function(spec.class_id)
             args, kwargs = await self._resolve_args(spec.ctor_args_payload)
             loop = asyncio.get_running_loop()
+
+            def construct():
+                # An executor thread copies no context: the creator's
+                # trace is installed by hand, as for a task's body.
+                from ray_tpu.util.tracing import set_context
+
+                set_context(spec.trace_ctx and tuple(spec.trace_ctx))
+                try:
+                    return cls(*args, **kwargs)
+                finally:
+                    set_context(None)
+
             instance = await loop.run_in_executor(
-                self._task_executor, lambda: cls(*args, **kwargs)
+                self._task_executor, construct
             )
             self._hold_to_leased_chips()
             self.actor_instance = instance

@@ -204,6 +204,7 @@ class NodeAgent:
         # successful obs_report, so workers re-deliver un-forwarded
         # batches instead of losing them (at-least-once).
         self._obs_acks: Dict[str, int] = {}
+        self._obs_round_lock = asyncio.Lock()
 
     # ------------------------------------------------------------- lifecycle
     async def start(self) -> str:
@@ -418,11 +419,24 @@ class NodeAgent:
                 logger.warning("heartbeat round took %.1fs", took)
             await asyncio.sleep(period)
 
+    async def handle_obs_pull_now(self, payload, conn):
+        """A round outside the heartbeat's cadence: whoever reads the
+        store next (``tracing.write_spans``) finds what this node's
+        workers recorded up to now."""
+        await self._obs_pull_round()
+        return True
+
     async def _obs_pull_round(self):
         """One aggregator round: drain each ready local worker's
         observability buffers (obs_pull) and ship the merged batches to
         the control plane as one obs_report.  Per-worker failures are
-        isolated — a dying worker must not cost the node its telemetry."""
+        isolated — a dying worker must not cost the node its telemetry.
+        One round at a time: two would each re-deliver what the other's
+        ack has not yet covered."""
+        async with self._obs_round_lock:
+            await self._obs_pull_round_locked()
+
+    async def _obs_pull_round_locked(self):
         self._obs_rounds += 1
         timeout = max(1.0, GlobalConfig.health_check_period_s)
 

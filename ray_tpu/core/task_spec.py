@@ -136,6 +136,9 @@ class ActorSpec:
     # orders the control plane's pending-actor drain when freed capacity
     # is contended (docs/scheduling.md).
     priority: Optional[int] = None
+    # (trace_id, span_id) of the creator's active span: the constructor
+    # runs under it, as a task's body runs under ``TaskSpec.trace_ctx``.
+    trace_ctx: Optional[Tuple[str, str]] = None
 
 
 class ObjectRef:

@@ -94,7 +94,24 @@ token's sampler and its way into the operand), ``engine.decode.dispatch``,
 ``engine.retire``, and a zero-length ``engine.counts`` at the end of every
 step — which a profiler session writes on the device trace's clock.
 ``stats()`` gives the same counts with no session, and every step feeds
-``flight_recorder.record_llm_step`` (``/metrics``).
+``flight_recorder.record_llm_step`` (``/metrics``).  ``engine.admit`` carries
+the request's cluster ``trace_id`` and ``unix_ns``, the wall clock at its
+entry: the anchor between the device trace's clock and the cluster trace's
+(``util/tracing.py``).
+
+On the wall clock (``tracing.start_span`` / ``record_span``: the cluster
+trace, written to ``spans.jsonl`` at shutdown, whether or not a session
+ran): ``llm.engine.build`` (the constructor) > ``llm.engine.weights`` (the
+host's part of the load), ``llm.engine.compile`` (one a program: ``program``
+= ``prefill_one`` with its ``rung``, ``decode_step``); and ONE
+``engine.stream`` a streamed request, recorded by ``stream_request`` when
+the stream ends, under its caller's context: ``start`` = ``add_request``
+(after the tokenizer), ``request_id``, ``admitted_unix_ns``,
+``first_token_unix_ns`` (the loop's first ``put`` of a token into the
+mailbox), ``deltas`` and ``tokens`` (a delta is whatever the mailbox held:
+``tokens`` / ``deltas`` is 1.0 while the caller's thread keeps up with the
+loop).  ``stats()["stream_deltas"]`` and ``["stream_delta_tokens"]`` are
+their sums over the streams that have ended.  No span a token anywhere.
 """
 
 from __future__ import annotations
@@ -164,6 +181,18 @@ class _Slot:
         """Cache position of the most recent token: a COUNT, which the host
         knows without the token's value."""
         return self.prompt_len + self.sampled - 1
+
+
+class _Mailbox(queue.SimpleQueue):
+    """A request's mailbox (``JaxLLMEngine._mailboxes``), with the stamps of
+    its way through the engine that ``engine.stream`` reports: wall clock,
+    each written once by the one thread that knows it."""
+
+    def __init__(self):
+        super().__init__()
+        self.added = time.time()  # ``add_request``, after the tokenizer
+        self.admitted_unix_ns = 0  # the loop: ``engine.admit``'s entry
+        self.first_token_unix_ns = 0  # the loop: the first ``put`` of a token
 
 
 def _arrival() -> tuple:
@@ -249,6 +278,10 @@ def _without_counts(step):
 
 class JaxLLMEngine:
     def __init__(self, cfg: EngineConfig, tokenizer=None):
+        with tracing.start_span("llm.engine.build"):
+            self._build(cfg, tokenizer)
+
+    def _build(self, cfg: EngineConfig, tokenizer) -> None:
         import jax
         import jax.numpy as jnp
 
@@ -257,12 +290,16 @@ class JaxLLMEngine:
         mcfg = cfg.model
         fam = model_family(mcfg)
         self.family = fam
-        if cfg.param_loader is not None:
-            self.params = cfg.param_loader()
-        else:
-            self.params = fam.init(jax.random.PRNGKey(cfg.seed), mcfg)
-        self._key = jax.random.PRNGKey(cfg.seed + 1)
-        self.cache = fam.init_cache(mcfg, cfg.max_batch_size, cfg.max_seq_len)
+        # The host's part: a jitted loader returns before the device has
+        # run it, and the compilations below overlap what is left.
+        with tracing.start_span("llm.engine.weights"):
+            if cfg.param_loader is not None:
+                self.params = cfg.param_loader()
+            else:
+                self.params = fam.init(jax.random.PRNGKey(cfg.seed), mcfg)
+            self._key = jax.random.PRNGKey(cfg.seed + 1)
+            self.cache = fam.init_cache(
+                mcfg, cfg.max_batch_size, cfg.max_seq_len)
         # Per-slot state; None = free.
         self.slots: List[Optional[_Slot]] = [None] * cfg.max_batch_size
         self._next_id = itertools.count()
@@ -272,7 +309,7 @@ class JaxLLMEngine:
         # collected or cancelled it: whoever steps puts there the tokens
         # the request's slot gained (lists), then its result (a dict), or
         # the error that ended it (an exception).
-        self._mailboxes: Dict[int, queue.SimpleQueue] = {}
+        self._mailboxes: Dict[int, _Mailbox] = {}
         self._blocked: set = set()  # idents of threads waiting on a mailbox
         # ALL engine-state mutation serializes on this lock: the loop holds
         # it for a step, an outside caller (cancel, stats, an adopted
@@ -294,7 +331,7 @@ class JaxLLMEngine:
             ("steps", "loop_steps", "decode_steps", "admitted", "retired",
              "cancelled", "prompt_tokens", "padded_prompt_tokens",
              "generated_tokens", "occupied_slot_steps", "host_syncs",
-             "overrun_row_steps"), 0)
+             "overrun_row_steps", "stream_deltas", "stream_delta_tokens"), 0)
         self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
         prefill = fam.prefill_counted or _without_counts(fam.prefill)
@@ -324,10 +361,20 @@ class JaxLLMEngine:
         jitted = jax.jit(prefill_one, donate_argnums=(1,))
         scalar = jax.ShapeDtypeStruct((), np.int32)
 
+        build = tracing.current_context()
+
+        def compiled(program: str, lower, **attrs):
+            """One ``llm.engine.compile`` span a program.  A pool thread
+            copies no context: the build's is handed over."""
+            tracing.set_context(build)
+            with tracing.start_span(
+                    "llm.engine.compile", {"program": program, **attrs}):
+                return lower().compile()
+
         def compile_rung(rung: int):
             tokens = jax.ShapeDtypeStruct((rung,), np.int32)
-            return jitted.lower(
-                self.params, self.cache, tokens, scalar, scalar).compile()
+            return compiled("prefill_one", lambda: jitted.lower(
+                self.params, self.cache, tokens, scalar, scalar), rung=rung)
 
         # The decode step beside them: its tracing, lowering and the
         # executable's load are seconds of a replica's first request
@@ -341,8 +388,9 @@ class JaxLLMEngine:
         )
         a_slot = jax.ShapeDtypeStruct((cfg.max_batch_size,), np.int32)
         with ThreadPoolExecutor(len(self._prefill_rungs) + 1) as pool:
-            decoding = pool.submit(lambda: decode.lower(
-                self.params, self.cache, a_slot, a_slot).compile())
+            decoding = pool.submit(
+                compiled, "decode_step", lambda: decode.lower(
+                    self.params, self.cache, a_slot, a_slot))
             self._prefill_one = dict(zip(
                 self._prefill_rungs,
                 pool.map(compile_rung, self._prefill_rungs)))
@@ -384,7 +432,7 @@ class JaxLLMEngine:
         params = params or SamplingParams()
         token_ids = encode_prompt(self.tokenizer, prompt, self.cfg.max_seq_len)
         request_id = next(self._next_id)
-        self._mailboxes[request_id] = queue.SimpleQueue()  # before the queue
+        self._mailboxes[request_id] = _Mailbox()  # before the queue
         self._waiting.append((request_id, token_ids, params, *_arrival()))
         self._notify_loop()
         return request_id
@@ -401,7 +449,7 @@ class JaxLLMEngine:
         arrival = _arrival()  # before the wait for the lock
         with self.locked():
             request_id = next(self._next_id)
-            self._mailboxes[request_id] = queue.SimpleQueue()
+            self._mailboxes[request_id] = _Mailbox()
             self._waiting_kv.append(
                 (request_id, meta, jax.tree.map(jnp.asarray, row), *arrival)
             )
@@ -459,8 +507,13 @@ class JaxLLMEngine:
                     trace_id: Optional[str]):
         """Count one admission and open its ``engine.admit`` span (the
         attributes are all known at entry; ``trace_id`` joins the span to
-        the cluster trace of ``tracing.start_span``)."""
+        the cluster trace of ``tracing.start_span``, and ``unix_ns``, the
+        wall clock here, anchors that trace's clock to this one's)."""
         wait_s = time.perf_counter() - t_arrive
+        unix_ns = time.time_ns()
+        box = self._mailboxes.get(request_id)
+        if box is not None:
+            box.admitted_unix_ns = unix_ns
         c = self._counts
         c["admitted"] += 1
         c["prompt_tokens"] += prompt_len
@@ -470,7 +523,7 @@ class JaxLLMEngine:
         return host_span(
             "engine.admit", request_id=request_id, slot=slot,
             prompt_len=prompt_len, padded_len=padded_len,
-            queue_wait_ms=wait_s * 1e3, **attrs)
+            queue_wait_ms=wait_s * 1e3, unix_ns=unix_ns, **attrs)
 
     def _admit(self, jnp) -> tuple:
         """Fill free slots from the queues.  Nothing here waits for the
@@ -675,6 +728,8 @@ class JaxLLMEngine:
             if len(s.generated) > s.delivered:
                 box = self._mailboxes.get(s.request_id)
                 if box is not None:
+                    if not s.delivered:
+                        box.first_token_unix_ns = time.time_ns()
                     box.put(s.generated[s.delivered:])
                 s.delivered = len(s.generated)
 
@@ -817,6 +872,8 @@ class JaxLLMEngine:
                 }
                 box = self._mailboxes.get(s.request_id)
                 if box is not None:
+                    if not box.first_token_unix_ns:  # its first was its last
+                        box.first_token_unix_ns = time.time_ns()
                     box.put(result)  # its caller wakes: no step is owed
                 out.append(result)
             for i, s in enumerate(self.slots):
@@ -919,7 +976,9 @@ class JaxLLMEngine:
         and never steps: each delta is everything the loop has put there
         since the last one, so one token a delta while the consumer keeps
         up, and more by themselves while it does not."""
-        emitted = 0
+        box = self._mailboxes.get(request_id)
+        context = tracing.current_context()
+        deltas = emitted = 0
         deadline = time.monotonic() + timeout_s
         try:
             while True:
@@ -931,15 +990,29 @@ class JaxLLMEngine:
                         tokens.extend(item)
                 if done is not None:  # its ids hold the tail, stop cut off
                     tokens = done["token_ids"][emitted:]
+                emitted += len(tokens)
+                deltas += bool(tokens)
                 text = self.tokenizer.decode(tokens)
                 if text:
                     yield text
                 if done is not None:
                     return
-                emitted += len(tokens)
         finally:
-            # Timeout or abandoned consumer: release the slot/queue entry.
-            self.cancel_request(request_id)
+            end = time.time()
+            with self.locked(request_id):
+                self._counts["stream_deltas"] += deltas
+                self._counts["stream_delta_tokens"] += emitted
+                # Timeout or abandoned consumer: release the slot/queue
+                # entry.
+                self.cancel_request(request_id)
+            if box is not None:
+                tracing.record_span(
+                    "engine.stream", box.added, end,
+                    {"request_id": request_id,
+                     "admitted_unix_ns": box.admitted_unix_ns,
+                     "first_token_unix_ns": box.first_token_unix_ns,
+                     "deltas": deltas, "tokens": emitted},
+                    context=context)
 
     def wait(self, request_ids: List[int],
              timeout_s: float = 300.0) -> List[dict]:

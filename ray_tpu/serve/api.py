@@ -64,9 +64,17 @@ def run(
         raise TypeError("serve.run expects an Application or Deployment")
     from ray_tpu.core.usage import record_library_usage
 
+    from ray_tpu.util import tracing
+
     record_library_usage("serve")
-    controller = _get_or_create_controller()
-    return _deploy_app(app, controller, route_prefix)
+    # The root of a replica's start in the cluster trace.  It ends when the
+    # deployment is registered and its replicas are spawned (this call does
+    # not wait for them): ``serve.replica.spawn`` under it ends when a
+    # replica answers.
+    with tracing.start_span(
+            "serve.run", {"deployment": app.deployment.name}):
+        controller = _get_or_create_controller()
+        return _deploy_app(app, controller, route_prefix)
 
 
 def _deploy_app(
@@ -254,12 +262,18 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
     async def stream_sse(request: "web.Request", handle, body, name=""):
         import asyncio as _asyncio
         import contextvars as _cv
+        import time as _time
 
         from ray_tpu.util import tracing
 
         # One request-scoped span covering the whole stream; the trace id
         # goes out as a response header so clients can fetch the stitched
         # cross-process trace (driver/proxy -> replica -> downstream).
+        # What the stream did on its way out is on the span when it ends:
+        # ``route_ms`` (entry -> ``handle.remote`` returned), ``chunks``,
+        # ``writes`` (one a ``take()`` that brought something: chunks /
+        # writes is 1.0 while this process keeps up) and the wall clock
+        # when the first and the last write returned.
         with tracing.start_span(
             "serve.http.stream", {"route": request.path, "deployment": name}
         ) as span:
@@ -283,6 +297,8 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
                     lambda: handle.options(stream=True).remote(body)
                 ),
             )
+            span.set_attribute("route_ms", (_time.time() - span.start) * 1e3)
+            n_chunks = writes = first_write = last_write = 0
             try:
                 # One `data:` frame a chunk, and whatever chunks arrived
                 # while the last ones went out leave in one write: with many
@@ -297,12 +313,21 @@ def start_http_proxy(host: str = "127.0.0.1", port: int = 8000,
                         b"data: " + json.dumps(chunk, default=str).encode()
                         + b"\n\n" for chunk in chunks
                     ))
+                    last_write = _time.time_ns()
+                    if not writes:
+                        first_write = last_write
+                    n_chunks += len(chunks)
+                    writes += 1
             except Exception as e:  # noqa: BLE001 — surface mid-stream errors
                 span.set_attribute("error", str(e))
                 await resp.write(
                     b"data: " + json.dumps({"error": str(e)}).encode()
                     + b"\n\n"
                 )
+            span.attributes.update(
+                chunks=n_chunks, writes=writes,
+                first_write_unix_ns=first_write,
+                last_write_unix_ns=last_write)
             await resp.write(b"data: [DONE]\n\n")
             await resp.write_eof()
             return resp

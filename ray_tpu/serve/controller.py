@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu.core.serialization import loads_function
+from ray_tpu.util import tracing
 from ray_tpu.util.debug_locks import make_lock
 
 logger = logging.getLogger(__name__)
@@ -52,13 +53,18 @@ class Replica:
                  deployment_name: str = ""):
         import os as _os
 
-        obj = loads_function(payload)
-        if isinstance(obj, type):
-            self.callable = obj(*init_args, **init_kwargs)
-            self._is_class = True
-        else:
-            self.callable = obj
-            self._is_class = False
+        # Under the controller's ``serve.replica.spawn`` (the actor's
+        # creation carries its context): the user's code is imported and
+        # its constructor runs in here.
+        with tracing.start_span(
+                "serve.replica.init", {"deployment": deployment_name}):
+            obj = loads_function(payload)
+            if isinstance(obj, type):
+                self.callable = obj(*init_args, **init_kwargs)
+                self._is_class = True
+            else:
+                self.callable = obj
+                self._is_class = False
         self._ongoing = 0
         self._lock = make_lock("serve.replica.stats")
         self._total = 0
@@ -163,12 +169,12 @@ class Replica:
                 metadata["multiplexed_model_id"]
             )
         await self._user_sem.acquire()
+        sem_wait_s = time.perf_counter() - t_arrive
         # Per-chunk cost stays an append; histograms land in one batch at
         # stream end (TTFT + every inter-chunk gap — the inter-token
         # stall distribution the serving SLOs gate on).
         tele = flight_recorder.StreamTelemetry(
-            self._deployment, self._replica_tag,
-            time.perf_counter() - t_arrive,
+            self._deployment, self._replica_tag, sem_wait_s,
         )
         outcome = "ok"
         try:
@@ -228,6 +234,7 @@ class Replica:
                     "serve.request.stream", t_wall, time.time(),
                     {"deployment": self._deployment,
                      "replica": self._replica_tag,
+                     "sem_wait_ms": sem_wait_s * 1e3,
                      "ttft_s": tele.ttft_s,
                      "chunks": len(tele.gaps) + (1 if tele.ttft_s else 0),
                      "outcome": outcome},
@@ -379,6 +386,10 @@ class ServeController:
                 "max_ongoing_requests": max_ongoing_requests,
             }
             entry["version"] = version
+            # The deploy call's trace: a replica's ``serve.replica.spawn``
+            # parents to it whichever thread spawns it (the reconcile
+            # loop is not the caller's task).
+            entry["trace_ctx"] = tracing.current_context()
             # Normalize once at registration ('/v1/' == '/v1'); the proxy
             # does prefix matching against these keys.
             prefix = route_prefix or f"/{name}"
@@ -403,14 +414,25 @@ class ServeController:
             return {"name": name, "num_replicas": len(entry["replicas"])}
 
     def _spawn_replica(self, entry: dict):
+        """Create one replica actor under a ``serve.replica.spawn`` span,
+        which ends at the replica's first successful health check
+        (``_replace_dead_replicas``): lease, worker process, constructor."""
         spec = entry["spec"]
-        return Replica.options(**spec["opts"]).remote(
-            spec["payload"],
-            spec["init_args"],
-            spec["init_kwargs"],
-            spec.get("max_ongoing_requests", 16),
-            spec.get("name", ""),
-        )
+        span = tracing.detached_span(
+            "serve.replica.spawn", {"deployment": spec.get("name", "")},
+            context=entry.get("trace_ctx"))
+        with tracing.span_context(span):
+            handle = Replica.options(**spec["opts"]).remote(
+                spec["payload"],
+                spec["init_args"],
+                spec["init_kwargs"],
+                spec.get("max_ongoing_requests", 16),
+                spec.get("name", ""),
+            )
+        # With the handle, so it goes when the handle does; pickling a
+        # handle drops it.
+        handle._spawn_span = span
+        return handle
 
     def _set_replica_count(self, entry: dict, n: int,
                            drain: bool = False) -> None:
@@ -568,6 +590,9 @@ class ServeController:
             try:
                 ray_tpu.get(ref, timeout=per_replica_timeout)
                 fails.pop(hid, None)
+                span = h.__dict__.pop("_spawn_span", None)
+                if span is not None:  # its first answer: it is up
+                    tracing.finish_span(span)
             except Exception as e:  # noqa: BLE001
                 # Tolerate consecutive timeouts before replacing
                 # (reference: serve replica health uses a 30s+ budget):

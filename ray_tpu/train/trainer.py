@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
 from ray_tpu.core.serialization import dumps_function
+from ray_tpu.util import tracing
 
 from .backend import Backend, JaxBackend
 from .checkpoint import Checkpoint, CheckpointManager
@@ -95,7 +96,14 @@ class DataParallelTrainer:
         resize_reason = ""
 
         while attempts <= max(0, failure_cfg.max_failures):
-            group = self._create_group_elastic()
+            # One root span an attempt in the cluster trace.  It is the
+            # context of the gang's START only (placement, workers, backend,
+            # the ``run`` calls): the polls that follow would add a span
+            # each, five a second a worker, for as long as the job runs.
+            fit_span = tracing.detached_span(
+                "train.fit", {"attempt": attempts})
+            with tracing.span_context(fit_span):
+                group = self._create_group_elastic()
             if prev_world is not None and group.num_workers != prev_world:
                 from ray_tpu.util import flight_recorder
 
@@ -119,7 +127,9 @@ class DataParallelTrainer:
             prev_world = group.num_workers
             resize_reason = ""
             try:
-                self.backend.on_start(group)
+                with tracing.span_context(fit_span), tracing.start_span(
+                        "train.backend"):
+                    self.backend.on_start(group)
                 if self.collective_config is not None:
                     ray_tpu.get(
                         [
@@ -141,10 +151,11 @@ class DataParallelTrainer:
                         {name: split[name][i] for name in split}
                         for i in range(n)
                     ]
-                run_refs = group.run_async(
-                    payload, self.train_loop_config, ckpt_mgr.latest(),
-                    ckpt_mgr.run_dir, shards_per_worker,
-                )
+                with tracing.span_context(fit_span):
+                    run_refs = group.run_async(
+                        payload, self.train_loop_config, ckpt_mgr.latest(),
+                        ckpt_mgr.run_dir, shards_per_worker,
+                    )
                 result, grow_to = self._poll_until_done(
                     group, run_refs, ckpt_mgr, metrics_history
                 )
@@ -175,6 +186,8 @@ class DataParallelTrainer:
                     group.shutdown()
                 except Exception:
                     pass
+            finally:
+                tracing.finish_span(fit_span)
         return Result(
             metrics=metrics_history[-1] if metrics_history else {},
             checkpoint=ckpt_mgr.latest(),

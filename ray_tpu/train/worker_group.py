@@ -9,6 +9,7 @@ user ``train_loop_per_worker`` running on a thread inside each actor
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
@@ -18,6 +19,8 @@ from ray_tpu.core.placement import (
     placement_group_strategy,
     remove_placement_group,
 )
+
+from ray_tpu.util import tracing
 
 from .checkpoint import Checkpoint
 from .session import TrainContext, _clear_session, _set_session
@@ -58,6 +61,18 @@ class TrainWorker:
 
     def init_jax_distributed(self, coordinator: str, n: int, rank: int,
                              platform: str = "", peers=None):
+        with tracing.start_span(
+                "train.worker.jax_init", {"rank": rank}) as span:
+            return self._init_jax_distributed(
+                span, coordinator, n, rank, platform, peers)
+
+    def _init_jax_distributed(self, span, coordinator, n, rank, platform,
+                              peers):
+        """``span`` gets the three parts of its own length: ``import_s``
+        (``import jax``), ``initialize_s`` (the rendezvous) and
+        ``runtime_s`` (the first device query, which is this process's
+        device runtime starting, and returns when every member's has)."""
+        t_enter = time.time()
         import jax
 
         from ray_tpu.core import tpu_detect
@@ -66,16 +81,22 @@ class TrainWorker:
             jax.config.update("jax_platforms", platform)
         elif peers and tpu_detect.lease_holds_chips():
             tpu_detect.join_host_process_grid(rank, peers)
+        t_imported = time.time()
         jax.distributed.initialize(
             coordinator_address=coordinator, num_processes=n, process_id=rank,
             initialization_timeout=JAX_INIT_TIMEOUT_S,
         )
-        if jax.process_count() != n:
+        t_joined = time.time()
+        processes = jax.process_count()
+        span.attributes.update(
+            import_s=t_imported - t_enter, initialize_s=t_joined - t_imported,
+            runtime_s=time.time() - t_joined)
+        if processes != n:
             # The coordination service joined, the device runtime did not:
             # every member would train alone and call it data-parallel.
             raise RuntimeError(
                 f"jax.distributed joined {n} processes but this backend "
-                f"sees {jax.process_count()} ({jax.device_count()} devices)"
+                f"sees {processes} ({jax.device_count()} devices)"
             )
         return True
 
@@ -137,6 +158,8 @@ class TrainWorker:
             _should_stop_fn=lambda: self._stop_requested,
         )
         _set_session(ctx)
+        now = time.time()  # zero-length: the gang's start ends here
+        tracing.record_span("train.worker.loop", now, now, {"rank": self.rank})
         try:
             if config is not None:
                 train_fn(config)
@@ -168,11 +191,13 @@ class WorkerGroup:
         self.num_workers = num_workers
         self._own_pg = pg is None
         if pg is None and num_workers > 0:
-            pg = placement_group(
-                [dict(resources) for _ in range(num_workers)],
-                strategy=strategy if num_workers > 1 else "PACK",
-            )
-            pg.ready(timeout=120)
+            with tracing.start_span(
+                    "train.placement", {"bundles": num_workers}):
+                pg = placement_group(
+                    [dict(resources) for _ in range(num_workers)],
+                    strategy=strategy if num_workers > 1 else "PACK",
+                )
+                pg.ready(timeout=120)
         self.pg = pg
         self.workers = [
             TrainWorker.options(

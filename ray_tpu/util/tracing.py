@@ -28,9 +28,19 @@ Two sinks, two clocks — which to use:
   and records nothing.  It must begin and end on one thread and must not
   be held across ``await`` or a generator ``yield`` (annotations are a
   per-thread stack).  Attributes are fixed at entry; what is known only
-  at the end goes on a zero-length span written there.  The join between
-  the two traces is an attribute (``engine.admit`` carries the cluster
-  ``trace_id``), not a shared span.
+  at the end goes on a zero-length span written there.
+
+How the two relate: no span is shared, an ANCHOR is.  ``engine.admit`` (a
+``host_span``) carries the request's cluster ``trace_id`` and ``unix_ns``,
+the wall clock (``time.time_ns()``) at the span's entry.  Every admission a
+profiler session saw therefore gives one reading of (wall clock - the device
+trace's clock): the median over a session's admissions places any
+``start_span`` of those requests on the device trace's time base, and their
+spread says how well (about a millisecond a session).
+
+The first sink outlives the cluster: ``ray_tpu.shutdown()`` of the driver
+that started the head writes every span row to ``<log dir>/spans.jsonl``
+(``write_spans``; the row is in docs/observability.md).
 """
 
 from __future__ import annotations
@@ -38,6 +48,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import json
 import os
 import sys
 import time
@@ -162,15 +173,19 @@ def start_span(name: str, attributes: Optional[Dict[str, Any]] = None):
 
 
 def detached_span(name: str,
-                  attributes: Optional[Dict[str, Any]] = None) -> Span:
+                  attributes: Optional[Dict[str, Any]] = None,
+                  context: Optional[Tuple[str, str]] = None) -> Span:
     """Open a span WITHOUT installing it as the current context.
 
     For long-lived scopes that cross ``yield`` boundaries (the streaming
     data scheduler's generator pump): a ``start_span`` block entered
     inside a generator would leak its contextvar into the consumer's
     context between yields.  Scope individual operations to the span
-    with ``span_context``; close it with ``finish_span``."""
-    parent = _current.get()
+    with ``span_context``; close it with ``finish_span``.  ``context``:
+    an explicit (trace_id, parent_span_id), as ``record_span`` takes one,
+    for a thread that is not the caller's task (the serve controller's
+    reconcile loop)."""
+    parent = context if context is not None else _current.get()
     return Span(
         trace_id=parent[0] if parent else _rand_id(16),
         span_id=_rand_id(),
@@ -286,3 +301,43 @@ def get_trace(trace_id: str, timeout: float = 30.0,
         if len(spans) >= min_spans or time.monotonic() > deadline:
             return spans
         time.sleep(0.2)
+
+
+def write_spans(path: str, session_id: str = "",
+                timeout: float = 10.0) -> int:
+    """Write every span the control plane's store holds to ``path``, one
+    JSON object a line: first ``{"session", "dropped_spans", "spans"}``
+    (``dropped_spans`` as ``get_trace`` reads it: above 0 the file has
+    holes), then a row a span (``name``, ``start``, ``end``, ``trace_id``,
+    ``span_id``, ``parent_id``, the recording process's ``worker_id`` and
+    ``node_id``, ``attributes``).  This process's buffer is flushed first
+    and every node agent pulls its workers' once more, so what the cluster
+    recorded up to this call is in the file.  Returns the number of spans."""
+    from ray_tpu.core.core_worker import global_worker
+
+    w = global_worker()
+    w._run_sync(w.task_events.flush())
+    w._run_sync(w.cp.call("collect_task_events", {}, timeout=timeout))
+    reply = w._run_sync(
+        w.cp.call("list_task_events", {"limit": 1}, timeout=timeout))
+    rows = []
+    for ev in reply.get("profile_events", ()):
+        extra = dict(ev.get("extra") or {})
+        if not extra.pop("span", False):
+            continue
+        rows.append({
+            "name": ev["name"], "start": ev["start"], "end": ev["end"],
+            "trace_id": extra.pop("trace_id", None),
+            "span_id": extra.pop("span_id", None),
+            "parent_id": extra.pop("parent_id", None),
+            "worker_id": ev.get("worker_id"), "node_id": ev.get("node_id"),
+            "attributes": extra,
+        })
+    head = {"session": session_id,
+            "dropped_spans": int(reply.get("num_span_drops", 0)),
+            "spans": len(rows)}
+    with open(path + ".tmp", "w") as f:
+        for row in (head, *rows):
+            f.write(json.dumps(row, default=str) + "\n")
+    os.replace(path + ".tmp", path)
+    return len(rows)

@@ -10,6 +10,7 @@ Nothing here times anything.
 import subprocess
 import sys
 import threading
+import time
 
 import pytest
 
@@ -125,7 +126,8 @@ def test_attributes_at_entry(scenario):
     for admit, prompt in zip(admits, PROMPTS):
         st = admit.stats
         assert set(st) == {"request_id", "slot", "prompt_len", "padded_len",
-                           "queue_wait_ms"}  # no cluster trace: no trace_id
+                           "queue_wait_ms",  # no cluster trace: no trace_id
+                           "unix_ns"}
         assert st["prompt_len"] == len(engine.tokenizer.encode(prompt))
         assert st["padded_len"] == SEQ and 0 <= st["slot"] < SLOTS
         assert st["queue_wait_ms"] >= 0
@@ -188,6 +190,7 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
         # One token read a decode step, one a prefilled admission.
         "host_syncs": len(outside) - 1 + n,
         "overrun_row_steps": 0,  # every request ran to its max_tokens
+        "stream_deltas": 0, "stream_delta_tokens": 0,  # nobody streamed
     }
     assert engine.stats()["occupied"] == engine.occupied() == 0
     assert engine.stats()["waiting"] == 0
@@ -210,6 +213,7 @@ def test_stats_only_grow_and_waits_are_counted():
         "cancelled", "prompt_tokens", "padded_prompt_tokens",
         "generated_tokens",
         "occupied_slot_steps", "host_syncs", "overrun_row_steps",
+        "stream_deltas", "stream_delta_tokens",
         "queue_wait_s_total", "lock_wait_s_total", "occupied", "waiting"}
     engine.add_request("queued", SamplingParams(max_tokens=9, stop_token=-1))
     assert engine.stats()["waiting"] == 1 and engine.occupied() == 0
@@ -297,9 +301,75 @@ def test_admit_carries_the_cluster_trace_id(tmp_path):
         while engine.has_unfinished():
             engine.step()
 
+    t0 = time.time_ns()
     first, second = traced(tmp_path, body).spans("engine.admit")
     assert first.stats["trace_id"] == body.trace_id
     assert "trace_id" not in second.stats
+    # The anchor between the two clocks: the wall clock at the span's entry,
+    # on every admission, traced by the cluster or not.
+    assert t0 <= first.stats["unix_ns"] <= second.stats["unix_ns"] <= (
+        time.time_ns())
+    # One session, one clock relation: the two readings of (wall clock -
+    # the trace's clock) agree to well under the check's 2 ms.
+    offsets = [s.stats["unix_ns"] - s.start for s in (first, second)]
+    assert abs(offsets[0] - offsets[1]) < 2e6
+
+
+def test_one_engine_stream_row_a_streamed_request(monkeypatch):
+    """``engine.stream``: one wall-clock span a streamed request, recorded
+    when the stream ends under its caller's context, from the stamps the
+    request's mailbox holds; ``stats()`` sums what the rows count."""
+    rows = []
+    monkeypatch.setattr(tracing, "_record", rows.append)
+    engine = make_engine()
+    # The build, with the host's part of the load and one span a program.
+    assert sorted(r.name for r in rows) == [
+        "llm.engine.build", "llm.engine.compile", "llm.engine.compile",
+        "llm.engine.weights"]
+    build = rows[-1]
+    assert build.name == "llm.engine.build"
+    programs = {(r.attributes["program"], r.attributes.get("rung"))
+                for r in rows if r.name == "llm.engine.compile"}
+    assert programs == {("prefill_one", SEQ), ("decode_step", None)}
+    assert all((r.trace_id, r.parent_id) == (build.trace_id, build.span_id)
+               for r in rows[:-1])
+    del rows[:]
+    engine.generate(["warm"], SamplingParams(max_tokens=2, stop_token=-1))
+    assert not rows  # a unary request writes none
+    before = engine.stats()
+    lengths = {"first": 9, "second": 1, "third": 5}  # one whose first is last
+    got, contexts = {}, {}
+
+    def stream(prompt):
+        with tracing.start_span("caller") as span:
+            contexts[prompt] = (span.trace_id, span.span_id)
+            got[prompt] = list(engine.generate_stream(prompt, SamplingParams(
+                max_tokens=lengths[prompt], stop_token=-1)))
+
+    t0 = time.time()
+    threads = [threading.Thread(target=stream, args=(p,)) for p in lengths]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    streams = [r for r in rows if r.name == "engine.stream"]
+    assert len(streams) == len(lengths)
+    assert len({r.attributes["request_id"] for r in streams}) == len(lengths)
+    by_parent = {(r.trace_id, r.parent_id): r for r in streams}
+    for prompt, n in lengths.items():
+        row = by_parent[contexts[prompt]]
+        a = row.attributes
+        assert set(a) == {"request_id", "admitted_unix_ns",
+                          "first_token_unix_ns", "deltas", "tokens"}
+        assert t0 <= row.start <= a["admitted_unix_ns"] / 1e9 <= (
+            a["first_token_unix_ns"] / 1e9) <= row.end <= time.time()
+        assert 1 <= a["deltas"] <= a["tokens"] == n
+    after = engine.stats()
+    assert after["stream_delta_tokens"] - before["stream_delta_tokens"] == (
+        sum(lengths.values()))
+    assert after["stream_deltas"] - before["stream_deltas"] == sum(
+        r.attributes["deltas"] for r in streams)
+    engine.shutdown()
 
 
 def test_start_span_opens_no_annotation(tmp_path):
