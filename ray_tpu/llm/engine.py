@@ -23,9 +23,15 @@ request a call, padded to the smallest rung of ``prefill_ladder(max_seq_len)``
 (256, 512, 1024, … and ``max_seq_len`` itself) that holds it, and the row it
 writes covers positions ``[0, rung)`` of the slot.  Every rung is one
 compilation of the same ``prefill_one``, made when the engine is BUILT
-(ahead of time, in threads, while the weights load), so no prompt length
-meets a compiler later; an engine of ``max_seq_len <= 256`` has one rung.
-The decode step is compiled there too, beside the rungs.  Two samplers over
+(ahead of time, in threads), so no prompt length meets a compiler later; an
+engine of ``max_seq_len <= 256`` has one rung.  The decode step is compiled
+there too, FIRST, because it decides where the weights lie: the compiler
+chooses their device layouts for it (``jit_decode_step``), the rungs are
+compiled for the weights as they will lie there, so that no program relays
+a weight when it runs, and the build moves each leaf that lies otherwise
+into the layout chosen, once (``stats()["relaid_param_bytes"]``; logical
+shapes, dtypes and values are the loader's).  All of that is compiled from
+shapes, beside the weights' load.  Two samplers over
 its ``[max_batch_size, V]`` logits compile at the first request:
 ``sample_logits_greedy`` when every active slot has temperature 0,
 ``sample_logits_rows`` otherwise (both also at ``[1, V]``, for a prefill's
@@ -102,8 +108,10 @@ entry: the anchor between the device trace's clock and the cluster trace's
 On the wall clock (``tracing.start_span`` / ``record_span``: the cluster
 trace, written to ``spans.jsonl`` at shutdown, whether or not a session
 ran): ``llm.engine.build`` (the constructor) > ``llm.engine.weights`` (the
-host's part of the load), ``llm.engine.compile`` (one a program: ``program``
-= ``prefill_one`` with its ``rung``, ``decode_step``); and ONE
+host's part of the load), ``llm.engine.relayout`` (the dispatch of the
+weights' move into the decode step's layouts, ``relaid_param_bytes``),
+``llm.engine.compile`` (one a program: ``program`` = ``prefill_one`` with
+its ``rung``, ``decode_step``); and ONE
 ``engine.stream`` a streamed request, recorded by ``stream_request`` when
 the stream ends, under its caller's context: ``start`` = ``add_request``
 (after the tokenizer), ``request_id``, ``admitted_unix_ns``,
@@ -276,6 +284,72 @@ def _without_counts(step):
     return counted
 
 
+def jit_decode_step(family, model, params):
+    """The decode program as the engine jits it, ``(params, cache, tokens,
+    pos) -> (logits, cache, counts)`` with the cache donated and the PARAMS'
+    device layouts left to the compiler.  ``params``: a pytree of anything
+    with a ``sharding`` (arrays, ``jax.ShapeDtypeStruct``s); a leaf stays
+    where that puts it.  Lowered from SHAPES and compiled, the program's
+    ``input_formats[0][0]`` say how it wants each weight laid out: the default
+    for most, another where a product could not read the default in place
+    and the step would copy the weight first, every time it runs (Mistral's
+    ``wq`` / ``wk`` / ``wv``: 0.81 GB a step).  A lambda, so that the program
+    keeps the name the benchmark's readers know."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    decode_step = (family.decode_step_counted
+                   or _without_counts(family.decode_step))
+    anywhere = jax.tree.map(
+        lambda leaf: Format(Layout.AUTO, leaf.sharding), params)
+    return jax.jit(
+        lambda params, cache, tokens, pos: decode_step(
+            params, tokens, pos, cache, model),
+        in_shardings=(anywhere, None, None, None),
+        donate_argnums=(1,),
+    )
+
+
+def jit_prefill_one(family, model):
+    """The prefill program as the engine jits it, ``(params, cache, tokens
+    [rung], length, slot_idx) -> (logits [1, V], cache, counts)`` with the
+    cache donated: ONE named function under one jit (the program's name is
+    what the benchmark's reader finds), lowered once a rung."""
+    import jax
+    import jax.numpy as jnp
+
+    prefill = family.prefill_counted or _without_counts(family.prefill)
+
+    def prefill_one(params, cache, tokens, length, slot_idx):
+        """Prefill a single request, padded to ``tokens``' length (a
+        rung), into positions ``[0, rung)`` of batch row ``slot_idx``.
+        What the slot's last tenant left beyond the rung stays: decode
+        reads nothing at or beyond a slot's ``pos``."""
+        one_cache = family.init_cache(model, 1, tokens.shape[0])
+        logits, one_cache, counts = prefill(
+            params, tokens[None], jnp.asarray([length]), one_cache, model
+        )
+        # [1, V]: a batch of one for the sampler
+        return logits, splice_row(cache, one_cache, slot_idx), counts
+
+    return jax.jit(prefill_one, donate_argnums=(1,))
+
+
+def lay_out(params, formats):
+    """``params`` with every leaf in its ``formats`` leaf's device layout
+    (``jax.experimental.layout.Format``: layout + sharding), and the bytes of
+    the leaves that had to move for it.  A leaf that lies so already is
+    returned as it is; one that moves is copied on its device.  Logical
+    shapes, dtypes and values do not change."""
+    import jax
+
+    leaves, tree = jax.tree.flatten(params)
+    laid = [leaf if leaf.format == fmt else jax.device_put(leaf, fmt)
+            for leaf, fmt in zip(leaves, tree.flatten_up_to(formats))]
+    return tree.unflatten(laid), sum(
+        was.nbytes for was, now in zip(leaves, laid) if now is not was)
+
+
 class JaxLLMEngine:
     def __init__(self, cfg: EngineConfig, tokenizer=None):
         with tracing.start_span("llm.engine.build"):
@@ -290,16 +364,7 @@ class JaxLLMEngine:
         mcfg = cfg.model
         fam = model_family(mcfg)
         self.family = fam
-        # The host's part: a jitted loader returns before the device has
-        # run it, and the compilations below overlap what is left.
-        with tracing.start_span("llm.engine.weights"):
-            if cfg.param_loader is not None:
-                self.params = cfg.param_loader()
-            else:
-                self.params = fam.init(jax.random.PRNGKey(cfg.seed), mcfg)
-            self._key = jax.random.PRNGKey(cfg.seed + 1)
-            self.cache = fam.init_cache(
-                mcfg, cfg.max_batch_size, cfg.max_seq_len)
+        self._key = jax.random.PRNGKey(cfg.seed + 1)
         # Per-slot state; None = free.
         self.slots: List[Optional[_Slot]] = [None] * cfg.max_batch_size
         self._next_id = itertools.count()
@@ -334,32 +399,14 @@ class JaxLLMEngine:
              "overrun_row_steps", "stream_deltas", "stream_delta_tokens"), 0)
         self._counts.update(queue_wait_s_total=0.0, lock_wait_s_total=0.0)
 
-        prefill = fam.prefill_counted or _without_counts(fam.prefill)
         decode_step = (fam.decode_step_counted
                        or _without_counts(fam.decode_step))
-
-        def prefill_one(params, cache, tokens, length, slot_idx):
-            """Prefill a single request, padded to ``tokens``' length (a
-            rung), into positions ``[0, rung)`` of batch row ``slot_idx``.
-            What the slot's last tenant left beyond the rung stays: decode
-            reads nothing at or beyond a slot's ``pos``."""
-            import jax.numpy as jnp
-
-            one_cache = fam.init_cache(mcfg, 1, tokens.shape[0])
-            logits, one_cache, counts = prefill(
-                params, tokens[None], jnp.asarray([length]), one_cache, mcfg
-            )
-            # [1, V]: a batch of one for the sampler
-            return logits, splice_row(cache, one_cache, slot_idx), counts
-
-        # ONE named function under one jit (the program's name is what the
-        # benchmark's reader finds), compiled ahead of time once a rung: on
-        # shapes alone, so beside the weights' load (a jitted loader returns
-        # before the device has run it), and in threads, because XLA
+        # Compiled ahead of time once a rung, in threads, because XLA
         # compiles outside the GIL.  ``_admit`` calls these executables.
         self._prefill_rungs = prefill_ladder(cfg.max_seq_len)
-        jitted = jax.jit(prefill_one, donate_argnums=(1,))
+        jitted = jit_prefill_one(fam, mcfg)
         scalar = jax.ShapeDtypeStruct((), np.int32)
+        a_slot = jax.ShapeDtypeStruct((cfg.max_batch_size,), np.int32)
 
         build = tracing.current_context()
 
@@ -371,30 +418,67 @@ class JaxLLMEngine:
                     "llm.engine.compile", {"program": program, **attrs}):
                 return lower().compile()
 
-        def compile_rung(rung: int):
+        def shaped(leaf, sharding):
+            """All a compilation needs of an array: its shape and dtype, and
+            where it lies (a sharding, or a format = layout + sharding)."""
+            return jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sharding)
+
+        def compile_rung(params, cache, rung: int):
             tokens = jax.ShapeDtypeStruct((rung,), np.int32)
             return compiled("prefill_one", lambda: jitted.lower(
-                self.params, self.cache, tokens, scalar, scalar), rung=rung)
+                params, cache, tokens, scalar, scalar), rung=rung)
 
-        # The decode step beside them: its tracing, lowering and the
-        # executable's load are seconds of a replica's first request
-        # otherwise (one executable, so it cannot compile a second time).
-        # A lambda, so that the program keeps the name the readers know.
-        decode = jax.jit(
-            lambda params, cache, tokens, pos: decode_step(
-                params, tokens, pos, cache, mcfg
-            ),
-            donate_argnums=(1,),
-        )
-        a_slot = jax.ShapeDtypeStruct((cfg.max_batch_size,), np.int32)
+        def compile_all(params, cache):
+            """From shapes: the decode step, whose answer is where the
+            weights lie, then every other program that takes the weights
+            (the rungs, in the pool) for the weights as they will lie
+            there, so that none relays a weight when it runs.  Returns
+            (the decode step, a future a rung)."""
+            decode = compiled("decode_step", lambda: jit_decode_step(
+                fam, mcfg, params).lower(params, cache, a_slot, a_slot))
+            lying = jax.tree.map(shaped, params, decode.input_formats[0][0])
+            return decode, [pool.submit(compile_rung, lying, cache, rung)
+                            for rung in self._prefill_rungs]
+
+        # The compilations FIRST, from the shapes the family's ``init`` and
+        # ``init_cache`` give, where this process puts what nobody placed:
+        # they need no weight, and run beside the weights' load.
+        expected = jax.tree.map(
+            lambda leaf: shaped(leaf, self._key.sharding), jax.eval_shape(
+                lambda: (fam.init(jax.random.PRNGKey(cfg.seed), mcfg),
+                         fam.init_cache(
+                             mcfg, cfg.max_batch_size, cfg.max_seq_len))))
         with ThreadPoolExecutor(len(self._prefill_rungs) + 1) as pool:
-            decoding = pool.submit(
-                compiled, "decode_step", lambda: decode.lower(
-                    self.params, self.cache, a_slot, a_slot))
-            self._prefill_one = dict(zip(
-                self._prefill_rungs,
-                pool.map(compile_rung, self._prefill_rungs)))
-            self._decode = decoding.result()
+            programs = pool.submit(compile_all, *expected)
+            # The host's part: a jitted loader returns before the device
+            # has run it.
+            with tracing.start_span("llm.engine.weights"):
+                if cfg.param_loader is not None:
+                    # (a loader's host arrays go to the device here)
+                    self.params = jax.tree.map(
+                        jnp.asarray, cfg.param_loader())
+                else:
+                    self.params = fam.init(jax.random.PRNGKey(cfg.seed), mcfg)
+                self.cache = fam.init_cache(
+                    mcfg, cfg.max_batch_size, cfg.max_seq_len)
+            self._decode, rungs = programs.result()
+            arrived = jax.tree.map(
+                lambda leaf: shaped(leaf, leaf.sharding),
+                (self.params, self.cache))
+            if arrived != expected:  # a loader's own dtypes or placement
+                for stale in rungs:
+                    stale.cancel()
+                self._decode, rungs = compile_all(*arrived)
+            # The step's layouts are the engine's: each leaf that lies
+            # otherwise moves there, once, on the device.
+            with tracing.start_span("llm.engine.relayout") as span:
+                self.params, relaid = lay_out(
+                    self.params, self._decode.input_formats[0][0])
+                span.set_attribute("relaid_param_bytes", relaid)
+            self._counts["relaid_param_bytes"] = relaid
+            self._prefill_one = {rung: program.result() for rung, program
+                                 in zip(self._prefill_rungs, rungs)}
         # Disaggregated admission: the one-slot cache arrives from a prefill
         # replica instead of the local prefill program.
         self._insert_row = jax.jit(splice_row, donate_argnums=(0,))

@@ -212,7 +212,9 @@ def test_the_replicas_start_is_a_tree(session):
     assert {(r["attributes"]["program"], r["attributes"].get("rung"))
             for r in compiles} == {("prefill_one", 64), ("decode_step", None)}
     [weights] = named(session, "llm.engine.weights")
-    for row in (*compiles, weights):
+    [relayout] = named(session, "llm.engine.relayout")
+    assert relayout["attributes"] == {"relaid_param_bytes": 0}  # the CPU
+    for row in (*compiles, weights, relayout):
         assert row["parent_id"] == build["span_id"]
         assert build["start"] <= row["start"] <= row["end"] <= build["end"]
 

@@ -191,6 +191,7 @@ def test_stats_are_exact_and_the_same_with_and_without_a_session(scenario):
         "host_syncs": len(outside) - 1 + n,
         "overrun_row_steps": 0,  # every request ran to its max_tokens
         "stream_deltas": 0, "stream_delta_tokens": 0,  # nobody streamed
+        "relaid_param_bytes": 0,  # set at build; on the CPU nothing moves
     }
     assert engine.stats()["occupied"] == engine.occupied() == 0
     assert engine.stats()["waiting"] == 0
@@ -213,7 +214,7 @@ def test_stats_only_grow_and_waits_are_counted():
         "cancelled", "prompt_tokens", "padded_prompt_tokens",
         "generated_tokens",
         "occupied_slot_steps", "host_syncs", "overrun_row_steps",
-        "stream_deltas", "stream_delta_tokens",
+        "stream_deltas", "stream_delta_tokens", "relaid_param_bytes",
         "queue_wait_s_total", "lock_wait_s_total", "occupied", "waiting"}
     engine.add_request("queued", SamplingParams(max_tokens=9, stop_token=-1))
     assert engine.stats()["waiting"] == 1 and engine.occupied() == 0
@@ -322,10 +323,11 @@ def test_one_engine_stream_row_a_streamed_request(monkeypatch):
     rows = []
     monkeypatch.setattr(tracing, "_record", rows.append)
     engine = make_engine()
-    # The build, with the host's part of the load and one span a program.
+    # The build, with the host's part of the load, the weights' move into
+    # the decode step's layouts and one span a program.
     assert sorted(r.name for r in rows) == [
         "llm.engine.build", "llm.engine.compile", "llm.engine.compile",
-        "llm.engine.weights"]
+        "llm.engine.relayout", "llm.engine.weights"]
     build = rows[-1]
     assert build.name == "llm.engine.build"
     programs = {(r.attributes["program"], r.attributes.get("rung"))

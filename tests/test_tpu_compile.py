@@ -13,6 +13,7 @@ branch during such a compile, so the tests steer ``_on_tpu`` themselves.
 """
 
 import functools
+import math
 import os
 import re
 
@@ -117,38 +118,55 @@ CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
 
 
 @pytest.fixture(scope="module")
-def cell_decode_step(one_chip):
-    """``compiled(name) -> (executable, cache)``: a serving cell's decode
-    step as ``JaxLLMEngine`` jits it (the counted twin where the family has
-    one, cache donated, the cell's widths, slots and positions), compiled
-    for the v5e: once a module, 10-13 s each."""
+def on_chip(one_chip):
+    """``on_chip(tree)``: a tree of shapes, each on the described chip."""
+    return lambda tree: jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), tree)
+
+
+@pytest.fixture(scope="module")
+def cell(on_chip):
+    """``cell(name) -> (family, model config, params, cache)``: a serving
+    cell's shapes (its widths, slots and positions) on the described chip."""
     import importlib
     import json
 
     from ray_tpu.models import model_family
 
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one_chip), tree)
-
     @functools.cache
-    def compiled(name):
+    def shapes(name):
         with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                                "configs", name + ".json")) as f:
             cell = json.load(f)
         cfg = importlib.import_module(
             "benchmarks.families." + cell["family"]).config(cell["model"])
-        slots = cell["engine"]["max_batch_size"]
         fam = model_family(cfg)
-        decode_step = fam.decode_step_counted or fam.decode_step
         params = on_chip(jax.eval_shape(
             lambda: fam.init(jax.random.PRNGKey(0), cfg)))
         cache = on_chip(jax.eval_shape(lambda: fam.init_cache(
-            cfg, slots, cell["engine"]["max_seq_len"])))
-        rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-        step = jax.jit(lambda p, c, t, pos: decode_step(p, t, pos, c, cfg),
-                       donate_argnums=(1,))
-        return step.lower(params, cache, rows, rows).compile(), cache
+            cfg, cell["engine"]["max_batch_size"],
+            cell["engine"]["max_seq_len"])))
+        return fam, cfg, params, cache
+
+    return shapes
+
+
+@pytest.fixture(scope="module")
+def cell_decode_step(cell, on_chip):
+    """``compiled(name) -> (executable, cache, params)``: a serving cell's
+    decode step as ``JaxLLMEngine`` jits it (``jit_decode_step``, the
+    engine's own: the counted twin where the family has one, cache donated,
+    the weights' layouts the compiler's), compiled for the v5e: once a
+    module, 10-13 s each."""
+    from ray_tpu.llm.engine import jit_decode_step
+
+    @functools.cache
+    def compiled(name):
+        fam, cfg, params, cache = cell(name)
+        slots = jax.tree.leaves(cache)[0].shape[1]
+        rows = on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32))
+        step = jit_decode_step(fam, cfg, params)
+        return step.lower(params, cache, rows, rows).compile(), cache, params
 
     return compiled
 
@@ -178,7 +196,7 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
     operand of a scatter relaid T-major and back, for k and for v: 12.8 of
     its 28.6 ms on the chip, ``temp`` 1.085 GB), and the LongCat step a
     ``select`` fusion over the whole latent cache."""
-    compiled, cache = cell_decode_step(name)
+    compiled, cache, _ = cell_decode_step(name)
     # bf16[16,16,8,2048,128] twice; bf16[8,32,2048,576]; the hybrid's
     # bf16[1,64,2,2048,128] twice and bf16[5,64,10240,3] (its float32 state
     # has a test of its own, below)
@@ -202,8 +220,9 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
               and not (op == "copy-done" and leaves[leaf] < 100e6)}
     assert not others
     if name == "mistral7b_l16":
-        # 0.745 GB
-        assert compiled.memory_analysis().temp_size_in_bytes < 0.80e9
+        # 0.005 GB; 0.745 while every step copied ``wq`` / ``wk`` / ``wv``
+        # out of their stacks (the weights' default layout: below)
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
 
 
 def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
@@ -219,7 +238,7 @@ def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     and the step's temporaries stay far under one layer's slice (268 MB): a
     stack of the layers' new states at the step's end, or a slice copied
     out for its products, would be 1.34 GB more a step (PR 34's lesson)."""
-    compiled, cache = cell_decode_step("nemotron3_super_l11_ep4")
+    compiled, cache, _ = cell_decode_step("nemotron3_super_l11_ep4")
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 10.8e9
     assert memory.alias_size_in_bytes > 1.5e9  # the whole cache, donated
@@ -235,6 +254,126 @@ def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     # (``dynamic-update-slice``: those fusions' own roots)
     assert {op for name, op in producers if name not in updates} <= {
         "parameter", "get-tuple-element", "dynamic-update-slice"}
+
+
+# What hands an array on as it is, and what prefetches one into the chip's
+# fast memory (``S(1)``), whole or in pieces that are then viewed as one.
+VIEWS = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+         "call", "conditional", "opt-barrier"}
+PREFETCHES = {"copy-done", "slice-done", "ConcatBitcast"}
+HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32"}
+
+
+def weight_parts(params):
+    """(HLO dtype, dims without the 1s) of every part of a weight leaf that
+    a program might take out whole and that is >= 8 MB: the leaf, one layer
+    of a stack, one sublayer or expert of a layer (each suffix of the
+    leaf's shape)."""
+    parts = set()
+    for leaf in jax.tree.leaves(params):
+        for i in range(leaf.ndim):
+            if math.prod(leaf.shape[i:]) * leaf.dtype.itemsize >= 8e6:
+                parts.add((HLO_DTYPES[leaf.dtype.name],
+                           tuple(d for d in leaf.shape[i:] if d != 1)))
+    return parts
+
+
+def copied_weights(text, params, entry_only=True):
+    """[(op, array)] of the instructions of a compiled program (its ENTRY
+    computation, or every computation that is not a fusion's) that PRODUCE
+    an array of a weight part's dtype and dims (``weight_parts``) and are
+    neither views nor prefetches under 100 MB: the copies of weights the
+    program makes every time it runs.  Any op counts, not ``copy`` and
+    ``transpose`` alone: the v5e's relayout of Mistral's ``wq`` was a
+    multi-output ``fusion`` named for nothing (``fusion.1230``, 16 outputs),
+    whose only trace beyond itself was a prefetch of each output."""
+    parts = weight_parts(params)
+    fused = set(re.findall(r"calls=%([\w.-]+)", text))
+    found = []
+    for computation in re.finditer(
+            r"^(ENTRY )?%([\w.-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S):
+        is_entry, name, body = computation.groups()
+        if (entry_only and not is_entry) or name in fused:
+            continue
+        for _, result, op, rest in re.findall(
+                r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)$",
+                body, re.M):
+            if op in VIEWS or op.endswith("-start"):
+                continue
+            if op == "custom-call":
+                op = re.search(r'custom_call_target="(\w+)"', rest).group(1)
+            for dtype, dims, layout in re.findall(
+                    r"(\w+)\[([\d,]*)\](?:\{([^}]*)\})?", result):
+                dims = [int(d) for d in dims.split(",") if d]
+                part = (dtype, tuple(d for d in dims if d != 1))
+                prefetch = (op in PREFETCHES and "S(1)" in layout
+                            and math.prod(dims) * 2 < 100e6)
+                if part in parts and not prefetch:
+                    found.append((op, f"{dtype}{dims}{{{layout}}}"))
+    return found
+
+
+@pytest.mark.parametrize("name", CELL_CONFIGS)
+def test_decode_step_copies_no_weight(cell_decode_step, name):
+    """The decode step reads its weights where they lie: with their device
+    layouts left to the compiler (``jit_decode_step``) nothing in the ENTRY
+    computation produces an array the size of a weight, a layer's or an
+    expert's part of one, but a prefetch into the chip's fast memory.  In
+    the DEFAULT layout (``[L, E, H, D]`` row-major) the Mistral step relaid
+    ``wq`` / ``wk`` / ``wv`` of every layer before their products, every
+    step: 48 fusion outputs, 0.81 GB written and read back, 2.3 of its 15.6
+    ms on the chip (PERF.md, PR 44); the compiler asks for ``[L, H, E, D]``
+    (``major_to_minor=(0, 2, 1, 3)``) and reads that in place."""
+    compiled, _, params = cell_decode_step(name)
+    copies = copied_weights(compiled.as_text(), params)
+    if name == "longcat_flash_l4_ep32":
+        # Today's truth (ROADMAP S5 keeps it): each attention's ``wkv_b``
+        # still leaves its ``[4, 2, 512, 64, 256]`` stack by ONE sliced copy
+        # (a ``slice_bitcast_fusion`` of eight outputs, 0.134 GB a step);
+        # the second copy each then took, into ``{2,0,1}`` for the absorbed
+        # products (eight ``copy_bitcast_fusion``), is what the layout cured.
+        assert len(copies) <= 8
+        assert {array.split("{")[0] for _, array in copies} <= {
+            "bf16[512, 64, 256]"}
+    else:
+        assert not copies
+    if name == "mistral7b_l16":
+        blocks = compiled.input_formats[0][0]["blocks"]
+        assert {k: blocks[k].layout.major_to_minor for k in blocks} == dict(
+            dict.fromkeys(("wq", "wk", "wv"), (0, 2, 1, 3)),
+            wo=(0, 1, 2, 3), rms1=(0, 1), rms2=(0, 1),
+            w_gate=(0, 1, 2), w_up=(0, 1, 2), w_down=(0, 1, 2))
+
+
+@pytest.mark.parametrize("rung", [256, 512, 1024, 2048])
+def test_prefill_rung_copies_no_more_weights_in_the_decode_steps_layouts(
+    cell, cell_decode_step, on_chip, rung
+):
+    """Decode's choice of the weights' layouts is prefill's too (the engine
+    compiles every rung, ``jit_prefill_one``, against the weights as the
+    decode step has them laid).  Per rung of the Mistral cell's ladder:
+    compiled so, the rung takes the formats it is given (it relays nothing
+    on its way in) and holds no more weight-sized copies, in its ENTRY and
+    in its scan's body, than compiled against the default layouts: one
+    fewer (4 against 5)."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    fam, cfg, params, cache = cell("mistral7b_l16")
+    formats = cell_decode_step("mistral7b_l16")[0].input_formats[0][0]
+    tokens = on_chip(jax.ShapeDtypeStruct((rung,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+
+    def compiled(weights):
+        return jit_prefill_one(fam, cfg).lower(
+            weights, cache, tokens, scalar, scalar).compile()
+
+    def copies(rung):
+        return copied_weights(rung.as_text(), params, entry_only=False)
+
+    as_they_lie = compiled(jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats))
+    assert as_they_lie.input_formats[0][0] == formats
+    assert len(copies(as_they_lie)) <= len(copies(compiled(params)))
 
 
 # Cache leaves as the families shape them (positions on the axis before the
