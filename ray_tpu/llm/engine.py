@@ -339,12 +339,29 @@ def lay_out(params, formats):
     """``params`` with every leaf in its ``formats`` leaf's device layout
     (``jax.experimental.layout.Format``: layout + sharding), and the bytes of
     the leaves that had to move for it.  A leaf that lies so already is
-    returned as it is; one that moves is copied on its device.  Logical
-    shapes, dtypes and values do not change."""
+    returned as it is; one that moves is copied on its device, and HELD to
+    the layout asked for.  Logical shapes, dtypes and values do not
+    change."""
     import jax
 
+    def put(leaf, fmt):
+        """``device_put`` into a layout is not taken on trust.  On the v5e,
+        in a build whose programs all come out of the compile cache, an
+        UNCOMMITTED leaf (a jitted loader's output) has come back as a
+        committed copy in its OLD layout, in silence, and the first prefill
+        then refused it (PERF.md, PR 45); put again, the committed copy
+        moves.  What still lies otherwise is an error here, at build."""
+        moved = jax.device_put(leaf, fmt)
+        if moved.format != fmt:
+            moved = jax.device_put(moved, fmt)
+        if moved.format != fmt:
+            raise RuntimeError(
+                f"a {leaf.dtype}{list(leaf.shape)} weight stays in layout "
+                f"{moved.format.layout} where {fmt.layout} was asked for")
+        return moved
+
     leaves, tree = jax.tree.flatten(params)
-    laid = [leaf if leaf.format == fmt else jax.device_put(leaf, fmt)
+    laid = [leaf if leaf.format == fmt else put(leaf, fmt)
             for leaf, fmt in zip(leaves, tree.flatten_up_to(formats))]
     return tree.unflatten(laid), sum(
         was.nbytes for was, now in zip(leaves, laid) if now is not was)

@@ -42,6 +42,18 @@ from .longcat_decode import (  # noqa: F401
     longcat_init_cache,
     longcat_prefill,
 )
+from .mimo_v2 import (  # noqa: F401
+    MimoV2Config,
+    mimo_v2_apply,
+    mimo_v2_init,
+    mimo_v2_loss,
+    mimo_v2_param_axes,
+)
+from .mimo_v2_decode import (  # noqa: F401
+    mimo_v2_decode_step,
+    mimo_v2_init_cache,
+    mimo_v2_prefill,
+)
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig,
     nemotron_h_apply,
@@ -174,5 +186,21 @@ register_model_family(
             nemotron_h_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             nemotron_h_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    MimoV2Config,
+    ModelFamily(
+        name="mimo_v2",
+        init=mimo_v2_init,
+        apply=mimo_v2_apply,
+        loss=mimo_v2_loss,
+        param_axes=mimo_v2_param_axes,
+        init_cache=mimo_v2_init_cache,
+        prefill=mimo_v2_prefill,
+        decode_step=mimo_v2_decode_step,
+        prefill_counted=_functools.partial(mimo_v2_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            mimo_v2_decode_step, with_counts=True),
     ),
 )

@@ -3,15 +3,16 @@ has in common once it has routed.
 
 A layer that is told which experts it holds (``experts_held`` from
 ``expert_offset``) routes over ALL the model's experts, its own way
-(LongCat: softmax, not renormalised, identity experts; Nemotron-H: sigmoid
-scores, renormalised, experts in a latent), and then computes
+(LongCat: softmax, not renormalised, identity experts; Nemotron-H and
+MiMo-V2: sigmoid scores, renormalised, ``sigmoid_route`` here), and then
+computes
 ``sum_{e held, chosen} w_e f_e(u)`` for the tokens that chose a held expert.
 That sum is here: ``held_choices`` turns the router's choices into the held
 experts' hit mask and combine weights, ``held_experts`` gathers each
 expert's tokens and walks the chunks.  The expert itself, ``f_e``, is the
-caller's: gated SwiGLU on the hidden state in ``longcat.py``, an ungated
-``relu^2`` MLP on a latent in ``nemotron_h.py``.  What absent experts would
-add is left out.
+caller's: gated SwiGLU on the hidden state in ``longcat.py`` (``ffn``, which
+``mimo_v2.py`` runs too), an ungated ``relu^2`` MLP on a latent in
+``nemotron_h.py``.  What absent experts would add is left out.
 """
 
 from __future__ import annotations
@@ -24,6 +25,33 @@ import jax.numpy as jnp
 # chunks of at most this many rows; an expert no live token chose runs
 # nothing and reads no weight.
 EXPERT_CHUNK = 128
+
+
+def runs_every_held_expert(rows: int, top_k: int, n_routed: int) -> bool:
+    """Which way a layer's held experts run, read off the SHAPES, never off
+    the load (the two ways round differently, so a request's greedy ids
+    would depend on who else is served: ``nemotron_h.moe`` has the
+    timings).  When the rows fit one chunk and make a choice or more an
+    expert (a decode step of 64 slots) nearly every held expert is touched,
+    and running ALL of them on every row in batched products streams the
+    layer's experts once at the memory's speed; otherwise (a prefill) the
+    gather and the chunk loop of ``held_experts``, whose cost hardly grows
+    with the rows."""
+    return rows <= EXPERT_CHUNK and rows * top_k >= n_routed
+
+
+def sigmoid_route(u, router, bias, top_k: int, scale: float = 1.0):
+    """The renormalised sigmoid router (``noaux_tc`` without group limits).
+    u ``[N, d]`` float32, ``router [d, E]`` and ``bias [E]`` float32 -> the
+    ``top_k`` experts each token chose ``[N, k]`` (the largest of ``p + bias``,
+    ``p = sigmoid(u router)``) and their combine weights ``scale p / sum(p)``
+    over the chosen, float32."""
+    logits = jnp.dot(u, router, precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(p + bias, top_k)
+    chosen = jnp.take_along_axis(p, sel, axis=-1)
+    w = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
+    return sel, w
 
 
 def held_choices(sel, w, live, expert_offset: int, experts_held: int):

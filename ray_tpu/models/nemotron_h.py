@@ -72,7 +72,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .expert_share import EXPERT_CHUNK, held_choices, held_experts
+from .expert_share import (held_choices, held_experts,
+                           runs_every_held_expert, sigmoid_route)
 from .llama import _rmsnorm
 from .longcat import add_counts, matmul
 
@@ -415,13 +416,8 @@ def held_experts_dense(v, w_held, w1, w2):
 def route(u, router, bias, cfg: NemotronHConfig):
     """u ``[N, d]`` float32 -> the ``k`` experts each token chose ``[N, k]``
     and their combine weights ``s p / sum(p)`` (float32)."""
-    logits = jnp.dot(u, router, precision=jax.lax.Precision.HIGHEST)
-    p = jax.nn.sigmoid(logits)
-    _, sel = jax.lax.top_k(p + bias, cfg.top_k)
-    chosen = jnp.take_along_axis(p, sel, axis=-1)
-    w = cfg.routed_scaling_factor * chosen / (
-        chosen.sum(-1, keepdims=True) + 1e-20)
-    return sel, w
+    return sigmoid_route(u, router, bias, cfg.top_k,
+                         cfg.routed_scaling_factor)
 
 
 def moe(u, live, params, i: int, cfg: NemotronHConfig):
@@ -461,7 +457,7 @@ def moe(u, live, params, i: int, cfg: NemotronHConfig):
         v = matmul("ne,el->nl", ud, blocks["w_dl"][i]).astype(dt)
 
         n = u.shape[0]
-        if n <= EXPERT_CHUNK and n * cfg.top_k >= cfg.n_routed_experts:
+        if runs_every_held_expert(n, cfg.top_k, cfg.n_routed_experts):
             latent = held_experts_dense(
                 v, w_held, experts["w1"][i], experts["w2"][i])
         else:  # [i, e] inside the loop: expert_share.py

@@ -50,40 +50,81 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def ring_held(newest, window: int):
+    """The position each slot of a ring of ``window`` slots holds once
+    positions ``0 .. newest`` are in: slot ``r`` the newest ``p <= newest``
+    with ``p = r mod window``; negative where no position has reached the
+    slot yet (it holds whatever it held).  newest ``[..., 1]`` -> ``[...,
+    window]``.  THE ring's addressing rule: prefill fills by it, decode masks
+    by it and writes position ``p`` at ``p mod window``."""
+    return newest - jnp.mod(newest - jnp.arange(window), window)
+
+
+def ring_positions(pos, newest, window: int):
+    """Which slots of a ring the token at ``pos`` attends: those whose
+    position ``p`` (``ring_held``; ``newest`` is ``pos - 1`` while the current
+    token rides beside the cache, ``pos`` once it is written) is ``>= 0`` and
+    inside the window, ``pos - p < window``.  pos, newest ``[B, 1, 1, 1]`` ->
+    mask ``[B, 1, 1, window]``."""
+    held = ring_held(newest, window)
+    return (held >= 0) & (pos - held < window)
+
+
 def reference_decode_attention(q, k_cache, v_cache, pos, layer: int,
-                               k_self=None, v_self=None):
-    """Ground truth in plain XLA.  q [B,H,D]; caches [L,B,Hkv,T,D].
+                               k_self=None, v_self=None, window=None,
+                               sink=None):
+    """Ground truth in plain XLA.  q [B,H,D]; k cache [L,B,Hkv,T,D], v cache
+    [L,B,Hkv,T,Dv] (the values' width is their own) -> [B,H,Dv].
 
     Without self k/v: attends [0, pos] of the cache (current token assumed
     already written).  With self k/v: attends [0, pos-1] plus the explicit
-    current token (the deferred-scatter form the kernel implements)."""
+    current token (the deferred-scatter form the kernel implements).
+
+    ``window`` (static): the cache's position axis is a RING of that extent
+    (``T == window``): position ``p`` lives at ``p mod window`` and the token
+    attends the last ``window`` positions, itself included
+    (``ring_positions``).  ``sink`` ``[H]``: one more logit a head in the
+    softmax, whose probability is dropped (the row then sums to less than
+    one).  Both default to absent, and the program without them is what it
+    was."""
     k = k_cache[layer]  # [B, Hkv, T, D]
     v = v_cache[layer]
     b, hkv, t, d = k.shape
+    dv = v.shape[-1]
     h = q.shape[1]
     g = h // hkv
     qg = q.reshape(b, hkv, g, d)
     scale = d ** -0.5
     scores = jnp.einsum("bkgd,bktd->bkgt", qg, k).astype(jnp.float32) * scale
     limit = pos[:, None, None, None]
-    idx = jnp.arange(t)[None, None, None, :]
-    if k_self is None:
-        mask = idx <= limit
-        scores = jnp.where(mask, scores, NEG_INF)
+    if window is not None:
+        if t != window:
+            raise ValueError(f"a ring of {window} positions in a cache of {t}")
+        mask = ring_positions(
+            limit, limit if k_self is None else limit - 1, window)
+    else:
+        idx = jnp.arange(t)[None, None, None, :]
+        # with self k/v: strictly before the current token
+        mask = idx <= limit if k_self is None else idx < limit
+    scores = jnp.where(mask, scores, NEG_INF)
+    if k_self is None and sink is None:  # no column beside the cache's
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bkgt,bktd->bkgd", probs.astype(v.dtype), v)
-        return out.reshape(b, h, d)
-    mask = idx < limit  # strictly before the current token
-    scores = jnp.where(mask, scores, NEG_INF)
-    s_self = (
-        jnp.einsum("bkgd,bkd->bkg", qg, k_self).astype(jnp.float32) * scale
-    )[..., None]
-    full = jnp.concatenate([scores, s_self], axis=-1)
-    probs = jax.nn.softmax(full, axis=-1)
-    out = jnp.einsum(
-        "bkgt,bktd->bkgd", probs[..., :-1].astype(v.dtype), v
-    ) + probs[..., -1:].astype(v.dtype) * v_self[:, :, None, :]
-    return out.reshape(b, h, d)
+        return out.reshape(b, h, dv)
+    columns = [scores]
+    if k_self is not None:
+        columns.append((
+            jnp.einsum("bkgd,bkd->bkg", qg, k_self).astype(jnp.float32) * scale
+        )[..., None])
+    if sink is not None:
+        columns.append(jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, hkv, g, 1), (b, hkv, g, 1)))
+    probs = jax.nn.softmax(jnp.concatenate(columns, axis=-1), axis=-1)
+    out = jnp.einsum("bkgt,bktd->bkgd", probs[..., :t].astype(v.dtype), v)
+    if k_self is not None:
+        out = out + (probs[..., t:t + 1].astype(v.dtype)
+                     * v_self[:, :, None, :])
+    return out.reshape(b, h, dv)
 
 
 def tile_positions(shape, dtype, axis: int) -> int:
@@ -92,10 +133,11 @@ def tile_positions(shape, dtype, axis: int) -> int:
     sublanes of the next one: 8 rows of 32 bits, so 16 of bf16.  The TPU
     keeps an array's last axis minor unless that would pad it: where the
     last axis is no multiple of 128 it swaps the last two
-    (``tests/test_tpu_compile.py`` reads that from the compiler for nine
-    shapes).  So positions lie on the sublanes of Mistral's ``[.., T, 128]``
-    and on the lanes of LongCat's ``[.., T, 576]`` and GPT-2's
-    ``[.., T, 64]``."""
+    (``tests/test_tpu_compile.py`` reads that from the compiler for
+    thirteen shapes).  So positions lie on the sublanes of Mistral's ``[..,
+    T, 128]`` and on the lanes of LongCat's ``[.., T, 576]``, GPT-2's ``[..,
+    T, 64]`` and MiMo's keys ``[.., T, 192]``, whose ring of 128 positions is
+    then ONE tile."""
     last_is_minor = shape[-1] % 128 == 0
     on_lanes = axis == len(shape) - (1 if last_is_minor else 2)
     return 128 if on_lanes else 32 // jnp.dtype(dtype).itemsize
@@ -204,12 +246,17 @@ def _decode_kernel(pos_ref, q_ref, ks_ref, vs_ref, k_ref, v_ref, o_ref, *,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("layer", "block_t", "kernel", "interpret")
+    jax.jit,
+    static_argnames=("layer", "block_t", "kernel", "interpret", "window"),
 )
 def decode_attention(q, k_cache, v_cache, pos, layer: int = 0, *,
                      k_self=None, v_self=None, block_t: int = 256,
-                     kernel: bool = True, interpret: bool = False):
-    """q [B,H,D], k/v cache [L,B,Hkv,T,D], pos [B] → [B,H,D].
+                     kernel: bool = True, interpret: bool = False,
+                     window=None, sink=None):
+    """q [B,H,D], k cache [L,B,Hkv,T,D], v cache [L,B,Hkv,T,Dv], pos [B] →
+    [B,H,Dv].  ``window`` / ``sink``: ``reference_decode_attention``'s (a
+    ring of the last ``window`` positions, one more logit a head); the
+    Pallas kernel knows neither, nor a value width of its own.
 
     ``layer`` is static: the BlockSpecs read that slice of the stacked
     cache in place.  With ``k_self``/``v_self`` [B,Hkv,D] the current
@@ -224,8 +271,12 @@ def decode_attention(q, k_cache, v_cache, pos, layer: int = 0, *,
     on_tpu = _on_tpu()
     if not kernel or not (on_tpu or interpret):
         return reference_decode_attention(
-            q, k_cache, v_cache, pos, layer, k_self, v_self
+            q, k_cache, v_cache, pos, layer, k_self, v_self, window, sink
         )
+    if window is not None or sink is not None or v_cache.shape[-1] != d:
+        raise ValueError(
+            "decode attention kernel: no ring, no sink and values as wide "
+            "as the keys; ask for the reference with kernel=False")
     if t % block_t or k_self is None:
         raise ValueError(
             f"decode attention kernel: cache length {t} must be a multiple "

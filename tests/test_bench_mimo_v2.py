@@ -1,0 +1,60 @@
+"""The MiMo-V2 configuration, traffic, arithmetic and metric files the
+benchmark gained in PR 45, under every PR's tests: the cases live beside the
+code they pin."""
+
+from benchmarks.tests.test_bench_mimo_v2 import *  # noqa
+
+import json  # noqa: E402
+import os  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
+import pytest  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def run(*command):
+    out = subprocess.run(
+        [sys.executable, *command], capture_output=True, text=True,
+        timeout=600,
+        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
+    assert out.returncode == 0, (out.stdout + out.stderr)[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_the_new_cell_rehearses_on_the_cpu_with_its_trace():
+    """``benchmarks/selftest.py --rehearse`` names its cells and may not be
+    edited by the PR that adds one (the benchmark's files are add-only), so
+    the new cell's rehearsal lives here, ``selftest.py --rehearse``'s way:
+    serve -> proxy -> ``LLMServer`` -> ``JaxLLMEngine`` at tiny widths on CPU
+    workers, traced, with the harness's two-layer reference check (whose 64
+    + 3 positions wrap the tiny ring of 8 eight times), ending in a line
+    that cannot be mistaken for a run."""
+    last = run(os.path.join(REPO, "benchmarks", "run.py"), "--workload",
+               "mimo25_ep16_mixed_closed64", "--seed", "4500000019",
+               "--seconds", "3", "--trace", "1", "--rehearse-cpu")
+    assert last["rehearsal_ok"] is True and last["attempted"] > 0
+    assert last["failed"] == 0 and not last["problems"]
+    assert not {"metrics", "correct", "device"} & set(last), last
+
+
+@pytest.mark.parametrize("mode", [[], ["--harness-cut", "2"]],
+                         ids=["all_layers", "harness_cut"])
+def test_the_builders_comparison_rehearses_on_the_cpu(mode):
+    """``benchmarks/mimo_v2_all_layers.py``: all the layers through the
+    engine's own programs across a wrapped ring with its two controls (no
+    sink; the matrices at three bits of mantissa), and the harness's
+    two-layer cut with the harness's own functions, walked at tiny
+    widths."""
+    last = run(os.path.join(REPO, "benchmarks", "mimo_v2_all_layers.py"),
+               "--rehearse-cpu", *mode)
+    assert last["rehearsal_ok"] is True and "ok" not in last
+    program, control = last["program"], last["control_coarse_matrices"]
+    if mode:
+        assert len(program) == len(control) == 2
+    else:
+        assert last["steps"] == 40 and last["positions"] == 20
+        # the sink is a fifth of the stream even at these widths
+        assert program["worst_rms"] < last["control_no_sink"]["median_rms"]
+        assert program["median_rms"] < control["median_rms"]

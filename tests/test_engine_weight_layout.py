@@ -21,7 +21,7 @@ from ray_tpu.llm.engine import (EngineConfig, JaxLLMEngine, SamplingParams,
                                 lay_out)
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models import (GPT2Config, LlamaConfig, LongcatConfig,
-                            model_family)
+                            MimoV2Config, model_family)
 from ray_tpu.util import tracing
 
 SEQ = 512  # rungs 256, 512
@@ -29,6 +29,7 @@ FAMILIES = {
     "gpt2": lambda **kw: GPT2Config.tiny(vocab_size=384, max_seq=SEQ, **kw),
     "llama": lambda **kw: LlamaConfig.tiny(vocab_size=384, max_seq=SEQ, **kw),
     "longcat": lambda **kw: LongcatConfig.tiny(vocab_size=384, **kw),
+    "mimo_v2": lambda **kw: MimoV2Config.tiny(vocab_size=384, **kw),
 }
 PROMPTS = ["where do the weights lie", "b" * 300, "as the decode step reads"]
 GREEDY = SamplingParams(max_tokens=6, stop_token=-1)
@@ -194,6 +195,37 @@ def test_lay_out_moves_only_what_lies_otherwise():
     assert moved == 0 and again["a"] is laid["a"]
 
 
+@pytest.mark.parametrize("stubborn", [False, True])
+def test_lay_out_holds_a_moved_leaf_to_the_layout_asked_for(
+    monkeypatch, stubborn
+):
+    """``device_put`` into a layout has come back in the OLD layout in
+    silence (v5e, an uncommitted leaf, every program out of the compile
+    cache: PERF.md, PR 45), and the first prefill refused the weight.
+    ``lay_out`` looks at what came back: a leaf that did not move is put
+    again (the copy it came back as moves), and one that still lies
+    otherwise is an error at build, not in the first request."""
+    a = jax.numpy.arange(24.0).reshape(2, 3, 4)
+    asked = formats_of({"a": jax.device_put(a, minor_axes_swapped(a))})
+    real, calls = jax.device_put, []
+
+    def forgetful(x, fmt):
+        calls.append(x)
+        if stubborn or len(calls) == 1:  # comes back as a copy, unmoved
+            return real(x, x.format)
+        return real(x, fmt)
+
+    monkeypatch.setattr(jax, "device_put", forgetful)
+    if stubborn:
+        with pytest.raises(RuntimeError, match="stays in layout"):
+            lay_out({"a": a}, asked)
+        return
+    laid, moved = lay_out({"a": a}, asked)
+    assert len(calls) == 2 and calls[0] is a and calls[1] is not a
+    assert laid["a"].format == asked["a"] and moved == a.nbytes
+    np.testing.assert_array_equal(laid["a"], a)
+
+
 def test_a_loader_of_another_dtype_gets_a_decode_step_of_its_own(monkeypatch):
     """The decode step is compiled before the weights are there, from the
     shapes the family's ``init`` gives.  A loader that hands over other
@@ -233,17 +265,40 @@ def test_a_loader_of_host_arrays_ends_on_the_device():
             == on_device.generate(PROMPTS, GREEDY))
 
 
+@pytest.mark.parametrize("family", ["llama", "mimo_v2"])
 def test_the_decode_step_compiles_beside_the_load_and_the_rungs_after_it(
-    monkeypatch, a_compiler_that_asks
+    monkeypatch, a_compiler_that_asks, family
 ):
-    """Build order, on the wall clock's spans: the decode step's compilation
-    starts before the weights' load has ended (it needs shapes alone); the
-    relayout follows both and says what it moved; no rung starts before the
-    decode step's answer is there (a rung is compiled for the weights as
-    they will lie, which needs the answer and no weight)."""
-    rows = []
-    monkeypatch.setattr(tracing, "_record", rows.append)
-    engine = make_engine("llama")
+    """Build order, as the build GUARANTEES it by the order of its own
+    statements (not by who wins a race between a tiny model's load and a
+    thread's start): the decode step's compilation is handed to the pool
+    before the loader is called (it needs shapes alone, so the two run side
+    by side); no rung is handed over before the decode step's compilation
+    has ended (a rung is compiled for the weights as they will lie, which
+    needs the decode step's answer and no weight); the relayout follows
+    both the decode step and the load and says what it moved.  One list
+    takes the pool's submissions, the loader's call and every span as it
+    ends, in the order they happen."""
+    events, rows = [], []
+
+    def record(row):
+        rows.append(row)
+        events.append(("span", row.name, row.attributes.get("program")))
+
+    class RecordingPool(engine_module.ThreadPoolExecutor):
+        def submit(self, fn, *args, **kwargs):
+            events.append(("submit", fn.__name__, None))
+            return super().submit(fn, *args, **kwargs)
+
+    monkeypatch.setattr(tracing, "_record", record)
+    monkeypatch.setattr(engine_module, "ThreadPoolExecutor", RecordingPool)
+    model = FAMILIES[family]()
+
+    def loader():
+        events.append(("load", None, None))
+        return model_family(model).init(jax.random.PRNGKey(0), model)
+
+    engine = make_engine(family, loader=loader)
     by_name = {}
     for row in rows:
         by_name.setdefault(row.name, []).append(row)
@@ -254,9 +309,16 @@ def test_the_decode_step_compiles_beside_the_load_and_the_rungs_after_it(
     rungs = [r for r in by_name["llm.engine.compile"]
              if r.attributes["program"] == "prefill_one"]
     assert sorted(r.attributes["rung"] for r in rungs) == [256, 512]
-    assert decode.start <= weights.end
-    assert max(decode.end, weights.end) <= relayout.start
+    # submitted, then loaded: nothing of the decode step waits for a weight
+    assert events.index(("submit", "compile_all", None)) < events.index(
+        ("load", None, None))
+    # every rung is handed over after the decode step's span has ended
+    decoded = events.index(("span", "llm.engine.compile", "decode_step"))
+    handed = [i for i, e in enumerate(events)
+              if e == ("submit", "compile_rung", None)]
+    assert len(handed) == 2 and decoded < min(handed)
     assert all(decode.end <= r.start for r in rungs)
+    assert max(decode.end, weights.end) <= relayout.start
     assert relayout.attributes == {
         "relaid_param_bytes": engine.stats()["relaid_param_bytes"]}
     assert relayout.attributes["relaid_param_bytes"] > 0

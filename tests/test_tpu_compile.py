@@ -114,7 +114,7 @@ def test_decode_kernel_refused_for_vmem_at_tinyllama_shape(
 
 # The serving cells' configurations (benchmarks/configs/<name>.json).
 CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
-                "nemotron3_super_l11_ep4"]
+                "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16"]
 
 
 @pytest.fixture(scope="module")
@@ -199,7 +199,8 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
     compiled, cache, _ = cell_decode_step(name)
     # bf16[16,16,8,2048,128] twice; bf16[8,32,2048,576]; the hybrid's
     # bf16[1,64,2,2048,128] twice and bf16[5,64,10240,3] (its float32 state
-    # has a test of its own, below)
+    # has a test of its own, below); MiMo's two extents:
+    # bf16[2,64,4,4096,192|128] and the rings bf16[5,64,8,128,192|128]
     leaves = {"bf16[%s]" % ",".join(map(str, leaf.shape)): leaf.size * 2
               for leaf in jax.tree.leaves(cache)}
     producers = set(re.findall(
@@ -254,6 +255,37 @@ def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     # (``dynamic-update-slice``: those fusions' own roots)
     assert {op for name, op in producers if name not in updates} <= {
         "parameter", "get-tuple-element", "dynamic-update-slice"}
+
+
+def test_windowed_decode_step_fits_and_its_top_rung_beside_it(
+    cell, cell_decode_step, on_chip
+):
+    """The MiMo cell's programs at its size (published widths, 7 layers, 16
+    experts held, 64 slots x 4096): the decode step's temporaries are a
+    thousandth of what it reads (no slice of a cache leaf or of an expert
+    stack copied out: 0.013 GB), the whole cache is aliased to its output,
+    and the top prefill rung, which scores a window layer's band alone and a
+    full layer in blocks of 512 queries, needs 1.44 GB beside 8.42 GB of
+    arguments (dense ``[64, 4096, 4096]`` float32 scores would be 4.3 GB a
+    layer)."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("mimo_v25_l7_ep16")
+    memory = step.memory_analysis()
+    assert 8.3e9 < memory.argument_size_in_bytes < 8.6e9
+    assert memory.alias_size_in_bytes > 1.5e9  # rings and full leaves
+    assert memory.temp_size_in_bytes < 0.05e9
+    assert cache["k_win"].shape == (5, 64, 8, 128, 192)
+    assert cache["v"].shape == (2, 64, 4, 4096, 128)
+    fam, cfg, _, _ = cell("mimo_v25_l7_ep16")
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params,
+        step.input_formats[0][0])
+    tokens = on_chip(jax.ShapeDtypeStruct((4096,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    rung = jit_prefill_one(fam, cfg).lower(
+        lying, cache, tokens, scalar, scalar).compile()
+    assert rung.memory_analysis().temp_size_in_bytes < 2.0e9
 
 
 # What hands an array on as it is, and what prefetches one into the chip's
@@ -388,6 +420,10 @@ CACHE_LEAVES = [
     ((8, 32, 2000, 576), "bfloat16"),
     ((8, 32, 2048, 96), "float32"),
     ((8, 16, 8, 2048, 256), "float32"),
+    ((2, 64, 4, 4096, 192), "bfloat16"),   # MiMo cell: full keys,
+    ((5, 64, 8, 128, 192), "bfloat16"),    # a ring of keys (one tile),
+    ((2, 64, 4, 4096, 128), "bfloat16"),   # values
+    ((5, 64, 8, 128, 128), "bfloat16"),
 ]
 
 
