@@ -95,7 +95,12 @@ Observability (names are a contract: tests pin them, PERF.md lists which
 metric reads which).  Host work runs inside ``util.tracing.host_span``s —
 ``engine.lock_wait`` (outside callers only), ``engine.step`` >
 ``engine.admit`` > (``engine.prefill.dispatch``, ``engine.sample``: the first
-token's sampler and its way into the operand), ``engine.decode.dispatch``,
+token's sampler and its way into the operand), ``engine.decode.dispatch``
+(``longest``: the riding rows' highest ``pos``; ``read_positions``: how far
+the step's attention reads each full-extent cache for it,
+``ops/decode_attention.live_extent``, the function the program bounds its
+loop with; ``cache_positions``: ``max_seq_len``; ``live_positions``: the
+riding rows' mean ``pos``, what a read bounded a slot would take),
 ``engine.sample`` (the sampler's dispatch and every read of the iteration) >
 ``engine.retire``, and a zero-length ``engine.counts`` at the end of every
 step — which a profiler session writes on the device trace's clock.
@@ -138,6 +143,7 @@ import numpy as np
 
 from ..models import GPT2Config, model_family
 from ..models.gpt2_decode import sample_logits_greedy, sample_logits_rows
+from ..ops.decode_attention import live_extent
 from ..util import flight_recorder, tracing
 from ..util.tracing import host_span
 from .tokenizer import ByteTokenizer
@@ -901,10 +907,19 @@ class JaxLLMEngine:
             riding = [(i, s) for i, s in enumerate(self.slots)
                       if s is not None]
             if riding:
-                with host_span("engine.decode.dispatch", active=len(riding)):
-                    pos = np.zeros(self.cfg.max_batch_size, np.int32)
-                    for i, s in riding:
-                        pos[i] = s.last_pos
+                pos = np.zeros(self.cfg.max_batch_size, np.int32)
+                for i, s in riding:
+                    pos[i] = s.last_pos
+                # What the step's attention reads of each full-extent cache:
+                # the program bounds it by the same function of ``pos``.
+                longest = int(pos.max())
+                with host_span(
+                        "engine.decode.dispatch", active=len(riding),
+                        longest=longest,
+                        read_positions=live_extent(
+                            longest, self.cfg.max_seq_len),
+                        cache_positions=self.cfg.max_seq_len,
+                        live_positions=float(pos.sum()) / len(riding)):
                     logits, self.cache, counts = self._decode(
                         self.params, self.cache, self._feed, jnp.asarray(pos))
                     self._note_counts("decode", counts)

@@ -15,8 +15,10 @@ builds a key or a value.  Deferred-scatter protocol as in ``gpt2_decode.py``:
 the cache holds ``[0, pos-1]``, the current token's latent is merged as a
 last score, and all ``2L`` latents are written at the step's end by the
 families' one ``write_token_to_cache`` (the tile of rows that holds each
-slot's position, in place; each attention's slice of the cache is still
-copied out of it for its products: ``tests/test_tpu_compile.py``).
+slot's position, in place).  An attention reads its slice of the cache in
+blocks of 512 positions up to the batch's longest context, each block taken
+out of the stack once for both of its products (``mla_absorbed``;
+``tests/test_tpu_compile.py``).
 
 Both return ``(logits, cache)`` as every family's do; with
 ``with_counts=True`` (the family's ``*_counted`` twins, which the engine
@@ -33,7 +35,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.decode_attention import write_token_to_cache
+from ..ops.decode_attention import (attend_live_blocks, extent_step,
+                                    write_token_to_cache)
 from .llama import _rmsnorm
 from .longcat import (LongcatConfig, add_counts, double_layer,
                       longcat_forward, matmul, mla_project)
@@ -63,24 +66,45 @@ def longcat_prefill(
     return (*out, counts) if with_counts else out
 
 
-def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg: LongcatConfig):
+def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg: LongcatConfig,
+                 layer: int = 0):
     """One query token a row against its slot's latents.  q [B, H, dn+dr];
-    latent_self [B, C] (the current token's); latent_cache [B, T, C] holding
-    [0, pos-1]; pos [B] -> [B, d] float32."""
+    latent_self [B, C] (the current token's); latent_cache [A, B, T, C], the
+    STACKED cache, of which attention ``layer``'s slice holds [0, pos-1];
+    pos [B] -> [B, d] float32.  A cache of several extents is read in blocks
+    up to the batch's longest context (``ops/decode_attention``'s
+    ``attend_live_blocks``), each block taken from the stack itself."""
     rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     w_k, w_v = att["wkv_b"][..., :dn], att["wkv_b"][..., dn:]
     qt = matmul("bhn,chn->bhc", q[..., :dn], w_k).astype(q.dtype)
     qc = jnp.concatenate([qt, q[..., dn:]], -1)  # [B, H, C]
     scale = q.shape[-1] ** -0.5
-    scores = matmul("bhc,btc->bht", qc, latent_cache) * scale
-    before = jnp.arange(latent_cache.shape[1])[None, None] < pos[:, None, None]
-    scores = jnp.where(before, scores, -1e30)
+    _, b, t, c = latent_cache.shape
+    step = extent_step(t)
     s_self = matmul("bhc,bc->bh", qc, latent_self) * scale
-    probs = jax.nn.softmax(
-        jnp.concatenate([scores, s_self[..., None]], -1), axis=-1)
-    oc = (matmul("bht,btc->bhc", probs[..., :-1].astype(q.dtype),
-                 latent_cache[..., :rkv])
-          + probs[..., -1:] * latent_self[:, None, :rkv])
+    if step < t:
+        def block(start):
+            latents = jax.lax.dynamic_slice(
+                latent_cache, (layer, 0, start, 0), (1, b, step, c))[0]
+            scores = matmul("bhc,btc->bht", qc, latents) * scale
+            before = jnp.arange(step)[None, None] < (
+                pos - start)[:, None, None]
+            return jnp.where(before, scores, -1e30), lambda p: matmul(
+                "bht,btc->bhc", p.astype(q.dtype), latents[..., :rkv])
+
+        oc = attend_live_blocks(
+            block, jnp.max(pos), t, qc.shape[:2] + (rkv,),
+            [(s_self, latent_self[:, None, :rkv].astype(jnp.float32))])
+    else:
+        latents = latent_cache[layer]
+        scores = matmul("bhc,btc->bht", qc, latents) * scale
+        before = jnp.arange(t)[None, None] < pos[:, None, None]
+        scores = jnp.where(before, scores, -1e30)
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, s_self[..., None]], -1), axis=-1)
+        oc = (matmul("bht,btc->bhc", probs[..., :-1].astype(q.dtype),
+                     latents[..., :rkv])
+              + probs[..., -1:] * latent_self[:, None, :rkv])
     o = matmul("bhc,chv->bhv", oc.astype(q.dtype), w_v)
     return matmul("bhv,hve->be", o.astype(q.dtype), att["wo"])
 
@@ -99,8 +123,8 @@ def longcat_decode_step(
     def attend(att, y):
         q, latent = mla_project(y[:, None], att, pos[:, None], cfg)
         new.append(latent[:, 0].astype(latent_cache.dtype))
-        return mla_absorbed(q[:, 0], new[-1], latent_cache[len(new) - 1],
-                            pos, att, cfg)
+        return mla_absorbed(q[:, 0], new[-1], latent_cache, pos, att, cfg,
+                            layer=len(new) - 1)
 
     for layer in range(cfg.n_layer):
         x, counts = double_layer(x, params, layer, live, attend, cfg)

@@ -2,10 +2,22 @@
 
 During generation each sequence attends one query token against its own
 ``[0, pos]`` cache prefix.  This is HBM-bandwidth-bound (the live cache
-prefix streams through once per token), so the Pallas kernel's job is to
-stream exactly the live prefix and nothing else.  The TPU analog of the
-paged/decode attention kernels the reference gets from vLLM's CUDA side
-(SURVEY.md §2.3: the reference has no kernels of its own).
+prefix streams through once per token), so the job is to stream exactly the
+live prefix and nothing else.  The TPU analog of the paged/decode attention
+kernels the reference gets from vLLM's CUDA side (SURVEY.md §2.3: the
+reference has no kernels of its own).
+
+What each path reads today.  The XLA path (``kernel=False``, what the model
+decode steps run) reads a full-extent cache up to the BATCH's longest live
+context, in blocks of ``extent_step(T)`` = 512 positions: one loop with a
+dynamic trip count a layer (``attend_live_blocks``), bounded on the device
+from ``pos`` itself, the blocks taken out of the stacked cache where they
+lie.  A ring (``window``) and a cache of one block are read whole.  The
+Pallas kernel copies a row's whole ``T`` and bounds its arithmetic by the
+row.  "Exactly the live prefix" a SLOT (in the traffic of the benchmark's
+cells a fifth of the cache, where the batch's longest context makes a step
+read 54-88 %: ``cache_read_pct.serve``) is still nobody's: it takes a
+ragged copy or a paged cache (ROADMAP D3).
 
 Kernel design (v5e-measured; see ``models/gpt2_decode.py`` docstring):
   - grid ``(B,)`` — one program per batch row, all kv heads processed
@@ -70,6 +82,73 @@ def ring_positions(pos, newest, window: int):
     return (held >= 0) & (pos - held < window)
 
 
+EXTENT_STEP = 512
+
+
+def extent_step(t: int) -> int:
+    """The step by which a decode's read of ``t`` cache positions is bounded:
+    blocks of 512 positions (four of 2048, eight of 4096).  Whole memory
+    tiles whatever the leaf's layout (every ``tile_positions`` divides 128),
+    and long enough that a block's fixed cost in the loop over blocks, 4 us
+    on the v5e beside 23 us a 256 positions of a Mistral layer's keys and
+    values, is a tenth of its read (PERF.md, PR 46: at 256 the finer bound
+    gave back more than it took).  A ``t`` of one block or of no whole
+    number of them has ONE extent, itself."""
+    return EXTENT_STEP if t > EXTENT_STEP and t % EXTENT_STEP == 0 else t
+
+
+def live_extent(longest, t: int):
+    """How many of ``t`` cache positions a decode step reads when the
+    longest of its rows needs positions ``[0, longest)``: the smallest
+    multiple of ``extent_step(t)`` that holds them, and at least one step.
+    Integer arithmetic alone, so the host (``llm/engine.py``'s dispatch
+    span) and the program (a traced ``longest``) say the same number."""
+    step = extent_step(t)
+    steps = (longest + step - 1) // step
+    return (steps + (steps == 0)) * step
+
+
+def attend_live_blocks(block, longest, t: int, shape, columns=()):
+    """Softmax-weighted values over the LIVE blocks of ``t`` cache positions
+    and a few columns beside them -> ``shape`` ``[..., Dv]`` float32.  A loop
+    over blocks of ``extent_step(t)`` positions whose trip count is this
+    step's ``live_extent(longest, t)`` in blocks, so a block beyond every
+    row's context is never read.  ``block(start) -> (scores, weigh)``: the
+    masked float32 scores ``[..., step]`` of positions ``[start, start +
+    step)`` and ``weigh(p) -> [..., Dv]`` float32, their values under
+    weights ``p``.  ``columns``: ``(score [...], value [..., Dv] or None)``,
+    one more logit each (the current token's; a sink, whose weight counts in
+    the sum and nothing else).
+
+    An online softmax: a running ``(max, sum, weighted values)`` that every
+    block and column updates alike.  A block that lies wholly beyond a row's
+    own context changes nothing of that row (its weights are exact zeros and
+    its maximum no higher), and every block runs the one compiled body, so a
+    row's result is the same BITS whatever its neighbours' contexts made the
+    trip count."""
+    step = extent_step(t)
+
+    def update(carry, scores, weigh):
+        m, l, acc = carry
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(scores - m_new)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + weigh(p))
+
+    lead = tuple(shape[:-1]) + (1,)
+    carry = jax.lax.fori_loop(
+        0, live_extent(longest, t) // step,
+        lambda j, carry: update(carry, *block(j * step)),
+        (jnp.full(lead, NEG_INF, jnp.float32), jnp.zeros(lead, jnp.float32),
+         jnp.zeros(shape, jnp.float32)))
+    for score, value in columns:
+        carry = update(carry, score[..., None],
+                       lambda p: 0.0 if value is None else p * value)
+    _, l, acc = carry
+    return acc / l
+
+
 def reference_decode_attention(q, k_cache, v_cache, pos, layer: int,
                                k_self=None, v_self=None, window=None,
                                sink=None):
@@ -86,7 +165,12 @@ def reference_decode_attention(q, k_cache, v_cache, pos, layer: int,
     (``ring_positions``).  ``sink`` ``[H]``: one more logit a head in the
     softmax, whose probability is dropped (the row then sums to less than
     one).  Both default to absent, and the program without them is what it
-    was."""
+    was.
+
+    A cache of several extents (``extent_step``) that is no ring is read in
+    blocks, up to the BATCH's longest live context and not to ``T``
+    (``attend_live_blocks``).  Nothing in a ring is dead, and a short cache
+    has one extent: both are scored whole, in one softmax, as ever."""
     k = k_cache[layer]  # [B, Hkv, T, D]
     v = v_cache[layer]
     b, hkv, t, d = k.shape
@@ -95,11 +179,38 @@ def reference_decode_attention(q, k_cache, v_cache, pos, layer: int,
     g = h // hkv
     qg = q.reshape(b, hkv, g, d)
     scale = d ** -0.5
-    scores = jnp.einsum("bkgd,bktd->bkgt", qg, k).astype(jnp.float32) * scale
     limit = pos[:, None, None, None]
+    if window is not None and t != window:
+        raise ValueError(f"a ring of {window} positions in a cache of {t}")
+    step = extent_step(t)
+    if window is None and step < t:
+        def block(start):
+            at = (layer, 0, 0, start, 0)
+            kb = jax.lax.dynamic_slice(k_cache, at, (1, b, hkv, step, d))[0]
+            vb = jax.lax.dynamic_slice(v_cache, at, (1, b, hkv, step, dv))[0]
+            scores = jnp.einsum(
+                "bkgd,bktd->bkgt", qg, kb).astype(jnp.float32) * scale
+            idx = jnp.arange(step)[None, None, None, :]
+            mask = (idx <= limit - start if k_self is None
+                    else idx < limit - start)
+            return jnp.where(mask, scores, NEG_INF), lambda p: jnp.einsum(
+                "bkgt,bktd->bkgd", p.astype(vb.dtype), vb,
+                preferred_element_type=jnp.float32)
+
+        columns = []
+        if k_self is not None:
+            columns.append((
+                jnp.einsum("bkgd,bkd->bkg", qg, k_self).astype(jnp.float32)
+                * scale, v_self[:, :, None, :].astype(jnp.float32)))
+        if sink is not None:
+            columns.append((jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+                1, hkv, g), (b, hkv, g)), None))
+        # a row reads [0, pos), and [0, pos] where its own token lies there
+        out = attend_live_blocks(block, jnp.max(pos) + (k_self is None), t,
+                                 (b, hkv, g, dv), columns)
+        return out.astype(v.dtype).reshape(b, h, dv)
+    scores = jnp.einsum("bkgd,bktd->bkgt", qg, k).astype(jnp.float32) * scale
     if window is not None:
-        if t != window:
-            raise ValueError(f"a ring of {window} positions in a cache of {t}")
         mask = ring_positions(
             limit, limit if k_self is None else limit - 1, window)
     else:

