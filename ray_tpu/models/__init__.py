@@ -54,6 +54,18 @@ from .mimo_v2_decode import (  # noqa: F401
     mimo_v2_init_cache,
     mimo_v2_prefill,
 )
+from .mistral4 import (  # noqa: F401
+    Mistral4Config,
+    mistral4_apply,
+    mistral4_init,
+    mistral4_loss,
+    mistral4_param_axes,
+)
+from .mistral4_decode import (  # noqa: F401
+    mistral4_decode_step,
+    mistral4_init_cache,
+    mistral4_prefill,
+)
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig,
     nemotron_h_apply,
@@ -202,5 +214,21 @@ register_model_family(
         prefill_counted=_functools.partial(mimo_v2_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             mimo_v2_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    Mistral4Config,
+    ModelFamily(
+        name="mistral4",
+        init=mistral4_init,
+        apply=mistral4_apply,
+        loss=mistral4_loss,
+        param_axes=mistral4_param_axes,
+        init_cache=mistral4_init_cache,
+        prefill=mistral4_prefill,
+        decode_step=mistral4_decode_step,
+        prefill_counted=_functools.partial(mistral4_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            mistral4_decode_step, with_counts=True),
     ),
 )

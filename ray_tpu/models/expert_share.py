@@ -4,15 +4,17 @@ has in common once it has routed.
 A layer that is told which experts it holds (``experts_held`` from
 ``expert_offset``) routes over ALL the model's experts, its own way
 (LongCat: softmax, not renormalised, identity experts; Nemotron-H and
-MiMo-V2: sigmoid scores, renormalised, ``sigmoid_route`` here), and then
+MiMo-V2: sigmoid scores, renormalised, ``sigmoid_route`` here; Mistral-4:
+softmax, renormalised over the chosen, ``softmax_route``), and then
 computes
 ``sum_{e held, chosen} w_e f_e(u)`` for the tokens that chose a held expert.
 That sum is here: ``held_choices`` turns the router's choices into the held
 experts' hit mask and combine weights, ``held_experts`` gathers each
 expert's tokens and walks the chunks.  The expert itself, ``f_e``, is the
 caller's: gated SwiGLU on the hidden state in ``longcat.py`` (``ffn``, which
-``mimo_v2.py`` runs too), an ungated ``relu^2`` MLP on a latent in
-``nemotron_h.py``.  What absent experts would add is left out.
+``mimo_v2.py`` and ``mistral4.py`` run too), an ungated ``relu^2`` MLP on a
+latent in ``nemotron_h.py``.  What absent experts would add is left out; what
+every chip computes alike (a shared expert) is the family's, beside this.
 """
 
 from __future__ import annotations
@@ -25,6 +27,10 @@ import jax.numpy as jnp
 # chunks of at most this many rows; an expert no live token chose runs
 # nothing and reads no weight.
 EXPERT_CHUNK = 128
+
+
+def _matmul(spec, x, w):  # ``longcat.matmul``, which imports this module
+    return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
 
 
 def runs_every_held_expert(rows: int, top_k: int, n_routed: int) -> bool:
@@ -52,6 +58,16 @@ def sigmoid_route(u, router, bias, top_k: int, scale: float = 1.0):
     chosen = jnp.take_along_axis(p, sel, axis=-1)
     w = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
     return sel, w
+
+
+def softmax_route(u, router, top_k: int, scale: float = 1.0):
+    """The renormalised softmax router (no bias, no group limits).  u ``[N,
+    d]`` float32, ``router [d, E]`` float32 -> the ``top_k`` experts each token
+    chose ``[N, k]`` (the largest of ``p = softmax(u router)``) and their
+    combine weights ``scale p / sum(p)`` over the chosen, float32."""
+    logits = jnp.dot(u, router, precision=jax.lax.Precision.HIGHEST)
+    chosen, sel = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return sel, scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
 
 
 def held_choices(sel, w, live, expert_offset: int, experts_held: int):
@@ -108,3 +124,16 @@ def held_experts(u, hit, w_held, expert):
                                 unique_indices=True)
 
     return jax.lax.fori_loop(0, ends[-1], one_chunk, out)
+
+
+def held_experts_dense(u, w_held, experts, i: int):
+    """``sum_e w_held[:, e] SwiGLU_e(u)`` with EVERY held expert run on every
+    row (a row that did not choose it weighs 0): three batched products over
+    layer ``i``'s stacks, no gather, no loop.  u ``[N, d]`` in the matrices'
+    dtype, w_held ``[N, Eh]`` float32 -> ``[N, d]`` float32.  Dropless and row by
+    row like the loop; the weight is applied before the last product (which
+    is linear), in float32."""
+    gate = jax.nn.silu(_matmul("nd,edf->enf", u, experts["w_gate"][i]))
+    up = _matmul("nd,edf->enf", u, experts["w_up"][i])
+    h = (gate * up * w_held.T[..., None]).astype(u.dtype)
+    return _matmul("enf,efd->nd", h, experts["w_down"][i])

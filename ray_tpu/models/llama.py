@@ -125,10 +125,13 @@ def _rmsnorm(x, g, eps: float):
     return (x32 * scale * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embedding.  x: [B, S, H, D]; positions: [B, S] or [S]."""
+def rope(x, positions, theta: float, inv_freq=None):
+    """Rotary embedding.  x: [B, S, H, D]; positions: [B, S] or [S].
+    ``inv_freq`` ``[D/2]``: a family's own frequencies a pair (scaled rotary:
+    ``mistral4.yarn_inv_freq``) in place of ``theta ** (-2i / D)``."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    freqs = (theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     if positions.ndim == 1:
         positions = positions[None]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]

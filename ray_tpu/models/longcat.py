@@ -196,20 +196,35 @@ def matmul(spec, x, w):
     return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
 
 
-def mla_project(y, att, positions, cfg: LongcatConfig):
+def mla_project(y, att, positions, cfg, *, latent_scales: bool = True,
+                inv_freq=None, q_factor=None):
     """y ``[B, S, d]`` -> roped queries ``[B, S, H, dn+dr]`` and the latent
-    ``[ckv | kr]`` ``[B, S, rkv+dr]`` that the cache holds."""
+    ``[ckv | kr]`` ``[B, S, rkv+dr]`` that the cache holds.  ``cfg``: any
+    config with the latent attention's sizes (``LongcatConfig``,
+    ``Mistral4Config``).  The defaults are LongCat's conventions: ``aq`` and
+    ``akv`` on query and latent, rotary at ``theta ** (-2i / dr)``.  A family
+    says otherwise by arguments that are static or absent:
+    ``latent_scales=False`` (neither scale), ``inv_freq`` ``[dr/2]`` (its own
+    rotary frequencies), ``q_factor`` (float32, broadcast against ``[B, S, H,
+    dn+dr]``: what multiplies the whole query before it is rounded, a
+    softmax scale that depends on the query's position)."""
     rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    aq = (cfg.d_model / cfg.q_lora_rank) ** 0.5
-    akv = (cfg.d_model / rkv) ** 0.5
     cq = _rmsnorm(matmul("bse,er->bsr", y, att["wq_a"]), att["rms_q"],
                   cfg.rms_eps).astype(y.dtype)
-    q = matmul("bsr,rhd->bshd", cq, att["wq_b"]) * aq
+    q = matmul("bsr,rhd->bshd", cq, att["wq_b"])
+    if latent_scales:
+        q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
+    if q_factor is not None:
+        q = q * q_factor
     q = jnp.concatenate(
-        [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta)], -1)
+        [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta,
+                           inv_freq)], -1)
     kv = matmul("bse,er->bsr", y, att["wkv_a"])
-    ckv = _rmsnorm(kv[..., :rkv], att["rms_kv"], cfg.rms_eps) * akv
-    kr = rope(kv[..., None, rkv:], positions, cfg.rope_theta)[..., 0, :]
+    ckv = _rmsnorm(kv[..., :rkv], att["rms_kv"], cfg.rms_eps)
+    if latent_scales:
+        ckv = ckv * (cfg.d_model / rkv) ** 0.5
+    kr = rope(kv[..., None, rkv:], positions, cfg.rope_theta,
+              inv_freq)[..., 0, :]
     return q.astype(y.dtype), jnp.concatenate([ckv, kr], -1).astype(y.dtype)
 
 

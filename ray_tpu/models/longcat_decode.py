@@ -66,16 +66,25 @@ def longcat_prefill(
     return (*out, counts) if with_counts else out
 
 
-def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg: LongcatConfig,
+def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg,
                  layer: int = 0):
     """One query token a row against its slot's latents.  q [B, H, dn+dr];
     latent_self [B, C] (the current token's); latent_cache [A, B, T, C], the
     STACKED cache, of which attention ``layer``'s slice holds [0, pos-1];
     pos [B] -> [B, d] float32.  A cache of several extents is read in blocks
     up to the batch's longest context (``ops/decode_attention``'s
-    ``attend_live_blocks``), each block taken from the stack itself."""
+    ``attend_live_blocks``), each block taken from the stack itself.
+    ``att`` holds ``Wkvb`` whole (``wkv_b [rkv, H, dn+dv]``, LongCat's: its
+    halves are sliced out here) or as two leaves (``wk_b [rkv, H, dn]``,
+    ``wv_b [rkv, H, dv]``, mistral4's: each product reads its own stack
+    where it lies).  The softmax scale is ``(dn+dr)^-0.5``; what else
+    multiplies the scores is in ``q`` already (``mla_project``'s
+    ``q_factor``)."""
     rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    w_k, w_v = att["wkv_b"][..., :dn], att["wkv_b"][..., dn:]
+    if "wkv_b" in att:
+        w_k, w_v = att["wkv_b"][..., :dn], att["wkv_b"][..., dn:]
+    else:
+        w_k, w_v = att["wk_b"], att["wv_b"]
     qt = matmul("bhn,chn->bhc", q[..., :dn], w_k).astype(q.dtype)
     qc = jnp.concatenate([qt, q[..., dn:]], -1)  # [B, H, C]
     scale = q.shape[-1] ** -0.5

@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.decode_attention import NEG_INF, ring_held
-from .expert_share import (held_choices, held_experts,
+from .expert_share import (held_choices, held_experts, held_experts_dense,
                            runs_every_held_expert, sigmoid_route)
 from .llama import _rmsnorm
 from .longcat import add_counts, ffn, matmul
@@ -342,19 +342,6 @@ def ring_of(a, lengths, window: int):
 
 
 # ------------------------------------------------------------------ experts
-def held_experts_dense(u, w_held, experts, i: int):
-    """``sum_e w_held[:, e] SwiGLU_e(u)`` with EVERY held expert run on every
-    row (a row that did not choose it weighs 0): three batched products over
-    layer ``i``'s stacks, no gather, no loop.  u ``[N, d]`` in ``cfg.dtype``,
-    w_held ``[N, Eh]`` float32 -> ``[N, d]`` float32.  Dropless and row by
-    row like the loop; the weight is applied before the last product (which
-    is linear), in float32."""
-    gate = jax.nn.silu(matmul("nd,edf->enf", u, experts["w_gate"][i]))
-    up = matmul("nd,edf->enf", u, experts["w_up"][i])
-    h = (gate * up * w_held.T[..., None]).astype(u.dtype)
-    return matmul("enf,efd->nd", h, experts["w_down"][i])
-
-
 def moe(u, live, params, i: int, cfg: MimoV2Config):
     """Expert layer ``i``'s share on this chip.  ``u [N, d]`` normed tokens
     in float32 (the router reads them as they are, the experts in

@@ -108,25 +108,22 @@ def live_extent(longest, t: int):
     return (steps + (steps == 0)) * step
 
 
-def attend_live_blocks(block, longest, t: int, shape, columns=()):
-    """Softmax-weighted values over the LIVE blocks of ``t`` cache positions
-    and a few columns beside them -> ``shape`` ``[..., Dv]`` float32.  A loop
-    over blocks of ``extent_step(t)`` positions whose trip count is this
-    step's ``live_extent(longest, t)`` in blocks, so a block beyond every
-    row's context is never read.  ``block(start) -> (scores, weigh)``: the
-    masked float32 scores ``[..., step]`` of positions ``[start, start +
-    step)`` and ``weigh(p) -> [..., Dv]`` float32, their values under
-    weights ``p``.  ``columns``: ``(score [...], value [..., Dv] or None)``,
-    one more logit each (the current token's; a sink, whose weight counts in
-    the sum and nothing else).
+def attend_blocks(block, n_blocks, step: int, shape, columns=()):
+    """Softmax-weighted values over the first ``n_blocks`` blocks of ``step``
+    positions and a few columns beside them -> ``shape`` ``[..., Dv]``
+    float32; ``n_blocks`` may be traced (the loop's trip count).
+    ``block(start) -> (scores, weigh)``: the masked float32 scores ``[...,
+    step]`` of positions ``[start, start + step)`` and ``weigh(p) -> [...,
+    Dv]`` float32, their values under weights ``p``.  ``columns``: ``(score
+    [...], value [..., Dv] or None)``, one more logit each (the current
+    token's; a sink, whose weight counts in the sum and nothing else).
 
     An online softmax: a running ``(max, sum, weighted values)`` that every
     block and column updates alike.  A block that lies wholly beyond a row's
     own context changes nothing of that row (its weights are exact zeros and
     its maximum no higher), and every block runs the one compiled body, so a
-    row's result is the same BITS whatever its neighbours' contexts made the
-    trip count."""
-    step = extent_step(t)
+    row's result is the same BITS whatever made the trip count longer.  A
+    row must see a position somewhere (a block or a column)."""
 
     def update(carry, scores, weigh):
         m, l, acc = carry
@@ -138,7 +135,7 @@ def attend_live_blocks(block, longest, t: int, shape, columns=()):
 
     lead = tuple(shape[:-1]) + (1,)
     carry = jax.lax.fori_loop(
-        0, live_extent(longest, t) // step,
+        0, n_blocks,
         lambda j, carry: update(carry, *block(j * step)),
         (jnp.full(lead, NEG_INF, jnp.float32), jnp.zeros(lead, jnp.float32),
          jnp.zeros(shape, jnp.float32)))
@@ -147,6 +144,18 @@ def attend_live_blocks(block, longest, t: int, shape, columns=()):
                        lambda p: 0.0 if value is None else p * value)
     _, l, acc = carry
     return acc / l
+
+
+def attend_live_blocks(block, longest, t: int, shape, columns=()):
+    """``attend_blocks`` over the LIVE blocks of ``t`` cache positions: blocks
+    of ``extent_step(t)`` positions, as many as this step's
+    ``live_extent(longest, t)`` holds, so a block beyond every row's context
+    is never read.  A decode step's read of a full-extent cache; a row's
+    result is the same bits whatever its neighbours' contexts made the trip
+    count."""
+    step = extent_step(t)
+    return attend_blocks(block, live_extent(longest, t) // step, step, shape,
+                         columns)
 
 
 def reference_decode_attention(q, k_cache, v_cache, pos, layer: int,
@@ -245,7 +254,7 @@ def tile_positions(shape, dtype, axis: int) -> int:
     keeps an array's last axis minor unless that would pad it: where the
     last axis is no multiple of 128 it swaps the last two
     (``tests/test_tpu_compile.py`` reads that from the compiler for
-    thirteen shapes).  So positions lie on the sublanes of Mistral's ``[..,
+    twenty-one shapes).  So positions lie on the sublanes of Mistral's ``[..,
     T, 128]`` and on the lanes of LongCat's ``[.., T, 576]``, GPT-2's ``[..,
     T, 64]`` and MiMo's keys ``[.., T, 192]``, whose ring of 128 positions is
     then ONE tile."""

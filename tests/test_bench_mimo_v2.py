@@ -16,13 +16,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
     """The benchmark's case of this name (star-imported above, shadowed
-    here) holds PR 45's four metrics to be the LAST four of ``per_layer``,
-    which no later PR that adds a metric can keep, and the benchmark's files
-    are add-only, its tests among them.  The same case over ``per_layer`` as
-    far as PR 45 wrote it, and what was appended since by name."""
+    here) holds PR 45's four metrics to be the LAST four of ``per_layer`` and
+    its cell the LAST of ``workloads``, which no later PR that adds a metric
+    or a cell can keep, and the benchmark's files are add-only, its tests
+    among them.  The same case over ``per_layer`` and ``workloads`` as far as
+    PR 45 wrote them, and what was appended since by name."""
     import benchmarks.tests.test_bench_mimo_v2 as theirs
 
-    appended = []
+    appended, cells = [], []
 
     def load_as_pr45_left_it(*path):
         bench = theirs_load(*path)
@@ -31,12 +32,25 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
             cut = names.index(theirs.NEW_METRICS[-1]) + 1
             appended[:] = names[cut:]
             bench["per_layer"] = bench["per_layer"][:cut]
+            names = [w["name"] for w in bench["workloads"]]
+            cut = names.index(theirs.CELL) + 1
+            cells[:] = names[cut:]
+            bench["workloads"] = bench["workloads"][:cut]
+            for metric in bench["end_to_end"] + bench["per_layer"]:
+                if "workloads" in metric:  # a later cell's name, appended
+                    metric["workloads"] = [
+                        w for w in metric["workloads"] if w not in cells]
         return bench
 
     theirs_load = theirs.load
     monkeypatch.setattr(theirs, "load", load_as_pr45_left_it)
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
-    assert appended == ["cache_read_pct.serve"]  # PR 46
+    assert appended == [
+        "cache_read_pct.serve",  # PR 46
+        "latent_long_decode_roofline.serve", "prefill_mfu.serve",  # PR 48
+        "prefill_useful_pct.serve_rate", "ep8_expert_tokens.serve",
+        "ep8_experts_touched_pct.serve"]
+    assert cells == ["mistral4_ep8_longdoc_closed32"]  # PR 48
 
 
 def run(*command):

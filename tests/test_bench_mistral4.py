@@ -1,0 +1,55 @@
+"""The Mistral-4 configuration, traffic, arithmetic, reader and metric files
+the benchmark gained in PR 48, under every PR's tests: the cases live beside
+the code they pin."""
+
+from benchmarks.tests.test_bench_mistral4 import *  # noqa
+
+import json  # noqa: E402
+import os  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def run(*command):
+    out = subprocess.run(
+        [sys.executable, *command], capture_output=True, text=True,
+        timeout=600,
+        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
+    assert out.returncode == 0, (out.stdout + out.stderr)[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_the_new_cell_rehearses_on_the_cpu_with_its_trace():
+    """``benchmarks/selftest.py --rehearse`` names its cells and may not be
+    edited by the PR that adds one (the benchmark's files are add-only), so
+    the new cell's rehearsal lives here, ``selftest.py --rehearse``'s way:
+    serve -> proxy -> ``LLMServer`` -> ``JaxLLMEngine`` at tiny widths on CPU
+    workers, traced, with the harness's two-layer reference check (whose 64
+    + 3 positions cross the tiny trained length of 16 four times), ending in
+    a line that cannot be mistaken for a run."""
+    last = run(os.path.join(REPO, "benchmarks", "run.py"), "--workload",
+               "mistral4_ep8_longdoc_closed32", "--seed", "4800000019",
+               "--seconds", "3", "--trace", "1", "--rehearse-cpu")
+    assert last["rehearsal_ok"] is True and last["attempted"] > 0
+    assert last["failed"] == 0 and not last["problems"]
+    assert not {"metrics", "correct", "device"} & set(last), last
+
+
+def test_the_builders_comparison_rehearses_on_the_cpu():
+    """``benchmarks/mistral4_all_layers.py``: all the layers through the
+    engine's own programs with rows beyond and below the trained length, the
+    reference's two switched-off controls and the coarse matrices, walked at
+    tiny widths (where the scales leave the limit without meaning)."""
+    last = run(os.path.join(REPO, "benchmarks", "mistral4_all_layers.py"),
+               "--rehearse-cpu")
+    assert last["rehearsal_ok"] is True and "ok" not in last
+    assert last["positions"] == 20 and last["layers"] == 2
+    long_rows, short_rows = last["lengths"][:2], last["lengths"][2:]
+    assert min(long_rows) > 4 * 16 > max(short_rows) > 16
+    for name in ("program", "control_no_yarn",
+                 "control_no_query_scale_long_rows",
+                 "control_coarse_matrices"):
+        assert 0 < last[name]["median_rms"] <= last[name]["worst_rms"]
+    assert last["attention_layer0"]["positions"] == long_rows[0]

@@ -33,13 +33,13 @@ TOL = {"float32": 2e-6, "bfloat16": 2e-2}
 
 
 def test_the_step_is_blocks_of_512_positions():
-    assert {extent_step(t) for t in (1024, 2048, 4096, 8192)} == {512}
+    assert {extent_step(t) for t in (1024, 2048, 4096, 8192, 16384)} == {512}
     # one block, or no whole number of them: one extent, no loop
     alone = (8, 24, 32, 64, 128, 256, 512, 1000, 1280, 2047)
     assert [extent_step(t) for t in alone] == list(alone)
 
 
-@pytest.mark.parametrize("t", [128, 1024, 2048, 4096])
+@pytest.mark.parametrize("t", [128, 1024, 2048, 4096, 16384])
 def test_live_extent_on_ints_equals_the_traced_value(t):
     traced = jax.jit(lambda longest: live_extent(longest, t))
     step = extent_step(t)
@@ -249,3 +249,36 @@ def test_mla_absorbed_over_a_cache_of_one_extent_is_bit_for_bit_what_it_was():
     np.testing.assert_array_equal(now, before)
     assert loops(lambda *a: longcat_decode.mla_absorbed(
         *a, att, cfg, layer=1), q, latent_self, cache, pos) == 0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_mla_absorbed_over_thirty_two_blocks_with_the_halves_as_two_leaves(
+    dtype
+):
+    """``T`` = 16,384 (the Mistral-4 cell's: 32 blocks of 512 where the older
+    cells have 4-8), ``Wkvb`` as two leaves (``wk_b`` / ``wv_b``) instead of
+    one sliced here: the one-shot softmax's result to rounding, rows at 1,
+    9,000 and 16,383 cached positions in one batch; a row's bits do not
+    depend on whether its neighbour makes the loop run 1 or 32 blocks; and
+    nothing beyond the longest context's block is read."""
+    t = 16384
+    cfg, att, (q, latent_self, cache) = mla_operands(t, dtype, seed=2)
+    dn = cfg.qk_nope_head_dim
+    halves = {"wk_b": att["wkv_b"][..., :dn], "wv_b": att["wkv_b"][..., dn:],
+              "wo": att["wo"]}
+    now = jax.jit(lambda *a: longcat_decode.mla_absorbed(
+        *a, halves, cfg, layer=1))
+    before = jax.jit(lambda q, ls, cache, pos: mla_absorbed_before(
+        q, ls, cache[1], pos, att, cfg))
+    pos = jnp.asarray([1, 9000, t - 1], jnp.int32)
+    got = now(q, latent_self, cache, pos)
+    np.testing.assert_allclose(got, before(q, latent_self, cache, pos),
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    near = now(q, latent_self, cache, jnp.asarray([1, 9000, 9001], jnp.int32))
+    np.testing.assert_array_equal(got[:2], near[:2])
+    poisoned = cache.at[:, :, 18 * STEP:].set(jnp.nan)
+    np.testing.assert_array_equal(
+        now(q, latent_self, poisoned, jnp.asarray([1, 9000, 9001], jnp.int32)),
+        near)
+    assert loops(lambda *a: longcat_decode.mla_absorbed(
+        *a, halves, cfg, layer=1), q, latent_self, cache, pos) == 1

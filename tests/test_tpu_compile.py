@@ -114,7 +114,8 @@ def test_decode_kernel_refused_for_vmem_at_tinyllama_shape(
 
 # The serving cells' configurations (benchmarks/configs/<name>.json).
 CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
-                "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16"]
+                "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16",
+                "mistral_small4_l9_ep8"]
 
 
 @pytest.fixture(scope="module")
@@ -232,7 +233,8 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
 BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "longcat_flash_l4_ep32": (8, 0.2e9),
                  "nemotron3_super_l11_ep4": (1, 0.2e9),
-                 "mimo_v25_l7_ep16": (2, 0.05e9)}
+                 "mimo_v25_l7_ep16": (2, 0.05e9),
+                 "mistral_small4_l9_ep8": (9, 0.05e9)}
 
 
 @pytest.mark.parametrize("name", CELL_CONFIGS)
@@ -264,7 +266,8 @@ def test_decode_step_reads_its_live_blocks_where_they_lie(
     assert step == 512 < t
     loops, temp_limit = BOUNDED_READS[name]
     assert len(re.findall(
-        r' while\(.*op_name="[^"]*(?:decode_attention\)|longcat\.mla)/while"',
+        r' while\(.*op_name="[^"]*(?:decode_attention\)|longcat\.mla|mistral4\.mla)'
+        r'/while"',
         text)) == loops
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
     prefixes = {}  # dims without the 1s -> steps
@@ -351,6 +354,44 @@ def test_windowed_decode_step_fits_and_its_top_rung_beside_it(
     rung = jit_prefill_one(fam, cfg).lower(
         lying, cache, tokens, scalar, scalar).compile()
     assert rung.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+def test_latent_long_decode_step_fits_and_its_top_rung_beside_it(
+    cell, cell_decode_step, on_chip
+):
+    """The Mistral-4 cell's programs at its size (published widths, 9
+    layers, 16 experts held, 32 slots x 16,384): the decode step's arguments
+    are the weights and a cache of 9 x 32 x 16384 x 320 x 2 B = 3.02 GB (the
+    TPU lays positions on the lanes and the 320 on the sublanes: NOT padded
+    to 384), all of it aliased to the output, its temporaries a thousandth
+    of what it reads (no slice of the cache or of an expert stack copied
+    out); the compiler asks for no other layout of an expert stack (the
+    build moves relaid leaves while holding them twice: 7.25 GB would not
+    fit); and the top prefill rung, whose layers are one scanned body and
+    whose scores exist a tile of 512 x 512 at a time, needs 1.4 GB beside
+    them (dense ``[32, 16384, 16384]`` float32 scores would be 34 GB a layer;
+    nine layers written out kept 5.05 GB of temporaries)."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("mistral_small4_l9_ep8")
+    memory = step.memory_analysis()
+    assert cache["latent"].shape == (9, 32, 16384, 320)
+    assert 11.4e9 < memory.argument_size_in_bytes < 11.6e9
+    assert 3.01e9 < memory.alias_size_in_bytes < 3.03e9
+    assert memory.temp_size_in_bytes < 0.05e9
+    formats = step.input_formats[0][0]
+    assert all(fmt.layout.major_to_minor == (0, 1, 2, 3)
+               for fmt in formats["experts"].values())
+    fam, cfg, _, _ = cell("mistral_small4_l9_ep8")
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+    tokens = on_chip(jax.ShapeDtypeStruct((16384,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    rung = jit_prefill_one(fam, cfg).lower(
+        lying, cache, tokens, scalar, scalar).compile()
+    assert rung.input_formats[0][0] == formats
+    assert rung.memory_analysis().temp_size_in_bytes < 2.0e9
+    assert rung.memory_analysis().generated_code_size_in_bytes < 20e6
 
 
 # What hands an array on as it is, and what prefetches one into the chip's
@@ -499,6 +540,14 @@ CACHE_LEAVES = [
     ((5, 64, 8, 128, 192), "bfloat16"),    # a ring of keys (one tile),
     ((2, 64, 4, 4096, 128), "bfloat16"),   # values
     ((5, 64, 8, 128, 128), "bfloat16"),
+    ((9, 32, 16384, 320), "bfloat16"),     # Mistral-4 cell: a last axis that
+    ((9, 1, 16384, 320), "bfloat16"),      # is no multiple of 128, and its
+    ((9, 1, 8192, 320), "bfloat16"),       # one-row twin at each rung
+    ((9, 1, 4096, 320), "bfloat16"),
+    ((9, 1, 2048, 320), "bfloat16"),
+    ((9, 1, 1024, 320), "bfloat16"),
+    ((9, 1, 512, 320), "bfloat16"),
+    ((9, 1, 256, 320), "bfloat16"),
 ]
 
 
