@@ -53,16 +53,26 @@ def reference_attention(
 # Pallas TPU flash attention: one forward kernel, one backward kernel
 # --------------------------------------------------------------------------
 #
+# Every operand and result crosses the ``pallas_call`` boundary as
+# ``[B*H, D, S]``: the sequence along the lanes, the head's 64 or 128 down
+# the sublanes.  That is where the compiled training step keeps q, k, v and
+# their gradients anyway (the projection leaves ``[B, 3, H, D, S]``), so the
+# folds around the kernels are bitcasts and no ``copy`` program stands
+# between a projection and a kernel; and a ``[D, S]`` tile is dense where a
+# ``[S, 64]`` one fills half of every 128-lane tile row (PERF.md, PR 50).
+#
 # Both kernels hold a tile pair's scores TRANSPOSED, ``k q^T`` =
 # [block_k, block_q], keys down the sublanes and queries along the lanes.
 # The softmax's reductions over keys are then elementwise maxima and sums of
 # whole registers (no cross-lane reduction), the per-query statistics (m, l,
-# lse, delta) are lane-dense rows, and all but one product a kernel feed the
-# MXU their operands as they lie: the forward's acc^T = v^T p^T and the backward's
-# dq^T = k^T ds^T contract over the first axis of both operands, whose
-# transposed side is the small [block_k, D] tile.  No [block_q, block_k]
-# tile is ever transposed.  out and dq leave the kernels as [D, S] and are
-# turned by the reshape to [B, S, H, D] that follows them anyway.
+# lse, delta) are lane-dense rows, and no [block_k, block_q] tile is ever
+# turned: acc^T = v^T p^T and dq^T = k^T ds^T are plain products, dv^T =
+# dO^T p and dk^T = q^T ds contract over the lanes of both sides (the
+# ``q k^T`` form).  What does not come as it lies is k (and in the backward
+# v) as [keys, D] for s^T = k q^T and dp^T = v dO^T: the small [D, block_k]
+# side.  The forward turns the resident K once a head into VMEM; the
+# backward contracts over the sublanes of both sides and leaves the turn to
+# the compiler (each is what the chip preferred: PERF.md, PR 50).
 #
 # Under a causal mask a kernel visits only the tile pairs that hold a live
 # score: ``_live_key_tiles`` / ``_first_query_tile`` are the loops' bounds,
@@ -134,19 +144,27 @@ def flash_tile_work(sq: int, sk: int, block_q: int, block_k: int,
 _TILE = 512
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
-                      block_k: int, sk: int, causal: bool, scale: float):
-    """Grid: (batch*heads, Sq/block_q).  Ref tiles (leading dim squeezed):
-    q_ref [block_q, D], k_ref/v_ref [Sk, D], o_ref [D, block_q] (out^T),
-    lse_ref [1, block_q] (per-query logsumexp, saved for the backward
-    kernel)."""
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, k_rows, *,
+                      block_q: int, block_k: int, sk: int, causal: bool,
+                      scale: float):
+    """Grid: (batch*heads, Sq/block_q), the second axis in order.  Ref tiles
+    (leading dim squeezed): q_ref [D, block_q] (q^T), k_ref/v_ref [D, Sk]
+    (k^T, v^T: the same block for every query tile of a head), o_ref
+    [D, block_q] (out^T), lse_ref [1, block_q] (per-query logsumexp, saved
+    for the backward kernel).  ``k_rows`` (VMEM, [Sk, D]) is k turned, once a
+    head: at the head's first query tile."""
     import jax.experimental.pallas as pl
 
     iota = jax.lax.broadcasted_iota
     q_block = pl.program_id(1)
+
+    @pl.when(q_block == 0)
+    def _():
+        k_rows[:] = k_ref[:].T
+
     # Matmul inputs stay in the storage dtype (bf16): the MXU's native rate
     # is bf16xbf16->f32; upcasting tiles first would run every dot at the
-    # much slower f32 rate.  The softmax scale goes into the [block_q, D]
+    # much slower f32 rate.  The softmax scale goes into the [D, block_q]
     # query tile once, not into every score tile; softmax arithmetic happens
     # on the f32 accumulator.
     q = (q_ref[:] * scale).astype(q_ref.dtype)
@@ -154,8 +172,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     def body(kb, carry):
         m, l, acc = carry
         keys = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
-        s = jax.lax.dot_general(k_ref[keys, :], q, _NT,
-                                preferred_element_type=jnp.float32)
+        s = jnp.dot(k_rows[keys, :], q, preferred_element_type=jnp.float32)
         if causal:
             k_pos = kb * block_k + iota(jnp.int32, (block_k, block_q), 0)
             q_pos = q_block * block_q + iota(jnp.int32, (block_k, block_q), 1)
@@ -164,11 +181,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-        v_tile = v_ref[keys, :]
-        acc = acc * alpha + jax.lax.dot_general(
-            v_tile, p.astype(v_tile.dtype), _TN,
-            preferred_element_type=jnp.float32,
-        )
+        v_cols = v_ref[:, keys]
+        acc = acc * alpha + jnp.dot(v_cols, p.astype(v_cols.dtype),
+                                    preferred_element_type=jnp.float32)
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(
@@ -182,52 +197,53 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     lse_ref[:] = m + jnp.log(l)
 
 
-def _fold_heads(x):
-    """[B, S, H, D] -> [B*H, S, D]: batch and heads are the grid's first
-    axis."""
+def _fold(x):
+    """[B, S, H, D] -> [B*H, D, S], the kernels' layout: batch and heads are
+    the grid's first axis, the sequence lies along the lanes."""
     b, s, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+    return x.transpose(0, 2, 3, 1).reshape(b * h, d, s)
 
 
-def _unfold_transposed(x, b: int):
-    """A kernel's [B*H, D, S] output -> [B, S, H, D]."""
+def _unfold(x, b: int):
+    """A kernel's [B*H, D, S] -> [B, S, H, D]."""
     bh, d, s = x.shape
     return x.reshape(b, bh // b, d, s).transpose(0, 3, 1, 2)
 
 
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
                interpret: bool):
-    """-> (out [B, Sq, H, D], lse [B*H, 1, Sq] float32)."""
+    """q [B*H, D, Sq], k/v [B*H, D, Sk] (``_fold``ed) -> (out [B*H, D, Sq],
+    lse [B*H, 1, Sq] float32)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
+    bh, d, sq = q.shape
+    sk = k.shape[2]
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, sk=sk,
         causal=causal, scale=scale,
     )
-    out, lse = pl.pallas_call(
+    query_tile = pl.BlockSpec((None, d, block_q), lambda i, qb: (i, 0, qb))
+    whole_k = pl.BlockSpec((None, d, sk), lambda i, qb: (i, 0, 0))
+    return pl.pallas_call(
         kernel,
-        grid=(b * h, sq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, qb: (bh, qb, 0)),
-            pl.BlockSpec((None, sk, d), lambda bh, qb: (bh, 0, 0)),
-            pl.BlockSpec((None, sk, d), lambda bh, qb: (bh, 0, 0)),
-        ],
+        grid=(bh, sq // block_q),
+        in_specs=[query_tile, whole_k, whole_k],
         out_specs=[
-            pl.BlockSpec((None, d, block_q), lambda bh, qb: (bh, 0, qb)),
-            pl.BlockSpec((None, 1, block_q), lambda bh, qb: (bh, 0, qb)),
+            query_tile,
+            pl.BlockSpec((None, 1, block_q), lambda i, qb: (i, 0, qb)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, d, sq), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, d, sq), q.dtype),
+            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((sk, d), k.dtype)],
+        # k_rows is filled at a head's first query tile: that axis runs in
+        # order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(_fold_heads(q), _fold_heads(k), _fold_heads(v))
-    return _unfold_transposed(out, b), lse
+    )(q, k, v)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -236,27 +252,31 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       scale: float):
     """dQ, dK, dV in one pass that builds s, p, dp, ds once a tile pair: 5
     products and 1 exponential.  Grid (batch*heads, Sk/block_k), the K/V tile
-    resident, an inner loop over the query tiles from the diagonal on.  Refs:
-    q_ref/do_ref [Sq, D], k_ref/v_ref [block_k, D], lse_ref/delta_ref
-    [1, Sq], dk_ref/dv_ref [block_k, D]; dq_ref [D, Sq] (dq^T) keeps its
-    block along the key-tile axis, so ``dq_acc`` (float32, VMEM) gathers
-    every key tile's share: zeroed at the first key tile, cast into dq_ref
-    after the last.  ``dk_acc`` / ``dv_acc`` (float32 [block_k, D], VMEM)
-    gather a key tile's query tiles.
+    resident, an inner loop over the query tiles from the diagonal on.  Refs,
+    all transposed: q_ref/do_ref [D, Sq], k_ref/v_ref [D, block_k],
+    lse_ref/delta_ref [1, Sq], dk_ref/dv_ref [D, block_k]; dq_ref [D, Sq]
+    keeps its block along the key-tile axis, so ``dq_acc`` (float32, VMEM)
+    gathers every key tile's share: zeroed at the first key tile, cast into
+    dq_ref after the last.  ``dk_acc`` / ``dv_acc`` (float32 [D, block_k],
+    VMEM) gather a key tile's query tiles.
 
-    With the scores transposed, p^T and ds^T are what the loop holds:
-    dv += p^T dO and dk += ds^T q are plain products, dq^T += k^T ds^T
-    contracts over the first axis of both.  ds = p * (dO v^T - delta), p
-    from the saved per-query logsumexp."""
+    With the scores transposed, p^T and ds^T are what the loop holds, and
+    q^T, dO^T stream as they come: dv^T += dO^T p and dk^T += q^T ds
+    contract over the lanes of both sides, dq^T += k^T ds^T is plain, and
+    s^T = k q^T and dp^T = v dO^T contract over the sublanes of both (the
+    compiler turns the [D, block_k] side a product; on the chip that beats
+    tiles turned once a grid cell into VMEM, 2.41 against 2.52 ms a layer:
+    PERF.md, PR 50).  ds = p * (dO v^T - delta), p from the saved per-query
+    logsumexp."""
     import jax.experimental.pallas as pl
 
     iota = jax.lax.broadcasted_iota
     k_block = pl.program_id(1)
-    v_tile = v_ref[:]
     # bf16 matmul operands, f32 accumulation/arithmetic (see fwd kernel).
     # The scale goes into the resident key tile once: s = q (scale k)^T and
     # dq = ds (scale k) need no other; dk takes it after the loop.
-    k_tile = (k_ref[:] * scale).astype(k_ref.dtype)
+    k_cols = (k_ref[:] * scale).astype(k_ref.dtype)
+    v_cols = v_ref[:]
 
     @pl.when(k_block == 0)
     def _():
@@ -266,24 +286,25 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_acc[:] = jnp.zeros(dv_acc.shape, jnp.float32)
 
     def body(qb, _):
-        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
-        q_tile = q_ref[rows, :]
-        do = do_ref[rows, :]
-        s = jax.lax.dot_general(k_tile, q_tile, _NT,
+        cols = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+        q_cols = q_ref[:, cols]
+        do = do_ref[:, cols]
+        s = jax.lax.dot_general(k_cols, q_cols, _TN,
                                 preferred_element_type=jnp.float32)
         if causal:
             k_pos = k_block * block_k + iota(jnp.int32, (block_k, block_q), 0)
             q_pos = qb * block_q + iota(jnp.int32, (block_k, block_q), 1)
             s = jnp.where(k_pos <= q_pos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, rows])
-        dv_acc[:] += jnp.dot(p.astype(do.dtype), do,
-                             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v_tile, do, _NT,
+        p = jnp.exp(s - lse_ref[:, cols])
+        dv_acc[:] += jax.lax.dot_general(
+            do, p.astype(do.dtype), _NT, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_cols, do, _TN,
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[:, rows])).astype(q_tile.dtype)
-        dk_acc[:] += jnp.dot(ds, q_tile, preferred_element_type=jnp.float32)
-        dq_acc[:, rows] += jax.lax.dot_general(
-            k_tile, ds, _TN, preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[:, cols])).astype(q_cols.dtype)
+        dk_acc[:] += jax.lax.dot_general(
+            q_cols, ds, _NT, preferred_element_type=jnp.float32)
+        dq_acc[:, cols] += jnp.dot(k_cols, ds,
+                                   preferred_element_type=jnp.float32)
         return 0
 
     nq = sq // block_q
@@ -299,54 +320,44 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float, block_q: int,
                block_k: int, interpret: bool):
+    """Everything ``_fold``ed, [B*H, D, S]: -> (dq, dk, dv) so too."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    # delta_i = Σ_d dO_id · O_id  (per query), in plain XLA; along the lanes
-    # like lse.
-    delta = (
-        (g.astype(jnp.float32) * o.astype(jnp.float32))
-        .sum(-1)
-        .transpose(0, 2, 1)
-        .reshape(b * h, 1, sq)
-    )
+    bh, d, sq = q.shape
+    sk = k.shape[2]
+    # delta_i = Σ_d dO_id · O_id  (per query), in plain XLA: a sum down the
+    # sublanes of [D, S], its result along the lanes like lse.
+    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        1, keepdims=True)
 
     kernel = functools.partial(
         _flash_bwd_kernel, block_q=block_q, block_k=block_k, sq=sq,
         causal=causal, scale=scale,
     )
-    whole_q = pl.BlockSpec((None, sq, d), lambda bh, kb: (bh, 0, 0))
-    key_tile = pl.BlockSpec((None, block_k, d), lambda bh, kb: (bh, kb, 0))
-    row = pl.BlockSpec((None, 1, sq), lambda bh, kb: (bh, 0, 0))
-    dq, dk, dv = pl.pallas_call(
+    whole_q = pl.BlockSpec((None, d, sq), lambda i, kb: (i, 0, 0))
+    key_tile = pl.BlockSpec((None, d, block_k), lambda i, kb: (i, 0, kb))
+    row = pl.BlockSpec((None, 1, sq), lambda i, kb: (i, 0, 0))
+    return pl.pallas_call(
         kernel,
-        grid=(b * h, sk // block_k),
+        grid=(bh, sk // block_k),
         in_specs=[whole_q, key_tile, key_tile, whole_q, row, row],
-        out_specs=[
-            pl.BlockSpec((None, d, sq), lambda bh, kb: (bh, 0, 0)),
-            key_tile, key_tile,
-        ],
+        out_specs=[whole_q, key_tile, key_tile],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, d, sq), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, d, sq), q.dtype),
+            jax.ShapeDtypeStruct((bh, d, sk), k.dtype),
+            jax.ShapeDtypeStruct((bh, d, sk), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((d, sq), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((d, block_k), jnp.float32),
+            pltpu.VMEM((d, block_k), jnp.float32),
         ],
         # dq's block is revisited along the key tiles: that axis runs in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(_fold_heads(q), _fold_heads(k), _fold_heads(v), _fold_heads(g), lse,
-      delta)
-
-    unfold = lambda x: x.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    return _unfold_transposed(dq, b), unfold(dk), unfold(dv)
+    )(q, k, v, g, lse, delta)
 
 
 def _on_tpu() -> bool:
@@ -355,23 +366,21 @@ def _on_tpu() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, causal, block_q, block_k, interpret):
-    scale = q.shape[-1] ** -0.5
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out
+    return _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    scale = q.shape[-1] ** -0.5
-    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
-    return out, (q, k, v, out, lse)
+    folded = _fold(q), _fold(k), _fold(v)
+    out, lse = _flash_fwd(*folded, causal, q.shape[-1] ** -0.5, block_q,
+                          block_k, interpret)
+    return _unfold(out, q.shape[0]), (*folded, out, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    scale = q.shape[-1] ** -0.5
-    return _flash_bwd(
-        q, k, v, out, lse, g, causal, scale, block_q, block_k, interpret
-    )
+    grads = _flash_bwd(q, k, v, out, lse, _fold(g), causal,
+                       q.shape[1] ** -0.5, block_q, block_k, interpret)
+    return tuple(_unfold(x, g.shape[0]) for x in grads)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)

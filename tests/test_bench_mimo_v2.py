@@ -49,7 +49,8 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "cache_read_pct.serve",  # PR 46
         "latent_long_decode_roofline.serve", "prefill_mfu.serve",  # PR 48
         "prefill_useful_pct.serve_rate", "ep8_expert_tokens.serve",
-        "ep8_experts_touched_pct.serve"]
+        "ep8_experts_touched_pct.serve",
+        "relayout_ms.train"]  # PR 50
     assert cells == ["mistral4_ep8_longdoc_closed32"]  # PR 48
 
 

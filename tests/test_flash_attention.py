@@ -1,8 +1,10 @@
 """The flash kernels (``ray_tpu/ops/attention.py``) in interpret mode on the
 CPU: forward and the three gradients against ``reference_attention`` over
 the tile geometries the loops meet (tiles under the diagonal, on it, and
-none above it), the backward's one kernel against the parent's two, and the
-work the loops' bounds leave (``flash_tile_work``)."""
+none above it) and at the callers' head sizes, the backward's one kernel
+against the parent's two, what a tile pair costs and how its operands lie
+(``[B*H, D, S]``: the sequence along the lanes), and the work the loops'
+bounds leave (``flash_tile_work``)."""
 
 import jax
 import jax.numpy as jnp
@@ -40,11 +42,10 @@ GEOMETRIES = {
 TOLERANCE = {jnp.float32: 2e-5, jnp.bfloat16: 6e-2}
 
 
-@pytest.mark.parametrize("dtype", list(TOLERANCE), ids=lambda d: d.__name__)
-@pytest.mark.parametrize("geometry", list(GEOMETRIES))
-def test_forward_and_gradients_match_the_reference(geometry, dtype):
-    sq, sk, bq, bk, causal = GEOMETRIES[geometry]
-    q, k, v = _qkv(sq, sk, dtype)
+def _check_against_the_reference(q, k, v, bq, bk, causal):
+    """Forward and dq, dk, dv of the kernels against the float32
+    reference's, to the operands' type's tolerance."""
+    dtype = q.dtype.type
     weight = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
 
     def flash(q, k, v):
@@ -69,6 +70,27 @@ def test_forward_and_gradients_match_the_reference(geometry, dtype):
         assert g.dtype == dtype
         np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w),
                                    rtol=tol, atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE), ids=lambda d: d.__name__)
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_forward_and_gradients_match_the_reference(geometry, dtype):
+    sq, sk, bq, bk, causal = GEOMETRIES[geometry]
+    _check_against_the_reference(*_qkv(sq, sk, dtype), bq, bk, causal)
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE), ids=lambda d: d.__name__)
+@pytest.mark.parametrize("sq,sk", [(128, 128), (128, 256)])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "no mask"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_the_callers_heads_with_the_sequence_along_the_lanes(d, causal, sq,
+                                                             sk, dtype):
+    """Heads of 64 (``models/gpt2.py``, the training cells) and of 128
+    (``models/llama.py``) through the same kernels: every operand crosses as
+    ``[B*H, D, S]``, whole ``[D, S]`` tiles for either head, tiles of 64 so
+    that both loops run several pairs and ``Sq != Sk`` is met too."""
+    _check_against_the_reference(*_qkv(sq, sk, dtype, b=1, d=d), 64, 64,
+                                 causal)
 
 
 def _parents_two_kernels(q, k, v, do, block_q, block_k):
@@ -150,13 +172,13 @@ def _subjaxprs(eqn):
 
 
 def _kernels(fn, *args):
-    """The Pallas kernels' jaxprs in ``fn``'s jaxpr, by their outputs."""
+    """The ``pallas_call`` equations in ``fn``'s jaxpr, by their outputs."""
     found = {}
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                found[len(eqn.outvars)] = eqn.params["jaxpr"]
+                found[len(eqn.outvars)] = eqn
             else:
                 for sub in _subjaxprs(eqn):
                     walk(sub)
@@ -178,6 +200,20 @@ def _per_loop(kernel, primitive):
             for eqn in kernel.eqns if eqn.primitive.name in body]
 
 
+def _products(jaxpr):
+    """The contracted axes (lhs, rhs) of every product in ``jaxpr``, its
+    sub-programs included, sorted."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (lhs, rhs), batch = eqn.params["dimension_numbers"]
+            assert batch == ((), ())
+            found.append((*lhs, *rhs))
+        for sub in _subjaxprs(eqn):
+            found += _products(sub)
+    return sorted(found)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_a_tile_pair_costs_what_the_mathematics_has(causal):
     """Forward: 2 products (k q^T, v^T p^T) and 2 exponentials (p, and the
@@ -185,7 +221,18 @@ def test_a_tile_pair_costs_what_the_mathematics_has(causal):
     the mathematics has (s, dv, dp, dk, dq) and 1 exponential, in ONE kernel
     (three outputs).  Until PR 49 dQ ran 3 + 1 and dK/dV 4 + 1 (7 and 2 a
     pair) and dK/dV transposed two [block_q, block_k] tiles a pair; now no
-    kernel transposes anything (the scores are BUILT transposed)."""
+    loop transposes anything (the scores are BUILT transposed).
+
+    How the products take operands that all come as ``[D, S]`` (PR 50),
+    (lhs axis, rhs axis) contracted: the forward's two are plain, ``(1, 0)``:
+    acc^T = v^T p^T as it lies, s^T = k q^T against K turned ONCE A HEAD
+    into VMEM (the one ``transpose`` of the kernel, under the ``cond`` of
+    the head's first query tile: none a tile pair, none a later grid cell).
+    The backward's five: dq^T = k^T ds^T plain; dv^T = dO^T p and dk^T =
+    q^T ds over the lanes of both sides, ``(1, 1)``; s^T and dp^T = v dO^T
+    over the sublanes of both, ``(0, 0)``, which leaves the turn of the
+    [D, block_k] side to the compiler: no ``transpose`` in that kernel (the
+    chip preferred it to two tiles turned a grid cell: PERF.md, PR 50)."""
     q, k, v = _qkv(64, 64)
 
     def grads(q, k, v):
@@ -195,7 +242,7 @@ def test_a_tile_pair_costs_what_the_mathematics_has(causal):
 
     kernels = _kernels(grads, q, k, v)
     assert sorted(kernels) == [2, 3]  # (out, lse); (dq, dk, dv): no other
-    forward, backward = kernels[2], kernels[3]
+    forward, backward = (kernels[n].params["jaxpr"] for n in (2, 3))
     assert _per_loop(forward, "dot_general") == [2]
     assert _per_loop(forward, "exp") == [2]
     assert _per_loop(backward, "dot_general") == [5]
@@ -203,6 +250,37 @@ def test_a_tile_pair_costs_what_the_mathematics_has(causal):
     for kernel in (forward, backward):
         assert _per_loop(kernel, "select_n") == [int(causal)]
         assert _per_loop(kernel, "transpose") == [0]
+    assert _products(forward) == [(1, 0), (1, 0)]
+    assert _products(backward) == [(0, 0), (0, 0), (1, 0), (1, 1), (1, 1)]
+    assert _count(forward, "transpose") == _count(forward, "cond") == 1
+    assert _count(backward, "transpose") == 0
+
+
+def test_every_operand_crosses_as_heads_by_depth_by_sequence():
+    """q, k, v, dO in and out, dq, dk, dv back: ``[B*H, D, S]`` each (the
+    statistics ``[B*H, 1, S]``), so that the compiled step, which keeps the
+    sequence along the lanes, hands them over and takes them back without a
+    ``copy`` (``tests/test_tpu_compile.py`` holds that on the cells' step)."""
+    b, h, d, sq, sk = 2, 2, 8, 64, 32
+    q, k, v = _qkv(sq, sk, b=b, h=h, d=d)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_q=16, block_k=16, force_pallas=True).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    calls = _kernels(grads, q, k, v)
+    shapes = lambda variables: [tuple(x.aval.shape) for x in variables]
+    query, key, row = (b * h, d, sq), (b * h, d, sk), (b * h, 1, sq)
+    assert shapes(calls[2].invars) == [query, key, key]
+    assert shapes(calls[2].outvars) == [query, row]
+    assert shapes(calls[3].invars) == [query, key, key, query, row, row]
+    assert shapes(calls[3].outvars) == [query, key, key]
+    x = jnp.arange(b * sq * h * d, dtype=jnp.float32).reshape(b, sq, h, d)
+    folded = attention._fold(x)
+    assert folded.shape == query
+    assert float(folded[1 * h + 1, 3, 5]) == float(x[1, 5, 1, 3])
+    np.testing.assert_array_equal(attention._unfold(folded, b), x)
 
 
 def _brute_force(sq, sk, bq, bk, causal):

@@ -49,9 +49,11 @@ def _qkv(shape, sharding):
     return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)] * 3
 
 
-# (B, S, H, D): GPT-2 small (``chip_smoke.py``), ``bench.py``'s, and the
-# training cells' own (``gpt2_medium`` at 32 rows a chip)
-FLASH_SHAPES = [(32, 1024, 12, 64), (4, 2048, 32, 64), (32, 1024, 16, 64)]
+# (B, S, H, D): GPT-2 small (``chip_smoke.py``), ``bench.py``'s, the
+# training cells' own (``gpt2_medium`` at 32 rows a chip), and a head of 128
+# (``models/llama.py``)
+FLASH_SHAPES = [(32, 1024, 12, 64), (4, 2048, 32, 64), (32, 1024, 16, 64),
+                (8, 2048, 32, 128)]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
@@ -75,17 +77,12 @@ def test_flash_forward_backward_compiles_to_pallas(
     assert text.count("tpu_custom_call") == 2
 
 
-def test_training_cells_step_compiles_with_its_three_kernels(
-    one_chip, as_if_on_tpu
-):
+@pytest.fixture(scope="module")
+def training_step(one_chip):
     """``gpt2_medium``'s whole optimizer step as ``benchmarks/jobs/
     train_dp.py`` builds it (``shard_map`` over the gang's mesh, AdamW,
-    state donated; one chip, 32 x 1024 tokens), compiled for the v5e: the
-    forward kernel, the forward again under the layers' remat, and the one
-    backward kernel.  The job calls a run incorrect under 3
-    ``tpu_custom_call``s ("flash attention fell back").  The kernels ask for
-    no VMEM limit of their own: that the compile passes is that they fit
-    the default scoped limit."""
+    state donated; one chip, 32 x 1024 tokens), compiled for the v5e: once
+    a module, ~15 s."""
     import importlib
     import json
 
@@ -124,11 +121,59 @@ def test_training_cells_step_compiles_with_its_three_kernels(
         updates, o = tx.update(grads, o, p)
         return optax.apply_updates(p, updates), o, loss, stop
 
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        params, opt_state, tokens, votes).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, "_on_tpu", lambda: True)
+        return jax.jit(step, donate_argnums=(0, 1)).lower(
+            params, opt_state, tokens, votes).compile()
+
+
+def test_training_cells_step_compiles_with_its_three_kernels(training_step):
+    """The forward kernel, the forward again under the layers' remat, and
+    the one backward kernel.  The job calls a run incorrect under 3
+    ``tpu_custom_call``s ("flash attention fell back").  The kernels ask for
+    no VMEM limit of their own: that the compile passes is that they fit
+    the default scoped limit."""
+    assert training_step.as_text().count("tpu_custom_call") >= 3
     # 6.76 GB of temporaries beside 2.13 GB of state (the cell's file)
-    assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9
+    assert training_step.memory_analysis().temp_size_in_bytes < 7.5e9
+
+
+def test_training_cells_step_turns_no_kernel_operand(training_step):
+    """The kernels take q, k, v, dO and give out, dq, dk, dv as
+    ``[B*H, D, S]``, which is where the compiled step keeps them (the q/k/v
+    projection leaves ``[B, 3, H, D, S]``: the sequence along the lanes), so
+    nowhere in the step, the layers' two loop bodies included, does a
+    ``copy`` or a ``transpose`` produce an array of a kernel operand's size,
+    and no ``slice_bitcast_fusion``
+    splits the projection into arrays that a copy then turns.  Until PR 50
+    the kernels asked for ``[B*H, S, D]`` and a layer ran nine such copies
+    and two such fusions around its three kernels (108 ms of an 801 ms step
+    on the chip).  What is left is today's truth, pinned: the three fusions
+    that lay dq, dk and dv into d(qkv) (``copy_bitcast_fusion``), and the
+    split of the projection's result as a plain ``fusion`` a body (the next
+    issue's: a packed entry that indexes the result where it lies).  An
+    array is a kernel operand's if it has its size and keeps the head's 64
+    as an axis: the residual stream's own ``bf16[32,1024,1024]`` copies are
+    not the kernels' and not counted."""
+    size = 32 * 16 * 1024 * 64
+    made = {}
+    for name, result, op, rest in named_instructions(training_step.as_text()):
+        if op == "custom-call":
+            op = re.search(r'custom_call_target="(\w+)"', rest).group(1)
+        if op in PREFETCHES or op.endswith("-start"):
+            continue
+        shapes = [tuple(int(d) for d in dims.split(",") if d not in ("", "1"))
+                  for dims in re.findall(r"bf16\[([\d,]*)\]", result)]
+        if any(math.prod(dims) == size and 64 in dims for dims in shapes):
+            kind = re.sub(r"[.\d]+$", "", name) if op == "fusion" else op
+            made[kind] = made.get(kind, 0) + 1
+    assert "copy" not in made and "transpose" not in made, made
+    assert "slice_bitcast_fusion" not in made, made
+    assert made.pop("tpu_custom_call") == 3  # the kernels themselves
+    assert made.pop("copy_bitcast_fusion") == 3  # dq, dk, dv into d(qkv)
+    # the split of [B, 3, H, D, S], forward and again under remat; d(out)
+    # of the output projection
+    assert made == {"fusion": 3}
 
 
 def _decode_args(L, B, H, Hkv, T, D, sharding):
@@ -461,10 +506,10 @@ PREFETCHES = {"copy-done", "slice-done", "ConcatBitcast"}
 HLO_DTYPES = {"bfloat16": "bf16", "float32": "f32"}
 
 
-def instructions(text, entry_only=False):
-    """(result, op, the rest of the line) of every instruction of a compiled
-    program's text that is no view, in its ENTRY computation or in every
-    computation that is not a fusion's own (loops' bodies and branches
+def named_instructions(text, entry_only=False):
+    """(name, result, op, the rest of the line) of every instruction of a
+    compiled program's text that is no view, in its ENTRY computation or in
+    every computation that is not a fusion's own (loops' bodies and branches
     included)."""
     fused = set(re.findall(r"calls=%([\w.-]+)", text))
     for computation in re.finditer(
@@ -472,11 +517,17 @@ def instructions(text, entry_only=False):
         is_entry, name, body = computation.groups()
         if (entry_only and not is_entry) or name in fused:
             continue
-        for result, op, rest in re.findall(
-                r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\((.*)$",
+        for name, result, op, rest in re.findall(
+                r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)$",
                 body, re.M):
             if op not in VIEWS:
-                yield result, op, rest
+                yield name, result, op, rest
+
+
+def instructions(text, entry_only=False):
+    """``named_instructions`` without the names."""
+    for _name, *rest in named_instructions(text, entry_only):
+        yield tuple(rest)
 
 
 def weight_parts(params):
