@@ -37,7 +37,7 @@ class LocalXlaGroup:
         # ``slice_size``: devices per ICI slice.  Default: every device in
         # one slice (pure-ICI topology).  A multi-slice local group (e.g.
         # megascale hosts, or a CPU mesh standing in for a 2-slice DCN
-        # fabric in tests/bench) unlocks the two-level algorithms.
+        # fabric in tests) unlocks the two-level algorithms.
         self.topology = Topology(self.world_size,
                                  slice_size or self.world_size)
         self.mesh = Mesh(np.array(self.devices), ("world",))

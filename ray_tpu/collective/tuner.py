@@ -410,5 +410,5 @@ def select_for_group(group, op: str, per_rank_nbytes: int,
 
 
 def reset_tuner() -> None:
-    """Drop all buckets (tests / bench stages)."""
+    """Drop all buckets (tests)."""
     get_tuner().reset()

@@ -324,7 +324,8 @@ _KNOBS: Dict[str, tuple] = {
         "Runtime-internal telemetry: per-task phase timings, collective "
         "op/bytes/bandwidth capture, object-store and backpressure "
         "counters (ray_tpu_* metrics + timeline phase rows).  Guarded at "
-        "<5% round-trip overhead by `bench.py obs_overhead`",
+        "<5% round-trip overhead by tests/test_flight_recorder.py "
+        "(TestObsOverheadEnvelope, a slow test)",
     ),
     "enable_obs_aggregator": (
         bool, True,

@@ -123,14 +123,6 @@ def model_family(cfg: _Any) -> ModelFamily:
         f"no registered model family for config type {type(cfg).__name__}"
     )
 from .mlp import mlp_apply, mlp_init  # noqa: F401
-from .moe import (  # noqa: F401
-    MoEConfig,
-    moe_apply,
-    moe_ffn,
-    moe_init,
-    moe_loss,
-    moe_param_axes,
-)
 from .resnet import (  # noqa: F401
     ResNetConfig,
     resnet_apply,

@@ -6,15 +6,14 @@ the model runner inside the vLLM engine the reference wraps, ray
 
   - the KV cache is a pair of layer-stacked **head-major** arrays
     ``[L, B, H, T_max, D]`` living in HBM across steps — this layout means
-    neither prefill writes, decode reads, nor the decode-attention kernel
-    ever transpose the cache on the hot path;
+    neither prefill writes nor decode reads ever transpose the cache on
+    the hot path;
   - cache writes are **deferred**: each layer's current-token k/v is merged
     into attention analytically (``k_self``/``v_self`` in
     ``ops/decode_attention.py``) and all 2L writes collapse into one
     ``write_token_to_cache`` a cache array at the end of the step, which
     updates the one tile of rows that holds each slot's position, in the
-    donated cache (a write a layer, as a scatter, cost ~1 ms each on the
-    v5e: round-1 design 36 ms/step at L=12, B=32, T=1024; deferred, 20.5);
+    donated cache;
   - per-slot positions make the batch *ragged*: each sequence attends only
     to its own ``[0, pos]`` prefix;
   - the layer loop is a Python loop (static layer indices; L compile-time
@@ -23,7 +22,6 @@ the model runner inside the vLLM engine the reference wraps, ray
 
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -96,19 +94,13 @@ def gpt2_prefill(
 
 
 def gpt2_decode_step(
-    params, tokens, pos, cache, cfg: GPT2Config, *, kernel: bool = False
+    params, tokens, pos, cache, cfg: GPT2Config
 ) -> Tuple[jnp.ndarray, dict]:
     """One generation step for a ragged batch.
 
     tokens: [B] the most recent token per slot; pos: [B] its position.
     Writes k/v at ``pos`` and attends each slot to its own ``[0, pos]``.
     Returns (logits [B, V], updated cache).
-
-    ``kernel=False`` (default) uses the XLA decode attention: on the
-    bandwidth-limited v5e-lite part the fused einsum path measures 20.5 ms
-    vs 29 ms for the Pallas kernel at B=32/T=1024 (the kernel's per-program
-    full-T block copies can't ride the ~40 GB/s effective HBM).  The kernel
-    remains the right call on full-bandwidth parts / long caches.
     """
     from ..ops.decode_attention import (decode_attention,
                                         write_token_to_cache)
@@ -127,11 +119,10 @@ def gpt2_decode_step(
         new_ks.append(k.astype(ck.dtype))
         new_vs.append(v.astype(cv.dtype))
         # Deferred-scatter protocol: the cache holds [0, pos-1]; the current
-        # token's k/v are merged in-kernel, and written once below for all
-        # layers.
+        # token's k/v are one more column of the softmax, and written once
+        # below for all layers.
         o = decode_attention(
-            q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1],
-            kernel=kernel,
+            q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1]
         )  # [B, H, D]
         x = x + (
             jnp.einsum("bhd,hde->be", o.astype(y.dtype), layer["wo"])
@@ -148,33 +139,6 @@ def gpt2_decode_step(
     x = _layernorm(x, params["lnf_g"], params["lnf_b"])
     logits = jnp.einsum("be,ve->bv", x, params["wte"])
     return logits.astype(jnp.float32), {"k": ck, "v": cv}
-
-
-def gpt2_decode_multi(
-    params, tokens, pos, cache, cfg: GPT2Config, n_steps: int,
-    *, kernel: bool = False,
-):
-    """Multi-step greedy decode: ``n_steps`` tokens per dispatch via
-    ``lax.scan`` with the argmax fused in-graph (vLLM-style multi-step
-    scheduling).  On a remote-dispatch backend this amortizes the per-call
-    launch latency across n_steps tokens — the single-step loop pays ~2
-    host round trips per token.
-
-    Continuous-batching engines call this between admission points: new
-    requests join slots only at chunk boundaries.  Returns
-    (tokens_out [n_steps, B], next_tokens [B], next_pos [B], cache).
-    """
-
-    def body(carry, _):
-        toks, p, c = carry
-        logits, c = gpt2_decode_step(params, toks, p, c, cfg, kernel=kernel)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (nxt, p + 1, c), nxt
-
-    (nxt, next_pos, cache), out = jax.lax.scan(
-        body, (tokens, pos, cache), None, length=n_steps
-    )
-    return out, nxt, next_pos, cache
 
 
 def sample_logits(logits, key, temperature, top_k: int = 0, top_p: float = 1.0):

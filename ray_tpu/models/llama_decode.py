@@ -2,9 +2,9 @@
 
 Same design as ``gpt2_decode.py`` (head-major stacked cache
 ``[L, B, Hkv, T, D]``, one deferred in-place write of the step's token a
-cache array, optional Pallas decode-attention kernel) with
+cache array) with
 the Llama specifics: RMSNorm, rotary positions, SwiGLU, and **grouped-query
-attention** — the cache holds only the Hkv kv-heads and the decode kernel
+attention** — the cache holds only the Hkv kv-heads and decode attention
 attends each group of H/Hkv query heads against its shared kv-head in one
 score tile (the GQA memory win is the whole point of serving Llama-style
 models: cache bytes shrink by H/Hkv).
@@ -80,7 +80,7 @@ def llama_prefill(
 
 
 def llama_decode_step(
-    params, tokens, pos, cache, cfg: LlamaConfig, *, kernel: bool = False
+    params, tokens, pos, cache, cfg: LlamaConfig
 ) -> Tuple[jnp.ndarray, dict]:
     """tokens: [B]; pos: [B] position of each token.  Ragged decode with
     per-slot rotary positions."""
@@ -104,10 +104,9 @@ def llama_decode_step(
         new_ks.append(k.astype(ck.dtype))
         new_vs.append(v.astype(cv.dtype))
         # Deferred-scatter protocol (see gpt2_decode.py): cache holds
-        # [0, pos-1]; current k/v merged in-kernel, one write below.
+        # [0, pos-1]; current k/v a column of the softmax, one write below.
         o = decode_attention(
-            q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1],
-            kernel=kernel,
+            q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1]
         )  # [B, H, D]
         x = x + jnp.einsum(
             "bhd,hde->be", o.astype(y.dtype), layer["wo"]

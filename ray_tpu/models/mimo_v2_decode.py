@@ -101,7 +101,7 @@ def mimo_v2_decode_step(
             "window": cfg.window, "sink": att["sink"][i]}
         return decode_attention(
             q, cache[k_leaf], cache[v_leaf], pos, i, k_self=new[k_leaf][-1],
-            v_self=new[v_leaf][-1], kernel=False, **ring)
+            v_self=new[v_leaf][-1], **ring)
 
     x, counts = run_layers(params, x, pos > 0, attend, cfg)
     for kind, at in (("F", pos), ("W", pos % cfg.window)):

@@ -127,7 +127,7 @@ def nemotron_h_decode_step(
         new_k.append(k.astype(cache["k"].dtype))
         new_v.append(v.astype(cache["v"].dtype))
         o = decode_attention(q, cache["k"], cache["v"], pos, i,
-                             k_self=new_k[-1], v_self=new_v[-1], kernel=False)
+                             k_self=new_k[-1], v_self=new_v[-1])
         return matmul("bhd,hde->be", o.astype(y.dtype), blocks["attn"]["wo"][i])
 
     x, counts = run_layers(params, x, pos > 0, mamba, attend, cfg)

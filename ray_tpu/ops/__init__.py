@@ -1,5 +1,2 @@
 from .attention import flash_attention, reference_attention  # noqa: F401
-from .decode_attention import (  # noqa: F401
-    decode_attention,
-    reference_decode_attention,
-)
+from .decode_attention import decode_attention  # noqa: F401

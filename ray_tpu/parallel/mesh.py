@@ -8,7 +8,7 @@ Axes follow the standard recipe (scaling-book / maxtext conventions):
   - ``model``: tensor parallelism (matmul-sharded, psum on contraction)
   - ``seq``:   sequence/context parallelism (ring attention / Ulysses)
   - ``stage``: pipeline parallelism across slices
-  - ``expert``: expert parallelism (MoE dispatch via all_to_all)
+  - ``expert``: expert parallelism (the expert axis of per-expert params)
 
 ``mesh_utils.create_device_mesh`` lays axes onto the physical ICI topology so
 the innermost (most chatty) axes ride the fastest links.

@@ -15,7 +15,7 @@ Run standalone (JSON lines on stdout):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m ray_tpu.parallel.scaling_bench
 
-Or from bench.py, which re-emits the metrics in the driver's format.
+``__graft_entry__.py``'s ``dryrun_multichip`` prints the same curve.
 """
 
 from __future__ import annotations
@@ -89,8 +89,8 @@ def run_scaling_curve(
 ) -> List[Dict]:
     """Weak-scaling partition retention across mesh sizes (FSDP axis).
 
-    METHODOLOGY (one definition, emitted identically by bench.py and
-    ``dryrun_multichip``): per-device batch is FIXED at
+    METHODOLOGY (one definition, emitted identically by this module's
+    ``main`` and ``dryrun_multichip``): per-device batch is FIXED at
     ``batch_per_device`` (weak scaling).  For each mesh size n the same
     global batch (n * batch_per_device) also runs UNPARTITIONED on one
     device — identical total compute, zero partitioning — and

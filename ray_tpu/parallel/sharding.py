@@ -32,12 +32,8 @@ DEFAULT_RULES: LogicalRules = {
     "vocab": "model",
     "stage": "stage",
     "norm": None,
-    # MoE: the expert axis of per-expert params and of dispatched token
-    # buffers shards over the expert mesh axis; XLA lowers the
-    # dispatch/combine einsums to all_to_all over ICI.
+    # the expert axis of per-expert params shards over the expert mesh axis
     "expert": "expert",
-    "capacity": None,
-    "expert_mlp": "model",
 }
 
 

@@ -7,9 +7,9 @@ whole updates, and ``pmap`` replicates the loop across devices with
 gradients ``pmean``-ed over the device axis.  Parameters, env states,
 and trajectories NEVER leave the chip; Python only triggers the next
 compiled chunk.  Against the host-loop IMPALA (Python env stepping, one
-RPC round per rollout) this is the difference between thousands and
-millions of env steps per second — ``bench.py rl`` measures the ratio
-in one interleaved window.
+RPC round per rollout) this is the design's difference between
+thousands and millions of env steps per second (the ratio is not
+measured on a chip).
 
 The loss is IMPALA's V-trace (``rllib.impala.make_vtrace_loss``) vmapped
 over the env axis; on-policy the importance ratios are exactly 1, so it

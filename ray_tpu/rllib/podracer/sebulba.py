@@ -48,8 +48,7 @@ logger = logging.getLogger(__name__)
 def evaluate_policy_numpy(params, env_maker, episodes: int = 6,
                           seed: int = 0, greedy: bool = True) -> float:
     """Mean episode return of ``params`` over fresh env copies (host
-    rollout, no cluster) — the seeded eval both learning tests and the
-    bench use."""
+    rollout, no cluster) — the seeded eval the learning tests use."""
     from ..ppo import _np_policy_forward
 
     returns: List[float] = []

@@ -1249,11 +1249,9 @@ def reference_run(
 ):
     """Sequential (non-pipelined) execution of the SAME chunked model
     with the SAME microbatch gradient accumulation — the 1-stage
-    self-baseline for loss-parity checks and bench `vs` ratios.
+    self-baseline for loss-parity checks.
 
-    Returns (per-step mean losses, final [chunk state dicts]); per-step
-    wall times are exposed on the returned list as ``.step_walls`` via
-    :class:`_LossList` (the bench's steady-state timing hook).
+    Returns (per-step mean losses, final [chunk state dicts]).
     """
     import numpy as np
 
@@ -1262,9 +1260,8 @@ def reference_run(
                rng_seed, learning_rate)
         for v in range(total_virtual)
     ]
-    losses_per_step = _LossList()
+    losses_per_step = []
     for step in range(num_steps):
-        t_step = time.perf_counter()
         inputs, targets = data_per_step(step)
         mb_inputs = np.split(np.asarray(inputs), num_microbatches)
         mb_targets = np.split(np.asarray(targets), num_microbatches)
@@ -1284,13 +1281,4 @@ def reference_run(
         for chunk in chunks:
             chunk.apply_grads(num_microbatches)
         losses_per_step.append(sum(mb_losses) / len(mb_losses))
-        losses_per_step.step_walls.append(time.perf_counter() - t_step)
     return losses_per_step, [c.state() for c in chunks]
-
-
-class _LossList(list):
-    """Per-step losses with per-step wall times riding along."""
-
-    def __init__(self, *args):
-        super().__init__(*args)
-        self.step_walls: List[float] = []

@@ -10,7 +10,8 @@ phases additionally ride the task-event profile channel so they render as
 rows in the Chrome-trace ``/api/timeline`` dump.
 
 What gets recorded (all gated on ``GlobalConfig.enable_flight_recorder``;
-``bench.py obs_overhead`` guards the cost at <5% of the task round trip):
+``tests/test_flight_recorder.py`` ``TestObsOverheadEnvelope``, a slow test,
+guards the cost at <5% of the task round trip):
 
   - per-task phase timings on the executing worker — queue wait (push
     arrival -> execution start, including function fetch and pipeline

@@ -188,18 +188,6 @@ def test_flash_off_tpu_unforced_is_the_reference():
     )
 
 
-def test_bench_model_suite_refuses_to_measure_off_the_tpu():
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    with pytest.raises(RuntimeError, match="measures a TPU"):
-        bench.run_model_suite()
-    with pytest.raises(KeyError, match="no peak FLOP/s on record"):
-        bench.peak_bf16_flops()  # device_kind "cpu"
-
-
 # ------------------------------------------------------------ chip_smoke.py
 def _smoke(args, cwd=REPO, script=None):
     return subprocess.run(

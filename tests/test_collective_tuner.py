@@ -1,6 +1,6 @@
 """Online collective autotuner: bucketing, explore->commit->decaying
 re-probe, member sync at commit points, observability (stats / cluster
-merge / metrics), bench smoke, and the train-layer opt-in threading."""
+merge / metrics), and the train-layer opt-in threading."""
 
 import numpy as np
 import pytest
@@ -238,35 +238,6 @@ class TestObservability:
             ) >= 4
         finally:
             col.destroy_collective_group("clu-t")
-
-
-# ------------------------------------------------------------ bench smoke
-class TestBenchSmoke:
-    def test_quick_smoke_under_cpu(self, capsys):
-        """The `bench.py collective --quick` smoke (the stage module runs
-        under JAX_PLATFORMS=cpu; in-process here — the conftest already
-        pins the cpu platform, and skipping the subprocess saves a cold
-        jax import in tier-1): every stage must emit its record."""
-        import json
-
-        from ray_tpu.collective import bench_collective
-
-        bench_collective.main(quick=True)
-        out = capsys.readouterr().out
-        metrics_seen = set()
-        for line in out.splitlines():
-            try:
-                rec = json.loads(line)
-            except ValueError:
-                continue
-            if "collective" in rec:
-                metrics_seen.add(rec["collective"]["metric"])
-        assert {
-            "collective_allreduce_algo_ab",
-            "collective_allreduce_bytes_per_s",
-            "collective_allreduce_quantized_bytes_per_s",
-            "collective_group_allreduce_e2e_bytes_per_s",
-        } <= metrics_seen
 
 
 # ----------------------------------------------------- train threading

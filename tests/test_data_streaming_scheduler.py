@@ -31,6 +31,47 @@ def _wait_until(pred, timeout=5.0):
     return pred()
 
 
+def _data_straggler_walls(rd, n_blocks=10, straggler_s=1.8, per_block_s=0.18):
+    """Ordered-vs-unordered wall time on a straggler-skewed pipeline.
+
+    One slow map task at the head of the stream feeds a consumer that
+    does fixed work per block (a simulated train step — ingest on the
+    step's critical path, the JaxTrainer scenario).  Ordered emission
+    parks the consumer until the straggler lands (wall ~= straggler +
+    n*per_block); unordered keeps it fed (wall ~= max(straggler,
+    n*per_block) + per_block).  Returns both walls and checks the result
+    SETS are identical — the out-of-order win must never change the
+    answer.
+    """
+
+    def skew_map(x):
+        time.sleep(straggler_s if x == 0 else 0.01)
+        return x
+
+    def run(preserve_order):
+        ds = (
+            rd.from_items(list(range(n_blocks)), parallelism=n_blocks)
+            .map(skew_map)
+            .execution_options(preserve_order=preserve_order)
+        )
+        got = []
+        t0 = time.perf_counter()
+        for block in ds.iter_blocks():
+            time.sleep(per_block_s)  # simulated per-batch train step
+            got.extend(block)
+        return time.perf_counter() - t0, sorted(got)
+
+    walls = {}
+    for label, preserve in (("unordered", False), ("ordered", True)):
+        samples = []
+        for _ in range(2):
+            dt, got = run(preserve)
+            assert got == list(range(n_blocks)), got
+            samples.append(dt)
+        walls[label] = min(samples)
+    return walls
+
+
 class TestOutOfOrder:
     def test_unordered_set_completeness_under_skew(self, cluster):
         """Injected per-task latency skew: unordered emission must still
@@ -72,12 +113,10 @@ class TestOutOfOrder:
 
     @pytest.mark.slow
     def test_unordered_beats_ordered_on_straggler_skew(self, cluster):
-        """The recorded bench claim: unordered >= 1.5x faster wall time
-        than ordered on the straggler-skew stage, identical result sets
-        (set equality is asserted inside the helper)."""
-        import bench
-
-        walls = bench._data_straggler_walls(rdata)
+        """Unordered >= 1.5x faster wall time than ordered on the
+        straggler-skew pipeline, identical result sets (set equality is
+        asserted inside the helper)."""
+        walls = _data_straggler_walls(rdata)
         speedup = walls["ordered"] / walls["unordered"]
         assert speedup >= 1.5, walls
 
@@ -388,8 +427,8 @@ class TestStatsAndSmoke:
         assert st.wall_s < consume_wall * 0.8, (st.wall_s, consume_wall)
 
     def test_streaming_rows_smoke(self, cluster):
-        """Tier-1 smoke of the bench.py data_streaming_rows_per_s
-        machinery at small scale."""
+        """Tier-1 smoke of a map / filter / take_all stream at small
+        scale."""
         n = 20_000
         t0 = time.perf_counter()
         out = (

@@ -15,8 +15,8 @@ import pytest
 from ray_tpu.models import longcat_decode
 from ray_tpu.models.longcat import LongcatConfig, matmul
 from ray_tpu.ops.decode_attention import (decode_attention, extent_step,
-                                          live_extent,
-                                          reference_decode_attention)
+                                          live_extent)
+from test_llama_kernels import dense_decode_attention
 from test_mimo_v2 import (OLDER_FAMILIES, decode_attention_before,
                           decode_operands)
 
@@ -62,31 +62,13 @@ def attend(shape, pos, with_self, seed=0):
         own["sink"] = jnp.asarray(
             np.random.default_rng(2).normal(size=h), jnp.float32)
     return decode_attention(q, kc, vc, jnp.asarray(pos, jnp.int32), 1,
-                            kernel=False, **own), (q, kc, vc, own)
+                            **own), (q, kc, vc, own)
 
 
 def one_shot(q, kc, vc, pos, own):
     """The whole cache in one softmax, in float64."""
-    b, h, _ = q.shape
-    hkv = kc.shape[2]
-    g = h // hkv
-    f = lambda x: np.asarray(x, np.float64)
-    out = np.zeros((b, h, vc.shape[-1]))
-    for row in range(b):
-        for head in range(h):
-            kv = head // g
-            n = pos[row] if "k_self" in own else pos[row] + 1
-            keys, vals = f(kc[1, row, kv, :n]), f(vc[1, row, kv, :n])
-            if "k_self" in own:
-                keys = np.vstack([keys, f(own["k_self"][row, kv])[None]])
-                vals = np.vstack([vals, f(own["v_self"][row, kv])[None]])
-            scores = keys @ f(q[row, head]) / np.sqrt(q.shape[-1])
-            top = scores.max()
-            e = np.exp(scores - top)
-            sink = (np.exp(float(own["sink"][head]) - top)
-                    if "sink" in own else 0.0)
-            out[row, head] = (e / (e.sum() + sink)) @ vals
-    return out
+    return dense_decode_attention(q, kc, vc, pos, 1, own.get("k_self"),
+                                  own.get("v_self"), own.get("sink"))
 
 
 # pos 0; an extent's last position and the next one's first, in the form
@@ -146,12 +128,11 @@ def test_nothing_beyond_the_live_extent_is_read(with_self):
     longest = reach if with_self else reach - 1
     poisoned = vc.at[:, :, :, reach:].set(jnp.nan)
     pos = jnp.asarray([longest] + [9] * (b - 1), jnp.int32)
-    clean = decode_attention(q, kc, vc, pos, 1, kernel=False, **own)
-    got = decode_attention(q, kc, poisoned, pos, 1, kernel=False, **own)
+    clean = decode_attention(q, kc, vc, pos, 1, **own)
+    got = decode_attention(q, kc, poisoned, pos, 1, **own)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(clean, np.float32))
-    beyond = decode_attention(q, kc, poisoned, pos.at[0].add(1), 1,
-                              kernel=False, **own)
+    beyond = decode_attention(q, kc, poisoned, pos.at[0].add(1), 1, **own)
     assert not np.isfinite(np.asarray(beyond, np.float32)).all()
 
 
@@ -165,8 +146,8 @@ def test_a_ring_and_a_cache_of_one_extent_take_the_unbounded_path():
     holds that path to PR 44's bits and operations)."""
     pos = jnp.asarray([5, 1900, 17], jnp.int32)
     q, kc, vc, ks, vs = decode_operands(3, 4, 2, T, 16, 16, "float32")
-    call = lambda **kw: lambda q, kc, vc, pos: reference_decode_attention(
-        q, kc, vc, pos, 1, ks, vs, **kw)
+    call = lambda **kw: lambda q, kc, vc, pos: decode_attention(
+        q, kc, vc, pos, 1, k_self=ks, v_self=vs, **kw)
     assert loops(call(), q, kc, vc, pos) == 1
     assert loops(call(window=T), q, kc, vc, pos) == 0
     short = decode_operands(3, 4, 2, 128, 16, 16, "float32")[:3]

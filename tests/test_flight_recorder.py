@@ -367,14 +367,56 @@ class TestFinalFlush:
 
 
 # ------------------------------------------------------- overhead envelope
+def measure_obs_overhead(n_calls=300, trials=3, n_warmup=30):
+    """Task round-trip cost with the flight recorder ON vs OFF.
+
+    Two fresh clusters (same shape) so the OFF run carries zero residue of
+    the ON run's instrumentation; best-of-``trials`` per config because
+    single-shot throughput on a shared 1-core box swings with scheduler
+    noise.  Returns per-call seconds for each config and the overhead
+    fraction.  The <5% guard is the acceptance bar for all flight-recorder
+    instrumentation on the hot path."""
+
+    def per_call_s(flight_recorder_on: bool) -> float:
+        ray_tpu.init(
+            num_cpus=1,
+            _system_config={
+                "enable_flight_recorder": flight_recorder_on,
+                "prestart_workers": 2,
+            },
+        )
+        try:
+            @ray_tpu.remote
+            def f():
+                return b"ok"
+
+            for _ in range(n_warmup):
+                ray_tpu.get(f.remote(), timeout=60)
+            best = float("inf")
+            for _ in range(trials):
+                t0 = time.perf_counter()
+                for _ in range(n_calls):
+                    ray_tpu.get(f.remote(), timeout=60)
+                best = min(best, (time.perf_counter() - t0) / n_calls)
+            return best
+        finally:
+            ray_tpu.shutdown()
+
+    t_on = per_call_s(True)
+    t_off = per_call_s(False)
+    return {
+        "per_call_on_s": t_on,
+        "per_call_off_s": t_off,
+        "overhead_fraction": max(0.0, t_on / t_off - 1.0),
+    }
+
+
 @pytest.mark.slow
 class TestObsOverheadEnvelope:
     def test_overhead_under_five_percent(self):
-        import bench
-
         best = float("inf")
         for _ in range(3):  # shared-box noise: keep the best measurement
-            res = bench.measure_obs_overhead(n_calls=200, trials=3)
+            res = measure_obs_overhead(n_calls=200, trials=3)
             best = min(best, res["overhead_fraction"])
             if best < 0.05:
                 break

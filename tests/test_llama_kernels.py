@@ -1,5 +1,5 @@
-"""Llama model family + decode-attention kernel tests (CPU via pallas
-interpret mode, following tests/test_models.py conventions)."""
+"""Llama model family + decode attention against a dense float64 oracle
+(CPU, following tests/test_models.py conventions)."""
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +14,7 @@ from ray_tpu.models.llama import (
     llama_param_axes,
     rope,
 )
-from ray_tpu.ops.decode_attention import (
-    decode_attention,
-    reference_decode_attention,
-)
+from ray_tpu.ops.decode_attention import decode_attention, extent_step
 
 
 def _cfg(**kw):
@@ -112,38 +109,105 @@ class TestLlama:
         assert all(np.isfinite(x).all() for x in jax.tree.leaves(grads))
 
 
+def dense_decode_attention(q, k, v, pos, layer, ks=None, vs=None, sink=None):
+    """The oracle: each row's softmax over its cache prefix in float64,
+    ``[0, pos)`` plus the token itself where its k/v ride beside the cache,
+    ``[0, pos]`` where they are written in it.  Head ``h`` reads kv head
+    ``h // (H // Hkv)``; ``sink`` [H] is one more logit a head whose weight
+    counts in the sum and nothing else.  -> [B, H, Dv] float64."""
+    f = lambda x: None if x is None else np.asarray(x, np.float64)
+    q, k, v, ks, vs = f(q), f(k)[layer], f(v)[layer], f(ks), f(vs)
+    (b, h, d), hkv = q.shape, k.shape[1]
+    out = np.zeros((b, h, v.shape[-1]))
+    for row in range(b):
+        n = int(pos[row]) + (ks is None)
+        for head in range(h):
+            kv = head // (h // hkv)
+            keys, vals = k[row, kv, :n], v[row, kv, :n]
+            if ks is not None:
+                keys = np.vstack([keys, ks[row, kv]])
+                vals = np.vstack([vals, vs[row, kv]])
+            scores = keys @ q[row, head] / np.sqrt(d)
+            p = np.exp(scores - scores.max())
+            drop = (0.0 if sink is None
+                    else np.exp(float(sink[head]) - scores.max()))
+            out[row, head] = (p / (p.sum() + drop)) @ vals
+    return out
+
+
+# rounding of a result of magnitude ~1, as tests/test_decode_live_extent.py
+ORACLE_TOL = {"float32": 1e-5, "bfloat16": 2e-2}
+
+# T=1536 is three extents of 512: the batch's longest context decides how
+# many of them a step reads (one, two, three); the other rows are short
+_THREE = dict(hkv=2, t=1536)
+_FIRST, _SECOND, _THIRD = [400, 3, 17], [3, 1000, 17], [3, 17, 1535]
+# (id, shape of ``_data``, pos, the token beside the cache (what the decode
+# steps do) or written in it)
+ORACLE_CASES = [
+    ("heads-8:1-self", dict(h=8, hkv=1), [5, 31, 63], True),
+    ("heads-8:1-written", dict(h=8, hkv=1), [5, 31, 63], False),
+    ("heads-8:1-bf16-values-wider-1536-third-self",
+     dict(h=8, hkv=1, t=1536, dv=24, dtype=jnp.bfloat16), _THIRD, True),
+    ("bf16-heads-4:2-written", dict(hkv=2, dtype=jnp.bfloat16),
+     [5, 31, 63], False),
+    ("values-narrower-self", dict(dv=8), [0, 31, 63], True),
+    ("values-wider-written", dict(hkv=2, dv=24), [5, 31, 62], False),
+    ("1536-first-self", _THREE, _FIRST, True),
+    ("1536-first-written", _THREE, _FIRST, False),
+    ("1536-second-self", _THREE, _SECOND, True),
+    ("1536-second-written", _THREE, _SECOND, False),
+    ("1536-third-self", _THREE, _THIRD, True),
+    ("1536-third-written", _THREE, _THIRD, False),
+]
+
+
 class TestDecodeAttention:
-    def _data(self, b=3, t=64, h=4, hkv=None, d=16, layers=2,
+    def _data(self, b=3, t=64, h=4, hkv=None, d=16, dv=None, layers=2,
               dtype=jnp.float32):
         hkv = hkv if hkv is not None else h
+        dv = dv if dv is not None else d
         keys = jax.random.split(jax.random.PRNGKey(0), 6)
         q = jax.random.normal(keys[0], (b, h, d), dtype)
         k = jax.random.normal(keys[1], (layers, b, hkv, t, d), dtype)
-        v = jax.random.normal(keys[2], (layers, b, hkv, t, d), dtype)
+        v = jax.random.normal(keys[2], (layers, b, hkv, t, dv), dtype)
         ks = jax.random.normal(keys[3], (b, hkv, d), dtype)
-        vs = jax.random.normal(keys[4], (b, hkv, d), dtype)
+        vs = jax.random.normal(keys[4], (b, hkv, dv), dtype)
         pos = jnp.array([5, 31, 63], jnp.int32)[:b]
         return q, k, v, ks, vs, pos
 
-    def test_kernel_matches_reference(self):
+    def test_matches_the_dense_oracle(self):
         q, k, v, ks, vs, pos = self._data()
         for layer in (0, 1):
-            ref = reference_decode_attention(q, k, v, pos, layer, ks, vs)
-            out = decode_attention(
-                q, k, v, pos, layer, k_self=ks, v_self=vs, block_t=16,
-                kernel=True, interpret=True,
-            )
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+            ref = dense_decode_attention(q, k, v, pos, layer, ks, vs)
+            out = decode_attention(q, k, v, pos, layer, k_self=ks, v_self=vs)
+            np.testing.assert_allclose(np.asarray(out), ref,
                                        atol=1e-5, rtol=1e-5)
+
+    @pytest.mark.parametrize("shape,pos,with_self",
+                             [case[1:] for case in ORACLE_CASES],
+                             ids=[case[0] for case in ORACLE_CASES])
+    def test_against_the_dense_oracle(self, shape, pos, with_self):
+        """What the older cases leave out: eight query heads on one kv head,
+        bfloat16 with the token written, values of another width than the
+        keys, and a cache of three extents read up to its first, second and
+        third block (``attend_live_blocks``: 1536 is no power of two, so no
+        other test's cache has an odd number of blocks)."""
+        q, k, v, ks, vs, _ = self._data(**shape)
+        own = dict(k_self=ks, v_self=vs) if with_self else {}
+        out = decode_attention(q, k, v, jnp.asarray(pos, jnp.int32), 1, **own)
+        assert out.shape == (3, q.shape[1], v.shape[-1])
+        assert out.dtype == v.dtype
+        np.testing.assert_allclose(
+            np.asarray(out, np.float64),
+            dense_decode_attention(q, k, v, pos, 1, *own.values()),
+            atol=ORACLE_TOL[jnp.dtype(v.dtype).name])
 
     def test_gqa_grouped_heads(self):
         q, k, v, ks, vs, pos = self._data(h=4, hkv=2)
-        ref = reference_decode_attention(q, k, v, pos, 0, ks, vs)
-        out = decode_attention(
-            q, k, v, pos, 0, k_self=ks, v_self=vs, block_t=16, kernel=True,
-            interpret=True,
-        )
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+        ref = dense_decode_attention(q, k, v, pos, 0, ks, vs)
+        out = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs)
+        np.testing.assert_allclose(np.asarray(out), ref,
                                    atol=1e-5, rtol=1e-5)
 
     def test_self_vs_prewritten_cache_agree(self):
@@ -153,9 +217,12 @@ class TestDecodeAttention:
         bidx = jnp.arange(3)
         k_written = k.at[0, bidx, :, pos].set(ks)
         v_written = v.at[0, bidx, :, pos].set(vs)
-        a = reference_decode_attention(q, k_written, v_written, pos, 0)
-        b_ = reference_decode_attention(q, k, v, pos, 0, ks, vs)
+        a = decode_attention(q, k_written, v_written, pos, 0)
+        b_ = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(a), dense_decode_attention(q, k, v, pos, 0, ks, vs),
+            atol=1e-5)
 
     def test_ragged_positions_masked(self):
         """Cache entries at or past pos must not affect the output."""
@@ -163,25 +230,20 @@ class TestDecodeAttention:
         pos = jnp.array([5, 20, 39])
         k_poisoned = k.at[:, :, :, 39:].set(1e4)
         v_poisoned = v.at[:, :, :, 39:].set(1e4)
-        out_a = decode_attention(
-            q, k, v, pos, 0, k_self=ks, v_self=vs, block_t=16,
-            interpret=True,
-        )
+        out_a = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs)
         out_b = decode_attention(
-            q, k_poisoned, v_poisoned, pos, 0, k_self=ks, v_self=vs,
-            block_t=16, interpret=True,
-        )
+            q, k_poisoned, v_poisoned, pos, 0, k_self=ks, v_self=vs)
         np.testing.assert_allclose(np.asarray(out_a), np.asarray(out_b),
                                    atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(out_b),
+            dense_decode_attention(q, k, v, pos, 0, ks, vs), atol=1e-5)
 
     def test_pos_zero_attends_only_self(self):
         """Empty prefix: output is exactly v_self per head group."""
         q, k, v, ks, vs, _ = self._data(b=3)
         pos = jnp.zeros((3,), jnp.int32)
-        out = decode_attention(
-            q, k, v, pos, 0, k_self=ks, v_self=vs, block_t=16,
-            interpret=True,
-        )
+        out = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs)
         expect = jnp.broadcast_to(
             vs[:, :, None, :], (3, 4, 1, 16)
         ).reshape(3, 4, 16)
@@ -190,26 +252,23 @@ class TestDecodeAttention:
 
     def test_bf16_inputs(self):
         q, k, v, ks, vs, pos = self._data(dtype=jnp.bfloat16)
-        ref = reference_decode_attention(q, k, v, pos, 0, ks, vs)
-        out = decode_attention(
-            q, k, v, pos, 0, k_self=ks, v_self=vs, block_t=32, kernel=True,
-            interpret=True,
-        )
+        ref = dense_decode_attention(q, k, v, pos, 0, ks, vs)
+        out = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs)
+        assert out.dtype == jnp.bfloat16
         np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            atol=2e-2, rtol=2e-2,
+            np.asarray(out, np.float32), ref, atol=2e-2, rtol=2e-2,
         )
 
-    def test_non_divisible_t_raises(self):
-        """A caller who asks for the kernel and cannot have it gets an
-        error, not the reference in silence; kernel=False is the explicit
-        way to the reference."""
-        q, k, v, ks, vs, pos = self._data(t=60)
-        with pytest.raises(ValueError, match="multiple of block_t"):
-            decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs,
-                             block_t=16, kernel=True, interpret=True)
-        ref = reference_decode_attention(q, k, v, pos, 0, ks, vs)
-        out = decode_attention(q, k, v, pos, 0, k_self=ks, v_self=vs,
-                               block_t=16, kernel=False)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5)
+    def test_a_cache_no_block_divides_is_read_whole_and_right(self):
+        """T=60 is no multiple of anything the op blocks by: one extent
+        (``extent_step``), one softmax over all of it, the last position
+        included."""
+        q, k, v, ks, vs, _ = self._data(t=60)
+        pos = jnp.array([5, 31, 59], jnp.int32)
+        assert extent_step(60) == 60
+        for own in (dict(k_self=ks, v_self=vs), {}):
+            out = decode_attention(q, k, v, pos, 0, **own)
+            np.testing.assert_allclose(
+                np.asarray(out),
+                dense_decode_attention(q, k, v, pos, 0, *own.values()),
+                atol=1e-5)

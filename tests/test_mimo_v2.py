@@ -21,7 +21,6 @@ from ray_tpu.models import (MimoV2Config, mimo_v2, mimo_v2_init,
 from ray_tpu.models.expert_share import (runs_every_held_expert,
                                          sigmoid_route)
 from ray_tpu.ops.decode_attention import (NEG_INF, decode_attention,
-                                          reference_decode_attention,
                                           ring_positions)
 
 # float32 against float32: the two differ by the order of their sums only
@@ -279,7 +278,7 @@ def test_the_query_blocked_full_prefill_equals_the_dense_one(s, block):
 # ------------------------------------------------- ops/decode_attention.py
 def decode_attention_before(q, k_cache, v_cache, pos, layer, k_self=None,
                             v_self=None):
-    """``reference_decode_attention`` as it was before it learned of a ring,
+    """``decode_attention``'s program as it was before it learned of a ring,
     a sink and a value width of its own (PR 44's), verbatim."""
     k = k_cache[layer]
     v = v_cache[layer]
@@ -333,7 +332,7 @@ def test_decode_attention_without_the_new_arguments_is_bit_for_bit_what_it_was(
     q, kc, vc, ks, vs = decode_operands(b, h, hkv, t, d, d, dtype, seed=t)
     pos = jnp.asarray(np.random.default_rng(1).integers(0, t, b), jnp.int32)
     own = dict(k_self=ks, v_self=vs) if with_self else {}
-    now = decode_attention(q, kc, vc, pos, 1, kernel=False, **own)
+    now = decode_attention(q, kc, vc, pos, 1, **own)
     before = jax.jit(decode_attention_before, static_argnums=4)(
         q, kc, vc, pos, 1, *own.values())
     assert now.dtype == before.dtype
@@ -345,8 +344,8 @@ def test_decode_attention_without_the_new_arguments_is_bit_for_bit_what_it_was(
         return sorted(line.split(" = ")[1].split("(")[0].split()[-1]
                       for line in text.splitlines() if " = " in line)
 
-    assert operations(lambda q, kc, vc, pos: reference_decode_attention(
-        q, kc, vc, pos, 1, *own.values())) == operations(
+    assert operations(lambda q, kc, vc, pos: decode_attention(
+        q, kc, vc, pos, 1, **own)) == operations(
             lambda q, kc, vc, pos: decode_attention_before(
                 q, kc, vc, pos, 1, *own.values()))
 
@@ -366,7 +365,7 @@ def test_decode_attention_over_a_ring_with_a_sink_and_narrower_values(
     sink = jnp.asarray(np.random.default_rng(2).normal(size=h), jnp.float32)
     pos = np.asarray(pos, np.int32)
     got = decode_attention(
-        q, kc, vc, jnp.asarray(pos), 1, kernel=False, window=w, sink=sink,
+        q, kc, vc, jnp.asarray(pos), 1, window=w, sink=sink,
         **(dict(k_self=ks, v_self=vs) if with_self else {}))
     assert got.shape == (b, h, dv)
     g = h // hkv
@@ -392,10 +391,7 @@ def test_decode_attention_over_a_ring_with_a_sink_and_narrower_values(
     with pytest.raises(ValueError, match="ring"):
         decode_attention(q, jnp.tile(kc, (1, 1, 1, 2, 1)),
                          jnp.tile(vc, (1, 1, 1, 2, 1)), jnp.asarray(pos), 1,
-                         kernel=False, window=w)
-    with pytest.raises(ValueError, match="no ring"):
-        decode_attention(q, kc, vc, jnp.asarray(pos), 1, k_self=ks,
-                         v_self=vs, window=w, interpret=True)
+                         window=w)
 
 
 # ------------------------------------------------------------------ experts

@@ -208,102 +208,6 @@ class TestViT:
                                    rtol=2e-3, atol=2e-3)
 
 
-class TestMoE:
-    def test_forward_shapes_and_aux(self):
-        from ray_tpu.models import MoEConfig, moe_apply, moe_init
-
-        cfg = MoEConfig.tiny(dtype="float32")
-        params = moe_init(jax.random.PRNGKey(0), cfg)
-        toks = _tokens(2, 16, cfg.vocab_size)
-        logits, aux = moe_apply(params, toks, cfg)
-        assert logits.shape == (2, 16, cfg.vocab_size)
-        assert float(aux) > 0.0  # balanced routing gives aux ≈ 1
-
-    def test_loss_decreases(self):
-        from ray_tpu.models import MoEConfig, moe_init, moe_loss
-
-        cfg = MoEConfig.tiny(dtype="float32")
-        params = moe_init(jax.random.PRNGKey(0), cfg)
-        toks = _tokens(2, 17, cfg.vocab_size)
-        grad_fn = jax.jit(jax.value_and_grad(
-            lambda p: moe_loss(p, toks, cfg)))
-        l0 = None
-        for _ in range(6):
-            loss, g = grad_fn(params)
-            l0 = l0 if l0 is not None else float(loss)
-            params = jax.tree.map(lambda p, gg: p - 0.1 * gg, params, g)
-        assert float(loss) < l0
-
-    def test_capacity_drops_tokens_gracefully(self):
-        from ray_tpu.models import MoEConfig, moe_ffn, moe_init
-
-        cfg = MoEConfig.tiny(dtype="float32", capacity_factor=0.1)
-        params = moe_init(jax.random.PRNGKey(0), cfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
-        layer0 = jax.tree.map(lambda p: p[0], params["blocks"])
-        y, aux = moe_ffn(x, layer0["wg"], layer0["wi"], layer0["wo2"], cfg)
-        assert y.shape == x.shape
-        assert np.isfinite(np.asarray(y)).all()
-
-    def test_expert_parallel_matches_single_device(self):
-        from ray_tpu.models import (
-            MoEConfig, moe_apply, moe_init, moe_param_axes)
-
-        cfg = MoEConfig.tiny(dtype="float32")
-        params = moe_init(jax.random.PRNGKey(0), cfg)
-        toks = _tokens(4, 32, cfg.vocab_size)
-        ref, ref_aux = moe_apply(params, toks, cfg)
-        mesh = build_mesh(MeshConfig(data=2, expert=4))
-        sharded = shard_pytree(params, moe_param_axes(), mesh)
-        out, aux = jax.jit(
-            lambda p, t: moe_apply(p, t, cfg, mesh)
-        )(sharded, toks)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-3, atol=2e-3)
-        np.testing.assert_allclose(float(aux), float(ref_aux), rtol=1e-4)
-
-
-class TestMultiStepDecode:
-    def test_multi_step_matches_single_step(self):
-        """gpt2_decode_multi (n tokens per dispatch, fused argmax) must
-        produce exactly the greedy single-step token sequence."""
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.models import GPT2Config, gpt2_init
-        from ray_tpu.models.gpt2_decode import (
-            gpt2_decode_multi,
-            gpt2_decode_step,
-            gpt2_init_cache,
-        )
-
-        cfg = GPT2Config.tiny(dtype="float32")
-        B, T, K = 2, 32, 5
-        params = gpt2_init(jax.random.PRNGKey(0), cfg)
-
-        tokens = jnp.array([3, 7], jnp.int32)
-        pos = jnp.array([4, 9], jnp.int32)
-
-        cache = gpt2_init_cache(cfg, B, T)
-        single = []
-        t, p = tokens, pos
-        for _ in range(K):
-            logits, cache = gpt2_decode_step(params, t, p, cache, cfg)
-            t = jnp.argmax(logits, -1).astype(jnp.int32)
-            p = p + 1
-            single.append(t)
-
-        cache2 = gpt2_init_cache(cfg, B, T)
-        out, nxt, npos, _cache2 = gpt2_decode_multi(
-            params, tokens, pos, cache2, cfg, K
-        )
-        import numpy as np
-
-        np.testing.assert_array_equal(np.asarray(out), np.stack(single))
-        np.testing.assert_array_equal(np.asarray(nxt), np.asarray(single[-1]))
-        assert int(npos[0]) == 4 + K
-
-
 class TestDecodeThroughTheCache:
     """Every family with a cache: prefill, then ragged decode steps whose
     writes cross the tiles ``write_token_to_cache`` updates, against the

@@ -1,7 +1,7 @@
 """Podracer RL tests (arxiv 2104.06272): jax-env parity with the numpy
 envs, Anakin TPU-resident learning + placement composition, Sebulba
 host/device split (IMPALA loss parity at staleness 0, staleness bound,
-injected-death recovery), and the bench rl --quick smoke."""
+injected-death recovery)."""
 
 import numpy as np
 import pytest
@@ -515,25 +515,3 @@ class TestBroadcastFanOut:
             )
             assert seq == 5
             np.testing.assert_array_equal(value["w"], np.ones(4))
-
-
-# ----------------------------------------------------------- bench smoke
-class TestBenchRlQuick:
-    def test_bench_rl_quick_smoke(self, cluster):
-        """The tier-1 pin for ``bench.py rl --quick``: every stage runs
-        in-process (no cold jax import) and the Anakin-vs-host-loop
-        ratio clears 1.0."""
-        from ray_tpu.rllib.podracer import bench_rl
-
-        rows = bench_rl.bench_anakin_scaling(quick=True)
-        assert any(
-            r["metric"].startswith("rl_anakin_env_steps_per_s")
-            and r["value"] > 0
-            for r in rows
-        )
-        rows = bench_rl.bench_anakin_vs_host_loop(quick=True)
-        assert rows[0]["metric"] == "rl_anakin_vs_host_loop"
-        assert rows[0]["ratio"] > 1.0, rows[0]
-        rows = bench_rl.bench_sebulba(quick=True)
-        assert rows[0]["metric"] == "rl_sebulba_learner_steps_per_s"
-        assert rows[0]["value"] > 0

@@ -2,9 +2,10 @@
 ``release/benchmarks/single_node/test_single_node.py``).
 
 The full envelopes (10k args, 3k returns, 10k-ref get, 100k queued,
-arena-oversized spill) run in ``python bench.py limits``; the tests here
-pin the MACHINERY those envelopes lean on at smoke scale so tier-1 stays
-fast, plus heavier (still box-sane) versions under ``@pytest.mark.slow``:
+arena-oversized spill) are run by nothing in this repository; the tests
+here pin the MACHINERY those envelopes lean on at smoke scale so tier-1
+stays fast, plus heavier (still box-sane) versions under
+``@pytest.mark.slow``:
 
   - wide-args / wide-returns / wide-get correctness at scale,
   - submission backpressure: queued-task memory is CAPPED — a producer

@@ -49,7 +49,7 @@ def _qkv(shape, sharding):
     return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)] * 3
 
 
-# (B, S, H, D): GPT-2 small (``chip_smoke.py``), ``bench.py``'s, the
+# (B, S, H, D): GPT-2 small (``chip_smoke.py``), a sequence of 2048, the
 # training cells' own (``gpt2_medium`` at 32 rows a chip), and a head of 128
 # (``models/llama.py``)
 FLASH_SHAPES = [(32, 1024, 12, 64), (4, 2048, 32, 64), (32, 1024, 16, 64),
@@ -176,44 +176,31 @@ def test_training_cells_step_turns_no_kernel_operand(training_step):
     assert made == {"fusion": 3}
 
 
-def _decode_args(L, B, H, Hkv, T, D, sharding):
-    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
-        shape, dt, sharding=sharding
-    )
-    return dict(
-        q=s((B, H, D)),
-        k_cache=s((L, B, Hkv, T, D)),
-        v_cache=s((L, B, Hkv, T, D)),
-        pos=s((B,), jnp.int32),
-        k_self=s((B, Hkv, D)),
-        v_self=s((B, Hkv, D)),
-    )
+# (L, B, H, Hkv, T, D): GPT-2 small's cache and TinyLlama's, the two shapes
+# at which the Pallas decode kernel was pinned until PR 51 (compiled at the
+# first, refused for VMEM at the second)
+DECODE_SHAPES = [(12, 32, 12, 12, 1024, 64), (22, 32, 32, 8, 2048, 64)]
 
 
-def _lower_decode(args):
-    def step(q, k_cache, v_cache, pos, k_self, v_self):
-        return decode_attention(
-            q, k_cache, v_cache, pos, 0, k_self=k_self, v_self=v_self,
-            kernel=True,
-        )
-
-    return jax.jit(step).lower(**args)
-
-
-def test_decode_kernel_compiles_at_gpt2_shape(one_chip, as_if_on_tpu):
-    args = _decode_args(12, 32, 12, 12, 1024, 64, one_chip)
-    assert "tpu_custom_call" in _lower_decode(args).compile().as_text()
-
-
-def test_decode_kernel_refused_for_vmem_at_tinyllama_shape(
-    one_chip, as_if_on_tpu
+@pytest.mark.parametrize("shape", DECODE_SHAPES, ids=str)
+def test_decode_attention_compiles_where_the_kernel_was_pinned(
+    one_chip, shape
 ):
-    """Today's truth (ROADMAP D2 starts here): each program copies a whole
-    [Hkv, T, D] cache slice per operand, and at Hkv=8, T=2048 the v5e
-    compiler runs out of VMEM.  The decode steps default to kernel=False."""
-    args = _decode_args(22, 32, 32, 8, 2048, 64, one_chip)
-    with pytest.raises(Exception, match="(?i)vmem|RESOURCE_EXHAUSTED"):
-        _lower_decode(args).compile()
+    """What the decode steps run compiles for the v5e at both shapes, as
+    plain XLA (no kernel), a layer's read taken out of the stacked cache
+    where it lies: no temporary the size of a layer's keys."""
+    L, B, H, Hkv, T, D = shape
+    s = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    cache = s((L, B, Hkv, T, D))
+    compiled = jax.jit(
+        lambda q, k_cache, v_cache, pos, k_self, v_self: decode_attention(
+            q, k_cache, v_cache, pos, L - 1, k_self=k_self, v_self=v_self)
+    ).lower(s((B, H, D)), cache, cache, s((B,), jnp.int32),
+            s((B, Hkv, D)), s((B, Hkv, D))).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        B * Hkv * T * D * 2)
 
 
 # The serving cells' configurations (benchmarks/configs/<name>.json).
