@@ -18,6 +18,18 @@ from .gpt2_decode import (  # noqa: F401
     sample_logits_greedy,
     sample_logits_rows,
 )
+from .laguna import (  # noqa: F401
+    LagunaConfig,
+    laguna_apply,
+    laguna_init,
+    laguna_loss,
+    laguna_param_axes,
+)
+from .laguna_decode import (  # noqa: F401
+    laguna_decode_step,
+    laguna_init_cache,
+    laguna_prefill,
+)
 from .llama import (  # noqa: F401
     LlamaConfig,
     llama_apply,
@@ -222,5 +234,21 @@ register_model_family(
         prefill_counted=_functools.partial(mistral4_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             mistral4_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    LagunaConfig,
+    ModelFamily(
+        name="laguna",
+        init=laguna_init,
+        apply=laguna_apply,
+        loss=laguna_loss,
+        param_axes=laguna_param_axes,
+        init_cache=laguna_init_cache,
+        prefill=laguna_prefill,
+        decode_step=laguna_decode_step,
+        prefill_counted=_functools.partial(laguna_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            laguna_decode_step, with_counts=True),
     ),
 )

@@ -243,15 +243,22 @@ def mimo_v2_param_axes():
 
 
 # ---------------------------------------------------------------- attention
-def rope_half(x, positions, theta: float, rotary_dim: int):
+def rope_half(x, positions, theta: float, rotary_dim: int, inv_freq=None,
+              factor=None):
     """Rotate the first ``rotary_dim`` dimensions of every head in the
     ``rotate_half`` pairing (dimension ``j`` with ``j + rotary_dim / 2``).  x
     ``[..., heads, D]`` float32, positions of x's leading shape (or one that
-    broadcasts to it) -> float32."""
+    broadcasts to it) -> float32.  ``inv_freq`` ``[rotary_dim / 2]``: a
+    family's own frequencies a pair (scaled rotary) in place of ``theta ** (-2j
+    / rotary_dim)``; ``factor``: what multiplies cos and sin (YaRN's
+    attention factor).  Both absent, the program is what it was."""
     half = rotary_dim // 2
-    freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freqs = (theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     angles = positions[..., None, None].astype(jnp.float32) * freqs
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half], x[..., half:rotary_dim]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary_dim:]], -1)

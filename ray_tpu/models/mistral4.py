@@ -169,29 +169,40 @@ class Mistral4Config:
 
 
 # ------------------------------------------------------------------- rotary
-def yarn_correction_range(cfg: Mistral4Config):
-    """``(low, high)``: the rotary pairs below ``low`` keep their frequency,
-    those from ``high`` on are slowed by ``rope_factor``, a linear ramp
-    between (floor / ceil of the pair that turns ``beta_fast`` / ``beta_slow``
-    times over the trained positions, clipped to the pairs there are)."""
-    dr = cfg.qk_rope_head_dim
+def yarn_correction_range(dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float):
+    """``(low, high)`` of the ``dim / 2`` rotary pairs: those below ``low``
+    keep their frequency, those from ``high`` on are slowed by the factor, a
+    linear ramp between (floor / ceil of the pair that turns ``beta_fast`` /
+    ``beta_slow`` times over the ``original_max`` trained positions, clipped
+    to the pairs there are).  The numbers, not a config: Laguna's full
+    layers read the same table off other fields."""
 
     def pair_turning(turns):
-        return dr * math.log(cfg.rope_original_max / (turns * 2 * math.pi)) / (
-            2 * math.log(cfg.rope_theta))
+        return dim * math.log(original_max / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
 
-    low = max(math.floor(pair_turning(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(pair_turning(cfg.rope_beta_slow)), dr - 1)
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), dim - 1)
     return low, high
 
 
-def yarn_inv_freq(cfg: Mistral4Config) -> np.ndarray:
-    """The ``dr/2`` rotary frequencies, float32 (a constant of the program)."""
-    half = cfg.qk_rope_head_dim // 2
-    f = cfg.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
-    low, high = yarn_correction_range(cfg)
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """The ``dim / 2`` YaRN-scaled rotary frequencies, float32 (a constant
+    of the program)."""
+    half = dim // 2
+    f = theta ** (-np.arange(half, dtype=np.float64) / half)
+    low, high = yarn_correction_range(dim, theta, original_max, beta_fast,
+                                      beta_slow)
     ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
-    return ((1 - ramp) * f + ramp * f / cfg.rope_factor).astype(np.float32)
+    return ((1 - ramp) * f + ramp * f / factor).astype(np.float32)
+
+
+def yarn_numbers(cfg: Mistral4Config) -> tuple:
+    """``yarn_inv_freq``'s arguments as this family's config names them."""
+    return (cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+            cfg.rope_original_max, cfg.rope_beta_fast, cfg.rope_beta_slow)
 
 
 def yarn_mscale(cfg: Mistral4Config) -> float:
@@ -215,7 +226,7 @@ def project(y, att, positions, cfg: Mistral4Config):
     ``[B, S, rkv+dr]`` that the cache holds."""
     return mla_project(
         y, att, positions, cfg, latent_scales=False,
-        inv_freq=yarn_inv_freq(cfg),
+        inv_freq=yarn_inv_freq(*yarn_numbers(cfg)),
         q_factor=query_factor(positions, cfg)[..., None, None])
 
 
@@ -293,18 +304,27 @@ def mistral4_param_axes():
 
 # ---------------------------------------------------------------- attention
 def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
-                      key_block: int = KEY_BLOCK):
+                      key_block: int = KEY_BLOCK, window=None):
     """Causal attention of ``[B, S]`` tokens over themselves, a tile of
-    ``query_block`` queries by ``key_block`` keys at a time.  q, k ``[B, S,
-    H, D]`` (the softmax scale ``D^-0.5``; what else scales a score is in
-    ``q``), v ``[B, S, H, Dv]`` -> ``[B, S, H, Dv]`` float32.  Query block
-    ``c`` sees the key blocks up to its own last row and no further (an
-    online softmax over them: ``attend_blocks``); ``longest`` (traced; the
-    longest prompt of the batch) bounds the query blocks, and the rows of
-    those wholly beyond it come out zero: nothing reads them.  A sequence
-    that the blocks do not divide is padded with keys no query sees."""
+    ``query_block`` queries by ``key_block`` keys at a time.  q ``[B, S, H,
+    D]``, k ``[B, S, Hkv, D]`` (the softmax scale ``D^-0.5``; what else scales
+    a score is in ``q``), v ``[B, S, Hkv, Dv]`` -> ``[B, S, H, Dv]`` float32.
+    Query block ``c`` sees the key blocks up to its own last row and no
+    further (an online softmax over them: ``attend_blocks``); ``longest``
+    (traced; the longest prompt of the batch) bounds the query blocks, and
+    the rows of those wholly beyond it come out zero: nothing reads them.  A
+    sequence that the blocks do not divide is padded with keys no query
+    sees.
+
+    Where ``Hkv`` divides ``H`` (grouped queries: query head ``h`` reads
+    key-value head ``h // (H / Hkv)``) a tile's products carry the group as
+    an axis of the queries, and the keys and values are never repeated.
+    ``window`` (static): query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``: the BAND, whose key blocks have a lower bound too, so a query
+    block of ``window`` rows meets two key blocks whatever ``S`` is.  With
+    equal head counts and no window the program is what it was."""
     bsz, s, h, d = q.shape
-    dv = v.shape[-1]
+    hkv, dv = k.shape[2], v.shape[-1]
     query_block, key_block = min(query_block, s), min(key_block, s)
     whole = math.lcm(query_block, key_block)
     padded = -(-s // whole) * whole
@@ -312,25 +332,42 @@ def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
         q, k, v = (jnp.pad(a, ((0, 0), (0, padded - s), (0, 0), (0, 0)))
                    for a in (q, k, v))
     scale = d ** -0.5
+    # a tile's products and its result's shape, heads equal or grouped
+    if hkv == h:
+        to_scores, to_values = "bqhd,bkhd->bhqk", "bhqk,bkhv->bhqv"
+        tile = (bsz, h, query_block, dv)
+    else:
+        to_scores, to_values = "bqkgd,btkd->bkgqt", "bkgqt,btkv->bkgqv"
+        tile = (bsz, hkv, h // hkv, query_block, dv)
 
     def query_rows(c, out):
         first = c * query_block
         qb = jax.lax.dynamic_slice_in_dim(q, first, query_block, axis=1)
+        if hkv != h:
+            qb = qb.reshape(bsz, query_block, hkv, h // hkv, d)
         rows = first + jnp.arange(query_block)
 
         def keys(start):
             kb = jax.lax.dynamic_slice_in_dim(k, start, key_block, axis=1)
             vb = jax.lax.dynamic_slice_in_dim(v, start, key_block, axis=1)
-            scores = matmul("bqhd,bkhd->bhqk", qb, kb) * scale
+            scores = matmul(to_scores, qb, kb) * scale
             seen = rows[:, None] >= start + jnp.arange(key_block)[None]
+            if window is not None:
+                seen &= (rows[:, None] - start
+                         - jnp.arange(key_block)[None]) < window
             return jnp.where(seen, scores, NEG_INF), lambda p: matmul(
-                "bhqk,bkhv->bhqv", p.astype(q.dtype), vb)
+                to_values, p.astype(q.dtype), vb)
 
-        o = attend_blocks(keys, (first + query_block + key_block - 1)
-                          // key_block, key_block,
-                          (bsz, h, query_block, dv))
-        return jax.lax.dynamic_update_slice_in_dim(
-            out, o.transpose(0, 2, 1, 3), first, axis=1)
+        last = (first + query_block + key_block - 1) // key_block
+        if window is None:
+            o = attend_blocks(keys, last, key_block, tile)
+        else:  # the first key block that a row of this query block sees
+            low = jnp.maximum(first - window + 1, 0) // key_block
+            o = attend_blocks(lambda start: keys(low * key_block + start),
+                              last - low, key_block, tile)
+        o = (o.transpose(0, 2, 1, 3) if hkv == h else
+             o.transpose(0, 3, 1, 2, 4).reshape(bsz, query_block, h, dv))
+        return jax.lax.dynamic_update_slice_in_dim(out, o, first, axis=1)
 
     blocks = padded // query_block
     if longest is not None:

@@ -50,8 +50,11 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "latent_long_decode_roofline.serve", "prefill_mfu.serve",  # PR 48
         "prefill_useful_pct.serve_rate", "ep8_expert_tokens.serve",
         "ep8_experts_touched_pct.serve",
-        "relayout_ms.train"]  # PR 50
-    assert cells == ["mistral4_ep8_longdoc_closed32"]  # PR 48
+        "relayout_ms.train",  # PR 50
+        "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
+        "top10_experts_touched_pct.serve"]  # PR 52
+    assert cells == ["mistral4_ep8_longdoc_closed32",  # PR 48
+                     "laguna_ep16_code_closed32"]  # PR 52
 
 
 def run(*command):

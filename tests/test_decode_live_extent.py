@@ -159,6 +159,48 @@ def test_a_ring_and_a_cache_of_one_extent_take_the_unbounded_path():
         atol=TOL["float32"])
 
 
+@pytest.mark.parametrize("h", [12, 18])
+def test_a_ring_of_512_beside_a_cache_of_16384_positions(h):
+    """Laguna's pair of extents, with its query groups of 6 and 9 over two
+    key-value heads: rows at 5, 511, 512, 9,000 and 15,871 positions.  The
+    full leaf (``T`` = 16,384, thirty-two extents) is read in one loop up to
+    the batch's longest context and gives the one-shot softmax over ``[0,
+    pos)`` and the row's own token; the ring (four lane tiles' worth, 512
+    slots, position ``p`` at ``p mod 512``, wrapped up to thirty times) is
+    read whole in one softmax, no loop, and gives the softmax over the last
+    511 positions and the row's own token, whatever the slots held
+    before."""
+    t, w, pos = 16384, 512, [5, 511, 512, 9000, 15871]
+    q, kc, vc, ks, vs = decode_operands(len(pos), h, 2, t, 16, 16, "float32",
+                                        seed=h)
+    own = dict(k_self=ks, v_self=vs)
+    at = jnp.asarray(pos, jnp.int32)
+    full = lambda q, kc, vc, at: decode_attention(q, kc, vc, at, 1, **own)
+    assert loops(full, q, kc, vc, at) == 1
+    np.testing.assert_allclose(full(q, kc, vc, at),
+                               one_shot(q, kc, vc, pos, own),
+                               atol=TOL["float32"])
+    # the rings as prefill and the steps since have left them: slot r the
+    # newest position < pos with p = r mod 512, junk where none has come
+    held = np.stack([(n - 1) - np.mod((n - 1) - np.arange(w), w)
+                     for n in pos])  # [B, w]
+    take = jnp.asarray(np.clip(held, 0, t - 1))[None, :, None, :, None]
+    ring_k, ring_v = (jnp.where(
+        jnp.asarray(held >= 0)[None, :, None, :, None],
+        jnp.take_along_axis(a, take, axis=3), 7.0) for a in (kc, vc))
+    assert ring_k.shape == (kc.shape[0], len(pos), 2, w, 16)
+    ring = lambda q, kc, vc, at: decode_attention(
+        q, kc, vc, at, 1, window=w, **own)
+    assert loops(ring, q, ring_k, ring_v, at) == 0
+    got = ring(q, ring_k, ring_v, at)
+    for b, n in enumerate(pos):  # the window's positions out of the full leaf
+        low = max(n - (w - 1), 0)
+        want = dense_decode_attention(
+            q[b:b + 1], kc[:, b:b + 1, :, low:n], vc[:, b:b + 1, :, low:n],
+            [n - low], 1, ks[b:b + 1], vs[b:b + 1])
+        np.testing.assert_allclose(got[b], want[0], atol=TOL["float32"])
+
+
 # --------------------------------------------- models/longcat_decode.py
 def mla_absorbed_before(q, latent_self, latent_cache, pos, att, cfg):
     """``mla_absorbed`` as it was at PR 45, verbatim: one attention's slice

@@ -515,9 +515,9 @@ def test_cache_read_pct_metric_reads_the_dispatch_span(tmp_path):
         [entry] = [m for m in json.load(f)["per_layer"] if m["name"] == name]
     assert (entry["moves"], entry["layer"], entry["better"]) == (
         "serve_tokens_per_s", "model step", "lower")
-    # every serving cell with a full-extent cache: PR 46's four, and the
-    # Mistral-4 cell (PR 48)
-    assert len(entry["workloads"]) == 5
+    # every serving cell with a full-extent cache: PR 46's four, the
+    # Mistral-4 cell (PR 48) and the Laguna cell (PR 52)
+    assert len(entry["workloads"]) == 6
     with open(os.path.join(hs.ROOT, "benchmarks", "layer_metrics",
                            name + ".json")) as f:
         spec = json.load(f)

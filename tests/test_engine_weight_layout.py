@@ -20,8 +20,8 @@ import ray_tpu.llm.engine as engine_module
 from ray_tpu.llm.engine import (EngineConfig, JaxLLMEngine, SamplingParams,
                                 lay_out)
 from ray_tpu.llm.tokenizer import ByteTokenizer
-from ray_tpu.models import (GPT2Config, LlamaConfig, LongcatConfig,
-                            MimoV2Config, model_family)
+from ray_tpu.models import (GPT2Config, LagunaConfig, LlamaConfig,
+                            LongcatConfig, MimoV2Config, model_family)
 from ray_tpu.util import tracing
 
 SEQ = 512  # rungs 256, 512
@@ -30,6 +30,7 @@ FAMILIES = {
     "llama": lambda **kw: LlamaConfig.tiny(vocab_size=384, max_seq=SEQ, **kw),
     "longcat": lambda **kw: LongcatConfig.tiny(vocab_size=384, **kw),
     "mimo_v2": lambda **kw: MimoV2Config.tiny(vocab_size=384, **kw),
+    "laguna": lambda **kw: LagunaConfig.tiny(vocab_size=384, **kw),
 }
 PROMPTS = ["where do the weights lie", "b" * 300, "as the decode step reads"]
 GREEDY = SamplingParams(max_tokens=6, stop_token=-1)

@@ -109,15 +109,14 @@ def test_the_yarn_table_and_both_scales_at_the_published_sizes():
     times slower, a linear ramp between; ``m`` = 0.1 ln 128 + 1; ``a`` is 1
     below 8192 positions and 1 + 0.1 ln 2 from there to 16383."""
     cfg = Mistral4Config()
-    assert mistral4.yarn_correction_range(cfg) == (12, 25)
+    assert mistral4.yarn_correction_range(64, 1e4, 8192, 32.0, 1.0) == (12, 25)
+    table = mistral4.yarn_inv_freq(*mistral4.yarn_numbers(cfg))
     f = 1e4 ** (-2 * np.arange(32) / 64)
     r = np.clip((np.arange(32) - 12) / 13, 0, 1)
-    np.testing.assert_allclose(mistral4.yarn_inv_freq(cfg),
+    np.testing.assert_allclose(table,
                                (1 - r) * f + r * f / 128, rtol=1e-6)
-    np.testing.assert_allclose(mistral4.yarn_inv_freq(cfg)[:13], f[:13],
-                               rtol=1e-6)
-    np.testing.assert_allclose(mistral4.yarn_inv_freq(cfg)[25:],
-                               f[25:] / 128, rtol=1e-6)
+    np.testing.assert_allclose(table[:13], f[:13], rtol=1e-6)
+    np.testing.assert_allclose(table[25:], f[25:] / 128, rtol=1e-6)
     assert mistral4.yarn_mscale(cfg) == pytest.approx(1.48520, abs=1e-5)
     a = np.asarray(mistral4.query_factor(
         jnp.asarray([0, 8191, 8192, 16383, 16384]), cfg)) / 1.48520 ** 2
@@ -125,8 +124,7 @@ def test_the_yarn_table_and_both_scales_at_the_published_sizes():
         a, [1, 1, 1.06931, 1.06931, 1 + 0.1 * math.log(3)], rtol=1e-5)
     # the reference writes the same table from the same keys, on its own
     np.testing.assert_allclose(
-        ref.inv_freq(bench_family.sizes_of(cfg)), mistral4.yarn_inv_freq(cfg),
-        rtol=1e-6)
+        ref.inv_freq(bench_family.sizes_of(cfg)), table, rtol=1e-6)
     assert cfg.latent_dim == 320
     with pytest.raises(ValueError, match="mscale"):
         Mistral4Config(rope_mscale=0.5)

@@ -206,7 +206,7 @@ def test_decode_attention_compiles_where_the_kernel_was_pinned(
 # The serving cells' configurations (benchmarks/configs/<name>.json).
 CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
                 "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16",
-                "mistral_small4_l9_ep8"]
+                "mistral_small4_l9_ep8", "laguna_s21_l9_ep16"]
 
 
 @pytest.fixture(scope="module")
@@ -293,7 +293,9 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
     # bf16[16,16,8,2048,128] twice; bf16[8,32,2048,576]; the hybrid's
     # bf16[1,64,2,2048,128] twice and bf16[5,64,10240,3] (its float32 state
     # has a test of its own, below); MiMo's two extents:
-    # bf16[2,64,4,4096,192|128] and the rings bf16[5,64,8,128,192|128]
+    # bf16[2,64,4,4096,192|128] and the rings bf16[5,64,8,128,192|128];
+    # Laguna's: bf16[3,32,8,16384,128] and rings of four lane tiles,
+    # bf16[6,32,8,512,128]
     leaves = {"bf16[%s]" % ",".join(map(str, leaf.shape)): leaf.size * 2
               for leaf in jax.tree.leaves(cache)}
     producers = set(re.findall(
@@ -325,7 +327,8 @@ BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "longcat_flash_l4_ep32": (8, 0.2e9),
                  "nemotron3_super_l11_ep4": (1, 0.2e9),
                  "mimo_v25_l7_ep16": (2, 0.05e9),
-                 "mistral_small4_l9_ep8": (9, 0.05e9)}
+                 "mistral_small4_l9_ep8": (9, 0.05e9),
+                 "laguna_s21_l9_ep16": (3, 0.05e9)}
 
 
 @pytest.mark.parametrize("name", CELL_CONFIGS)
@@ -483,6 +486,72 @@ def test_latent_long_decode_step_fits_and_its_top_rung_beside_it(
     assert rung.input_formats[0][0] == formats
     assert rung.memory_analysis().temp_size_in_bytes < 2.0e9
     assert rung.memory_analysis().generated_code_size_in_bytes < 20e6
+
+
+def test_ring_long_decode_step_fits_and_its_top_rung_beside_it(
+    cell, cell_decode_step, on_chip
+):
+    """The Laguna cell's programs at its size (published widths, 9 layers,
+    16 experts held, 32 slots x 16,384): the decode step's arguments are the
+    weights (4.00 GB) and a cache of 3 x 32 x 16384 x 4 KB = 6.44 GB of full
+    keys and values beside 0.40 GB of rings, all of it aliased to the
+    output, its temporaries a thousandth of what it reads (no slice of a
+    cache leaf or of an expert stack copied out, and query groups of 6 and
+    9, neither a power of two nor a multiple of the sublanes, cost ONE copy
+    a layer of the float32 query before it is rounded, ``[32, 8, 9, 128]`` =
+    1.2 MB, the window layers' in the chip's fast memory: microseconds of a
+    13 ms step; nothing else in the step is copied or transposed at a
+    megabyte); the compiler asks for ``[L, H, E, D]`` of the
+    projections and the gate (0.57 GB relaid at the build) and for no other
+    layout of an expert stack; and the top prefill rung, whose layers are
+    THREE scanned bodies (``laguna.layer_plan``) and whose scores exist a
+    tile of 512 x 512 at a time over grouped heads, needs 2.9 GB beside
+    them (the band of 72 heads whole would be 4.8 GB, a full layer's 512
+    queries against all keys 1.6 GB; the 16,384 x 12,288 hidden state of
+    layer 0's dense MLP is 0.8 GB in float32) in 24 MB of code (31 with five
+    bodies: seven rungs and the step must stay inside the chip's compile
+    cache, ~190 MiB)."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("laguna_s21_l9_ep16")
+    memory = step.memory_analysis()
+    assert cache["k"].shape == (3, 32, 8, 16384, 128)
+    assert cache["v_win"].shape == (6, 32, 8, 512, 128)
+    assert 10.8e9 < memory.argument_size_in_bytes < 10.9e9
+    assert 6.84e9 < memory.alias_size_in_bytes < 6.85e9
+    assert memory.temp_size_in_bytes < 0.05e9
+    assert memory.generated_code_size_in_bytes < 20e6
+    formats = step.input_formats[0][0]
+    assert all(fmt.layout.major_to_minor == (0, 1, 2, 3)
+               for fmt in formats["experts"].values())
+    for kind in ("full", "window"):
+        moved = {k: f.layout.major_to_minor
+                 for k, f in formats["blocks"][kind].items()
+                 if f.layout.major_to_minor != tuple(range(len(
+                     f.layout.major_to_minor)))}
+        assert moved == dict(dict.fromkeys(("wq", "wk", "wv"), (0, 2, 1, 3)),
+                             wg=(0, 2, 1))
+    # what a group of 6 or 9 costs: the query's copy, and nothing larger
+    copied = [(op, dtype, dims) for result, op, _ in instructions(
+        step.as_text()) if op in ("copy", "transpose")
+        for dtype, dims in re.findall(r"(bf16|f32)\[([\d,]+)\]", result)
+        if math.prod(map(int, dims.split(","))) * (
+            2 if dtype == "bf16" else 4) > 0.5e6]
+    assert len(copied) <= 9 and set(copied) <= {
+        ("copy", "f32", "32,8,6,128"), ("copy", "f32", "32,8,9,128")}
+    fam, cfg, _, _ = cell("laguna_s21_l9_ep16")
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+    tokens = on_chip(jax.ShapeDtypeStruct((16384,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    rung = jit_prefill_one(fam, cfg).lower(
+        lying, cache, tokens, scalar, scalar).compile()
+    assert rung.input_formats[0][0] == formats
+    assert rung.memory_analysis().temp_size_in_bytes < 3.2e9
+    assert rung.memory_analysis().generated_code_size_in_bytes < 27e6
+    # 15.75 GB of the chip: arguments + the rung's temporaries + 0.26 held
+    assert (memory.argument_size_in_bytes
+            + rung.memory_analysis().temp_size_in_bytes) < 14.5e9
 
 
 # What hands an array on as it is, and what prefetches one into the chip's
@@ -645,6 +714,16 @@ CACHE_LEAVES = [
     ((9, 1, 1024, 320), "bfloat16"),
     ((9, 1, 512, 320), "bfloat16"),
     ((9, 1, 256, 320), "bfloat16"),
+    ((3, 32, 8, 16384, 128), "bfloat16"),  # Laguna cell: full keys / values
+    ((6, 32, 8, 512, 128), "bfloat16"),    # and a ring of four lane tiles'
+    ((6, 1, 8, 512, 128), "bfloat16"),     # worth of positions (on the
+    ((3, 1, 8, 16384, 128), "bfloat16"),   # sublanes here: 32 tiles of 16);
+    ((3, 1, 8, 8192, 128), "bfloat16"),    # the one-row twins at each rung
+    ((3, 1, 8, 4096, 128), "bfloat16"),
+    ((3, 1, 8, 2048, 128), "bfloat16"),
+    ((3, 1, 8, 1024, 128), "bfloat16"),
+    ((3, 1, 8, 512, 128), "bfloat16"),
+    ((3, 1, 8, 256, 128), "bfloat16"),
 ]
 
 
