@@ -1011,17 +1011,19 @@ class JaxLLMEngine:
 
     def _fold_counts(self, n: int) -> Dict[str, int]:
         """Add the ``n`` oldest unread runs' counts to the totals; returns
-        what the decode steps among them counted (``engine.counts``'
-        attributes: one step late, because a step folds only runs dispatched
-        before it, whose copies have arrived: no wait, no ``host_syncs``)."""
-        decoded: Dict[str, int] = {}
+        what the decode steps among them counted under the family's names
+        and what the prefills counted with ``prefill_`` before them, as
+        ``stats()`` has them (``engine.counts``' attributes: one step late,
+        because a step folds only runs dispatched before it, whose copies
+        have arrived: no wait, no ``host_syncs``)."""
+        folded: Dict[str, int] = {}
         for kind, counts in self._unread_counts[:n]:
             for name, value in counts.items():
                 self._family_counts[kind][name] += int(value)
-                if kind == "decode":
-                    decoded[name] = decoded.get(name, 0) + int(value)
+                name = name if kind == "decode" else "prefill_" + name
+                folded[name] = folded.get(name, 0) + int(value)
         del self._unread_counts[:n]
-        return decoded
+        return folded
 
     def occupied(self) -> int:
         """Slots that hold a request right now."""

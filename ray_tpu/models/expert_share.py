@@ -23,10 +23,12 @@ import jax
 import jax.numpy as jnp
 
 # Rows of one expert's matrix product: a held expert sees few tokens (0.5-3 a
-# decode step, 11-32 a prefill), so its tokens are gathered and run in
-# chunks of at most this many rows; an expert no live token chose runs
-# nothing and reads no weight.
+# decode step, 8-128 a prefill of 256-4096 rows), so its tokens are gathered
+# and run in chunks of at most this many rows (``chunk_rows``: twice as many
+# in a prefill of 8,192 rows or more, where it sees 250-650); an expert no
+# live token chose runs nothing and reads no weight.
 EXPERT_CHUNK = 128
+LOOP_COUNT_NAMES = ("held_chunks", "held_chunk_rows")  # ``loop_counts``
 
 
 def _matmul(spec, x, w):  # ``longcat.matmul``, which imports this module
@@ -41,9 +43,21 @@ def runs_every_held_expert(rows: int, top_k: int, n_routed: int) -> bool:
     expert (a decode step of 64 slots) nearly every held expert is touched,
     and running ALL of them on every row in batched products streams the
     layer's experts once at the memory's speed; otherwise (a prefill) the
-    gather and the chunk loop of ``held_experts``, whose cost hardly grows
-    with the rows."""
+    gather and the chunk loop of ``held_experts``, whose cost is its
+    touched experts' up to 512 rows (measured) and its rows' beyond
+    (``held_experts`` has a turn's parts at 8,192 and 16,384 rows)."""
     return rows <= EXPERT_CHUNK and rows * top_k >= n_routed
+
+
+def chosen_scores(p, sel):
+    """``p [N, E]`` at ``sel [N, k]`` -> ``[N, k]``, the bits ``take_along_axis``
+    gives: a select and a max over ``E``, which fuse into one pass.  The
+    gather of ``N k`` scalars it replaces cost a 16,384-row prefill 1.7 ms an
+    expert layer on the v5e, as much as the router's product (PERF.md,
+    PR 53); a max, not a sum, so that no pass can merge it into the sum
+    over the chosen that follows and reorder that."""
+    picked = sel[..., None] == jnp.arange(p.shape[-1])
+    return jnp.where(picked, p[:, None, :], -jnp.inf).max(-1)
 
 
 def sigmoid_route(u, router, bias, top_k: int, scale: float = 1.0):
@@ -55,7 +69,7 @@ def sigmoid_route(u, router, bias, top_k: int, scale: float = 1.0):
     logits = jnp.dot(u, router, precision=jax.lax.Precision.HIGHEST)
     p = jax.nn.sigmoid(logits)
     _, sel = jax.lax.top_k(p + bias, top_k)
-    chosen = jnp.take_along_axis(p, sel, axis=-1)
+    chosen = chosen_scores(p, sel)
     w = scale * chosen / (chosen.sum(-1, keepdims=True) + 1e-20)
     return sel, w
 
@@ -82,24 +96,46 @@ def held_choices(sel, w, live, expert_offset: int, experts_held: int):
     return held, onehot.any(1), (w[..., None] * onehot).sum(1)
 
 
+def chunk_rows(n: int) -> int:
+    """Rows of one turn of ``held_experts`` over ``n`` rows, read off the
+    shape: ``EXPERT_CHUNK`` where a held expert sees tens of rows (larger
+    chunks would run padding), twice that from 8,192 rows on, where it sees
+    hundreds and a turn's products cost what the expert's weights cost to
+    read, whatever the rows: 33 us for 128 rows and 39 us for 256 of
+    Laguna's experts (19 MB), 78 and 91 us of Mistral-4's (50 MB), in their
+    16,384-row prefills on the v5e; 512 rows lose more to the padding of
+    each expert's last chunk than they save (PERF.md, PR 53)."""
+    return min(n, EXPERT_CHUNK if n < 8192 else 2 * EXPERT_CHUNK)
+
+
 def held_experts(u, hit, w_held, expert):
     """``sum_e w_held[:, e] * expert(u, e)`` over the experts held here, for
     the tokens that chose them: ``u [N, d]``, ``hit [N, Eh]`` bool,
     ``w_held [N, Eh]`` float32, ``expert(x [chunk, d], e) -> [chunk, d]``
     float32 -> ``[N, d]`` float32.  Dropless: each expert's tokens are
     gathered (hit rows first, in row order) and run in chunks of
-    ``EXPERT_CHUNK`` rows, as many chunks as its tokens need.  One loop
+    ``chunk_rows(N)`` rows, as many chunks as its tokens need; a token's
+    held choices are added in float32 in ascending expert order.  One loop
     walks the chunks of all experts, so an expert nobody chose costs no
     iteration and its weights are not read.  ``expert`` takes its weights as
     ``stack[layer, e]`` of the whole layer-stacked subtree, inside the loop:
     a layer's slice taken outside it is copied (1.2 GB a layer at LongCat's
-    published sizes) before the loop may read it."""
+    published sizes) before the loop may read it.
+
+    What a turn of 256 rows costs in a 16,384-row prefill on the v5e (device
+    trace, PERF.md, PR 53; Laguna's ``d`` 3072 / Mistral-4's 4096): the rows'
+    gather 9 / 11 us, the products 39 / 91 us, the scatter-add 87 / 113 us.
+    The scatter-add is XLA's price for a float32 row by index, 0.34-0.44 us,
+    and it is the same inside this loop and in ONE scatter-add after it: with
+    the rows permuted into expert order once, the chunks contiguous slices
+    and the result combined once (bit-identical, tried in PR 53) the layer
+    took as long, so the gather and the add stay where the chunk is."""
     n, d = u.shape
     held = hit.shape[1]
     out = jnp.zeros((n, d), jnp.float32)
     if held == 0:  # a share with no expert
         return out
-    chunk = min(n, EXPERT_CHUNK)
+    chunk = chunk_rows(n)
     padded = -(-n // chunk) * chunk
     counts = hit.sum(0)  # [Eh] tokens of each expert
     # Per expert, its rows first; the tail (and the padding to whole chunks)
@@ -124,6 +160,19 @@ def held_experts(u, hit, w_held, expert):
                                 unique_indices=True)
 
     return jax.lax.fori_loop(0, ends[-1], one_chunk, out)
+
+
+def loop_counts(hit, looped: bool = True):
+    """What ``held_experts`` runs for ``hit [N, Eh]``, for the family's
+    counts (int32 scalars): ``held_chunks``, its loop's turns, and
+    ``held_chunk_rows``, the rows they ran, of which ``routed_held`` chose
+    their expert and the rest pad an expert's last chunk.  Zeros for a layer
+    that ran the dense products instead (``looped`` false)."""
+    if not looped:
+        return dict.fromkeys(LOOP_COUNT_NAMES, jnp.zeros((), jnp.int32))
+    chunk = chunk_rows(hit.shape[0])
+    chunks = (-(-hit.sum(0) // chunk)).sum()
+    return {"held_chunks": chunks, "held_chunk_rows": chunks * chunk}
 
 
 def held_experts_dense(u, w_held, experts, i: int):

@@ -76,7 +76,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .expert_share import (held_choices, held_experts, held_experts_dense,
+from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
+                           held_experts_dense, loop_counts,
                            runs_every_held_expert, sigmoid_route)
 from .llama import _rmsnorm
 from .longcat import add_counts, ffn, matmul
@@ -86,7 +87,8 @@ from .mistral4 import blocked_attention, yarn_inv_freq
 # layer_types (full at l mod 4 == 0) and mlp_layer_types, as letters
 PUBLISHED_ATTN = "FWWW" * 12
 PUBLISHED_MLP = "D" + "E" * 47
-COUNT_NAMES = ("routed_total", "routed_held", "experts_touched")
+COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
+               *LOOP_COUNT_NAMES)
 # a kind of layer -> its stack under params["blocks"]
 STACK = {"F": "full", "W": "window", "D": "dense", "E": "moe"}
 # a kind of attention -> its cache leaves (``laguna_decode.py``)
@@ -324,8 +326,9 @@ def moe(u, live, params, i, cfg: LagunaConfig):
                                cfg.routed_scaling_factor)
         held, hit, w_held = held_choices(
             sel, w, live, cfg.expert_offset, cfg.experts_held)
-        if runs_every_held_expert(u.shape[0], cfg.top_k,
-                                  cfg.n_routed_experts):
+        dense = runs_every_held_expert(u.shape[0], cfg.top_k,
+                                       cfg.n_routed_experts)
+        if dense:
             y = held_experts_dense(ud, w_held, experts, i)
         else:  # [i, e] inside the loop: expert_share.py
             y = held_experts(ud, hit, w_held, lambda x, e: ffn(
@@ -338,6 +341,7 @@ def moe(u, live, params, i, cfg: LagunaConfig):
         "routed_total": live.sum() * cfg.top_k,
         "routed_held": held.sum(),
         "experts_touched": hit.any(0).sum(),
+        **loop_counts(hit, looped=not dense),
     }
 
 

@@ -58,11 +58,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .expert_share import EXPERT_CHUNK, held_choices, held_experts  # noqa: F401
+from .expert_share import (EXPERT_CHUNK, LOOP_COUNT_NAMES,  # noqa: F401
+                           held_choices, held_experts, loop_counts)
 from .llama import _rmsnorm, rope
 
 ATTENTION = ("wq_a", "rms_q", "wq_b", "wkv_a", "rms_kv", "wkv_b", "wo")
-COUNT_NAMES = ("routed_total", "routed_zero", "routed_held", "experts_touched")
+COUNT_NAMES = ("routed_total", "routed_zero", "routed_held", "experts_touched",
+               *LOOP_COUNT_NAMES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -289,6 +291,7 @@ def moe(u, live, router, bias, experts, layer: int, cfg: LongcatConfig):
             "routed_zero": (zero & live[:, None]).sum(),
             "routed_held": held.sum(),
             "experts_touched": hit.any(0).sum(),
+            **loop_counts(hit),
         }
 
 

@@ -67,7 +67,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.decode_attention import NEG_INF, ring_held
-from .expert_share import (held_choices, held_experts, held_experts_dense,
+from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
+                           held_experts_dense, loop_counts,
                            runs_every_held_expert, sigmoid_route)
 from .llama import _rmsnorm
 from .longcat import add_counts, ffn, matmul
@@ -75,7 +76,8 @@ from .longcat import add_counts, ffn, matmul
 # hybrid_layer_pattern (0 = full) and moe_layer_freq (0 = dense), as letters
 PUBLISHED_ATTN = "F" + "WWWWF" + "WWWWWF" * 7
 PUBLISHED_MLP = "D" + "E" * 47
-COUNT_NAMES = ("routed_total", "routed_held", "experts_touched")
+COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
+               *LOOP_COUNT_NAMES)
 # a kind of attention -> its stack under params["blocks"]
 STACK = {"F": "full", "W": "window"}
 # Query rows of one block of a full layer's scores over a sequence.
@@ -366,8 +368,9 @@ def moe(u, live, params, i: int, cfg: MimoV2Config):
         held, hit, w_held = held_choices(
             sel, w, live, cfg.expert_offset, cfg.experts_held)
         ud = u.astype(jnp.dtype(cfg.dtype))
-        if runs_every_held_expert(u.shape[0], cfg.top_k,
-                                  cfg.n_routed_experts):
+        dense = runs_every_held_expert(u.shape[0], cfg.top_k,
+                                       cfg.n_routed_experts)
+        if dense:
             y = held_experts_dense(ud, w_held, experts, i)
         else:  # [i, e] inside the loop: expert_share.py
             y = held_experts(ud, hit, w_held, lambda x, e: ffn(
@@ -377,6 +380,7 @@ def moe(u, live, params, i: int, cfg: MimoV2Config):
             "routed_total": live.sum() * cfg.top_k,
             "routed_held": held.sum(),
             "experts_touched": hit.any(0).sum(),
+            **loop_counts(hit, looped=not dense),
         }
 
 

@@ -88,13 +88,15 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops.decode_attention import NEG_INF, attend_blocks
-from .expert_share import (held_choices, held_experts, held_experts_dense,
+from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
+                           held_experts_dense, loop_counts,
                            runs_every_held_expert, softmax_route)
 from .llama import _rmsnorm
 from .longcat import add_counts, ffn, matmul, mla_project
 
 ATTENTION = ("wq_a", "rms_q", "wq_b", "wkv_a", "rms_kv", "wk_b", "wv_b", "wo")
-COUNT_NAMES = ("routed_total", "routed_held", "experts_touched")
+COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
+               *LOOP_COUNT_NAMES)
 # A prefill's scores exist a tile of this many queries by this many keys at a
 # time: 32 heads x 512 x 512 float32 = 32 MB at the published sizes, which the
 # v5e's compiler keeps in its fast memory from the scores' product to the
@@ -412,8 +414,9 @@ def moe(u, live, params, i: int, cfg: Mistral4Config):
                                cfg.routed_scaling_factor)
         held, hit, w_held = held_choices(
             sel, w, live, cfg.expert_offset, cfg.experts_held)
-        if runs_every_held_expert(u.shape[0], cfg.top_k,
-                                  cfg.n_routed_experts):
+        dense = runs_every_held_expert(u.shape[0], cfg.top_k,
+                                       cfg.n_routed_experts)
+        if dense:
             y = held_experts_dense(ud, w_held, experts, i)
         else:  # [i, e] inside the loop: expert_share.py
             y = held_experts(ud, hit, w_held, lambda x, e: ffn(
@@ -426,6 +429,7 @@ def moe(u, live, params, i: int, cfg: Mistral4Config):
         "routed_total": live.sum() * cfg.top_k,
         "routed_held": held.sum(),
         "experts_touched": hit.any(0).sum(),
+        **loop_counts(hit, looped=not dense),
     }
 
 

@@ -72,14 +72,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .expert_share import (held_choices, held_experts,
-                           runs_every_held_expert, sigmoid_route)
+from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
+                           loop_counts, runs_every_held_expert,
+                           sigmoid_route)
 from .llama import _rmsnorm
 from .longcat import add_counts, matmul
 
 PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
                      "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
-COUNT_NAMES = ("routed_total", "routed_held", "experts_touched")
+COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
+               *LOOP_COUNT_NAMES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -438,7 +440,8 @@ def moe(u, live, params, i: int, cfg: NemotronHConfig):
     loop of ``expert_share.held_experts`` costs 0.9 ms + 23 us a touched
     expert (3.7 ms with 124 touched, and ~600 turns a step, over which the
     profiler could not stop).  A prefill takes the loop, whose cost hardly
-    grows with the rows (4.7 ms at 256, 4.8 at 512) where the products' does
+    grows with the rows up to 512 (4.7 ms at 256, 4.8 at 512; from 8,192 rows
+    it is the rows': ``expert_share.held_experts``) where the products' does
     (3.6, 4.9, and an intermediate of 2.8 GB at the 2048 rung).  ``N`` is the
     engine's slot count, so a replica of 64 slots with four callers streams
     all its experts too, where the loop would take 1.4 ms (PERF.md, PR 39,
@@ -456,8 +459,9 @@ def moe(u, live, params, i: int, cfg: NemotronHConfig):
         ud = u.astype(dt)
         v = matmul("ne,el->nl", ud, blocks["w_dl"][i]).astype(dt)
 
-        n = u.shape[0]
-        if runs_every_held_expert(n, cfg.top_k, cfg.n_routed_experts):
+        dense = runs_every_held_expert(u.shape[0], cfg.top_k,
+                                       cfg.n_routed_experts)
+        if dense:
             latent = held_experts_dense(
                 v, w_held, experts["w1"][i], experts["w2"][i])
         else:  # [i, e] inside the loop: expert_share.py
@@ -469,6 +473,7 @@ def moe(u, live, params, i: int, cfg: NemotronHConfig):
             "routed_total": live.sum() * cfg.top_k,
             "routed_held": held.sum(),
             "experts_touched": hit.any(0).sum(),
+            **loop_counts(hit, looped=not dense),
         }
 
 

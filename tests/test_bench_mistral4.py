@@ -15,17 +15,23 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
     """The benchmark's case of this name (star-imported above, shadowed
     here) holds PR 48's five metrics to list its cell ALONE, which no later
-    cell that reports ``prefill_mfu.serve`` can keep, and the benchmark's
-    files are add-only, its tests among them.  The same case over the cells
-    as far as PR 48 wrote them (``tests/test_bench_mimo_v2.py``'s way), and
-    what was appended since by name."""
+    cell that reports ``prefill_mfu.serve`` can keep, and the cell's list to
+    be the MiMo cell's but for its own, which no later metric of the long
+    prompts' cells can keep, and the benchmark's files are add-only, its
+    tests among them.  The same case over the cells and metrics as far as
+    PR 48 wrote them (``tests/test_bench_mimo_v2.py``'s way), and what was
+    appended since by name."""
     import benchmarks.tests.test_bench_mistral4 as theirs
 
-    cells = []
+    appended, cells = [], []
 
     def load_as_pr48_left_it(*path):
         bench = theirs_load(*path)
         if path[-1] == "BENCHMARK.json":
+            names = [m["name"] for m in bench["per_layer"]]
+            cut = max(map(names.index, theirs.NEW_METRICS)) + 1
+            appended[:] = names[cut:]
+            bench["per_layer"] = bench["per_layer"][:cut]
             names = [w["name"] for w in bench["workloads"]]
             cut = names.index(theirs.CELL) + 1
             cells[:] = names[cut:]
@@ -40,6 +46,11 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
     monkeypatch.setattr(theirs, "load", load_as_pr48_left_it)
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
     assert cells == ["laguna_ep16_code_closed32"]  # PR 52
+    assert appended == [
+        "relayout_ms.train",  # PR 50
+        "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
+        "top10_experts_touched_pct.serve",  # PR 52
+        "prefill_chunk_fill_pct.serve"]  # PR 53
 
 
 def run(*command):

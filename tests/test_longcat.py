@@ -17,6 +17,7 @@ from benchmarks.reference.longcat_ref import moe as ref_moe
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
 from ray_tpu.models import LongcatConfig, longcat_init, model_family
 from ray_tpu.models import longcat
+from ray_tpu.models.expert_share import chunk_rows
 
 # float32 against float32: the two differ by the order of their sums only
 # (absorbed against expanded attention, experts added in another order);
@@ -104,7 +105,10 @@ def recount(chosen, cfg):
 
 
 def as_ints(counts):
-    return {k: int(v) for k, v in counts.items()}
+    """The routing counts (the loop's own, ``expert_share.loop_counts``, are
+    counted by hand in ``tests/test_expert_share.py``)."""
+    return {k: int(v) for k, v in counts.items()
+            if k not in longcat.LOOP_COUNT_NAMES}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -345,6 +349,45 @@ def test_engine_counts_span_carries_the_routing_one_step_late(tmp_path):
     # The vector of the step before, and a first token an admission.
     assert all(a["host_syncs"] <= 1 + a["admitted"] for a in seen)
     assert all(a["overrun"] == 0 for a in seen)  # streams end by count
+
+
+def test_engine_counts_span_carries_a_prefills_counts_under_stats_names(
+        monkeypatch):
+    """What a prefill counted is written on ``engine.counts`` with
+    ``prefill_`` before the family's names, as ``stats()`` has them: every
+    prefill of the run is on some span by its end (a step folds the runs
+    dispatched before it), spans that folded none carry none, and the rows
+    the loop's chunks ran hold the choices they were run for
+    (``prefill_chunk_fill_pct.serve`` reads the two)."""
+    import ray_tpu.llm.engine as engine_module
+
+    engine = make_engine(slots=2)
+    params = SamplingParams(max_tokens=6, stop_token=-1)
+    engine.generate(["warm"], params)
+    before = engine.stats()
+    seen = []
+    real = engine_module.host_span
+
+    def spy(name, **attrs):
+        if name == "engine.counts":
+            seen.append(attrs)
+        return real(name, **attrs)
+
+    monkeypatch.setattr(engine_module, "host_span", spy)
+    engine.generate(PROMPTS[:3], params)
+    after = engine.stats()
+    names = ["prefill_" + name for name in longcat.COUNT_NAMES]
+    folded = [a for a in seen if names[0] in a]
+    assert 0 < len(folded) <= 3 < len(seen)
+    assert all(set(names) <= set(a) for a in folded)
+    assert not any(set(names) & set(a) for a in seen if a not in folded)
+    for name in names:
+        assert sum(a[name] for a in folded) == after[name] - before[name] > 0
+    held = sum(a["prefill_routed_held"] for a in folded)
+    rows = sum(a["prefill_held_chunk_rows"] for a in folded)
+    assert 0 < held <= rows
+    assert rows == chunk_rows(64) * sum(  # max_seq_len 64: its one rung
+        a["prefill_held_chunks"] for a in folded)
 
 
 def cfg_choices(engine):

@@ -18,7 +18,7 @@ from benchmarks.reference import mimo_v2_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
 from ray_tpu.models import (MimoV2Config, mimo_v2, mimo_v2_init,
                             model_family)
-from ray_tpu.models.expert_share import (runs_every_held_expert,
+from ray_tpu.models.expert_share import (chunk_rows, runs_every_held_expert,
                                          sigmoid_route)
 from ray_tpu.ops.decode_attention import (NEG_INF, decode_attention,
                                           ring_positions)
@@ -425,10 +425,15 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(weights, rows):
             u, live, part, layer, share))(u)
         local = np.asarray(chosen)[0][np.asarray(live)] - offset
         held = (local >= 0) & (local < 4)
+        # the loop's turns and the rows they ran, counted by hand
+        turns = sum(-(-int((local[held] == e).sum()) // chunk_rows(rows))
+                    for e in range(4)) if rows != 13 else 0
         assert {k: int(v) for k, v in counts.items()} == {
             "routed_total": (rows - 1) * cfg.top_k,
             "routed_held": int(held.sum()),
-            "experts_touched": len(np.unique(local[held]))}
+            "experts_touched": len(np.unique(local[held])),
+            "held_chunks": turns,
+            "held_chunk_rows": turns * chunk_rows(rows)}
         total, held_sum = total + y, held_sum + int(held.sum())
     assert held_sum == (rows - 1) * cfg.top_k  # every choice is somebody's
     np.testing.assert_allclose(

@@ -18,6 +18,7 @@ from benchmarks.reference import nemotron_h_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
 from ray_tpu.models import (NemotronHConfig, model_family, nemotron_h,
                             nemotron_h_init)
+from ray_tpu.models.expert_share import chunk_rows
 
 # float32 against float32: the two differ by the order of their sums only
 # (the chunked scan against the recurrence, experts added in another order,
@@ -253,10 +254,15 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
         np.testing.assert_allclose(shared, want_shared[0], atol=F32_TOL)
         local = np.asarray(chosen)[0][np.asarray(live)] - offset
         held = (local >= 0) & (local < 4)
+        # the loop's turns and the rows they ran, counted by hand
+        turns = sum(-(-int((local[held] == e).sum()) // chunk_rows(rows))
+                    for e in range(4)) if rows != 13 else 0
         assert {k: int(v) for k, v in counts.items()} == {
             "routed_total": (rows - 1) * cfg.top_k,
             "routed_held": int(held.sum()),
-            "experts_touched": len(np.unique(local[held]))}
+            "experts_touched": len(np.unique(local[held])),
+            "held_chunks": turns,
+            "held_chunk_rows": turns * chunk_rows(rows)}
         total, held_sum = total + routed, held_sum + int(held.sum())
     assert held_sum == (rows - 1) * cfg.top_k  # every choice is somebody's
     np.testing.assert_allclose(

@@ -450,6 +450,16 @@ def test_windowed_decode_step_fits_and_its_top_rung_beside_it(
     assert rung.memory_analysis().temp_size_in_bytes < 2.0e9
 
 
+def moe_row_ops(text):
+    """{(result array, op)} of every ``gather`` and ``scatter`` a compiled
+    program runs under an expert layer's scope (``<family>.moe``), fusions'
+    own computations included: what the layer moves by row or element
+    index."""
+    return {(result, op) for result, op, name in re.findall(
+        r'= (\w+\[[\d,]*\])\S* (gather|scatter)\([^\n]*op_name="([^"]*)"',
+        text) if ".moe/" in name}
+
+
 def test_latent_long_decode_step_fits_and_its_top_rung_beside_it(
     cell, cell_decode_step, on_chip
 ):
@@ -486,6 +496,11 @@ def test_latent_long_decode_step_fits_and_its_top_rung_beside_it(
     assert rung.input_formats[0][0] == formats
     assert rung.memory_analysis().temp_size_in_bytes < 2.0e9
     assert rung.memory_analysis().generated_code_size_in_bytes < 20e6
+    # an expert layer's turn: 256 rows gathered, their weights, the chunk
+    # added in place; nothing else there moves a row or an element by index
+    assert moe_row_ops(rung.as_text()) == {
+        ("bf16[256,4096]", "gather"), ("f32[256]", "gather"),
+        ("f32[16384,4096]", "scatter")}
 
 
 def test_ring_long_decode_step_fits_and_its_top_rung_beside_it(
@@ -549,6 +564,13 @@ def test_ring_long_decode_step_fits_and_its_top_rung_beside_it(
     assert rung.input_formats[0][0] == formats
     assert rung.memory_analysis().temp_size_in_bytes < 3.2e9
     assert rung.memory_analysis().generated_code_size_in_bytes < 27e6
+    # an expert layer's turn: 256 rows gathered, their weights, the chunk
+    # added in place; the router's chosen scores are a select, not
+    # ``take_along_axis``'s gather of 16,384 x 10 scalars (1.7 ms a layer on
+    # the chip, PERF.md, PR 53): nothing else moves by index
+    assert moe_row_ops(rung.as_text()) == {
+        ("bf16[256,3072]", "gather"), ("f32[256]", "gather"),
+        ("f32[16384,3072]", "scatter")}
     # 15.75 GB of the chip: arguments + the rung's temporaries + 0.26 held
     assert (memory.argument_size_in_bytes
             + rung.memory_analysis().temp_size_in_bytes) < 14.5e9
