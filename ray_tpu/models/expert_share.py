@@ -10,7 +10,8 @@ computes
 ``sum_{e held, chosen} w_e f_e(u)`` for the tokens that chose a held expert.
 That sum is here: ``held_choices`` turns the router's choices into the held
 experts' hit mask and combine weights, ``held_experts`` gathers each
-expert's tokens and walks the chunks.  The expert itself, ``f_e``, is the
+expert's tokens and walks the chunks (a decode step's rows are one chunk: a
+turn a touched expert on the whole batch).  The expert itself, ``f_e``, is the
 caller's: gated SwiGLU on the hidden state in ``longcat.py`` (``ffn``, which
 ``mimo_v2.py`` and ``mistral4.py`` run too), an ungated ``relu^2`` MLP on a
 latent in ``nemotron_h.py``.  What absent experts would add is left out; what
@@ -25,8 +26,9 @@ import jax.numpy as jnp
 # Rows of one expert's matrix product: a held expert sees few tokens (0.5-3 a
 # decode step, 8-128 a prefill of 256-4096 rows), so its tokens are gathered
 # and run in chunks of at most this many rows (``chunk_rows``: twice as many
-# in a prefill of 8,192 rows or more, where it sees 250-650); an expert no
-# live token chose runs nothing and reads no weight.
+# in a prefill of 8,192 rows or more, where it sees 250-650; a decode step's
+# rows, never more than this, are one chunk and stay where they are); an
+# expert no live token chose runs nothing and reads no weight.
 EXPERT_CHUNK = 128
 LOOP_COUNT_NAMES = ("held_chunks", "held_chunk_rows")  # ``loop_counts``
 
@@ -38,15 +40,27 @@ def _matmul(spec, x, w):  # ``longcat.matmul``, which imports this module
 def runs_every_held_expert(rows: int, top_k: int, n_routed: int) -> bool:
     """Which way a layer's held experts run, read off the SHAPES, never off
     the load (the two ways round differently, so a request's greedy ids
-    would depend on who else is served: ``nemotron_h.moe`` has the
-    timings).  When the rows fit one chunk and make a choice or more an
-    expert (a decode step of 64 slots) nearly every held expert is touched,
-    and running ALL of them on every row in batched products streams the
-    layer's experts once at the memory's speed; otherwise (a prefill) the
-    gather and the chunk loop of ``held_experts``, whose cost is its
-    touched experts' up to 512 rows (measured) and its rows' beyond
-    (``held_experts`` has a turn's parts at 8,192 and 16,384 rows)."""
-    return rows <= EXPERT_CHUNK and rows * top_k >= n_routed
+    would depend on who else is served: ``nemotron_h.moe``).  When the rows
+    fit one chunk (a decode step) and would, each choosing on its own, touch
+    three in four of the experts or more, every held expert runs on every
+    row in batched products, which stream the layer's experts once at the
+    memory's speed whatever was chosen; otherwise ``held_experts``: a
+    prefill's gather and chunk loop, a decode step's turn a touched expert.
+
+    Where the line lies is a timing of one layer's held experts on the v5e
+    at the four callers' step shapes (PERF.md, PR 54): a turn of the loop
+    costs its expert's read (76 us of 50 MB, 36 of 19, 19 of 11) and the
+    products read all the held at 79-90 % of the memory's speed, so the
+    loop wins while fewer than 14.5 of Mistral-4's 16, 13.9 of MiMo-V2's,
+    12.3 of Laguna's, 100 of Nemotron-H's 128 are touched: 77-90 %, and
+    every shape under three in four is under all of them.  Independent rows
+    touch ``1 - (1 - k / E) ** rows``:
+    64 % in Mistral-4's step (32 x 4 / 128, a choice an expert; 43-49 % as
+    served) and 72 % in Laguna's (32 x 10 / 256), which take the loop; 87 %
+    in MiMo-V2's (64 x 8 / 256, a tie) and 94 % in Nemotron-H's (64 x 22 /
+    512), which run them all."""
+    return (rows <= EXPERT_CHUNK
+            and 1 - (1 - top_k / n_routed) ** rows >= 0.75)
 
 
 def chosen_scores(p, sel):
@@ -129,13 +143,36 @@ def held_experts(u, hit, w_held, expert):
     and it is the same inside this loop and in ONE scatter-add after it: with
     the rows permuted into expert order once, the chunks contiguous slices
     and the result combined once (bit-identical, tried in PR 53) the layer
-    took as long, so the gather and the add stay where the chunk is."""
+    took as long, so the gather and the add stay where the chunk is.
+
+    Where all the rows are ONE chunk (``n <= chunk_rows(n)``: a decode step;
+    no prefill rung is that short) there is nothing to gather: a turn runs
+    the i-th TOUCHED expert, in ascending order, on the whole batch and
+    adds it where it was chosen, so the same float32 sum in the same order
+    without the sort, the gather and the scatter-add (bit-identical to them
+    on the v5e at the five cells' step shapes).  The trip count is the
+    load's, a row's value is not: it is computed from that row alone
+    whatever the others hold or choose.  A turn there costs what its
+    expert's matrices cost to read at 64-83 % of the memory's speed: 19 us of
+    Nemotron-H's 11 MB, 36 of Laguna's 19, 76 of Mistral-4's 50 (77 on
+    MiMo-V2's 64 rows), 111 of LongCat's 75.5; the sort, gather and
+    scatter-add it dropped were 4-9 us of a turn (PERF.md, PR 54)."""
     n, d = u.shape
     held = hit.shape[1]
     out = jnp.zeros((n, d), jnp.float32)
     if held == 0:  # a share with no expert
         return out
     chunk = chunk_rows(n)
+    if n <= chunk:  # a decode step: every turn's chunk is all the rows
+        ends = jnp.cumsum(hit.any(0))  # a turn for each touched expert
+
+        def one_expert(i, out):
+            e = (ends <= i).sum()  # the i-th touched expert, ascending
+            chose = jax.lax.dynamic_index_in_dim(hit, e, 1)
+            w = jax.lax.dynamic_index_in_dim(w_held, e, 1)
+            return out + jnp.where(chose, expert(u, e) * w, 0.0)
+
+        return jax.lax.fori_loop(0, ends[-1], one_expert, out)
     padded = -(-n // chunk) * chunk
     counts = hit.sum(0)  # [Eh] tokens of each expert
     # Per expert, its rows first; the tail (and the padding to whole chunks)

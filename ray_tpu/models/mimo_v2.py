@@ -357,10 +357,14 @@ def moe(u, live, params, i: int, cfg: MimoV2Config):
     ``cfg.dtype``), ``live [N]`` bool (a padded or idle row chooses nothing:
     it touches no expert and is not counted) -> (``[N, d]`` float32,
     counts).  A decode step of 64 slots (64 x 8 / 256 = 2.0 choices an
-    expert, seven in eight of the sixteen touched) runs every held expert
-    in batched products, 1.1 ms a step faster on the v5e than the loop over
-    the touched ones, which a prefill takes
-    (``expert_share.runs_every_held_expert``; PERF.md, PR 45)."""
+    expert, seven in eight of the sixteen touched if the rows choose each
+    on its own) runs every held expert in batched products: 1.11 ms a layer
+    on the v5e whatever was chosen, which the loop over the touched ones
+    (a prefill's way) reaches at 13.9 of 16, a turn of its one-chunk form
+    being 78 us (PERF.md, PR 54: a tie at these shapes, so the step stays
+    where PR 45 put it; the served rows touch 67 %, where the loop would be
+    ~0.25 ms a layer faster: ROADMAP S5).  The way is read off the SHAPES
+    (``expert_share.runs_every_held_expert``)."""
     blocks, experts = params["blocks"]["moe"], params["experts"]
     with jax.named_scope("mimo.moe"):
         sel, w = sigmoid_route(u, blocks["router"][i],

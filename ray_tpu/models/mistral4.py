@@ -403,10 +403,13 @@ def moe(u, live, params, i: int, cfg: Mistral4Config):
     (the router reads them as they are, the experts in ``cfg.dtype``), ``live
     [N]`` bool (a padded or idle row chooses nothing: it touches no held
     expert and is not counted) -> (``[N, d]`` float32, counts).  A decode step
-    of 32 slots (32 x 4 / 128 = 1.0 choices an expert) runs every held expert
-    in batched products, a prefill the loop over the touched ones: the way
-    is read off the SHAPES, never off the load
-    (``expert_share.runs_every_held_expert``)."""
+    of 32 slots (32 x 4 / 128 = 1.0 choices an expert: independent rows
+    touch 64 % of the held experts, the served ones 43-49 %) takes the loop
+    over the touched ones like a prefill, in its one-chunk form: a turn
+    reads ONE expert's 50 MB for the whole batch, 77 us on the v5e, where
+    the batched products over all sixteen took 1.14 ms a layer whatever was
+    chosen (PERF.md, PR 54).  The way is read off the SHAPES, never off the
+    load (``expert_share.runs_every_held_expert``)."""
     blocks, experts = params["blocks"], params["experts"]
     ud = u.astype(jnp.dtype(cfg.dtype))
     with jax.named_scope("mistral4.moe"):

@@ -431,20 +431,23 @@ def moe(u, live, params, i: int, cfg: NemotronHConfig):
     float32, counts).  The layer's output is the sum of the two.
 
     Which way the held experts run is read off the SHAPES, never off the
-    load.  When the rows fit one chunk and make a choice or more an expert
-    (``N k >= E``: a decode step of 64 slots, 64 x 22 / 512 = 2.75, nine in
-    ten of the held experts touched when the slots are full) every held
-    expert runs on every row in two batched products
+    load.  When the rows fit one chunk and would, each choosing on its own,
+    touch three in four of the held experts or more (a decode step of 64
+    slots, 64 x 22 / 512 = 2.75 choices an expert: 94 %, 86 % as served)
+    every held expert runs on every row in two batched products
     (``held_experts_dense``), which stream the layer's experts once at the
-    memory's speed: 2.8 ms a layer on the v5e whatever is live, where the
-    loop of ``expert_share.held_experts`` costs 0.9 ms + 23 us a touched
-    expert (3.7 ms with 124 touched, and ~600 turns a step, over which the
-    profiler could not stop).  A prefill takes the loop, whose cost hardly
-    grows with the rows up to 512 (4.7 ms at 256, 4.8 at 512; from 8,192 rows
-    it is the rows': ``expert_share.held_experts``) where the products' does
-    (3.6, 4.9, and an intermediate of 2.8 GB at the 2048 rung).  ``N`` is the
-    engine's slot count, so a replica of 64 slots with four callers streams
-    all its experts too, where the loop would take 1.4 ms (PERF.md, PR 39,
+    memory's speed: 1.91 ms a layer on the v5e whatever is live, where a
+    turn of ``expert_share.held_experts``' one-chunk form costs 19 us a
+    touched expert (2.10 ms with the 110 of 128 it serves, 2.44 with all;
+    under the products only below 100: PERF.md, PR 54; with its sort,
+    gather and scatter-add, before PR 54, 3.04 ms with all, and ~600 turns a
+    step, over which PR 39's profiler could not stop).  A prefill takes the
+    loop, whose cost hardly grows with the rows up to 512 (4.7 ms at 256,
+    4.8 at 512; from 8,192 rows it is the rows':
+    ``expert_share.held_experts``) where the products' does (3.6, 4.9, and
+    an intermediate of 2.8 GB at the 2048 rung).  ``N`` is the engine's slot
+    count, so a replica of 64 slots with four callers streams all its
+    experts too, where the loop would take a quarter of that (PERF.md, PR 39,
     has the timings).  Choosing by the live rows (``lax.cond`` on the touched
     count) would cure that and break what the engine promises: the two ways
     round differently (2e-3 of the result), so a request's greedy ids would

@@ -50,7 +50,8 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "relayout_ms.train",  # PR 50
         "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
         "top10_experts_touched_pct.serve",  # PR 52
-        "prefill_chunk_fill_pct.serve"]  # PR 53
+        "prefill_chunk_fill_pct.serve",  # PR 53
+        "held_loop_turns.serve"]  # PR 54
 
 
 def run(*command):

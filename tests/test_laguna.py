@@ -405,12 +405,15 @@ def test_the_band_meets_two_key_tiles_a_query_tile_whatever_the_length():
 
 
 # ------------------------------------------------------------------ experts
-@pytest.mark.parametrize("rows", [13, 150])
+@pytest.mark.parametrize("rows", [4, 13, 150])
 def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(weights, rows):
-    """Both ways the held experts run: 13 rows (a decode step's: one chunk
-    and a choice or more an expert, so every held expert runs on every row
-    in batched products) and 150 rows (a prefill's: the gather and the chunk
-    loop of ``expert_share.held_experts``).  The deployment's cut: each of
+    """The ways the held experts run: 13 rows (a decode step whose rows, each
+    on its own, would touch nearly every held expert: all of them run on
+    every row in batched products), 4 rows (a decode step whose rows would
+    touch two in three, as the cell's 32 x 10 / 256: the loop of
+    ``expert_share.held_experts`` in its one-chunk form, a turn a touched
+    expert on the whole batch) and 150 rows (a prefill's: the gather and the
+    chunk loop).  The deployment's cut: each of
     ``n_routed_experts / experts_held`` = four chips holds a quarter of the
     experts, routes over all sixteen, sums ITS experts' part x 2.5 and adds
     the shared expert.  The four held parts + the shared expert COUNTED ONCE
@@ -424,7 +427,8 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(weights, rows):
     w = jax.tree.map(lambda a: a[i], params["blocks"]["moe"])
     experts = jax.tree.map(lambda a: a[i], params["experts"])
     u = jax.random.normal(jax.random.PRNGKey(3), (rows, cfg.d_model))
-    live = jnp.arange(rows) != 4  # a padded row chooses no held expert
+    pad = min(4, rows - 2)
+    live = jnp.arange(rows) != pad  # a padded row chooses no held expert
     with jax.default_matmul_precision("highest"):
         want, chosen = ref.experts_layer(u[None], w, experts, sizes, 0)
         shared, _ = ref.experts_layer(
@@ -438,7 +442,8 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(weights, rows):
             u, live, part, i, share))(u)
         local = np.asarray(chosen)[0][np.asarray(live)] - offset
         held = (local >= 0) & (local < 4)
-        # the loop's turns and the rows they ran, counted by hand
+        # the loop's turns and the rows they ran, counted by hand (4 rows
+        # are one chunk: a turn is a touched expert)
         turns = sum(-(-int((local[held] == e).sum()) // chunk_rows(rows))
                     for e in range(4)) if rows != 13 else 0
         assert {k: int(v) for k, v in counts.items()} == {
@@ -453,7 +458,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(weights, rows):
         np.asarray(total)[np.asarray(live)],
         np.asarray(want[0])[np.asarray(live)], atol=F32_TOL)
     # the padded row: the shared expert alone (four times less three)
-    np.testing.assert_allclose(total[4], shared[0, 4], atol=F32_TOL)
+    np.testing.assert_allclose(total[pad], shared[0, pad], atol=F32_TOL)
     combine = sigmoid_route(u, w["router"], w["router_bias"], cfg.top_k,
                             cfg.routed_scaling_factor)[1]
     np.testing.assert_allclose(combine.sum(-1), 2.5, rtol=1e-5)
@@ -656,9 +661,9 @@ def test_the_harness_two_layer_cut_and_the_cells_draw(monkeypatch):
 # --------------------------------------------------------- older families
 @pytest.mark.parametrize("cfg,prefill_sha,decode_sha", [
     (MimoV2Config.tiny(), "16772087bf73326081b75dc93bfb0dfb9c4749de",
-     "a7066f3b7b053687fbdbe99da5f688f74691a6f4"),
+     "8bd0dfba3e02f082be71984ad01897d310c8c355"),
     (Mistral4Config.tiny(), "d979f58cccbca1cb44fa5733c4f251147805c04b",
-     "e93b66d22c8af753e809849320772ea857af816c")],
+     "7c629245dec20c29d937ccafeb35b4df04b77eeb")],
     ids=["mimo_v2", "mistral4"])
 def test_the_older_families_programs_lower_to_the_text_they_lowered_to(
     cfg, prefill_sha, decode_sha
@@ -671,7 +676,12 @@ def test_the_older_families_programs_lower_to_the_text_they_lowered_to(
     ``lower().as_text()``, PR 45's way, read on the parent's tree).  Read
     again on PR 53's tree, whose expert layers count their loop's chunks
     (``expert_share.loop_counts``) and whose sigmoid router picks its
-    chosen scores by a select (``expert_share.chosen_scores``)."""
+    chosen scores by a select (``expert_share.chosen_scores``).  The decode
+    steps read once more on PR 54's tree: four slots of these tiny configs
+    (4 x 4 choices for 16 experts, as the Mistral-4 cell's 32 x 4 for 128)
+    now take ``expert_share.held_experts`` in its one-chunk form, a turn a
+    touched expert on the whole batch; the prefills (64 rows: every held
+    expert in batched products) are the text they were."""
     fam = model_family(cfg)
     params = jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), cfg))
     cache = jax.eval_shape(lambda: fam.init_cache(cfg, 4, 1024))

@@ -503,6 +503,49 @@ def test_latent_long_decode_step_fits_and_its_top_rung_beside_it(
         ("f32[16384,4096]", "scatter")}
 
 
+def test_latent_long_decode_step_reads_a_touched_expert_where_it_lies(
+    cell_decode_step
+):
+    """The Mistral-4 cell's decode step takes the held experts' loop (32 x 4
+    choices for 128 experts: ``expert_share.runs_every_held_expert``) in its
+    one-chunk form: nine loops, one an expert layer, whose body is three
+    products on the whole batch.  Each product's fusion is handed the WHOLE
+    ``[9, 16, d, f]`` stack and slices its expert's matrix out by the loop's
+    own index, inside the fusion; the body makes nothing larger than
+    ``[32, 4096]`` float32; no computation of the program that is not a
+    fusion's own produces a stack, a layer's slice of one or an expert's
+    matrix (805 MB a layer copied would cost more than the step); and
+    nothing under the layer's scope moves by row or element index (the
+    sort, the gather and the scatter-add are the longer loops')."""
+    step, _, params = cell_decode_step("mistral_small4_l9_ep8")
+    text = step.as_text()
+    bodies = dict(re.findall(
+        r"^%([\w.-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+    loops = [bodies[name] for name in re.findall(
+        r"while\([^\n]*body=%([\w.-]+)", text)
+        if "mistral4.moe/while/body" in bodies[name]]
+    assert len(loops) == 9
+    stacks = {"bf16[9,16,4096,2048]", "bf16[9,16,2048,4096]"}
+    for body in loops:
+        products = re.findall(
+            r"= (\w+\[[\d,]*\])\S* fusion\([^\n]*calls=%([\w.-]+)"
+            r"[^\n]*dot_general", body)
+        assert len(products) == 3
+        for result, fused in products:
+            assert result in ("f32[32,2048]", "bf16[32,2048]", "f32[32,4096]")
+            taken = set(re.findall(
+                r"= (\w+\[[\d,]*\])\S* parameter\(", bodies[fused]))
+            assert taken & stacks and "dynamic-slice" in "".join(
+                bodies[name] for name in re.findall(
+                    r"calls=%([\w.-]+)", bodies[fused]))
+        made = [math.prod(map(int, dims.split(","))) for dims, op in re.findall(
+            r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* ([\w-]+)\(", body, re.M)
+            if op not in VIEWS]
+        assert max(made) == 32 * 4096
+    assert not copied_weights(text, params, entry_only=False)
+    assert moe_row_ops(text) == set()
+
+
 def test_ring_long_decode_step_fits_and_its_top_rung_beside_it(
     cell, cell_decode_step, on_chip
 ):
