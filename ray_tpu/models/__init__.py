@@ -90,6 +90,18 @@ from .nemotron_h_decode import (  # noqa: F401
     nemotron_h_init_cache,
     nemotron_h_prefill,
 )
+from .olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig,
+    olmo_hybrid_apply,
+    olmo_hybrid_init,
+    olmo_hybrid_loss,
+    olmo_hybrid_param_axes,
+)
+from .olmo_hybrid_decode import (  # noqa: F401
+    olmo_hybrid_decode_step,
+    olmo_hybrid_init_cache,
+    olmo_hybrid_prefill,
+)
 
 
 @_dataclasses.dataclass(frozen=True)
@@ -250,5 +262,22 @@ register_model_family(
         prefill_counted=_functools.partial(laguna_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             laguna_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    OlmoHybridConfig,
+    ModelFamily(
+        name="olmo_hybrid",
+        init=olmo_hybrid_init,
+        apply=olmo_hybrid_apply,
+        loss=olmo_hybrid_loss,
+        param_axes=olmo_hybrid_param_axes,
+        init_cache=olmo_hybrid_init_cache,
+        prefill=olmo_hybrid_prefill,
+        decode_step=olmo_hybrid_decode_step,
+        prefill_counted=_functools.partial(
+            olmo_hybrid_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            olmo_hybrid_decode_step, with_counts=True),
     ),
 )

@@ -1,0 +1,175 @@
+"""Olmo-Hybrid prefill + decode through a cache whose leaves are of two kinds.
+
+``{"k", "v": [Lf, B, H, T, D]}`` are the full layers' keys (after their norm)
+and values, with a position axis, as the Llama family's; ``{"conv": [Ll, B,
+(K-1)(2 H dk + H dv)], "state": [Ll, B, H / p, dk, p dv]}`` are the linear
+layers' state, float32, with NO position axis: the convolution's last ``K-1``
+inputs (oldest first, side by side, as Nemotron-H's) and the delta rule's
+matrix ``S [dk, dv]`` a head after the slot's last token, ``p`` heads side by
+side on the lanes (``olmo_hybrid.pack_state``: two heads of 192 are three
+tiles of 128, one alone would be padded to two).  The slot axis is axis 1 of
+every leaf, which is all ``llm/engine.py`` knows: ``init_cache(cfg, 1, rung)``
+gives a one-slot row whose state leaves do not depend on the rung, and
+``splice_row`` writes it over the slot's, so an admission replaces a slot's
+state WHOLE while its keys and values beyond the rung keep what the last
+tenant left (decode reads nothing at or beyond ``pos``).
+
+Prefill runs the chunked scan over the padded prompt with ``beta = 0`` and
+``g = 0`` at positions ``>= length``: the state it returns is the state at
+the prompt's TRUE length, whatever the rung, and the convolution's state is
+its last ``K-1`` true inputs.  Decode runs one step of the recurrence for all
+slots, in float32: one pass over ``S`` gives ``S^T k`` and ``S^T q`` (``o_t =
+alpha S^T q + (k . q) delta`` needs no second reading of the new state), one
+more writes ``alpha S + k (x) delta`` over the layer's slice of the stacked
+leaf where it lies (the engine donates the cache; ``tests/test_tpu_compile.py``
+reads the compiled step for a copy; the small ``conv`` leaf, of which every
+element moves every step, is built anew).  Attention goes by the
+deferred-scatter protocol of ``llama_decode.py``: the cache holds ``[0,
+pos-1]``, the current key and value are merged as a last score, and all are
+written at the step's end by ``write_token_to_cache``.
+
+A decode row at position 0 is an idle slot (a prompt has at least one
+token).  Its state is computed like any other's and stays finite: ``k`` is
+normalised and ``beta < 2``, so a step's map on ``S`` never expands.  Both
+return ``(logits, cache)``; with ``with_counts=True`` (the family's
+``*_counted`` twins, which the engine runs) ``(logits, cache, counts)``: the
+counts of ``olmo_hybrid.py`` as int32 scalars.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.decode_attention import decode_attention, write_token_to_cache
+from .llama import _rmsnorm
+from .longcat import matmul
+from .olmo_hybrid import (STACK, OlmoHybridConfig, attention_project, block,
+                          delta_output, delta_project, olmo_hybrid_forward,
+                          split_heads)
+
+
+def olmo_hybrid_init_cache(cfg: OlmoHybridConfig, batch: int, max_len: int):
+    nl, nf = cfg.kinds.count("L"), cfg.kinds.count("F")
+    kv = (nf, batch, cfg.n_head, max_len, cfg.head_dim)
+    dt, p = jnp.dtype(cfg.dtype), cfg.state_pack
+    return {
+        "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+        "conv": jnp.zeros((nl, batch, (cfg.conv_kernel - 1) * cfg.d_conv),
+                          jnp.float32),
+        "state": jnp.zeros(
+            (nl, batch, cfg.linear_num_heads // p, cfg.linear_key_head_dim,
+             p * cfg.linear_value_head_dim), jnp.float32),
+    }
+
+
+def olmo_hybrid_prefill(
+    params, tokens, lengths, cache, cfg: OlmoHybridConfig, *,
+    with_counts: bool = False
+) -> Tuple:
+    """tokens: [B, S] right-padded prompts; lengths: [B] true lengths.
+    Returns (last_logits [B, V], cache with keys and values of positions
+    [0, S) written and the state after position ``length - 1`` in place of
+    the slot's, counts of the positions scanned)."""
+    x, kept, counts = olmo_hybrid_forward(params, tokens, lengths, cfg)
+    cache = dict(cache)
+    for name, new in kept.items():
+        if name in ("k", "v"):  # [Lf, B, S, H, D] -> head-major
+            new = new.transpose(0, 1, 3, 2, 4)
+        cache[name] = jax.lax.dynamic_update_slice(
+            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
+    last = jnp.take_along_axis(
+        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    logits = matmul("be,ve->bv", last, params["lm_head"])
+    out = (logits, cache)
+    return (*out, counts) if with_counts else out
+
+
+def over_lanes(a, cfg: OlmoHybridConfig):
+    """A head's numbers beside its packed state: a ``[B, H, n]`` -> ``[B, H /
+    p, n, p dv]``, head ``j`` of a row's ``p`` on its own ``dv`` lanes.  A
+    select between broadcasts, so it fuses into whatever reads the state."""
+    p, dv = cfg.state_pack, cfg.linear_value_head_dim
+    b, h, n = a.shape
+    a = a.reshape(b, h // p, p, n)
+    head = jnp.arange(p * dv) // dv
+    out = a[:, :, 0, :, None]
+    for j in range(1, p):
+        out = jnp.where(head == j, a[:, :, j, :, None], out)
+    return jnp.broadcast_to(out, (b, h // p, n, p * dv))
+
+
+def delta_step(y, conv_state, state, m, i: int, cfg: OlmoHybridConfig):
+    """One token a row through linear layer ``i``.  y ``[B, d]``, conv_state
+    ``[B, (K-1)(2 H dk + H dv)]``, state ``[B, H / p, dk, p dv]`` -> (``[B, d]``
+    float32, the two states after the token, in the dtypes they came in)."""
+    h, dv = cfg.linear_num_heads, cfg.linear_value_head_dim
+    qkv, z, g, beta = delta_project(y, m, i, cfg)
+    window = jnp.concatenate(
+        [conv_state.astype(jnp.float32), qkv], axis=1)  # [B, K C]
+    conv = (window.reshape(-1, cfg.conv_kernel, cfg.d_conv)
+            * m["conv_w"][i]).sum(1)
+    q, k, v = split_heads(jax.nn.silu(conv), cfg)  # [B, H, dk], [B, H, dv]
+    alpha = jnp.exp(g)[..., None]  # [B, H, 1]
+    s = state.astype(jnp.float32)
+    k_lanes = over_lanes(k, cfg)
+    # S^T k and S^T q of the state as it came, a head: [B, H, dv]
+    sk = (s * k_lanes).sum(2).reshape(-1, h, dv)
+    sq = (s * over_lanes(q, cfg)).sum(2).reshape(-1, h, dv)
+    delta = beta[..., None] * (v - alpha * sk)
+    o = alpha * sq + (k * q).sum(-1, keepdims=True) * delta
+    new = (over_lanes(alpha, cfg) * s
+           + k_lanes * delta.reshape(s.shape[0], s.shape[1], 1, s.shape[3]))
+    return (delta_output(o, z, m, i, cfg),
+            window[:, cfg.d_conv:].astype(conv_state.dtype),
+            new.astype(state.dtype))
+
+
+def olmo_hybrid_decode_step(
+    params, tokens, pos, cache, cfg: OlmoHybridConfig, *,
+    with_counts: bool = False
+) -> Tuple:
+    """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
+    pos = jnp.asarray(pos)
+    blocks = params["blocks"]
+    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    cache = dict(cache)
+    new_conv, new_k, new_v = [], [], []
+    seen = dict.fromkeys(STACK, 0)
+    for kind in cfg.kinds:
+        i = seen[kind]
+        seen[kind] += 1
+
+        def delta(y):
+            out, conv, state = delta_step(
+                y, cache["conv"][i], cache["state"][i], blocks["linear"], i,
+                cfg)
+            new_conv.append(conv)
+            cache["state"] = cache["state"].at[i].set(state)
+            return out
+
+        def attend(y):
+            q, k, v = attention_project(y, blocks["full"], i, cfg)
+            new_k.append(k.astype(cache["k"].dtype))
+            new_v.append(v.astype(cache["v"].dtype))
+            o = decode_attention(q, cache["k"], cache["v"], pos, i,
+                                 k_self=new_k[-1], v_self=new_v[-1])
+            return matmul("bhd,hde->be", o.astype(y.dtype),
+                          blocks["full"]["wo"][i])
+
+        x = block(params, x, kind, i, delta if kind == "L" else attend, cfg)
+    if new_conv:
+        cache["conv"] = jnp.stack(new_conv)
+    if new_k:
+        cache["k"] = write_token_to_cache(
+            cache["k"], jnp.stack(new_k), pos, axis=3)
+        cache["v"] = write_token_to_cache(
+            cache["v"], jnp.stack(new_v), pos, axis=3)
+    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
+    logits = matmul("be,ve->bv", x, params["lm_head"])
+    out = (logits, cache)
+    counts = {"delta_positions": (pos > 0).sum().astype(jnp.int32),
+              "delta_chunk_positions": jnp.asarray(pos.shape[0], jnp.int32)}
+    return (*out, counts) if with_counts else out

@@ -54,9 +54,11 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
         "top10_experts_touched_pct.serve",  # PR 52
         "prefill_chunk_fill_pct.serve",  # PR 53
-        "held_loop_turns.serve"]  # PR 54
+        "held_loop_turns.serve",  # PR 54
+        "delta_decode_roofline.serve", "delta_chunk_fill_pct.serve"]  # PR 56
     assert cells == ["mistral4_ep8_longdoc_closed32",  # PR 48
-                     "laguna_ep16_code_closed32"]  # PR 52
+                     "laguna_ep16_code_closed32",  # PR 52
+                     "olmohybrid_l12_reason_closed64"]  # PR 56
 
 
 def run(*command):

@@ -45,13 +45,15 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
     theirs_load = theirs.load
     monkeypatch.setattr(theirs, "load", load_as_pr48_left_it)
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
-    assert cells == ["laguna_ep16_code_closed32"]  # PR 52
+    assert cells == ["laguna_ep16_code_closed32",  # PR 52
+                     "olmohybrid_l12_reason_closed64"]  # PR 56
     assert appended == [
         "relayout_ms.train",  # PR 50
         "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
         "top10_experts_touched_pct.serve",  # PR 52
         "prefill_chunk_fill_pct.serve",  # PR 53
-        "held_loop_turns.serve"]  # PR 54
+        "held_loop_turns.serve",  # PR 54
+        "delta_decode_roofline.serve", "delta_chunk_fill_pct.serve"]  # PR 56
 
 
 def run(*command):

@@ -206,7 +206,8 @@ def test_decode_attention_compiles_where_the_kernel_was_pinned(
 # The serving cells' configurations (benchmarks/configs/<name>.json).
 CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
                 "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16",
-                "mistral_small4_l9_ep8", "laguna_s21_l9_ep16"]
+                "mistral_small4_l9_ep8", "laguna_s21_l9_ep16",
+                "olmo_hybrid7b_l12"]
 
 
 @pytest.fixture(scope="module")
@@ -328,7 +329,8 @@ BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "nemotron3_super_l11_ep4": (1, 0.2e9),
                  "mimo_v25_l7_ep16": (2, 0.05e9),
                  "mistral_small4_l9_ep8": (9, 0.05e9),
-                 "laguna_s21_l9_ep16": (3, 0.05e9)}
+                 "laguna_s21_l9_ep16": (3, 0.05e9),
+                 "olmo_hybrid7b_l12": (3, 0.2e9)}
 
 
 @pytest.mark.parametrize("name", CELL_CONFIGS)
@@ -617,6 +619,81 @@ def test_ring_long_decode_step_fits_and_its_top_rung_beside_it(
     # 15.75 GB of the chip: arguments + the rung's temporaries + 0.26 held
     assert (memory.argument_size_in_bytes
             + rung.memory_analysis().temp_size_in_bytes) < 14.5e9
+
+
+def test_delta_decode_step_updates_its_packed_state_where_it_lies(
+    cell, cell_decode_step, on_chip
+):
+    """The Olmo-Hybrid cell's programs at its size (published widths, 12
+    layers ``FLLL x 3``, the whole vocabulary, 64 slots x 2048): the decode
+    step's arguments are the weights (6.54 GB) and a cache of 3 x 64 x 2048 x
+    15,360 B = 6.04 GB of keys and values beside 1.35 GB of state, all of it
+    aliased to the output.  The delta rule's state lies TWO heads to a row
+    (``f32[9,64,15,96,384]``, ``olmo_hybrid.pack_state``): a head's ``[96,
+    192]`` float32 matrix alone, either way up, is padded to 256 lanes (or
+    its 96 to 128) and a third more is held, read and written every step
+    (read off the compiler below: 1.333 against 1.000).  Every element of
+    the leaf changes every step, so the least a step can do is read it and
+    write it, where it lies: each linear layer is ONE fusion that reads its
+    slice for ``S^T k`` and ``S^T q`` (two results of ``[64, 15, 384]``) and
+    ONE rooted at the ``dynamic-update-slice`` of ``alpha S + k (x) delta``
+    into the donated leaf; nothing else produces an array of the leaf's
+    shape.  The four rungs are two layer bodies each, scanned
+    (``olmo_hybrid.layer_plan``): 12.6-16.9 MB of code a rung where the top
+    rung written out is 59 MB (the chip's compile cache holds ~190 MiB for
+    all cells' programs), 0.57 GB of temporaries at the top rung (1.08
+    written out), the weights read where they lie in the decode step's
+    layouts."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("olmo_hybrid7b_l12")
+    memory = step.memory_analysis()
+    assert cache["k"].shape == cache["v"].shape == (3, 64, 30, 2048, 128)
+    assert cache["state"].shape == (9, 64, 15, 96, 384)
+    assert cache["conv"].shape == (9, 64, 3 * 11520)
+    assert 13.9e9 < memory.argument_size_in_bytes < 13.95e9
+    assert 7.39e9 < memory.alias_size_in_bytes < 7.40e9  # the whole cache
+    assert memory.temp_size_in_bytes < 0.2e9  # 0.13 GB
+    assert memory.generated_code_size_in_bytes < 25e6
+    text = step.as_text()
+    shape = ",".join(map(str, cache["state"].shape))
+    # the leaf as the program holds it: last axis minor, no padding
+    assert f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}" in text
+    producers = re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M)
+    updates = [name for name, op in producers if op == "fusion"
+               and re.search("dynamic[-_]update[-_]slice", name)]
+    assert len(updates) == 9  # one a linear layer
+    assert {op for name, op in producers if name not in updates} <= {
+        "parameter", "get-tuple-element", "dynamic-update-slice"}
+    # what one head a row would cost, either way up
+    held = {}
+    for dims in ((64, 30, 96, 192), (64, 30, 192, 96), (64, 15, 96, 384)):
+        leaf = on_chip(jax.ShapeDtypeStruct(dims, jnp.float32))
+        held[dims] = jax.jit(lambda a: a * 2).lower(
+            leaf).compile().memory_analysis().argument_size_in_bytes
+    assert held[(64, 15, 96, 384)] == 64 * 15 * 96 * 384 * 4
+    assert held[(64, 30, 96, 192)] == held[(64, 30, 192, 96)] == (
+        64 * 30 * 96 * 256 * 4)
+    fam, cfg, _, _ = cell("olmo_hybrid7b_l12")
+    formats = step.input_formats[0][0]
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+    tokens = on_chip(jax.ShapeDtypeStruct((2048,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    rung = jit_prefill_one(fam, cfg).lower(
+        lying, cache, tokens, scalar, scalar).compile()
+    assert rung.input_formats[0][0] == formats
+    assert rung.memory_analysis().temp_size_in_bytes < 0.7e9
+    assert rung.memory_analysis().generated_code_size_in_bytes < 20e6
+    # the chunk's (I + A)^-1 is the TPU's own blocked inverse, once a
+    # linear layer's body: no loop of 64 rows
+    assert len(re.findall(
+        'custom_call_target="InvertDiagBlocksLowerTriangular"',
+        rung.as_text())) == 1
+    # 15.75 GB of the chip: arguments + the rung's temporaries + 0.26 held
+    assert (memory.argument_size_in_bytes
+            + rung.memory_analysis().temp_size_in_bytes) < 15.0e9
 
 
 # What hands an array on as it is, and what prefetches one into the chip's
