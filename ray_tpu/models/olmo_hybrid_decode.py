@@ -18,11 +18,13 @@ Prefill runs the chunked scan over the padded prompt with ``beta = 0`` and
 ``g = 0`` at positions ``>= length``: the state it returns is the state at
 the prompt's TRUE length, whatever the rung, and the convolution's state is
 its last ``K-1`` true inputs.  Decode runs one step of the recurrence for all
-slots, in float32: one pass over ``S`` gives ``S^T k`` and ``S^T q`` (``o_t =
-alpha S^T q + (k . q) delta`` needs no second reading of the new state), one
-more writes ``alpha S + k (x) delta`` over the layer's slice of the stacked
-leaf where it lies (the engine donates the cache; ``tests/test_tpu_compile.py``
-reads the compiled step for a copy; the small ``conv`` leaf, of which every
+slots, in float32, in ONE pass over ``S`` (``ops.delta_update``: on a TPU a
+Pallas kernel that holds a slot's rows in fast memory for ``S^T k``, ``S^T q``
+and ``alpha S + k (x) delta``; ``o_t = alpha S^T q + (k . q) delta`` needs no
+reading of the new state), written over the layer's blocks of the stacked
+leaf where they lie: the whole leaf goes through the nine calls, aliased (the
+engine donates the cache; ``tests/test_tpu_compile.py`` reads the compiled
+step for a copy or a slice; the small ``conv`` leaf, of which every
 element moves every step, is built anew).  Attention goes by the
 deferred-scatter protocol of ``llama_decode.py``: the cache holds ``[0,
 pos-1]``, the current key and value are merged as a last score, and all are
@@ -44,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import decode_attention, write_token_to_cache
+from ..ops.delta_update import delta_update
 from .llama import _rmsnorm
 from .longcat import matmul
 from .olmo_hybrid import (STACK, OlmoHybridConfig, attention_project, block,
@@ -87,44 +90,31 @@ def olmo_hybrid_prefill(
     return (*out, counts) if with_counts else out
 
 
-def over_lanes(a, cfg: OlmoHybridConfig):
-    """A head's numbers beside its packed state: a ``[B, H, n]`` -> ``[B, H /
-    p, n, p dv]``, head ``j`` of a row's ``p`` on its own ``dv`` lanes.  A
-    select between broadcasts, so it fuses into whatever reads the state."""
-    p, dv = cfg.state_pack, cfg.linear_value_head_dim
-    b, h, n = a.shape
-    a = a.reshape(b, h // p, p, n)
-    head = jnp.arange(p * dv) // dv
-    out = a[:, :, 0, :, None]
-    for j in range(1, p):
-        out = jnp.where(head == j, a[:, :, j, :, None], out)
-    return jnp.broadcast_to(out, (b, h // p, n, p * dv))
-
-
-def delta_step(y, conv_state, state, m, i: int, cfg: OlmoHybridConfig):
-    """One token a row through linear layer ``i``.  y ``[B, d]``, conv_state
-    ``[B, (K-1)(2 H dk + H dv)]``, state ``[B, H / p, dk, p dv]`` -> (``[B, d]``
-    float32, the two states after the token, in the dtypes they came in)."""
-    h, dv = cfg.linear_num_heads, cfg.linear_value_head_dim
+def delta_step_at(y, conv_state, leaf, at: int, m, i: int,
+                  cfg: OlmoHybridConfig):
+    """One token a row through linear layer ``i``, whose state is layer ``at``
+    of the stacked ``leaf [layers, B, H / p, dk, p dv]``.  y ``[B, d]``,
+    conv_state ``[B, (K-1)(2 H dk + H dv)]`` -> (``[B, d]`` float32, the
+    convolution's state after the token in the dtype it came in, the leaf
+    with layer ``at`` updated where it lies: ``ops.delta_update``)."""
     qkv, z, g, beta = delta_project(y, m, i, cfg)
     window = jnp.concatenate(
         [conv_state.astype(jnp.float32), qkv], axis=1)  # [B, K C]
     conv = (window.reshape(-1, cfg.conv_kernel, cfg.d_conv)
             * m["conv_w"][i]).sum(1)
     q, k, v = split_heads(jax.nn.silu(conv), cfg)  # [B, H, dk], [B, H, dv]
-    alpha = jnp.exp(g)[..., None]  # [B, H, 1]
-    s = state.astype(jnp.float32)
-    k_lanes = over_lanes(k, cfg)
-    # S^T k and S^T q of the state as it came, a head: [B, H, dv]
-    sk = (s * k_lanes).sum(2).reshape(-1, h, dv)
-    sq = (s * over_lanes(q, cfg)).sum(2).reshape(-1, h, dv)
-    delta = beta[..., None] * (v - alpha * sk)
-    o = alpha * sq + (k * q).sum(-1, keepdims=True) * delta
-    new = (over_lanes(alpha, cfg) * s
-           + k_lanes * delta.reshape(s.shape[0], s.shape[1], 1, s.shape[3]))
+    o, leaf = delta_update(leaf, at, q, k, v, jnp.exp(g)[..., None],
+                           beta[..., None])
     return (delta_output(o, z, m, i, cfg),
-            window[:, cfg.d_conv:].astype(conv_state.dtype),
-            new.astype(state.dtype))
+            window[:, cfg.d_conv:].astype(conv_state.dtype), leaf)
+
+
+def delta_step(y, conv_state, state, m, i: int, cfg: OlmoHybridConfig):
+    """``delta_step_at`` on ONE layer's state ``[B, H / p, dk, p dv]`` (a
+    stack of one: the same kernel) -> (``[B, d]`` float32, the two states
+    after the token, in the dtypes they came in)."""
+    out, conv, leaf = delta_step_at(y, conv_state, state[None], 0, m, i, cfg)
+    return out, conv, leaf[0]
 
 
 def olmo_hybrid_decode_step(
@@ -143,11 +133,10 @@ def olmo_hybrid_decode_step(
         seen[kind] += 1
 
         def delta(y):
-            out, conv, state = delta_step(
-                y, cache["conv"][i], cache["state"][i], blocks["linear"], i,
+            out, conv, cache["state"] = delta_step_at(
+                y, cache["conv"][i], cache["state"], i, blocks["linear"], i,
                 cfg)
             new_conv.append(conv)
-            cache["state"] = cache["state"].at[i].set(state)
             return out
 
         def attend(y):

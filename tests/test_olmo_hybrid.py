@@ -7,6 +7,7 @@ Each tolerance says what it allows for.
 """
 
 import dataclasses
+import functools
 import inspect
 
 import jax
@@ -20,6 +21,7 @@ from benchmarks.reference import olmo_hybrid_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
 from ray_tpu.models import (OlmoHybridConfig, model_family, olmo_hybrid,
                             olmo_hybrid_decode, olmo_hybrid_init)
+from ray_tpu.ops import delta_update
 
 # float32 against float32: the two differ by the order of their sums only
 # (the chunked scan and its triangular solve against the recurrence,
@@ -137,8 +139,21 @@ def rel_rms(got, want):
     return float(err.max())
 
 
+@pytest.fixture(params=["xla", "kernel"])
+def state_update(request, monkeypatch):
+    """The decode step's way through a linear layer's state: what the CPU
+    runs unasked (``ops.delta_update``'s XLA formulation), then the Pallas
+    kernel a TPU runs, forced here in interpret mode."""
+    if request.param == "kernel":
+        monkeypatch.setattr(
+            olmo_hybrid_decode, "delta_update", functools.partial(
+                delta_update.delta_update, force_pallas=True))
+    return request.param
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_prefill_then_decode_through_the_cache_matches_full_forward(dtype):
+def test_prefill_then_decode_through_the_cache_matches_full_forward(
+        dtype, state_update):
     cfg = tiny(dtype=dtype)
     params = weights_of(cfg, seed=1)
     lengths, steps = [5, 9, 14], 8
@@ -313,7 +328,7 @@ def test_the_packed_state_is_the_heads_states_side_by_side():
     np.testing.assert_array_equal(packed[:, 1, :, 64:], state[:, 3])
     np.testing.assert_array_equal(olmo_hybrid.unpack_state(packed, cfg), state)
     per_head = jnp.asarray(rng.normal(size=(3, 4, 8)), jnp.float32)
-    lanes = olmo_hybrid_decode.over_lanes(per_head, cfg)
+    lanes = delta_update.over_lanes(per_head, cfg.state_pack, 64)
     np.testing.assert_array_equal(
         olmo_hybrid.unpack_state(lanes, cfg),
         np.broadcast_to(per_head[..., None], (3, 4, 8, 64)))
@@ -444,7 +459,7 @@ def test_engine_slots_hold_a_matrix_of_state_beside_keys_and_values():
     full.shutdown()
 
 
-def test_idle_slots_stay_finite_through_two_hundred_steps():
+def test_idle_slots_stay_finite_through_two_hundred_steps(state_update):
     """Every slot is decoded every step, tenant or not: the state of the
     slots nobody occupies (token 0 at position 0, over and over, on whatever
     the last tenant left) must stay finite for a whole run: a step's map on

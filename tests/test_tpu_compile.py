@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, delta_update
 from ray_tpu.ops.decode_attention import decode_attention, tile_positions
 
 
@@ -259,7 +259,10 @@ def cell_decode_step(cell, on_chip):
         slots = jax.tree.leaves(cache)[0].shape[1]
         rows = on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32))
         step = jit_decode_step(fam, cfg, params)
-        return step.lower(params, cache, rows, rows).compile(), cache, params
+        with pytest.MonkeyPatch.context() as patch:  # dispatch asks the CPU
+            patch.setattr(attention, "_on_tpu", lambda: True)
+            lowered = step.lower(params, cache, rows, rows)
+        return lowered.compile(), cache, params
 
     return compiled
 
@@ -634,11 +637,15 @@ def test_delta_decode_step_updates_its_packed_state_where_it_lies(
     its 96 to 128) and a third more is held, read and written every step
     (read off the compiler below: 1.333 against 1.000).  Every element of
     the leaf changes every step, so the least a step can do is read it and
-    write it, where it lies: each linear layer is ONE fusion that reads its
-    slice for ``S^T k`` and ``S^T q`` (two results of ``[64, 15, 384]``) and
-    ONE rooted at the ``dynamic-update-slice`` of ``alpha S + k (x) delta``
-    into the donated leaf; nothing else produces an array of the leaf's
-    shape.  The four rungs are two layer bodies each, scanned
+    write it, where it lies, and that is what it does: each linear layer is
+    ONE Pallas kernel (``ops/delta_update.py``) whose operand and result are
+    the WHOLE donated leaf, aliased, and whose blocks are that layer's
+    slots: ``S^T k``, ``S^T q`` and ``alpha S + k (x) delta`` on a block
+    held in fast memory (XLA's own step was a reduce fusion over the layer's
+    slice and a second fusion, rooted at the ``dynamic-update-slice``, that
+    read it again: PR 56).  Nothing else produces or reads an array of the
+    leaf's or of a layer's shape: no slice, no ``dynamic-update-slice``, no
+    ``copy``.  The four rungs are two layer bodies each, scanned
     (``olmo_hybrid.layer_plan``): 12.6-16.9 MB of code a rung where the top
     rung written out is 59 MB (the chip's compile cache holds ~190 MiB for
     all cells' programs), 0.57 GB of temporaries at the top rung (1.08
@@ -653,19 +660,31 @@ def test_delta_decode_step_updates_its_packed_state_where_it_lies(
     assert cache["conv"].shape == (9, 64, 3 * 11520)
     assert 13.9e9 < memory.argument_size_in_bytes < 13.95e9
     assert 7.39e9 < memory.alias_size_in_bytes < 7.40e9  # the whole cache
-    assert memory.temp_size_in_bytes < 0.2e9  # 0.13 GB
+    assert memory.temp_size_in_bytes < 0.2e9  # 0.11 GB
     assert memory.generated_code_size_in_bytes < 25e6
     text = step.as_text()
     shape = ",".join(map(str, cache["state"].shape))
     # the leaf as the program holds it: last axis minor, no padding
-    assert f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}" in text
-    producers = re.findall(
-        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M)
-    updates = [name for name, op in producers if op == "fusion"
-               and re.search("dynamic[-_]update[-_]slice", name)]
-    assert len(updates) == 9  # one a linear layer
-    assert {op for name, op in producers if name not in updates} <= {
-        "parameter", "get-tuple-element", "dynamic-update-slice"}
+    leaf = re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}")
+    assert re.search(leaf, text)
+    # nine kernels, each from the leaf (the parameter, then the kernel
+    # before it) to the leaf, in the same bytes; the layer is their first
+    # operand, a constant of each call of the ONE lowered kernel
+    kernels = re.findall(
+        rf"^\s*%(\S+) = \({leaf}, [^\n]*?\) custom-call\(%constant[\w.]*, "
+        r'%([\w.-]+), [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        + re.escape("output_to_operand_aliasing={{0}: (1, {})}"), text, re.M)
+    assert len(kernels) == 9 == text.count("tpu_custom_call")
+    producers = dict(re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M))
+    assert set(producers.values()) == {"parameter", "get-tuple-element"}
+    assert {operand for _, operand in kernels} <= set(producers)
+    # no instruction makes or reads a layer's [64, 15, 96, 384] of it, and
+    # none copies or updates a slice of the leaf
+    assert "15,96,384]" not in text.replace(f"[{shape}]", "")
+    assert not [line for line in text.splitlines()
+                if f"[{shape}]" in line and re.search(
+                    r" (copy|fusion|dynamic-update-slice|slice)\(", line)]
     # what one head a row would cost, either way up
     held = {}
     for dims in ((64, 30, 96, 192), (64, 30, 192, 96), (64, 15, 96, 384)):
@@ -694,6 +713,67 @@ def test_delta_decode_step_updates_its_packed_state_where_it_lies(
     # 15.75 GB of the chip: arguments + the rung's temporaries + 0.26 held
     assert (memory.argument_size_in_bytes
             + rung.memory_analysis().temp_size_in_bytes) < 15.0e9
+
+
+@pytest.mark.parametrize("layers,at", [(1, 0), (9, 4)],
+                         ids=["one_layer", "layer_4_of_the_stack"])
+def test_delta_update_alone_is_one_kernel_over_the_donated_leaf(
+    on_chip, as_if_on_tpu, layers, at
+):
+    """``ops.delta_update`` at the published widths and the cell's 64 slots,
+    on its own: a stack of one (what ``olmo_hybrid_decode.delta_step`` makes
+    of one layer's state, as ``benchmarks/olmo_hybrid_all_layers.py
+    --time-delta`` donates it) and layer 4 of the cell's nine.  ONE custom
+    call from the donated leaf to itself, no temporary, no copy of the
+    state, the small operands a few megabytes beside it."""
+    slots, heads, dk, dv = 64, 30, 96, 192
+    leaf, q, v, scalar = (
+        on_chip(jax.ShapeDtypeStruct(dims, jnp.float32)) for dims in (
+            (layers, slots, heads // 2, dk, 2 * dv), (slots, heads, dk),
+            (slots, heads, dv), (slots, heads, 1)))
+    step = jax.jit(
+        lambda leaf, *small: delta_update.delta_update(leaf, at, *small),
+        donate_argnums=(0,)).lower(leaf, q, q, v, scalar, scalar).compile()
+    memory = step.memory_analysis()
+    state = layers * slots * heads * dk * dv * 4
+    assert memory.alias_size_in_bytes == state
+    assert memory.temp_size_in_bytes == 0
+    assert memory.argument_size_in_bytes < state + 8e6
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+    shape = f"[{layers},{slots},{heads // 2},{dk},{2 * dv}]"
+    assert set(re.findall(  # the kernel's own result is a tuple
+        rf"= f32{re.escape(shape)}\S* ([\w-]+)\(", text)) == {
+            "parameter", "get-tuple-element"}
+
+
+def test_a_steps_linear_layers_are_calls_of_one_lowered_kernel(
+    on_chip, as_if_on_tpu
+):
+    """The layer is the kernel's prefetched operand, not a constant of its
+    index maps, and ``delta_update._call`` a jitted function: three layers
+    lower to ONE ``tpu_custom_call`` called three times (a kernel's fifteen
+    unrolled rows are ~0.2 s of tracing and lowering, which nine constants
+    paid nine times at every start of a replica) and compile to three, each
+    over the leaf where it lies."""
+    leaf, q, v, scalar = (
+        on_chip(jax.ShapeDtypeStruct(dims, jnp.float32)) for dims in (
+            (3, 8, 15, 96, 384), (8, 30, 96), (8, 30, 192), (8, 30, 1)))
+
+    def three(leaf, *small):
+        outs = []
+        for at in range(3):
+            o, leaf = delta_update.delta_update(leaf, at, *small)
+            outs.append(o)
+        return outs, leaf
+
+    lowered = jax.jit(three, donate_argnums=(0,)).lower(
+        leaf, q, q, v, scalar, scalar)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 # What hands an array on as it is, and what prefetches one into the chip's
