@@ -84,6 +84,8 @@ class LLMServer:
         return path
 
     def stop_profile(self) -> None:
+        """End the session; ``<path>/programs.jsonl`` (this replica's
+        programs' instruction -> ``op_name`` tables) is written after it."""
         tracing.stop_profile()
 
     def device_info(self) -> Dict[str, Any]:

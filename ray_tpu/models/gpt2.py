@@ -12,6 +12,11 @@ TPU-first design decisions:
   - ``remat=True`` wraps each layer in ``jax.checkpoint`` to trade FLOPs
     for HBM;
   - bf16 activations/params with f32 layernorm + softmax accumulation.
+
+Device operations carry ``jax.named_scope``s ``gpt2.embed``, ``gpt2.attn``
+(first norm to the residual), ``gpt2.mlp`` and ``gpt2.head`` (final norm,
+logits, cross-entropy); a backward pass keeps them inside ``jvp(...)`` /
+``transpose(jvp(...))`` (docs/observability.md, "Which part of the model").
 """
 
 from __future__ import annotations
@@ -175,23 +180,25 @@ def _block(x, layer, cfg: GPT2Config, mesh):
     # and no remat (stored-activation reads dominate a bandwidth-poor bwd).
     from jax.ad_checkpoint import checkpoint_name as _ckpt_name
 
-    y = _layernorm(x, layer["ln1_g"], layer["ln1_b"])
-    qkv = jnp.einsum("bse,ethd->bsthd", y, layer["wqkv"]) + layer["bqkv"]
-    qkv = _ckpt_name(qkv, "qkv")
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    q = wlc(q, P("batch", "seq", "heads", "kv"), mesh)
-    k = wlc(k, P("batch", "seq", "heads", "kv"), mesh)
-    v = wlc(v, P("batch", "seq", "heads", "kv"), mesh)
-    o = _attention(q, k, v, cfg, mesh)
-    o = _ckpt_name(o, "attn_out")
-    x = x + (jnp.einsum("bshd,hde->bse", o, layer["wo"]) + layer["bo"]).astype(x.dtype)
-    x = _ckpt_name(x, "attn_resid")
-    y = _layernorm(x, layer["ln2_g"], layer["ln2_b"])
-    hdn = jax.nn.gelu(jnp.einsum("bse,ef->bsf", y, layer["wi"]) + layer["bi"])
-    hdn = _ckpt_name(hdn, "mlp_hidden")
-    hdn = wlc(hdn, P("batch", "seq", "mlp"), mesh)
-    x = x + (jnp.einsum("bsf,fe->bse", hdn, layer["wo2"]) + layer["bo2"]).astype(x.dtype)
-    return wlc(x, P("batch", "seq", "act_embed"), mesh)
+    with jax.named_scope("gpt2.attn"):
+        y = _layernorm(x, layer["ln1_g"], layer["ln1_b"])
+        qkv = jnp.einsum("bse,ethd->bsthd", y, layer["wqkv"]) + layer["bqkv"]
+        qkv = _ckpt_name(qkv, "qkv")
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        q = wlc(q, P("batch", "seq", "heads", "kv"), mesh)
+        k = wlc(k, P("batch", "seq", "heads", "kv"), mesh)
+        v = wlc(v, P("batch", "seq", "heads", "kv"), mesh)
+        o = _attention(q, k, v, cfg, mesh)
+        o = _ckpt_name(o, "attn_out")
+        x = x + (jnp.einsum("bshd,hde->bse", o, layer["wo"]) + layer["bo"]).astype(x.dtype)
+        x = _ckpt_name(x, "attn_resid")
+    with jax.named_scope("gpt2.mlp"):
+        y = _layernorm(x, layer["ln2_g"], layer["ln2_b"])
+        hdn = jax.nn.gelu(jnp.einsum("bse,ef->bsf", y, layer["wi"]) + layer["bi"])
+        hdn = _ckpt_name(hdn, "mlp_hidden")
+        hdn = wlc(hdn, P("batch", "seq", "mlp"), mesh)
+        x = x + (jnp.einsum("bsf,fe->bse", hdn, layer["wo2"]) + layer["bo2"]).astype(x.dtype)
+        return wlc(x, P("batch", "seq", "act_embed"), mesh)
 
 
 def gpt2_hidden(params, tokens, cfg: GPT2Config, mesh=None):
@@ -205,9 +212,10 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config, mesh=None):
     # below is a free slice.  Gathering from the fsdp-sharded table instead
     # makes SPMD reshard the gather output embed→batch, which it can only do
     # by full rematerialization (round-1 MULTICHIP finding).
-    wte = wlc(params["wte"], P(None, "act_embed"), mesh)
-    x = wte[tokens] + params["wpe"][:s][None]
-    x = wlc(x, P("batch", "seq", "act_embed"), mesh)
+    with jax.named_scope("gpt2.embed"):
+        wte = wlc(params["wte"], P(None, "act_embed"), mesh)
+        x = wte[tokens] + params["wpe"][:s][None]
+        x = wlc(x, P("batch", "seq", "act_embed"), mesh)
 
     block = functools.partial(_block, cfg=cfg, mesh=mesh)
     if cfg.remat:
@@ -249,7 +257,8 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config, mesh=None):
         return block(x, layer), None
 
     x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-    return _layernorm(x, params["lnf_g"], params["lnf_b"])
+    with jax.named_scope("gpt2.head"):  # the final norm is the head's
+        return _layernorm(x, params["lnf_g"], params["lnf_b"])
 
 
 def gpt2_apply(params, tokens, cfg: GPT2Config, mesh=None):
@@ -257,8 +266,9 @@ def gpt2_apply(params, tokens, cfg: GPT2Config, mesh=None):
     from ..parallel.sharding import with_logical_constraint as wlc
 
     x = gpt2_hidden(params, tokens, cfg, mesh)
-    logits = jnp.einsum("bse,ve->bsv", x, params["wte"])
-    return wlc(logits, P("batch", "seq", "vocab"), mesh)
+    with jax.named_scope("gpt2.head"):
+        logits = jnp.einsum("bse,ve->bsv", x, params["wte"])
+        return wlc(logits, P("batch", "seq", "vocab"), mesh)
 
 
 def _ce_from_logits(logits, targets, z_loss: float):
@@ -293,25 +303,26 @@ def gpt2_loss(
             "(silently falling back would materialize the full [B,S,V] "
             "logits the caller asked to avoid)"
         )
-    if ce_chunks <= 1:
-        logits = jnp.einsum("bse,ve->bsv", x, params["wte"])
-        from ..parallel.sharding import with_logical_constraint as wlc
+    with jax.named_scope("gpt2.head"):  # logits and cross-entropy
+        if ce_chunks <= 1:
+            logits = jnp.einsum("bse,ve->bsv", x, params["wte"])
+            from ..parallel.sharding import with_logical_constraint as wlc
 
-        logits = wlc(logits, P("batch", "seq", "vocab"), mesh)
-        return _ce_from_logits(logits, targets, z_loss) / (b * s)
+            logits = wlc(logits, P("batch", "seq", "vocab"), mesh)
+            return _ce_from_logits(logits, targets, z_loss) / (b * s)
 
-    c = s // ce_chunks
-    xs = x.reshape(b, ce_chunks, c, e).swapaxes(0, 1)  # [n, B, C, E]
-    ts = targets.reshape(b, ce_chunks, c).swapaxes(0, 1)
+        c = s // ce_chunks
+        xs = x.reshape(b, ce_chunks, c, e).swapaxes(0, 1)  # [n, B, C, E]
+        ts = targets.reshape(b, ce_chunks, c).swapaxes(0, 1)
 
-    @jax.checkpoint
-    def chunk_nll(wte, x_c, t_c):
-        logits = jnp.einsum("bce,ve->bcv", x_c, wte)
-        return _ce_from_logits(logits, t_c, z_loss)
+        @jax.checkpoint
+        def chunk_nll(wte, x_c, t_c):
+            logits = jnp.einsum("bce,ve->bcv", x_c, wte)
+            return _ce_from_logits(logits, t_c, z_loss)
 
-    def body(acc, xt):
-        x_c, t_c = xt
-        return acc + chunk_nll(params["wte"], x_c, t_c), None
+        def body(acc, xt):
+            x_c, t_c = xt
+            return acc + chunk_nll(params["wte"], x_c, t_c), None
 
-    total, _ = jax.lax.scan(body, jnp.float32(0.0), (xs, ts))
-    return total / (b * s)
+        total, _ = jax.lax.scan(body, jnp.float32(0.0), (xs, ts))
+        return total / (b * s)

@@ -60,8 +60,12 @@ Parameters: ``params["blocks"]`` holds one layer-stack a KIND of layer
 (``full`` with ``wq [Lf, d, Hf, D]``, ``window`` with ``[Lw, d, Hw, D]``,
 ``dense``, ``moe``), each as long as the patterns have layers of that kind; a
 model of fewer layers reads the front of each stack.  Device operations
-carry ``jax.named_scope``s ``laguna.attn_full``, ``laguna.attn_window``,
-``laguna.moe``, ``laguna.shared`` and ``laguna.mlp``.  Routing is counted in
+carry ``jax.named_scope``s ``laguna.embed`` (the token gather, and the
+positions and masks a program makes once from its inputs),
+``laguna.attn_full``, ``laguna.attn_window`` (norm to residual, and the cache
+write), ``laguna.moe`` (norm, router, held experts, counts), ``laguna.shared``
+(the shared expert and the residual), ``laguna.mlp`` and ``laguna.head``
+(final norm + vocabulary product).  Routing is counted in
 the program: ``routed_total`` (choices made by live tokens), ``routed_held``
 (those on experts held here) and ``experts_touched`` (distinct held experts
 a layer ran, summed over layers).
@@ -322,8 +326,8 @@ def moe(u, live, params, i, cfg: LagunaConfig):
     whatever was chosen (PERF.md, PR 54).  The way is read off the SHAPES,
     never off the load (``expert_share.runs_every_held_expert``)."""
     blocks, experts = params["blocks"]["moe"], params["experts"]
-    ud = u.astype(jnp.dtype(cfg.dtype))
     with jax.named_scope("laguna.moe"):
+        ud = u.astype(jnp.dtype(cfg.dtype))
         sel, w = sigmoid_route(u, blocks["router"][i],
                                blocks["router_bias"][i], cfg.top_k,
                                cfg.routed_scaling_factor)
@@ -340,12 +344,13 @@ def moe(u, live, params, i, cfg: LagunaConfig):
     with jax.named_scope("laguna.shared"):
         y = y + ffn(ud, blocks["w_gate"][i], blocks["w_up"][i],
                     blocks["w_down"][i])
-    return y, {  # int32 scalars
-        "routed_total": live.sum() * cfg.top_k,
-        "routed_held": held.sum(),
-        "experts_touched": hit.any(0).sum(),
-        **loop_counts(hit, looped=not dense),
-    }
+    with jax.named_scope("laguna.moe"):
+        return y, {  # int32 scalars
+            "routed_total": live.sum() * cfg.top_k,
+            "routed_held": held.sum(),
+            "experts_touched": hit.any(0).sum(),
+            **loop_counts(hit, looped=not dense),
+        }
 
 
 # -------------------------------------------------------------------- model
@@ -375,10 +380,12 @@ def block(params, x, live, kinds: str, i, j, attend, cfg: LagunaConfig):
             u = _rmsnorm(x, dense["rms"][j], cfg.rms_eps).astype(dt)
             return x + ffn(u, dense["w_gate"][j], dense["w_up"][j],
                            dense["w_down"][j]), None
-    u = _rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # float32
-    y, counts = moe(u.reshape(-1, u.shape[-1]), live.reshape(-1), params, j,
-                    cfg)
-    return x + y.reshape(x.shape), counts
+    with jax.named_scope("laguna.moe"):
+        u = _rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # float32
+        u, live = u.reshape(-1, u.shape[-1]), live.reshape(-1)
+    y, counts = moe(u, live, params, j, cfg)
+    with jax.named_scope("laguna.shared"):  # the sum's last term
+        return x + y.reshape(x.shape), counts
 
 
 def layer_runs(cfg: LagunaConfig):
@@ -439,10 +446,11 @@ def laguna_forward(params, tokens, lengths, cfg: LagunaConfig):
     that differ (three bodies for the published nine layers or for all 48;
     seven rungs are compiled a replica) and a rung's temporaries are one
     layer's."""
-    x = params["wte"][tokens].astype(jnp.float32)
-    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    live = positions[None] < lengths[:, None]
-    longest = jnp.max(lengths)
+    with jax.named_scope("laguna.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)
+        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        live = positions[None] < lengths[:, None]
+        longest = jnp.max(lengths)
 
     def one_run(carry, run, period, strides):
         """The ``layers`` layers of one run in period ``period`` of its
@@ -469,7 +477,8 @@ def laguna_forward(params, tokens, lengths, cfg: LagunaConfig):
 
             x, counts = block(params, x, live, kinds, i, j, attend, cfg)
             if counts is not None:
-                total = add_counts(total, counts)
+                with jax.named_scope("laguna.moe"):
+                    total = add_counts(total, counts)
             return (x, total), tuple(held)
 
         return scan_or_call(one_layer, carry, layers)
@@ -491,15 +500,19 @@ def laguna_forward(params, tokens, lengths, cfg: LagunaConfig):
 
         carry, held = scan_or_call(one_period, carry, repeats)
         for kind, kv in held.items():  # [repeats, layers of the kind, ...]
-            kept[kind].append(tuple(a.reshape((-1,) + a.shape[2:])
-                                    for a in kv))
+            with jax.named_scope("laguna.attn_" + STACK[kind]):
+                kept[kind].append(tuple(a.reshape((-1,) + a.shape[2:])
+                                        for a in kv))
 
     x, total = carry
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("laguna.head"):  # the final norm is the head's
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
     cache = {}
     for kind, names in LEAVES.items():
-        for name, parts in zip(names, zip(*kept[kind])):
-            cache[name] = jnp.concatenate(parts)
+        with jax.named_scope("laguna.attn_" + STACK[kind]):
+            for name, parts in zip(names, zip(*kept[kind])):
+                cache[name] = jnp.concatenate(parts)
     return x, cache, total
 
 
@@ -512,7 +525,8 @@ def laguna_apply(params, tokens, cfg: LagunaConfig, mesh=None):
             "laguna runs one chip's share of a layer; no mesh yet")
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = laguna_forward(params, tokens, lengths, cfg)
-    return matmul("bse,ve->bsv", x, params["lm_head"])
+    with jax.named_scope("laguna.head"):
+        return matmul("bse,ve->bsv", x, params["lm_head"])
 
 
 def laguna_loss(params, tokens, cfg: LagunaConfig, mesh=None):

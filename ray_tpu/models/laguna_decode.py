@@ -41,8 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import decode_attention, write_token_to_cache
-from .laguna import (COUNT_NAMES, LEAVES, LagunaConfig, attention_project,
-                     block, laguna_forward)
+from .laguna import (COUNT_NAMES, LEAVES, STACK, LagunaConfig,
+                     attention_project, block, laguna_forward)
 from .llama import _rmsnorm
 from .longcat import add_counts, matmul
 
@@ -69,12 +69,17 @@ def laguna_prefill(
     length)."""
     x, kept, counts = laguna_forward(params, tokens, lengths, cfg)
     cache = dict(cache)
-    for name, new in kept.items():
-        cache[name] = jax.lax.dynamic_update_slice(
-            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = matmul("be,ve->bv", last, params["lm_head"])
+    for kind, names in LEAVES.items():
+        with jax.named_scope("laguna.attn_" + STACK[kind]):
+            for name in names:
+                if name in kept:
+                    cache[name] = jax.lax.dynamic_update_slice(
+                        cache[name], kept[name].astype(cache[name].dtype),
+                        (0,) * kept[name].ndim)
+    with jax.named_scope("laguna.head"):
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = matmul("be,ve->bv", last, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out
 
@@ -85,9 +90,10 @@ def laguna_decode_step(
 ) -> Tuple:
     """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
     pos = jnp.asarray(pos)
-    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    with jax.named_scope("laguna.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+        live = pos > 0
     cache = dict(cache)
-    live = pos > 0
     new = {leaf: [] for leaf in cache}
     total = dict.fromkeys(COUNT_NAMES, jnp.zeros((), jnp.int32))
     seen = dict.fromkeys("FWDE", 0)
@@ -106,15 +112,21 @@ def laguna_decode_step(
 
         x, counts = block(params, x, live, kinds, i, j, attend, cfg)
         if counts is not None:
-            total = add_counts(total, counts)
+            with jax.named_scope("laguna.moe"):
+                total = add_counts(total, counts)
         for kind in kinds:
             seen[kind] += 1
-    for kind, at in (("F", pos), ("W", pos % cfg.window)):
-        for leaf in LEAVES[kind]:
-            if new[leaf]:
-                cache[leaf] = write_token_to_cache(
-                    cache[leaf], jnp.stack(new[leaf]), at, axis=3)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    logits = matmul("be,ve->bv", x, params["lm_head"])
+    with jax.named_scope("laguna.attn_window"):
+        ring_at = pos % cfg.window
+    for kind, at in (("F", pos), ("W", ring_at)):
+        with jax.named_scope("laguna.attn_" + STACK[kind]):  # its cache write
+            for leaf in LEAVES[kind]:
+                if new[leaf]:
+                    cache[leaf] = write_token_to_cache(
+                        cache[leaf], jnp.stack(new[leaf]), at, axis=3)
+    with jax.named_scope("laguna.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+        logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)
     return (*out, total) if with_counts else out

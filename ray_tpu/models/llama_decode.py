@@ -11,6 +11,10 @@ models: cache bytes shrink by H/Hkv).
 
 Reference role: the model runner inside the engines the reference wraps
 (ray ``python/ray/llm/_internal/serve/engines/vllm/``).
+
+Device operations carry ``jax.named_scope``s ``llama.embed``, ``llama.attn``
+(norm, projections, rotary, attention, residual, the cache write),
+``llama.mlp`` and ``llama.head`` (final norm + vocabulary product).
 """
 
 from __future__ import annotations
@@ -36,47 +40,56 @@ def llama_prefill(
     Returns (last_logits [B, V], cache with positions [0, S) written)."""
     b, s = tokens.shape
     groups = cfg.n_head // cfg.n_kv_head
-    x = params["wte"][tokens].astype(jnp.dtype(cfg.dtype))
-    positions = jnp.arange(s, dtype=jnp.int32)
-    causal = jnp.tril(jnp.ones((s, s), bool))[None]
+    with jax.named_scope("llama.embed"):
+        x = params["wte"][tokens].astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("llama.attn"):
+        positions = jnp.arange(s, dtype=jnp.int32)
+        causal = jnp.tril(jnp.ones((s, s), bool))[None]
 
     def body(x, layer):
-        y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
-        q = jnp.einsum("bse,ehd->bshd", y, layer["wq"])
-        k = jnp.einsum("bse,ekd->bskd", y, layer["wk"])
-        v = jnp.einsum("bse,ekd->bskd", y, layer["wv"])
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        kr = jnp.repeat(k, groups, axis=2)
-        vr = jnp.repeat(v, groups, axis=2)
-        scores = jnp.einsum("bshd,bthd->bhst", q, kr).astype(jnp.float32)
-        scores = scores / (cfg.head_dim ** 0.5)
-        scores = jnp.where(causal[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhst,bthd->bshd", probs, vr)
-        x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"]).astype(x.dtype)
-        y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
-        gate = jax.nn.silu(jnp.einsum("bse,ef->bsf", y, layer["w_gate"]))
-        up = jnp.einsum("bse,ef->bsf", y, layer["w_up"])
-        x = x + jnp.einsum(
-            "bsf,fe->bse", gate * up, layer["w_down"]
-        ).astype(x.dtype)
+        with jax.named_scope("llama.attn"):
+            y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
+            q = jnp.einsum("bse,ehd->bshd", y, layer["wq"])
+            k = jnp.einsum("bse,ekd->bskd", y, layer["wk"])
+            v = jnp.einsum("bse,ekd->bskd", y, layer["wv"])
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+            kr = jnp.repeat(k, groups, axis=2)
+            vr = jnp.repeat(v, groups, axis=2)
+            scores = jnp.einsum("bshd,bthd->bhst", q, kr).astype(jnp.float32)
+            scores = scores / (cfg.head_dim ** 0.5)
+            scores = jnp.where(causal[:, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            o = jnp.einsum("bhst,bthd->bshd", probs, vr)
+            x = x + jnp.einsum(
+                "bshd,hde->bse", o, layer["wo"]).astype(x.dtype)
+        with jax.named_scope("llama.mlp"):
+            y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
+            gate = jax.nn.silu(jnp.einsum("bse,ef->bsf", y, layer["w_gate"]))
+            up = jnp.einsum("bse,ef->bsf", y, layer["w_up"])
+            x = x + jnp.einsum(
+                "bsf,fe->bse", gate * up, layer["w_down"]
+            ).astype(x.dtype)
         return x, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
-    # [L, B, S, Hkv, D] → head-major [L, B, Hkv, S, D].
-    ks = ks.transpose(0, 1, 3, 2, 4).astype(cache["k"].dtype)
-    vs = vs.transpose(0, 1, 3, 2, 4).astype(cache["v"].dtype)
-    cache = {
-        "k": jax.lax.dynamic_update_slice(cache["k"], ks, (0, 0, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(cache["v"], vs, (0, 0, 0, 0, 0)),
-    }
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
-    )[:, 0]
-    logits = jnp.einsum("be,ve->bv", last, params["lm_head"])
-    return logits.astype(jnp.float32), cache
+    with jax.named_scope("llama.attn"):  # the cache write is attention's
+        # [L, B, S, Hkv, D] → head-major [L, B, Hkv, S, D].
+        ks = ks.transpose(0, 1, 3, 2, 4).astype(cache["k"].dtype)
+        vs = vs.transpose(0, 1, 3, 2, 4).astype(cache["v"].dtype)
+        cache = {
+            "k": jax.lax.dynamic_update_slice(
+                cache["k"], ks, (0, 0, 0, 0, 0)),
+            "v": jax.lax.dynamic_update_slice(
+                cache["v"], vs, (0, 0, 0, 0, 0)),
+        }
+    with jax.named_scope("llama.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
+        )[:, 0]
+        logits = jnp.einsum("be,ve->bv", last, params["lm_head"])
+        return logits.astype(jnp.float32), cache
 
 
 def llama_decode_step(
@@ -88,38 +101,46 @@ def llama_decode_step(
                                         write_token_to_cache)
 
     b = tokens.shape[0]
-    x = params["wte"][tokens].astype(jnp.dtype(cfg.dtype))  # [B, E]
+    with jax.named_scope("llama.embed"):
+        x = params["wte"][tokens].astype(jnp.dtype(cfg.dtype))  # [B, E]
     ck, cv = cache["k"], cache["v"]
     new_ks, new_vs = [], []
 
     for l in range(cfg.n_layer):
-        layer = jax.tree.map(lambda a: a[l], params["blocks"])
-        y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
-        q = jnp.einsum("be,ehd->bhd", y, layer["wq"])
-        k = jnp.einsum("be,ekd->bkd", y, layer["wk"])
-        v = jnp.einsum("be,ekd->bkd", y, layer["wv"])
-        # rope expects [B, S, H, D]; per-slot positions ride the batch dim.
-        q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-        k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-        new_ks.append(k.astype(ck.dtype))
-        new_vs.append(v.astype(cv.dtype))
-        # Deferred-scatter protocol (see gpt2_decode.py): cache holds
-        # [0, pos-1]; current k/v a column of the softmax, one write below.
-        o = decode_attention(
-            q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1]
-        )  # [B, H, D]
-        x = x + jnp.einsum(
-            "bhd,hde->be", o.astype(y.dtype), layer["wo"]
-        ).astype(x.dtype)
-        y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
-        gate = jax.nn.silu(jnp.einsum("be,ef->bf", y, layer["w_gate"]))
-        up = jnp.einsum("be,ef->bf", y, layer["w_up"])
-        x = x + jnp.einsum(
-            "bf,fe->be", gate * up, layer["w_down"]
-        ).astype(x.dtype)
+        # A layer's slices are read by both parts; attention's come first.
+        with jax.named_scope("llama.attn"):
+            layer = jax.tree.map(lambda a: a[l], params["blocks"])
+            y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
+            q = jnp.einsum("be,ehd->bhd", y, layer["wq"])
+            k = jnp.einsum("be,ekd->bkd", y, layer["wk"])
+            v = jnp.einsum("be,ekd->bkd", y, layer["wv"])
+            # rope expects [B, S, H, D]; per-slot positions ride the batch
+            # dim.
+            q = rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+            k = rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+            new_ks.append(k.astype(ck.dtype))
+            new_vs.append(v.astype(cv.dtype))
+            # Deferred-scatter protocol (see gpt2_decode.py): cache holds
+            # [0, pos-1]; current k/v a column of the softmax, one write
+            # below.
+            o = decode_attention(
+                q, ck, cv, pos, l, k_self=new_ks[-1], v_self=new_vs[-1]
+            )  # [B, H, D]
+            x = x + jnp.einsum(
+                "bhd,hde->be", o.astype(y.dtype), layer["wo"]
+            ).astype(x.dtype)
+        with jax.named_scope("llama.mlp"):
+            y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
+            gate = jax.nn.silu(jnp.einsum("be,ef->bf", y, layer["w_gate"]))
+            up = jnp.einsum("be,ef->bf", y, layer["w_up"])
+            x = x + jnp.einsum(
+                "bf,fe->be", gate * up, layer["w_down"]
+            ).astype(x.dtype)
 
-    ck = write_token_to_cache(ck, jnp.stack(new_ks), pos, axis=3)
-    cv = write_token_to_cache(cv, jnp.stack(new_vs), pos, axis=3)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
-    logits = jnp.einsum("be,ve->bv", x, params["lm_head"])
-    return logits.astype(jnp.float32), {"k": ck, "v": cv}
+    with jax.named_scope("llama.attn"):  # the cache write is attention's
+        ck = write_token_to_cache(ck, jnp.stack(new_ks), pos, axis=3)
+        cv = write_token_to_cache(cv, jnp.stack(new_vs), pos, axis=3)
+    with jax.named_scope("llama.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
+        logits = jnp.einsum("be,ve->bv", x, params["lm_head"])
+        return logits.astype(jnp.float32), {"k": ck, "v": cv}

@@ -43,8 +43,12 @@ skips the second half, so a layer is not a chain of blocks.
 head.  Rope rotates interleaved pairs as ``llama.rope`` does (a fixed
 permutation of the published layout).
 
-Device operations carry ``jax.named_scope``s ``longcat.mla``,
-``longcat.moe`` and ``longcat.ffn``.  Routing is counted in the program:
+Device operations carry ``jax.named_scope``s ``longcat.embed`` (the token
+gather, and the positions and masks a program makes once from its inputs),
+``longcat.mla`` (norm to residual, and the cache write), ``longcat.moe``
+(``u0``'s norm, router, held and identity experts, counts), ``longcat.ffn``
+(a dense half with its norm and residual; the second takes up ``m``) and
+``longcat.head`` (final norm + vocabulary product).  Routing is counted in the program:
 ``routed_total`` (choices made by live tokens), ``routed_zero`` (those that
 fell on identity experts), ``routed_held`` (on experts held here) and
 ``experts_touched`` (distinct held experts a layer ran, summed over layers).
@@ -311,23 +315,27 @@ def double_layer(h, params, layer: int, live, attend, cfg: LongcatConfig):
         return _rmsnorm(v, blocks[name][layer, j], cfg.rms_eps).astype(dt)
 
     def dense(u, j):
-        with jax.named_scope("longcat.ffn"):
-            return ffn(u, blocks["w_gate"][layer, j], blocks["w_up"][layer, j],
-                       blocks["w_down"][layer, j])
+        return ffn(u, blocks["w_gate"][layer, j], blocks["w_up"][layer, j],
+                   blocks["w_down"][layer, j])
 
     def attention(j, y):
-        with jax.named_scope("longcat.mla"):
-            return attend({k: blocks[k][layer, j] for k in ATTENTION}, y)
+        return attend({k: blocks[k][layer, j] for k in ATTENTION}, y)
 
-    a0 = h + attention(0, norm(h, "rms_attn", 0))
-    u0 = _rmsnorm(a0, blocks["rms_ffn"][layer, 0], cfg.rms_eps)  # float32
-    m, counts = moe(u0.reshape(-1, u0.shape[-1]), live.reshape(-1),
-                    blocks["router"][layer], blocks["router_bias"][layer],
-                    params["experts"], layer, cfg)
-    b0 = a0 + dense(u0.astype(dt), 0)
-    a1 = b0 + attention(1, norm(b0, "rms_attn", 1))
-    u1 = norm(a1, "rms_ffn", 1)
-    return a1 + dense(u1, 1) + m.reshape(h.shape), counts
+    # Each part's scope holds its norm and the addition that takes it up.
+    with jax.named_scope("longcat.mla"):
+        a0 = h + attention(0, norm(h, "rms_attn", 0))
+    with jax.named_scope("longcat.moe"):  # u0 feeds the dense half too
+        u0 = _rmsnorm(a0, blocks["rms_ffn"][layer, 0], cfg.rms_eps)  # float32
+        flat, live = u0.reshape(-1, u0.shape[-1]), live.reshape(-1)
+        router, bias = blocks["router"][layer], blocks["router_bias"][layer]
+    m, counts = moe(flat, live, router, bias, params["experts"], layer, cfg)
+    with jax.named_scope("longcat.ffn"):
+        b0 = a0 + dense(u0.astype(dt), 0)
+    with jax.named_scope("longcat.mla"):
+        a1 = b0 + attention(1, norm(b0, "rms_attn", 1))
+    with jax.named_scope("longcat.ffn"):  # and the expert layer's sum
+        u1 = norm(a1, "rms_ffn", 1)
+        return a1 + dense(u1, 1) + m.reshape(h.shape), counts
 
 
 def add_counts(total, counts):
@@ -337,8 +345,9 @@ def add_counts(total, counts):
 def longcat_forward(params, tokens, live, cfg: LongcatConfig):
     """tokens ``[B, S]`` -> (final normed state ``[B, S, d]``, the latents of
     every attention ``[2L, B, S, rkv+dr]``, routing counts)."""
-    x = params["wte"][tokens].astype(jnp.float32)
-    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    with jax.named_scope("longcat.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)
+        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     latents, total = [], None
 
     def attend(att, y):
@@ -348,9 +357,14 @@ def longcat_forward(params, tokens, live, cfg: LongcatConfig):
 
     for layer in range(cfg.n_layer):
         x, counts = double_layer(x, params, layer, live, attend, cfg)
-        total = add_counts(total, counts)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    return x, jnp.stack(latents), total
+        with jax.named_scope("longcat.moe"):
+            total = add_counts(total, counts)
+    with jax.named_scope("longcat.head"):  # the final norm is the head's
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+    with jax.named_scope("longcat.mla"):
+        latents = jnp.stack(latents)
+    return x, latents, total
 
 
 def longcat_apply(params, tokens, cfg: LongcatConfig, mesh=None):
@@ -362,7 +376,8 @@ def longcat_apply(params, tokens, cfg: LongcatConfig, mesh=None):
             "longcat runs one chip's share of a layer; no mesh yet")
     x, _, _ = longcat_forward(params, tokens, jnp.ones(tokens.shape, bool),
                               cfg)
-    return matmul("bse,ve->bsv", x, params["lm_head"])
+    with jax.named_scope("longcat.head"):
+        return matmul("bse,ve->bsv", x, params["lm_head"])
 
 
 def longcat_loss(params, tokens, cfg: LongcatConfig, mesh=None):

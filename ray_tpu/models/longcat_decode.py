@@ -55,13 +55,17 @@ def longcat_prefill(
     Returns (last_logits [B, V], cache with positions [0, S) written,
     routing counts of the positions < length)."""
     s = tokens.shape[1]
-    live = jnp.arange(s)[None] < lengths[:, None]
+    with jax.named_scope("longcat.embed"):
+        live = jnp.arange(s)[None] < lengths[:, None]
     x, latents, counts = longcat_forward(params, tokens, live, cfg)
-    cache = {"latent": jax.lax.dynamic_update_slice(
-        cache["latent"], latents.astype(cache["latent"].dtype), (0, 0, 0, 0))}
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = matmul("be,ve->bv", last, params["lm_head"])
+    with jax.named_scope("longcat.mla"):  # the cache write is attention's
+        cache = {"latent": jax.lax.dynamic_update_slice(
+            cache["latent"], latents.astype(cache["latent"].dtype),
+            (0, 0, 0, 0))}
+    with jax.named_scope("longcat.head"):
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = matmul("be,ve->bv", last, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out
 
@@ -124,9 +128,10 @@ def longcat_decode_step(
 ) -> Tuple:
     """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
     pos = jnp.asarray(pos)
-    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    with jax.named_scope("longcat.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+        live = pos > 0
     latent_cache = cache["latent"]
-    live = pos > 0
     new, total = [], None
 
     def attend(att, y):
@@ -137,10 +142,14 @@ def longcat_decode_step(
 
     for layer in range(cfg.n_layer):
         x, counts = double_layer(x, params, layer, live, attend, cfg)
-        total = add_counts(total, counts)
-    latent_cache = write_token_to_cache(
-        latent_cache, jnp.stack(new), pos, axis=2)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    logits = matmul("be,ve->bv", x, params["lm_head"])
+        with jax.named_scope("longcat.moe"):
+            total = add_counts(total, counts)
+    with jax.named_scope("longcat.mla"):  # the cache write is attention's
+        latent_cache = write_token_to_cache(
+            latent_cache, jnp.stack(new), pos, axis=2)
+    with jax.named_scope("longcat.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+        logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, {"latent": latent_cache})
     return (*out, total) if with_counts else out

@@ -51,8 +51,11 @@ towers; the model's own logits depend on neither.
 Parameters: ``params["blocks"]`` holds one layer-stack a KIND of layer
 (``full``, ``window``, ``dense``, ``moe``), each as long as the patterns have
 layers of that kind; a model of fewer layers reads the front of each stack.
-Device operations carry ``jax.named_scope``s ``mimo.attn_full``,
-``mimo.attn_window``, ``mimo.moe`` and ``mimo.mlp``.  Routing is counted in
+Device operations carry ``jax.named_scope``s ``mimo.embed`` (the token gather,
+and the positions and mask a program makes once from its inputs),
+``mimo.attn_full``, ``mimo.attn_window`` (norm to residual, and the cache
+write), ``mimo.moe`` (norm, router, held experts, residual, counts),
+``mimo.mlp`` and ``mimo.head`` (final norm + vocabulary product).  Routing is counted in
 the program: ``routed_total`` (choices made by live tokens), ``routed_held``
 (those on experts held here) and ``experts_touched`` (distinct held experts
 a layer ran, summed over layers).
@@ -389,6 +392,11 @@ def moe(u, live, params, i: int, cfg: MimoV2Config):
 
 
 # -------------------------------------------------------------------- model
+def leaf_scope(leaf: str) -> str:
+    """The scope of the attention that keeps cache leaf ``leaf``."""
+    return "mimo.attn_window" if leaf.endswith("_win") else "mimo.attn_full"
+
+
 def run_layers(params, x, live, attend, cfg: MimoV2Config):
     """The blocks of the first ``cfg.n_layer`` layers over the float32 stream
     ``x [..., d]``.  ``attend(kind, i, y)`` is the attention of the ``i``-th
@@ -416,11 +424,13 @@ def run_layers(params, x, live, attend, cfg: MimoV2Config):
                 x = x + ffn(u, dense["w_gate"][j], dense["w_up"][j],
                             dense["w_down"][j])
         else:
-            u = _rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # float32
-            y, counts = moe(u.reshape(-1, u.shape[-1]), live.reshape(-1),
-                            params, j, cfg)
-            x = x + y.reshape(x.shape)
-            total = add_counts(total, counts)
+            with jax.named_scope("mimo.moe"):
+                u = _rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # f32
+                u, rows = u.reshape(-1, u.shape[-1]), live.reshape(-1)
+            y, counts = moe(u, rows, params, j, cfg)
+            with jax.named_scope("mimo.moe"):
+                x = x + y.reshape(x.shape)
+                total = add_counts(total, counts)
     return x, total
 
 
@@ -431,9 +441,10 @@ def mimo_v2_forward(params, tokens, lengths, cfg: MimoV2Config):
     w, D]`` / ``v_win`` of the window layers at each row's TRUE length
     (``ring_of``), routing counts of the positions ``< length``)."""
     blocks = params["blocks"]
-    x = params["wte"][tokens].astype(jnp.float32)
-    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    live = positions[None] < lengths[:, None]
+    with jax.named_scope("mimo.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)
+        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        live = positions[None] < lengths[:, None]
     kept = {"k": [], "v": [], "k_win": [], "v_win": []}
 
     def attend(kind, i, y):
@@ -448,8 +459,15 @@ def mimo_v2_forward(params, tokens, lengths, cfg: MimoV2Config):
         return window_attention(q, k, v, att["sink"][i], cfg.window)
 
     x, counts = run_layers(params, x, live, attend, cfg)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    return x, {name: jnp.stack(v) for name, v in kept.items() if v}, counts
+    with jax.named_scope("mimo.head"):  # the final norm is the head's
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+    stacked = {}
+    for name, v in kept.items():
+        if v:
+            with jax.named_scope(leaf_scope(name)):
+                stacked[name] = jnp.stack(v)
+    return x, stacked, counts
 
 
 def mimo_v2_apply(params, tokens, cfg: MimoV2Config, mesh=None):
@@ -461,7 +479,8 @@ def mimo_v2_apply(params, tokens, cfg: MimoV2Config, mesh=None):
             "mimo_v2 runs one chip's share of a layer; no mesh yet")
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = mimo_v2_forward(params, tokens, lengths, cfg)
-    return matmul("bse,ve->bsv", x, params["lm_head"])
+    with jax.named_scope("mimo.head"):
+        return matmul("bse,ve->bsv", x, params["lm_head"])
 
 
 def mimo_v2_loss(params, tokens, cfg: MimoV2Config, mesh=None):

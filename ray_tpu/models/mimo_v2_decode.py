@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from ..ops.decode_attention import decode_attention, write_token_to_cache
 from .llama import _rmsnorm
 from .longcat import matmul
-from .mimo_v2 import (STACK, MimoV2Config, attention_project,
+from .mimo_v2 import (STACK, MimoV2Config, attention_project, leaf_scope,
                       mimo_v2_forward, run_layers)
 
 # a kind of attention -> its cache leaves
@@ -71,11 +71,13 @@ def mimo_v2_prefill(
     x, kept, counts = mimo_v2_forward(params, tokens, lengths, cfg)
     cache = dict(cache)
     for name, new in kept.items():
-        cache[name] = jax.lax.dynamic_update_slice(
-            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = matmul("be,ve->bv", last, params["lm_head"])
+        with jax.named_scope(leaf_scope(name)):  # its cache write
+            cache[name] = jax.lax.dynamic_update_slice(
+                cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
+    with jax.named_scope("mimo.head"):
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = matmul("be,ve->bv", last, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out
 
@@ -87,7 +89,9 @@ def mimo_v2_decode_step(
     """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
     pos = jnp.asarray(pos)
     blocks = params["blocks"]
-    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    with jax.named_scope("mimo.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+        live = pos > 0
     cache = dict(cache)
     new = {leaf: [] for leaf in cache}
 
@@ -103,13 +107,18 @@ def mimo_v2_decode_step(
             q, cache[k_leaf], cache[v_leaf], pos, i, k_self=new[k_leaf][-1],
             v_self=new[v_leaf][-1], **ring)
 
-    x, counts = run_layers(params, x, pos > 0, attend, cfg)
-    for kind, at in (("F", pos), ("W", pos % cfg.window)):
+    x, counts = run_layers(params, x, live, attend, cfg)
+    with jax.named_scope("mimo.attn_window"):
+        ring_at = pos % cfg.window
+    for kind, at in (("F", pos), ("W", ring_at)):
         for leaf in LEAVES[kind]:
             if new[leaf]:
-                cache[leaf] = write_token_to_cache(
-                    cache[leaf], jnp.stack(new[leaf]), at, axis=3)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    logits = matmul("be,ve->bv", x, params["lm_head"])
+                with jax.named_scope(leaf_scope(leaf)):  # its cache write
+                    cache[leaf] = write_token_to_cache(
+                        cache[leaf], jnp.stack(new[leaf]), at, axis=3)
+    with jax.named_scope("mimo.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+        logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out

@@ -70,8 +70,12 @@ Not here: the vision encoder (the catalog gives the language model's
 configuration alone; the model's own logits on token ids do not depend on
 it).
 
-Device operations carry ``jax.named_scope``s ``mistral4.mla``,
-``mistral4.moe`` and ``mistral4.shared``.  Routing is counted in the program:
+Device operations carry ``jax.named_scope``s ``mistral4.embed`` (the token
+gather, and the positions and masks a program makes once from its inputs),
+``mistral4.mla`` (norm to residual, and the cache write), ``mistral4.moe``
+(norm, router, held experts, counts), ``mistral4.shared`` (the shared expert
+and the residual) and ``mistral4.head`` (final norm + vocabulary product).
+Routing is counted in the program:
 ``routed_total`` (choices made by live tokens), ``routed_held`` (those on
 experts held here) and ``experts_touched`` (distinct held experts a layer
 ran, summed over layers).
@@ -411,8 +415,8 @@ def moe(u, live, params, i: int, cfg: Mistral4Config):
     chosen (PERF.md, PR 54).  The way is read off the SHAPES, never off the
     load (``expert_share.runs_every_held_expert``)."""
     blocks, experts = params["blocks"], params["experts"]
-    ud = u.astype(jnp.dtype(cfg.dtype))
     with jax.named_scope("mistral4.moe"):
+        ud = u.astype(jnp.dtype(cfg.dtype))
         sel, w = softmax_route(u, blocks["router"][i], cfg.top_k,
                                cfg.routed_scaling_factor)
         held, hit, w_held = held_choices(
@@ -428,12 +432,13 @@ def moe(u, live, params, i: int, cfg: Mistral4Config):
     with jax.named_scope("mistral4.shared"):
         y = y + ffn(ud, blocks["w_gate"][i], blocks["w_up"][i],
                     blocks["w_down"][i])
-    return y, {  # int32 scalars
-        "routed_total": live.sum() * cfg.top_k,
-        "routed_held": held.sum(),
-        "experts_touched": hit.any(0).sum(),
-        **loop_counts(hit, looped=not dense),
-    }
+    with jax.named_scope("mistral4.moe"):
+        return y, {  # int32 scalars
+            "routed_total": live.sum() * cfg.top_k,
+            "routed_held": held.sum(),
+            "experts_touched": hit.any(0).sum(),
+            **loop_counts(hit, looped=not dense),
+        }
 
 
 # -------------------------------------------------------------------- model
@@ -453,10 +458,12 @@ def layer(params, x, live, i, attend, cfg: Mistral4Config):
         y = _rmsnorm(x, blocks["rms_attn"][i], cfg.rms_eps).astype(dt)
         o, latent = attend({k: blocks[k][i] for k in ATTENTION}, y)
         x = x + o
-    u = _rmsnorm(x, blocks["rms_ffn"][i], cfg.rms_eps)  # float32
-    y, counts = moe(u.reshape(-1, u.shape[-1]), live.reshape(-1), params, i,
-                    cfg)
-    return x + y.reshape(x.shape), latent, counts
+    with jax.named_scope("mistral4.moe"):
+        u = _rmsnorm(x, blocks["rms_ffn"][i], cfg.rms_eps)  # float32
+        u, live = u.reshape(-1, u.shape[-1]), live.reshape(-1)
+    y, counts = moe(u, live, params, i, cfg)
+    with jax.named_scope("mistral4.shared"):  # the sum's last term
+        return x + y.reshape(x.shape), latent, counts
 
 
 def mistral4_forward(params, tokens, lengths, cfg: Mistral4Config):
@@ -468,10 +475,11 @@ def mistral4_forward(params, tokens, lengths, cfg: Mistral4Config):
     are bound by compute, so a layer's weights may be sliced out of their
     stacks as they are needed, the program is a ninth as long (seven rungs
     are compiled a replica) and a rung's temporaries are one layer's."""
-    x = params["wte"][tokens].astype(jnp.float32)
-    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    live = positions[None] < lengths[:, None]
-    longest = jnp.max(lengths)
+    with jax.named_scope("mistral4.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)
+        positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        live = positions[None] < lengths[:, None]
+        longest = jnp.max(lengths)
 
     def attend(att, y):
         q, latent = project(y, att, positions, cfg)
@@ -480,12 +488,15 @@ def mistral4_forward(params, tokens, lengths, cfg: Mistral4Config):
     def one_layer(carry, i):
         x, total = carry
         x, latent, counts = layer(params, x, live, i, attend, cfg)
-        return (x, add_counts(total, counts)), latent
+        with jax.named_scope("mistral4.moe"):
+            return (x, add_counts(total, counts)), latent
 
     zero = dict.fromkeys(COUNT_NAMES, jnp.zeros((), jnp.int32))
     (x, counts), latents = jax.lax.scan(
         one_layer, (x, zero), jnp.arange(cfg.n_layer))
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
+    with jax.named_scope("mistral4.head"):  # the final norm is the head's
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
     return x, latents, counts
 
 
@@ -498,7 +509,8 @@ def mistral4_apply(params, tokens, cfg: Mistral4Config, mesh=None):
             "mistral4 runs one chip's share of a layer; no mesh yet")
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = mistral4_forward(params, tokens, lengths, cfg)
-    return matmul("bse,ve->bsv", x, params["lm_head"])
+    with jax.named_scope("mistral4.head"):
+        return matmul("bse,ve->bsv", x, params["lm_head"])
 
 
 def mistral4_loss(params, tokens, cfg: Mistral4Config, mesh=None):

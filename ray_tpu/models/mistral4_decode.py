@@ -58,11 +58,14 @@ def mistral4_prefill(
     Returns (last_logits [B, V], cache with positions [0, S) written,
     routing counts of the positions < length)."""
     x, latents, counts = mistral4_forward(params, tokens, lengths, cfg)
-    cache = {"latent": jax.lax.dynamic_update_slice(
-        cache["latent"], latents.astype(cache["latent"].dtype), (0, 0, 0, 0))}
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = matmul("be,ve->bv", last, params["lm_head"])
+    with jax.named_scope("mistral4.mla"):  # the cache write is attention's
+        cache = {"latent": jax.lax.dynamic_update_slice(
+            cache["latent"], latents.astype(cache["latent"].dtype),
+            (0, 0, 0, 0))}
+    with jax.named_scope("mistral4.head"):
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = matmul("be,ve->bv", last, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out
 
@@ -73,9 +76,10 @@ def mistral4_decode_step(
 ) -> Tuple:
     """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
     pos = jnp.asarray(pos)
-    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    with jax.named_scope("mistral4.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+        live = pos > 0
     latent_cache = cache["latent"]
-    live = pos > 0
     new, counts = [], None
     for i in range(cfg.n_layer):
         def attend(att, y):
@@ -86,10 +90,14 @@ def mistral4_decode_step(
 
         x, latent, layer_counts = layer(params, x, live, i, attend, cfg)
         new.append(latent)
-        counts = add_counts(counts, layer_counts)
-    latent_cache = write_token_to_cache(
-        latent_cache, jnp.stack(new), pos, axis=2)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    logits = matmul("be,ve->bv", x, params["lm_head"])
+        with jax.named_scope("mistral4.moe"):
+            counts = add_counts(counts, layer_counts)
+    with jax.named_scope("mistral4.mla"):  # the cache write is attention's
+        latent_cache = write_token_to_cache(
+            latent_cache, jnp.stack(new), pos, axis=2)
+    with jax.named_scope("mistral4.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+        logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, {"latent": latent_cache})
     return (*out, counts) if with_counts else out

@@ -56,8 +56,11 @@ draft head the model's own logits do not depend on.
 Parameters: ``params["blocks"]`` holds one layer-stack a KIND of layer
 (``mamba``, ``attn``, ``moe``), each as long as the pattern has layers of
 that kind; a model of fewer layers reads the front of each stack.  Device
-operations carry ``jax.named_scope``s ``nemotron.mamba``, ``nemotron.attn``
-and ``nemotron.moe``.  Routing is counted in the program: ``routed_total``
+operations carry ``jax.named_scope``s ``nemotron.embed`` (the token gather,
+and the mask a program makes once from its inputs), ``nemotron.mamba`` and
+``nemotron.attn`` (norm to residual, and what the cache keeps of them),
+``nemotron.moe`` (norm, router, held and shared experts, residual, counts)
+and ``nemotron.head`` (final norm + vocabulary product).  Routing is counted in the program: ``routed_total``
 (choices made by live tokens), ``routed_held`` (those on experts held here)
 and ``experts_touched`` (distinct held experts a layer ran, summed over
 layers).
@@ -82,6 +85,9 @@ PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
                      "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
 COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
                *LOOP_COUNT_NAMES)
+# a cache leaf -> the scope of the part that keeps it
+CACHE_SCOPE = {"k": "nemotron.attn", "v": "nemotron.attn",
+               "conv": "nemotron.mamba", "ssm": "nemotron.mamba"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -503,11 +509,13 @@ def run_layers(params, x, live, mamba, attend, cfg: NemotronHConfig):
                 y = _rmsnorm(x, blocks["attn"]["rms"][i], cfg.rms_eps)
                 x = x + attend(i, y.astype(dt))
         else:
-            u = _rmsnorm(x, blocks["moe"]["rms"][i], cfg.rms_eps)  # float32
-            routed, shared, counts = moe(
-                u.reshape(-1, u.shape[-1]), live.reshape(-1), params, i, cfg)
-            x = x + (routed + shared).reshape(x.shape)
-            total = add_counts(total, counts)
+            with jax.named_scope("nemotron.moe"):
+                u = _rmsnorm(x, blocks["moe"]["rms"][i], cfg.rms_eps)  # f32
+                u, rows = u.reshape(-1, u.shape[-1]), live.reshape(-1)
+            routed, shared, counts = moe(u, rows, params, i, cfg)
+            with jax.named_scope("nemotron.moe"):
+                x = x + (routed + shared).reshape(x.shape)
+                total = add_counts(total, counts)
     return x, total
 
 
@@ -517,8 +525,9 @@ def nemotron_h_forward(params, tokens, lengths, cfg: NemotronHConfig):
     ``conv`` ``[M, B, (K-1)(HP + 2GN)]`` and ``ssm`` ``[M, B, H, P, N]`` at each
     row's TRUE length, routing counts of the positions ``< length``)."""
     blocks = params["blocks"]
-    x = params["wte"][tokens].astype(jnp.float32)
-    live = jnp.arange(tokens.shape[1])[None] < lengths[:, None]
+    with jax.named_scope("nemotron.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)
+        live = jnp.arange(tokens.shape[1])[None] < lengths[:, None]
     kept = {"k": [], "v": [], "conv": [], "ssm": []}
 
     def mamba(i, y):
@@ -534,8 +543,15 @@ def nemotron_h_forward(params, tokens, lengths, cfg: NemotronHConfig):
         return out
 
     x, counts = run_layers(params, x, live, mamba, attend, cfg)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    return x, {name: jnp.stack(v) for name, v in kept.items() if v}, counts
+    with jax.named_scope("nemotron.head"):  # the final norm is the head's
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+    stacked = {}
+    for name, v in kept.items():
+        if v:
+            with jax.named_scope(CACHE_SCOPE[name]):
+                stacked[name] = jnp.stack(v)
+    return x, stacked, counts
 
 
 def nemotron_h_apply(params, tokens, cfg: NemotronHConfig, mesh=None):
@@ -547,7 +563,8 @@ def nemotron_h_apply(params, tokens, cfg: NemotronHConfig, mesh=None):
             "nemotron_h runs one chip's share of a layer; no mesh yet")
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = nemotron_h_forward(params, tokens, lengths, cfg)
-    return matmul("bse,ve->bsv", x, params["lm_head"])
+    with jax.named_scope("nemotron.head"):
+        return matmul("bse,ve->bsv", x, params["lm_head"])
 
 
 def nemotron_h_loss(params, tokens, cfg: NemotronHConfig, mesh=None):

@@ -43,9 +43,9 @@ import jax.numpy as jnp
 from ..ops.decode_attention import decode_attention, write_token_to_cache
 from .llama import _rmsnorm
 from .longcat import matmul
-from .nemotron_h import (NemotronHConfig, attention_project, mamba_output,
-                         mamba_project, nemotron_h_forward, run_layers,
-                         split_xbc)
+from .nemotron_h import (CACHE_SCOPE, NemotronHConfig, attention_project,
+                         mamba_output, mamba_project, nemotron_h_forward,
+                         run_layers, split_xbc)
 
 
 def nemotron_h_init_cache(cfg: NemotronHConfig, batch: int, max_len: int):
@@ -72,13 +72,15 @@ def nemotron_h_prefill(
     x, kept, counts = nemotron_h_forward(params, tokens, lengths, cfg)
     cache = dict(cache)
     for name, new in kept.items():
-        if name in ("k", "v"):  # [A, B, S, Hkv, D] -> head-major
-            new = new.transpose(0, 1, 3, 2, 4)
-        cache[name] = jax.lax.dynamic_update_slice(
-            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = matmul("be,ve->bv", last, params["lm_head"])
+        with jax.named_scope(CACHE_SCOPE[name]):
+            if name in ("k", "v"):  # [A, B, S, Hkv, D] -> head-major
+                new = new.transpose(0, 1, 3, 2, 4)
+            cache[name] = jax.lax.dynamic_update_slice(
+                cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
+    with jax.named_scope("nemotron.head"):
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = matmul("be,ve->bv", last, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out
 
@@ -111,7 +113,9 @@ def nemotron_h_decode_step(
     """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
     pos = jnp.asarray(pos)
     blocks = params["blocks"]
-    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    with jax.named_scope("nemotron.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+        live = pos > 0
     cache = dict(cache)
     new_conv, new_k, new_v = [], [], []
 
@@ -130,15 +134,19 @@ def nemotron_h_decode_step(
                              k_self=new_k[-1], v_self=new_v[-1])
         return matmul("bhd,hde->be", o.astype(y.dtype), blocks["attn"]["wo"][i])
 
-    x, counts = run_layers(params, x, pos > 0, mamba, attend, cfg)
+    x, counts = run_layers(params, x, live, mamba, attend, cfg)
     if new_conv:
-        cache["conv"] = jnp.stack(new_conv)
+        with jax.named_scope("nemotron.mamba"):
+            cache["conv"] = jnp.stack(new_conv)
     if new_k:
-        cache["k"] = write_token_to_cache(
-            cache["k"], jnp.stack(new_k), pos, axis=3)
-        cache["v"] = write_token_to_cache(
-            cache["v"], jnp.stack(new_v), pos, axis=3)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    logits = matmul("be,ve->bv", x, params["lm_head"])
+        with jax.named_scope("nemotron.attn"):  # its cache write
+            cache["k"] = write_token_to_cache(
+                cache["k"], jnp.stack(new_k), pos, axis=3)
+            cache["v"] = write_token_to_cache(
+                cache["v"], jnp.stack(new_v), pos, axis=3)
+    with jax.named_scope("nemotron.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+        logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out

@@ -50,8 +50,10 @@ Then a final RMSNorm and an untied head.
 Parameters: ``params["blocks"]`` holds one layer-stack a KIND of layer
 (``linear``, ``full``; each with its MLP), as long as the pattern has layers
 of that kind; a model of fewer layers reads the front of each stack.  Device
-operations carry ``jax.named_scope``s ``olmo.delta``, ``olmo.attn`` and
-``olmo.mlp``.  Counted in the program: ``delta_positions`` (true positions a
+operations carry ``jax.named_scope``s ``olmo.embed``, ``olmo.delta`` (a linear
+layer's mixer with its norm, residual and the state it leaves), ``olmo.attn``
+(a full layer's, with the cache write), ``olmo.mlp`` and ``olmo.head`` (final
+norm + vocabulary product).  Counted in the program: ``delta_positions`` (true positions a
 prefill scanned; rows a decode step served) and ``delta_chunk_positions``
 (positions of the chunks it ran; a decode step's rows).
 """
@@ -446,8 +448,10 @@ def olmo_hybrid_forward(params, tokens, lengths, cfg: OlmoHybridConfig):
     weights are sliced out of their stacks inside the product that reads
     them (no copy: ``tests/test_tpu_compile.py``)."""
     blocks, dt = params["blocks"], jnp.dtype(cfg.dtype)
-    x = params["wte"][tokens].astype(jnp.float32)
-    longest = jnp.max(lengths)
+    with jax.named_scope("olmo.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)
+    with jax.named_scope("olmo.attn"):
+        longest = jnp.max(lengths)
     period, repeats, runs = layer_plan(cfg.kinds)
 
     def one_run(x, p, kind, first, length):
@@ -485,16 +489,19 @@ def olmo_hybrid_forward(params, tokens, lengths, cfg: OlmoHybridConfig):
                    for kind, kept in held.items() if kept}
 
     x, held = scan_or_call(one_period, x, repeats)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(dt)
+    with jax.named_scope("olmo.head"):  # the final norm is the head's
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(dt)
     kept = {}  # [repeats, a period's layers of the kind, ...] -> [layers, ...]
     for kind, names in (("L", ("conv", "state")), ("F", ("k", "v"))):
-        for name, a in zip(names, held.get(kind, ())):
-            kept[name] = a.reshape((-1,) + a.shape[2:])
+        with jax.named_scope("olmo.delta" if kind == "L" else "olmo.attn"):
+            for name, a in zip(names, held.get(kind, ())):
+                kept[name] = a.reshape((-1,) + a.shape[2:])
     bsz, s = tokens.shape
-    counts = {
-        "delta_positions": lengths.sum().astype(jnp.int32),
-        "delta_chunk_positions": jnp.asarray(
-            bsz * -(-s // cfg.chunk_size) * cfg.chunk_size, jnp.int32)}
+    with jax.named_scope("olmo.delta"):
+        counts = {
+            "delta_positions": lengths.sum().astype(jnp.int32),
+            "delta_chunk_positions": jnp.asarray(
+                bsz * -(-s // cfg.chunk_size) * cfg.chunk_size, jnp.int32)}
     return x, kept, counts
 
 
@@ -505,7 +512,8 @@ def olmo_hybrid_apply(params, tokens, cfg: OlmoHybridConfig, mesh=None):
         raise NotImplementedError("olmo_hybrid runs on one chip; no mesh yet")
     lengths = jnp.full((tokens.shape[0],), tokens.shape[1], jnp.int32)
     x, _, _ = olmo_hybrid_forward(params, tokens, lengths, cfg)
-    return matmul("bse,ve->bsv", x, params["lm_head"])
+    with jax.named_scope("olmo.head"):
+        return matmul("bse,ve->bsv", x, params["lm_head"])
 
 
 def olmo_hybrid_loss(params, tokens, cfg: OlmoHybridConfig, mesh=None):

@@ -79,13 +79,16 @@ def olmo_hybrid_prefill(
     x, kept, counts = olmo_hybrid_forward(params, tokens, lengths, cfg)
     cache = dict(cache)
     for name, new in kept.items():
-        if name in ("k", "v"):  # [Lf, B, S, H, D] -> head-major
-            new = new.transpose(0, 1, 3, 2, 4)
-        cache[name] = jax.lax.dynamic_update_slice(
-            cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = matmul("be,ve->bv", last, params["lm_head"])
+        full = name in ("k", "v")
+        with jax.named_scope("olmo.attn" if full else "olmo.delta"):
+            if full:  # [Lf, B, S, H, D] -> head-major
+                new = new.transpose(0, 1, 3, 2, 4)
+            cache[name] = jax.lax.dynamic_update_slice(
+                cache[name], new.astype(cache[name].dtype), (0,) * new.ndim)
+    with jax.named_scope("olmo.head"):
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        logits = matmul("be,ve->bv", last, params["lm_head"])
     out = (logits, cache)
     return (*out, counts) if with_counts else out
 
@@ -124,7 +127,8 @@ def olmo_hybrid_decode_step(
     """tokens: [B]; pos: [B] position of each token (0 = idle slot)."""
     pos = jnp.asarray(pos)
     blocks = params["blocks"]
-    x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
+    with jax.named_scope("olmo.embed"):
+        x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
     cache = dict(cache)
     new_conv, new_k, new_v = [], [], []
     seen = dict.fromkeys(STACK, 0)
@@ -150,15 +154,21 @@ def olmo_hybrid_decode_step(
 
         x = block(params, x, kind, i, delta if kind == "L" else attend, cfg)
     if new_conv:
-        cache["conv"] = jnp.stack(new_conv)
+        with jax.named_scope("olmo.delta"):
+            cache["conv"] = jnp.stack(new_conv)
     if new_k:
-        cache["k"] = write_token_to_cache(
-            cache["k"], jnp.stack(new_k), pos, axis=3)
-        cache["v"] = write_token_to_cache(
-            cache["v"], jnp.stack(new_v), pos, axis=3)
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(jnp.dtype(cfg.dtype))
-    logits = matmul("be,ve->bv", x, params["lm_head"])
+        with jax.named_scope("olmo.attn"):  # the cache write is attention's
+            cache["k"] = write_token_to_cache(
+                cache["k"], jnp.stack(new_k), pos, axis=3)
+            cache["v"] = write_token_to_cache(
+                cache["v"], jnp.stack(new_v), pos, axis=3)
+    with jax.named_scope("olmo.head"):
+        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+            jnp.dtype(cfg.dtype))
+        logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)
-    counts = {"delta_positions": (pos > 0).sum().astype(jnp.int32),
-              "delta_chunk_positions": jnp.asarray(pos.shape[0], jnp.int32)}
+    with jax.named_scope("olmo.delta"):
+        counts = {"delta_positions": (pos > 0).sum().astype(jnp.int32),
+                  "delta_chunk_positions": jnp.asarray(
+                      pos.shape[0], jnp.int32)}
     return (*out, counts) if with_counts else out

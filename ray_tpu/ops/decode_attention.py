@@ -268,7 +268,15 @@ def write_token_to_cache(cache_arr, new, pos, axis: int):
     a ``where`` over the position axis reads and writes everything; a loop
     of one-row updates, a tile of the wrong size or one whose alignment the
     compiler cannot see is updated through masked partial stores (0.8-1.2
-    ms a LongCat step, where a row is one lane of 288 tiles)."""
+    ms a LongCat step, where a row is one lane of 288 tiles).  The loop's
+    operations carry the scope ``cache_write`` inside the caller's
+    (``<family>.attn/cache_write/while/...``): a step's write is told from
+    its attention's loops in a trace's ``op_name``s."""
+    with jax.named_scope("cache_write"):
+        return _write_token_to_cache(cache_arr, new, pos, axis)
+
+
+def _write_token_to_cache(cache_arr, new, pos, axis: int):
     t = cache_arr.shape[axis]
     rows = min(t, tile_positions(cache_arr.shape, cache_arr.dtype, axis))
     new = jnp.expand_dims(new, axis)

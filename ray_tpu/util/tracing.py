@@ -41,6 +41,16 @@ spread says how well (about a millisecond a session).
 The first sink outlives the cluster: ``ray_tpu.shutdown()`` of the driver
 that started the head writes every span row to ``<log dir>/spans.jsonl``
 (``write_spans``; the row is in docs/observability.md).
+
+The second sink says which part of the model a device operation belongs to:
+``stop_profile()``, after the session has ended, writes
+``<path>/programs.jsonl`` beside the trace (``write_programs``): for every
+compiled program alive in the process ``{"module", "fingerprint", "ops":
+{instruction: op_name}}``, the ``op_name`` holding the ``jax.named_scope``s
+(``<family>.<part>``) the instruction was traced under.  Every process that
+calls ``stop_profile`` writes its own, under the ``path`` it gave
+``start_profile``.  The recipe: ``start_profile(dir)`` -> ``stop_profile()``
+-> ``python3 -m benchmarks.lib.scopes report <dir>``.
 """
 
 from __future__ import annotations
@@ -49,10 +59,14 @@ import contextlib
 import contextvars
 import dataclasses
 import json
+import logging
 import os
+import re
 import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+logger = logging.getLogger(__name__)
 
 # (trace_id, span_id) of the currently active span in THIS process/task.
 _current: contextvars.ContextVar[Optional[Tuple[str, str]]] = (
@@ -84,23 +98,126 @@ def host_span(name: str, **attrs):
     return _annotation(name, **attrs)
 
 
+_profile_path: Optional[str] = None  # the running session's, as jax holds one
+
+
 def start_profile(path: str) -> None:
     """Start a profiler session in THIS process (the one that holds the
     chip): device operations and ``host_span``s into ``path``, one file,
     one time base.  The profiler's Python tracer is off: it doubled a
     host-bound serving step (PERF.md, PR 24)."""
+    global _profile_path
     import jax
 
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 1
     jax.profiler.start_trace(path, profiler_options=options)
+    _profile_path = path
 
 
 def stop_profile() -> None:
+    """End the session, then write ``<path>/programs.jsonl``
+    (``write_programs``): after the trace is closed, so the write is in no
+    trace, and never at the trace's cost: a table that cannot be written is
+    a warning."""
+    global _profile_path
     import jax
 
     jax.profiler.stop_trace()
+    path, _profile_path = _profile_path, None
+    if path is None:  # a session someone else started
+        return
+    tables, t0 = os.path.join(path, "programs.jsonl"), time.perf_counter()
+    try:
+        rows = write_programs(tables)
+    except Exception:  # the trace is written; its tables are an extra
+        logger.warning("no programs.jsonl under %s", path, exc_info=True)
+        return
+    logger.info("%s: %d programs, %d bytes, %.3f s after the trace", tables,
+                rows, os.path.getsize(tables), time.perf_counter() - t0)
+
+
+# An instruction line of an optimized module's text and a computation's
+# first line; ``calls=`` / ``to_apply=`` name the computation fused into the
+# instruction or applied by it an element (a ``call``'s alone runs as itself).
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+_HLO_APPLIED = re.compile(r"(?:calls|to_apply)=%([^\s,}]+)")
+MIN_PROGRAM_OPS = 8
+
+
+def program_ops(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: ``op_name``} of an optimized HLO module's text,
+    for every instruction OUTSIDE fused computations (``""`` for one the
+    compiler made and gave no ``op_name``: a name that is here and has no
+    scope is told from a name that is nowhere).  An instruction inside a
+    fusion never runs by itself: the fusion's own line does, under its
+    root's ``op_name``, so a fusion across two ``jax.named_scope``s is
+    booked to the scope of its root."""
+    by_computation: Dict[str, Dict[str, str]] = {}
+    fused, ops = set(), None
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            ops = by_computation.setdefault(head.group(1), {})
+            continue
+        found = _HLO_INSTRUCTION.match(line) if ops is not None else None
+        if not found:
+            continue
+        called = _HLO_APPLIED.search(line)
+        if called and " call(" not in line:
+            fused.add(called.group(1))
+        op_name = _HLO_OP_NAME.search(line)
+        ops[found.group(1)] = op_name.group(1) if op_name else ""
+    return {name: op_name for computation, ops in by_computation.items()
+            if computation not in fused for name, op_name in ops.items()}
+
+
+def _live_executables() -> list:
+    """Every compiled program alive in this process, on the backend that
+    holds the chip."""
+    import jax
+
+    return jax.devices()[0].client.live_executables()
+
+
+def write_programs(path: str) -> int:
+    """Write the scope table of every compiled program alive in this
+    process to ``path``, one JSON object a line: ``{"module": the HLO
+    module's name (``jit__lambda``: what the trace's ``XLA Modules`` line
+    has before the parenthesis), "fingerprint": the executable's, in hex,
+    "ops": program_ops(its optimized text)}``.  The programs are the
+    backend's ``live_executables()``: whatever could have run in the trace
+    (the engine's decode step, every prefill rung, the samplers, a training
+    step someone else compiled), with no registry and nothing kept on any
+    path that runs when no session does.  Left out: a program with under
+    ``MIN_PROGRAM_OPS`` instructions that have an ``op_name``
+    (``jit_convert_element_type`` and its like: nothing a scope could
+    split), and one whose text cannot be read (a warning).  Returns the
+    number of rows."""
+    rows = []
+    for executable in _live_executables():
+        try:
+            module = executable.hlo_modules()[0]
+            ops = program_ops(module.to_string())
+            fingerprint = executable.fingerprint
+            if isinstance(fingerprint, bytes):  # 32 raw bytes on a TPU
+                fingerprint = fingerprint.hex()
+            row = {"module": module.name, "fingerprint": str(fingerprint),
+                   "ops": ops}
+        except Exception:  # one program's table, not the others'
+            logger.warning("a program's text could not be read",
+                           exc_info=True)
+            continue
+        if sum(1 for op_name in ops.values() if op_name) >= MIN_PROGRAM_OPS:
+            rows.append(row)
+    with open(path + ".tmp", "w") as f:
+        for row in rows:
+            f.write(json.dumps(row) + "\n")
+    os.replace(path + ".tmp", path)
+    return len(rows)
 
 
 @dataclasses.dataclass

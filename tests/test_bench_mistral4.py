@@ -53,7 +53,11 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "top10_experts_touched_pct.serve",  # PR 52
         "prefill_chunk_fill_pct.serve",  # PR 53
         "held_loop_turns.serve",  # PR 54
-        "delta_decode_roofline.serve", "delta_chunk_fill_pct.serve"]  # PR 56
+        "delta_decode_roofline.serve", "delta_chunk_fill_pct.serve",  # PR 56
+        "attn_ms.serve", "experts_ms.serve", "state_ms.serve", "mlp_ms.serve",
+        "head_ms.serve", "unscoped_pct.serve", "prefill_experts_ms.serve_rate",
+        "prefill_state_ms.serve_rate", "attn_ms.train", "mlp_ms.train",
+        "head_ms.train", "unscoped_pct.train"]  # PR 58
 
 
 def run(*command):

@@ -565,3 +565,204 @@ def test_cache_read_pct_metric_reads_the_dispatch_span(tmp_path):
         types.SimpleNamespace(stats={"active": 2})])
     ctx.host_spans = [parents]
     assert span_stat.read(ctx, **spec["args"]) is None
+
+
+# ------------------------------------------- the programs' scope tables
+# family -> (its benchmark configuration's file, the scopes' prefix, the
+# parts its decode step must carry: what the module's docstring names)
+FAMILIES = {
+    "gpt2": (None, "gpt2", {"embed", "attn", "mlp", "head"}),
+    "llama": ("mistral7b_l16", "llama", {"embed", "attn", "mlp", "head"}),
+    "longcat": ("longcat_flash_l4_ep32", "longcat",
+                {"embed", "mla", "moe", "ffn", "head"}),
+    "nemotron_h": ("nemotron3_super_l11_ep4", "nemotron",
+                   {"embed", "mamba", "attn", "moe", "head"}),
+    "mimo_v2": ("mimo_v25_l7_ep16", "mimo",
+                {"embed", "attn_full", "attn_window", "moe", "mlp", "head"}),
+    "mistral4": ("mistral_small4_l9_ep8", "mistral4",
+                 {"embed", "mla", "moe", "shared", "head"}),
+    "laguna": ("laguna_s21_l9_ep16", "laguna",
+               {"embed", "attn_full", "attn_window", "moe", "shared", "mlp",
+                "head"}),
+    "olmo_hybrid": ("olmo_hybrid7b_l12", "olmo",
+                    {"embed", "delta", "attn", "mlp", "head"}),
+}
+
+
+def tiny_model(family):
+    """The family's model at the widths its benchmark cell rehearses at."""
+    import importlib
+    import json
+    import os
+
+    config_file = FAMILIES[family][0]
+    if config_file is None:
+        return GPT2Config.tiny(vocab_size=384)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           config_file + ".json")) as f:
+        tiny = json.load(f)["tiny"]
+    return importlib.import_module(
+        "benchmarks.families." + family).config(tiny)
+
+
+def read_programs(path):
+    import json
+
+    with open(path / "programs.jsonl") as f:
+        return [json.loads(line) for line in f]
+
+
+def parts_of(row, prefix):
+    from benchmarks.lib import scopes
+
+    found = {scopes.scope_of(op_name) for op_name in row["ops"].values()}
+    return {s.split(".", 1)[1] for s in found if s.startswith(prefix + ".")}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_stop_profile_writes_every_programs_scope_table(family, tmp_path):
+    """``programs.jsonl`` beside the trace: a row for the decode program
+    and one for each prefill rung, every ``op_name`` text, and the decode
+    row's scopes are the parts the family's docstring names, ``head`` and
+    ``embed`` among them."""
+    import importlib
+
+    _file, prefix, parts = FAMILIES[family]
+    engine = JaxLLMEngine(EngineConfig(
+        model=tiny_model(family), max_batch_size=2, max_seq_len=32))
+    tracing.start_profile(str(tmp_path))
+    try:
+        engine.add_request("ab", SamplingParams(max_tokens=3, stop_token=-1))
+        by_hand(engine)
+    finally:
+        tracing.stop_profile()
+    assert tr.find_traces(str(tmp_path))  # the trace is there too
+    rows = read_programs(tmp_path)
+    for row in rows:
+        assert set(row) == {"module", "fingerprint", "ops"}
+        assert isinstance(row["fingerprint"], str) and row["fingerprint"]
+        assert all(isinstance(k, str) and isinstance(v, str)
+                   for k, v in row["ops"].items())
+        assert not any(name.startswith("%") for name in row["ops"])
+    # Other engines of this process may be alive too: this family's rows.
+    mine = [row for row in rows if parts_of(row, prefix)]
+    decode = [row for row in mine if row["module"] == "jit__lambda"]
+    rungs = [row for row in mine if row["module"] == "jit_prefill_one"]
+    assert decode and len(rungs) >= len(engine._prefill_one) >= 1
+    assert all(parts_of(row, prefix) == parts for row in decode)
+    for row in rungs:
+        assert parts_of(row, prefix) >= parts - {"embed"}  # a gather may fuse
+    doc = importlib.import_module(
+        "ray_tpu.models." + {"llama": "llama_decode"}.get(family, family)
+    ).__doc__
+    assert all(f"``{prefix}.{part}``" in doc for part in parts), family
+
+
+def test_gpt2_loss_carries_its_scopes_through_the_backward_pass(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt2_init, gpt2_loss
+
+    cfg = GPT2Config.tiny(vocab_size=384)
+    params = gpt2_init(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((2, 17), jnp.int32)
+
+    def step(p, t):
+        return jax.value_and_grad(lambda q: gpt2_loss(q, t, cfg))(p)
+
+    compiled = jax.jit(step).lower(params, tokens).compile()
+    tracing.start_profile(str(tmp_path))
+    try:
+        jax.block_until_ready(compiled(params, tokens))
+    finally:
+        tracing.stop_profile()
+    [row] = [r for r in read_programs(tmp_path) if r["module"] == "jit_step"
+             and parts_of(r, "gpt2")]
+    assert parts_of(row, "gpt2") == {"embed", "attn", "mlp", "head"}
+    backward = [v for v in row["ops"].values() if "transpose(jvp(" in v]
+    assert any("gpt2.attn" in v for v in backward)
+    assert any("gpt2.head" in v for v in backward)
+
+
+def test_one_unreadable_program_costs_its_row_and_nothing_else(
+        tmp_path, monkeypatch, caplog):
+    import jax
+    import jax.numpy as jnp
+
+    class Unreadable:
+        fingerprint = b"x"
+
+        def hlo_modules(self):
+            raise RuntimeError("no text for this one")
+
+    def f(x):
+        with jax.named_scope("fam.mlp"):
+            for _ in range(tracing.MIN_PROGRAM_OPS):
+                x = jnp.sin(x) @ x
+        return x
+
+    compiled = jax.jit(f).lower(jnp.ones((8, 8))).compile()
+    real = tracing._live_executables
+    monkeypatch.setattr(tracing, "_live_executables",
+                        lambda: [Unreadable(), *real()])
+    tracing.start_profile(str(tmp_path))
+    jax.block_until_ready(compiled(jnp.ones((8, 8))))
+    with caplog.at_level("WARNING", logger=tracing.__name__):
+        tracing.stop_profile()  # returns
+    assert "could not be read" in caplog.text
+    assert tr.find_traces(str(tmp_path))
+    assert any(r["module"] == "jit_f" for r in read_programs(tmp_path))
+
+
+def test_a_session_someone_else_started_gets_no_table(tmp_path):
+    """A process that never calls ``start_profile`` writes nothing: the
+    path is remembered there and nowhere else."""
+    import jax
+
+    assert tracing._profile_path is None
+    jax.profiler.start_trace(str(tmp_path))
+    tracing.stop_profile()
+    assert not (tmp_path / "programs.jsonl").exists()
+    with pytest.raises(RuntimeError):
+        tracing.stop_profile()  # no session: jax's own error, nothing written
+    assert not (tmp_path / "programs.jsonl").exists()
+
+
+CACHED_SCOPE = """
+import sys, jax, jax.numpy as jnp
+from ray_tpu.util import tracing
+jax.config.update("jax_enable_compilation_cache", True)  # off under tests
+jax.config.update("jax_compilation_cache_dir", sys.argv[2])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+hits = []
+jax.monitoring.register_event_listener(
+    lambda name, **kw: hits.append(name) if name.endswith("cache_hits") else None)
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        for _ in range(tracing.MIN_PROGRAM_OPS):
+            x = jnp.sin(x) @ x
+    return x
+text = jax.jit(f).lower(jnp.ones((8, 8))).compile().as_text()
+print(len(hits), sorted({v.split("/")[1] for v in tracing.program_ops(text).values() if "/" in v}))
+"""
+
+
+def test_a_program_from_the_compile_cache_names_the_source_that_compiled_it(
+        tmp_path):
+    """What docs/observability.md warns of: the persistent cache's key leaves
+    metadata out, so the second process, whose source says ``fam.mlp``, is
+    handed the first one's executable and its ``fam.attn``.  If this fails
+    jax has changed and the warning (and PERF.md section 3) can go."""
+    def run(scope):
+        out = subprocess.run(
+            [sys.executable, "-c", CACHED_SCOPE, scope, str(tmp_path)],
+            capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return out.stdout.strip().splitlines()[-1]
+
+    assert run("fam.attn") == "0 ['fam.attn']"
+    hits, scopes_seen = run("fam.mlp").split(" ", 1)
+    assert int(hits) >= 1 and scopes_seen == "['fam.attn']"
