@@ -149,10 +149,6 @@ def _attention(q, k, v, cfg: GPT2Config, mesh):
         return jax.checkpoint(
             lambda q, k, v: reference_attention(q, k, v, causal=True)
         )(q, k, v)
-    if cfg.attention == "flash":
-        from ..ops.attention import flash_attention
-
-        return flash_attention(q, k, v, causal=True)
     if cfg.attention == "ring":
         from ..parallel.ring_attention import ring_attention
 
@@ -184,11 +180,19 @@ def _block(x, layer, cfg: GPT2Config, mesh):
         y = _layernorm(x, layer["ln1_g"], layer["ln1_b"])
         qkv = jnp.einsum("bse,ethd->bsthd", y, layer["wqkv"]) + layer["bqkv"]
         qkv = _ckpt_name(qkv, "qkv")
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        q = wlc(q, P("batch", "seq", "heads", "kv"), mesh)
-        k = wlc(k, P("batch", "seq", "heads", "kv"), mesh)
-        v = wlc(v, P("batch", "seq", "heads", "kv"), mesh)
-        o = _attention(q, k, v, cfg, mesh)
+        if cfg.attention == "flash":
+            # the kernels address q, k and v inside the projection's result
+            # and write ONE d(qkv): q, k, v are never formed
+            from ..ops.attention import flash_attention_packed
+
+            qkv = wlc(qkv, P("batch", "seq", None, "heads", "kv"), mesh)
+            o = flash_attention_packed(qkv, causal=True)
+        else:
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            q = wlc(q, P("batch", "seq", "heads", "kv"), mesh)
+            k = wlc(k, P("batch", "seq", "heads", "kv"), mesh)
+            v = wlc(v, P("batch", "seq", "heads", "kv"), mesh)
+            o = _attention(q, k, v, cfg, mesh)
         o = _ckpt_name(o, "attn_out")
         x = x + (jnp.einsum("bshd,hde->bse", o, layer["wo"]) + layer["bo"]).astype(x.dtype)
         x = _ckpt_name(x, "attn_resid")

@@ -1,2 +1,6 @@
-from .attention import flash_attention, reference_attention  # noqa: F401
+from .attention import (  # noqa: F401
+    flash_attention,
+    flash_attention_packed,
+    reference_attention,
+)
 from .decode_attention import decode_attention  # noqa: F401

@@ -12,7 +12,13 @@ reference has no attention kernels of its own).  TPU-first design:
     back to the reference off-TPU.  Forward kernel + custom VJP backed by
     the one Pallas backward kernel below (``_flash_bwd_kernel``), which
     rebuilds each tile pair's probabilities once from the saved softmax
-    statistics.
+    statistics.  The entry for q, k and v that come as three arrays
+    (separate projections: ``models/llama.py``'s grouped heads,
+    ``models/vit.py``).
+  - ``flash_attention_packed``: the same two kernels for a caller that has
+    ONE projected array ``[B, S, 3, H, D]`` (``models/gpt2.py``): they
+    address q, k and v inside it and write one d(qkv) of its shape.  Which
+    entry is taken follows from what the caller has, not from an option.
 """
 
 from __future__ import annotations
@@ -53,13 +59,21 @@ def reference_attention(
 # Pallas TPU flash attention: one forward kernel, one backward kernel
 # --------------------------------------------------------------------------
 #
-# Every operand and result crosses the ``pallas_call`` boundary as
-# ``[B*H, D, S]``: the sequence along the lanes, the head's 64 or 128 down
-# the sublanes.  That is where the compiled training step keeps q, k, v and
-# their gradients anyway (the projection leaves ``[B, 3, H, D, S]``), so the
-# folds around the kernels are bitcasts and no ``copy`` program stands
-# between a projection and a kernel; and a ``[D, S]`` tile is dense where a
-# ``[S, 64]`` one fills half of every 128-lane tile row (PERF.md, PR 50).
+# Every operand and result crosses the ``pallas_call`` boundary with the
+# sequence along the lanes and the head's 64 or 128 down the sublanes:
+# ``[B*H, D, S]`` (``_fold``) for q, k, v, dO, out, dq, dk, dv of the
+# unpacked entry.  That is where the compiled training step keeps them (the
+# q/k/v projection leaves ``[B, 3, H, D, S]``), so the folds around the
+# kernels are bitcasts and no ``copy`` program stands between a projection
+# and a kernel; and a ``[D, S]`` tile is dense where a ``[S, 64]`` one fills
+# half of every 128-lane tile row (PERF.md, PR 50).  The packed entry goes
+# one step further: the projection's result crosses as it lies, ONE
+# ``[B, 3, H, D, S]`` operand given three times, a head's q, k or v tile
+# addressed inside it by its ``BlockSpec`` (``_head_spec``), and the
+# backward's three results are views of ONE d(qkv) of that shape; only
+# ``out`` and dO stay ``[B*H, D, S]``.  So the step neither splits the
+# projection's result into three arrays (forward, and again under the
+# layers' remat) nor lays three gradients back into one (PERF.md, PR 59).
 #
 # Both kernels hold a tile pair's scores TRANSPOSED, ``k q^T`` =
 # [block_k, block_q], keys down the sublanes and queries along the lanes.
@@ -146,8 +160,9 @@ _TILE = 512
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, k_rows, *,
                       block_q: int, block_k: int, sk: int, causal: bool,
-                      scale: float):
-    """Grid: (batch*heads, Sq/block_q), the second axis in order.  Ref tiles
+                      scale: float, tile_axis: int = 1):
+    """Grid: (batch*heads, Sq/block_q), or (batch, heads, Sq/block_q) over
+    the packed projection; the last axis (``tile_axis``) in order.  Ref tiles
     (leading dim squeezed): q_ref [D, block_q] (q^T), k_ref/v_ref [D, Sk]
     (k^T, v^T: the same block for every query tile of a head), o_ref
     [D, block_q] (out^T), lse_ref [1, block_q] (per-query logsumexp, saved
@@ -156,7 +171,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, k_rows, *,
     import jax.experimental.pallas as pl
 
     iota = jax.lax.broadcasted_iota
-    q_block = pl.program_id(1)
+    q_block = pl.program_id(tile_axis)
 
     @pl.when(q_block == 0)
     def _():
@@ -210,38 +225,81 @@ def _unfold(x, b: int):
     return x.reshape(b, bh // b, d, s).transpose(0, 3, 1, 2)
 
 
-def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
+def _head_spec(rows: int, cols: int, heads: Optional[int] = None,
+               plane: Optional[int] = None, *, resident: bool = False):
+    """The ``BlockSpec`` of one head's ``[rows, cols]`` tile, at the grid
+    cell's column tile or, ``resident``, at the head's only one.  ``heads``
+    None: a ``[B*H, rows, S]`` operand under the grid ``(B*H, tiles)``.
+    Else the grid is ``(B, H, tiles)`` (``_grid``) and the operand that same
+    array (``plane`` None: head ``b * H + h``) or the packed projection
+    ``[B, 3, H, D, S]``, the tile addressed in its plane ``plane`` (q 0, k 1,
+    v 2) where it lies."""
+    import jax.experimental.pallas as pl
+
+    col = (lambda j: 0) if resident else (lambda j: j)
+    if heads is None:
+        return pl.BlockSpec((None, rows, cols), lambda i, j: (i, 0, col(j)))
+    if plane is None:
+        return pl.BlockSpec((None, rows, cols),
+                            lambda b, h, j: (b * heads + h, 0, col(j)))
+    return pl.BlockSpec((None, None, None, rows, cols),
+                        lambda b, h, j: (b, plane, h, 0, col(j)))
+
+
+def _heads_of(q, k, v):
+    """(B*H, D, Sq, Sk, H if packed) of a kernel's q, k, v: three
+    ``[B*H, D, S]`` arrays, or the ONE packed ``[B, 3, H, D, S]`` given for
+    all three."""
+    if q.ndim == 5:
+        assert q is k and q is v, "one packed projection, three times"
+        b, _three, h, d, s = q.shape
+        return b * h, d, s, s, h
+    bh, d, sq = q.shape
+    return bh, d, sq, k.shape[2], None
+
+
+def _grid(bh: int, tiles: int, heads: Optional[int]):
+    """The grid, its static arguments to a kernel body and its semantics:
+    heads by tiles, the tiles' axis last and in order (a scratch or a
+    result block lives across it).  Over the packed projection batch and
+    heads are an axis each, so that no index map divides."""
+    import jax.experimental.pallas.tpu as pltpu
+
+    grid = (bh, tiles) if heads is None else (bh // heads, heads, tiles)
+    semantics = ("parallel",) * (len(grid) - 1) + ("arbitrary",)
+    return grid, len(grid) - 1, pltpu.CompilerParams(
+        dimension_semantics=semantics)
+
+
+def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                interpret: bool):
-    """q [B*H, D, Sq], k/v [B*H, D, Sk] (``_fold``ed) -> (out [B*H, D, Sq],
-    lse [B*H, 1, Sq] float32)."""
+    """q [B*H, D, Sq], k/v [B*H, D, Sk] (``_fold``ed), or the packed
+    projection [B, 3, H, D, S] as all three, its planes addressed by the
+    ``BlockSpec``s -> (out [B*H, D, Sq], lse [B*H, 1, Sq] float32)."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    bh, d, sq = q.shape
-    sk = k.shape[2]
+    bh, d, sq, sk, heads = _heads_of(q, k, v)
+    # k_rows is filled at a head's first query tile: that axis runs in order
+    grid, tile_axis, params = _grid(bh, sq // block_q, heads)
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, sk=sk,
-        causal=causal, scale=scale,
+        causal=causal, scale=d ** -0.5, tile_axis=tile_axis,
     )
-    query_tile = pl.BlockSpec((None, d, block_q), lambda i, qb: (i, 0, qb))
-    whole_k = pl.BlockSpec((None, d, sk), lambda i, qb: (i, 0, 0))
     return pl.pallas_call(
         kernel,
-        grid=(bh, sq // block_q),
-        in_specs=[query_tile, whole_k, whole_k],
-        out_specs=[
-            query_tile,
-            pl.BlockSpec((None, 1, block_q), lambda i, qb: (i, 0, qb)),
-        ],
+        grid=grid,
+        in_specs=[_head_spec(d, block_q, heads, 0),
+                  _head_spec(d, sk, heads, 1, resident=True),
+                  _head_spec(d, sk, heads, 2, resident=True)],
+        out_specs=[_head_spec(d, block_q, heads),
+                   _head_spec(1, block_q, heads)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, d, sq), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((sk, d), k.dtype)],
-        # k_rows is filled at a head's first query tile: that axis runs in
-        # order
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
     )(q, k, v)
 
@@ -249,9 +307,10 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int, block_k: int,
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                       block_q: int, block_k: int, sq: int, causal: bool,
-                      scale: float):
+                      scale: float, tile_axis: int = 1):
     """dQ, dK, dV in one pass that builds s, p, dp, ds once a tile pair: 5
-    products and 1 exponential.  Grid (batch*heads, Sk/block_k), the K/V tile
+    products and 1 exponential.  Grid (batch*heads, Sk/block_k), or (batch,
+    heads, Sk/block_k) over the packed projection (``tile_axis`` 2), the K/V tile
     resident, an inner loop over the query tiles from the diagonal on.  Refs,
     all transposed: q_ref/do_ref [D, Sq], k_ref/v_ref [D, block_k],
     lse_ref/delta_ref [1, Sq], dk_ref/dv_ref [D, block_k]; dq_ref [D, Sq]
@@ -271,7 +330,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     import jax.experimental.pallas as pl
 
     iota = jax.lax.broadcasted_iota
-    k_block = pl.program_id(1)
+    k_block = pl.program_id(tile_axis)
     # bf16 matmul operands, f32 accumulation/arithmetic (see fwd kernel).
     # The scale goes into the resident key tile once: s = q (scale k)^T and
     # dq = ds (scale k) need no other; dk takes it after the loop.
@@ -313,49 +372,78 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref[:] = (dk_acc[:] * scale).astype(dk_ref.dtype)
     dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
-    @pl.when(k_block == pl.num_programs(1) - 1)
+    @pl.when(k_block == pl.num_programs(tile_axis) - 1)
     def _():
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, g, causal: bool, scale: float, block_q: int,
-               block_k: int, interpret: bool):
-    """Everything ``_fold``ed, [B*H, D, S]: -> (dq, dk, dv) so too."""
+def _flash_bwd_packed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                             dqkv_ref, dq_acc, dk_acc, dv_acc, *, block_k: int,
+                             tile_axis: int, **static):
+    """``_flash_bwd_kernel`` with its three results as views of ONE:
+    dqkv_ref [3, D, S] is a head's planes of d(qkv) [B, 3, H, D, S], its
+    block kept along the key-tile axis as ``dq``'s is.  A key tile's dk and
+    dv are its columns of planes 1 and 2, dq is plane 0, whole."""
+    import jax.experimental.pallas as pl
+
+    keys = pl.ds(
+        pl.multiple_of(pl.program_id(tile_axis) * block_k, block_k), block_k)
+    _flash_bwd_kernel(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dqkv_ref.at[0],
+        dqkv_ref.at[1, :, keys], dqkv_ref.at[2, :, keys], dq_acc, dk_acc,
+        dv_acc, block_k=block_k, tile_axis=tile_axis, **static)
+
+
+def _flash_bwd(q, k, v, o, lse, g, causal: bool, block_q: int, block_k: int,
+               interpret: bool):
+    """q, k, v, o, g ``_fold``ed, [B*H, D, S]: -> (dq, dk, dv) so too.  With
+    the packed projection [B, 3, H, D, S] as q, k and v: -> d(qkv) of that
+    shape, ONE result whose planes the kernel writes where they lie."""
     import jax.experimental.pallas as pl
     import jax.experimental.pallas.tpu as pltpu
 
-    bh, d, sq = q.shape
-    sk = k.shape[2]
+    bh, d, sq, sk, heads = _heads_of(q, k, v)
     # delta_i = Σ_d dO_id · O_id  (per query), in plain XLA: a sum down the
     # sublanes of [D, S], its result along the lanes like lse.
     delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(
         1, keepdims=True)
 
-    kernel = functools.partial(
-        _flash_bwd_kernel, block_q=block_q, block_k=block_k, sq=sq,
-        causal=causal, scale=scale,
-    )
-    whole_q = pl.BlockSpec((None, d, sq), lambda i, kb: (i, 0, 0))
-    key_tile = pl.BlockSpec((None, d, block_k), lambda i, kb: (i, 0, kb))
-    row = pl.BlockSpec((None, 1, sq), lambda i, kb: (i, 0, 0))
-    return pl.pallas_call(
-        kernel,
-        grid=(bh, sk // block_k),
-        in_specs=[whole_q, key_tile, key_tile, whole_q, row, row],
-        out_specs=[whole_q, key_tile, key_tile],
-        out_shape=[
+    # dq's block (d(qkv)'s) is revisited along the key tiles: that axis runs
+    # in order
+    grid, tile_axis, params = _grid(bh, sk // block_k, heads)
+    static = dict(block_q=block_q, block_k=block_k, sq=sq, causal=causal,
+                  scale=d ** -0.5, tile_axis=tile_axis)
+    whole_q = _head_spec(d, sq, heads, resident=True)
+    row = _head_spec(1, sq, heads, resident=True)
+    if heads is None:
+        kernel = functools.partial(_flash_bwd_kernel, **static)
+        key_tile = _head_spec(d, block_k)
+        out_specs = [whole_q, key_tile, key_tile]
+        out_shape = [
             jax.ShapeDtypeStruct((bh, d, sq), q.dtype),
             jax.ShapeDtypeStruct((bh, d, sk), k.dtype),
             jax.ShapeDtypeStruct((bh, d, sk), v.dtype),
-        ],
+        ]
+    else:
+        kernel = functools.partial(_flash_bwd_packed_kernel, **static)
+        out_specs = pl.BlockSpec((None, 3, None, d, sq),
+                                 lambda b, h, kb: (b, 0, h, 0, 0))
+        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[_head_spec(d, sq, heads, 0, resident=True),
+                  _head_spec(d, block_k, heads, 1),
+                  _head_spec(d, block_k, heads, 2),
+                  whole_q, row, row],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((d, sq), jnp.float32),
             pltpu.VMEM((d, block_k), jnp.float32),
             pltpu.VMEM((d, block_k), jnp.float32),
         ],
-        # dq's block is revisited along the key tiles: that axis runs in order
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
     )(q, k, v, g, lse, delta)
 
@@ -371,19 +459,56 @@ def _flash(q, k, v, causal, block_q, block_k, interpret):
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
     folded = _fold(q), _fold(k), _fold(v)
-    out, lse = _flash_fwd(*folded, causal, q.shape[-1] ** -0.5, block_q,
-                          block_k, interpret)
+    out, lse = _flash_fwd(*folded, causal, block_q, block_k, interpret)
     return _unfold(out, q.shape[0]), (*folded, out, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    grads = _flash_bwd(q, k, v, out, lse, _fold(g), causal,
-                       q.shape[1] ** -0.5, block_q, block_k, interpret)
+    grads = _flash_bwd(q, k, v, out, lse, _fold(g), causal, block_q, block_k,
+                       interpret)
     return tuple(_unfold(x, g.shape[0]) for x in grads)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_packed(qkv, causal, block_q, block_k, interpret):
+    return _flash_packed_fwd_rule(qkv, causal, block_q, block_k, interpret)[0]
+
+
+def _flash_packed_fwd_rule(qkv, causal, block_q, block_k, interpret):
+    # [B, S, 3, H, D] seen as [B, 3, H, D, S]: where the compiled step keeps
+    # the projection's result, so a bitcast there
+    packed = qkv.transpose(0, 2, 3, 4, 1)
+    out, lse = _flash_fwd(packed, packed, packed, causal, block_q, block_k,
+                          interpret)
+    return _unfold(out, qkv.shape[0]), (packed, out, lse)
+
+
+def _flash_packed_bwd_rule(causal, block_q, block_k, interpret, res, g):
+    packed, out, lse = res
+    dqkv = _flash_bwd(packed, packed, packed, out, lse, _fold(g), causal,
+                      block_q, block_k, interpret)
+    return (dqkv.transpose(0, 4, 1, 2, 3),)
+
+
+_flash_packed.defvjp(_flash_packed_fwd_rule, _flash_packed_bwd_rule)
+
+
+def _tiles(sq: int, sk: int, block_q: Optional[int], block_k: Optional[int]):
+    """The tiles' sides for sequences of ``sq`` queries and ``sk`` keys:
+    what was asked for, else ``_TILE``, the whole sequence where that is
+    shorter; an error where they do not divide the sequences."""
+    bq, bk = min(block_q or _TILE, sq), min(block_k or _TILE, sk)
+    if sq % bq or sk % bk:
+        raise ValueError(
+            f"flash attention: sequence lengths ({sq}, {sk}) are not "
+            f"multiples of the blocks ({bq}, {bk}); pad the sequence, pass "
+            "other blocks, or ask for the reference (force_reference=True)"
+        )
+    return bq, bk
 
 
 def flash_attention(
@@ -411,12 +536,29 @@ def flash_attention(
     on_tpu = _on_tpu()
     if force_reference or not (on_tpu or force_pallas):
         return reference_attention(q, k, v, causal=causal)
-    sq, sk = q.shape[1], k.shape[1]
-    bq, bk = min(block_q or _TILE, sq), min(block_k or _TILE, sk)
-    if sq % bq or sk % bk:
-        raise ValueError(
-            f"flash attention: sequence lengths ({sq}, {sk}) are not "
-            f"multiples of the blocks ({bq}, {bk}); pad the sequence, pass "
-            "other blocks, or ask for the reference (force_reference=True)"
-        )
+    bq, bk = _tiles(q.shape[1], k.shape[1], block_q, block_k)
     return _flash(q, k, v, causal, bq, bk, not on_tpu)
+
+
+def flash_attention_packed(
+    qkv, *, causal: bool = True, block_q: Optional[int] = None,
+    block_k: Optional[int] = None, force_pallas: bool = False,
+):
+    """``flash_attention`` for a caller that HAS one projected array: qkv
+    [B, S, 3, H, D] (q, k, v its planes, as ``"bse,ethd->bsthd"`` gives
+    them) -> [B, S, H, D].  The kernels address q, k and v inside it and the
+    backward writes ONE d(qkv) of its shape, so nothing splits the
+    projection's result on the way in and nothing lays three gradients back
+    into one on the way out.  The same kernels, tiles and errors as
+    ``flash_attention``, which remains for separate projections; off a TPU
+    the unforced path is the reference on the three planes."""
+    if qkv.ndim != 5 or qkv.shape[2] != 3:
+        raise ValueError(
+            f"flash attention: a packed projection is [B, S, 3, H, D], not "
+            f"{qkv.shape}")
+    on_tpu = _on_tpu()
+    if not (on_tpu or force_pallas):
+        return reference_attention(
+            qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], causal=causal)
+    bq, bk = _tiles(qkv.shape[1], qkv.shape[1], block_q, block_k)
+    return _flash_packed(qkv, causal, bq, bk, not on_tpu)

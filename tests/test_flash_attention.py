@@ -3,8 +3,11 @@ CPU: forward and the three gradients against ``reference_attention`` over
 the tile geometries the loops meet (tiles under the diagonal, on it, and
 none above it) and at the callers' head sizes, the backward's one kernel
 against the parent's two, what a tile pair costs and how its operands lie
-(``[B*H, D, S]``: the sequence along the lanes), and the work the loops'
-bounds leave (``flash_tile_work``)."""
+(``[B*H, D, S]``: the sequence along the lanes), the work the loops'
+bounds leave (``flash_tile_work``), and the packed entry
+(``flash_attention_packed``: the same kernels over the ONE projected array
+``[B, S, 3, H, D]``, addressed where it lies) against the unpacked one, bit
+for bit, and from ``models/gpt2.py``'s block."""
 
 import jax
 import jax.numpy as jnp
@@ -12,8 +15,10 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import attention
+from ray_tpu.models import gpt2
 from ray_tpu.ops.attention import (
     flash_attention,
+    flash_attention_packed,
     flash_tile_work,
     reference_attention,
 )
@@ -91,6 +96,85 @@ def test_the_callers_heads_with_the_sequence_along_the_lanes(d, causal, sq,
     that both loops run several pairs and ``Sq != Sk`` is met too."""
     _check_against_the_reference(*_qkv(sq, sk, dtype, b=1, d=d), 64, 64,
                                  causal)
+
+
+def _planes(qkv):
+    return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+
+def _check_packed_against_unpacked(qkv, bq, bk, causal):
+    """``out`` and d(qkv) of the packed entry EQUAL the unpacked entry's on
+    the planes (stacked back by the slices' own gradient): the same kernels
+    on the same numbers.  The unpacked entry's against the reference, to the
+    type's tolerance, and so the packed one's."""
+    weight = jax.random.normal(
+        jax.random.PRNGKey(7), qkv.shape[:2] + qkv.shape[3:], jnp.float32)
+    blocks = dict(causal=causal, block_q=bq, block_k=bk, force_pallas=True)
+
+    def packed(qkv):
+        return flash_attention_packed(qkv, **blocks)
+
+    def unpacked(qkv):
+        return flash_attention(*_planes(qkv), **blocks)
+
+    def loss(attn):
+        return lambda qkv: (attn(qkv).astype(jnp.float32) * weight).sum()
+
+    np.testing.assert_array_equal(np.asarray(packed(qkv), np.float32),
+                                  np.asarray(unpacked(qkv), np.float32))
+    got, want = jax.grad(loss(packed))(qkv), jax.grad(loss(unpacked))(qkv)
+    assert got.shape == qkv.shape and got.dtype == qkv.dtype
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    _check_against_the_reference(*_planes(qkv), bq, bk, causal)
+
+
+def _packed(s, dtype, b=2, h=2, d=8, seed=0):
+    return jax.random.normal(jax.random.PRNGKey(seed), (b, s, 3, h, d), dtype)
+
+
+# one projected array has as many keys as queries
+PACKED_GEOMETRIES = [name for name, (sq, sk, *_) in GEOMETRIES.items()
+                     if sq == sk]
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE), ids=lambda d: d.__name__)
+@pytest.mark.parametrize("geometry", PACKED_GEOMETRIES)
+def test_the_packed_entry_gives_the_unpacked_ones_bits(geometry, dtype):
+    s, _sk, bq, bk, causal = GEOMETRIES[geometry]
+    _check_packed_against_unpacked(_packed(s, dtype), bq, bk, causal)
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE), ids=lambda d: d.__name__)
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "no mask"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_the_packed_entry_at_the_callers_heads(d, causal, dtype):
+    """Heads of 64 (``models/gpt2.py``, the packed entry's caller) and of
+    128, planes addressed inside ``[B, 3, H, D, S]`` under the grid ``(B, H,
+    tiles)`` (2 rows of 3 heads, so that a row for a head shows), tiles of
+    64: several key tiles write their columns of planes 1 and 2 of the one
+    result, and ``out`` / dO / the statistics are head ``b * H + h``'s."""
+    _check_packed_against_unpacked(_packed(128, dtype, b=2, h=3, d=d), 64, 64,
+                                   causal)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "no mask"])
+def test_the_packed_entry_off_a_tpu_is_the_reference_on_the_planes(causal):
+    qkv = _packed(48, jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention_packed(qkv, causal=causal)),
+        np.asarray(reference_attention(*_planes(qkv), causal=causal)))
+
+
+@pytest.mark.parametrize("shape,message", [
+    ((2, 48, 3, 2, 8), "not multiples of the blocks"),  # as flash_attention
+    ((2, 32, 2, 2, 8), "a packed projection is"),
+    ((2, 32, 2, 8), "a packed projection is"),
+])
+def test_the_packed_entry_refuses_what_the_kernels_cannot_tile(shape, message):
+    with pytest.raises(ValueError, match=message):
+        flash_attention_packed(jnp.zeros(shape), block_q=32, block_k=32,
+                               force_pallas=True)
 
 
 def _parents_two_kernels(q, k, v, do, block_q, block_k):
@@ -214,8 +298,27 @@ def _products(jaxpr):
     return sorted(found)
 
 
+def _gradients(entry, **blocks):
+    """``arguments -> gradients`` of the summed output through ``entry``:
+    q, k, v (``[B, S, H, D]`` each) through ``flash_attention``, or the one
+    ``[B, S, 3, H, D]`` through ``flash_attention_packed``."""
+    call = {"three arrays": flash_attention,
+            "one packed array": flash_attention_packed}[entry]
+
+    def grads(*arrays):
+        return jax.grad(
+            lambda *a: call(*a, force_pallas=True, **blocks).sum(),
+            argnums=tuple(range(len(arrays))))(*arrays)
+
+    return grads
+
+
+ENTRIES = ["three arrays", "one packed array"]
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("causal", [True, False])
-def test_a_tile_pair_costs_what_the_mathematics_has(causal):
+def test_a_tile_pair_costs_what_the_mathematics_has(causal, entry):
     """Forward: 2 products (k q^T, v^T p^T) and 2 exponentials (p, and the
     accumulator's rescale, one a QUERY) a tile pair; backward: the 5 products
     the mathematics has (s, dv, dp, dk, dq) and 1 exponential, in ONE kernel
@@ -232,17 +335,18 @@ def test_a_tile_pair_costs_what_the_mathematics_has(causal):
     q^T ds over the lanes of both sides, ``(1, 1)``; s^T and dp^T = v dO^T
     over the sublanes of both, ``(0, 0)``, which leaves the turn of the
     [D, block_k] side to the compiler: no ``transpose`` in that kernel (the
-    chip preferred it to two tiles turned a grid cell: PERF.md, PR 50)."""
-    q, k, v = _qkv(64, 64)
+    chip preferred it to two tiles turned a grid cell: PERF.md, PR 50).
 
-    def grads(q, k, v):
-        return jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, causal=causal, block_q=16, block_k=16,
-            force_pallas=True).sum(), argnums=(0, 1, 2))(q, k, v)
-
-    kernels = _kernels(grads, q, k, v)
-    assert sorted(kernels) == [2, 3]  # (out, lse); (dq, dk, dv): no other
-    forward, backward = (kernels[n].params["jaxpr"] for n in (2, 3))
+    The packed entry runs the same two bodies: its backward's three results
+    are views of ONE (d(qkv)), so that kernel has one output."""
+    arrays = {"three arrays": _qkv(64, 64),
+              "one packed array": [_packed(64, jnp.float32)]}[entry]
+    kernels = _kernels(
+        _gradients(entry, causal=causal, block_q=16, block_k=16), *arrays)
+    results = {"three arrays": 3, "one packed array": 1}[entry]
+    # (out, lse); (dq, dk, dv) or d(qkv): no other
+    assert sorted(kernels) == sorted([2, results])
+    forward, backward = (kernels[n].params["jaxpr"] for n in (2, results))
     assert _per_loop(forward, "dot_general") == [2]
     assert _per_loop(forward, "exp") == [2]
     assert _per_loop(backward, "dot_general") == [5]
@@ -263,13 +367,8 @@ def test_every_operand_crosses_as_heads_by_depth_by_sequence():
     ``copy`` (``tests/test_tpu_compile.py`` holds that on the cells' step)."""
     b, h, d, sq, sk = 2, 2, 8, 64, 32
     q, k, v = _qkv(sq, sk, b=b, h=h, d=d)
-
-    def grads(q, k, v):
-        return jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, block_q=16, block_k=16, force_pallas=True).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-
-    calls = _kernels(grads, q, k, v)
+    calls = _kernels(_gradients("three arrays", block_q=16, block_k=16),
+                     q, k, v)
     shapes = lambda variables: [tuple(x.aval.shape) for x in variables]
     query, key, row = (b * h, d, sq), (b * h, d, sk), (b * h, 1, sq)
     assert shapes(calls[2].invars) == [query, key, key]
@@ -281,6 +380,89 @@ def test_every_operand_crosses_as_heads_by_depth_by_sequence():
     assert folded.shape == query
     assert float(folded[1 * h + 1, 3, 5]) == float(x[1, 5, 1, 3])
     np.testing.assert_array_equal(attention._unfold(folded, b), x)
+
+
+def test_the_packed_projection_crosses_once_as_it_lies():
+    """The packed entry hands the kernels ONE array, ``[B, 3, H, D, S]`` (the
+    projection's ``[B, S, 3, H, D]`` with the sequence along the lanes: what
+    the compiled step keeps), three times in, and takes ONE of that shape
+    back from the backward; ``out``, dO and the statistics as the unpacked
+    entry's.  Nothing slices, concatenates or updates a slice on either side
+    of a kernel: between the caller's array and the kernels stand two
+    ``transpose``s of the packed array (in; d(qkv) out), which the compiled
+    step makes bitcasts, and the folds of ``out`` and dO."""
+    b, h, d, s = 2, 3, 8, 64
+    qkv = _packed(s, jnp.float32, b=b, h=h, d=d)
+    grads = _gradients("one packed array", block_q=16, block_k=16)
+    calls = _kernels(grads, qkv)
+    shapes = lambda variables: [tuple(x.aval.shape) for x in variables]
+    packed, head, row = (b, 3, h, d, s), (b * h, d, s), (b * h, 1, s)
+    assert shapes(calls[2].invars) == [packed] * 3
+    assert len(set(calls[2].invars)) == 1  # the same array, not three
+    assert shapes(calls[2].outvars) == [head, row]
+    assert shapes(calls[1].invars) == [packed] * 3 + [head, row, row]
+    assert len(set(calls[1].invars[:3])) == 1
+    assert shapes(calls[1].outvars) == [packed]
+    jaxpr = jax.make_jaxpr(grads)(qkv).jaxpr
+    for moved in ("slice", "dynamic_slice", "gather", "concatenate",
+                  "dynamic_update_slice", "scatter", "pad"):
+        assert _count(jaxpr, moved) == _count(
+            calls[1].params["jaxpr"], moved) + _count(
+            calls[2].params["jaxpr"], moved), moved
+    # a head's plane where the BlockSpecs say it is
+    seen = qkv.transpose(0, 2, 3, 4, 1)
+    assert float(seen[1, 2, 1, 3, 5]) == float(qkv[1, 5, 2, 1, 3])
+
+
+TOKENS = jax.random.randint(jax.random.PRNGKey(5), (2, 33), 0, 512)
+
+
+@pytest.mark.parametrize("mode", ["flash", "dense", "dense_remat", "ring",
+                                  "ulysses"])
+def test_gpt2s_block_hands_over_what_it_has(mode, monkeypatch):
+    """``attention="flash"`` reaches the packed entry with the projection's
+    one ``[B, S, 3, H, D]`` and never ``flash_attention`` nor three arrays;
+    every other mode still gets q, k, v, ``[B, S, H, D]`` each."""
+    cfg = gpt2.GPT2Config.tiny(dtype="float32", attention=mode)
+    seen = []
+
+    def spy(name, shapes_of, result):
+        def call(*args, **kwargs):
+            seen.append((name, shapes_of(args)))
+            return result(*args)
+        return call
+
+    three = lambda args: [a.shape for a in args[:3]]
+    monkeypatch.setattr(attention, "flash_attention_packed", spy(
+        "packed", lambda args: args[0].shape,
+        lambda qkv: reference_attention(*_planes(qkv))))
+    monkeypatch.setattr(attention, "flash_attention", spy(
+        "flash_attention", three, reference_attention))
+    monkeypatch.setattr(gpt2, "_attention", spy(
+        "three arrays", three,
+        lambda q, k, v, *_: reference_attention(q, k, v)))
+    params = gpt2.gpt2_init(jax.random.PRNGKey(0), cfg)
+    gpt2.gpt2_loss(params, TOKENS, cfg)
+    b, s, h, d = 2, 32, cfg.n_head, cfg.head_dim
+    want = (("packed", (b, s, 3, h, d)) if mode == "flash"
+            else ("three arrays", [(b, s, h, d)] * 3))
+    assert seen == [want]  # the layers are one scan: traced once
+
+
+def test_gpt2s_loss_through_the_packed_entry_is_the_dense_ones():
+    """``gpt2_loss`` and every gradient at the ``tiny`` widths on the CPU,
+    where the packed entry takes its reference path: ``attention="dense"``'s
+    numbers (the tolerance of ``tests/test_models.py``'s remat check)."""
+    dense = gpt2.GPT2Config.tiny(dtype="float32")
+    flash = gpt2.GPT2Config.tiny(dtype="float32", attention="flash")
+    params = gpt2.gpt2_init(jax.random.PRNGKey(0), dense)
+    got, want = (jax.value_and_grad(
+        lambda p: gpt2.gpt2_loss(p, TOKENS, cfg))(params)
+        for cfg in (flash, dense))
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
 
 
 def _brute_force(sq, sk, bq, bk, causal):

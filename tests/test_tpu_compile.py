@@ -77,6 +77,28 @@ def test_flash_forward_backward_compiles_to_pallas(
     assert text.count("tpu_custom_call") == 2
 
 
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_packed_flash_forward_backward_compiles_to_pallas(
+    one_chip, as_if_on_tpu, shape
+):
+    """The packed entry over the projection's one ``[B, S, 3, H, D]``: the
+    ``BlockSpec``s that address a head's plane inside ``[B, 3, H, D, S]``
+    (five dims, three of them squeezed) and the backward's one result block
+    ``(3, D, S)`` are what Mosaic must take.  Alone, a row-major parameter
+    is turned once on its way in; that no such turn is left in the cells'
+    step is ``test_training_cells_step_turns_no_kernel_operand``'s."""
+    b, s, h, d = shape
+
+    def loss(qkv):
+        return attention.flash_attention_packed(qkv).astype(jnp.float32).sum()
+
+    qkv = jax.ShapeDtypeStruct((b, s, 3, h, d), jnp.bfloat16,
+                               sharding=one_chip)
+    text = jax.jit(jax.grad(loss)).lower(qkv).compile().as_text()
+    # the forward kernel and the one backward kernel, as the unpacked entry's
+    assert text.count("tpu_custom_call") == 2
+
+
 @pytest.fixture(scope="module")
 def training_step(one_chip):
     """``gpt2_medium``'s whole optimizer step as ``benchmarks/jobs/
@@ -139,24 +161,25 @@ def test_training_cells_step_compiles_with_its_three_kernels(training_step):
 
 
 def test_training_cells_step_turns_no_kernel_operand(training_step):
-    """The kernels take q, k, v, dO and give out, dq, dk, dv as
-    ``[B*H, D, S]``, which is where the compiled step keeps them (the q/k/v
-    projection leaves ``[B, 3, H, D, S]``: the sequence along the lanes), so
-    nowhere in the step, the layers' two loop bodies included, does a
-    ``copy`` or a ``transpose`` produce an array of a kernel operand's size,
-    and no ``slice_bitcast_fusion``
-    splits the projection into arrays that a copy then turns.  Until PR 50
-    the kernels asked for ``[B*H, S, D]`` and a layer ran nine such copies
-    and two such fusions around its three kernels (108 ms of an 801 ms step
-    on the chip).  What is left is today's truth, pinned: the three fusions
-    that lay dq, dk and dv into d(qkv) (``copy_bitcast_fusion``), and the
-    split of the projection's result as a plain ``fusion`` a body (the next
-    issue's: a packed entry that indexes the result where it lies).  An
-    array is a kernel operand's if it has its size and keeps the head's 64
-    as an axis: the residual stream's own ``bf16[32,1024,1024]`` copies are
-    not the kernels' and not counted."""
+    """The kernels take q, k and v INSIDE the projection's result, as the
+    compiled step keeps it (``[B, 3, H, D, S]``: the sequence along the
+    lanes), give ``out`` and take dO as ``[B*H, D, S]``, and write ONE
+    d(qkv) of the projection's shape (``flash_attention_packed``, PR 59).
+    So nowhere in the step, the layers' two loop bodies included, does
+    anything but a product or a kernel make an array of a kernel operand's
+    size, or of the packed three's.  Until PR 50 the kernels asked for
+    ``[B*H, S, D]`` and a layer ran nine copies and two
+    ``slice_bitcast_fusion``s around its three kernels (108 ms of an 801 ms
+    step on the chip).  Until PR 59 they asked for q, k and v apart: a plain
+    ``fusion`` split the projection's result a body (forward, and again
+    under the layers' remat: 29.4 ms a step) and three
+    ``copy_bitcast_fusion``s laid dq, dk and dv into d(qkv) (13.6 ms); both
+    went with the packed entry.  An array is counted if it has a kernel
+    operand's size, or three times it, and keeps the head's 64 as an axis:
+    the residual stream's own ``bf16[32,1024,1024]`` copies are not the
+    kernels' and not counted."""
     size = 32 * 16 * 1024 * 64
-    made = {}
+    made, products = {}, []
     for name, result, op, rest in named_instructions(training_step.as_text()):
         if op == "custom-call":
             op = re.search(r'custom_call_target="(\w+)"', rest).group(1)
@@ -164,16 +187,24 @@ def test_training_cells_step_turns_no_kernel_operand(training_step):
             continue
         shapes = [tuple(int(d) for d in dims.split(",") if d not in ("", "1"))
                   for dims in re.findall(r"bf16\[([\d,]*)\]", result)]
-        if any(math.prod(dims) == size and 64 in dims for dims in shapes):
+        if any(math.prod(dims) in (size, 3 * size) and 64 in dims
+               for dims in shapes):
             kind = re.sub(r"[.\d]+$", "", name) if op == "fusion" else op
             made[kind] = made.get(kind, 0) + 1
-    assert "copy" not in made and "transpose" not in made, made
-    assert "slice_bitcast_fusion" not in made, made
-    assert made.pop("tpu_custom_call") == 3  # the kernels themselves
-    assert made.pop("copy_bitcast_fusion") == 3  # dq, dk, dv into d(qkv)
-    # the split of [B, 3, H, D, S], forward and again under remat; d(out)
-    # of the output projection
-    assert made == {"fusion": 3}
+            if kind == "fusion":
+                products.append(
+                    re.search(r'op_name="[^"]*/([^/"]+/[^/"]+)"', rest).group(1))
+    # the kernels themselves: the forward, the forward again under the
+    # layers' remat, the backward (its result the packed d(qkv))
+    assert made.pop("tpu_custom_call") == 3
+    # no copy, transpose, slice_bitcast_fusion or copy_bitcast_fusion, and
+    # no group under another name: what is left are the products that make
+    # a kernel's operand (the q/k/v projection, forward and under remat,
+    # feeds the kernels as it is) and d(out) of the output projection
+    assert made == {"fusion": 3}, made
+    assert sorted(products) == [
+        "bse,ethd->bsthd/dot_general", "bse,ethd->bsthd/dot_general",
+        "bshd,hde->bse/dot_general"], products
 
 
 # (L, B, H, Hkv, T, D): GPT-2 small's cache and TinyLlama's, the two shapes
