@@ -18,6 +18,18 @@ from .gpt2_decode import (  # noqa: F401
     sample_logits_greedy,
     sample_logits_rows,
 )
+from .granite_h import (  # noqa: F401
+    GraniteHConfig,
+    granite_h_apply,
+    granite_h_init,
+    granite_h_loss,
+    granite_h_param_axes,
+)
+from .granite_h_decode import (  # noqa: F401
+    granite_h_decode_step,
+    granite_h_init_cache,
+    granite_h_prefill,
+)
 from .laguna import (  # noqa: F401
     LagunaConfig,
     laguna_apply,
@@ -279,5 +291,22 @@ register_model_family(
             olmo_hybrid_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             olmo_hybrid_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    GraniteHConfig,
+    ModelFamily(
+        name="granite_h",
+        init=granite_h_init,
+        apply=granite_h_apply,
+        loss=granite_h_loss,
+        param_axes=granite_h_param_axes,
+        init_cache=granite_h_init_cache,
+        prefill=granite_h_prefill,
+        decode_step=granite_h_decode_step,
+        prefill_counted=_functools.partial(
+            granite_h_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            granite_h_decode_step, with_counts=True),
     ),
 )

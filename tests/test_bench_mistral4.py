@@ -46,7 +46,8 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
     monkeypatch.setattr(theirs, "load", load_as_pr48_left_it)
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
     assert cells == ["laguna_ep16_code_closed32",  # PR 52
-                     "olmohybrid_l12_reason_closed64"]  # PR 56
+                     "olmohybrid_l12_reason_closed64",  # PR 56
+                     "granite4h_micro_chat_closed64"]  # PR 60
     assert appended == [
         "relayout_ms.train",  # PR 50
         "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
@@ -57,7 +58,9 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "attn_ms.serve", "experts_ms.serve", "state_ms.serve", "mlp_ms.serve",
         "head_ms.serve", "unscoped_pct.serve", "prefill_experts_ms.serve_rate",
         "prefill_state_ms.serve_rate", "attn_ms.train", "mlp_ms.train",
-        "head_ms.train", "unscoped_pct.train"]  # PR 58
+        "head_ms.train", "unscoped_pct.train",  # PR 58
+        "ssm_decode_roofline.serve", "prefill_ssm_ms.serve_rate",
+        "ssm_chunk_fill_pct.serve"]  # PR 60
 
 
 def run(*command):

@@ -193,7 +193,7 @@ def test_the_twelve_metrics_are_declared_with_patterns_that_find_their_parts():
             spec = json.load(fh)
         if spec["reader"] == "scope_ms_per_run":
             found[metric["name"]] = (metric, spec["args"])
-    assert len(found) == 12
+    assert len(found) == 13  # PR 58's twelve and PR 60's prefill_ssm_ms
     op_names = {
         "attn": "jit(f)/llama.attn/dot", "attn_full": "jit(f)/mimo.attn_full/x",
         "attn_window": "jit(f)/laguna.attn_window/x",
@@ -210,6 +210,7 @@ def test_the_twelve_metrics_are_declared_with_patterns_that_find_their_parts():
               "mlp_ms.serve": {"mlp", "ffn"}, "head_ms.serve": {"head"},
               "prefill_experts_ms.serve_rate": {"moe", "shared"},
               "prefill_state_ms.serve_rate": {"delta"},
+              "prefill_ssm_ms.serve_rate": {"mamba"},
               "attn_ms.train": {"gpt2.attn"}, "mlp_ms.train": set(),
               "head_ms.train": {"head"},
               "unscoped_pct.serve": set(op_names),

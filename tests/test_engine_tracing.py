@@ -516,9 +516,9 @@ def test_cache_read_pct_metric_reads_the_dispatch_span(tmp_path):
     assert (entry["moves"], entry["layer"], entry["better"]) == (
         "serve_tokens_per_s", "model step", "lower")
     # every serving cell with a full-extent cache: PR 46's four, the
-    # Mistral-4 cell (PR 48), the Laguna cell (PR 52) and the Olmo-Hybrid
-    # cell (PR 56)
-    assert len(entry["workloads"]) == 7
+    # Mistral-4 cell (PR 48), the Laguna cell (PR 52), the Olmo-Hybrid cell
+    # (PR 56) and the Granite cell (PR 60)
+    assert len(entry["workloads"]) == 8
     with open(os.path.join(hs.ROOT, "benchmarks", "layer_metrics",
                            name + ".json")) as f:
         spec = json.load(f)
@@ -586,6 +586,8 @@ FAMILIES = {
                 "head"}),
     "olmo_hybrid": ("olmo_hybrid7b_l12", "olmo",
                     {"embed", "delta", "attn", "mlp", "head"}),
+    "granite_h": ("granite4h_micro", "granite",
+                  {"embed", "mamba", "attn", "mlp", "head"}),
 }
 
 

@@ -238,7 +238,7 @@ def test_decode_attention_compiles_where_the_kernel_was_pinned(
 CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
                 "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16",
                 "mistral_small4_l9_ep8", "laguna_s21_l9_ep16",
-                "olmo_hybrid7b_l12"]
+                "olmo_hybrid7b_l12", "granite4h_micro"]
 
 
 @pytest.fixture(scope="module")
@@ -364,7 +364,8 @@ BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "mimo_v25_l7_ep16": (2, 0.05e9),
                  "mistral_small4_l9_ep8": (9, 0.05e9),
                  "laguna_s21_l9_ep16": (3, 0.05e9),
-                 "olmo_hybrid7b_l12": (3, 0.2e9)}
+                 "olmo_hybrid7b_l12": (3, 0.2e9),
+                 "granite4h_micro": (4, 0.2e9)}
 
 
 @pytest.mark.parametrize("name", CELL_CONFIGS)
@@ -744,6 +745,78 @@ def test_delta_decode_step_updates_its_packed_state_where_it_lies(
     # 15.75 GB of the chip: arguments + the rung's temporaries + 0.26 held
     assert (memory.argument_size_in_bytes
             + rung.memory_analysis().temp_size_in_bytes) < 15.0e9
+
+
+def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
+    cell, cell_decode_step, on_chip
+):
+    """The Granite-4.0-H cell's programs at its size (every published width,
+    all 40 layers, the whole vocabulary once: the head is the table; 64
+    slots x 2048): the decode step's arguments are 6.38 GB of weights and a
+    cache of 6.03 GB, of which the ``ssm`` leaf alone is 4.83 GB (36 x 64
+    slots x 64 heads x [64, 128] float32), the largest thing on the chip
+    after the weights: all of the cache is aliased to the output.  Every
+    Mamba-2 layer's update is a fusion rooted at the ``dynamic-update-slice``
+    of its slice into the donated leaf: thirty-six layers' writes, nothing
+    else produces an array of the leaf's shape (no ``copy``: a second leaf
+    would not fit), and the step's temporaries are a layer's slice (137 MB)
+    at most.  NONE of the updates is a clone: at this size (not at 32 slots)
+    the compiler rematerialised layer 0's update (``...remat``,
+    ``...remat2``: one fed layer 1's read-out, one layer 1's update, both
+    written in place over the same slice), which stepped layer 0's state
+    twice a step on the chip (PERF.md, PR 60); the step now hands the leaf
+    on through an ``optimization_barrier`` a layer, so that an update's
+    result has one reader.  Keys and values lie positions-minor (heads of 64 on the
+    sublanes, 2048 positions on the lanes): NOT padded to 128, 0.54 GB each.
+    The forty layers are written out (53 MB of code); the top rung folds
+    them into five bodies (19 MB) and needs 0.29 GB beside the arguments."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("granite4h_micro")
+    memory = step.memory_analysis()
+    assert cache["ssm"].shape == (36, 64, 64, 64, 128)
+    assert cache["conv"].shape == (36, 64, 3 * 4352)
+    assert cache["k"].shape == cache["v"].shape == (4, 64, 8, 2048, 64)
+    assert "lm_head" not in params
+    assert 12.40e9 < memory.argument_size_in_bytes < 12.42e9
+    assert 6.02e9 < memory.alias_size_in_bytes < 6.03e9  # the whole cache
+    assert memory.temp_size_in_bytes < 0.2e9  # 0.12 GB
+    assert memory.generated_code_size_in_bytes < 70e6  # 53 MB
+    text = step.as_text()
+    shape = ",".join(map(str, cache["ssm"].shape))
+    assert re.search(re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}"), text)
+    producers = re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M)
+    updates = [name for name, op in producers if op == "fusion"
+               and re.search("dynamic[-_]update[-_]slice", name)]
+    assert len(updates) == 36  # one a layer
+    assert not [name for name in updates if "remat" in name]
+    assert {op for name, op in producers if name not in updates} <= {
+        "parameter", "get-tuple-element", "dynamic-update-slice"}
+    # keys and values: positions on the lanes, no padding of the heads' 64
+    kv = ",".join(map(str, cache["k"].shape))
+    assert re.search(re.escape(f"bf16[{kv}]{{3,4,2,1,0:T(8,128)(2,1)}}"), text)
+    held = jax.jit(lambda a: a * 2).lower(on_chip(jax.ShapeDtypeStruct(
+        cache["k"].shape, jnp.bfloat16))).compile().memory_analysis()
+    assert held.argument_size_in_bytes == 4 * 64 * 8 * 2048 * 64 * 2
+    fam, cfg, _, _ = cell("granite4h_micro")
+    formats = step.input_formats[0][0]
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+    tokens = on_chip(jax.ShapeDtypeStruct((2048,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    rung = jit_prefill_one(fam, cfg).lower(
+        lying, cache, tokens, scalar, scalar).compile()
+    assert rung.input_formats[0][0] == formats
+    assert rung.memory_analysis().alias_size_in_bytes > 6.02e9
+    assert rung.memory_analysis().temp_size_in_bytes < 0.4e9  # 0.29 GB
+    assert rung.memory_analysis().generated_code_size_in_bytes < 25e6
+    # the splice of a 77 MB row lands in the donated leaf: nothing in the
+    # rung's ENTRY makes a second one
+    assert not [op for result, op, _ in instructions(rung.as_text(), True)
+                if f"f32[{shape}]" in result and op == "copy"]
+    assert (memory.argument_size_in_bytes
+            + rung.memory_analysis().temp_size_in_bytes) < 13.0e9
 
 
 @pytest.mark.parametrize("layers,at", [(1, 0), (9, 4)],
