@@ -17,23 +17,32 @@ Prefill runs the chunked scan over the padded prompt with ``dt = 0`` at
 positions ``>= length`` (``nemotron_h.mamba_sequence``): the state it returns
 is the state at the prompt's TRUE length, whatever the rung.  Decode runs one
 step of the recurrence for all slots (``nemotron_h_decode.mamba_step``, the
-same function), each layer's slice of the stacked ``ssm`` updated where it
-lies: thirty-six writes into the one donated leaf and no copy of it
+same function): the WHOLE stacked ``ssm`` leaf goes through thirty-six calls
+of ``ops/mamba_update.py`` and comes back with every layer stepped, on a TPU
+by ONE lowered kernel that reads a slot's heads once and writes them where
+they lay, two crossings of the 4.83 GB a step and no copy of it
 (``tests/test_tpu_compile.py`` reads the compiled step); the small ``conv``
 leaf, of which every element moves every step, is built anew; attention goes
 by the deferred-scatter protocol of ``llama_decode.py``.  The forty layers of
 a decode step are written out, not looped: a loop would slice each layer's
 weights out of their stacks by a traced index, and a step that is bound by
 the memory's speed cannot afford a product that copies its weight first;
-what forty bodies cost a replica's start is in ``PERF.md`` (PR 60).  After
-each Mamba-2 layer the stream and the leaf pass one ``optimization_barrier``
-together, so that the updated leaf has ONE reader: without it the next
-layer's read-out and its update both read the update's result, and at 64
-slots (12.4 GB of arguments; not at 32) the v5e compiler rematerialised
-layer 0's update for each of them, two in-place writes of one slice where
-the program has one: the compiled step stepped layer 0's state twice and
-its logits were 14 % off the reference on the chip (PR 60; the all-layers
-script found it, the two-layer check could not).
+what forty bodies cost a replica's start is in ``PERF.md`` (PR 60).  Two
+things the v5e compiler did at 64 slots (12.4 GB of arguments; not at 32)
+and what stands against each.  While a layer's update was an XLA fusion
+that wrote its slice of the donated leaf in place, the compiler
+rematerialised layer 0's update for each of its two readers and the
+compiled step stepped layer 0's state twice (logits 14 % off the reference
+on the chip, PR 60; the all-layers script found it, the two-layer check
+could not): a kernel's result has ONE reader of the leaf, the next kernel,
+and is not cloned, so the ``optimization_barrier`` a layer that cured it is
+gone (PR 61: thirty-six calls with and without it, the all-layers script
+``ok`` without).  And with the kernels in, the compiler moved the step's
+write of the new VALUES into the cache ahead of the last attention layer's
+read of the old ones and paid for it with two copies of the 0.54 GB ``v``
+leaf a step: the new keys and values now pass one ``optimization_barrier``
+together with the stream after the last layer, so the cache is written at
+the step's end, as the protocol says, and nothing copies it.
 
 A decode row at position 0 is an idle slot (a prompt has at least one
 token): its state is computed like any other's and stays finite, every step
@@ -112,10 +121,9 @@ def granite_h_decode_step(
         seen[kind] += 1
 
         def mamba(y):
-            out, conv, ssm = mamba_step(
-                y, cache["conv"][i], cache["ssm"][i], blocks["mamba"], i, cfg)
+            out, conv, cache["ssm"] = mamba_step(
+                y, cache["conv"][i], cache["ssm"], blocks["mamba"], i, cfg)
             new_conv.append(conv)
-            cache["ssm"] = cache["ssm"].at[i].set(ssm)
             return out
 
         def attend(y):
@@ -129,15 +137,13 @@ def granite_h_decode_step(
 
         x = block(params, x, kind, i, layer,
                   mamba if kind == "M" else attend, cfg)
-        if kind == "M":  # the updated leaf has ONE reader: the docstring
-            with jax.named_scope(SCOPE["M"]):
-                x, cache["ssm"] = jax.lax.optimization_barrier(
-                    (x, cache["ssm"]))
     if new_conv:
         with jax.named_scope(SCOPE["M"]):
             cache["conv"] = jnp.stack(new_conv)
     if new_k:
         with jax.named_scope(SCOPE["*"]):  # the cache write is attention's
+            # at the step's END, after the last layer's reads: the docstring
+            x, new_k, new_v = jax.lax.optimization_barrier((x, new_k, new_v))
             cache["k"] = write_token_to_cache(
                 cache["k"], jnp.stack(new_k), pos, axis=3)
             cache["v"] = write_token_to_cache(

@@ -17,11 +17,17 @@ Prefill runs the chunked scan over the padded prompt with ``dt = 0`` at
 positions ``>= length``: the state it returns is the state at the prompt's
 TRUE length, whatever the rung, and the convolution's state is its last
 ``K-1`` true inputs.  Decode runs one step of the recurrence for all slots:
-``S <- exp(dt A) S + dt x (x) B`` and ``y = S C + D x`` in float32, the layer's
-slice of the stacked ``ssm`` updated where it lies (the engine donates the
-cache; ``tests/test_tpu_compile.py`` reads the compiled step for a copy;
-the small ``conv`` leaf, of which every element moves every step, is built
-anew), and attention by the deferred-scatter protocol of ``llama_decode.py``: the
+``S <- exp(dt A) S + dt x (x) B`` and ``y = S C + D x`` in float32, by
+``ops/mamba_update.py``: the WHOLE stacked ``ssm`` leaf goes through every
+Mamba-2 layer's call and comes back with that layer stepped, on a TPU by one
+kernel that reads a slot's heads once, steps them, reads ``y`` out of what
+it holds and writes them where they lay (the engine donates the cache;
+``tests/test_tpu_compile.py`` reads the compiled step: one kernel a layer
+and nothing else touches an array of the leaf's shape; off a TPU, and at the
+toy widths of the CPU tests, the XLA formulation: a slice updated in place
+and read again); the small ``conv`` leaf, of which every element moves every
+step, is built anew; and attention by the deferred-scatter protocol of
+``llama_decode.py``: the
 cache holds ``[0, pos-1]``, the current key and value are merged as a last
 score, and all are written at the step's end by ``write_token_to_cache``.
 
@@ -41,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import decode_attention, write_token_to_cache
+from ..ops.mamba_update import mamba_update
 from .llama import _rmsnorm
 from .longcat import matmul
 from .nemotron_h import (CACHE_SCOPE, NemotronHConfig, attention_project,
@@ -85,25 +92,23 @@ def nemotron_h_prefill(
     return (*out, counts) if with_counts else out
 
 
-def mamba_step(y, conv_state, state, m, i: int, cfg: NemotronHConfig):
-    """One token a row through Mamba-2 layer ``i``.  y ``[B, d]``,
-    conv_state ``[B, (K-1)(HP + 2GN)]``, state ``[B, H, P, N]`` -> (``[B, d]``
-    float32, the two states after the token, in the dtypes they came in)."""
-    r = cfg.mamba_num_heads // cfg.n_groups
+def mamba_step(y, conv_state, leaf, m, i: int, cfg: NemotronHConfig):
+    """One token a row through Mamba-2 layer ``i``, whose state is layer
+    ``i`` of the stacked ``leaf [M, B, H, P, N]``.  y ``[B, d]``, conv_state
+    ``[B, (K-1)(HP + 2GN)]`` -> (``[B, d]`` float32, the convolution's state
+    after the token, the leaf with layer ``i`` stepped: the same buffer
+    where the caller donated it, ``ops/mamba_update.py``)."""
     z, xbc, dt = mamba_project(y, m, i, cfg)
     window = jnp.concatenate(
         [conv_state.astype(jnp.float32), xbc], axis=1)  # [B, K C]
     conv = (window.reshape(-1, cfg.conv_kernel, cfg.d_conv)
             * m["conv_w"][i]).sum(1) + m["conv_b"][i]
     x, b, c = split_xbc(jax.nn.silu(conv), cfg)
-    b, c = (jnp.repeat(v, r, axis=1)[:, :, None] for v in (b, c))  # [B,H,1,N]
     keep = jnp.exp(dt * -jnp.exp(m["a_log"][i]))  # [B, H]
-    new = (keep[..., None, None] * state.astype(jnp.float32)
-           + (dt[..., None] * x)[..., None] * b)
-    out = (new * c).sum(-1) + m["d_skip"][i][:, None] * x  # [B, H, P]
+    out, leaf = mamba_update(leaf, i, x, dt, keep, b, c)
+    out = out + m["d_skip"][i][:, None] * x  # [B, H, P]
     return (mamba_output(out, z, m, i, cfg),
-            window[:, cfg.d_conv:].astype(conv_state.dtype),
-            new.astype(state.dtype))
+            window[:, cfg.d_conv:].astype(conv_state.dtype), leaf)
 
 
 def nemotron_h_decode_step(
@@ -120,10 +125,9 @@ def nemotron_h_decode_step(
     new_conv, new_k, new_v = [], [], []
 
     def mamba(i, y):
-        out, conv, ssm = mamba_step(
-            y, cache["conv"][i], cache["ssm"][i], blocks["mamba"], i, cfg)
+        out, conv, cache["ssm"] = mamba_step(
+            y, cache["conv"][i], cache["ssm"], blocks["mamba"], i, cfg)
         new_conv.append(conv)
-        cache["ssm"] = cache["ssm"].at[i].set(ssm)
         return out
 
     def attend(i, y):

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import attention, delta_update
+from ray_tpu.ops import attention, delta_update, mamba_update
 from ray_tpu.ops.decode_attention import decode_attention, tile_positions
 
 
@@ -425,35 +425,63 @@ def test_decode_step_reads_its_live_blocks_where_they_lie(
     assert not made
 
 
+def mamba_kernels(text, shape):
+    """The names of a compiled step's ``ops.mamba_update`` kernels: custom
+    calls whose first result is the ``ssm`` leaf ``f32[shape]`` as the
+    program holds it (last axis minor, no padding), aliased to their third
+    operand (after the layer, a constant of each call of the ONE lowered
+    kernel, and the heads' ``keep``)."""
+    leaf = re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}")
+    assert re.search(leaf, text)
+    return re.findall(
+        rf"^\s*%(\S+) = \({leaf}, [^\n]*?\) custom-call\(%constant[\w.]*, "
+        r'[^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        + re.escape("output_to_operand_aliasing={{0}: (2, {})}")
+        + r'[^\n]*/mamba_update/pallas_call"', text, re.M)
+
+
+def leaf_is_only_handed_on(text, shape):
+    """Nothing but a program's parameter and its kernels' results produces
+    an array of the leaf's shape, and no instruction copies, slices or
+    updates a slice of one: the kernels are the only readers and writers of
+    the state."""
+    producers = dict(re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M))
+    touched = [line for line in text.splitlines()
+               if f"[{shape}]" in line and re.search(
+                   r" (copy|fusion|dynamic-update-slice|slice)\(", line)]
+    return set(producers.values()) <= {
+        "parameter", "get-tuple-element"} and not touched
+
+
 def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     cell_decode_step
 ):
     """The Nemotron-H cell's decode step (published widths, one period of
     11 layers, 128 experts held, 64 slots x 2048): every element of the 1.34
     GB ``ssm`` leaf changes every step, so the least a step can do is read
-    it once and write it once, where it lies.  Each Mamba-2 layer's update
-    is ONE fusion rooted at the ``dynamic-update-slice`` of its slice into
-    the donated leaf (the leaf is aliased to the output: nothing is
-    allocated for it), nothing else produces an array of the leaf's shape,
-    and the step's temporaries stay far under one layer's slice (268 MB): a
-    stack of the layers' new states at the step's end, or a slice copied
-    out for its products, would be 1.34 GB more a step (PR 34's lesson)."""
+    it once and write it once, where it lies, and that is what it does: each
+    Mamba-2 layer is ONE Pallas kernel (``ops/mamba_update.py``) whose
+    operand and result are the WHOLE donated leaf, aliased, and whose blocks
+    are that layer's slots (XLA's own step was a fusion rooted at the
+    ``dynamic-update-slice`` of the layer's slice and a reduce fusion that
+    read it again: three crossings, until PR 61).  NO fusion produces an
+    array of the leaf's shape, nothing slices or copies it, and the step's
+    temporaries stay far under one layer's slice (268 MB): a stack of the
+    layers' new states at the step's end, or a slice copied out for its
+    products, would be 1.34 GB more a step (PR 34's lesson)."""
     compiled, cache, _ = cell_decode_step("nemotron3_super_l11_ep4")
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 10.8e9
     assert memory.alias_size_in_bytes > 1.5e9  # the whole cache, donated
-    assert memory.temp_size_in_bytes < 0.2e9   # 0.07 GB
+    assert memory.temp_size_in_bytes < 0.2e9   # 0.05 GB
     shape = ",".join(map(str, cache["ssm"].shape))
     assert shape == "5,64,128,64,128"
-    producers = re.findall(
-        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(",
-        compiled.as_text(), re.M)
-    updates = [name for name, op in producers if op == "fusion"
-               and re.search("dynamic[-_]update[-_]slice", name)]
-    assert len(updates) == 5  # one a Mamba-2 layer
-    # (``dynamic-update-slice``: those fusions' own roots)
-    assert {op for name, op in producers if name not in updates} <= {
-        "parameter", "get-tuple-element", "dynamic-update-slice"}
+    text = compiled.as_text()
+    # one a Mamba-2 layer, and the step has no other kernel
+    assert len(mamba_kernels(text, shape)) == 5 == text.count(
+        "tpu_custom_call")
+    assert leaf_is_only_handed_on(text, shape)
 
 
 def test_windowed_decode_step_fits_and_its_top_rung_beside_it(
@@ -756,20 +784,24 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
     cache of 6.03 GB, of which the ``ssm`` leaf alone is 4.83 GB (36 x 64
     slots x 64 heads x [64, 128] float32), the largest thing on the chip
     after the weights: all of the cache is aliased to the output.  Every
-    Mamba-2 layer's update is a fusion rooted at the ``dynamic-update-slice``
-    of its slice into the donated leaf: thirty-six layers' writes, nothing
-    else produces an array of the leaf's shape (no ``copy``: a second leaf
-    would not fit), and the step's temporaries are a layer's slice (137 MB)
-    at most.  NONE of the updates is a clone: at this size (not at 32 slots)
-    the compiler rematerialised layer 0's update (``...remat``,
-    ``...remat2``: one fed layer 1's read-out, one layer 1's update, both
-    written in place over the same slice), which stepped layer 0's state
-    twice a step on the chip (PERF.md, PR 60); the step now hands the leaf
-    on through an ``optimization_barrier`` a layer, so that an update's
-    result has one reader.  Keys and values lie positions-minor (heads of 64 on the
-    sublanes, 2048 positions on the lanes): NOT padded to 128, 0.54 GB each.
-    The forty layers are written out (53 MB of code); the top rung folds
-    them into five bodies (19 MB) and needs 0.29 GB beside the arguments."""
+    Mamba-2 layer's update is ONE Pallas kernel (``ops/mamba_update.py``)
+    from the donated leaf to itself: thirty-six calls of the one lowered
+    kernel, nothing else produces, slices or copies an array of the leaf's
+    shape (a second leaf would not fit), and the step's temporaries are a
+    layer's slice (137 MB) at most.  NONE of the kernels is a clone: while
+    the updates were XLA fusions the compiler rematerialised layer 0's at
+    this size (not at 32 slots; ``...remat``, ``...remat2``: one fed layer
+    1's read-out, one layer 1's update, both written in place over the same
+    slice), which stepped layer 0's state twice a step on the chip
+    (PERF.md, PR 60).  And the temporaries pin a second thing: with the
+    kernels in, the compiler wrote the new values into ``v`` ahead of the
+    last attention layer's read and copied the 0.54 GB leaf twice a step
+    (0.60 GB of temporaries) until the step held its cache writes behind an
+    ``optimization_barrier`` (PR 61).  Keys and values lie positions-minor
+    (heads of 64 on the sublanes, 2048 positions on the lanes): NOT padded
+    to 128, 0.54 GB each.  The forty layers are written out (40 MB of code,
+    53 while each update was two fusions); the top rung folds them into five
+    bodies (19 MB) and needs 0.29 GB beside the arguments."""
     from ray_tpu.llm.engine import jit_prefill_one
 
     step, cache, params = cell_decode_step("granite4h_micro")
@@ -781,18 +813,16 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
     assert 12.40e9 < memory.argument_size_in_bytes < 12.42e9
     assert 6.02e9 < memory.alias_size_in_bytes < 6.03e9  # the whole cache
     assert memory.temp_size_in_bytes < 0.2e9  # 0.12 GB
-    assert memory.generated_code_size_in_bytes < 70e6  # 53 MB
+    assert memory.generated_code_size_in_bytes < 70e6  # 40 MB
     text = step.as_text()
     shape = ",".join(map(str, cache["ssm"].shape))
     assert re.search(re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}"), text)
-    producers = re.findall(
-        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M)
-    updates = [name for name, op in producers if op == "fusion"
-               and re.search("dynamic[-_]update[-_]slice", name)]
-    assert len(updates) == 36  # one a layer
-    assert not [name for name in updates if "remat" in name]
-    assert {op for name, op in producers if name not in updates} <= {
-        "parameter", "get-tuple-element", "dynamic-update-slice"}
+    # one kernel a Mamba-2 layer, thirty-six calls of the ONE lowered
+    # kernel, the step's only kernels; NONE is a clone
+    kernels = mamba_kernels(text, shape)
+    assert len(kernels) == 36 == text.count("tpu_custom_call")
+    assert not [name for name in kernels if "remat" in name]
+    assert leaf_is_only_handed_on(text, shape)
     # keys and values: positions on the lanes, no padding of the heads' 64
     kv = ",".join(map(str, cache["k"].shape))
     assert re.search(re.escape(f"bf16[{kv}]{{3,4,2,1,0:T(8,128)(2,1)}}"), text)
@@ -874,6 +904,71 @@ def test_a_steps_linear_layers_are_calls_of_one_lowered_kernel(
 
     lowered = jax.jit(three, donate_argnums=(0,)).lower(
         leaf, q, q, v, scalar, scalar)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+MAMBA_WIDTHS = {"granite_64_heads_1_group": (64, 1),
+                "nemotron_128_heads_8_groups": (128, 8)}
+
+
+def mamba_operands(on_chip, layers, slots, heads, groups, p=64, n=128):
+    """The leaf and the small operands of ``ops.mamba_update``: leaf, x, dt,
+    keep, b, c."""
+    return [on_chip(jax.ShapeDtypeStruct(dims, jnp.float32)) for dims in (
+        (layers, slots, heads, p, n), (slots, heads, p), (slots, heads),
+        (slots, heads), (slots, groups, n), (slots, groups, n))]
+
+
+@pytest.mark.parametrize("layers,at", [(1, 0), (5, 3)],
+                         ids=["one_layer", "layer_3_of_the_stack"])
+@pytest.mark.parametrize("widths", MAMBA_WIDTHS)
+def test_mamba_update_alone_is_one_kernel_over_the_donated_leaf(
+    on_chip, as_if_on_tpu, widths, layers, at
+):
+    """``ops.mamba_update`` at both families' published widths and the
+    cells' 64 slots, on its own: a stack of one and a layer inside a stack.
+    ONE custom call from the donated leaf to itself, no temporary, no copy
+    of the state, the small operands (``dt x`` a head's width, ``b`` and
+    ``c`` a GROUP, never a head) a few megabytes beside it."""
+    heads, groups = MAMBA_WIDTHS[widths]
+    slots = 64
+    step = jax.jit(
+        lambda leaf, *small: mamba_update.mamba_update(leaf, at, *small),
+        donate_argnums=(0,)).lower(
+            *mamba_operands(on_chip, layers, slots, heads, groups)).compile()
+    memory = step.memory_analysis()
+    state = layers * slots * heads * 64 * 128 * 4
+    assert memory.alias_size_in_bytes == state
+    assert memory.temp_size_in_bytes == 0
+    assert memory.argument_size_in_bytes < state + 8e6
+    text = step.as_text()
+    shape = f"{layers},{slots},{heads},64,128"
+    assert len(mamba_kernels(text, shape)) == 1 == text.count(
+        "tpu_custom_call")
+    assert leaf_is_only_handed_on(text, shape)
+
+
+def test_a_steps_mamba_layers_are_calls_of_one_lowered_kernel(
+    on_chip, as_if_on_tpu
+):
+    """The layer is the kernel's prefetched operand, not a constant of its
+    index maps, and ``mamba_update._call`` a jitted function: three layers
+    lower to ONE ``tpu_custom_call`` called three times (Granite's step has
+    thirty-six: a constant a layer would trace and lower the kernel
+    thirty-six times at every start of a replica) and compile to three,
+    each over the leaf where it lies."""
+    def three(leaf, *small):
+        outs = []
+        for at in range(3):
+            y, leaf = mamba_update.mamba_update(leaf, at, *small)
+            outs.append(y)
+        return outs, leaf
+
+    lowered = jax.jit(three, donate_argnums=(0,)).lower(
+        *mamba_operands(on_chip, 3, 8, 64, 1))
     assert lowered.as_text().count("tpu_custom_call") == 1
     compiled = lowered.compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
