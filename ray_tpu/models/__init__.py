@@ -78,6 +78,18 @@ from .mimo_v2_decode import (  # noqa: F401
     mimo_v2_init_cache,
     mimo_v2_prefill,
 )
+from .minicpm_sala import (  # noqa: F401
+    MinicpmSalaConfig,
+    minicpm_sala_apply,
+    minicpm_sala_init,
+    minicpm_sala_loss,
+    minicpm_sala_param_axes,
+)
+from .minicpm_sala_decode import (  # noqa: F401
+    minicpm_sala_decode_step,
+    minicpm_sala_init_cache,
+    minicpm_sala_prefill,
+)
 from .mistral4 import (  # noqa: F401
     Mistral4Config,
     mistral4_apply,
@@ -308,5 +320,22 @@ register_model_family(
             granite_h_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             granite_h_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    MinicpmSalaConfig,
+    ModelFamily(
+        name="minicpm_sala",
+        init=minicpm_sala_init,
+        apply=minicpm_sala_apply,
+        loss=minicpm_sala_loss,
+        param_axes=minicpm_sala_param_axes,
+        init_cache=minicpm_sala_init_cache,
+        prefill=minicpm_sala_prefill,
+        decode_step=minicpm_sala_decode_step,
+        prefill_counted=_functools.partial(
+            minicpm_sala_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            minicpm_sala_decode_step, with_counts=True),
     ),
 )

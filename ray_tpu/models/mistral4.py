@@ -310,7 +310,8 @@ def mistral4_param_axes():
 
 # ---------------------------------------------------------------- attention
 def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
-                      key_block: int = KEY_BLOCK, window=None):
+                      key_block: int = KEY_BLOCK, window=None, select=None,
+                      select_block=None):
     """Causal attention of ``[B, S]`` tokens over themselves, a tile of
     ``query_block`` queries by ``key_block`` keys at a time.  q ``[B, S, H,
     D]``, k ``[B, S, Hkv, D]`` (the softmax scale ``D^-0.5``; what else scales
@@ -327,12 +328,24 @@ def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
     an axis of the queries, and the keys and values are never repeated.
     ``window`` (static): query ``i`` sees key ``j`` iff ``0 <= i - j <
     window``: the BAND, whose key blocks have a lower bound too, so a query
-    block of ``window`` rows meets two key blocks whatever ``S`` is.  With
-    equal head counts and no window the program is what it was."""
+    block of ``window`` rows meets two key blocks whatever ``S`` is.
+
+    ``select`` (with ``select_block``, static, which the padded length is
+    then made a multiple of): a query reads a key only where ``select``
+    lets it, besides causality.  ``select(qb, rows)`` is called once a
+    query tile with the tile's queries as the products read them (``[B,
+    query_block, H, D]``, grouped ``[B, query_block, Hkv, G, D]``) and
+    their positions ``[query_block]``, and returns ``[B, Hkv or H,
+    query_block, n]`` bool: entry ``j`` stands for keys ``[j select_block,
+    (j + 1) select_block)``, and entries it does not give are not read.  The
+    mask exists a tile of queries at a time (MiniCPM-SALA's block
+    selection).  With equal head counts, no window and no selection the
+    program is what it was."""
     bsz, s, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
-    query_block, key_block = min(query_block, s), min(key_block, s)
-    whole = math.lcm(query_block, key_block)
+    fit = -(-s // select_block) * select_block if select_block else s
+    query_block, key_block = min(query_block, fit), min(key_block, fit)
+    whole = math.lcm(query_block, key_block, select_block or 1)
     padded = -(-s // whole) * whole
     if padded > s:
         q, k, v = (jnp.pad(a, ((0, 0), (0, padded - s), (0, 0), (0, 0)))
@@ -352,6 +365,12 @@ def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
         if hkv != h:
             qb = qb.reshape(bsz, query_block, hkv, h // hkv, d)
         rows = first + jnp.arange(query_block)
+        if select is not None:
+            chosen = select(qb, rows)[..., :padded // select_block]
+            chosen = jnp.pad(chosen, ((0, 0),) * 3 + (
+                (0, padded // select_block - chosen.shape[-1]),))
+            if hkv != h:  # a group's query heads read what their head does
+                chosen = chosen[:, :, None]
 
         def keys(start):
             kb = jax.lax.dynamic_slice_in_dim(k, start, key_block, axis=1)
@@ -361,6 +380,10 @@ def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
             if window is not None:
                 seen &= (rows[:, None] - start
                          - jnp.arange(key_block)[None]) < window
+            if select is not None:
+                seen = seen & jnp.repeat(jax.lax.dynamic_slice_in_dim(
+                    chosen, start // select_block, key_block // select_block,
+                    axis=-1), select_block, axis=-1)
             return jnp.where(seen, scores, NEG_INF), lambda p: matmul(
                 to_values, p.astype(q.dtype), vb)
 

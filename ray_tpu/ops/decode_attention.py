@@ -140,6 +140,69 @@ def attend_live_blocks(block, longest, t: int, shape, columns=()):
                          columns)
 
 
+def attend_listed_blocks(q, k_cache, v_cache, ids, pos, layer: int, *,
+                         block_size: int, turn: int, k_self, v_self):
+    """``attend_live_blocks``' sibling for a row that reads a LIST of blocks
+    and not the contiguous ``[0, live_extent)``: q ``[B, H, D]``, caches ``[L,
+    B, Hkv, T, D]``, ids ``[B, Hkv, n]`` int32: the blocks of ``block_size``
+    positions (block ``b`` = positions ``[b block_size, (b + 1)
+    block_size)``) each (row, key-value head) reads, the listed ones FIRST
+    and -1 after them; ``n`` a multiple of ``turn``.  Of a listed block the
+    positions before ``pos [B]`` count, and the current token's ``k_self`` /
+    ``v_self [B, Hkv, D]`` is one more column (the deferred write) ->
+    ``[B, H, Dv]`` in the values' dtype.
+
+    ``turn`` entries of every row's list are gathered at a time out of the
+    stacked cache where it lies (the layer is part of the gather's index:
+    no slice of the leaf is taken first) and go through one update of the
+    online softmax; as many turns run as the longest list needs, so a batch
+    whose rows all list ``turn`` blocks reads ``turn * block_size``
+    positions a row and head whatever their contexts are.  An entry beyond
+    a row's own list is masked: exact zeros, the same bits whatever its
+    neighbours made the trip count."""
+    n_layers, b, hkv, t, d = k_cache.shape
+    dv = v_cache.shape[-1]
+    h = q.shape[1]
+    g = h // hkv
+    qg = q.reshape(b, hkv, g, d)
+    scale = d ** -0.5
+    if t % block_size or ids.shape[-1] % turn:
+        raise ValueError(
+            f"a cache of {t} positions in blocks of {block_size}, a list of "
+            f"{ids.shape[-1]} in turns of {turn}: neither may leave a rest")
+    blocks = t // block_size
+    # [L, B, Hkv, T / block, block, D]: the same bytes where a block is
+    # whole memory tiles
+    kb_all = k_cache.reshape(n_layers, b, hkv, blocks, block_size, d)
+    vb_all = v_cache.reshape(n_layers, b, hkv, blocks, block_size, dv)
+    row = jnp.arange(b)[:, None, None]
+    head = jnp.arange(hkv)[None, :, None]
+    limit = pos[:, None, None, None]
+
+    def block(start):  # ``start`` counts positions of the LIST
+        listed = jax.lax.dynamic_slice_in_dim(
+            ids, start // block_size, turn, axis=2)  # [B, Hkv, turn]
+        at = jnp.clip(listed, 0, blocks - 1)
+        kb = kb_all[layer, row, head, at]  # [B, Hkv, turn, block, D]
+        vb = vb_all[layer, row, head, at]
+        scores = jnp.einsum(
+            "bkgd,bknsd->bkgns", qg, kb).astype(jnp.float32) * scale
+        where = at[..., None] * block_size + jnp.arange(block_size)
+        seen = ((listed >= 0)[..., None] & (where < limit))[:, :, None]
+        scores = jnp.where(seen, scores, NEG_INF).reshape(b, hkv, g, -1)
+        return scores, lambda p: jnp.einsum(
+            "bkgns,bknsd->bkgd",
+            p.reshape(b, hkv, g, turn, block_size).astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+
+    columns = [(jnp.einsum("bkgd,bkd->bkg", qg, k_self).astype(jnp.float32)
+                * scale, v_self[:, :, None, :].astype(jnp.float32))]
+    turns = (jnp.max(jnp.sum(ids >= 0, axis=-1)) + turn - 1) // turn
+    out = attend_blocks(block, turns, turn * block_size, (b, hkv, g, dv),
+                        columns)
+    return out.astype(v_cache.dtype).reshape(b, h, dv)
+
+
 @functools.partial(jax.jit, static_argnames=("layer", "window"))
 def decode_attention(q, k_cache, v_cache, pos, layer: int = 0, *,
                      k_self=None, v_self=None, window=None, sink=None):

@@ -9,7 +9,56 @@ import os  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
 
+import pytest  # noqa: E402
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def as_pr60_left_it(monkeypatch):
+    """The benchmark's cases of PR 60 (star-imported above, two of them
+    shadowed below) hold its configuration to be the LAST of ``configs`` and
+    its cell the LAST of ``workloads``, which no later PR that adds either
+    can keep, and the benchmark's files are add-only, its tests among them.
+    ``load`` gives ``BENCHMARK.json`` as far as PR 60 wrote it; what was
+    appended since comes back by name."""
+    import benchmarks.tests.test_bench_granite_h as theirs
+
+    later = {"configs": [], "workloads": []}
+    theirs_load = theirs.load
+
+    def load(*path):
+        bench = theirs_load(*path)
+        if path[-1] == "BENCHMARK.json":
+            for key, last in (("configs", "granite4h_micro"),
+                              ("workloads", theirs.CELL)):
+                names = [entry["name"] for entry in bench[key]]
+                cut = names.index(last) + 1
+                later[key][:] = names[cut:]
+                bench[key] = bench[key][:cut]
+            for metric in bench["end_to_end"] + bench["per_layer"]:
+                if "workloads" in metric:  # a later cell's name, appended
+                    metric["workloads"] = [w for w in metric["workloads"]
+                                           if w not in later["workloads"]]
+        return bench
+
+    monkeypatch.setattr(theirs, "load", load)
+    return theirs, later
+
+
+def test_the_configuration_is_the_source_with_nothing_cut(  # noqa: F811
+        as_pr60_left_it):
+    theirs, later = as_pr60_left_it
+    theirs.test_the_configuration_is_the_source_with_nothing_cut(
+        theirs.load(theirs.HERE, "configs", "granite4h_micro.json"))
+    assert later["configs"] == ["minicpm_sala_l12"]  # PR 62
+
+
+def test_the_cell_lists_itself_where_its_metrics_are_true(  # noqa: F811
+        as_pr60_left_it):
+    theirs, later = as_pr60_left_it
+    theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
+    assert later["workloads"] == ["sala_l12_longctx_closed8"]  # PR 62
 
 
 def run(*command):

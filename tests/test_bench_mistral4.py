@@ -47,7 +47,8 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
     assert cells == ["laguna_ep16_code_closed32",  # PR 52
                      "olmohybrid_l12_reason_closed64",  # PR 56
-                     "granite4h_micro_chat_closed64"]  # PR 60
+                     "granite4h_micro_chat_closed64",  # PR 60
+                     "sala_l12_longctx_closed8"]  # PR 62
     assert appended == [
         "relayout_ms.train",  # PR 50
         "ring_long_decode_roofline.serve", "top10_expert_tokens.serve",
@@ -60,7 +61,11 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "prefill_state_ms.serve_rate", "attn_ms.train", "mlp_ms.train",
         "head_ms.train", "unscoped_pct.train",  # PR 58
         "ssm_decode_roofline.serve", "prefill_ssm_ms.serve_rate",
-        "ssm_chunk_fill_pct.serve"]  # PR 60
+        "ssm_chunk_fill_pct.serve",  # PR 60
+        "lightning_ms.serve", "select_ms.serve",
+        "prefill_lightning_ms.serve_rate", "prefill_sparse_ms.serve_rate",
+        "sparse_read_pct.serve", "lightning_chunk_fill_pct.serve",
+        "sala_decode_roofline.serve", "sala_unscoped_pct.serve"]  # PR 62
 
 
 def run(*command):

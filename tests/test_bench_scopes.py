@@ -193,7 +193,9 @@ def test_the_twelve_metrics_are_declared_with_patterns_that_find_their_parts():
             spec = json.load(fh)
         if spec["reader"] == "scope_ms_per_run":
             found[metric["name"]] = (metric, spec["args"])
-    assert len(found) == 13  # PR 58's twelve and PR 60's prefill_ssm_ms
+    # PR 58's twelve, PR 60's prefill_ssm_ms and PR 62's five of the sala
+    # scopes
+    assert len(found) == 18
     op_names = {
         "attn": "jit(f)/llama.attn/dot", "attn_full": "jit(f)/mimo.attn_full/x",
         "attn_window": "jit(f)/laguna.attn_window/x",
@@ -202,8 +204,15 @@ def test_the_twelve_metrics_are_declared_with_patterns_that_find_their_parts():
         "ffn": "jit(f)/longcat.ffn/jit(ffn)/x",
         "mamba": "jit(f)/nemotron.mamba/x", "delta": "jit(f)/olmo.delta/x",
         "head": "jit(f)/gpt2.head/x", "embed": "jit(f)/gpt2.embed/x",
+        "lightning": "jit(f)/sala.lightning/x",
+        "sala.attn": "jit(f)/sala.attn/dot",
+        "select": "jit(f)/sala.attn/sala.select/top_k",  # INSIDE sala.attn
         "gpt2.attn": "jit(step)/transpose(jvp(gpt2.attn))/dot_general"}
+    # the shared patterns know no ``lightning`` (a benchmark PR's to widen):
+    # the cell that runs it reads sala_unscoped_pct.serve instead
+    shared = set(op_names) - {"lightning"}
     wanted = {"attn_ms.serve": {"attn", "attn_full", "attn_window", "mla",
+                                "sala.attn", "select",
                                 "gpt2.attn"},  # under jvp(...) too
               "experts_ms.serve": {"moe", "shared"},
               "state_ms.serve": {"mamba", "delta"},
@@ -213,8 +222,12 @@ def test_the_twelve_metrics_are_declared_with_patterns_that_find_their_parts():
               "prefill_ssm_ms.serve_rate": {"mamba"},
               "attn_ms.train": {"gpt2.attn"}, "mlp_ms.train": set(),
               "head_ms.train": {"head"},
-              "unscoped_pct.serve": set(op_names),
-              "unscoped_pct.train": set(op_names)}
+              "lightning_ms.serve": {"lightning"},
+              "select_ms.serve": {"select"},
+              "prefill_lightning_ms.serve_rate": {"lightning"},
+              "prefill_sparse_ms.serve_rate": {"sala.attn", "select"},
+              "sala_unscoped_pct.serve": {"lightning", "sala.attn", "select"},
+              "unscoped_pct.serve": shared, "unscoped_pct.train": shared}
     for name, (metric, args) in found.items():
         hit = {part for part, op_name in op_names.items()
                if re.search(args["scope"], op_name)}

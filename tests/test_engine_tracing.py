@@ -588,6 +588,12 @@ FAMILIES = {
                     {"embed", "delta", "attn", "mlp", "head"}),
     "granite_h": ("granite4h_micro", "granite",
                   {"embed", "mamba", "attn", "mlp", "head"}),
+    # ``lib/scopes.py``'s PARTS (the benchmark's, a benchmark PR's to widen)
+    # know no ``lightning`` and no ``select``: ``scope_of`` reads those
+    # operations as unscoped, and the cell's own metric files find them by
+    # pattern (``tests/test_bench_scopes.py``)
+    "minicpm_sala": ("minicpm_sala_l12", "sala",
+                     {"embed", "attn", "mlp", "head"}),
 }
 
 

@@ -238,7 +238,7 @@ def test_decode_attention_compiles_where_the_kernel_was_pinned(
 CELL_CONFIGS = ["mistral7b_l16", "longcat_flash_l4_ep32",
                 "nemotron3_super_l11_ep4", "mimo_v25_l7_ep16",
                 "mistral_small4_l9_ep8", "laguna_s21_l9_ep16",
-                "olmo_hybrid7b_l12", "granite4h_micro"]
+                "olmo_hybrid7b_l12", "granite4h_micro", "minicpm_sala_l12"]
 
 
 @pytest.fixture(scope="module")
@@ -365,7 +365,11 @@ BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "mistral_small4_l9_ep8": (9, 0.05e9),
                  "laguna_s21_l9_ep16": (3, 0.05e9),
                  "olmo_hybrid7b_l12": (3, 0.2e9),
-                 "granite4h_micro": (4, 0.2e9)}
+                 "granite4h_micro": (4, 0.2e9),
+                 # no contiguous read: its three sparse layers read a LIST
+                 # of blocks (``attend_listed_blocks``; the test of the
+                 # MiniCPM-SALA cell below), and nothing of a prefix's shape
+                 "minicpm_sala_l12": (0, 0.05e9)}
 
 
 @pytest.mark.parametrize("name", CELL_CONFIGS)
@@ -849,6 +853,70 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
             + rung.memory_analysis().temp_size_in_bytes) < 13.0e9
 
 
+def test_sala_decode_step_selects_and_reads_its_blocks_where_they_lie(
+    cell, cell_decode_step, on_chip
+):
+    """The MiniCPM-SALA cell (published widths, layers 9-20, 8 slots x
+    16,384): three kinds of position-bearing leaf beside a float32 state,
+    all 0.57 GB of cache aliased to the step's output, temporaries of a few
+    megabytes.  The nine lightning layers are nine calls of THE
+    ``ops/mamba_update.py`` kernel at one group a head (``[128, 128]`` a
+    head: sixteen registers where Granite's head is eight; the kernel as it
+    stood), the step's only kernels, over the ``state`` leaf as the compiler
+    lays it (last axis minor, unpadded).  A sparse layer GATHERS a turn of
+    64 listed blocks a row and key-value head out of the stacked leaf where
+    it lies (the leaf bitcast to blocks of 64 positions, the layer part of
+    the gather's index: six gathers of ``[8, 2, 64, 64, 128]``, keys and
+    values of three layers) and the 31 keys before ``pos`` for the window a
+    position completes (three of ``[8, 2, 31, 128]``); nothing slices a
+    layer out of ``k`` / ``v`` first.  The top rung's temporaries
+    (3.2 GB: a tile of the selection's float32 scores, the MLP's gate and
+    up, the scan's chunks) fit beside the arguments."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("minicpm_sala_l12")
+    memory = step.memory_analysis()
+    assert cache["k"].shape == cache["v"].shape == (3, 8, 2, 16384, 128)
+    assert cache["kbar"].shape == (3, 8, 2, 1024, 128)
+    assert cache["state"].shape == (9, 8, 32, 128, 128)
+    assert 8.41e9 < memory.argument_size_in_bytes < 8.44e9
+    assert 0.56e9 < memory.alias_size_in_bytes < 0.57e9  # the whole cache
+    assert memory.temp_size_in_bytes < 0.05e9  # 0.008 GB
+    assert memory.generated_code_size_in_bytes < 15e6  # 8 MB
+    text = step.as_text()
+    shape = ",".join(map(str, cache["state"].shape))
+    kernels = mamba_kernels(text, shape)
+    assert len(kernels) == 9 == text.count("tpu_custom_call")
+    assert not [name for name in kernels if "remat" in name]
+    assert leaf_is_only_handed_on(text, shape)
+    for leaf in ("k", "kbar"):  # positions (windows) on the sublanes
+        dims = ",".join(map(str, cache[leaf].shape))
+        assert re.search(
+            re.escape(f"bf16[{dims}]{{4,3,2,1,0:T(8,128)(2,1)}}"), text)
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
+    assert gathers.count("bf16[8,2,64,64,128]") == 6
+    assert gathers.count("bf16[8,2,31,128]") == 3
+    # a layer's slice of the keys or the values is never made (of the
+    # pooled keys it is, inside the fusion that scores them: 4 MB a layer)
+    assert not re.findall(
+        r"= bf16\[(?:1,)?8,2,16384,128\]\S* "
+        r"(?:copy|slice|dynamic-slice|fusion)\(", text)
+    fam, cfg, _, _ = cell("minicpm_sala_l12")
+    formats = step.input_formats[0][0]
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+    tokens = on_chip(jax.ShapeDtypeStruct((16384,), jnp.int32))
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    rung = jit_prefill_one(fam, cfg).lower(
+        lying, cache, tokens, scalar, scalar).compile()
+    assert rung.input_formats[0][0] == formats
+    assert rung.memory_analysis().alias_size_in_bytes > 0.56e9
+    assert rung.memory_analysis().temp_size_in_bytes < 3.6e9  # 3.23 GB
+    assert rung.memory_analysis().generated_code_size_in_bytes < 30e6
+    assert (memory.argument_size_in_bytes
+            + rung.memory_analysis().temp_size_in_bytes) < 12.5e9
+
+
 @pytest.mark.parametrize("layers,at", [(1, 0), (9, 4)],
                          ids=["one_layer", "layer_4_of_the_stack"])
 def test_delta_update_alone_is_one_kernel_over_the_donated_leaf(
@@ -1145,6 +1213,11 @@ CACHE_LEAVES = [
     ((3, 1, 8, 1024, 128), "bfloat16"),
     ((3, 1, 8, 512, 128), "bfloat16"),
     ((3, 1, 8, 256, 128), "bfloat16"),
+    ((3, 8, 2, 16384, 128), "bfloat16"),   # MiniCPM-SALA cell: keys / values
+    ((3, 8, 2, 1024, 128), "bfloat16"),    # of two heads and their POOLED
+    ((3, 1, 2, 1024, 128), "bfloat16"),    # keys, a window every 16
+    ((3, 1, 2, 16, 128), "bfloat16"),      # positions; one-row twins at the
+    ((3, 1, 2, 256, 128), "bfloat16"),     # top and the lowest rung
 ]
 
 
