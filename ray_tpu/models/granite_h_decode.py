@@ -21,13 +21,15 @@ same function): the WHOLE stacked ``ssm`` leaf goes through thirty-six calls
 of ``ops/mamba_update.py`` and comes back with every layer stepped, on a TPU
 by ONE lowered kernel that reads a slot's heads once and writes them where
 they lay, two crossings of the 4.83 GB a step and no copy of it
-(``tests/test_tpu_compile.py`` reads the compiled step); the small ``conv``
-leaf, of which every element moves every step, is built anew; attention goes
-by the deferred-scatter protocol of ``llama_decode.py``.  The forty layers of
+(``tests/test_tpu_compile.py`` reads the compiled step); the 120 MB ``conv``
+leaf goes through the same thirty-six layers whole (``ops/conv_update.py``:
+one slice into the fast memory and one in-place scatter a layer, the leaf
+read once and written once a step); attention goes by the deferred-scatter
+protocol of ``llama_decode.py``.  The forty layers of
 a decode step are written out, not looped: a loop would slice each layer's
 weights out of their stacks by a traced index, and a step that is bound by
 the memory's speed cannot afford a product that copies its weight first;
-what forty bodies cost a replica's start is in ``PERF.md`` (PR 60).  Two
+what forty bodies cost a replica's start is in ``PERF.md`` (PR 60).  Three
 things the v5e compiler did at 64 slots (12.4 GB of arguments; not at 32)
 and what stands against each.  While a layer's update was an XLA fusion
 that wrote its slice of the donated leaf in place, the compiler
@@ -42,7 +44,16 @@ write of the new VALUES into the cache ahead of the last attention layer's
 read of the old ones and paid for it with two copies of the 0.54 GB ``v``
 leaf a step: the new keys and values now pass one ``optimization_barrier``
 together with the stream after the last layer, so the cache is written at
-the step's end, as the protocol says, and nothing copies it.
+the step's end, as the protocol says, and nothing copies it.  And while the
+step took ``cache["conv"][i]`` a layer and built the leaf anew with a
+``jnp.stack`` at its end, the stack was written into the donated buffer the
+slices were read from: the compiler copied every slice out before the first
+write and then rematerialised the copies, 38 fusions with 414 outputs of
+``[64, 13056]`` float32 a step, 2.8 GB moved where 0.24 must be (3.8 ms of
+a 31 ms step on the chip, PR 63).  Threaded through the layers whole, the
+leaf is cut once a layer; ``conv_update`` holds its result behind an
+``optimization_barrier``, because an in-place scatter is an XLA fusion with
+two readers and layer 0's was cloned like the ``ssm`` updates of old.
 
 A decode row at position 0 is an idle slot (a prompt has at least one
 token): its state is computed like any other's and stays finite, every step
@@ -114,16 +125,15 @@ def granite_h_decode_step(
     blocks = params["blocks"]
     x = embed(params, tokens, cfg)  # [B, d]
     cache = dict(cache)
-    new_conv, new_k, new_v = [], [], []
+    new_k, new_v = [], []
     seen = dict.fromkeys(SCOPE, 0)
     for layer, kind in enumerate(cfg.kinds):
         i = seen[kind]
         seen[kind] += 1
 
         def mamba(y):
-            out, conv, cache["ssm"] = mamba_step(
-                y, cache["conv"][i], cache["ssm"], blocks["mamba"], i, cfg)
-            new_conv.append(conv)
+            out, cache["conv"], cache["ssm"] = mamba_step(
+                y, cache["conv"], cache["ssm"], blocks["mamba"], i, cfg)
             return out
 
         def attend(y):
@@ -137,9 +147,6 @@ def granite_h_decode_step(
 
         x = block(params, x, kind, i, layer,
                   mamba if kind == "M" else attend, cfg)
-    if new_conv:
-        with jax.named_scope(SCOPE["M"]):
-            cache["conv"] = jnp.stack(new_conv)
     if new_k:
         with jax.named_scope(SCOPE["*"]):  # the cache write is attention's
             # at the step's END, after the last layer's reads: the docstring

@@ -25,9 +25,14 @@ it holds and writes them where they lay (the engine donates the cache;
 ``tests/test_tpu_compile.py`` reads the compiled step: one kernel a layer
 and nothing else touches an array of the leaf's shape; off a TPU, and at the
 toy widths of the CPU tests, the XLA formulation: a slice updated in place
-and read again); the small ``conv`` leaf, of which every element moves every
-step, is built anew; and attention by the deferred-scatter protocol of
-``llama_decode.py``: the
+and read again); the ``conv`` leaf goes through the same layers whole, as
+``ssm`` does (``ops/conv_update.py``: a layer's window read out of the leaf,
+its taps lane slices of it, the shifted window written over the layer it
+was read from: one slice and one in-place update a layer in the compiled
+step, none a clone, where a ``jnp.stack`` of the new windows into the
+donated buffer made the v5e compiler copy the leaf's slices out eleven
+times over at Granite-4.0-H's sizes, PR 63); and
+attention by the deferred-scatter protocol of ``llama_decode.py``: the
 cache holds ``[0, pos-1]``, the current key and value are merged as a last
 score, and all are written at the step's end by ``write_token_to_cache``.
 
@@ -46,6 +51,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.conv_update import conv_update
 from ..ops.decode_attention import decode_attention, write_token_to_cache
 from ..ops.mamba_update import mamba_update
 from .llama import _rmsnorm
@@ -92,23 +98,19 @@ def nemotron_h_prefill(
     return (*out, counts) if with_counts else out
 
 
-def mamba_step(y, conv_state, leaf, m, i: int, cfg: NemotronHConfig):
+def mamba_step(y, conv_leaf, leaf, m, i: int, cfg: NemotronHConfig):
     """One token a row through Mamba-2 layer ``i``, whose state is layer
-    ``i`` of the stacked ``leaf [M, B, H, P, N]``.  y ``[B, d]``, conv_state
-    ``[B, (K-1)(HP + 2GN)]`` -> (``[B, d]`` float32, the convolution's state
-    after the token, the leaf with layer ``i`` stepped: the same buffer
-    where the caller donated it, ``ops/mamba_update.py``)."""
+    ``i`` of the two stacked leaves ``conv_leaf [M, B, (K-1)(HP + 2GN)]`` and
+    ``leaf [M, B, H, P, N]``.  y ``[B, d]`` -> (``[B, d]`` float32, the two
+    leaves with layer ``i`` stepped: the same buffers where the caller
+    donated them, ``ops/conv_update.py`` and ``ops/mamba_update.py``)."""
     z, xbc, dt = mamba_project(y, m, i, cfg)
-    window = jnp.concatenate(
-        [conv_state.astype(jnp.float32), xbc], axis=1)  # [B, K C]
-    conv = (window.reshape(-1, cfg.conv_kernel, cfg.d_conv)
-            * m["conv_w"][i]).sum(1) + m["conv_b"][i]
-    x, b, c = split_xbc(jax.nn.silu(conv), cfg)
+    conv, conv_leaf = conv_update(conv_leaf, i, xbc, m["conv_w"][i])
+    x, b, c = split_xbc(jax.nn.silu(conv + m["conv_b"][i]), cfg)
     keep = jnp.exp(dt * -jnp.exp(m["a_log"][i]))  # [B, H]
     out, leaf = mamba_update(leaf, i, x, dt, keep, b, c)
     out = out + m["d_skip"][i][:, None] * x  # [B, H, P]
-    return (mamba_output(out, z, m, i, cfg),
-            window[:, cfg.d_conv:].astype(conv_state.dtype), leaf)
+    return mamba_output(out, z, m, i, cfg), conv_leaf, leaf
 
 
 def nemotron_h_decode_step(
@@ -122,12 +124,11 @@ def nemotron_h_decode_step(
         x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
         live = pos > 0
     cache = dict(cache)
-    new_conv, new_k, new_v = [], [], []
+    new_k, new_v = [], []
 
     def mamba(i, y):
-        out, conv, cache["ssm"] = mamba_step(
-            y, cache["conv"][i], cache["ssm"], blocks["mamba"], i, cfg)
-        new_conv.append(conv)
+        out, cache["conv"], cache["ssm"] = mamba_step(
+            y, cache["conv"], cache["ssm"], blocks["mamba"], i, cfg)
         return out
 
     def attend(i, y):
@@ -139,9 +140,6 @@ def nemotron_h_decode_step(
         return matmul("bhd,hde->be", o.astype(y.dtype), blocks["attn"]["wo"][i])
 
     x, counts = run_layers(params, x, live, mamba, attend, cfg)
-    if new_conv:
-        with jax.named_scope("nemotron.mamba"):
-            cache["conv"] = jnp.stack(new_conv)
     if new_k:
         with jax.named_scope("nemotron.attn"):  # its cache write
             cache["k"] = write_token_to_cache(

@@ -24,8 +24,10 @@ and ``alpha S + k (x) delta``; ``o_t = alpha S^T q + (k . q) delta`` needs no
 reading of the new state), written over the layer's blocks of the stacked
 leaf where they lie: the whole leaf goes through the nine calls, aliased (the
 engine donates the cache; ``tests/test_tpu_compile.py`` reads the compiled
-step for a copy or a slice; the small ``conv`` leaf, of which every
-element moves every step, is built anew).  Attention goes by the
+step for a copy or a slice); the ``conv`` leaf goes through the same nine
+layers whole (``ops.conv_update``: a layer's window read out of the leaf,
+the shifted window written over the layer it was read from).  Attention
+goes by the
 deferred-scatter protocol of ``llama_decode.py``: the cache holds ``[0,
 pos-1]``, the current key and value are merged as a last score, and all are
 written at the step's end by ``write_token_to_cache``.
@@ -45,6 +47,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops.conv_update import conv_update
 from ..ops.decode_attention import decode_attention, write_token_to_cache
 from ..ops.delta_update import delta_update
 from .llama import _rmsnorm
@@ -93,31 +96,29 @@ def olmo_hybrid_prefill(
     return (*out, counts) if with_counts else out
 
 
-def delta_step_at(y, conv_state, leaf, at: int, m, i: int,
+def delta_step_at(y, conv_leaf, leaf, at: int, m, i: int,
                   cfg: OlmoHybridConfig):
     """One token a row through linear layer ``i``, whose state is layer ``at``
-    of the stacked ``leaf [layers, B, H / p, dk, p dv]``.  y ``[B, d]``,
-    conv_state ``[B, (K-1)(2 H dk + H dv)]`` -> (``[B, d]`` float32, the
-    convolution's state after the token in the dtype it came in, the leaf
-    with layer ``at`` updated where it lies: ``ops.delta_update``)."""
+    of the two stacked leaves ``conv_leaf [layers, B, (K-1)(2 H dk + H dv)]``
+    and ``leaf [layers, B, H / p, dk, p dv]``.  y ``[B, d]`` -> (``[B, d]``
+    float32, the two leaves with layer ``at`` updated where it lies:
+    ``ops.conv_update`` and ``ops.delta_update``)."""
     qkv, z, g, beta = delta_project(y, m, i, cfg)
-    window = jnp.concatenate(
-        [conv_state.astype(jnp.float32), qkv], axis=1)  # [B, K C]
-    conv = (window.reshape(-1, cfg.conv_kernel, cfg.d_conv)
-            * m["conv_w"][i]).sum(1)
+    conv, conv_leaf = conv_update(conv_leaf, at, qkv, m["conv_w"][i])
     q, k, v = split_heads(jax.nn.silu(conv), cfg)  # [B, H, dk], [B, H, dv]
     o, leaf = delta_update(leaf, at, q, k, v, jnp.exp(g)[..., None],
                            beta[..., None])
-    return (delta_output(o, z, m, i, cfg),
-            window[:, cfg.d_conv:].astype(conv_state.dtype), leaf)
+    return delta_output(o, z, m, i, cfg), conv_leaf, leaf
 
 
 def delta_step(y, conv_state, state, m, i: int, cfg: OlmoHybridConfig):
-    """``delta_step_at`` on ONE layer's state ``[B, H / p, dk, p dv]`` (a
-    stack of one: the same kernel) -> (``[B, d]`` float32, the two states
-    after the token, in the dtypes they came in)."""
-    out, conv, leaf = delta_step_at(y, conv_state, state[None], 0, m, i, cfg)
-    return out, conv, leaf[0]
+    """``delta_step_at`` on ONE layer's window ``[B, (K-1)(2 H dk + H dv)]``
+    and state ``[B, H / p, dk, p dv]`` (stacks of one: the same kernel) ->
+    (``[B, d]`` float32, the two after the token, in the dtypes they came
+    in)."""
+    out, conv, leaf = delta_step_at(
+        y, conv_state[None], state[None], 0, m, i, cfg)
+    return out, conv[0], leaf[0]
 
 
 def olmo_hybrid_decode_step(
@@ -130,17 +131,15 @@ def olmo_hybrid_decode_step(
     with jax.named_scope("olmo.embed"):
         x = params["wte"][tokens].astype(jnp.float32)  # [B, d]
     cache = dict(cache)
-    new_conv, new_k, new_v = [], [], []
+    new_k, new_v = [], []
     seen = dict.fromkeys(STACK, 0)
     for kind in cfg.kinds:
         i = seen[kind]
         seen[kind] += 1
 
         def delta(y):
-            out, conv, cache["state"] = delta_step_at(
-                y, cache["conv"][i], cache["state"], i, blocks["linear"], i,
-                cfg)
-            new_conv.append(conv)
+            out, cache["conv"], cache["state"] = delta_step_at(
+                y, cache["conv"], cache["state"], i, blocks["linear"], i, cfg)
             return out
 
         def attend(y):
@@ -153,9 +152,6 @@ def olmo_hybrid_decode_step(
                           blocks["full"]["wo"][i])
 
         x = block(params, x, kind, i, delta if kind == "L" else attend, cfg)
-    if new_conv:
-        with jax.named_scope("olmo.delta"):
-            cache["conv"] = jnp.stack(new_conv)
     if new_k:
         with jax.named_scope("olmo.attn"):  # the cache write is attention's
             cache["k"] = write_token_to_cache(

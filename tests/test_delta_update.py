@@ -208,9 +208,10 @@ def test_one_layers_state_goes_through_the_same_kernel_as_the_stack(
     monkeypatch.setattr(olmo_hybrid_decode, "delta_update", forced)
     alone = olmo_hybrid_decode.delta_step(y, conv[1], state[1], m, 1, cfg)
     out, new_conv, leaf = olmo_hybrid_decode.delta_step_at(
-        y, conv[1], state, 1, m, 1, cfg)
+        y, conv, state, 1, m, 1, cfg)
     assert calls == [(1,) + state.shape[1:], state.shape]
-    for got, other, ideal in zip(alone, (out, new_conv, leaf[1]), want):
+    for got, other, ideal in zip(alone, (out, new_conv[1], leaf[1]), want):
         np.testing.assert_array_equal(got, other)
         close(got, ideal)
     np.testing.assert_array_equal(leaf[0], state[0])
+    np.testing.assert_array_equal(new_conv[0], conv[0])

@@ -214,6 +214,41 @@ def test_a_padded_prefill_leaves_the_state_of_the_true_length(weights, n):
         atol=F32_TOL)
 
 
+@pytest.mark.parametrize("lengths", [[1, 2, 6], [5, 9, 14]], ids=str)
+def test_each_decode_step_shifts_every_layers_window_by_its_token(lengths):
+    """The ``conv`` leaf goes through the linear layers whole and each
+    shifts its own layer of it where it lies: after every step, in EVERY
+    layer, the window is the last three inputs of the convolution, oldest
+    first (zeros before a prompt's start: rows of 1 and 2 tokens), which is
+    what a prefill of the same tokens leaves.  What it held moved one place
+    to the bit, and the ``state`` leaf beside it is the prefill's too.  A
+    layer shifted twice (a cloned update), a layer left stale or a window
+    written into another layer's place fails here."""
+    cfg = tiny()
+    params = weights_of(cfg, seed=2)
+    fam = model_family(cfg)
+    lengths, steps = np.asarray(lengths, np.int32), 5
+    width = int(lengths.max()) + steps
+    toks = tokens_of(cfg, 3, width, seed=2)
+    prefill = jax.jit(lambda n: fam.prefill(
+        params, toks, n, fam.init_cache(cfg, 3, width), cfg)[1])
+    decode = jax.jit(lambda t, pos, c: fam.decode_step(
+        params, t, pos, c, cfg)[1])
+    cache, rows, c = prefill(lengths), np.arange(3), cfg.d_conv
+    for i in range(steps):
+        pos, old = lengths + i, np.asarray(cache["conv"])
+        cache = decode(toks[rows, pos], pos, cache)
+        new, want = np.asarray(cache["conv"]), prefill(pos + 1)
+        np.testing.assert_array_equal(new[..., :-c], old[..., c:])
+        np.testing.assert_allclose(new, want["conv"], atol=F32_TOL)
+        np.testing.assert_allclose(cache["state"], want["state"],
+                                   atol=F32_TOL)
+        # every layer's newest input is its own and none is a repeat
+        newest = new[..., -c:]
+        assert np.abs(newest - new[..., -2 * c:-c]).max(-1).min() > 1e-2
+        assert np.abs(newest[1:] - newest[:-1]).max(-1).min() > 1e-2
+
+
 def first_linear_layer(params, toks, cfg):
     """(its weights, its normed input): the stream after layer 0 (full)."""
     sizes = dataclasses.asdict(cfg)

@@ -360,12 +360,12 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
 # temporaries (the limits of the tests around this one).
 BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "longcat_flash_l4_ep32": (8, 0.2e9),
-                 "nemotron3_super_l11_ep4": (1, 0.2e9),
+                 "nemotron3_super_l11_ep4": (1, 0.05e9),
                  "mimo_v25_l7_ep16": (2, 0.05e9),
                  "mistral_small4_l9_ep8": (9, 0.05e9),
                  "laguna_s21_l9_ep16": (3, 0.05e9),
-                 "olmo_hybrid7b_l12": (3, 0.2e9),
-                 "granite4h_micro": (4, 0.2e9),
+                 "olmo_hybrid7b_l12": (3, 0.05e9),
+                 "granite4h_micro": (4, 0.05e9),
                  # no contiguous read: its three sparse layers read a LIST
                  # of blocks (``attend_listed_blocks``; the test of the
                  # MiniCPM-SALA cell below), and nothing of a prefix's shape
@@ -458,6 +458,27 @@ def leaf_is_only_handed_on(text, shape):
         "parameter", "get-tuple-element"} and not touched
 
 
+def window_traffic(text, leaf):
+    """What a compiled step's ENTRY does with the convolutions' stacked
+    windows ``leaf [layers, slots, (K-1) C]`` (``ops/conv_update.py``): the
+    ops that produce an array of the leaf's shape, the ops that produce an
+    array of ONE layer's shape (a fusion of several outputs once an output),
+    and the names among both that are rematerialised clones.  Views and the
+    ``-start`` half of an asynchronous pair apart."""
+    layers, slots, width = leaf.shape
+    one = re.compile(rf"f32\[(?:1,)?{slots},{width}\]")
+    made, slices, clones = [], [], []
+    for name, result, op, _ in named_instructions(text, entry_only=True):
+        if op.endswith("-start"):
+            continue
+        whole = f"f32[{layers},{slots},{width}]" in result
+        made += [op] * whole
+        slices += [op] * len(one.findall(result))
+        if "remat" in name and (whole or one.search(result)):
+            clones.append(name)
+    return made, slices, clones
+
+
 def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     cell_decode_step
 ):
@@ -473,12 +494,21 @@ def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     array of the leaf's shape, nothing slices or copies it, and the step's
     temporaries stay far under one layer's slice (268 MB): a stack of the
     layers' new states at the step's end, or a slice copied out for its
-    products, would be 1.34 GB more a step (PR 34's lesson)."""
+    products, would be 1.34 GB more a step (PR 34's lesson).  The 39 MB
+    ``conv`` leaf goes through the same five layers whole
+    (``ops/conv_update.py``): five fusions take a layer's window out of it,
+    five more, rooted at the scatter, write the shifted window where it lay,
+    and none of the ten is a clone.  What else produces an array of the
+    leaf's or a layer's shape only moves it between the chip's memories: the
+    compiler prefetches the whole leaf into the fast memory, runs the middle
+    layers' updates there and copies it back ONCE, as it did the stack of
+    the new windows that the step built until PR 63 (32 asynchronous slices
+    of the leaf then, and 45 MB of temporaries where 13 stand)."""
     compiled, cache, _ = cell_decode_step("nemotron3_super_l11_ep4")
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 10.8e9
     assert memory.alias_size_in_bytes > 1.5e9  # the whole cache, donated
-    assert memory.temp_size_in_bytes < 0.2e9   # 0.05 GB
+    assert memory.temp_size_in_bytes < 0.05e9  # 0.013 GB
     shape = ",".join(map(str, cache["ssm"].shape))
     assert shape == "5,64,128,64,128"
     text = compiled.as_text()
@@ -486,6 +516,12 @@ def test_hybrid_decode_step_updates_its_recurrent_state_where_it_lies(
     assert len(mamba_kernels(text, shape)) == 5 == text.count(
         "tpu_custom_call")
     assert leaf_is_only_handed_on(text, shape)
+    assert cache["conv"].shape == (5, 64, 3 * 10240)
+    made, slices, clones = window_traffic(text, cache["conv"])
+    assert not clones
+    assert sorted(made) == ["copy-done", "custom-call", *["fusion"] * 5]
+    assert slices.count("fusion") == 5
+    assert set(slices) <= {"fusion", "copy-done", "slice-done", "custom-call"}
 
 
 def test_windowed_decode_step_fits_and_its_top_rung_beside_it(
@@ -709,7 +745,15 @@ def test_delta_decode_step_updates_its_packed_state_where_it_lies(
     slice and a second fusion, rooted at the ``dynamic-update-slice``, that
     read it again: PR 56).  Nothing else produces or reads an array of the
     leaf's or of a layer's shape: no slice, no ``dynamic-update-slice``, no
-    ``copy``.  The four rungs are two layer bodies each, scanned
+    ``copy``.  The 80 MB ``conv`` leaf goes through the same nine layers
+    whole (``ops/conv_update.py``): nine fusions take a layer's window out
+    of it into the fast memory, nine more, rooted at the scatter, write the
+    shifted window where it lay, none a clone and no copy of the leaf (three
+    of the windows pass through the main memory on their way: the
+    compiler's eviction, a ``copy-done``); while the step stacked the new
+    windows at its end the compiler cut the donated leaf into seventeen
+    rematerialised slices and held 0.11 GB of temporaries (until PR 63).
+    The four rungs are two layer bodies each, scanned
     (``olmo_hybrid.layer_plan``): 12.6-16.9 MB of code a rung where the top
     rung written out is 59 MB (the chip's compile cache holds ~190 MiB for
     all cells' programs), 0.57 GB of temporaries at the top rung (1.08
@@ -724,9 +768,14 @@ def test_delta_decode_step_updates_its_packed_state_where_it_lies(
     assert cache["conv"].shape == (9, 64, 3 * 11520)
     assert 13.9e9 < memory.argument_size_in_bytes < 13.95e9
     assert 7.39e9 < memory.alias_size_in_bytes < 7.40e9  # the whole cache
-    assert memory.temp_size_in_bytes < 0.2e9  # 0.11 GB
+    assert memory.temp_size_in_bytes < 0.05e9  # 0.025 GB
     assert memory.generated_code_size_in_bytes < 25e6
     text = step.as_text()
+    made, slices, clones = window_traffic(text, cache["conv"])
+    assert not clones
+    assert made == ["fusion"] * 9
+    assert slices.count("fusion") == 9 and set(slices) <= {
+        "fusion", "copy-done"}
     shape = ",".join(map(str, cache["state"].shape))
     # the leaf as the program holds it: last axis minor, no padding
     leaf = re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}")
@@ -792,7 +841,7 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
     from the donated leaf to itself: thirty-six calls of the one lowered
     kernel, nothing else produces, slices or copies an array of the leaf's
     shape (a second leaf would not fit), and the step's temporaries are a
-    layer's slice (137 MB) at most.  NONE of the kernels is a clone: while
+    sixth of a layer's slice (137 MB).  NONE of the kernels is a clone: while
     the updates were XLA fusions the compiler rematerialised layer 0's at
     this size (not at 32 slots; ``...remat``, ``...remat2``: one fed layer
     1's read-out, one layer 1's update, both written in place over the same
@@ -801,7 +850,20 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
     kernels in, the compiler wrote the new values into ``v`` ahead of the
     last attention layer's read and copied the 0.54 GB leaf twice a step
     (0.60 GB of temporaries) until the step held its cache writes behind an
-    ``optimization_barrier`` (PR 61).  Keys and values lie positions-minor
+    ``optimization_barrier`` (PR 61).  The 120 MB ``conv`` leaf goes through
+    the same thirty-six layers whole (``ops/conv_update.py``): ONE fusion a
+    layer takes the layer's ``[64, 13056]`` window out of it into the fast
+    memory and one more, rooted at the scatter, writes the shifted window
+    where it lay; nothing else produces an array of the leaf's or of a
+    layer's shape, none of the seventy-two is a clone and nothing copies the
+    leaf.  While the step took ``cache["conv"][i]`` a layer and stacked the
+    new windows at its end, the leaf it read WAS the donated buffer the
+    stack was written into: the compiler copied every slice out first and
+    rematerialised the copies, 38 fusions with 414 outputs of 3.3 MB a step
+    (3.8 ms of 31 on the chip, 124 MB of temporaries: PERF.md, PR 63); and
+    without ``conv_update``'s ``optimization_barrier`` layer 0's scatter is
+    cloned for its second reader (``fusion.815.remat``), as PR 60's updates
+    of the ``ssm`` leaf were.  Keys and values lie positions-minor
     (heads of 64 on the sublanes, 2048 positions on the lanes): NOT padded
     to 128, 0.54 GB each.  The forty layers are written out (40 MB of code,
     53 while each update was two fusions); the top rung folds them into five
@@ -816,8 +878,8 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
     assert "lm_head" not in params
     assert 12.40e9 < memory.argument_size_in_bytes < 12.42e9
     assert 6.02e9 < memory.alias_size_in_bytes < 6.03e9  # the whole cache
-    assert memory.temp_size_in_bytes < 0.2e9  # 0.12 GB
-    assert memory.generated_code_size_in_bytes < 70e6  # 40 MB
+    assert memory.temp_size_in_bytes < 0.05e9  # 0.023 GB
+    assert memory.generated_code_size_in_bytes < 70e6  # 41 MB
     text = step.as_text()
     shape = ",".join(map(str, cache["ssm"].shape))
     assert re.search(re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}"), text)
@@ -827,6 +889,10 @@ def test_whole_model_decode_step_updates_forty_layers_of_cache_in_place(
     assert len(kernels) == 36 == text.count("tpu_custom_call")
     assert not [name for name in kernels if "remat" in name]
     assert leaf_is_only_handed_on(text, shape)
+    # the windows: one slice and one in-place update a layer, no clone
+    made, slices, clones = window_traffic(text, cache["conv"])
+    assert not clones
+    assert made == slices == ["fusion"] * 36
     # keys and values: positions on the lanes, no padding of the heads' 64
     kv = ",".join(map(str, cache["k"].shape))
     assert re.search(re.escape(f"bf16[{kv}]{{3,4,2,1,0:T(8,128)(2,1)}}"), text)
