@@ -37,7 +37,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ..models import model_family
-from ..models.gpt2_decode import sample_logits
+from ..models.sampling import sample_logits
 from .engine import EngineConfig, JaxLLMEngine, SamplingParams
 from .tokenizer import ByteTokenizer
 

@@ -142,7 +142,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from ..models import GPT2Config, model_family
-from ..models.gpt2_decode import sample_logits_greedy, sample_logits_rows
+from ..models.sampling import sample_logits_greedy, sample_logits_rows
 from ..ops.decode_attention import live_extent
 from ..util import flight_recorder, tracing
 from ..util.tracing import host_span

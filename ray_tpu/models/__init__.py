@@ -14,9 +14,6 @@ from .gpt2_decode import (  # noqa: F401
     gpt2_decode_step,
     gpt2_init_cache,
     gpt2_prefill,
-    sample_logits,
-    sample_logits_greedy,
-    sample_logits_rows,
 )
 from .granite_h import (  # noqa: F401
     GraniteHConfig,
@@ -177,6 +174,11 @@ from .resnet import (  # noqa: F401
     resnet_init,
     resnet_loss,
     resnet_param_axes,
+)
+from .sampling import (  # noqa: F401
+    sample_logits,
+    sample_logits_greedy,
+    sample_logits_rows,
 )
 from .vit import ViTConfig, vit_apply, vit_init, vit_loss, vit_param_axes  # noqa: F401
 

@@ -12,9 +12,9 @@ That sum is here: ``held_choices`` turns the router's choices into the held
 experts' hit mask and combine weights, ``held_experts`` gathers each
 expert's tokens and walks the chunks (a decode step's rows are one chunk: a
 turn a touched expert on the whole batch).  The expert itself, ``f_e``, is the
-caller's: gated SwiGLU on the hidden state in ``longcat.py`` (``ffn``, which
-``mimo_v2.py`` and ``mistral4.py`` run too), an ungated ``relu^2`` MLP on a
-latent in ``nemotron_h.py``.  What absent experts would add is left out; what
+caller's: gated SwiGLU on the hidden state (``layers.ffn``: LongCat, MiMo-V2,
+Mistral-4, Laguna), an ungated ``relu^2`` MLP on a latent in
+``nemotron_h.py``.  What absent experts would add is left out; what
 every chip computes alike (a shared expert) is the family's, beside this.
 """
 
@@ -22,6 +22,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from .layers import matmul
 
 # Rows of one expert's matrix product: a held expert sees few tokens (0.5-3 a
 # decode step, 8-128 a prefill of 256-4096 rows), so its tokens are gathered
@@ -31,10 +33,6 @@ import jax.numpy as jnp
 # expert no live token chose runs nothing and reads no weight.
 EXPERT_CHUNK = 128
 LOOP_COUNT_NAMES = ("held_chunks", "held_chunk_rows")  # ``loop_counts``
-
-
-def _matmul(spec, x, w):  # ``longcat.matmul``, which imports this module
-    return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
 
 
 def runs_every_held_expert(rows: int, top_k: int, n_routed: int) -> bool:
@@ -219,7 +217,7 @@ def held_experts_dense(u, w_held, experts, i: int):
     dtype, w_held ``[N, Eh]`` float32 -> ``[N, d]`` float32.  Dropless and row by
     row like the loop; the weight is applied before the last product (which
     is linear), in float32."""
-    gate = jax.nn.silu(_matmul("nd,edf->enf", u, experts["w_gate"][i]))
-    up = _matmul("nd,edf->enf", u, experts["w_up"][i])
+    gate = jax.nn.silu(matmul("nd,edf->enf", u, experts["w_gate"][i]))
+    up = matmul("nd,edf->enf", u, experts["w_up"][i])
     h = (gate * up * w_held.T[..., None]).astype(u.dtype)
-    return _matmul("enf,efd->nd", h, experts["w_down"][i])
+    return matmul("enf,efd->nd", h, experts["w_down"][i])

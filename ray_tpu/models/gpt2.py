@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .layers import layernorm
+
 
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
@@ -129,14 +131,6 @@ def gpt2_param_axes():
     }
 
 
-def _layernorm(x, g, b, eps=1e-5):
-    x32 = x.astype(jnp.float32)
-    mu = x32.mean(-1, keepdims=True)
-    var = ((x32 - mu) ** 2).mean(-1, keepdims=True)
-    y = (x32 - mu) * jax.lax.rsqrt(var + eps)
-    return (y * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
-
-
 def _attention(q, k, v, cfg: GPT2Config, mesh):
     if cfg.attention == "dense_remat":
         # Dense XLA attention (fastest at moderate S on this chip: the
@@ -177,7 +171,7 @@ def _block(x, layer, cfg: GPT2Config, mesh):
     from jax.ad_checkpoint import checkpoint_name as _ckpt_name
 
     with jax.named_scope("gpt2.attn"):
-        y = _layernorm(x, layer["ln1_g"], layer["ln1_b"])
+        y = layernorm(x, layer["ln1_g"], layer["ln1_b"])
         qkv = jnp.einsum("bse,ethd->bsthd", y, layer["wqkv"]) + layer["bqkv"]
         qkv = _ckpt_name(qkv, "qkv")
         if cfg.attention == "flash":
@@ -197,7 +191,7 @@ def _block(x, layer, cfg: GPT2Config, mesh):
         x = x + (jnp.einsum("bshd,hde->bse", o, layer["wo"]) + layer["bo"]).astype(x.dtype)
         x = _ckpt_name(x, "attn_resid")
     with jax.named_scope("gpt2.mlp"):
-        y = _layernorm(x, layer["ln2_g"], layer["ln2_b"])
+        y = layernorm(x, layer["ln2_g"], layer["ln2_b"])
         hdn = jax.nn.gelu(jnp.einsum("bse,ef->bsf", y, layer["wi"]) + layer["bi"])
         hdn = _ckpt_name(hdn, "mlp_hidden")
         hdn = wlc(hdn, P("batch", "seq", "mlp"), mesh)
@@ -262,7 +256,7 @@ def gpt2_hidden(params, tokens, cfg: GPT2Config, mesh=None):
 
     x, _ = jax.lax.scan(scan_body, x, params["blocks"])
     with jax.named_scope("gpt2.head"):  # the final norm is the head's
-        return _layernorm(x, params["lnf_g"], params["lnf_b"])
+        return layernorm(x, params["lnf_g"], params["lnf_b"])
 
 
 def gpt2_apply(params, tokens, cfg: GPT2Config, mesh=None):

@@ -11,7 +11,7 @@ published sizes: ``d`` 2048; Mamba-2: ``H`` 64 heads of ``P`` 64 channels,
 ``N`` 128 the state's size, ``G`` 1 group, ``K`` 4 taps, ``Q`` 256 the chunk;
 attention: ``Hq`` 32 query / ``Hkv`` 8 key-value heads of ``D`` 64; ``F`` 8192
 the MLP's width.  Everything between two matrix products is float32; the
-products read ``cfg.dtype`` and accumulate in float32 (``longcat.matmul``);
+products read ``cfg.dtype`` and accumulate in float32 (``layers.matmul``);
 the residual stream is float32, as in ``nemotron_h``.
 
 **Model**: ``x_0 = embedding_multiplier E[token]`` (12).  Layer ``i``: ``x = x
@@ -70,11 +70,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .laguna import scan_or_call
-from .llama import _rmsnorm
-from .longcat import ffn, matmul
-from .mistral4 import blocked_attention
-from .nemotron_h import mamba_sequence
+from .layers import (blocked_attention, ffn, layer_plan, matmul, rmsnorm,
+                     scan_or_call)
+from .mamba2 import mamba_sequence
 
 PUBLISHED_PATTERN = "MMMMM*" + "MMMMMMMMM*" * 3 + "MMMM"
 # a kind of mixer -> its stack under params["blocks"]
@@ -273,40 +271,13 @@ def block(params, x, kind: str, i: int, layer, mix, cfg: GraniteHConfig):
     copy of the layer)."""
     blocks, dt = params["blocks"], jnp.dtype(cfg.dtype)
     with jax.named_scope(SCOPE[kind]):
-        y = _rmsnorm(x, blocks[STACK[kind]]["rms"][i], cfg.rms_eps)
+        y = rmsnorm(x, blocks[STACK[kind]]["rms"][i], cfg.rms_eps)
         x = x + cfg.residual_multiplier * mix(y.astype(dt))
     with jax.named_scope("granite.mlp"):
         w = blocks["mlp"]
-        y = _rmsnorm(x, w["rms"][layer], cfg.rms_eps).astype(dt)
+        y = rmsnorm(x, w["rms"][layer], cfg.rms_eps).astype(dt)
         return x + cfg.residual_multiplier * ffn(
             y, w["w_gate"][layer], w["w_up"][layer], w["w_down"][layer])
-
-
-def layer_plan(kinds: str):
-    """``kinds`` as runs of one kind, what repeats folded: a list of ``(group,
-    repeats)``, a group being consecutive runs ``(kind, length)`` whose kinds
-    and lengths come again right after it.  The published forty layers are
-    ``[M5]``, ``[*1, M9] x 3``, ``[*1]`` and ``[M4]``: five layer bodies in a
-    program of forty layers (three Mamba-2, two attention)."""
-    runs = []
-    for kind in kinds:
-        if runs and runs[-1][0] == kind:
-            runs[-1][1] += 1
-        else:
-            runs.append([kind, 1])
-    runs = [tuple(run) for run in runs]
-    plan, r = [], 0
-    while r < len(runs):
-        best = (1, 1)  # (runs in the group, repeats), most runs folded
-        for g in range(1, (len(runs) - r) // 2 + 1):
-            c = 1
-            while runs[r + c * g:r + (c + 1) * g] == runs[r:r + g]:
-                c += 1
-            if c > 1 and g * c > best[0] * best[1]:
-                best = (g, c)
-        plan.append((runs[r:r + best[0]], best[1]))
-        r += best[0] * best[1]
-    return plan
 
 
 def granite_h_forward(params, tokens, lengths, cfg: GraniteHConfig):
@@ -382,7 +353,7 @@ def granite_h_forward(params, tokens, lengths, cfg: GraniteHConfig):
         layer += repeats * span
 
     with jax.named_scope("granite.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
     cache = {}
     for kind, names in (("M", ("conv", "ssm")), ("*", ("k", "v"))):

@@ -14,9 +14,9 @@ rung keep what the last tenant left (decode reads nothing at or beyond
 ``pos``).
 
 Prefill runs the chunked scan over the padded prompt with ``dt = 0`` at
-positions ``>= length`` (``nemotron_h.mamba_sequence``): the state it returns
+positions ``>= length`` (``mamba2.mamba_sequence``): the state it returns
 is the state at the prompt's TRUE length, whatever the rung.  Decode runs one
-step of the recurrence for all slots (``nemotron_h_decode.mamba_step``, the
+step of the recurrence for all slots (``mamba2.mamba_step``, the
 same function): the WHOLE stacked ``ssm`` leaf goes through thirty-six calls
 of ``ops/mamba_update.py`` and comes back with every layer stepped, on a TPU
 by ONE lowered kernel that reads a slot's heads once and writes them where
@@ -74,9 +74,8 @@ from ..ops.decode_attention import decode_attention, write_token_to_cache
 from .granite_h import (CACHE_SCOPE, SCOPE, GraniteHConfig,
                         attention_project, block, embed, granite_h_forward,
                         head)
-from .llama import _rmsnorm
-from .longcat import matmul
-from .nemotron_h_decode import mamba_step
+from .layers import matmul, rmsnorm
+from .mamba2 import mamba_step
 
 
 def granite_h_init_cache(cfg: GraniteHConfig, batch: int, max_len: int):
@@ -156,7 +155,7 @@ def granite_h_decode_step(
             cache["v"] = write_token_to_cache(
                 cache["v"], jnp.stack(new_v), pos, axis=3)
     with jax.named_scope("granite.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = head(params, x, cfg)
     out = (logits, cache)

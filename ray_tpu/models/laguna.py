@@ -17,7 +17,7 @@ x))`` (eps 1e-6); ``attn_pattern[i]`` is ``F`` (full) or ``W`` (window),
 taken from the FRONT of both.  Then a final RMSNorm and an untied head.  The
 residual stream is float32; the matrix products read ``cfg.dtype`` and
 accumulate in float32, and what lies between two products is float32,
-rounded once where the next product reads it (``longcat.matmul``).
+rounded once where the next product reads it (``layers.matmul``).
 
 **Attention(u, pos)** of a layer with ``H`` query heads (its kind's): ``q =
 u Wq`` ``[H, D]``, ``k = u Wk``, ``v = u Wv`` ``[Hkv, D]``; ``q`` and ``k`` each
@@ -28,10 +28,10 @@ full ``j <= i``; window ``0 <= i - j < w``, no sink; ``o_h = P v``.  **The
 gate**: ``g = sigmoid(u Wg)``, ``Wg [d, H]``: one scalar a head a token, from
 the layer's NORMED INPUT; ``out = concat_h(g_h o_h) Wo``, ``Wo [H D, d]``.
 
-**Rotary** (``rotate_half`` pairing, ``mimo_v2.rope_half``).  A window layer:
+**Rotary** (``rotate_half`` pairing, ``layers.rope_half``).  A window layer:
 base ``rope_theta_window`` (1e4) on all ``D`` dimensions, no scaling.  A full
 layer: the FIRST ``rotary_dim`` (64) dimensions of a head, the others pass;
-YaRN (``mistral4.yarn_inv_freq`` over the ``rotary_dim / 2`` pairs: base
+YaRN (``layers.yarn_inv_freq`` over the ``rotary_dim / 2`` pairs: base
 ``rope_theta`` 5e5, ``rope_factor`` 128 over ``rope_original_max`` 8192
 positions, ``beta_fast`` 32 / ``beta_slow`` 1: pairs 0-9 keep their frequency,
 18-31 turn 128 x slower, a linear ramp between), and cos and sin carry
@@ -51,7 +51,7 @@ computes for the tokens that live on it.  The held parts of all shares and
 the shared expert counted once add up to the whole layer.
 
 A sequence's attention is scored a tile of 512 queries by 512 keys at a
-time (``mistral4.blocked_attention``, grouped: the 8 key-value heads are
+time (``layers.blocked_attention``, grouped: the 8 key-value heads are
 never repeated): a full layer's tiles stop at the diagonal and at the longest
 prompt, a window layer's are the BAND alone (two key tiles a query tile), so
 no array grows with the square of the sequence.
@@ -83,10 +83,8 @@ from jax.sharding import PartitionSpec as P
 from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
                            held_experts_dense, loop_counts,
                            runs_every_held_expert, sigmoid_route)
-from .llama import _rmsnorm
-from .longcat import add_counts, ffn, matmul
-from .mimo_v2 import ring_of, rope_half
-from .mistral4 import blocked_attention, yarn_inv_freq
+from .layers import (add_counts, blocked_attention, ffn, matmul, ring_of,
+                     rmsnorm, rope_half, scan_or_call, yarn_inv_freq)
 
 # layer_types (full at l mod 4 == 0) and mlp_layer_types, as letters
 PUBLISHED_ATTN = "FWWW" * 12
@@ -304,9 +302,9 @@ def attention_project(y, att, i, positions, kind: str, cfg: LagunaConfig):
     q = matmul("...e,ehd->...hd", y, att["wq"][i])
     k = matmul("...e,ekd->...kd", y, att["wk"][i])
     v = matmul("...e,ekd->...kd", y, att["wv"][i])
-    q = rotary(_rmsnorm(q, att["q_norm"][i], cfg.rms_eps), positions, kind,
+    q = rotary(rmsnorm(q, att["q_norm"][i], cfg.rms_eps), positions, kind,
                cfg)
-    k = rotary(_rmsnorm(k, att["k_norm"][i], cfg.rms_eps), positions, kind,
+    k = rotary(rmsnorm(k, att["k_norm"][i], cfg.rms_eps), positions, kind,
                cfg)
     return q.astype(y.dtype), k.astype(y.dtype), v.astype(y.dtype)
 
@@ -370,18 +368,18 @@ def block(params, x, live, kinds: str, i, j, attend, cfg: LagunaConfig):
     attn_kind, mlp_kind = kinds
     with jax.named_scope("laguna.attn_" + STACK[attn_kind]):
         att = blocks[STACK[attn_kind]]
-        y = _rmsnorm(x, att["rms"][i], cfg.rms_eps).astype(dt)
+        y = rmsnorm(x, att["rms"][i], cfg.rms_eps).astype(dt)
         gate = jax.nn.sigmoid(matmul("...e,eh->...h", y, att["wg"][i]))
         o = attend(att, y).astype(jnp.float32) * gate[..., None]
         x = x + matmul("...hd,hde->...e", o.astype(dt), att["wo"][i])
     if mlp_kind == "D":
         with jax.named_scope("laguna.mlp"):
             dense = blocks["dense"]
-            u = _rmsnorm(x, dense["rms"][j], cfg.rms_eps).astype(dt)
+            u = rmsnorm(x, dense["rms"][j], cfg.rms_eps).astype(dt)
             return x + ffn(u, dense["w_gate"][j], dense["w_up"][j],
                            dense["w_down"][j]), None
     with jax.named_scope("laguna.moe"):
-        u = _rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # float32
+        u = rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # float32
         u, live = u.reshape(-1, u.shape[-1]), live.reshape(-1)
     y, counts = moe(u, live, params, j, cfg)
     with jax.named_scope("laguna.shared"):  # the sum's last term
@@ -421,15 +419,6 @@ def layer_plan(cfg: LagunaConfig):
         plan.append((runs[r:r + best[0]], best[1]))
         r += best[0] * best[1]
     return plan
-
-
-def scan_or_call(body, carry, times: int):
-    """``lax.scan(body, carry, arange(times))``; once, the body itself with a
-    Python 0 for its counter, its outputs stacked as a scan's would be."""
-    if times > 1:
-        return jax.lax.scan(body, carry, jnp.arange(times))
-    carry, out = body(carry, 0)
-    return carry, jax.tree.map(lambda a: a[None], out)
 
 
 def laguna_forward(params, tokens, lengths, cfg: LagunaConfig):
@@ -506,7 +495,7 @@ def laguna_forward(params, tokens, lengths, cfg: LagunaConfig):
 
     x, total = carry
     with jax.named_scope("laguna.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
     cache = {}
     for kind, names in LEAVES.items():

@@ -15,7 +15,7 @@ rung keep what the last tenant left (decode reads nothing at or beyond
 
 Prefill at a rung ``S`` >= the prompt's length ``n`` writes the full layers'
 keys and values of ``[0, S)``, leaves in a ring the LAST ``w`` TRUE positions
-(``mimo_v2.ring_of``: a gather by ``lengths``), and takes the logits at ``n -
+(``layers.ring_of``: a gather by ``lengths``), and takes the logits at ``n -
 1``.  Decode: the current token's key and value ride beside the cache and
 are merged as a last score (the deferred write every family uses); a full
 layer reads its slice in blocks of 512 positions up to the batch's longest
@@ -43,8 +43,7 @@ import jax.numpy as jnp
 from ..ops.decode_attention import decode_attention, write_token_to_cache
 from .laguna import (COUNT_NAMES, LEAVES, STACK, LagunaConfig,
                      attention_project, block, laguna_forward)
-from .llama import _rmsnorm
-from .longcat import add_counts, matmul
+from .layers import add_counts, matmul, rmsnorm
 
 
 def laguna_init_cache(cfg: LagunaConfig, batch: int, max_len: int):
@@ -125,7 +124,7 @@ def laguna_decode_step(
                     cache[leaf] = write_token_to_cache(
                         cache[leaf], jnp.stack(new[leaf]), at, axis=3)
     with jax.named_scope("laguna.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .layers import rmsnorm, rope
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -119,31 +121,6 @@ def llama_param_axes():
     }
 
 
-def _rmsnorm(x, g, eps: float):
-    x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    return (x32 * scale * g.astype(jnp.float32)).astype(x.dtype)
-
-
-def rope(x, positions, theta: float, inv_freq=None):
-    """Rotary embedding.  x: [B, S, H, D]; positions: [B, S] or [S].
-    ``inv_freq`` ``[D/2]``: a family's own frequencies a pair (scaled rotary:
-    ``mistral4.yarn_inv_freq``) in place of ``theta ** (-2i / D)``."""
-    d = x.shape[-1]
-    freqs = (theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
-    if positions.ndim == 1:
-        positions = positions[None]
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,D/2]
-    cos = jnp.cos(angles)[:, :, None, :]  # [B,S,1,D/2]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    y1 = x1 * cos - x2 * sin
-    y2 = x1 * sin + x2 * cos
-    out = jnp.stack([y1, y2], axis=-1).reshape(x.shape)
-    return out.astype(x.dtype)
-
-
 def _attention(q, k, v, cfg: LlamaConfig, mesh):
     if cfg.attention == "flash":
         from ..ops.attention import flash_attention
@@ -168,7 +145,7 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh):
     from ..parallel.sharding import with_logical_constraint as wlc
 
     groups = cfg.n_head // cfg.n_kv_head
-    y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
+    y = rmsnorm(x, layer["rms1"], cfg.rms_eps)
     q = jnp.einsum("bse,ehd->bshd", y, layer["wq"])
     k = jnp.einsum("bse,ekd->bskd", y, layer["wk"])
     v = jnp.einsum("bse,ekd->bskd", y, layer["wv"])
@@ -182,7 +159,7 @@ def _block(x, layer, positions, cfg: LlamaConfig, mesh):
     v = wlc(v, P("batch", "seq", "heads", "kv"), mesh)
     o = _attention(q, k, v, cfg, mesh)
     x = x + jnp.einsum("bshd,hde->bse", o, layer["wo"]).astype(x.dtype)
-    y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
+    y = rmsnorm(x, layer["rms2"], cfg.rms_eps)
     gate = jax.nn.silu(jnp.einsum("bse,ef->bsf", y, layer["w_gate"]))
     up = jnp.einsum("bse,ef->bsf", y, layer["w_up"])
     h = wlc(gate * up, P("batch", "seq", "mlp"), mesh)
@@ -209,7 +186,7 @@ def llama_apply(params, tokens, cfg: LlamaConfig, mesh=None):
         return block(x, layer), None
 
     x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
+    x = rmsnorm(x, params["rms_f"], cfg.rms_eps)
     logits = jnp.einsum("bse,ve->bsv", x, params["lm_head"])
     return wlc(logits, P("batch", "seq", "vocab"), mesh)
 

@@ -24,7 +24,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .llama import LlamaConfig, _rmsnorm, rope
+from .layers import rmsnorm, rope
+from .llama import LlamaConfig
 
 
 def llama_init_cache(cfg: LlamaConfig, batch: int, max_len: int):
@@ -48,7 +49,7 @@ def llama_prefill(
 
     def body(x, layer):
         with jax.named_scope("llama.attn"):
-            y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
+            y = rmsnorm(x, layer["rms1"], cfg.rms_eps)
             q = jnp.einsum("bse,ehd->bshd", y, layer["wq"])
             k = jnp.einsum("bse,ekd->bskd", y, layer["wk"])
             v = jnp.einsum("bse,ekd->bskd", y, layer["wv"])
@@ -64,7 +65,7 @@ def llama_prefill(
             x = x + jnp.einsum(
                 "bshd,hde->bse", o, layer["wo"]).astype(x.dtype)
         with jax.named_scope("llama.mlp"):
-            y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
+            y = rmsnorm(x, layer["rms2"], cfg.rms_eps)
             gate = jax.nn.silu(jnp.einsum("bse,ef->bsf", y, layer["w_gate"]))
             up = jnp.einsum("bse,ef->bsf", y, layer["w_up"])
             x = x + jnp.einsum(
@@ -84,7 +85,7 @@ def llama_prefill(
                 cache["v"], vs, (0, 0, 0, 0, 0)),
         }
     with jax.named_scope("llama.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps)
         last = jnp.take_along_axis(
             x, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
         )[:, 0]
@@ -110,7 +111,7 @@ def llama_decode_step(
         # A layer's slices are read by both parts; attention's come first.
         with jax.named_scope("llama.attn"):
             layer = jax.tree.map(lambda a: a[l], params["blocks"])
-            y = _rmsnorm(x, layer["rms1"], cfg.rms_eps)
+            y = rmsnorm(x, layer["rms1"], cfg.rms_eps)
             q = jnp.einsum("be,ehd->bhd", y, layer["wq"])
             k = jnp.einsum("be,ekd->bkd", y, layer["wk"])
             v = jnp.einsum("be,ekd->bkd", y, layer["wv"])
@@ -130,7 +131,7 @@ def llama_decode_step(
                 "bhd,hde->be", o.astype(y.dtype), layer["wo"]
             ).astype(x.dtype)
         with jax.named_scope("llama.mlp"):
-            y = _rmsnorm(x, layer["rms2"], cfg.rms_eps)
+            y = rmsnorm(x, layer["rms2"], cfg.rms_eps)
             gate = jax.nn.silu(jnp.einsum("be,ef->bf", y, layer["w_gate"]))
             up = jnp.einsum("be,ef->bf", y, layer["w_up"])
             x = x + jnp.einsum(
@@ -141,6 +142,6 @@ def llama_decode_step(
         ck = write_token_to_cache(ck, jnp.stack(new_ks), pos, axis=3)
         cv = write_token_to_cache(cv, jnp.stack(new_vs), pos, axis=3)
     with jax.named_scope("llama.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps)
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps)
         logits = jnp.einsum("be,ve->bv", x, params["lm_head"])
         return logits.astype(jnp.float32), {"k": ck, "v": cv}

@@ -40,7 +40,7 @@ token lives).  What absent experts would add is left out.
 ``u1 = RMSNorm(a1)``; ``out = a1 + FFN1(u1) + m``: the expert layer's output
 skips the second half, so a layer is not a chain of blocks.
 ``FFN(u) = (silu(u Wg) * (u Wu)) Wd``.  Then a final RMSNorm and an untied
-head.  Rope rotates interleaved pairs as ``llama.rope`` does (a fixed
+head.  Rope rotates interleaved pairs as ``layers.rope`` does (a fixed
 permutation of the published layout).
 
 Device operations carry ``jax.named_scope``s ``longcat.embed`` (the token
@@ -64,7 +64,8 @@ from jax.sharding import PartitionSpec as P
 
 from .expert_share import (EXPERT_CHUNK, LOOP_COUNT_NAMES,  # noqa: F401
                            held_choices, held_experts, loop_counts)
-from .llama import _rmsnorm, rope
+from .layers import add_counts, ffn, matmul, rmsnorm
+from .mla import mla_expanded, mla_project
 
 ATTENTION = ("wq_a", "rms_q", "wq_b", "wkv_a", "rms_kv", "wkv_b", "wo")
 COUNT_NAMES = ("routed_total", "routed_zero", "routed_held", "experts_touched",
@@ -194,70 +195,6 @@ def longcat_param_axes():
     }
 
 
-def matmul(spec, x, w):
-    """A matrix product that reads ``cfg.dtype`` operands and gives a
-    float32 result: what lies between two products (norm, rope, softmax,
-    silu, the residual sum) is done in float32 and rounded once, where the
-    next product reads it."""
-    return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
-
-
-def mla_project(y, att, positions, cfg, *, latent_scales: bool = True,
-                inv_freq=None, q_factor=None):
-    """y ``[B, S, d]`` -> roped queries ``[B, S, H, dn+dr]`` and the latent
-    ``[ckv | kr]`` ``[B, S, rkv+dr]`` that the cache holds.  ``cfg``: any
-    config with the latent attention's sizes (``LongcatConfig``,
-    ``Mistral4Config``).  The defaults are LongCat's conventions: ``aq`` and
-    ``akv`` on query and latent, rotary at ``theta ** (-2i / dr)``.  A family
-    says otherwise by arguments that are static or absent:
-    ``latent_scales=False`` (neither scale), ``inv_freq`` ``[dr/2]`` (its own
-    rotary frequencies), ``q_factor`` (float32, broadcast against ``[B, S, H,
-    dn+dr]``: what multiplies the whole query before it is rounded, a
-    softmax scale that depends on the query's position)."""
-    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    cq = _rmsnorm(matmul("bse,er->bsr", y, att["wq_a"]), att["rms_q"],
-                  cfg.rms_eps).astype(y.dtype)
-    q = matmul("bsr,rhd->bshd", cq, att["wq_b"])
-    if latent_scales:
-        q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
-    if q_factor is not None:
-        q = q * q_factor
-    q = jnp.concatenate(
-        [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta,
-                           inv_freq)], -1)
-    kv = matmul("bse,er->bsr", y, att["wkv_a"])
-    ckv = _rmsnorm(kv[..., :rkv], att["rms_kv"], cfg.rms_eps)
-    if latent_scales:
-        ckv = ckv * (cfg.d_model / rkv) ** 0.5
-    kr = rope(kv[..., None, rkv:], positions, cfg.rope_theta,
-              inv_freq)[..., 0, :]
-    return q.astype(y.dtype), jnp.concatenate([ckv, kr], -1).astype(y.dtype)
-
-
-def mla_expanded(q, latent, att, cfg: LongcatConfig):
-    """Causal attention of ``[B, S]`` tokens over themselves with per-head
-    keys and values expanded from the latent (prefill, training);
-    ``[B, S, d]`` float32."""
-    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    s = q.shape[1]
-    kv = matmul("bsc,chd->bshd", latent[..., :rkv], att["wkv_b"]).astype(
-        q.dtype)
-    scores = (matmul("bshd,bthd->bhst", q[..., :dn], kv[..., :dn])
-              + matmul("bshd,btd->bhst", q[..., dn:], latent[..., rkv:]))
-    scores = scores / (q.shape[-1] ** 0.5)
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(jnp.where(causal, scores, -1e30), axis=-1)
-    o = matmul("bhst,bthd->bshd", probs.astype(q.dtype), kv[..., dn:])
-    return matmul("bshd,hde->bse", o.astype(q.dtype), att["wo"])
-
-
-def ffn(u, w_gate, w_up, w_down):
-    """SwiGLU; ``u [..., d]`` in ``cfg.dtype`` -> ``[..., d]`` float32."""
-    gate = jax.nn.silu(matmul("...e,ef->...f", u, w_gate))
-    up = matmul("...e,ef->...f", u, w_up)
-    return matmul("...f,fe->...e", (gate * up).astype(u.dtype), w_down)
-
-
 def route(u, router, bias, cfg: LongcatConfig):
     """u ``[N, d]`` float32 -> the ``k`` experts each token chose ``[N, k]`` and
     their combine weights ``s * p`` (float32, not renormalised)."""
@@ -312,7 +249,7 @@ def double_layer(h, params, layer: int, live, attend, cfg: LongcatConfig):
     blocks, dt = params["blocks"], jnp.dtype(cfg.dtype)
 
     def norm(v, name, j):  # the stream is float32; matrices read cfg.dtype
-        return _rmsnorm(v, blocks[name][layer, j], cfg.rms_eps).astype(dt)
+        return rmsnorm(v, blocks[name][layer, j], cfg.rms_eps).astype(dt)
 
     def dense(u, j):
         return ffn(u, blocks["w_gate"][layer, j], blocks["w_up"][layer, j],
@@ -325,7 +262,7 @@ def double_layer(h, params, layer: int, live, attend, cfg: LongcatConfig):
     with jax.named_scope("longcat.mla"):
         a0 = h + attention(0, norm(h, "rms_attn", 0))
     with jax.named_scope("longcat.moe"):  # u0 feeds the dense half too
-        u0 = _rmsnorm(a0, blocks["rms_ffn"][layer, 0], cfg.rms_eps)  # float32
+        u0 = rmsnorm(a0, blocks["rms_ffn"][layer, 0], cfg.rms_eps)  # float32
         flat, live = u0.reshape(-1, u0.shape[-1]), live.reshape(-1)
         router, bias = blocks["router"][layer], blocks["router_bias"][layer]
     m, counts = moe(flat, live, router, bias, params["experts"], layer, cfg)
@@ -336,10 +273,6 @@ def double_layer(h, params, layer: int, live, attend, cfg: LongcatConfig):
     with jax.named_scope("longcat.ffn"):  # and the expert layer's sum
         u1 = norm(a1, "rms_ffn", 1)
         return a1 + dense(u1, 1) + m.reshape(h.shape), counts
-
-
-def add_counts(total, counts):
-    return counts if total is None else jax.tree.map(jnp.add, total, counts)
 
 
 def longcat_forward(params, tokens, live, cfg: LongcatConfig):
@@ -360,7 +293,7 @@ def longcat_forward(params, tokens, live, cfg: LongcatConfig):
         with jax.named_scope("longcat.moe"):
             total = add_counts(total, counts)
     with jax.named_scope("longcat.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
     with jax.named_scope("longcat.mla"):
         latents = jnp.stack(latents)

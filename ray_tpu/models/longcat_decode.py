@@ -35,11 +35,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.decode_attention import (attend_live_blocks, extent_step,
-                                    write_token_to_cache)
-from .llama import _rmsnorm
-from .longcat import (LongcatConfig, add_counts, double_layer,
-                      longcat_forward, matmul, mla_project)
+from ..ops.decode_attention import write_token_to_cache
+from .layers import add_counts, matmul, rmsnorm
+from .longcat import LongcatConfig, double_layer, longcat_forward
+from .mla import mla_absorbed, mla_project
 
 
 def longcat_init_cache(cfg: LongcatConfig, batch: int, max_len: int):
@@ -70,58 +69,6 @@ def longcat_prefill(
     return (*out, counts) if with_counts else out
 
 
-def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg,
-                 layer: int = 0):
-    """One query token a row against its slot's latents.  q [B, H, dn+dr];
-    latent_self [B, C] (the current token's); latent_cache [A, B, T, C], the
-    STACKED cache, of which attention ``layer``'s slice holds [0, pos-1];
-    pos [B] -> [B, d] float32.  A cache of several extents is read in blocks
-    up to the batch's longest context (``ops/decode_attention``'s
-    ``attend_live_blocks``), each block taken from the stack itself.
-    ``att`` holds ``Wkvb`` whole (``wkv_b [rkv, H, dn+dv]``, LongCat's: its
-    halves are sliced out here) or as two leaves (``wk_b [rkv, H, dn]``,
-    ``wv_b [rkv, H, dv]``, mistral4's: each product reads its own stack
-    where it lies).  The softmax scale is ``(dn+dr)^-0.5``; what else
-    multiplies the scores is in ``q`` already (``mla_project``'s
-    ``q_factor``)."""
-    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    if "wkv_b" in att:
-        w_k, w_v = att["wkv_b"][..., :dn], att["wkv_b"][..., dn:]
-    else:
-        w_k, w_v = att["wk_b"], att["wv_b"]
-    qt = matmul("bhn,chn->bhc", q[..., :dn], w_k).astype(q.dtype)
-    qc = jnp.concatenate([qt, q[..., dn:]], -1)  # [B, H, C]
-    scale = q.shape[-1] ** -0.5
-    _, b, t, c = latent_cache.shape
-    step = extent_step(t)
-    s_self = matmul("bhc,bc->bh", qc, latent_self) * scale
-    if step < t:
-        def block(start):
-            latents = jax.lax.dynamic_slice(
-                latent_cache, (layer, 0, start, 0), (1, b, step, c))[0]
-            scores = matmul("bhc,btc->bht", qc, latents) * scale
-            before = jnp.arange(step)[None, None] < (
-                pos - start)[:, None, None]
-            return jnp.where(before, scores, -1e30), lambda p: matmul(
-                "bht,btc->bhc", p.astype(q.dtype), latents[..., :rkv])
-
-        oc = attend_live_blocks(
-            block, jnp.max(pos), t, qc.shape[:2] + (rkv,),
-            [(s_self, latent_self[:, None, :rkv].astype(jnp.float32))])
-    else:
-        latents = latent_cache[layer]
-        scores = matmul("bhc,btc->bht", qc, latents) * scale
-        before = jnp.arange(t)[None, None] < pos[:, None, None]
-        scores = jnp.where(before, scores, -1e30)
-        probs = jax.nn.softmax(
-            jnp.concatenate([scores, s_self[..., None]], -1), axis=-1)
-        oc = (matmul("bht,btc->bhc", probs[..., :-1].astype(q.dtype),
-                     latents[..., :rkv])
-              + probs[..., -1:] * latent_self[:, None, :rkv])
-    o = matmul("bhc,chv->bhv", oc.astype(q.dtype), w_v)
-    return matmul("bhv,hve->be", o.astype(q.dtype), att["wo"])
-
-
 def longcat_decode_step(
     params, tokens, pos, cache, cfg: LongcatConfig, *,
     with_counts: bool = False
@@ -148,7 +95,7 @@ def longcat_decode_step(
         latent_cache = write_token_to_cache(
             latent_cache, jnp.stack(new), pos, axis=2)
     with jax.named_scope("longcat.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, {"latent": latent_cache})

@@ -17,7 +17,7 @@ x))`` (eps 1e-5); ``attn_pattern[i]`` is ``F`` (full) or ``W`` (window),
 taken from the FRONT of both.  Then a final RMSNorm and an untied head.  The
 residual stream is float32; the matrix products read ``cfg.dtype`` and
 accumulate in float32, and what lies between two products is float32,
-rounded once where the next product reads it (``longcat.matmul``).
+rounded once where the next product reads it (``layers.matmul``).
 
 **Attention(u, pos)**, both kinds: ``q = u Wq`` ``[H, D]``, ``k = u Wk``
 ``[Hkv, D]``, ``v = a u Wv`` ``[Hkv, Dv]`` (``a`` = ``value_scale`` 0.707);
@@ -69,12 +69,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.decode_attention import NEG_INF, ring_held
+from ..ops.decode_attention import NEG_INF
 from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
                            held_experts_dense, loop_counts,
                            runs_every_held_expert, sigmoid_route)
-from .llama import _rmsnorm
-from .longcat import add_counts, ffn, matmul
+from .layers import add_counts, ffn, matmul, ring_of, rmsnorm, rope_half
 
 # hybrid_layer_pattern (0 = full) and moe_layer_freq (0 = dense), as letters
 PUBLISHED_ATTN = "F" + "WWWWF" + "WWWWWF" * 7
@@ -248,27 +247,6 @@ def mimo_v2_param_axes():
 
 
 # ---------------------------------------------------------------- attention
-def rope_half(x, positions, theta: float, rotary_dim: int, inv_freq=None,
-              factor=None):
-    """Rotate the first ``rotary_dim`` dimensions of every head in the
-    ``rotate_half`` pairing (dimension ``j`` with ``j + rotary_dim / 2``).  x
-    ``[..., heads, D]`` float32, positions of x's leading shape (or one that
-    broadcasts to it) -> float32.  ``inv_freq`` ``[rotary_dim / 2]``: a
-    family's own frequencies a pair (scaled rotary) in place of ``theta ** (-2j
-    / rotary_dim)``; ``factor``: what multiplies cos and sin (YaRN's
-    attention factor).  Both absent, the program is what it was."""
-    half = rotary_dim // 2
-    freqs = (theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-             if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
-    angles = positions[..., None, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(angles), jnp.sin(angles)
-    if factor is not None:
-        cos, sin = cos * factor, sin * factor
-    x1, x2 = x[..., :half], x[..., half:rotary_dim]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary_dim:]], -1)
-
-
 def attention_project(y, att, i: int, positions, kind: str,
                       cfg: MimoV2Config):
     """y ``[..., d]`` in ``cfg.dtype`` at ``positions`` -> roped q ``[..., H,
@@ -340,19 +318,6 @@ def window_attention(q, k, v, sink, window: int):
     return o.reshape(bsz, nc * window, h, v.shape[-1])[:, :s]
 
 
-def ring_of(a, lengths, window: int):
-    """What a ring of ``window`` slots holds of a sequence's keys (or values)
-    once its first ``lengths[b]`` positions are in: slot ``r`` the newest
-    position ``p < length`` with ``p = r mod window``, zeros where there is
-    none yet.  a ``[B, S, Hkv, X]``, lengths ``[B]`` -> ``[B, Hkv, window,
-    X]``, whatever ``S`` is padded to."""
-    held = ring_held(lengths[:, None] - 1, window)
-    taken = jnp.take_along_axis(
-        a, jnp.clip(held, 0, a.shape[1] - 1)[:, :, None, None], axis=1)
-    return jnp.where((held >= 0)[:, :, None, None], taken, 0).transpose(
-        0, 2, 1, 3)
-
-
 # ------------------------------------------------------------------ experts
 def moe(u, live, params, i: int, cfg: MimoV2Config):
     """Expert layer ``i``'s share on this chip.  ``u [N, d]`` normed tokens
@@ -414,18 +379,18 @@ def run_layers(params, x, live, attend, cfg: MimoV2Config):
         seen[mlp_kind] += 1
         with jax.named_scope("mimo.attn_" + STACK[attn_kind]):
             att = blocks[STACK[attn_kind]]
-            y = _rmsnorm(x, att["rms"][i], cfg.rms_eps).astype(dt)
+            y = rmsnorm(x, att["rms"][i], cfg.rms_eps).astype(dt)
             o = attend(attn_kind, i, y).astype(dt)
             x = x + matmul("...hv,hve->...e", o, att["wo"][i])
         if mlp_kind == "D":
             with jax.named_scope("mimo.mlp"):
                 dense = blocks["dense"]
-                u = _rmsnorm(x, dense["rms"][j], cfg.rms_eps).astype(dt)
+                u = rmsnorm(x, dense["rms"][j], cfg.rms_eps).astype(dt)
                 x = x + ffn(u, dense["w_gate"][j], dense["w_up"][j],
                             dense["w_down"][j])
         else:
             with jax.named_scope("mimo.moe"):
-                u = _rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # f32
+                u = rmsnorm(x, blocks["moe"]["rms"][j], cfg.rms_eps)  # f32
                 u, rows = u.reshape(-1, u.shape[-1]), live.reshape(-1)
             y, counts = moe(u, rows, params, j, cfg)
             with jax.named_scope("mimo.moe"):
@@ -460,7 +425,7 @@ def mimo_v2_forward(params, tokens, lengths, cfg: MimoV2Config):
 
     x, counts = run_layers(params, x, live, attend, cfg)
     with jax.named_scope("mimo.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
     stacked = {}
     for name, v in kept.items():

@@ -15,7 +15,7 @@ while its full keys and values beyond the rung keep what the last tenant
 left (decode reads nothing at or beyond ``pos``).
 
 Prefill leaves in a ring the LAST ``w`` TRUE positions of the prompt
-(``mimo_v2.ring_of``: a gather by ``lengths``, not the rung's tail), zeros
+(``layers.ring_of``: a gather by ``lengths``, not the rung's tail), zeros
 in the slots no position has reached.  Decode never trusts a slot's
 content: slot ``r`` is attended iff the position it must hold by now, the
 newest ``p < pos`` with ``p = r mod w``, is ``>= 0`` and inside the window
@@ -40,8 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import decode_attention, write_token_to_cache
-from .llama import _rmsnorm
-from .longcat import matmul
+from .layers import matmul, rmsnorm
 from .mimo_v2 import (STACK, MimoV2Config, attention_project, leaf_scope,
                       mimo_v2_forward, run_layers)
 
@@ -117,7 +116,7 @@ def mimo_v2_decode_step(
                     cache[leaf] = write_token_to_cache(
                         cache[leaf], jnp.stack(new[leaf]), at, axis=3)
     with jax.named_scope("mimo.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)

@@ -12,7 +12,7 @@ published sizes: ``d`` 4096; lightning: ``H`` 32 heads = 32 key-value heads of
 ``D`` 128; sparse: ``Hq`` 32 query / ``Hkv`` 2 key-value heads of 128 (a group
 is 16 query heads); ``F`` 16384 the MLP's width; ``N`` 32 the PUBLISHED depth.
 Everything between two matrix products is float32; the products read
-``cfg.dtype`` and accumulate in float32 (``longcat.matmul``); the residual
+``cfg.dtype`` and accumulate in float32 (``layers.matmul``); the residual
 stream is float32, as in ``nemotron_h``.
 
 **Model**: ``x_0 = scale_emb E[token]`` (12).  Layer ``l`` (its PUBLISHED
@@ -26,14 +26,14 @@ lightning, ``S`` sparse; ``n_layer`` layers are taken from the FRONT of
 **Lightning(u)**: ``q, k, v = u Wq, u Wk, u Wv`` as ``[H, D]``; ``q, k =
 RMSNorm_D(q) w_q, RMSNorm_D(k) w_k`` (``qk_norm``: over a head's channels,
 one learned ``[D]`` a layer); rotary on all ``D`` channels of ``q`` and ``k``,
-base 1e4 (``llama.rope``); a head: ``S_t = lambda_h S_{t-1} + v_t (x) k_t``
+base 1e4 (``layers.rope``); a head: ``S_t = lambda_h S_{t-1} + v_t (x) k_t``
 from ``S = 0`` (``S [D, D]`` float32), ``o_t = S_t q_t / sqrt(D)``; ``o =
 RMSNorm_D(o) w_o`` (``use_output_norm``: a head's channels, learned ``[H,
 D]``); ``o = o sigmoid(u Wg)`` (``use_output_gate``); ``out = o Wo``.
 ``lambda_h = exp(-s_h)``, ``s_h = 2^(-8 h / H) (1 - l / (N - 1) + 1e-5)``, ``h``
 = 1..H (``slopes``).  That recurrence IS Mamba-2's with ``x = v``, ``dt = 1``,
 ``a = -s_h``, ``B = k``, ``C = q / sqrt(D)``, no skip and as many groups as
-heads: a sequence runs ``nemotron_h.ssd_chunked`` (``dt = 0`` beyond a row's
+heads: a sequence runs ``mamba2.ssd_chunked`` (``dt = 0`` beyond a row's
 true length: the state passes through), a decode step
 ``ops/mamba_update.py``; there is no second scan and no second update in the
 tree.
@@ -82,12 +82,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.decode_attention import NEG_INF
-from .granite_h import layer_plan
-from .laguna import scan_or_call
-from .llama import _rmsnorm, rope
-from .longcat import ffn, matmul
-from .mistral4 import blocked_attention
-from .nemotron_h import ssd_chunked
+from .layers import (blocked_attention, ffn, layer_plan, matmul, rmsnorm, rope,
+                     scan_or_call)
+from .mamba2 import ssd_chunked
 
 # a kind of mixer -> its stack under params["blocks"]
 STACK = {"S": "sparse", "L": "lightning"}
@@ -276,8 +273,8 @@ def project(y, w, i, cfg: MinicpmSalaConfig):
     pre-activation ``[..., H, D]``, float32."""
     q, k, v, g = (matmul("...e,ehd->...hd", y, w[name][i])
                   for name in ("wq", "wk", "wv", "wg"))
-    return (_rmsnorm(q, w["q_norm"][i], cfg.rms_eps),
-            _rmsnorm(k, w["k_norm"][i], cfg.rms_eps), v, g)
+    return (rmsnorm(q, w["q_norm"][i], cfg.rms_eps),
+            rmsnorm(k, w["k_norm"][i], cfg.rms_eps), v, g)
 
 
 def gated_output(o, g, w, i, dtype):
@@ -300,7 +297,7 @@ def lightning_project(y, w, i, positions, cfg: MinicpmSalaConfig):
 def lightning_output(o, g, w, i, cfg: MinicpmSalaConfig):
     """The read-out ``o [..., H, D]`` float32 through the output norm, the
     gate and ``Wo`` -> ``[..., d]`` float32."""
-    o = _rmsnorm(o, w["o_norm"][i], cfg.rms_eps)
+    o = rmsnorm(o, w["o_norm"][i], cfg.rms_eps)
     return gated_output(o, g, w, i, jnp.dtype(cfg.dtype))
 
 
@@ -436,7 +433,7 @@ def head(params, x, cfg: MinicpmSalaConfig):
     """The stream ``[..., d]`` float32 -> logits ``[..., V]`` float32: final
     norm, the muP divisor (in float32, before it is rounded), the vocabulary
     product."""
-    x = _rmsnorm(x, params["rms_f"], cfg.rms_eps) / cfg.logit_divisor
+    x = rmsnorm(x, params["rms_f"], cfg.rms_eps) / cfg.logit_divisor
     return matmul("...e,ve->...v", x.astype(jnp.dtype(cfg.dtype)),
                   params["lm_head"])
 
@@ -450,11 +447,11 @@ def block(params, x, kind: str, i, layer, mix, cfg: MinicpmSalaConfig):
     blocks, dt = params["blocks"], jnp.dtype(cfg.dtype)
     r = cfg.residual_scale
     with jax.named_scope(SCOPE[kind]):
-        y = _rmsnorm(x, blocks[STACK[kind]]["rms"][i], cfg.rms_eps)
+        y = rmsnorm(x, blocks[STACK[kind]]["rms"][i], cfg.rms_eps)
         x = x + r * mix(y.astype(dt))
     with jax.named_scope("sala.mlp"):
         w = blocks["mlp"]
-        y = _rmsnorm(x, w["rms"][layer], cfg.rms_eps).astype(dt)
+        y = rmsnorm(x, w["rms"][layer], cfg.rms_eps).astype(dt)
         return x + r * ffn(
             y, w["w_gate"][layer], w["w_up"][layer], w["w_down"][layer])
 
@@ -466,8 +463,8 @@ def minicpm_sala_forward(params, tokens, lengths, cfg: MinicpmSalaConfig):
     ``[Nl, B, H, D, D]`` at each row's TRUE length, counts).  Rows at or
     beyond the longest prompt's last query block carry no attention
     (``blocked_attention``).  A run of layers of one kind is ONE loop's body
-    and a group of runs that repeats a loop of those (``granite_h.layer_plan``,
-    ``laguna.scan_or_call``): the published cut ``S L6 S2 L3`` is four
+    and a group of runs that repeats a loop of those (``layers.layer_plan``,
+    ``layers.scan_or_call``): the published cut ``S L6 S2 L3`` is four
     bodies."""
     blocks = params["blocks"]
     x = embed(params, tokens, cfg)

@@ -15,7 +15,7 @@ MoE(RMSNorm(x))`` (eps 1e-6; no leading dense layer), a final RMSNorm, an
 untied head.  The residual stream is float32; matrix products read
 ``cfg.dtype`` and accumulate in float32, and what lies between two products
 is float32, rounded once where the next product reads it
-(``longcat.matmul``).
+(``layers.matmul``).
 
 **MLA(x, pos)**: ``cq = RMSNorm(x Wqa)``; ``q = cq Wqb`` as ``[T, H, dn+dr]``,
 split ``qn | qr``; ``x Wkva`` split ``[T, rkv] | [T, dr]`` -> ``ckv =
@@ -25,15 +25,15 @@ H, dv]`` = ``v`` (the two halves of the published ``kv_b_proj``, kept as two
 stacks so that each product reads its own where it lies); ``score_h(i, j) =
 a(i) (qn_h(i) . kn_h(j) + qr_h(i) . kr(j)) (dn+dr)^-0.5 m^2``, causal softmax
 in float32, ``o_h = P v_h``, output ``concat_h(o_h) Wo``.  LongCat's latent
-attention without its ``aq`` / ``akv`` scales (``longcat.mla_project``, which
+attention without its ``aq`` / ``akv`` scales (``mla.mla_project``, which
 this module calls with its own frequencies and query factor).  **The cache
 holds ``[ckv | kr]``**: ``rkv + dr`` = 320 values a token a layer, after norm
-and rope.  Decode absorbs (``longcat_decode.mla_absorbed``): ``qt_h = qn_h
+and rope.  Decode absorbs (``mla.mla_absorbed``): ``qt_h = qn_h
 Wkb_h^T`` in ``R^rkv``, ``score = (qt_h . ckv + qr_h . kr) (dn+dr)^-0.5``,
 ``o_h = (P ckv) Wvb_h``; ``a(pos) m^2`` is folded into the query before it
 is rounded, in prefill and decode alike.
 
-**RoPE_yarn** (``yarn_inv_freq``; interleaved pairs, ``llama.rope``'s layout,
+**RoPE_yarn** (``yarn_inv_freq``; interleaved pairs, ``layers.rope``'s layout,
 which is the published ``rope_interleave``): over the ``dr/2`` pairs ``i``,
 ``f_i = theta^(-2i/dr)``; ``c(b) = dr ln(L0 / (2 pi b)) / (2 ln theta)`` with
 ``L0 = rope_original_max``; ``low = floor(c(beta_fast))``, ``high =
@@ -88,27 +88,18 @@ import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..ops.decode_attention import NEG_INF, attend_blocks
 from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
                            held_experts_dense, loop_counts,
                            runs_every_held_expert, softmax_route)
-from .llama import _rmsnorm
-from .longcat import add_counts, ffn, matmul, mla_project
+from .layers import (add_counts, blocked_attention, ffn, matmul, rmsnorm,
+                     yarn_inv_freq)
+from .mla import mla_project
 
 ATTENTION = ("wq_a", "rms_q", "wq_b", "wkv_a", "rms_kv", "wk_b", "wv_b", "wo")
 COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
                *LOOP_COUNT_NAMES)
-# A prefill's scores exist a tile of this many queries by this many keys at a
-# time: 32 heads x 512 x 512 float32 = 32 MB at the published sizes, which the
-# v5e's compiler keeps in its fast memory from the scores' product to the
-# values' (16,384 positions, one layer, my chip runs, PR 48: 23.2 ms at 512 x
-# 512, 22.0 at 1024 x 512, 86.2 at 1024 x 1024, whose tile goes through the
-# main memory; 34.2 at 256 x 256).  512 is also the step a decode reads by.
-QUERY_BLOCK = 512
-KEY_BLOCK = 512
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,36 +166,6 @@ class Mistral4Config:
 
 
 # ------------------------------------------------------------------- rotary
-def yarn_correction_range(dim: int, theta: float, original_max: int,
-                          beta_fast: float, beta_slow: float):
-    """``(low, high)`` of the ``dim / 2`` rotary pairs: those below ``low``
-    keep their frequency, those from ``high`` on are slowed by the factor, a
-    linear ramp between (floor / ceil of the pair that turns ``beta_fast`` /
-    ``beta_slow`` times over the ``original_max`` trained positions, clipped
-    to the pairs there are).  The numbers, not a config: Laguna's full
-    layers read the same table off other fields."""
-
-    def pair_turning(turns):
-        return dim * math.log(original_max / (turns * 2 * math.pi)) / (
-            2 * math.log(theta))
-
-    low = max(math.floor(pair_turning(beta_fast)), 0)
-    high = min(math.ceil(pair_turning(beta_slow)), dim - 1)
-    return low, high
-
-
-def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
-                  beta_fast: float, beta_slow: float) -> np.ndarray:
-    """The ``dim / 2`` YaRN-scaled rotary frequencies, float32 (a constant
-    of the program)."""
-    half = dim // 2
-    f = theta ** (-np.arange(half, dtype=np.float64) / half)
-    low, high = yarn_correction_range(dim, theta, original_max, beta_fast,
-                                      beta_slow)
-    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
-    return ((1 - ramp) * f + ramp * f / factor).astype(np.float32)
-
-
 def yarn_numbers(cfg: Mistral4Config) -> tuple:
     """``yarn_inv_freq``'s arguments as this family's config names them."""
     return (cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
@@ -309,104 +270,6 @@ def mistral4_param_axes():
 
 
 # ---------------------------------------------------------------- attention
-def blocked_attention(q, k, v, longest=None, query_block: int = QUERY_BLOCK,
-                      key_block: int = KEY_BLOCK, window=None, select=None,
-                      select_block=None):
-    """Causal attention of ``[B, S]`` tokens over themselves, a tile of
-    ``query_block`` queries by ``key_block`` keys at a time.  q ``[B, S, H,
-    D]``, k ``[B, S, Hkv, D]`` (the softmax scale ``D^-0.5``; what else scales
-    a score is in ``q``), v ``[B, S, Hkv, Dv]`` -> ``[B, S, H, Dv]`` float32.
-    Query block ``c`` sees the key blocks up to its own last row and no
-    further (an online softmax over them: ``attend_blocks``); ``longest``
-    (traced; the longest prompt of the batch) bounds the query blocks, and
-    the rows of those wholly beyond it come out zero: nothing reads them.  A
-    sequence that the blocks do not divide is padded with keys no query
-    sees.
-
-    Where ``Hkv`` divides ``H`` (grouped queries: query head ``h`` reads
-    key-value head ``h // (H / Hkv)``) a tile's products carry the group as
-    an axis of the queries, and the keys and values are never repeated.
-    ``window`` (static): query ``i`` sees key ``j`` iff ``0 <= i - j <
-    window``: the BAND, whose key blocks have a lower bound too, so a query
-    block of ``window`` rows meets two key blocks whatever ``S`` is.
-
-    ``select`` (with ``select_block``, static, which the padded length is
-    then made a multiple of): a query reads a key only where ``select``
-    lets it, besides causality.  ``select(qb, rows)`` is called once a
-    query tile with the tile's queries as the products read them (``[B,
-    query_block, H, D]``, grouped ``[B, query_block, Hkv, G, D]``) and
-    their positions ``[query_block]``, and returns ``[B, Hkv or H,
-    query_block, n]`` bool: entry ``j`` stands for keys ``[j select_block,
-    (j + 1) select_block)``, and entries it does not give are not read.  The
-    mask exists a tile of queries at a time (MiniCPM-SALA's block
-    selection).  With equal head counts, no window and no selection the
-    program is what it was."""
-    bsz, s, h, d = q.shape
-    hkv, dv = k.shape[2], v.shape[-1]
-    fit = -(-s // select_block) * select_block if select_block else s
-    query_block, key_block = min(query_block, fit), min(key_block, fit)
-    whole = math.lcm(query_block, key_block, select_block or 1)
-    padded = -(-s // whole) * whole
-    if padded > s:
-        q, k, v = (jnp.pad(a, ((0, 0), (0, padded - s), (0, 0), (0, 0)))
-                   for a in (q, k, v))
-    scale = d ** -0.5
-    # a tile's products and its result's shape, heads equal or grouped
-    if hkv == h:
-        to_scores, to_values = "bqhd,bkhd->bhqk", "bhqk,bkhv->bhqv"
-        tile = (bsz, h, query_block, dv)
-    else:
-        to_scores, to_values = "bqkgd,btkd->bkgqt", "bkgqt,btkv->bkgqv"
-        tile = (bsz, hkv, h // hkv, query_block, dv)
-
-    def query_rows(c, out):
-        first = c * query_block
-        qb = jax.lax.dynamic_slice_in_dim(q, first, query_block, axis=1)
-        if hkv != h:
-            qb = qb.reshape(bsz, query_block, hkv, h // hkv, d)
-        rows = first + jnp.arange(query_block)
-        if select is not None:
-            chosen = select(qb, rows)[..., :padded // select_block]
-            chosen = jnp.pad(chosen, ((0, 0),) * 3 + (
-                (0, padded // select_block - chosen.shape[-1]),))
-            if hkv != h:  # a group's query heads read what their head does
-                chosen = chosen[:, :, None]
-
-        def keys(start):
-            kb = jax.lax.dynamic_slice_in_dim(k, start, key_block, axis=1)
-            vb = jax.lax.dynamic_slice_in_dim(v, start, key_block, axis=1)
-            scores = matmul(to_scores, qb, kb) * scale
-            seen = rows[:, None] >= start + jnp.arange(key_block)[None]
-            if window is not None:
-                seen &= (rows[:, None] - start
-                         - jnp.arange(key_block)[None]) < window
-            if select is not None:
-                seen = seen & jnp.repeat(jax.lax.dynamic_slice_in_dim(
-                    chosen, start // select_block, key_block // select_block,
-                    axis=-1), select_block, axis=-1)
-            return jnp.where(seen, scores, NEG_INF), lambda p: matmul(
-                to_values, p.astype(q.dtype), vb)
-
-        last = (first + query_block + key_block - 1) // key_block
-        if window is None:
-            o = attend_blocks(keys, last, key_block, tile)
-        else:  # the first key block that a row of this query block sees
-            low = jnp.maximum(first - window + 1, 0) // key_block
-            o = attend_blocks(lambda start: keys(low * key_block + start),
-                              last - low, key_block, tile)
-        o = (o.transpose(0, 2, 1, 3) if hkv == h else
-             o.transpose(0, 3, 1, 2, 4).reshape(bsz, query_block, h, dv))
-        return jax.lax.dynamic_update_slice_in_dim(out, o, first, axis=1)
-
-    blocks = padded // query_block
-    if longest is not None:
-        blocks = jnp.minimum((longest + query_block - 1) // query_block,
-                             blocks)
-    out = jax.lax.fori_loop(
-        0, blocks, query_rows, jnp.zeros((bsz, padded, h, dv), jnp.float32))
-    return out[:, :s]
-
-
 def mla_blocked(q, latent, att, cfg: Mistral4Config, longest=None, **blocks):
     """Latent attention of ``[B, S]`` tokens over themselves with per-head
     keys and values expanded from the latent once (prefill, training) and
@@ -478,11 +341,11 @@ def layer(params, x, live, i, attend, cfg: Mistral4Config):
     in a forward over a sequence (``mistral4_forward``)."""
     blocks, dt = params["blocks"], jnp.dtype(cfg.dtype)
     with jax.named_scope("mistral4.mla"):
-        y = _rmsnorm(x, blocks["rms_attn"][i], cfg.rms_eps).astype(dt)
+        y = rmsnorm(x, blocks["rms_attn"][i], cfg.rms_eps).astype(dt)
         o, latent = attend({k: blocks[k][i] for k in ATTENTION}, y)
         x = x + o
     with jax.named_scope("mistral4.moe"):
-        u = _rmsnorm(x, blocks["rms_ffn"][i], cfg.rms_eps)  # float32
+        u = rmsnorm(x, blocks["rms_ffn"][i], cfg.rms_eps)  # float32
         u, live = u.reshape(-1, u.shape[-1]), live.reshape(-1)
     y, counts = moe(u, live, params, i, cfg)
     with jax.named_scope("mistral4.shared"):  # the sum's last term
@@ -518,7 +381,7 @@ def mistral4_forward(params, tokens, lengths, cfg: Mistral4Config):
     (x, counts), latents = jax.lax.scan(
         one_layer, (x, zero), jnp.arange(cfg.n_layer))
     with jax.named_scope("mistral4.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
     return x, latents, counts
 

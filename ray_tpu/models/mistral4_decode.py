@@ -11,10 +11,10 @@ cell), and nothing here depends on it but the cache's shape.
 Prefill at a rung ``S`` >= the prompt's length ``n`` writes the latents of
 ``[0, S)`` (positions ``>= n`` hold what padding gives and are never read:
 decode masks by ``pos``), expands the latent to per-head keys and values
-once a layer and scores them in blocks (``mistral4.blocked_attention``: no
+once a layer and scores them in blocks (``layers.blocked_attention``: no
 array grows with ``S^2``, nothing above the diagonal or beyond ``n``'s block
 is computed), and takes the logits at ``n - 1``.  Decode runs the absorbed
-form, LongCat's (``longcat_decode.mla_absorbed``): the current token's latent
+form, LongCat's (``mla.mla_absorbed``): the current token's latent
 rides beside the cache and is merged as a last score, a layer reads its
 slice of the cache in blocks of 512 positions up to the batch's longest
 context, each block taken out of the stack once for both products, and all
@@ -39,10 +39,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import write_token_to_cache
-from .llama import _rmsnorm
-from .longcat import add_counts, matmul
-from .longcat_decode import mla_absorbed
+from .layers import add_counts, matmul, rmsnorm
 from .mistral4 import Mistral4Config, layer, mistral4_forward, project
+from .mla import mla_absorbed
 
 
 def mistral4_init_cache(cfg: Mistral4Config, batch: int, max_len: int):
@@ -96,7 +95,7 @@ def mistral4_decode_step(
         latent_cache = write_token_to_cache(
             latent_cache, jnp.stack(new), pos, axis=2)
     with jax.named_scope("mistral4.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, {"latent": latent_cache})

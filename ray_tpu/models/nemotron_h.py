@@ -18,7 +18,7 @@ Then a final RMSNorm and an untied head.  The residual stream is kept in
 float32 (the config's ``residual_in_fp32`` is false: bf16 there); the
 matrix products read ``cfg.dtype`` and accumulate in float32, and what lies
 between two products is float32, rounded once where the next product reads
-it (``longcat.matmul``).
+it (``layers.matmul``).
 
 **Mamba-2(u)**: ``z = u Wz``, ``xBC = u Wxbc``, ``dt = u Wdt`` (the published
 ``in_proj``'s columns, widths ``HP | HP + 2GN | H``); ``xBC = silu(conv_K(xBC)
@@ -78,8 +78,8 @@ from jax.sharding import PartitionSpec as P
 from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
                            loop_counts, runs_every_held_expert,
                            sigmoid_route)
-from .llama import _rmsnorm
-from .longcat import add_counts, matmul
+from .layers import add_counts, matmul, rmsnorm
+from .mamba2 import mamba_sequence
 
 PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
                      "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
@@ -267,117 +267,6 @@ def nemotron_h_param_axes():
     }
 
 
-# ------------------------------------------------------------------ Mamba-2
-def mamba_project(y, m, i: int, cfg: NemotronHConfig):
-    """y ``[..., d]`` in ``cfg.dtype`` -> ``z [..., HP]``, ``xBC [..., HP +
-    2GN]`` (before the convolution) and ``dt [..., H]`` (after the
-    softplus), float32."""
-    z = matmul("...e,ef->...f", y, m["w_z"][i])
-    xbc = matmul("...e,ef->...f", y, m["w_xbc"][i])
-    dt = jax.nn.softplus(matmul("...e,eh->...h", y, m["w_dt"][i])
-                         + m["dt_bias"][i])
-    return z, xbc, dt
-
-
-def split_xbc(xbc, cfg: NemotronHConfig):
-    """``[..., HP + 2GN]`` -> ``x [..., H, P]``, ``B``, ``C [..., G, N]``."""
-    gn = cfg.n_groups * cfg.ssm_state_size
-    x, b, c = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + gn], axis=-1)
-    lead = xbc.shape[:-1]
-    return (x.reshape(*lead, cfg.mamba_num_heads, cfg.mamba_head_dim),
-            b.reshape(*lead, cfg.n_groups, cfg.ssm_state_size),
-            c.reshape(*lead, cfg.n_groups, cfg.ssm_state_size))
-
-
-def mamba_output(y, z, m, i: int, cfg: NemotronHConfig):
-    """``RMSNorm_grouped(y * silu(z)) Wout``: y ``[..., H, P]`` float32, z
-    ``[..., HP]`` -> ``[..., d]`` float32."""
-    lead, g = z.shape[:-1], cfg.n_groups
-    y = y.reshape(*lead, g, cfg.d_inner // g) * jax.nn.silu(z).reshape(
-        *lead, g, cfg.d_inner // g)
-    y = y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.rms_eps)
-    y = y.reshape(*lead, cfg.d_inner) * m["norm"][i].astype(jnp.float32)
-    return matmul("...f,fe->...e", y.astype(jnp.dtype(cfg.dtype)),
-                  m["w_out"][i])
-
-
-def ssd_chunked(x, dt, a, b, c, d_skip, chunk: int, dtype):
-    """The recurrence ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t``, ``y_t =
-    S_t C_t + D x_t`` from ``S = 0``, in chunks.  x ``[B, S, H, P]``, dt
-    ``[B, S, H]`` (0 = the position is left out of the state), a, d_skip
-    ``[H]``, b, c ``[B, S, G, N]``, all float32 -> y ``[B, S, H, P]``, the
-    last state ``[B, H, P, N]``, float32.  The products read ``dtype``."""
-    bsz, s, h, p = x.shape
-    g, n = b.shape[2:]
-    r = h // g
-    pad = -s % chunk
-    if pad:  # dt = 0 there: the state passes through
-        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-                       for v in (x, dt, b, c))
-    nc = (s + pad) // chunk
-    x = x.reshape(bsz, nc, chunk, g, r, p)
-    dt = dt.reshape(bsz, nc, chunk, g, r)
-    b = b.reshape(bsz, nc, chunk, g, n).astype(dtype)
-    c = c.reshape(bsz, nc, chunk, g, n).astype(dtype)
-    # log of the decay from a chunk's start through position q, inclusive
-    acum = jnp.cumsum(dt * a.reshape(g, r), axis=2)  # [B, c, Q, G, R]
-    # inside a chunk: y_q += sum_{k <= q} (C_q . B_k) exp(acum_q - acum_k)
-    # dt_k x_k
-    seg = (acum.transpose(0, 1, 3, 4, 2)[..., :, None]
-           - acum.transpose(0, 1, 3, 4, 2)[..., None, :])  # [B,c,G,R,Q,K]
-    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-    cb = matmul("bcqgn,bckgn->bcgqk", c, b)
-    weights = (cb[:, :, :, None] * decay
-               * dt.transpose(0, 1, 3, 4, 2)[..., None, :])
-    y = matmul("bcgrqk,bckgrp->bcqgrp", weights.astype(dtype),
-               x.astype(dtype))
-    # a chunk's own contribution to the state at its end
-    to_end = jnp.exp(acum[:, :, -1:] - acum) * dt  # [B, c, Q, G, R]
-    ends = matmul("bckgrp,bckgn->bcgrpn",
-                  (x * to_end[..., None]).astype(dtype), b)
-    through = jnp.exp(acum[:, :, -1])  # [B, c, G, R] a whole chunk's decay
-
-    def next_chunk(state, inp):
-        end, keep = inp
-        return keep[..., None, None] * state + end, state
-
-    last, before = jax.lax.scan(
-        next_chunk, jnp.zeros((bsz, g, r, p, n), jnp.float32),
-        (ends.swapaxes(0, 1), through.swapaxes(0, 1)))
-    # what the chunks before it left: y_q += exp(acum_q) C_q . S_before
-    y = y + jnp.exp(acum)[..., None] * matmul(
-        "bcqgn,bcgrpn->bcqgrp", c, before.swapaxes(0, 1).astype(dtype))
-    y = y + d_skip.reshape(g, r, 1) * x
-    return (y.reshape(bsz, nc * chunk, h, p)[:, :s],
-            last.reshape(bsz, h, p, n))
-
-
-def mamba_sequence(y, lengths, m, i: int, cfg: NemotronHConfig):
-    """The Mamba-2 mixer over whole sequences.  y ``[B, S, d]``, lengths
-    ``[B]`` -> (``[B, S, d]`` float32, the convolution's state ``[B, (K-1)(HP
-    + 2GN)]`` = its last ``K-1`` TRUE inputs side by side, oldest first, the
-    state ``[B, H, P, N]`` after position ``length - 1``).  Positions ``>=
-    length`` change neither."""
-    k = cfg.conv_kernel
-    s = y.shape[1]
-    z, xbc, dt = mamba_project(y, m, i, cfg)
-    dt = jnp.where(jnp.arange(s)[None, :, None] < lengths[:, None, None],
-                   dt, 0.0)
-    idx = lengths[:, None] - (k - 1) + jnp.arange(k - 1)[None]  # [B, K-1]
-    conv_state = jnp.where(
-        (idx >= 0)[..., None],
-        jnp.take_along_axis(xbc, jnp.maximum(idx, 0)[..., None], axis=1), 0.0)
-    padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-    conv = sum(padded[:, j:j + s] * m["conv_w"][i, j] for j in range(k))
-    x, b, c = split_xbc(jax.nn.silu(conv + m["conv_b"][i]), cfg)
-    out, state = ssd_chunked(
-        x, dt, -jnp.exp(m["a_log"][i]), b, c, m["d_skip"][i], cfg.chunk_size,
-        jnp.dtype(cfg.dtype))
-    return (mamba_output(out, z, m, i, cfg),
-            conv_state.reshape(y.shape[0], -1), state)
-
-
 # ---------------------------------------------------------------- attention
 def attention_project(y, att, i: int):
     """y ``[..., d]`` -> q ``[..., Hq, D]``, k, v ``[..., Hkv, D]`` in y's
@@ -502,15 +391,15 @@ def run_layers(params, x, live, mamba, attend, cfg: NemotronHConfig):
         seen[kind] += 1
         if kind == "M":
             with jax.named_scope("nemotron.mamba"):
-                y = _rmsnorm(x, blocks["mamba"]["rms"][i], cfg.rms_eps)
+                y = rmsnorm(x, blocks["mamba"]["rms"][i], cfg.rms_eps)
                 x = x + mamba(i, y.astype(dt))
         elif kind == "*":
             with jax.named_scope("nemotron.attn"):
-                y = _rmsnorm(x, blocks["attn"]["rms"][i], cfg.rms_eps)
+                y = rmsnorm(x, blocks["attn"]["rms"][i], cfg.rms_eps)
                 x = x + attend(i, y.astype(dt))
         else:
             with jax.named_scope("nemotron.moe"):
-                u = _rmsnorm(x, blocks["moe"]["rms"][i], cfg.rms_eps)  # f32
+                u = rmsnorm(x, blocks["moe"]["rms"][i], cfg.rms_eps)  # f32
                 u, rows = u.reshape(-1, u.shape[-1]), live.reshape(-1)
             routed, shared, counts = moe(u, rows, params, i, cfg)
             with jax.named_scope("nemotron.moe"):
@@ -544,7 +433,7 @@ def nemotron_h_forward(params, tokens, lengths, cfg: NemotronHConfig):
 
     x, counts = run_layers(params, x, live, mamba, attend, cfg)
     with jax.named_scope("nemotron.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
     stacked = {}
     for name, v in kept.items():

@@ -51,14 +51,11 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.conv_update import conv_update
 from ..ops.decode_attention import decode_attention, write_token_to_cache
-from ..ops.mamba_update import mamba_update
-from .llama import _rmsnorm
-from .longcat import matmul
+from .layers import matmul, rmsnorm
+from .mamba2 import mamba_step
 from .nemotron_h import (CACHE_SCOPE, NemotronHConfig, attention_project,
-                         mamba_output, mamba_project, nemotron_h_forward,
-                         run_layers, split_xbc)
+                         nemotron_h_forward, run_layers)
 
 
 def nemotron_h_init_cache(cfg: NemotronHConfig, batch: int, max_len: int):
@@ -98,21 +95,6 @@ def nemotron_h_prefill(
     return (*out, counts) if with_counts else out
 
 
-def mamba_step(y, conv_leaf, leaf, m, i: int, cfg: NemotronHConfig):
-    """One token a row through Mamba-2 layer ``i``, whose state is layer
-    ``i`` of the two stacked leaves ``conv_leaf [M, B, (K-1)(HP + 2GN)]`` and
-    ``leaf [M, B, H, P, N]``.  y ``[B, d]`` -> (``[B, d]`` float32, the two
-    leaves with layer ``i`` stepped: the same buffers where the caller
-    donated them, ``ops/conv_update.py`` and ``ops/mamba_update.py``)."""
-    z, xbc, dt = mamba_project(y, m, i, cfg)
-    conv, conv_leaf = conv_update(conv_leaf, i, xbc, m["conv_w"][i])
-    x, b, c = split_xbc(jax.nn.silu(conv + m["conv_b"][i]), cfg)
-    keep = jnp.exp(dt * -jnp.exp(m["a_log"][i]))  # [B, H]
-    out, leaf = mamba_update(leaf, i, x, dt, keep, b, c)
-    out = out + m["d_skip"][i][:, None] * x  # [B, H, P]
-    return mamba_output(out, z, m, i, cfg), conv_leaf, leaf
-
-
 def nemotron_h_decode_step(
     params, tokens, pos, cache, cfg: NemotronHConfig, *,
     with_counts: bool = False
@@ -147,7 +129,7 @@ def nemotron_h_decode_step(
             cache["v"] = write_token_to_cache(
                 cache["v"], jnp.stack(new_v), pos, axis=3)
     with jax.named_scope("nemotron.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)

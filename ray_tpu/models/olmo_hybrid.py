@@ -8,7 +8,7 @@ Symbols: ``d`` d_model; linear attention: ``H`` heads, keys of ``dk`` and
 values of ``dv`` a head, ``K`` the convolution's taps, ``C`` the chunk; full
 attention: ``Hf`` query = key-value heads of ``D``; ``F`` the MLP's width.
 No bias anywhere.  ``u`` is a mixer's input; everything between two matrix
-products is float32 (``longcat.matmul``: the products read ``cfg.dtype`` and
+products is float32 (``layers.matmul``: the products read ``cfg.dtype`` and
 accumulate in float32), and so are the residual stream and the state.
 
 **Linear attention (gated delta net)**: ``[q~ | k~ | v~] = u Wqkv`` (widths
@@ -68,10 +68,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .llama import _rmsnorm
-from .laguna import scan_or_call
-from .longcat import ffn, matmul
-from .mistral4 import blocked_attention
+from .layers import blocked_attention, ffn, matmul, rmsnorm, scan_or_call
 
 PUBLISHED_PATTERN = "LLLF" * 8
 # a kind of layer -> its stack under params["blocks"]
@@ -405,13 +402,13 @@ def block(params, x, kind: str, i: int, mix, cfg: OlmoHybridConfig):
 
     if kind == "L":  # pre-norm
         with jax.named_scope("olmo.delta"):
-            x = x + mix(_rmsnorm(x, w["rms_mix"][i], cfg.rms_eps).astype(dt))
+            x = x + mix(rmsnorm(x, w["rms_mix"][i], cfg.rms_eps).astype(dt))
         with jax.named_scope("olmo.mlp"):
-            return x + mlp(_rmsnorm(x, w["rms_mlp"][i], cfg.rms_eps))
+            return x + mlp(rmsnorm(x, w["rms_mlp"][i], cfg.rms_eps))
     with jax.named_scope("olmo.attn"):  # the norm on the mixer's OUTPUT
-        x = x + _rmsnorm(mix(x.astype(dt)), w["rms_mix"][i], cfg.rms_eps)
+        x = x + rmsnorm(mix(x.astype(dt)), w["rms_mix"][i], cfg.rms_eps)
     with jax.named_scope("olmo.mlp"):
-        return x + _rmsnorm(mlp(x), w["rms_mlp"][i], cfg.rms_eps)
+        return x + rmsnorm(mlp(x), w["rms_mlp"][i], cfg.rms_eps)
 
 
 def layer_plan(kinds: str):
@@ -490,7 +487,7 @@ def olmo_hybrid_forward(params, tokens, lengths, cfg: OlmoHybridConfig):
 
     x, held = scan_or_call(one_period, x, repeats)
     with jax.named_scope("olmo.head"):  # the final norm is the head's
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(dt)
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(dt)
     kept = {}  # [repeats, a period's layers of the kind, ...] -> [layers, ...]
     for kind, names in (("L", ("conv", "state")), ("F", ("k", "v"))):
         with jax.named_scope("olmo.delta" if kind == "L" else "olmo.attn"):

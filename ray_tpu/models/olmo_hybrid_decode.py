@@ -50,8 +50,7 @@ import jax.numpy as jnp
 from ..ops.conv_update import conv_update
 from ..ops.decode_attention import decode_attention, write_token_to_cache
 from ..ops.delta_update import delta_update
-from .llama import _rmsnorm
-from .longcat import matmul
+from .layers import matmul, rmsnorm
 from .olmo_hybrid import (STACK, OlmoHybridConfig, attention_project, block,
                           delta_output, delta_project, olmo_hybrid_forward,
                           split_heads)
@@ -159,7 +158,7 @@ def olmo_hybrid_decode_step(
             cache["v"] = write_token_to_cache(
                 cache["v"], jnp.stack(new_v), pos, axis=3)
     with jax.named_scope("olmo.head"):
-        x = _rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
+        x = rmsnorm(x, params["rms_f"], cfg.rms_eps).astype(
             jnp.dtype(cfg.dtype))
         logits = matmul("be,ve->bv", x, params["lm_head"])
     out = (logits, cache)

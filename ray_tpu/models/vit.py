@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .layers import layernorm
+
 
 @dataclasses.dataclass(frozen=True)
 class ViTConfig:
@@ -114,18 +116,10 @@ def vit_param_axes():
     }
 
 
-def _layernorm(x, g, b, eps=1e-6):
-    x32 = x.astype(jnp.float32)
-    mu = x32.mean(-1, keepdims=True)
-    var = ((x32 - mu) ** 2).mean(-1, keepdims=True)
-    y = (x32 - mu) * jax.lax.rsqrt(var + eps)
-    return (y * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
-
-
 def _encoder_block(x, layer, cfg: ViTConfig, mesh):
     from ..parallel.sharding import with_logical_constraint as wlc
 
-    y = _layernorm(x, layer["ln1_g"], layer["ln1_b"])
+    y = layernorm(x, layer["ln1_g"], layer["ln1_b"], eps=1e-6)
     qkv = jnp.einsum("bse,ethd->bsthd", y, layer["wqkv"]) + layer["bqkv"]
     q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
     if cfg.attention == "flash":
@@ -137,7 +131,7 @@ def _encoder_block(x, layer, cfg: ViTConfig, mesh):
 
         o = reference_attention(q, k, v, causal=False)
     x = x + (jnp.einsum("bshd,hde->bse", o, layer["wo"]) + layer["bo"]).astype(x.dtype)
-    y = _layernorm(x, layer["ln2_g"], layer["ln2_b"])
+    y = layernorm(x, layer["ln2_g"], layer["ln2_b"], eps=1e-6)
     hdn = jax.nn.gelu(jnp.einsum("bse,ef->bsf", y, layer["wi"]) + layer["bi"])
     hdn = wlc(hdn, P("batch", "seq", "mlp"), mesh)
     x = x + (jnp.einsum("bsf,fe->bse", hdn, layer["wo2"]) + layer["bo2"]).astype(x.dtype)
@@ -169,7 +163,7 @@ def vit_apply(params, images, cfg: ViTConfig, mesh=None):
         return block(x, layer), None
 
     x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-    x = _layernorm(x[:, 0], params["lnf_g"], params["lnf_b"])
+    x = layernorm(x[:, 0], params["lnf_g"], params["lnf_b"], eps=1e-6)
     logits = x.astype(jnp.float32) @ params["head_w"].astype(jnp.float32) + \
         params["head_b"].astype(jnp.float32)
     return wlc(logits, P("batch", None), mesh)

@@ -11,6 +11,14 @@ Host side lives in ``ServeController`` (``listen_for_change`` +
 ``_publish_state``); this module is the client: one daemon thread per
 process multiplexes every handle/proxy subscription in that process over
 a single outstanding listen call.
+
+A client belongs to the cluster it was made in.  One that outlives it
+(``ray_tpu.shutdown()`` with no ``serve.shutdown()`` before, then a new
+``init()`` in the same process) sits in a listen on the dead cluster for up
+to ``LISTEN_TIMEOUT_S + 15`` s, and a key registered meanwhile is in no
+listen until that ends: 44 s without a push, measured (PR 64).  So
+``long_poll_client()`` replaces a client whose core worker is not the
+process's current one.
 """
 
 from __future__ import annotations
@@ -20,6 +28,7 @@ import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
+from ..core.core_worker import try_global_worker
 from ..util.debug_locks import make_lock
 
 logger = logging.getLogger(__name__)
@@ -32,6 +41,7 @@ class LongPollClient:
 
     def __init__(self, controller_name: str):
         self._controller_name = controller_name
+        self._worker = try_global_worker()  # the cluster it listens to
         self._known: Dict[Tuple, Tuple[int, Any]] = {}
         self._keys: set = set()
         self._lock = make_lock("serve.long_poll.client")
@@ -97,6 +107,8 @@ def long_poll_client() -> LongPollClient:
     """Process-wide client (one listen loop no matter how many handles)."""
     global _client
     with _client_lock:
+        if _client is not None and _client._worker is not try_global_worker():
+            _client.stop()  # its cluster is gone; its thread ends with its get
         if _client is None or _client._stopped:
             from .controller import CONTROLLER_NAME
 

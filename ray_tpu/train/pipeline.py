@@ -216,8 +216,8 @@ def gpt2_stage_modules(cfg, total_virtual: int, seed: int = 0):
         from ray_tpu.models.gpt2 import (
             _ce_from_logits,
             _block,
-            _layernorm,
         )
+        from ray_tpu.models.layers import layernorm
 
         lo, hi = bounds[v]
         first, last = v == 0, v == total_virtual - 1
@@ -294,7 +294,7 @@ def gpt2_stage_modules(cfg, total_virtual: int, seed: int = 0):
             h = run_blocks(params, h)
             if not last:
                 return h
-            h = _layernorm(h, params["lnf_g"], params["lnf_b"])
+            h = layernorm(h, params["lnf_g"], params["lnf_b"])
             logits = jnp.einsum("bse,ve->bsv", h, params["unembed"])
             b, s = targets.shape
             return _ce_from_logits(logits, targets, 0.0) / (b * s)

@@ -12,8 +12,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import longcat_decode
-from ray_tpu.models.longcat import LongcatConfig, matmul
+from ray_tpu.models import mla
+from ray_tpu.models.layers import matmul
+from ray_tpu.models.longcat import LongcatConfig
 from ray_tpu.ops.decode_attention import (decode_attention, extent_step,
                                           live_extent)
 from test_llama_kernels import dense_decode_attention
@@ -201,7 +202,7 @@ def test_a_ring_of_512_beside_a_cache_of_16384_positions(h):
         np.testing.assert_allclose(got[b], want[0], atol=TOL["float32"])
 
 
-# --------------------------------------------- models/longcat_decode.py
+# ------------------------------------------------------- models/mla.py
 def mla_absorbed_before(q, latent_self, latent_cache, pos, att, cfg):
     """``mla_absorbed`` as it was at PR 45, verbatim: one attention's slice
     ``[B, T, C]`` of the cache, scored whole."""
@@ -239,7 +240,7 @@ def mla_operands(t, dtype, seed=0):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_mla_absorbed_reads_the_stack_in_blocks_and_gives_what_it_gave(dtype):
     cfg, att, (q, latent_self, cache) = mla_operands(T, dtype)
-    now = jax.jit(lambda *a: longcat_decode.mla_absorbed(
+    now = jax.jit(lambda *a: mla.mla_absorbed(
         *a, att, cfg, layer=2))
     before = jax.jit(lambda q, ls, cache, pos: mla_absorbed_before(
         q, ls, cache[2], pos, att, cfg))
@@ -265,12 +266,12 @@ def test_mla_absorbed_reads_the_stack_in_blocks_and_gives_what_it_gave(dtype):
 def test_mla_absorbed_over_a_cache_of_one_extent_is_bit_for_bit_what_it_was():
     cfg, att, (q, latent_self, cache) = mla_operands(64, "bfloat16", seed=1)
     pos = jnp.asarray([0, 17, 63], jnp.int32)
-    now = jax.jit(lambda *a: longcat_decode.mla_absorbed(
+    now = jax.jit(lambda *a: mla.mla_absorbed(
         *a, att, cfg, layer=1))(q, latent_self, cache, pos)
     before = jax.jit(lambda q, ls, cache, pos: mla_absorbed_before(
         q, ls, cache[1], pos, att, cfg))(q, latent_self, cache, pos)
     np.testing.assert_array_equal(now, before)
-    assert loops(lambda *a: longcat_decode.mla_absorbed(
+    assert loops(lambda *a: mla.mla_absorbed(
         *a, att, cfg, layer=1), q, latent_self, cache, pos) == 0
 
 
@@ -289,7 +290,7 @@ def test_mla_absorbed_over_thirty_two_blocks_with_the_halves_as_two_leaves(
     dn = cfg.qk_nope_head_dim
     halves = {"wk_b": att["wkv_b"][..., :dn], "wv_b": att["wkv_b"][..., dn:],
               "wo": att["wo"]}
-    now = jax.jit(lambda *a: longcat_decode.mla_absorbed(
+    now = jax.jit(lambda *a: mla.mla_absorbed(
         *a, halves, cfg, layer=1))
     before = jax.jit(lambda q, ls, cache, pos: mla_absorbed_before(
         q, ls, cache[1], pos, att, cfg))
@@ -303,5 +304,5 @@ def test_mla_absorbed_over_thirty_two_blocks_with_the_halves_as_two_leaves(
     np.testing.assert_array_equal(
         now(q, latent_self, poisoned, jnp.asarray([1, 9000, 9001], jnp.int32)),
         near)
-    assert loops(lambda *a: longcat_decode.mla_absorbed(
+    assert loops(lambda *a: mla.mla_absorbed(
         *a, halves, cfg, layer=1), q, latent_self, cache, pos) == 1

@@ -20,7 +20,7 @@ from ray_tpu.models import expert_share
 from ray_tpu.models.expert_share import (EXPERT_CHUNK, chunk_rows,
                                          held_experts, loop_counts,
                                          sigmoid_route)
-from ray_tpu.models.longcat import ffn
+from ray_tpu.models.layers import ffn
 from ray_tpu.models.nemotron_h import relu2
 
 # name: rows, width, held experts, expert's hidden width, its kind, the

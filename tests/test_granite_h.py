@@ -20,8 +20,8 @@ from benchmarks.lib import bench_server
 from benchmarks.reference import granite_h_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
 from ray_tpu.models import (GraniteHConfig, granite_h, granite_h_decode,
-                            granite_h_init, model_family, nemotron_h,
-                            nemotron_h_decode)
+                            granite_h_init, layers, mamba2, model_family,
+                            nemotron_h, nemotron_h_decode)
 
 # float32 against float32, the largest difference of a logit as a share of
 # the logits' spread (``off``; the spread is ~0.02 here: a tied table at 0.02
@@ -122,7 +122,7 @@ def test_family_resolves_and_full_forward_matches_the_reference(weights):
     assert full.kinds.count("M") == 36 and full.n_layer == 40
     assert [i for i, k in enumerate(full.kinds) if k == "*"] == [5, 15, 25, 35]
     assert (full.d_inner, full.d_conv, full.query_scale) == (4096, 4352, 0.125)
-    assert granite_h.layer_plan(full.kinds) == [
+    assert layers.layer_plan(full.kinds) == [
         ([("M", 5)], 1), ([("*", 1), ("M", 9)], 3), ([("*", 1)], 1),
         ([("M", 4)], 1)]
     with pytest.raises(ValueError):
@@ -132,10 +132,12 @@ def test_family_resolves_and_full_forward_matches_the_reference(weights):
 
 
 def test_the_mamba_mixer_is_nemotrons_not_a_copy():
-    """One Mamba-2 in the tree: the family calls ``nemotron_h``'s functions
-    on its own config, whose field names they read."""
+    """One Mamba-2 in the tree: both families call ``mamba2``'s functions,
+    this one on its own config, whose field names they read."""
     assert granite_h.mamba_sequence is nemotron_h.mamba_sequence
+    assert granite_h.mamba_sequence is mamba2.mamba_sequence
     assert granite_h_decode.mamba_step is nemotron_h_decode.mamba_step
+    assert granite_h_decode.mamba_step is mamba2.mamba_step
     for module in (granite_h, granite_h_decode):
         source = inspect.getsource(module)
         assert "def ssd_chunked" not in source
@@ -153,7 +155,7 @@ def test_folded_layers_are_the_layers_in_order(pattern):
     got = jax.jit(lambda p, t: granite_h.granite_h_apply(p, t, cfg))(
         params, toks)
     assert off(got, ref_logits(params, toks, cfg)) < F32_TOL
-    folded = sum(n * repeats for group, repeats in granite_h.layer_plan(
+    folded = sum(n * repeats for group, repeats in layers.layer_plan(
         pattern) for _, n in group)
     assert folded == len(pattern)
 
@@ -387,7 +389,7 @@ def test_each_decode_step_shifts_every_layers_window_by_its_token(lengths):
 
 @pytest.mark.parametrize("chunk", [4, 8, 16, 29, 64])
 def test_the_chunked_scan_equals_the_recurrence_at_one_group(chunk):
-    """``nemotron_h.ssd_chunked`` at ``G`` = 1 (every head reads the one
+    """``mamba2.ssd_chunked`` at ``G`` = 1 (every head reads the one
     ``B``, ``C``) against the recurrence itself, position by position, in
     numpy float64: 29 positions in chunks that divide them (29), that do not
     (4, 8, 16) and that hold them all (64); some positions with ``dt = 0``
@@ -402,7 +404,7 @@ def test_the_chunked_scan_equals_the_recurrence_at_one_group(chunk):
     dt[1, 20:] = 0.0  # a row's padding
     a, d_skip = -rng.uniform(0.5, 4.0, size=h), rng.normal(size=h)
     f32 = lambda v: jnp.asarray(v, jnp.float32)
-    y, last = nemotron_h.ssd_chunked(
+    y, last = mamba2.ssd_chunked(
         f32(x), f32(dt), f32(a), f32(b), f32(c), f32(d_skip), chunk,
         jnp.float32)
     state = np.zeros((bsz, h, p, n))
@@ -612,7 +614,7 @@ def test_the_cells_draw_keeps_the_state_old_and_the_scores_spread():
     m = params["blocks"]["mamba"]
     rng = np.random.default_rng(0)
     u = jnp.asarray(rng.normal(size=(1, 400, cfg.d_model)), jnp.float32)
-    _, _, dt = nemotron_h.mamba_project(u, m, 0, cfg)
+    _, _, dt = mamba2.mamba_project(u, m, 0, cfg)
     alpha = np.exp(np.asarray(dt) * -np.exp(np.asarray(m["a_log"][0])))
     assert alpha.min() > 0.7 and alpha.max() < 1 and np.median(alpha) > 0.97
     assert (alpha.mean(axis=(0, 1)) > 0.999).any()  # a head that is slow

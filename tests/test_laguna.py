@@ -11,7 +11,6 @@ Logits, not tokens.  Each tolerance says what it allows for.
 """
 
 import dataclasses
-import hashlib
 import json
 import pathlib
 import unittest.mock
@@ -24,8 +23,8 @@ import pytest
 from benchmarks.families import laguna as bench_family
 from benchmarks.reference import laguna_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
-from ray_tpu.models import (LagunaConfig, MimoV2Config, Mistral4Config,
-                            laguna, laguna_init, mistral4, model_family)
+from ray_tpu.models import (LagunaConfig, laguna, laguna_init, layers,
+                            model_family)
 from ray_tpu.models.expert_share import (chunk_rows, runs_every_held_expert,
                                          sigmoid_route)
 
@@ -181,8 +180,8 @@ def test_the_yarn_table_at_the_published_sizes():
     numbers = (cfg.rotary_dim, cfg.rope_theta, cfg.rope_original_max,
                cfg.rope_beta_fast, cfg.rope_beta_slow)
     assert numbers == (64, 5e5, 8192, 32.0, 1.0)
-    assert mistral4.yarn_correction_range(*numbers) == (9, 18)
-    table = mistral4.yarn_inv_freq(64, 5e5, 128.0, 8192, 32.0, 1.0)
+    assert layers.yarn_correction_range(*numbers) == (9, 18)
+    table = layers.yarn_inv_freq(64, 5e5, 128.0, 8192, 32.0, 1.0)
     f = 5e5 ** (-2 * np.arange(32) / 64)
     r = np.clip((np.arange(32) - 9) / 9, 0, 1)
     np.testing.assert_allclose(table, (1 - r) * f + r * f / 128, rtol=1e-6)
@@ -369,7 +368,7 @@ def test_the_tiled_prefill_over_grouped_heads_equals_the_dense_one(
     a lower bound too, and a row whose first tile is wholly masked takes its
     softmax from the next)."""
     q, k, v = attention_operands(s, seed=s)
-    got = jax.jit(lambda q, k, v: mistral4.blocked_attention(
+    got = jax.jit(lambda q, k, v: layers.blocked_attention(
         q, k, v, query_block=query_block, key_block=key_block,
         window=window))(q, k, v)
     np.testing.assert_allclose(got, dense_attention(q, k, v, window),
@@ -382,7 +381,7 @@ def test_the_band_meets_two_key_tiles_a_query_tile_whatever_the_length():
     diagonal: counted by the scores' products in the traced program, and
     the rows of query tiles beyond ``longest`` come out zero."""
     q, k, v = attention_operands(64, h=18)  # groups of 9
-    got = jax.jit(lambda q, k, v, n: mistral4.blocked_attention(
+    got = jax.jit(lambda q, k, v, n: layers.blocked_attention(
         q, k, v, n, query_block=8, key_block=8, window=8))(q, k, v, 19)
     np.testing.assert_allclose(got[:, :24],
                                dense_attention(q, k, v, 8)[:, :24],
@@ -395,12 +394,12 @@ def test_the_band_meets_two_key_tiles_a_query_tile_whatever_the_length():
         return jnp.zeros(shape, jnp.float32)
 
     with jax.disable_jit(), unittest.mock.patch.object(
-            mistral4, "attend_blocks", spy):
-        mistral4.blocked_attention(q, k, v, query_block=8, key_block=8,
-                                   window=8)
+            layers, "attend_blocks", spy):
+        layers.blocked_attention(q, k, v, query_block=8, key_block=8,
+                                 window=8)
         assert trips == [1] + [2] * 7
         trips.clear()
-        mistral4.blocked_attention(q, k, v, query_block=8, key_block=8)
+        layers.blocked_attention(q, k, v, query_block=8, key_block=8)
         assert trips == list(range(1, 9))
 
 
@@ -656,41 +655,3 @@ def test_the_harness_two_layer_cut_and_the_cells_draw(monkeypatch):
         np.testing.assert_array_equal(program, reference)
         np.testing.assert_array_equal(program[:, 30], program[:, 3])
     assert (chosen[0] != chosen[1]).any()  # every layer its own choice
-
-
-# --------------------------------------------------------- older families
-@pytest.mark.parametrize("cfg,prefill_sha,decode_sha", [
-    (MimoV2Config.tiny(), "16772087bf73326081b75dc93bfb0dfb9c4749de",
-     "8bd0dfba3e02f082be71984ad01897d310c8c355"),
-    (Mistral4Config.tiny(), "d979f58cccbca1cb44fa5733c4f251147805c04b",
-     "7c629245dec20c29d937ccafeb35b4df04b77eeb")],
-    ids=["mimo_v2", "mistral4"])
-def test_the_older_families_programs_lower_to_the_text_they_lowered_to(
-    cfg, prefill_sha, decode_sha
-):
-    """``mistral4.blocked_attention`` gained grouped heads and a window,
-    ``yarn_inv_freq`` takes numbers, ``mimo_v2.rope_half`` a table and a
-    factor: all static or absent, so MiMo-V2's and Mistral-4's prefill and
-    decode step (tiny configs, one row of 64 and four slots of 1024) lower
-    to the SAME StableHLO text as at the parent commit (sha1 of
-    ``lower().as_text()``, PR 45's way, read on the parent's tree).  Read
-    again on PR 53's tree, whose expert layers count their loop's chunks
-    (``expert_share.loop_counts``) and whose sigmoid router picks its
-    chosen scores by a select (``expert_share.chosen_scores``).  The decode
-    steps read once more on PR 54's tree: four slots of these tiny configs
-    (4 x 4 choices for 16 experts, as the Mistral-4 cell's 32 x 4 for 128)
-    now take ``expert_share.held_experts`` in its one-chunk form, a turn a
-    touched expert on the whole batch; the prefills (64 rows: every held
-    expert in batched products) are the text they were."""
-    fam = model_family(cfg)
-    params = jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: fam.init_cache(cfg, 4, 1024))
-    one = jax.eval_shape(lambda: fam.init_cache(cfg, 1, 64))
-    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    prefill = jax.jit(lambda p, t, n, c: fam.prefill_counted(
-        p, t, n, c, cfg)).lower(params, ints(1, 64), ints(1), one).as_text()
-    decode = jax.jit(lambda p, t, pos, c: fam.decode_step_counted(
-        p, t, pos, c, cfg)).lower(params, ints(4), ints(4), cache).as_text()
-    sha1 = lambda text: hashlib.sha1(text.encode()).hexdigest()  # noqa: E731
-    assert sha1(prefill) == prefill_sha
-    assert sha1(decode) == decode_sha

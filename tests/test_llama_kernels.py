@@ -6,13 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models.layers import rope
 from ray_tpu.models.llama import (
     LlamaConfig,
     llama_apply,
     llama_init,
     llama_loss,
     llama_param_axes,
-    rope,
 )
 from ray_tpu.ops.decode_attention import decode_attention, extent_step
 

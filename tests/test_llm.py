@@ -460,7 +460,7 @@ class TestSampling:
     def test_top_k_restricts(self):
         import jax
 
-        from ray_tpu.models.gpt2_decode import sample_logits
+        from ray_tpu.models.sampling import sample_logits
 
         logits = np.full((1, 10), -10.0, np.float32)
         logits[0, 3] = 5.0
@@ -478,7 +478,7 @@ class TestSampling:
     def test_greedy(self):
         import jax
 
-        from ray_tpu.models.gpt2_decode import sample_logits
+        from ray_tpu.models.sampling import sample_logits
 
         logits = np.zeros((2, 5), np.float32)
         logits[0, 2] = 3.0

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (GraniteHConfig, NemotronHConfig, granite_h_decode,
-                            model_family, nemotron_h_decode)
+                            mamba2, model_family, nemotron_h_decode)
 from ray_tpu.ops import mamba_update as mu
 
 # float32 sums of 128 products of numbers of order one, in two orders
@@ -228,7 +228,7 @@ def test_a_familys_decode_step_through_the_kernel_is_its_step_through_xla(
         calls.append((leaf.shape, at))
         return mu.mamba_update(leaf, at, *small, force_pallas=True)
 
-    monkeypatch.setattr(nemotron_h_decode, "mamba_update", forced)
+    monkeypatch.setattr(mamba2, "mamba_update", forced)
     logits, new = fam.decode_step(params, tokens, pos, cache, cfg)
     nm = cfg.kinds.count("M")
     assert calls == [(cache["ssm"].shape, i) for i in range(nm)]
@@ -236,3 +236,4 @@ def test_a_familys_decode_step_through_the_kernel_is_its_step_through_xla(
     for name in cache:
         close(new[name], want[name], tol=2e-5)
     assert granite_h_decode.mamba_step is nemotron_h_decode.mamba_step
+    assert granite_h_decode.mamba_step is mamba2.mamba_step

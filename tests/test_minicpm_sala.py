@@ -17,9 +17,9 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import minicpm_sala_ref as ref
-from ray_tpu.models import (MinicpmSalaConfig, minicpm_sala,
+from ray_tpu.models import (MinicpmSalaConfig, mamba2, minicpm_sala,
                             minicpm_sala_decode, minicpm_sala_init,
-                            model_family, nemotron_h)
+                            model_family)
 from ray_tpu.ops import mamba_update as mamba_update_op
 
 # float32 against float32, the largest difference of a logit as a share of
@@ -126,9 +126,9 @@ def test_family_resolves_and_full_forward_matches_the_reference(weights,
 
 def test_the_lightning_mixer_is_nemotrons_scan_and_the_trees_update():
     """No second scan and no second update in the tree: the family's modules
-    CALL ``nemotron_h.ssd_chunked`` and ``ops.mamba_update.mamba_update`` and
+    CALL ``mamba2.ssd_chunked`` and ``ops.mamba_update.mamba_update`` and
     define no recurrence of their own."""
-    assert minicpm_sala.ssd_chunked is nemotron_h.ssd_chunked
+    assert minicpm_sala.ssd_chunked is mamba2.ssd_chunked
     assert minicpm_sala_decode.mamba_update is mamba_update_op.mamba_update
     for module in (minicpm_sala, minicpm_sala_decode):
         source = inspect.getsource(module)
@@ -223,7 +223,7 @@ def test_rotary_added_to_the_sparse_layers_is_outside_the_tolerance(
 
 @pytest.mark.parametrize("chunk", [4, 8, 16, 29, 64])
 def test_the_chunked_scan_equals_the_recurrence_at_a_group_a_head(chunk):
-    """``nemotron_h.ssd_chunked`` at ``G = H`` (every head its own ``B``,
+    """``mamba2.ssd_chunked`` at ``G = H`` (every head its own ``B``,
     ``C``: lightning attention's ``k``, ``q``), ``dt`` 1 inside a row's length
     and 0 beyond it, no skip, against the recurrence itself, position by
     position, in numpy float64: 29 positions in chunks that divide them
@@ -237,7 +237,7 @@ def test_the_chunked_scan_equals_the_recurrence_at_a_group_a_head(chunk):
     a = -np.asarray(minicpm_sala.slopes(
         tiny(lightning_heads=h), 12), np.float64)
     f32 = lambda v: jnp.asarray(v, jnp.float32)
-    y, last = nemotron_h.ssd_chunked(
+    y, last = mamba2.ssd_chunked(
         f32(x), f32(dt), f32(a), f32(b), f32(c), jnp.zeros(h), chunk,
         jnp.float32)
     state = np.zeros((bsz, h, p, n))

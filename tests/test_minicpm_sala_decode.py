@@ -20,7 +20,7 @@ from benchmarks.families import minicpm_sala as bench_family
 from benchmarks.lib import bench_server
 from benchmarks.reference import minicpm_sala_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
-from ray_tpu.models import (MinicpmSalaConfig, minicpm_sala,
+from ray_tpu.models import (MinicpmSalaConfig, layers, minicpm_sala,
                             minicpm_sala_decode, minicpm_sala_init,
                             model_family)
 from test_minicpm_sala import (BF16_TOL, EDGE_TOL, F32_TOL, off, ref_logits,
@@ -394,7 +394,7 @@ def test_the_cells_draw_spreads_the_scores_the_selection_ranks_by():
     x = ref.ref_embed(params, toks, dataclasses.asdict(cfg))
     assert 0.4 < float(jnp.sqrt((x * x).mean())) < 0.6
     w = params["blocks"]["sparse"]
-    y = minicpm_sala._rmsnorm(x, w["rms"][0], cfg.rms_eps)
+    y = layers.rmsnorm(x, w["rms"][0], cfg.rms_eps)
     q, k, _, _ = minicpm_sala.project(y, w, 0, cfg)
     scores = jnp.einsum("bshd,bthd->bhst", q[:, :, :2], k) / np.sqrt(128)
     assert 1.7 < float(scores.std()) < 2.3

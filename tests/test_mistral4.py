@@ -9,7 +9,6 @@ Each tolerance says what it allows for.
 """
 
 import dataclasses
-import hashlib
 import math
 
 import jax
@@ -20,12 +19,11 @@ import pytest
 from benchmarks.families import mistral4 as bench_family
 from benchmarks.reference import mistral4_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
-from ray_tpu.models import (LongcatConfig, Mistral4Config, mistral4,
-                            mistral4_init, model_family)
+from ray_tpu.models import (Mistral4Config, layers, mistral4, mistral4_init,
+                            model_family)
 from ray_tpu.models.expert_share import (chunk_rows, runs_every_held_expert,
                                          softmax_route)
-from ray_tpu.models.longcat import mla_expanded
-from ray_tpu.models.longcat_decode import mla_absorbed
+from ray_tpu.models.mla import mla_absorbed, mla_expanded
 
 # float32 against float32: the two differ by the order of their sums only
 # (blocks under an online softmax against one dense row, the absorbed
@@ -110,8 +108,8 @@ def test_the_yarn_table_and_both_scales_at_the_published_sizes():
     times slower, a linear ramp between; ``m`` = 0.1 ln 128 + 1; ``a`` is 1
     below 8192 positions and 1 + 0.1 ln 2 from there to 16383."""
     cfg = Mistral4Config()
-    assert mistral4.yarn_correction_range(64, 1e4, 8192, 32.0, 1.0) == (12, 25)
-    table = mistral4.yarn_inv_freq(*mistral4.yarn_numbers(cfg))
+    assert layers.yarn_correction_range(64, 1e4, 8192, 32.0, 1.0) == (12, 25)
+    table = layers.yarn_inv_freq(*mistral4.yarn_numbers(cfg))
     f = 1e4 ** (-2 * np.arange(32) / 64)
     r = np.clip((np.arange(32) - 12) / 13, 0, 1)
     np.testing.assert_allclose(table,
@@ -237,7 +235,7 @@ def test_the_blocked_prefill_equals_the_dense_one(s, query_block, key_block):
     softmax over the key blocks up to a query block's last row is the dense
     causal softmax."""
     q, k, v = attention_operands(s, seed=s)
-    got = jax.jit(lambda q, k, v: mistral4.blocked_attention(
+    got = jax.jit(lambda q, k, v: layers.blocked_attention(
         q, k, v, query_block=query_block, key_block=key_block))(q, k, v)
     np.testing.assert_allclose(got, dense_attention(q, k, v), atol=F32_TOL)
 
@@ -247,7 +245,7 @@ def test_query_blocks_beyond_the_longest_prompt_are_not_computed():
     it reaches are the dense rows, those of the blocks wholly beyond it come
     out zero (nothing reads them)."""
     q, k, v = attention_operands(64)
-    got = jax.jit(lambda q, k, v, n: mistral4.blocked_attention(
+    got = jax.jit(lambda q, k, v, n: layers.blocked_attention(
         q, k, v, n, query_block=8, key_block=8))(q, k, v, 19)
     np.testing.assert_allclose(got[:, :24], dense_attention(q, k, v)[:, :24],
                                atol=F32_TOL)
@@ -258,7 +256,7 @@ def test_the_blocked_latent_attention_equals_longcats_dense_expanded_form(
     weights
 ):
     """``mla_blocked`` (keys ``[kn_h | kr]`` in one product, blocks of 8)
-    against ``longcat.mla_expanded`` (two products, dense ``[S, S]``) on the
+    against ``mla.mla_expanded`` (two products, dense ``[S, S]``) on the
     same queries and latents: what was shared with LongCat's latent attention
     and what was split compute the same thing."""
     cfg, params = weights
@@ -511,30 +509,3 @@ def test_the_harness_two_layer_cut_and_the_cells_draw(monkeypatch):
         logits = embedding @ params["blocks"]["router"][layer]
         assert (np.sort(np.asarray(jax.lax.top_k(logits, cfg.top_k)[1]), -1)
                 == np.sort(np.asarray(chosen[layer]), -1)).all()
-
-
-# --------------------------------------------------------- older families
-def test_longcats_programs_lower_to_the_text_they_lowered_to():
-    """``mla_project`` and ``mla_absorbed`` gained arguments that are static
-    or absent, ``attend_live_blocks`` a core that takes the trip count:
-    LongCat's prefill and decode step (tiny config, one row of 64 and four
-    slots of 1024) lower to the SAME StableHLO text as at the parent commit
-    (sha1 of ``lower().as_text()``, PR 45's way, read on the parent's tree).
-    Read again on PR 53's tree, whose expert layers count their loop's
-    chunks (``expert_share.loop_counts``): two more scalars a layer; and on
-    PR 54's, where rows that are one chunk (the four slots, and this one row
-    of 64, shorter than any rung the engine has) take ``held_experts``'
-    one-chunk form: a turn a touched expert on the whole batch."""
-    cfg = LongcatConfig.tiny()
-    fam = model_family(cfg)
-    params = jax.eval_shape(lambda: fam.init(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: fam.init_cache(cfg, 4, 1024))
-    one = jax.eval_shape(lambda: fam.init_cache(cfg, 1, 64))
-    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
-    prefill = jax.jit(lambda p, t, n, c: fam.prefill_counted(
-        p, t, n, c, cfg)).lower(params, ints(1, 64), ints(1), one).as_text()
-    decode = jax.jit(lambda p, t, pos, c: fam.decode_step_counted(
-        p, t, pos, c, cfg)).lower(params, ints(4), ints(4), cache).as_text()
-    sha1 = lambda text: hashlib.sha1(text.encode()).hexdigest()  # noqa: E731
-    assert sha1(prefill) == "d5bc64ad9a4e93b9edd4ab6d292cdea55242bb76"
-    assert sha1(decode) == "a67b2c217adce5c1a2c80f801316a2c602e10733"

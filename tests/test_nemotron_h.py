@@ -16,8 +16,8 @@ from benchmarks.families import nemotron_h as bench_family
 from benchmarks.lib import bench_server
 from benchmarks.reference import nemotron_h_ref as ref
 from ray_tpu.llm import EngineConfig, JaxLLMEngine, SamplingParams
-from ray_tpu.models import (NemotronHConfig, model_family, nemotron_h,
-                            nemotron_h_init)
+from ray_tpu.models import (NemotronHConfig, mamba2, model_family,
+                            nemotron_h, nemotron_h_init)
 from ray_tpu.models.expert_share import chunk_rows
 
 # float32 against float32: the two differ by the order of their sums only
@@ -240,7 +240,7 @@ def test_the_chunked_scan_equals_the_recurrence_over_several_chunks():
     b, c = rng.normal(size=(2, bsz, s, g, n))
     d_skip = rng.normal(size=h)
     f32 = lambda v: jnp.asarray(v, jnp.float32)
-    y, last = nemotron_h.ssd_chunked(f32(x), f32(dt), f32(a), f32(b), f32(c),
+    y, last = mamba2.ssd_chunked(f32(x), f32(dt), f32(a), f32(b), f32(c),
                                      f32(d_skip), 8, jnp.float32)
     state = np.zeros((bsz, h, p, n))
     bh, ch = (np.repeat(v, h // g, axis=2) for v in (b, c))

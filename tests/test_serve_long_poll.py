@@ -22,8 +22,29 @@ class Echo:
         return x
 
 
+@pytest.fixture(params=["first_cluster", "after_a_cluster_left_its_client"])
+def earlier_cluster(request):
+    """What this process did before the test's cluster: nothing, or a cluster
+    that served and was shut down with NO ``serve.shutdown()``, so that its
+    long-poll client, listening, is still the process's
+    (``tests/test_chaos_injection.py`` leaves one, and under six workers
+    this file ran after it in the same process: the push was 44 s late,
+    PR 64)."""
+    if request.param == "first_cluster":
+        return
+    from ray_tpu.serve.long_poll import long_poll_client
+
+    ray_tpu.init(num_cpus=4)
+    handle = serve.run(Echo.options(name="Earlier").bind())
+    assert handle.remote("a").result(timeout=60) == "a"
+    left = long_poll_client()
+    assert left._thread.is_alive()
+    ray_tpu.shutdown()
+    assert not left._stopped
+
+
 class TestLongPollPush:
-    def test_replica_update_pushed_fast(self, serve_cluster):
+    def test_replica_update_pushed_fast(self, earlier_cluster, serve_cluster):
         h = serve.run(Echo.options(num_replicas=1).bind())
         assert h.remote("a").result(timeout=60) == "a"
         # The handle is subscribed now (first _refresh registered the key).
