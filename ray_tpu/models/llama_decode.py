@@ -9,6 +9,19 @@ attends each group of H/Hkv query heads against its shared kv-head in one
 score tile (the GQA memory win is the whole point of serving Llama-style
 models: cache bytes shrink by H/Hkv).
 
+Prefill scores in tiles (``layers.blocked_attention``, as five other
+families' does): 512 queries against 512 keys at a time under an online
+softmax, a group's query heads carried as an axis against its one key-value
+head (keys and values are never repeated), the key blocks bounded by the
+diagonal.  No array grows with the square of the rung: the dense form's
+``[B, H, S, S]`` float32 scores were 537 MB a layer at 2048 positions and
+half of that rung's time (PERF.md, PR 66).  Every query block of the rung
+runs, padding rows attended as they always were: a prompt takes the smallest
+rung that holds it, so more than half of a rung is prompt, and the longest
+prompt as a traced bound on the query blocks cost every call of the 256 rung
+0.5 ms where it saved 2.5 ms on the few prompts that leave the top rung's
+last block empty (PERF.md, PR 66).
+
 Reference role: the model runner inside the engines the reference wraps
 (ray ``python/ray/llm/_internal/serve/engines/vllm/``).
 
@@ -24,7 +37,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .layers import rmsnorm, rope
+from .layers import KEY_BLOCK, QUERY_BLOCK, blocked_attention, rmsnorm, rope
 from .llama import LlamaConfig
 
 
@@ -35,17 +48,18 @@ def llama_init_cache(cfg: LlamaConfig, batch: int, max_len: int):
 
 
 def llama_prefill(
-    params, tokens, lengths, cache, cfg: LlamaConfig
+    params, tokens, lengths, cache, cfg: LlamaConfig,
+    query_block: int = QUERY_BLOCK, key_block: int = KEY_BLOCK,
 ) -> Tuple[jnp.ndarray, dict]:
     """tokens: [B, S] right-padded prompts; lengths: [B] true lengths.
-    Returns (last_logits [B, V], cache with positions [0, S) written)."""
+    Returns (last_logits [B, V], cache with positions [0, S) written).
+    ``query_block`` / ``key_block``: the attention's tile (a test's; the
+    programs take the defaults)."""
     b, s = tokens.shape
-    groups = cfg.n_head // cfg.n_kv_head
     with jax.named_scope("llama.embed"):
         x = params["wte"][tokens].astype(jnp.dtype(cfg.dtype))
     with jax.named_scope("llama.attn"):
         positions = jnp.arange(s, dtype=jnp.int32)
-        causal = jnp.tril(jnp.ones((s, s), bool))[None]
 
     def body(x, layer):
         with jax.named_scope("llama.attn"):
@@ -55,15 +69,11 @@ def llama_prefill(
             v = jnp.einsum("bse,ekd->bskd", y, layer["wv"])
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
-            kr = jnp.repeat(k, groups, axis=2)
-            vr = jnp.repeat(v, groups, axis=2)
-            scores = jnp.einsum("bshd,bthd->bhst", q, kr).astype(jnp.float32)
-            scores = scores / (cfg.head_dim ** 0.5)
-            scores = jnp.where(causal[:, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            o = jnp.einsum("bhst,bthd->bshd", probs, vr)
+            o = blocked_attention(
+                q, k, v, query_block=query_block, key_block=key_block)
             x = x + jnp.einsum(
-                "bshd,hde->bse", o, layer["wo"]).astype(x.dtype)
+                "bshd,hde->bse", o.astype(q.dtype), layer["wo"]
+            ).astype(x.dtype)
         with jax.named_scope("llama.mlp"):
             y = rmsnorm(x, layer["rms2"], cfg.rms_eps)
             gate = jax.nn.silu(jnp.einsum("bse,ef->bsf", y, layer["w_gate"]))

@@ -58,7 +58,8 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(  # noqa: F811
         as_pr60_left_it):
     theirs, later = as_pr60_left_it
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
-    assert later["workloads"] == ["sala_l12_longctx_closed8"]  # PR 62
+    assert later["workloads"] == ["sala_l12_longctx_closed8",  # PR 62
+                                  "mistral16_longprompt_closed16"]  # PR 66
 
 
 def run(*command):

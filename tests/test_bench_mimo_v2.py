@@ -70,7 +70,8 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
                      "laguna_ep16_code_closed32",  # PR 52
                      "olmohybrid_l12_reason_closed64",  # PR 56
                      "granite4h_micro_chat_closed64",  # PR 60
-                     "sala_l12_longctx_closed8"]  # PR 62
+                     "sala_l12_longctx_closed8",  # PR 62
+                     "mistral16_longprompt_closed16"]  # PR 66
 
 
 def run(*command):

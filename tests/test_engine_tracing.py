@@ -517,8 +517,9 @@ def test_cache_read_pct_metric_reads_the_dispatch_span(tmp_path):
         "serve_tokens_per_s", "model step", "lower")
     # every serving cell with a full-extent cache: PR 46's four, the
     # Mistral-4 cell (PR 48), the Laguna cell (PR 52), the Olmo-Hybrid cell
-    # (PR 56) and the Granite cell (PR 60)
-    assert len(entry["workloads"]) == 8
+    # (PR 56), the Granite cell (PR 60) and the Mistral long-prompt cell
+    # (PR 66)
+    assert len(entry["workloads"]) == 9
     with open(os.path.join(hs.ROOT, "benchmarks", "layer_metrics",
                            name + ".json")) as f:
         spec = json.load(f)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models.layers import rope
+from ray_tpu.models.layers import rmsnorm, rope
 from ray_tpu.models.llama import (
     LlamaConfig,
     llama_apply,
@@ -14,6 +14,7 @@ from ray_tpu.models.llama import (
     llama_loss,
     llama_param_axes,
 )
+from ray_tpu.models.llama_decode import llama_init_cache, llama_prefill
 from ray_tpu.ops.decode_attention import decode_attention, extent_step
 
 
@@ -272,3 +273,97 @@ class TestDecodeAttention:
                 np.asarray(out),
                 dense_decode_attention(q, k, v, pos, 0, *own.values()),
                 atol=1e-5)
+
+
+def dense_prefill(params, tokens, cfg):
+    """The dense form ``llama_prefill`` had, in float32: every key-value head
+    repeated over its group and the whole ``[S, S]`` square scored under a
+    lower-triangular mask.  tokens ``[B, S]`` -> (logits ``[B, S, V]``, keys
+    and values ``[L, B, Hkv, S, D]`` as the cache holds them)."""
+    p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    s, groups = tokens.shape[1], cfg.n_head // cfg.n_kv_head
+    positions, seen = jnp.arange(s), jnp.tril(jnp.ones((s, s), bool))
+    x, ks, vs = p["wte"][tokens], [], []
+    for l in range(cfg.n_layer):
+        w = jax.tree.map(lambda a: a[l], p["blocks"])
+        y = rmsnorm(x, w["rms1"], cfg.rms_eps)
+        q = rope(jnp.einsum("bse,ehd->bshd", y, w["wq"]), positions,
+                 cfg.rope_theta)
+        k = rope(jnp.einsum("bse,ekd->bskd", y, w["wk"]), positions,
+                 cfg.rope_theta)
+        v = jnp.einsum("bse,ekd->bskd", y, w["wv"])
+        ks.append(k.transpose(0, 2, 1, 3))
+        vs.append(v.transpose(0, 2, 1, 3))
+        scores = jnp.einsum("bshd,bthd->bhst", q, jnp.repeat(
+            k, groups, 2)) / cfg.head_dim ** 0.5
+        probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+        o = jnp.einsum("bhst,bthd->bshd", probs, jnp.repeat(v, groups, 2))
+        x = x + jnp.einsum("bshd,hde->bse", o, w["wo"])
+        y = rmsnorm(x, w["rms2"], cfg.rms_eps)
+        x = x + (jax.nn.silu(y @ w["w_gate"]) * (y @ w["w_up"])) @ w["w_down"]
+    x = rmsnorm(x, p["rms_f"], cfg.rms_eps)
+    return jnp.einsum("bse,ve->bsv", x, p["lm_head"]), jnp.stack(
+        ks), jnp.stack(vs)
+
+
+class TestPrefillInTiles:
+    """``llama_prefill`` scores through ``layers.blocked_attention`` (tiles
+    of 8 x 8 here, 512 x 512 in the programs), four query heads over two
+    key-value heads that are never repeated."""
+
+    def _run(self, cfg, lengths, s, query_block=8, key_block=8):
+        params = llama_init(jax.random.PRNGKey(0), cfg)
+        lengths = np.asarray(lengths, np.int32)
+        tokens = np.where(
+            np.arange(s)[None] < lengths[:, None], np.asarray(jax.random.randint(
+                jax.random.PRNGKey(1), (len(lengths), s), 1, cfg.vocab_size)),
+            0)
+        logits, cache = jax.jit(lambda p, t, n, c: llama_prefill(
+            p, t, n, c, cfg, query_block=query_block, key_block=key_block))(
+                params, tokens, lengths, llama_init_cache(
+                    cfg, len(lengths), s + 3))
+        return params, tokens, lengths, np.asarray(logits), cache
+
+    # one tile, several, and blocks that do not divide the rung
+    @pytest.mark.parametrize("s,lengths", [
+        (8, (8, 3)), (24, (24, 11)), (29, (29, 13))])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_logits_and_the_written_cache_are_the_dense_forms(
+        self, dtype, s, lengths
+    ):
+        """The last prompt token's logits and the whole written rung, the
+        padding rows beyond a prompt too (every query block runs, so they
+        are attended as the dense form attends them), against the dense
+        float32 form on the same weights: to float32's rounding, and in
+        bfloat16 by the benchmark's measure (root mean square over the
+        spread, 3 %)."""
+        cfg = LlamaConfig.tiny(dtype=dtype)
+        params, tokens, lengths, logits, cache = self._run(cfg, lengths, s)
+        want, ks, vs = jax.jit(lambda p, t: dense_prefill(p, t, cfg))(
+            params, tokens)
+        pairs = [(logits, np.asarray(want)[np.arange(2), lengths - 1])]
+        pairs += [(np.asarray(cache[leaf][:, :, :, :s], np.float32),
+                   np.asarray(dense))
+                  for leaf, dense in (("k", ks), ("v", vs))]
+        for got, dense in pairs:
+            if dtype == "float32":
+                np.testing.assert_allclose(got, dense, rtol=1e-5, atol=1e-5)
+            else:
+                assert np.sqrt(((got - dense) ** 2).mean()) < 0.03 * dense.std()
+        assert float(jnp.abs(cache["k"][:, :, :, s:]).max()) == 0.0
+
+    @pytest.mark.parametrize("query_block,key_block", [(16, 8), (8, 16)])
+    def test_the_tiles_shape_does_not_change_the_answer(
+        self, query_block, key_block
+    ):
+        """A rung of 29 in tiles of 16 x 8 and of 8 x 16 against tiles of
+        8 x 8: the online softmax regroups float32 sums and nothing else."""
+        cfg = _cfg()
+        *_, logits, cache = self._run(cfg, (29, 13), 29)
+        *_, other, other_cache = self._run(
+            cfg, (29, 13), 29, query_block, key_block)
+        np.testing.assert_allclose(other, logits, rtol=1e-5, atol=1e-5)
+        for leaf in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(other_cache[leaf]), np.asarray(cache[leaf]),
+                rtol=1e-5, atol=1e-5)

@@ -13,8 +13,11 @@ and LongCat prefill / decode hashes are older: they came here from
 ``test_laguna.py`` and ``test_mistral4.py`` as they stood, first read on
 PR 45's tree; PR 53 re-read all six (the expert layers count their loop's
 chunks, the sigmoid router picks its scores by a select) and PR 54 the three
-decode steps and LongCat's prefill (``held_experts``' one-chunk form).  A PR
-that re-reads a hash says here which and why.
+decode steps and LongCat's prefill (``held_experts``' one-chunk form).
+PR 66 re-read ``("llama", "prefill")`` alone: ``llama_prefill`` scores
+through ``layers.blocked_attention`` (its key-value heads unrepeated, no
+``[S, S]`` scores or mask); every other hash stayed, so no other family's
+program moved.  A PR that re-reads a hash says here which and why.
 """
 import hashlib
 
@@ -40,7 +43,7 @@ HASHES = {
     ("gpt2", "decode_step"): "2e685fbff3f022dc498ea63415bfbdaab17f26a4",
     ("gpt2", "loss"): "8c5315f0e3be6c89eb7662c1c2b9b4b65d35d117",
     ("gpt2", "loss_grad"): "46842b81dc9cd3e20c0ba8b183125f276b1a7806",
-    ("llama", "prefill"): "4cee5c13b51ca8d61e2094c9fcf306df87cd1326",
+    ("llama", "prefill"): "5c8b025a0e0fe7ae06bf54802e26e0c771f22fda",
     ("llama", "decode_step"): "a69a320eee2427cc366939752d1a78b626d8cdb1",
     ("llama", "loss"): "6259211fe07b8e9fd911bea322ccdf0f474d47bf",
     ("longcat", "prefill"): "d5bc64ad9a4e93b9edd4ab6d292cdea55242bb76",

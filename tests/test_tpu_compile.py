@@ -1214,9 +1214,34 @@ def test_decode_step_copies_no_weight(cell_decode_step, name):
             w_gate=(0, 1, 2), w_up=(0, 1, 2), w_down=(0, 1, 2))
 
 
+@pytest.fixture(scope="module")
+def mistral_rung(cell, cell_decode_step, on_chip):
+    """``compiled(rung, as_they_lie=True)``: a rung of the Mistral cell's
+    ladder as the engine compiles it (``jit_prefill_one`` against the weights
+    as the decode step has them laid), or against the default layouts; ~3.5 s
+    each."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    fam, cfg, params, cache = cell("mistral7b_l16")
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+
+    @functools.cache
+    def compiled(rung, as_they_lie=True):
+        weights = params
+        if as_they_lie:
+            formats = cell_decode_step("mistral7b_l16")[0].input_formats[0][0]
+            weights = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+        tokens = on_chip(jax.ShapeDtypeStruct((rung,), jnp.int32))
+        return jit_prefill_one(fam, cfg).lower(
+            weights, cache, tokens, scalar, scalar).compile()
+
+    return compiled
+
+
 @pytest.mark.parametrize("rung", [256, 512, 1024, 2048])
 def test_prefill_rung_copies_no_more_weights_in_the_decode_steps_layouts(
-    cell, cell_decode_step, on_chip, rung
+    cell, cell_decode_step, mistral_rung, rung
 ):
     """Decode's choice of the weights' layouts is prefill's too (the engine
     compiles every rung, ``jit_prefill_one``, against the weights as the
@@ -1225,24 +1250,31 @@ def test_prefill_rung_copies_no_more_weights_in_the_decode_steps_layouts(
     on its way in) and holds no more weight-sized copies, in its ENTRY and
     in its scan's body, than compiled against the default layouts: one
     fewer (4 against 5)."""
-    from ray_tpu.llm.engine import jit_prefill_one
-
-    fam, cfg, params, cache = cell("mistral7b_l16")
+    params = cell("mistral7b_l16")[2]
     formats = cell_decode_step("mistral7b_l16")[0].input_formats[0][0]
-    tokens = on_chip(jax.ShapeDtypeStruct((rung,), jnp.int32))
-    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
-
-    def compiled(weights):
-        return jit_prefill_one(fam, cfg).lower(
-            weights, cache, tokens, scalar, scalar).compile()
 
     def copies(rung):
         return copied_weights(rung.as_text(), params, entry_only=False)
 
-    as_they_lie = compiled(jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
-        leaf.shape, leaf.dtype, sharding=fmt), params, formats))
+    as_they_lie = mistral_rung(rung)
     assert as_they_lie.input_formats[0][0] == formats
-    assert len(copies(as_they_lie)) <= len(copies(compiled(params)))
+    assert len(copies(as_they_lie)) <= len(
+        copies(mistral_rung(rung, as_they_lie=False)))
+
+
+def test_the_dense_familys_top_rung_scores_in_tiles(mistral_rung):
+    """The Mistral cell's 2048 rung through ``layers.blocked_attention``:
+    no array with two axes of the rung's length (the ``[1, 32, 2048, 2048]``
+    float32 scores, 537 MB a layer, that the dense form wrote, masked and
+    normalised in HBM; the compiler printed them ``f32[32,2048,2048]``), and
+    temporaries of 0.25 GB where that form reserved 1.36 GB, which was the
+    Mistral cells' ``memory_peak_bytes`` over their live buffers (PERF.md,
+    PR 66)."""
+    rung = mistral_rung(2048)
+    squares = [array for array, *_ in instructions(rung.as_text())
+               if re.search(r"\b2048,(\d+,)*2048\b", array.replace(" ", ""))]
+    assert not squares
+    assert rung.memory_analysis().temp_size_in_bytes < 0.4e9  # 0.253 GB
 
 
 # Cache leaves as the families shape them (positions on the axis before the
