@@ -51,6 +51,18 @@ from .llama_decode import (  # noqa: F401
     llama_init_cache,
     llama_prefill,
 )
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig,
+    kimi_linear_apply,
+    kimi_linear_init,
+    kimi_linear_loss,
+    kimi_linear_param_axes,
+)
+from .kimi_linear_decode import (  # noqa: F401
+    kimi_linear_decode_step,
+    kimi_linear_init_cache,
+    kimi_linear_prefill,
+)
 from .longcat import (  # noqa: F401
     LongcatConfig,
     longcat_apply,
@@ -339,5 +351,22 @@ register_model_family(
             minicpm_sala_prefill, with_counts=True),
         decode_step_counted=_functools.partial(
             minicpm_sala_decode_step, with_counts=True),
+    ),
+)
+register_model_family(
+    KimiLinearConfig,
+    ModelFamily(
+        name="kimi_linear",
+        init=kimi_linear_init,
+        apply=kimi_linear_apply,
+        loss=kimi_linear_loss,
+        param_axes=kimi_linear_param_axes,
+        init_cache=kimi_linear_init_cache,
+        prefill=kimi_linear_prefill,
+        decode_step=kimi_linear_decode_step,
+        prefill_counted=_functools.partial(
+            kimi_linear_prefill, with_counts=True),
+        decode_step_counted=_functools.partial(
+            kimi_linear_decode_step, with_counts=True),
     ),
 )

@@ -16,6 +16,8 @@ a config it is handed as numbers, or reads by field name.
   ``QUERY_BLOCK`` / ``KEY_BLOCK`` (Mistral-4's latent attention, Laguna,
   Olmo-Hybrid, Granite-4.0-H and MiniCPM-SALA run it), and ``ring_of``,
   what a prefill leaves in a window layer's ring;
+- ``conv_sequence``: the causal depthwise convolution in front of a
+  recurrent mixer, with the window a cache keeps of it;
 - a stack of layers as a program: ``layer_plan`` (a pattern's runs, what
   repeats folded) and ``scan_or_call``.
 
@@ -151,6 +153,24 @@ def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
                                       beta_slow)
     ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
     return ((1 - ramp) * f + ramp * f / factor).astype(np.float32)
+
+
+# ------------------------------------- a causal convolution over sequences
+def conv_sequence(x, lengths, conv_w, i):
+    """A causal depthwise convolution over whole sequences, and what a cache
+    keeps of it.  x ``[B, S, C]`` float32, lengths ``[B]``, ``conv_w [layers,
+    K, C]`` of which layer ``i``'s taps are read where they are used ->
+    (``out_t = sum_j w_j x_{t-K+1+j}`` ``[B, S, C]``, before any bias or
+    activation; the window ``[B, K-1, C]``: the last ``K-1`` TRUE inputs,
+    oldest first, zeros before a sequence's start, whatever lies at or
+    beyond ``length``).  The Mamba-2 mixer's and both delta rules'."""
+    k, s = conv_w.shape[1], x.shape[1]
+    idx = lengths[:, None] - (k - 1) + jnp.arange(k - 1)[None]  # [B, K-1]
+    window = jnp.where(
+        (idx >= 0)[..., None],
+        jnp.take_along_axis(x, jnp.maximum(idx, 0)[..., None], axis=1), 0.0)
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + s] * conv_w[i, j] for j in range(k)), window
 
 
 # ------------------------------------------------------ prefill attention

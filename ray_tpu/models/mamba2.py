@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ..ops.conv_update import conv_update
 from ..ops.mamba_update import mamba_update
-from .layers import matmul
+from .layers import conv_sequence, matmul
 
 
 def mamba_project(y, m, i: int, cfg):
@@ -122,17 +122,11 @@ def mamba_sequence(y, lengths, m, i: int, cfg):
     + 2GN)]`` = its last ``K-1`` TRUE inputs side by side, oldest first, the
     state ``[B, H, P, N]`` after position ``length - 1``).  Positions ``>=
     length`` change neither."""
-    k = cfg.conv_kernel
     s = y.shape[1]
     z, xbc, dt = mamba_project(y, m, i, cfg)
     dt = jnp.where(jnp.arange(s)[None, :, None] < lengths[:, None, None],
                    dt, 0.0)
-    idx = lengths[:, None] - (k - 1) + jnp.arange(k - 1)[None]  # [B, K-1]
-    conv_state = jnp.where(
-        (idx >= 0)[..., None],
-        jnp.take_along_axis(xbc, jnp.maximum(idx, 0)[..., None], axis=1), 0.0)
-    padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-    conv = sum(padded[:, j:j + s] * m["conv_w"][i, j] for j in range(k))
+    conv, conv_state = conv_sequence(xbc, lengths, m["conv_w"], i)
     x, b, c = split_xbc(jax.nn.silu(conv + m["conv_b"][i]), cfg)
     out, state = ssd_chunked(
         x, dt, -jnp.exp(m["a_log"][i]), b, c, m["d_skip"][i], cfg.chunk_size,

@@ -61,10 +61,10 @@ of all shares and the shared expert counted once add up to the whole layer.
 
 **Prefill** expands the latent to per-head keys and values once a layer and
 scores them in blocks (``blocked_attention``): ``QUERY_BLOCK`` queries
-against ``KEY_BLOCK`` keys at a time under an online softmax
-(``ops.decode_attention.attend_blocks``), key blocks wholly above the
-diagonal and query blocks wholly beyond the longest prompt not computed, so
-that no array grows with the square of the rung.
+against ``KEY_BLOCK`` keys at a time (``mla.mla_blocked``) under an online
+softmax (``ops.decode_attention.attend_blocks``), key blocks wholly above
+the diagonal and query blocks wholly beyond the longest prompt not
+computed, so that no array grows with the square of the rung.
 
 Not here: the vision encoder (the catalog gives the language model's
 configuration alone; the model's own logits on token ids do not depend on
@@ -93,9 +93,8 @@ from jax.sharding import PartitionSpec as P
 from .expert_share import (LOOP_COUNT_NAMES, held_choices, held_experts,
                            held_experts_dense, loop_counts,
                            runs_every_held_expert, softmax_route)
-from .layers import (add_counts, blocked_attention, ffn, matmul, rmsnorm,
-                     yarn_inv_freq)
-from .mla import mla_project
+from .layers import add_counts, ffn, matmul, rmsnorm, yarn_inv_freq
+from .mla import mla_blocked, mla_project
 
 ATTENTION = ("wq_a", "rms_q", "wq_b", "wkv_a", "rms_kv", "wk_b", "wv_b", "wo")
 COUNT_NAMES = ("routed_total", "routed_held", "experts_touched",
@@ -267,23 +266,6 @@ def mistral4_param_axes():
         "rms_f": P("norm"),
         "lm_head": P("vocab", "embed"),
     }
-
-
-# ---------------------------------------------------------------- attention
-def mla_blocked(q, latent, att, cfg: Mistral4Config, longest=None, **blocks):
-    """Latent attention of ``[B, S]`` tokens over themselves with per-head
-    keys and values expanded from the latent once (prefill, training) and
-    scored in blocks; ``[B, S, d]`` float32.  A head's key is ``[kn_h | kr]``:
-    the one rotary key is repeated a head, so that scores are ONE product
-    over ``dn+dr``."""
-    rkv = cfg.kv_lora_rank
-    ckv, kr = latent[..., :rkv], latent[..., rkv:]
-    kn = matmul("bsc,chd->bshd", ckv, att["wk_b"]).astype(q.dtype)
-    k = jnp.concatenate([kn, jnp.broadcast_to(
-        kr[:, :, None], kn.shape[:3] + kr.shape[-1:])], -1)
-    v = matmul("bsc,chd->bshd", ckv, att["wv_b"]).astype(q.dtype)
-    o = blocked_attention(q, k, v, longest, **blocks)
-    return matmul("bshd,hde->bse", o.astype(q.dtype), att["wo"])
 
 
 # ------------------------------------------------------------------ experts

@@ -1,19 +1,25 @@
-"""Latent attention (MLA), which LongCat-Flash and Mistral-Small-4 share.
+"""Latent attention (MLA), which LongCat-Flash, Mistral-Small-4 and
+Kimi-Linear share.
 
 The cache holds ``[ckv | kr]`` a token an attention: the key-value latent
-after its norm and ONE rotary key for all heads.  ``mla_project`` makes the
-roped queries and that latent; ``mla_expanded`` is the dense form over whole
-sequences (per-head keys and values expanded from the latent: LongCat's
-prefill and training; Mistral-4 tiles the same expansion through
-``layers.blocked_attention``, ``mistral4.mla_blocked``); ``mla_absorbed`` is
-the decode step's form, where ``Wkvb``'s key half is folded into the query
-and no per-head key or value ever exists.  The mathematics is in
-``longcat.py``'s docstring.
+after its norm and ONE key of ``dr`` for all heads beside it (rotary in
+LongCat and Mistral-4; Kimi-Linear's carries NO position, ``rotate=False``:
+its delta-rule layers do).  ``mla_project`` makes the queries and that
+latent; ``mla_expanded`` is the dense form over whole sequences (per-head
+keys and values expanded from the latent: LongCat's prefill and training);
+``mla_blocked`` tiles the same expansion through ``layers.blocked_attention``
+(Mistral-4's and Kimi-Linear's prefill); ``mla_absorbed`` is the decode
+step's form, where ``Wkvb``'s key half is folded into the query and no
+per-head key or value ever exists.  The mathematics is in ``longcat.py``'s
+docstring.
 
 ``cfg`` is any config with the fields read here BY NAME: ``kv_lora_rank``,
-``qk_nope_head_dim``, ``q_lora_rank``, ``d_model``, ``rope_theta``,
-``rms_eps``.  What a family does otherwise it says by arguments that are
-static or absent.  This module imports no family (``layers.py``).
+``qk_nope_head_dim``, ``rms_eps``, and where they are used ``q_lora_rank``,
+``d_model`` (the latent scales), ``rope_theta`` (a rotation).  What a family
+does otherwise it says by arguments that are static or absent, or by the
+weights it hands in: a query projected by ONE matrix (``wq [d, H, dn+dr]``:
+no ``wq_a``, ``rms_q``, ``wq_b``; Kimi-Linear's ``q_lora_rank`` null).  This
+module imports no family (``layers.py``).
 """
 
 from __future__ import annotations
@@ -22,11 +28,11 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import attend_live_blocks, extent_step
-from .layers import matmul, rmsnorm, rope
+from .layers import blocked_attention, matmul, rmsnorm, rope
 
 
 def mla_project(y, att, positions, cfg, *, latent_scales: bool = True,
-                inv_freq=None, q_factor=None):
+                inv_freq=None, q_factor=None, rotate: bool = True):
     """y ``[B, S, d]`` -> roped queries ``[B, S, H, dn+dr]`` and the latent
     ``[ckv | kr]`` ``[B, S, rkv+dr]`` that the cache holds.  ``cfg``: any
     config with the latent attention's sizes (``LongcatConfig``,
@@ -36,25 +42,52 @@ def mla_project(y, att, positions, cfg, *, latent_scales: bool = True,
     ``latent_scales=False`` (neither scale), ``inv_freq`` ``[dr/2]`` (its own
     rotary frequencies), ``q_factor`` (float32, broadcast against ``[B, S, H,
     dn+dr]``: what multiplies the whole query before it is rounded, a
-    softmax scale that depends on the query's position)."""
+    softmax scale that depends on the query's position); ``rotate=False``
+    (no position anywhere: the ``dr`` columns of query and key are left as
+    projected and ``positions`` is not read).  A query with no bottleneck is
+    said by the weights: ``att["wq"] [d, H, dn+dr]`` in place of ``wq_a``,
+    ``rms_q`` and ``wq_b``."""
     rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
-    cq = rmsnorm(matmul("bse,er->bsr", y, att["wq_a"]), att["rms_q"],
-                  cfg.rms_eps).astype(y.dtype)
-    q = matmul("bsr,rhd->bshd", cq, att["wq_b"])
+    if "wq" in att:  # one matrix, no query latent
+        q = matmul("bse,ehd->bshd", y, att["wq"])
+    else:
+        cq = rmsnorm(matmul("bse,er->bsr", y, att["wq_a"]), att["rms_q"],
+                      cfg.rms_eps).astype(y.dtype)
+        q = matmul("bsr,rhd->bshd", cq, att["wq_b"])
     if latent_scales:
         q = q * (cfg.d_model / cfg.q_lora_rank) ** 0.5
     if q_factor is not None:
         q = q * q_factor
-    q = jnp.concatenate(
-        [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta,
-                           inv_freq)], -1)
+    if rotate:
+        q = jnp.concatenate(
+            [q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta,
+                               inv_freq)], -1)
     kv = matmul("bse,er->bsr", y, att["wkv_a"])
     ckv = rmsnorm(kv[..., :rkv], att["rms_kv"], cfg.rms_eps)
     if latent_scales:
         ckv = ckv * (cfg.d_model / rkv) ** 0.5
-    kr = rope(kv[..., None, rkv:], positions, cfg.rope_theta,
-              inv_freq)[..., 0, :]
+    kr = kv[..., rkv:]
+    if rotate:
+        kr = rope(kv[..., None, rkv:], positions, cfg.rope_theta,
+                  inv_freq)[..., 0, :]
     return q.astype(y.dtype), jnp.concatenate([ckv, kr], -1).astype(y.dtype)
+
+
+def mla_blocked(q, latent, att, cfg, longest=None, **blocks):
+    """Latent attention of ``[B, S]`` tokens over themselves with per-head
+    keys and values expanded from the latent once (prefill, training) and
+    scored in blocks; ``[B, S, d]`` float32.  A head's key is ``[kn_h | kr]``:
+    the one shared key is repeated a head, so that scores are ONE product
+    over ``dn+dr``.  ``att`` holds ``Wkvb`` as two leaves (``wk_b``,
+    ``wv_b``)."""
+    rkv = cfg.kv_lora_rank
+    ckv, kr = latent[..., :rkv], latent[..., rkv:]
+    kn = matmul("bsc,chd->bshd", ckv, att["wk_b"]).astype(q.dtype)
+    k = jnp.concatenate([kn, jnp.broadcast_to(
+        kr[:, :, None], kn.shape[:3] + kr.shape[-1:])], -1)
+    v = matmul("bsc,chd->bshd", ckv, att["wv_b"]).astype(q.dtype)
+    o = blocked_attention(q, k, v, longest, **blocks)
+    return matmul("bshd,hde->bse", o.astype(q.dtype), att["wo"])
 
 
 def mla_expanded(q, latent, att, cfg):

@@ -23,7 +23,8 @@ S_{t-1}``; ``S_t = S' + k_t (x) beta_t (v_t - S'^T k_t)``; ``o_t = S_t^T q_t``.
 ``y = RMSNorm_dv(o) * w * silu(z)`` a head (the norm BEFORE the gate), ``out
 = y Wo``.
 
-A sequence runs the chunked form (``delta_chunked``).  With ``gamma_i`` the
+A sequence runs the chunked form (``delta_rule.delta_chunked``, which
+Kimi-Linear's vector gate shares).  With ``gamma_i`` the
 running product of ``alpha`` inside a chunk of ``C``: ``A = strictly_lower(
 diag(beta) (K K^T * gamma_i / gamma_j))``, ``[W | U] = (I + A)^-1 diag(beta)
 [K * gamma | V]`` (one triangular solve a head a chunk: the WY / UT
@@ -61,14 +62,15 @@ prefill scanned; rows a decode step served) and ``delta_chunk_positions``
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .layers import blocked_attention, ffn, matmul, rmsnorm, scan_or_call
+from .delta_rule import delta_chunked, pack_state, unpack_state  # noqa: F401
+from .layers import (blocked_attention, conv_sequence, ffn, matmul, rmsnorm,
+                     scan_or_call)
 
 PUBLISHED_PATTERN = "LLLF" * 8
 # a kind of layer -> its stack under params["blocks"]
@@ -123,8 +125,9 @@ class OlmoHybridConfig:
     @property
     def state_pack(self) -> int:
         """Heads whose states share a row of the cache's ``state`` leaf
-        (``pack_state``): as many as make the row's lanes a multiple of the
-        TPU's 128 (two heads of 192), one where the heads do not divide."""
+        (``delta_rule.pack_state``): as many as make the row's lanes a
+        multiple of the TPU's 128 (two heads of 192), one where the heads do
+        not divide."""
         pack = 128 // math.gcd(self.linear_value_head_dim, 128)
         return pack if self.linear_num_heads % pack == 0 else 1
 
@@ -269,108 +272,21 @@ def delta_output(o, z, m, i: int, cfg: OlmoHybridConfig):
                   m["w_o"][i])
 
 
-def delta_chunked(q, k, v, g, beta, chunk: int):
-    """The recurrence ``S_t = alpha_t S_{t-1} + k_t (x) beta_t (v_t - alpha_t
-    S_{t-1}^T k_t)``, ``o_t = S_t^T q_t`` from ``S = 0``, in chunks.  q, k ``[B,
-    S, H, dk]`` (as ``split_heads`` gives them), v ``[B, S, H, dv]``, g =
-    ``log alpha`` and beta ``[B, S, H]`` (both 0 = the position is left out of
-    the state), all float32 -> o ``[B, S, H, dv]``, the last state ``[B, H,
-    dk, dv]``, float32.  Float32 THROUGHOUT, its products at ``HIGHEST``
-    precision (``rule``): they are under a hundredth of a prefill's
-    operations and cost it 0.2 ms a layer at 512 rows on the v5e (1.68
-    against 1.50 ms: PERF.md, PR 56), and with operands rounded to bfloat16
-    the rule alone is off the recurrence by 0.45 % of its output, four
-    times a projection's rounding (``V' = U - W S`` and ``O = Q S + ...`` are
-    differences of larger terms): 2.56 % against 2.09 at the logits of
-    twelve layers."""
-    bsz, s, h, dk = q.shape
-    pad = -s % chunk
-    if pad:  # beta = g = 0 there: the state passes through
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (q, k, v, g, beta))
-    n = (s + pad) // chunk
-    rule = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
-
-    def chunks(a):  # [B, S, H, x] -> [B, n, H, C, x]
-        return a.reshape(bsz, n, chunk, h, -1).transpose(0, 1, 3, 2, 4)
-
-    q, k, v = chunks(q), chunks(k), chunks(v)
-    g, beta = chunks(g)[..., 0], chunks(beta)  # [B, n, H, C], [.., C, 1]
-    # log gamma_i: the decay from the chunk's start through position i
-    acum = jnp.cumsum(g, axis=-1)
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    # gamma_i / gamma_j where i >= j, 0 above the diagonal
-    decay = jnp.exp(jnp.where(
-        lower, acum[..., :, None] - acum[..., None, :], -jnp.inf))
-    a = jnp.where(jnp.tril(lower, -1), beta * decay * rule(
-        "bnhik,bnhjk->bnhij", k, k), 0.0)
-    # [W | U] = (I + A)^-1 diag(beta) [K * gamma | V]: the diagonal of ones
-    # is the solve's ``unit_diagonal``
-    wu = jax.lax.linalg.triangular_solve(
-        a, beta * jnp.concatenate([k * jnp.exp(acum)[..., None], v], -1),
-        left_side=True, lower=True, unit_diagonal=True)
-    inside = decay * rule("bnhik,bnhjk->bnhij", q, k)
-    q_in = q * jnp.exp(acum)[..., None]
-    k_out = k * jnp.exp(acum[..., -1:] - acum)[..., None]
-    through = jnp.exp(acum[..., -1])  # [B, n, H]: a whole chunk's decay
-
-    def next_chunk(state, inp):
-        w, u, q_c, inside_c, k_c, keep = inp
-        v_new = u - rule("bhck,bhkv->bhcv", w, state)
-        o = (rule("bhck,bhkv->bhcv", q_c, state)
-             + rule("bhij,bhjv->bhiv", inside_c, v_new))
-        state = keep[..., None, None] * state + rule(
-            "bhck,bhcv->bhkv", k_c, v_new)
-        return state, o
-
-    last, o = jax.lax.scan(
-        next_chunk, jnp.zeros((bsz, h, dk, v.shape[-1]), jnp.float32),
-        tuple(x.swapaxes(0, 1) for x in (
-            wu[..., :dk], wu[..., dk:], q_in, inside, k_out, through)))
-    o = o.transpose(1, 0, 3, 2, 4).reshape(bsz, n * chunk, h, -1)
-    return o[:, :s], last
-
-
-def pack_state(state, cfg: OlmoHybridConfig):
-    """``[B, H, dk, dv]`` -> the cache's ``[B, H / p, dk, p dv]``: ``p =
-    state_pack`` heads side by side on the lanes, so that a row is a whole
-    number of the TPU's 128 (a ``[96, 192]`` float32 matrix alone is padded
-    to ``[96, 256]``: a third more to hold, read and write every step)."""
-    b, h, dk, dv = state.shape
-    p = cfg.state_pack
-    return state.reshape(b, h // p, p, dk, dv).swapaxes(2, 3).reshape(
-        b, h // p, dk, p * dv)
-
-
-def unpack_state(packed, cfg: OlmoHybridConfig):
-    """``pack_state``'s inverse."""
-    b, rows, dk, lanes = packed.shape
-    p = cfg.state_pack
-    return packed.reshape(b, rows, dk, p, lanes // p).swapaxes(2, 3).reshape(
-        b, rows * p, dk, lanes // p)
-
-
 def delta_sequence(y, lengths, m, i: int, cfg: OlmoHybridConfig):
     """The gated delta net over whole sequences.  y ``[B, S, d]``, lengths
     ``[B]`` -> (``[B, S, d]`` float32, the convolution's state ``[B, (K-1)(2 H
     dk + H dv)]`` = its last ``K-1`` TRUE inputs side by side, oldest first,
     the state after position ``length - 1``, packed).  Positions ``>=
     length`` change neither."""
-    k_taps, s = cfg.conv_kernel, y.shape[1]
     qkv, z, g, beta = delta_project(y, m, i, cfg)
-    live = jnp.arange(s)[None, :, None] < lengths[:, None, None]
+    live = jnp.arange(y.shape[1])[None, :, None] < lengths[:, None, None]
     g, beta = jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0)
-    idx = lengths[:, None] - (k_taps - 1) + jnp.arange(k_taps - 1)[None]
-    conv_state = jnp.where(
-        (idx >= 0)[..., None],
-        jnp.take_along_axis(qkv, jnp.maximum(idx, 0)[..., None], axis=1), 0.0)
-    padded = jnp.pad(qkv, ((0, 0), (k_taps - 1, 0), (0, 0)))
-    conv = sum(padded[:, j:j + s] * m["conv_w"][i, j] for j in range(k_taps))
+    conv, conv_state = conv_sequence(qkv, lengths, m["conv_w"], i)
     q, k, v = split_heads(jax.nn.silu(conv), cfg)
     o, state = delta_chunked(q, k, v, g, beta, cfg.chunk_size)
     return (delta_output(o, z, m, i, cfg),
-            conv_state.reshape(y.shape[0], -1), pack_state(state, cfg))
+            conv_state.reshape(y.shape[0], -1),
+            pack_state(state, cfg.state_pack))
 
 
 # ----------------------------------------------------------- full attention

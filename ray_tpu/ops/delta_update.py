@@ -1,13 +1,27 @@
 """The gated delta rule's one-token update: ONE pass over the state.
 
 A head's state is a matrix ``S [dk, dv]`` float32, ``p`` heads side by side
-on the lanes of a packed row ``[dk, p dv]`` (``models/olmo_hybrid.pack_state``).
+on the lanes of a packed row ``[dk, p dv]`` (``models/delta_rule.pack_state``).
 A decode step needs, a head, with ``q, k [dk]``, ``v [dv]`` and the scalars
 ``alpha`` (the decay) and ``beta``::
 
     delta = beta (v - alpha S^T k)
     o     = alpha S^T q + (k . q) delta        (= S_new^T q, without S_new)
     S_new = alpha S + k (x) delta
+
+Where the decay is a VECTOR over the key channels (``alpha [dk]``: Kimi Delta
+Attention; a scalar a head is Olmo-Hybrid's), it multiplies the state's ROWS,
+``S' = Diag(alpha) S``::
+
+    delta = beta (v - S'^T k)
+    o     = S'^T q + (k . q) delta
+    S_new = S' + k (x) delta
+
+and is a COLUMN beside ``q`` and ``k`` where the scalar rode the lanes beside
+``v``.  The gate's shape is static (``alpha``'s last axis: 1 or ``dk``) and
+chooses between two branches of ONE kernel function, each of which lowers
+as if the other were not there: a scalar gate's kernel is the kernel it was
+(PERF.md, PR 67).
 
 ``S^T k`` must be finished before ``delta`` is known and ``S_new`` needs
 ``delta``: XLA runs a reduce fusion over ``S`` and then an elementwise one
@@ -21,9 +35,11 @@ what came out, would each be one crossing more).
 
 Everything small comes in beside the state without ever taking its shape:
 ``q`` and ``k`` as COLUMNS (``[slots, dk, 2 H]``: ``dk`` down the sublanes as
-the state's rows are, a head a lane), spread over their head's lanes inside
+the state's rows are, a head a lane; ``[slots, dk, 3 H]`` with a vector
+gate's ``alpha`` after them), spread over their head's lanes inside
 the kernel; ``v``, ``alpha``, ``beta`` and ``k . q`` on the lanes of their
-head (``[slots, 4, H / p, p dv]``), spread down the sublanes.  All float32,
+head (``[slots, 4, H / p, p dv]``; three without the scalar ``alpha``),
+spread down the sublanes.  All float32,
 on the vector unit: no product is rounded to bfloat16.
 
 ``delta_update`` is the one way in.  On a TPU whose tiles the leaf fills
@@ -64,16 +80,30 @@ def over_lanes(a, p: int, dv: int):
     return jnp.broadcast_to(out, (b, h // p, n, p * dv))
 
 
+def vector_gate(alpha, dk: int) -> bool:
+    """The decay is a vector over a head's ``dk`` key channels (``[B, H,
+    dk]``) and not a scalar a head (``[B, H, 1]``)."""
+    return alpha.shape[-1] == dk and dk > 1
+
+
 def delta_update_xla(leaf, at: int, q, k, v, alpha, beta):
     """The update in plain XLA: a reduce pass over layer ``at``'s state for
     ``S^T k`` and ``S^T q`` and an elementwise one that writes ``alpha S + k
     (x) delta`` into the leaf.  Same arguments and results as
     ``delta_update``."""
     s = leaf[at].astype(jnp.float32)
-    b, rows, _, lanes = s.shape
+    b, rows, dk, lanes = s.shape
     h, dv = v.shape[1:]
     p = h // rows
     k_lanes = over_lanes(k, p, dv)
+    if vector_gate(alpha, dk):  # the decay multiplies the state's rows
+        s = over_lanes(alpha, p, dv) * s  # S' = Diag(alpha) S
+        sk = (s * k_lanes).sum(2).reshape(b, h, dv)
+        sq = (s * over_lanes(q, p, dv)).sum(2).reshape(b, h, dv)
+        delta = beta * (v - sk)
+        o = sq + (k * q).sum(-1, keepdims=True) * delta
+        new = s + k_lanes * delta.reshape(b, rows, 1, lanes)
+        return o, leaf.at[at].set(new.astype(leaf.dtype))
     # S^T k and S^T q of the state as it came, a head: [B, H, dv]
     sk = (s * k_lanes).sum(2).reshape(b, h, dv)
     sq = (s * over_lanes(q, p, dv)).sum(2).reshape(b, h, dv)
@@ -84,13 +114,16 @@ def delta_update_xla(leaf, at: int, q, k, v, alpha, beta):
     return o, leaf.at[at].set(new.astype(leaf.dtype))
 
 
-def _kernel(at_ref, s_ref, qk_ref, row_ref, s_out, o_ref, *, p: int):
+def _kernel(at_ref, s_ref, qk_ref, row_ref, s_out, o_ref, *, p: int,
+            gate_columns: bool = False):
     """One grid step: ``slots`` slots of one layer (``at_ref``: which, read by
     the blocks' index maps alone).  s_ref / s_out ``[slots, R, dk, W]`` (the
     same bytes), qk_ref ``[slots, dk, 2 H]`` (q's heads, then k's), row_ref
     ``[slots, 4, R, W]`` (v, alpha, beta, k . q), o_ref ``[slots, R, W]``.  A
     row ``[dk, W]`` (36 registers at the published widths) is read once and
-    held from the two reductions through the update."""
+    held from the two reductions through the update.  ``gate_columns``: the
+    decay is a vector a head, the third group of ``qk_ref``'s columns
+    (``[slots, dk, 3 H]``), and ``row_ref`` holds v, beta and k . q alone."""
     del at_ref
     slots, rows, dk, width = s_ref.shape
     dv = width // p
@@ -111,6 +144,15 @@ def _kernel(at_ref, s_ref, qk_ref, row_ref, s_out, o_ref, *, p: int):
         for r in range(rows):  # static: a head's column is a static lane
             s = s_ref[b, r]
             k = over_lanes((rows + r) * p)
+            if gate_columns:  # S' = Diag(alpha) S, then the rule on S'
+                s = over_lanes((2 * rows + r) * p) * s
+                sk = (s * k).sum(0, keepdims=True)
+                sq = (s * over_lanes(r * p)).sum(0, keepdims=True)
+                v, beta, kq = (row_ref[b, n, r:r + 1] for n in range(3))
+                delta = beta * (v - sk)
+                o_ref[b, r:r + 1] = sq + kq * delta
+                s_out[b, r] = s + k * delta
+                continue
             sk = (s * k).sum(0, keepdims=True)
             sq = (s * over_lanes(r * p)).sum(0, keepdims=True)
             v, alpha, beta, kq = (row_ref[b, n, r:r + 1] for n in range(4))
@@ -122,8 +164,10 @@ def _kernel(at_ref, s_ref, qk_ref, row_ref, s_out, o_ref, *, p: int):
     jax.lax.fori_loop(0, slots, one_slot, None)
 
 
-@functools.partial(jax.jit, static_argnames=("p", "slots", "interpret"))
-def _call(at, leaf, qk, row, *, p: int, slots: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=(
+    "p", "slots", "interpret", "gate_columns"))
+def _call(at, leaf, qk, row, *, p: int, slots: int, interpret: bool,
+          gate_columns: bool = False):
     """The kernel over layer ``at [1]`` (int32) of ``leaf``.  The layer is an
     OPERAND, prefetched for the index maps, and this a jitted function of its
     own, so that a decode step's nine linear layers are nine calls of ONE
@@ -138,7 +182,7 @@ def _call(at, leaf, qk, row, *, p: int, slots: int, interpret: bool):
                          lambda s, at: (at[0], s, 0, 0, 0))
     block = slots * rows * dk * width * 4
     return pl.pallas_call(
-        functools.partial(_kernel, p=p),
+        functools.partial(_kernel, p=p, gate_columns=gate_columns),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b // slots,),
@@ -168,7 +212,8 @@ def delta_update(leaf, at: int, q, k, v, alpha, beta, *,
                  force_pallas: bool = False, slots: int | None = None):
     """One token a slot through the delta rule of layer ``at`` (static) of
     the stacked state ``leaf [layers, B, H / p, dk, p dv]`` float32.  q, k
-    ``[B, H, dk]``, v ``[B, H, dv]``, alpha, beta ``[B, H, 1]``, float32 ->
+    ``[B, H, dk]``, v ``[B, H, dv]``, beta ``[B, H, 1]``, alpha ``[B, H, 1]`` (a
+    scalar gate) or ``[B, H, dk]`` (a vector gate, ``dk > 1``), float32 ->
     (``o [B, H, dv]``, the leaf with layer ``at`` updated: the same buffer
     where the caller donated it; the other layers are not touched).
 
@@ -194,11 +239,13 @@ def delta_update(leaf, at: int, q, k, v, alpha, beta, *,
         raise ValueError(f"delta_update: {b} slots are not a multiple of "
                          f"the {slots} a grid step carries")
     # columns: dk down the sublanes like the state's rows, a head a lane
-    qk = jnp.concatenate([q, k], axis=1).swapaxes(1, 2)  # [B, dk, 2 H]
-    kq = (k * q).sum(-1, keepdims=True)
-    row = jnp.stack([  # [B, 4, R, W]: each on the lanes of its head
+    columns = vector_gate(alpha, dk)
+    qk = jnp.concatenate([q, k] + [alpha] * columns, axis=1).swapaxes(1, 2)
+    kq = (k * q).sum(-1, keepdims=True)  # qk: [B, dk, 2 H] or [B, dk, 3 H]
+    row = jnp.stack([  # [B, 4 or 3, R, W]: each on the lanes of its head
         jnp.broadcast_to(a, (b, h, dv)).reshape(b, rows, width)
-        for a in (v, alpha, beta, kq)], axis=1)
+        for a in ((v, beta, kq) if columns else (v, alpha, beta, kq))],
+        axis=1)
     new, o = _call(jnp.asarray([at], jnp.int32), leaf, qk, row, p=p,
-                   slots=slots, interpret=not on_tpu)
+                   slots=slots, interpret=not on_tpu, gate_columns=columns)
     return o.reshape(b, h, dv), new

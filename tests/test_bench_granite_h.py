@@ -51,7 +51,8 @@ def test_the_configuration_is_the_source_with_nothing_cut(  # noqa: F811
     theirs, later = as_pr60_left_it
     theirs.test_the_configuration_is_the_source_with_nothing_cut(
         theirs.load(theirs.HERE, "configs", "granite4h_micro.json"))
-    assert later["configs"] == ["minicpm_sala_l12"]  # PR 62
+    assert later["configs"] == ["minicpm_sala_l12",  # PR 62
+                                "kimi_linear_l21_ep16"]  # PR 67
 
 
 def test_the_cell_lists_itself_where_its_metrics_are_true(  # noqa: F811
@@ -59,7 +60,9 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(  # noqa: F811
     theirs, later = as_pr60_left_it
     theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
     assert later["workloads"] == ["sala_l12_longctx_closed8",  # PR 62
-                                  "mistral16_longprompt_closed16"]  # PR 66
+                                  "mistral16_longprompt_closed16",  # PR 66
+                                  "kimilinear_ep16_rollout_closed64",
+                                  "mistral16_decode_closed16"]  # PR 67
 
 
 def run(*command):

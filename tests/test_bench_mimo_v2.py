@@ -65,13 +65,16 @@ def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
         "lightning_ms.serve", "select_ms.serve",
         "prefill_lightning_ms.serve_rate", "prefill_sparse_ms.serve_rate",
         "sparse_read_pct.serve", "lightning_chunk_fill_pct.serve",
-        "sala_decode_roofline.serve", "sala_unscoped_pct.serve"]  # PR 62
+        "sala_decode_roofline.serve", "sala_unscoped_pct.serve",  # PR 62
+        "kimi_decode_roofline.serve", "kda_update_roofline.serve"]  # PR 67
     assert cells == ["mistral4_ep8_longdoc_closed32",  # PR 48
                      "laguna_ep16_code_closed32",  # PR 52
                      "olmohybrid_l12_reason_closed64",  # PR 56
                      "granite4h_micro_chat_closed64",  # PR 60
                      "sala_l12_longctx_closed8",  # PR 62
-                     "mistral16_longprompt_closed16"]  # PR 66
+                     "mistral16_longprompt_closed16",  # PR 66
+                     "kimilinear_ep16_rollout_closed64",
+                     "mistral16_decode_closed16"]  # PR 67
 
 
 def run(*command):

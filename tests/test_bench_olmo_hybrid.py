@@ -12,6 +12,41 @@ import sys  # noqa: E402
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def test_the_cell_lists_itself_where_its_metrics_are_true(monkeypatch):
+    """The benchmark's case of this name (star-imported above, shadowed
+    here) holds PR 56's two metrics to list this cell ALONE, which no later
+    cell with a delta rule of its own can keep (``delta_chunk_fill_pct.serve``
+    reads Kimi-Linear's scan too: PR 67), and the benchmark's files are
+    add-only, its tests among them.  The same case over the cells as far as
+    PR 56 wrote them, and which later cells joined its two metrics by
+    name."""
+    import benchmarks.tests.test_bench_olmo_hybrid as theirs
+
+    joined = {}
+
+    def load_as_pr56_left_it(*path):
+        bench = theirs_load(*path)
+        if path[-1] == "BENCHMARK.json":
+            names = [w["name"] for w in bench["workloads"]]
+            later = names[names.index(theirs.CELL) + 1:]
+            for metric in bench["end_to_end"] + bench["per_layer"]:
+                if "workloads" in metric:  # a later cell's name, appended
+                    if metric["name"] in theirs.NEW_METRICS:
+                        joined[metric["name"]] = [
+                            w for w in metric["workloads"] if w in later]
+                    metric["workloads"] = [
+                        w for w in metric["workloads"] if w not in later]
+        return bench
+
+    theirs_load = theirs.load
+    monkeypatch.setattr(theirs, "load", load_as_pr56_left_it)
+    theirs.test_the_cell_lists_itself_where_its_metrics_are_true()
+    assert joined == {
+        "delta_decode_roofline.serve": [],
+        "delta_chunk_fill_pct.serve": [
+            "kimilinear_ep16_rollout_closed64"]}  # PR 67
+
+
 def run(*command):
     out = subprocess.run(
         [sys.executable, *command], capture_output=True, text=True,

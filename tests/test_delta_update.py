@@ -4,7 +4,10 @@ CPU against the XLA formulation it replaces on a TPU (``delta_update_xla``)
 and against the plain reference's recurrence
 (``benchmarks/reference/olmo_hybrid_ref.py`` ``gated_delta_rule``), at tiny
 widths.  The kernel sums a matrix's rows in another order than XLA's reduce:
-equal to float32 rounding, not bit for bit.
+equal to float32 rounding, not bit for bit.  The same with the decay a
+VECTOR a head (``alpha [B, H, dk]``: Kimi Delta Attention's gate, a column
+beside ``q`` and ``k``), against
+``benchmarks/reference/kimi_linear_ref.py`` ``kda_recurrence``.
 """
 
 import dataclasses
@@ -15,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.reference import kimi_linear_ref as kimi_ref
 from benchmarks.reference import olmo_hybrid_ref as ref
 from ray_tpu.models import OlmoHybridConfig, olmo_hybrid, olmo_hybrid_decode
 from ray_tpu.ops import delta_update as du
@@ -85,8 +89,9 @@ def recurrence(w: Widths, leaf, at, q, k, v, alpha, beta):
     cfg = pack_of(w)
     o, last = ref.gated_delta_rule(
         q[:, None], k[:, None], v[:, None], jnp.log(alpha[:, None, :, 0]),
-        beta[:, None, :, 0], olmo_hybrid.unpack_state(leaf[at], cfg))
-    return o[:, 0], olmo_hybrid.pack_state(last, cfg)
+        beta[:, None, :, 0],
+        olmo_hybrid.unpack_state(leaf[at], cfg.state_pack))
+    return o[:, 0], olmo_hybrid.pack_state(last, cfg.state_pack)
 
 
 def close(got, want, tol=TOL):
@@ -215,3 +220,65 @@ def test_one_layers_state_goes_through_the_same_kernel_as_the_stack(
         close(got, ideal)
     np.testing.assert_array_equal(leaf[0], state[0])
     np.testing.assert_array_equal(new_conv[0], conv[0])
+
+
+# ------------------------------------------------------- a vector gate
+def vector_gate(w: Widths, slots=4, seed=0, low=1e-3, high=1.0 - 1e-6):
+    """``alpha [slots, h, dk]``: every head holds channels that forget in a
+    token (``low``) beside channels that never do (``high``)."""
+    rng = np.random.default_rng(seed)
+    alpha = np.exp(rng.uniform(np.log(low), 0.0, (slots, w.h, w.dk)))
+    alpha[..., 0], alpha[..., 1] = low, high
+    return jnp.asarray(alpha, jnp.float32)
+
+
+def vector_recurrence(w: Widths, leaf, at, q, k, v, alpha, beta):
+    """The Kimi reference's one step from layer ``at``'s state."""
+    o, last = kimi_ref.kda_recurrence(
+        q[:, None], k[:, None], v[:, None], jnp.log(alpha)[:, None],
+        beta[:, None, :, 0], olmo_hybrid.unpack_state(leaf[at], w.p))
+    return o[:, 0], olmo_hybrid.pack_state(last, w.p)
+
+
+# one head a tile (Kimi-Linear's shape at toy widths), two heads a tile,
+# two heads of 192 over three tiles, one head over two tiles
+@pytest.mark.parametrize("slots", [1, 2], ids=["one_slot_a_step", "two"])
+@pytest.mark.parametrize("w", [Widths(1, 3, 16, 128), *WIDTHS], ids=str)
+def test_vector_gate_kernel_is_the_xla_formulation_and_the_recurrence(
+        w, slots):
+    leaf, q, k, v, _, beta = draw(w, seed=w.dk + slots)
+    alpha = vector_gate(w, seed=slots)
+    o, new = kernel(leaf, 0, q, k, v, alpha, beta, slots=slots)
+    want_o, want_new = du.delta_update_xla(leaf, 0, q, k, v, alpha, beta)
+    close(o, want_o)
+    close(new, want_new)
+    ref_o, ref_new = vector_recurrence(w, leaf, 0, q, k, v, alpha, beta)
+    close(o, ref_o)
+    close(new[0], ref_new)
+    assert o.shape == (4, w.h, w.dv) and new.dtype == jnp.float32
+    # a scalar gate is another update: the head's mean decay is not it
+    mean = alpha.mean(-1, keepdims=True)
+    scalar_o, _ = du.delta_update_xla(leaf, 0, q, k, v, mean, beta)
+    assert float(jnp.abs(scalar_o - ref_o).max()) > 1e-2
+    # and a vector gate whose channels agree IS the scalar gate's update
+    same = jnp.broadcast_to(mean, alpha.shape)
+    for got, want in zip(kernel(leaf, 0, q, k, v, same, beta, slots=slots),
+                         kernel(leaf, 0, q, k, v, mean, beta, slots=slots)):
+        close(got, want)
+
+
+@pytest.mark.parametrize("at", [0, 1, 2])
+def test_a_vector_gate_call_on_one_layer_leaves_the_others_bit_identical(at):
+    w = Widths(1, 3, 16, 128)
+    leaf, q, k, v, _, beta = draw(w, layers=3, seed=at)
+    alpha = vector_gate(w, seed=at)
+    o, new = kernel(leaf, at, q, k, v, alpha, beta)
+    want_o, want_new = du.delta_update_xla(leaf, at, q, k, v, alpha, beta)
+    close(o, want_o)
+    close(new[at], want_new[at])
+    for other in set(range(3)) - {at}:  # bit for bit
+        np.testing.assert_array_equal(new[other], leaf[other])
+    # off a TPU the unforced way is the XLA formulation, bit for bit
+    for got, want in zip(du.delta_update(leaf, at, q, k, v, alpha, beta),
+                         (want_o, want_new)):
+        np.testing.assert_array_equal(got, want)

@@ -517,9 +517,10 @@ def test_cache_read_pct_metric_reads_the_dispatch_span(tmp_path):
         "serve_tokens_per_s", "model step", "lower")
     # every serving cell with a full-extent cache: PR 46's four, the
     # Mistral-4 cell (PR 48), the Laguna cell (PR 52), the Olmo-Hybrid cell
-    # (PR 56), the Granite cell (PR 60) and the Mistral long-prompt cell
-    # (PR 66)
-    assert len(entry["workloads"]) == 9
+    # (PR 56), the Granite cell (PR 60), the Mistral long-prompt cell
+    # (PR 66), the Kimi-Linear cell (its latent layers) and the Mistral
+    # decode-only cell (PR 67)
+    assert len(entry["workloads"]) == 11
     with open(os.path.join(hs.ROOT, "benchmarks", "layer_metrics",
                            name + ".json")) as f:
         spec = json.load(f)

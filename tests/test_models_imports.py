@@ -17,12 +17,12 @@ import pytest
 PACKAGE = pathlib.Path(__file__).resolve().parent.parent / "ray_tpu"
 MODELS = PACKAGE / "models"
 # What more than one family is built from; imports no family.
-SHARED = {"layers", "mla", "mamba2", "expert_share", "sampling"}
-# ``<family>.py`` and ``<family>_decode.py``: the ten the registry serves, and
+SHARED = {"layers", "mla", "mamba2", "delta_rule", "expert_share", "sampling"}
+# ``<family>.py`` and ``<family>_decode.py``: the eleven the registry serves, and
 # the three that only train.
 FAMILIES = {"gpt2", "llama", "longcat", "nemotron_h", "mimo_v2", "mistral4",
             "laguna", "olmo_hybrid", "granite_h", "minicpm_sala",
-            "vit", "resnet", "mlp"}
+            "kimi_linear", "vit", "resnet", "mlp"}
 
 
 def family_of(module: str) -> str:

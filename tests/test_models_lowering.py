@@ -17,7 +17,13 @@ decode steps and LongCat's prefill (``held_experts``' one-chunk form).
 PR 66 re-read ``("llama", "prefill")`` alone: ``llama_prefill`` scores
 through ``layers.blocked_attention`` (its key-value heads unrepeated, no
 ``[S, S]`` scores or mask); every other hash stayed, so no other family's
-program moved.  A PR that re-reads a hash says here which and why.
+program moved.  PR 67 added Kimi-Linear's three, read on its own tree, and
+re-read none: the delta rule's chunked form and ``pack_state`` moved from
+``olmo_hybrid.py`` to ``delta_rule.py`` and ``mla_blocked`` from
+``mistral4.py`` to ``mla.py`` with their bodies as they were, and the
+vector gate, the one-matrix query and ``rotate=False`` are branches that a
+family which does not ask for them never traces.  A PR that re-reads a hash
+says here which and why.
 """
 import hashlib
 
@@ -25,9 +31,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import (GPT2Config, GraniteHConfig, LagunaConfig,
-                            LlamaConfig, LongcatConfig, MimoV2Config,
-                            MinicpmSalaConfig, Mistral4Config,
+from ray_tpu.models import (GPT2Config, GraniteHConfig, KimiLinearConfig,
+                            LagunaConfig, LlamaConfig, LongcatConfig,
+                            MimoV2Config, MinicpmSalaConfig, Mistral4Config,
                             NemotronHConfig, OlmoHybridConfig, model_family)
 
 CONFIGS = {
@@ -35,7 +41,7 @@ CONFIGS = {
     "nemotron_h": NemotronHConfig, "mimo_v2": MimoV2Config,
     "mistral4": Mistral4Config, "laguna": LagunaConfig,
     "olmo_hybrid": OlmoHybridConfig, "granite_h": GraniteHConfig,
-    "minicpm_sala": MinicpmSalaConfig,
+    "minicpm_sala": MinicpmSalaConfig, "kimi_linear": KimiLinearConfig,
 }
 
 HASHES = {
@@ -70,6 +76,9 @@ HASHES = {
     ("minicpm_sala", "prefill"): "311672df83d7f7253a025cf5c50c6b7aeafb912c",
     ("minicpm_sala", "decode_step"): "9384f2d75965c632b36d4426d417df3d2853ca84",
     ("minicpm_sala", "loss"): "165cfb5a34cfda92531400c3e69bba2980a93239",
+    ("kimi_linear", "prefill"): "5ea9e0dbe48031f3b45a20fbd3742a979f8691a5",
+    ("kimi_linear", "decode_step"): "f5633124a1d2f9d103881a184c0840a73da493fc",
+    ("kimi_linear", "loss"): "978105a754526aa582f4d8f1f1d810642175665f",
 }
 
 

@@ -208,7 +208,7 @@ def test_a_padded_prefill_leaves_the_state_of_the_true_length(weights, n):
     np.testing.assert_allclose(exact["conv"][0, 0].reshape(3, -1), want,
                                atol=F32_TOL)
     # and the state is the recurrence's own at n, head by head
-    state = olmo_hybrid.unpack_state(exact["state"][0], cfg)
+    state = olmo_hybrid.unpack_state(exact["state"][0], cfg.state_pack)
     np.testing.assert_allclose(
         state, first_linear_layers_state(params, toks[:, :n], cfg),
         atol=F32_TOL)
@@ -358,14 +358,14 @@ def test_the_packed_state_is_the_heads_states_side_by_side():
     cfg = tiny()
     rng = np.random.default_rng(2)
     state = jnp.asarray(rng.normal(size=(3, 4, 8, 64)), jnp.float32)
-    packed = olmo_hybrid.pack_state(state, cfg)
+    packed = olmo_hybrid.pack_state(state, cfg.state_pack)
     assert packed.shape == (3, 2, 8, 128)
     np.testing.assert_array_equal(packed[:, 1, :, 64:], state[:, 3])
-    np.testing.assert_array_equal(olmo_hybrid.unpack_state(packed, cfg), state)
+    np.testing.assert_array_equal(olmo_hybrid.unpack_state(packed, cfg.state_pack), state)
     per_head = jnp.asarray(rng.normal(size=(3, 4, 8)), jnp.float32)
     lanes = delta_update.over_lanes(per_head, cfg.state_pack, 64)
     np.testing.assert_array_equal(
-        olmo_hybrid.unpack_state(lanes, cfg),
+        olmo_hybrid.unpack_state(lanes, cfg.state_pack),
         np.broadcast_to(per_head[..., None], (3, 4, 8, 64)))
     # heads that do not pair (or values of a whole tile) lie one a row
     assert tiny(linear_num_heads=3).state_pack == 1

@@ -1044,6 +1044,101 @@ def test_a_steps_linear_layers_are_calls_of_one_lowered_kernel(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+def test_kimi_decode_step_updates_three_leaves_where_they_lie(
+    cell, cell_decode_step, on_chip
+):
+    """The Kimi-Linear cell's programs at its size (published widths, 21
+    layers ``k`` + ``MKKK`` x 5, 16 of 256 experts held, an eighth of the
+    vocabulary, 64 slots x 4096): the decode step's arguments are the
+    weights (6.73 GB) and a cache of 2.15 GB of KDA state (``f32[16,64,32,
+    128,128]``: a head's ``[128, 128]`` is whole tiles, nothing packed or
+    padded), 0.15 GB of convolution windows and 1.51 GB of latents, ALL of
+    it aliased to the output.  Each KDA layer is ONE Pallas kernel
+    (``ops/delta_update.py`` with the decay a column beside ``q`` and ``k``)
+    whose operand and result are the WHOLE donated leaf, aliased: sixteen
+    calls of one lowered kernel, and nothing else produces an array of the
+    leaf's or of a layer's shape.  The five latent layers read their
+    slices of the stacked cache in blocks (five loops), and the top rungs
+    the engine compiles (2048 is the highest the cell's prompts reach, 4096
+    the highest it serves) are three scanned layer bodies whose
+    temporaries fit beside the step's arguments on the chip."""
+    from ray_tpu.llm.engine import jit_prefill_one
+
+    step, cache, params = cell_decode_step("kimi_linear_l21_ep16")
+    memory = step.memory_analysis()
+    assert cache["state"].shape == (16, 64, 32, 128, 128)
+    assert cache["conv"].shape == (16, 64, 3 * 12288)
+    assert cache["latent"].shape == (5, 64, 4096, 576)
+    assert 10.5e9 < memory.argument_size_in_bytes < 10.6e9
+    assert 3.80e9 < memory.alias_size_in_bytes < 3.82e9  # the whole cache
+    assert memory.temp_size_in_bytes < 0.1e9  # 0.07 GB
+    assert memory.generated_code_size_in_bytes < 60e6
+    text = step.as_text()
+    shape = ",".join(map(str, cache["state"].shape))
+    leaf = re.escape(f"f32[{shape}]{{4,3,2,1,0:T(8,128)}}")
+    assert re.search(leaf, text)
+    kernels = re.findall(
+        rf"^\s*%(\S+) = \({leaf}, [^\n]*?\) custom-call\(%constant[\w.]*, "
+        r'%([\w.-]+), [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        + re.escape("output_to_operand_aliasing={{0}: (1, {})}"), text, re.M)
+    assert len(kernels) == 16 == text.count("tpu_custom_call")
+    producers = dict(re.findall(
+        rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M))
+    assert set(producers.values()) == {"parameter", "get-tuple-element"}
+    assert {operand for _, operand in kernels} <= set(producers)
+    assert "64,32,128,128]" not in text.replace(f"[{shape}]", "")
+    made, slices, clones = window_traffic(text, cache["conv"])
+    assert not clones and made == ["fusion"] * 16
+    assert len(re.findall(
+        r' while\(.*op_name="[^"]*kimi\.mla/while"', text)) == 5
+    fam, cfg, _, _ = cell("kimi_linear_l21_ep16")
+    formats = step.input_formats[0][0]
+    lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, sharding=fmt), params, formats)
+    scalar = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    for top, limit in ((2048, 0.55e9), (4096, 1.1e9)):
+        tokens = on_chip(jax.ShapeDtypeStruct((top,), jnp.int32))
+        rung = jit_prefill_one(fam, cfg).lower(
+            lying, cache, tokens, scalar, scalar).compile()
+        assert rung.input_formats[0][0] == formats
+        assert rung.memory_analysis().temp_size_in_bytes < limit
+        assert rung.memory_analysis().generated_code_size_in_bytes < 30e6
+        # the chunk's (I + A)^-1 once a KDA body: layer 1's and the run's
+        assert len(re.findall(
+            'custom_call_target="InvertDiagBlocksLowerTriangular"',
+            rung.as_text())) == 2
+        # 15.75 GB of the chip: arguments + the rung's temporaries
+        assert (memory.argument_size_in_bytes
+                + rung.memory_analysis().temp_size_in_bytes) < 12.0e9
+
+
+@pytest.mark.parametrize("layers,at", [(1, 0), (16, 7)],
+                         ids=["one_layer", "layer_7_of_the_stack"])
+def test_vector_gate_delta_update_is_one_kernel_over_the_donated_leaf(
+    on_chip, as_if_on_tpu, layers, at
+):
+    """``ops.delta_update`` with the decay a vector a head, at Kimi-Linear's
+    widths and the cell's 64 slots, on its own: ONE custom call from the
+    donated leaf to itself, no temporary, no copy of the state, the small
+    operands (the decay's columns among them) a few megabytes beside it."""
+    slots, heads, dk = 64, 32, 128
+    leaf, q, scalar = (
+        on_chip(jax.ShapeDtypeStruct(dims, jnp.float32)) for dims in (
+            (layers, slots, heads, dk, dk), (slots, heads, dk),
+            (slots, heads, 1)))
+    step = jax.jit(
+        lambda leaf, *small: delta_update.delta_update(leaf, at, *small),
+        donate_argnums=(0,)).lower(leaf, q, q, q, q, scalar).compile()
+    memory = step.memory_analysis()
+    state = layers * slots * heads * dk * dk * 4
+    assert memory.alias_size_in_bytes == state
+    assert memory.temp_size_in_bytes == 0
+    assert memory.argument_size_in_bytes < state + 8e6
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+
+
 MAMBA_WIDTHS = {"granite_64_heads_1_group": (64, 1),
                 "nemotron_128_heads_8_groups": (128, 8)}
 
