@@ -25,7 +25,10 @@ aliased in place, the layer a prefetched operand, sixteen calls of one
 lowered kernel), the ``conv`` leaf through the same layers whole
 (``ops.conv_update``).  Latent attention goes by the deferred-scatter
 protocol: the cache holds ``[0, pos-1]``, the current token's latent rides
-beside it and is merged as a last score (``mla.mla_absorbed``), and all
+beside it and is merged as a last score (``mla.mla_absorbed``: on a TPU the
+family's second kind of kernel, ``ops.latent_attention``, ONE pipelined pass
+over the live blocks of the stacked leaf where it lies, five calls of one
+lowered kernel), and all
 ``Lm`` latents are written at the step's END by ``write_token_to_cache``
 (behind an ``optimization_barrier`` with the stream, as Granite-4.0-H's keys
 and values are: no write may move ahead of a later layer's read).

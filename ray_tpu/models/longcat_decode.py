@@ -16,9 +16,10 @@ the cache holds ``[0, pos-1]``, the current token's latent is merged as a
 last score, and all ``2L`` latents are written at the step's end by the
 families' one ``write_token_to_cache`` (the tile of rows that holds each
 slot's position, in place).  An attention reads its slice of the cache in
-blocks of 512 positions up to the batch's longest context, each block taken
-out of the stack once for both of its products (``mla_absorbed``;
-``tests/test_tpu_compile.py``).
+blocks of 512 positions up to the batch's longest context, in ONE pipelined
+pass over the stacked leaf where it lies (``mla_absorbed``; on a TPU
+``ops.latent_attention``'s kernel, which fetches block ``j + 1`` while block
+``j`` is scored: ``tests/test_tpu_compile.py``).
 
 Both return ``(logits, cache)`` as every family's do; with
 ``with_counts=True`` (the family's ``*_counted`` twins, which the engine

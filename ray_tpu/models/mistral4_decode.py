@@ -17,7 +17,9 @@ is computed), and takes the logits at ``n - 1``.  Decode runs the absorbed
 form, LongCat's (``mla.mla_absorbed``): the current token's latent
 rides beside the cache and is merged as a last score, a layer reads its
 slice of the cache in blocks of 512 positions up to the batch's longest
-context, each block taken out of the stack once for both products, and all
+context in ONE pipelined pass over the stacked leaf where it lies (on a
+TPU ``ops.latent_attention``'s kernel: block ``j + 1`` is fetched while
+block ``j`` is scored; nine calls of one lowered kernel), and all
 ``L`` latents are written at the step's end by the families' one
 ``write_token_to_cache``.  The query's scale ``a(pos) m^2`` is folded into the
 query in ``mistral4.project``, so both forms score with ``(dn+dr)^-0.5``

@@ -27,7 +27,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops.decode_attention import attend_live_blocks, extent_step
+from ..ops.decode_attention import extent_step
+from ..ops.latent_attention import latent_attention
 from .layers import blocked_attention, matmul, rmsnorm, rope
 
 
@@ -113,8 +114,11 @@ def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg,
     latent_self [B, C] (the current token's); latent_cache [A, B, T, C], the
     STACKED cache, of which attention ``layer``'s slice holds [0, pos-1];
     pos [B] -> [B, d] float32.  A cache of several extents is read in blocks
-    up to the batch's longest context (``ops/decode_attention``'s
-    ``attend_live_blocks``), each block taken from the stack itself.
+    up to the batch's longest context, in one pass over the stack itself
+    (``ops/latent_attention``: on a TPU a Pallas kernel that fetches block
+    ``j + 1`` while block ``j`` is scored, elsewhere ``attend_live_blocks``'
+    loop; the shapes decide, no argument does); a cache of ONE extent is
+    scored whole, below.
     ``att`` holds ``Wkvb`` whole (``wkv_b [rkv, H, dn+dv]``, LongCat's: its
     halves are sliced out here) or as two leaves (``wk_b [rkv, H, dn]``,
     ``wv_b [rkv, H, dv]``, mistral4's: each product reads its own stack
@@ -129,23 +133,12 @@ def mla_absorbed(q, latent_self, latent_cache, pos, att, cfg,
     qt = matmul("bhn,chn->bhc", q[..., :dn], w_k).astype(q.dtype)
     qc = jnp.concatenate([qt, q[..., dn:]], -1)  # [B, H, C]
     scale = q.shape[-1] ** -0.5
-    _, b, t, c = latent_cache.shape
-    step = extent_step(t)
-    s_self = matmul("bhc,bc->bh", qc, latent_self) * scale
-    if step < t:
-        def block(start):
-            latents = jax.lax.dynamic_slice(
-                latent_cache, (layer, 0, start, 0), (1, b, step, c))[0]
-            scores = matmul("bhc,btc->bht", qc, latents) * scale
-            before = jnp.arange(step)[None, None] < (
-                pos - start)[:, None, None]
-            return jnp.where(before, scores, -1e30), lambda p: matmul(
-                "bht,btc->bhc", p.astype(q.dtype), latents[..., :rkv])
-
-        oc = attend_live_blocks(
-            block, jnp.max(pos), t, qc.shape[:2] + (rkv,),
-            [(s_self, latent_self[:, None, :rkv].astype(jnp.float32))])
+    t = latent_cache.shape[2]
+    if extent_step(t) < t:
+        oc = latent_attention(latent_cache, layer, qc, latent_self, pos,
+                              rkv=rkv, scale=scale)
     else:
+        s_self = matmul("bhc,bc->bh", qc, latent_self) * scale
         latents = latent_cache[layer]
         scores = matmul("bhc,btc->bht", qc, latents) * scale
         before = jnp.arange(t)[None, None] < pos[:, None, None]

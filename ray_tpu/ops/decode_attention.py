@@ -9,8 +9,11 @@ the reference has no kernels of its own).
 
 One path, in plain XLA: ``decode_attention``, which the GPT-2, Llama,
 Nemotron-H and MiMo-V2 decode steps call, and ``attend_live_blocks`` under
-it, which the latent attention of LongCat and Mistral-4 calls with a block
-of its own (``models/longcat_decode.py`` ``mla_absorbed``).
+it, which the latent attention of LongCat, Mistral-4 and Kimi-Linear calls
+with a block of its own off a TPU (``ops/latent_attention.py``
+``latent_attention_xla``; on a TPU the same pass over the same blocks is that
+file's Pallas kernel, which fetches a block while the one before it is
+scored).
 
 What a step reads.  A full-extent cache is read up to the BATCH's longest
 live context, in blocks of ``extent_step(T)`` = 512 positions: one loop with

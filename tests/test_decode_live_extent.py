@@ -5,7 +5,11 @@ context (``ops/decode_attention.py`` ``attend_live_blocks``; LongCat's
 What is pinned here, on the CPU: the extent is one function of ``pos`` for the
 host and the program; the result is the one-shot softmax's to rounding and a
 row's BITS do not depend on its neighbours' contexts; nothing beyond the
-extent is read; a ring and a cache of one extent take the old path."""
+extent is read; a ring and a cache of one extent take the old path.  The
+latent families' pass is pinned in both its forms: the XLA loop
+(``mla_absorbed`` as it runs here) and the Pallas kernel a TPU takes
+(``ops/latent_attention.py``, forced in interpret mode, at the three served
+head and channel shapes)."""
 
 import jax
 import jax.numpy as jnp
@@ -306,3 +310,106 @@ def test_mla_absorbed_over_thirty_two_blocks_with_the_halves_as_two_leaves(
         near)
     assert loops(lambda *a: mla.mla_absorbed(
         *a, halves, cfg, layer=1), q, latent_self, cache, pos) == 1
+
+
+# ------------------------------ the pass as ONE kernel (ops/latent_attention)
+# (family, H, rkv, Wkvb as two leaves): the three served head / channel
+# shapes, ``C`` = rkv + 64, with everything else cut small
+SERVED_LATENTS = [("mistral4", 32, 256, True), ("kimi_linear", 32, 512, True),
+                  ("longcat", 64, 512, False)]
+
+
+def served_operands(h, rkv, two_leaves, t, b=4, layers=3, seed=0):
+    """``mla_operands`` at a served head count and latent width: bfloat16,
+    ``dn`` 32 and ``dv`` 16 (the absorbed products do not see them), the key
+    of all heads ``dr`` 64 wide as published."""
+    import types
+
+    dn, dr, dv, d = 32, 64, 16, 64
+    cfg = types.SimpleNamespace(kv_lora_rank=rkv, qk_nope_head_dim=dn)
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(
+        rng.normal(size=shape) / np.sqrt(shape[-1]), "bfloat16")
+    att = {"wkv_b": draw(rkv, h, dn + dv), "wo": draw(h, dv, d)}
+    now = att if not two_leaves else {
+        "wk_b": att["wkv_b"][..., :dn], "wv_b": att["wkv_b"][..., dn:],
+        "wo": att["wo"]}
+    return cfg, att, now, (draw(b, h, dn + dr) * 4, draw(b, rkv + dr),
+                           draw(layers, b, t, rkv + dr) * 4)
+
+
+@pytest.fixture
+def as_one_kernel(monkeypatch):
+    """``mla_absorbed`` takes the Pallas kernel, in interpret mode: what a TPU
+    decides from the shapes the test decides here (no argument of
+    ``mla_absorbed`` does)."""
+    import functools
+
+    from ray_tpu.ops.latent_attention import latent_attention
+
+    def force(slots=None):
+        monkeypatch.setattr(mla, "latent_attention", functools.partial(
+            latent_attention, force_pallas=True, slots=slots))
+    return force
+
+
+@pytest.mark.parametrize("slots", [None, 2], ids=["by_shape", "two_a_cell"])
+@pytest.mark.parametrize("family,h,rkv,two_leaves", SERVED_LATENTS,
+                         ids=[s[0] for s in SERVED_LATENTS])
+def test_mla_absorbed_as_one_kernel_gives_what_the_whole_softmax_gave(
+    as_one_kernel, family, h, rkv, two_leaves, slots
+):
+    """Against PR 45's one-shot form at the existing tolerance: rows at 1,
+    ``step``, ``step + 1`` and ``t - 1`` cached positions and an idle row,
+    ``layer`` 2 of three reading its own slice; a row's bits the same whether
+    its neighbours make the live bound one block or all four, and whichever
+    cell of the grid it shares with them."""
+    as_one_kernel(slots)
+    cfg, att, now_att, (q, latent_self, cache) = served_operands(
+        h, rkv, two_leaves, T)
+    now = jax.jit(lambda *a: mla.mla_absorbed(*a, now_att, cfg, layer=2))
+    before = jax.jit(lambda q, ls, cache, pos: mla_absorbed_before(
+        q, ls, cache[2], pos, att, cfg))
+    assert "pallas" in str(jax.make_jaxpr(now)(
+        q, latent_self, cache, jnp.zeros(4, jnp.int32)))
+    beside = {}
+    for others in (1, STEP, STEP + 1, T - 1):
+        for edge in (0, 1, STEP, STEP + 1):
+            pos = jnp.asarray([edge, 0, others, others], jnp.int32)
+            got = now(q, latent_self, cache, pos)
+            assert got.dtype == jnp.float32
+            np.testing.assert_allclose(
+                got, before(q, latent_self, cache, pos),
+                atol=TOL["bfloat16"], rtol=TOL["bfloat16"])
+            beside.setdefault(edge, []).append(np.asarray(got[0]))
+            beside.setdefault("idle", []).append(np.asarray(got[1]))
+    for rows in beside.values():  # one row, four neighbourhoods
+        for other in rows[1:]:
+            np.testing.assert_array_equal(rows[0], other)
+    poisoned = cache.at[:, :, 2 * STEP:].set(jnp.nan).at[:2].set(jnp.nan)
+    pos = jnp.asarray([2 * STEP, 1, 40, 0], jnp.int32)
+    np.testing.assert_array_equal(
+        now(q, latent_self, poisoned, pos), now(q, latent_self, cache, pos))
+
+
+@pytest.mark.parametrize("family,h,rkv,two_leaves", SERVED_LATENTS,
+                         ids=[s[0] for s in SERVED_LATENTS])
+def test_mla_absorbed_as_one_kernel_is_the_xla_loop_bit_for_bit(
+    as_one_kernel, family, h, rkv, two_leaves
+):
+    """Off a TPU ``mla_absorbed`` is the XLA loop; forced through the kernel
+    (interpret mode runs the same operations) it gives the same bits, at
+    every layer of the stack."""
+    cfg, _, att, (q, latent_self, cache) = served_operands(
+        h, rkv, two_leaves, 1024, seed=1)
+    pos = jnp.asarray([0, 1023, 512, 513], jnp.int32)
+    loop = [jax.jit(lambda *a, i=i: mla.mla_absorbed(*a, att, cfg, layer=i))(
+        q, latent_self, cache, pos) for i in range(3)]
+    assert loops(lambda *a: mla.mla_absorbed(*a, att, cfg, layer=1),
+                 q, latent_self, cache, pos) == 1
+    as_one_kernel()
+    for i, want in enumerate(loop):
+        np.testing.assert_array_equal(jax.jit(
+            lambda *a: mla.mla_absorbed(*a, att, cfg, layer=i))(
+                q, latent_self, cache, pos), want)
+    assert not np.array_equal(loop[0], loop[1])

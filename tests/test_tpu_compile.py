@@ -357,7 +357,10 @@ def test_decode_step_writes_its_token_into_the_cache_in_place(
 
 
 # A cell's full-extent attentions, and the most its decode step may hold in
-# temporaries (the limits of the tests around this one).
+# temporaries (the limits of the tests around this one).  In the cells of
+# ``LATENT_READS`` each of them is ONE call of ``ops/latent_attention.py``'s
+# kernel and no loop (PR 68); in the others one loop over blocks.
+LATENT_READS = ("longcat_flash_l4_ep32", "mistral_small4_l9_ep8")
 BOUNDED_READS = {"mistral7b_l16": (16, 0.05e9),
                  "longcat_flash_l4_ep32": (8, 0.2e9),
                  "nemotron3_super_l11_ep4": (1, 0.05e9),
@@ -378,16 +381,23 @@ def test_decode_step_reads_its_live_blocks_where_they_lie(
 ):
     """Every full-extent attention of the step is ONE loop over blocks of
     ``extent_step(T)`` positions (``ops/decode_attention.py``
-    ``attend_live_blocks``), and in no computation of the module, the loops'
+    ``attend_live_blocks``) or, where the cache holds latents (LongCat,
+    Mistral-4), ONE call of ``ops/latent_attention.py``'s kernel, whose grid
+    is that loop: eight and nine calls of one lowered kernel, each handed
+    the donated ``latent`` leaf WHOLE, as a view of the bytes where they lie
+    (``latent_kernels``).  In no computation of the module, the loops'
     bodies included, does anything produce an array of a cache prefix's
     shape (a leaf's slice of fewer whole steps than it has, or fewer of its
     last axis) in
     the chip's main memory: the products read each block out of the donated
     stack.  A block copied first is three passes over it where there was one
     (what each LongCat attention did to its WHOLE ``[32, 2048, 576]`` slice
-    until PR 46: 0.60 GB of temporaries, 1.59 ms a step).  ONE block in the
-    fast memory (``S(1)``) is a prefetch: LongCat's two products share one
-    read of their ``[32, 512, 576]`` latents that way."""
+    until PR 46: 0.60 GB of temporaries, 1.59 ms a step).  XLA may hold ONE
+    block in the fast memory (``S(1)``) for a loop's products; the latent
+    layers' loops did, and ran copy, products, copy in turn (5.26 of the
+    Mistral-4 step's 13.4 ms: PERF.md, PR 58) until the kernel's double
+    buffer fetched block ``j + 1`` under block ``j``'s products: no XLA
+    instruction makes a block of latents any more, in any memory."""
     import json
 
     from ray_tpu.ops.decode_attention import extent_step
@@ -399,11 +409,17 @@ def test_decode_step_reads_its_live_blocks_where_they_lie(
         t = json.load(f)["engine"]["max_seq_len"]
     step = extent_step(t)
     assert step == 512 < t
-    loops, temp_limit = BOUNDED_READS[name]
-    assert len(re.findall(
+    reads, temp_limit = BOUNDED_READS[name]
+    loops = len(re.findall(
         r' while\(.*op_name="[^"]*(?:decode_attention\)|longcat\.mla|mistral4\.mla)'
         r'/while"',
-        text)) == loops
+        text))
+    if name in LATENT_READS:
+        assert loops == 0
+        assert len(latent_kernels(text, cache["latent"])) == reads
+        assert not re.search(r"bf16\[[\d,]*\b512,(?:320|576)\]", text)
+    else:
+        assert loops == reads
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
     prefixes = {}  # dims without the 1s -> steps
     for leaf in jax.tree.leaves(cache):
@@ -427,6 +443,34 @@ def test_decode_step_reads_its_live_blocks_where_they_lie(
             if steps and not (steps == 1 and "S(1)" in layout):
                 made.append((op, dims, layout))
     assert not made
+
+
+def latent_kernels(text, leaf):
+    """The names of a compiled step's ``ops.latent_attention`` kernels: custom
+    calls named ``latent_attention`` (one lowered kernel: the layer rides in
+    the prefetched scalars) whose third operand, after those scalars and the
+    queries, is the latent leaf ``[A, B, T, C]`` WHOLE, as the kernel sees
+    the bytes the program holds: ``[A, B, C, T]`` row-major, ONE ``bitcast``
+    of the donated parameter (positions minor: ``C`` is no multiple of 128),
+    not a copy, a transpose or a slice of it."""
+    a, b, t, c = leaf.shape
+    held = re.escape(f"bf16[{a},{b},{t},{c}]{{2,3,1,0:T(8,128)(2,1)}}")
+    seen = re.escape(f"bf16[{a},{b},{c},{t}]{{3,2,1,0:T(8,128)(2,1)}}")
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text,
+                      re.M | re.S).group(1)
+    donated = re.findall(rf"^\s*%(\S+) = {held} parameter\(", entry, re.M)
+    assert len(donated) == 1
+    views = re.findall(rf"^\s*%(\S+) = {seen} ([\w-]+)\(%([\w.-]+)\)", text,
+                       re.M)
+    assert [(op, of) for _, op, of in views] == [("bitcast", donated[0])]
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = f32\[[\d,]+\]\S* custom-call\(%[\w.-]+, "
+        r"%[\w.-]+, "
+        r'%([\w.-]+), [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
+        r'/latent_attention/pallas_call"', text, re.M)
+    assert {operand for _, operand in calls} <= {views[0][0]}
+    assert len(calls) == text.count("/latent_attention/pallas_call")
+    return [name for name, _ in calls]
 
 
 def mamba_kernels(text, shape):
@@ -1058,7 +1102,10 @@ def test_kimi_decode_step_updates_three_leaves_where_they_lie(
     whose operand and result are the WHOLE donated leaf, aliased: sixteen
     calls of one lowered kernel, and nothing else produces an array of the
     leaf's or of a layer's shape.  The five latent layers read their
-    slices of the stacked cache in blocks (five loops), and the top rungs
+    slices of the stacked cache in blocks through the family's second kind
+    of kernel (``ops/latent_attention.py``: five calls of one lowered kernel
+    over the donated ``latent`` leaf where it lies, no loop and no block of
+    latents made by anything else), and the top rungs
     the engine compiles (2048 is the highest the cell's prompts reach, 4096
     the highest it serves) are three scanned layer bodies whose
     temporaries fit beside the step's arguments on the chip."""
@@ -1081,7 +1128,9 @@ def test_kimi_decode_step_updates_three_leaves_where_they_lie(
         rf"^\s*%(\S+) = \({leaf}, [^\n]*?\) custom-call\(%constant[\w.]*, "
         r'%([\w.-]+), [^\n]*custom_call_target="tpu_custom_call"[^\n]*'
         + re.escape("output_to_operand_aliasing={{0}: (1, {})}"), text, re.M)
-    assert len(kernels) == 16 == text.count("tpu_custom_call")
+    assert len(kernels) == 16
+    assert len(latent_kernels(text, cache["latent"])) == 5
+    assert text.count("tpu_custom_call") == 16 + 5
     producers = dict(re.findall(
         rf"^\s*(?:ROOT )?%(\S+) = f32\[{shape}\]\S* ([\w-]+)\(", text, re.M))
     assert set(producers.values()) == {"parameter", "get-tuple-element"}
@@ -1089,8 +1138,8 @@ def test_kimi_decode_step_updates_three_leaves_where_they_lie(
     assert "64,32,128,128]" not in text.replace(f"[{shape}]", "")
     made, slices, clones = window_traffic(text, cache["conv"])
     assert not clones and made == ["fusion"] * 16
-    assert len(re.findall(
-        r' while\(.*op_name="[^"]*kimi\.mla/while"', text)) == 5
+    assert not re.search(r' while\(.*op_name="[^"]*kimi\.mla/while"', text)
+    assert not re.search(r"bf16\[[\d,]*\b512,576\]", text)
     fam, cfg, _, _ = cell("kimi_linear_l21_ep16")
     formats = step.input_formats[0][0]
     lying = jax.tree.map(lambda leaf, fmt: jax.ShapeDtypeStruct(
@@ -1137,6 +1186,40 @@ def test_vector_gate_delta_update_is_one_kernel_over_the_donated_leaf(
     text = step.as_text()
     assert text.count("tpu_custom_call") == 1
     assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+
+
+# (A, B, T, H, C, rkv): the three cells that hold a latent cache
+LATENT_LEAVES = {"mistral4": (9, 32, 16384, 32, 320, 256),
+                 "kimi_linear": (5, 64, 4096, 32, 576, 512),
+                 "longcat": (8, 32, 2048, 64, 576, 512)}
+
+
+@pytest.mark.parametrize("family", LATENT_LEAVES)
+def test_latent_attention_alone_is_one_kernel_over_the_leaf_where_it_lies(
+    on_chip, as_if_on_tpu, family
+):
+    """``ops.latent_attention`` at the three served shapes, on its own, a
+    layer inside the stack: ONE custom call that reads the leaf as the
+    program holds it (positions minor; ``latent_kernels`` checks the view is
+    a bitcast), no temporary, and the fast memory it asks for (a cell's block
+    of latents twice and as much again) under a quarter of the v5e's 128
+    MiB."""
+    from ray_tpu.ops import latent_attention as la
+
+    a, b, t, h, c, rkv = LATENT_LEAVES[family]
+    leaf, qc, own = (on_chip(jax.ShapeDtypeStruct(dims, jnp.bfloat16))
+                     for dims in ((a, b, t, c), (b, h, c), (b, c)))
+    pos = on_chip(jax.ShapeDtypeStruct((b,), jnp.int32))
+    step = jax.jit(lambda leaf, qc, own, pos: la.latent_attention(
+        leaf, a - 2, qc, own, pos, rkv=rkv, scale=0.1)).lower(
+            leaf, qc, own, pos).compile()
+    assert step.memory_analysis().temp_size_in_bytes == 0
+    text = step.as_text()
+    assert len(latent_kernels(text, leaf)) == 1 == text.count(
+        "tpu_custom_call")
+    assert " while(" not in text
+    asked = 4 * la.slots_per_cell(b, c, 512, 2) * c * 512 * 2
+    assert asked <= 4 * la._BLOCK_BYTES < 32 << 20
 
 
 MAMBA_WIDTHS = {"granite_64_heads_1_group": (64, 1),
